@@ -2,10 +2,10 @@
 """Continuous bench regression gate: run bench.py, diff the stdout JSON
 against the committed BENCH_trajectory.json, fail loudly on regression.
 
-The BENCH_r0*.json files record *round* headlines (human-curated, once
-per optimization round); nothing re-runs them, so a silent perf
-regression between rounds only surfaces at the next round. This gate
-closes the loop: tier-1 CI runs `bench_gate.py --smoke` on every push,
+Chip results live in the driver's PERF_LEDGER.jsonl and appear once per
+PR; between them a gross regression (a recompile per cycle, a serialized
+pipeline) would go unseen. This gate is the CPU tripwire for that:
+tier-1 CI runs `bench_gate.py --smoke` on every push,
 compares the measured smoke metrics against the committed trajectory
 with generous per-metric tolerances (CPU CI boxes are noisy — the gate
 is a tripwire for *gross* regressions like an accidental recompile per

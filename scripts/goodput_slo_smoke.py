@@ -178,7 +178,7 @@ def check_goodput(artifact_dir: str) -> str:
         if name.endswith(".metrics.jsonl"):
             with open(os.path.join(config.train.logging_dir, name)) as f:
                 rows += [json.loads(line) for line in f if line.strip()]
-    goodput_rows = [r for r in rows if "goodput/mfu" in r]
+    goodput_rows = [r for r in rows if "goodput/wall_s" in r]
     assert len(goodput_rows) >= 2, (
         f"goodput/* flushed {len(goodput_rows)}x; want every stats step"
     )

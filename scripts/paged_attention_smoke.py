@@ -1,6 +1,6 @@
 """CI smoke: the fused Pallas paged-attention decode kernel serving a
 short CPU PPO run end to end. A 2-cycle supervised-fleet run generates
-through paged replicas with `decode_kernel: pallas` (Pallas interpret
+through paged replicas with `decode_kernel: interpret` (Pallas interpret
 mode on CPU — the real kernel arithmetic, no TPU required) and
 `tracing: true` so every replica engine carries a CompileLedger.
 
@@ -65,7 +65,7 @@ def build_config(workdir: str):
         inference=dict(num_slots=4, max_prompt_len=32, max_new_tokens=MAX_NEW,
                        max_wait_s=0.0,
                        kv_paging=True, kv_block_size=KV_BLOCK,
-                       decode_kernel="pallas", tracing=True),
+                       decode_kernel="interpret", tracing=True),
     )
 
 
@@ -190,7 +190,7 @@ def run_unsupported_shape():
                 break
         return toks, eng.kv_stats()
 
-    kernel_toks, kernel_stats = decode("pallas")
+    kernel_toks, kernel_stats = decode("interpret")
     gather_toks, _ = decode("xla")
     n_alibi = kernel_stats.get("kv_kernel_fallbacks", {}).get("alibi", 0)
     assert n_alibi >= 1, f"no counted alibi fallback: {kernel_stats}"

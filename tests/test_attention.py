@@ -159,10 +159,7 @@ def test_model_ring_matches_xla():
     from trlx_tpu.models.transformer import TransformerConfig, TransformerLM
     from trlx_tpu.parallel import MeshRuntime
 
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     base = dict(

@@ -29,8 +29,15 @@ NUM_SLOTS = 4  # pool deliberately smaller than the request count
 MAX_NEW = 32
 
 
+# A test run does not edit a committed record: results go to the
+# git-ignored logs/ directory; BENCH_load_slo.json at the root is only
+# ever updated by hand from there.
+RECORD_PATH = os.path.join(os.path.dirname(__file__), "..", "logs",
+                           "BENCH_load_slo.json")
+
+
 def _merge_bench_record(path, record=None, **sections):
-    """Read-modify-write BENCH_load_slo.json: the SLO run owns the
+    """Read-modify-write logs/BENCH_load_slo.json: the SLO run owns the
     top-level keys, other tests (the paged KV A/B) own named sections —
     whichever runs later must not clobber the other's numbers."""
     merged = {}
@@ -45,6 +52,7 @@ def _merge_bench_record(path, record=None, **sections):
                 if k in merged}
         merged = {**record, **keep}
     merged.update(sections)
+    os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(merged, f, indent=2)
 
@@ -356,8 +364,7 @@ def test_sustained_saturation_slo_with_replica_kill(trainer):
             },
             "events": list(supervisor.events),
         }
-        out_path = os.path.join(os.path.dirname(__file__), "..",
-                                "BENCH_load_slo.json")
+        out_path = RECORD_PATH
         _merge_bench_record(out_path, record)
         print(f"\nsustained-saturation SLO: {json.dumps(record)}")
         assert p50 <= SLO_P50_S, f"p50 {p50:.2f}s blew the {SLO_P50_S}s SLO"
@@ -433,8 +440,7 @@ def test_paged_vs_fixed_ab_at_equal_hbm(trainer):
         "throughput_ratio": round(
             paged["tokens_per_s"] / max(fixed["tokens_per_s"], 1e-9), 2),
     }
-    out_path = os.path.join(os.path.dirname(__file__), "..",
-                            "BENCH_load_slo.json")
+    out_path = RECORD_PATH
     _merge_bench_record(out_path, paged_kv=record)
     print(f"\npaged-vs-fixed A/B: {json.dumps(record)}")
     assert ratio >= 2.0, (
@@ -602,8 +608,7 @@ def test_multi_tenant_skewed_load_slo(tmp_path):
             "store": {k: v for k, v in store.stats().items()
                       if isinstance(v, (int, float))},
         }
-        out_path = os.path.join(os.path.dirname(__file__), "..",
-                                "BENCH_load_slo.json")
+        out_path = RECORD_PATH
         _merge_bench_record(out_path, multi_tenant=record)
         print(f"\nmulti-tenant skewed SLO: {json.dumps(record)}")
         for tenant in ("hot", "bg"):
@@ -704,8 +709,7 @@ def test_session_multiturn_ttft_bench(trainer):
             "store": {k: v for k, v in stats.items()
                       if isinstance(v, (int, float))},
         }
-        out_path = os.path.join(os.path.dirname(__file__), "..",
-                                "BENCH_load_slo.json")
+        out_path = RECORD_PATH
         _merge_bench_record(out_path, sessions=record)
         print(f"\nsession multiturn bench: {json.dumps(record)}")
     finally:

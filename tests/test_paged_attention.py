@@ -88,14 +88,20 @@ BOUNDARY_PROMPTS = [
 
 def _random_paged_case(rng, nh, nkv, b=3, hd=16, blk=8, n_tbl=4, n_blocks=10):
     q = jnp.asarray(rng.randn(b, nh, hd), jnp.float32)
-    ka = jnp.asarray(rng.randn(n_blocks, blk, nkv, hd), jnp.float32).at[0].set(0.0)
-    va = jnp.asarray(rng.randn(n_blocks, blk, nkv, hd), jnp.float32).at[0].set(0.0)
+    ka = jnp.asarray(rng.randn(n_blocks, nkv, blk, hd), jnp.float32).at[0].set(0.0)
+    va = jnp.asarray(rng.randn(n_blocks, nkv, blk, hd), jnp.float32).at[0].set(0.0)
     table = jnp.asarray(rng.randint(0, n_blocks, (b, n_tbl)), jnp.int32)
     # lengths at / around block boundaries, plus one inactive row
     lens = jnp.asarray([blk - 1, 2 * blk + 1, 0], jnp.int32)[:b]
     cols = jnp.arange(n_tbl * blk)[None, :]
     mask = (cols < lens[:, None]).astype(jnp.int32)
     return q, ka, va, table, mask, lens
+
+
+def _quantize_arena(arena):
+    """int8 arena + its [n_blocks, 1, nkv*blk] head-major scale plane."""
+    q, scale = quant.quantize_kv(arena)
+    return q, scale.reshape(arena.shape[0], 1, -1)
 
 
 @pytest.mark.parametrize("nh,nkv", [(4, 4), (4, 2), (4, 1)])
@@ -118,8 +124,8 @@ def test_kernel_matches_reference_gqa(nh, nkv):
 def test_kernel_int8_in_kernel_dequant(nh, nkv):
     rng = np.random.RandomState(1)
     q, ka, va, table, mask, lens = _random_paged_case(rng, nh, nkv)
-    kq, ks = quant.quantize_kv(ka)
-    vq, vs = quant.quantize_kv(va)
+    kq, ks = _quantize_arena(ka)
+    vq, vs = _quantize_arena(va)
     out_k = paged_attention_decode(
         q, kq, vq, table, mask, k_scale=ks, v_scale=vs, interpret=True
     )
@@ -136,8 +142,8 @@ def test_kernel_int8_in_kernel_dequant(nh, nkv):
 def test_kernel_requires_scales_for_int8():
     rng = np.random.RandomState(2)
     q, ka, va, table, mask, _ = _random_paged_case(rng, 4, 2)
-    kq, ks = quant.quantize_kv(ka)
-    vq, vs = quant.quantize_kv(va)
+    kq, ks = _quantize_arena(ka)
+    vq, vs = _quantize_arena(va)
     with pytest.raises(ValueError, match="scale"):
         paged_attention_decode(q, kq, vq, table, mask, interpret=True)
 
@@ -150,7 +156,7 @@ def test_kernel_requires_scales_for_int8():
 def test_greedy_bitwise_f32_slot_reuse_and_boundaries(trainers, preset):
     tr = trainers[preset]
     gather = run_serial(make_engine(tr, "xla"), BOUNDARY_PROMPTS)
-    kernel = run_serial(make_engine(tr, "pallas"), BOUNDARY_PROMPTS)
+    kernel = run_serial(make_engine(tr, "interpret"), BOUNDARY_PROMPTS)
     assert kernel == gather
 
 
@@ -162,7 +168,7 @@ def test_greedy_bitwise_bf16_kv(trainers):
         make_engine(tr, "xla", kv_cache_dtype="bf16"), BOUNDARY_PROMPTS
     )
     kernel = run_serial(
-        make_engine(tr, "pallas", kv_cache_dtype="bf16"), BOUNDARY_PROMPTS
+        make_engine(tr, "interpret", kv_cache_dtype="bf16"), BOUNDARY_PROMPTS
     )
     assert kernel == gather
 
@@ -176,7 +182,7 @@ def test_greedy_int8_within_dequant_tolerance(trainers):
         make_engine(tr, "xla", kv_cache_dtype="int8"), BOUNDARY_PROMPTS
     )
     kernel = run_serial(
-        make_engine(tr, "pallas", kv_cache_dtype="int8"), BOUNDARY_PROMPTS
+        make_engine(tr, "interpret", kv_cache_dtype="int8"), BOUNDARY_PROMPTS
     )
     matches = sum(a == b for a, b in zip(gather, kernel))
     assert matches >= len(BOUNDARY_PROMPTS) - 1, (gather, kernel)
@@ -204,7 +210,7 @@ def test_decode_kernel_xla_pins_todays_path(trainers):
 
 def test_kernel_dispatch_counters(trainers):
     tr = trainers["llama-tiny"]
-    eng = make_engine(tr, "pallas")
+    eng = make_engine(tr, "interpret")
     assert eng._attn_kernel == "interpret"  # explicit request off-TPU
     run_serial(eng, BOUNDARY_PROMPTS[:2], max_new=4)
     stats = eng.kv_stats()
@@ -214,7 +220,7 @@ def test_kernel_dispatch_counters(trainers):
 
 def test_alibi_falls_back_with_reason():
     tr = _build_trainer("bloom-tiny")  # alibi=True
-    eng = make_engine(tr, "pallas")
+    eng = make_engine(tr, "interpret")
     assert eng._kernel_unsupported == "alibi"
     kernel = run_serial(eng, BOUNDARY_PROMPTS[:1], max_new=4)
     stats = eng.kv_stats()
@@ -235,24 +241,33 @@ def test_invalid_decode_kernel_rejected(trainers):
 # ----------------------------------------------------------------------
 
 def test_kernel_mode_env_override(monkeypatch):
-    # tier-1 runs under JAX_PLATFORMS=cpu: never the compiled kernel
+    # tier-1 runs on CPU devices: the rule resolves to the XLA paths, the
+    # interpreter only by name, and a demand for the compiled kernel raises
     monkeypatch.delenv("TRLX_TPU_KERNELS", raising=False)
-    assert kernel_mode() in ("off", "pallas")  # pallas only on real TPU
+    assert kernel_mode() == "off"
     monkeypatch.setenv("TRLX_TPU_KERNELS", "off")
     assert kernel_mode() == "off"
     monkeypatch.setenv("TRLX_TPU_KERNELS", "interpret")
     assert kernel_mode() == "interpret"
-    # a forced kernel off-TPU degrades to interpret, never compiled
     monkeypatch.setenv("TRLX_TPU_KERNELS", "pallas")
-    import jax
+    with pytest.raises(RuntimeError, match="platform 'cpu'"):
+        kernel_mode()
+    monkeypatch.setenv("TRLX_TPU_KERNELS", "mosaic")
+    with pytest.raises(ValueError, match="TRLX_TPU_KERNELS"):
+        kernel_mode()
 
-    expected = "pallas" if (
-        jax.default_backend() == "tpu" and jax.device_count() == 1
-    ) else "interpret"
-    assert kernel_mode() == expected
+
+def test_compiled_kernel_request_raises_off_tpu(trainers, monkeypatch):
+    """decode_kernel='pallas' never degrades to the interpreter: on CPU
+    devices it raises, as does the env demand under 'auto'."""
+    with pytest.raises(RuntimeError, match="platform 'cpu'"):
+        make_engine(trainers["gpt2-tiny"], "pallas")
+    monkeypatch.setenv("TRLX_TPU_KERNELS", "pallas")
+    with pytest.raises(RuntimeError, match="platform 'cpu'"):
+        make_engine(trainers["gpt2-tiny"], "auto")
 
 
 def test_env_kill_switch_pins_gather_path(trainers, monkeypatch):
     monkeypatch.setenv("TRLX_TPU_KERNELS", "off")
-    eng = make_engine(trainers["gpt2-tiny"], "pallas")
+    eng = make_engine(trainers["gpt2-tiny"], "interpret")
     assert eng._attn_kernel is None

@@ -212,15 +212,19 @@ class InferenceConfig:
     :param decode_kernel: paged decode attention read path. "auto"
         (default) uses the fused Pallas paged-attention kernel
         (`ops/paged_attention.py`: direct block-table KV fetch, in-kernel
-        int8 dequant, online flash softmax, GQA-grouped) on a single TPU
-        chip and the gather path elsewhere; "xla" pins today's
-        gather+dense-softmax read path bitwise; "pallas" requests the
-        kernel explicitly, running it through the Pallas interpreter
-        off-TPU (CPU-executable, same blockwise math — the CI smoke).
-        Shapes the kernel cannot express (spec-decode verify rows,
-        alibi/sliding-window biases, paging off) fall back to the gather
-        path per dispatch with a counted reason
-        (``kv_kernel_fallbacks{reason}`` in /metrics and healthz).
+        int8 dequant, online flash softmax, GQA-grouped) when the params
+        live on exactly one TPU device and the gather path elsewhere (a
+        Mosaic kernel cannot be partitioned over a multi-chip mesh: serve
+        one replica per chip); "xla" pins the gather+dense-softmax read
+        path bitwise; "pallas" demands the compiled kernel and raises
+        where it cannot be had (no TPU, params over several devices, or an
+        engine-static unsupported shape); "interpret" runs the same kernel
+        through the Pallas interpreter (CPU-executable, same blockwise
+        math — tests and CI smokes). Under "auto", shapes the kernel
+        cannot express (spec-decode verify rows, alibi/sliding-window
+        biases, paging off) fall back to the gather path per dispatch with
+        a counted reason (``kv_kernel_fallbacks{reason}`` in /metrics and
+        healthz); /healthz shows the resolved path as ``decode_kernel``.
     :param prefix_cache: share prompt-prefix KV blocks across requests
         (exact token-chain keys, refcounted, LRU-evicted when idle);
         requires kv_paging.

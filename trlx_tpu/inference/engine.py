@@ -33,7 +33,6 @@ from any thread (checkpoint hot-reload) and swaps atomically under a
 lock read at each dispatch.
 """
 
-import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -308,21 +307,30 @@ class InferenceEngine:
             # only feed rows whose outputs are already ignored.
             self._pool["adapter"] = jnp.zeros((P,), jnp.int32)
         # Paged decode kernel (ops/paged_attention.py) behind the
-        # inference.decode_kernel knob: "xla" pins today's gather read
-        # path bitwise; "auto" selects the Pallas kernel on a single TPU
-        # chip and the gather path elsewhere; "pallas" requests the
-        # kernel explicitly, degrading to interpret mode off-TPU (the CI
-        # smoke) — the TRLX_TPU_KERNELS env kill switch overrides all of
-        # it (ops.attention.kernel_mode, shared with the flash path).
+        # inference.decode_kernel knob: "xla" pins the gather read path;
+        # "auto" selects the compiled kernel when the engine's params live
+        # on a TPU and the gather path elsewhere; "pallas" demands the
+        # compiled kernel and raises where it cannot be had; "interpret"
+        # runs the same kernel through the Pallas interpreter (CPU tests
+        # and smokes). The TRLX_TPU_KERNELS env switch overrides "auto"
+        # and its "off" overrides everything (_resolve_attn_kernel).
         # Per-dispatch fallbacks to the gather path are counted with a
         # reason (kv_stats -> scheduler -> /metrics + healthz).
-        if decode_kernel not in ("auto", "pallas", "xla"):
+        if decode_kernel not in ("auto", "pallas", "interpret", "xla"):
             raise ValueError(
-                f"decode_kernel {decode_kernel!r} not in ('auto', 'pallas', 'xla')"
+                f"decode_kernel {decode_kernel!r} not in "
+                "('auto', 'pallas', 'interpret', 'xla')"
             )
         self.decode_kernel = decode_kernel
-        self._attn_kernel = self._resolve_attn_kernel()
         self._kernel_unsupported = self._kernel_unsupported_reason()
+        self._attn_kernel = self._resolve_attn_kernel()
+        devices = self._param_devices()
+        logger.info(
+            f"decode_kernel={decode_kernel!r} resolved to {self.decode_path!r} "
+            f"(params on {len(devices)} {devices[0].platform} device(s)"
+            + (f"; kernel unsupported: {self._kernel_unsupported})"
+               if self._attn_kernel and self._kernel_unsupported else ")")
+        )
         self._kv_kernel_dispatches = 0
         self._kv_kernel_fallbacks: Dict[str, int] = {}
         self._prefill_fns: Dict[Tuple[int, int], Callable] = {}
@@ -341,21 +349,49 @@ class InferenceEngine:
         """Map the decode_kernel knob onto the per-dispatch attn_kernel
         value threaded into decode_step_rows: None (gather path),
         "pallas" (compiled Mosaic kernel) or "interpret" (same kernel
-        through the Pallas interpreter — CPU-executable)."""
-        from trlx_tpu.ops.attention import kernel_mode
+        through the Pallas interpreter — CPU-executable). The devices are
+        those the params live on, i.e. where the decode program runs (with
+        no params yet, the default device): the compiled kernel needs
+        exactly one TPU device, so an engine over a trainer's multi-chip
+        mesh takes the gather path and a one-chip replica the kernel."""
+        from trlx_tpu.ops.attention import kernels_env, require_tpu
 
-        env = os.environ.get("TRLX_TPU_KERNELS", "").strip().lower()
-        if self.decode_kernel == "xla" or env in ("off", "xla", "0"):
+        env = kernels_env()
+        if self.decode_kernel == "xla" or env == "off":
             return None
-        mode = kernel_mode()
-        if mode == "pallas":
-            return "pallas"
-        if self.decode_kernel == "pallas" or mode == "interpret":
-            # explicit request off-TPU (or env-forced interpret): run the
-            # kernel through the interpreter rather than silently using
-            # the gather path — same blockwise math, CPU-executable
+        if "interpret" in (self.decode_kernel, env):
             return "interpret"
-        return None  # auto off-TPU: gather path
+        devices = self._param_devices()
+        demanded = ("inference.decode_kernel='pallas'" if self.decode_kernel == "pallas"
+                    else "TRLX_TPU_KERNELS=pallas" if env == "pallas" else None)
+        if demanded:
+            require_tpu(devices, demanded)
+            if len(devices) > 1:
+                # a Mosaic kernel cannot be partitioned automatically, and
+                # the engine has no shard_map wrapper for it
+                raise RuntimeError(
+                    f"{demanded} cannot be honoured: the params span "
+                    f"{len(devices)} devices; serve one replica per chip"
+                )
+        if self.decode_kernel == "pallas" and self._kernel_unsupported is not None:
+            raise ValueError(
+                "inference.decode_kernel='pallas' cannot be honoured: "
+                f"{self._kernel_unsupported} (use 'auto' for a counted "
+                "fallback to the gather path)"
+            )
+        return "pallas" if devices[0].platform == "tpu" and len(devices) == 1 else None
+
+    def _param_devices(self) -> List:
+        leaves = jax.tree_util.tree_leaves(self._params)
+        return list(leaves[0].devices()) if leaves else jax.devices()[:1]
+
+    @property
+    def decode_path(self) -> str:
+        """The read path decode programs are built with: "pallas",
+        "interpret" or "xla" (shown in /healthz)."""
+        if self._attn_kernel is None or self._kernel_unsupported is not None:
+            return "xla"
+        return self._attn_kernel
 
     def _kernel_unsupported_reason(self) -> Optional[str]:
         """Engine-static reason the paged decode kernel cannot serve this
@@ -1248,23 +1284,6 @@ class InferenceEngine:
         the pool. The logprob is the policy's raw-logit log-probability
         of the emitted token (see `_sample_fused`), meaningful only where
         `emitted`."""
-        # kernel dispatch accounting (driver thread; read under _kv_lock
-        # by kv_stats): a decode dispatch either rides the fused kernel
-        # or falls back to the gather path for a counted reason. The
-        # spec path counts BOTH — its t=1 trunk draft steps use the
-        # kernel while the multi-position verify cannot, so every spec
-        # dispatch also logs a "spec_verify_rows" fallback explaining
-        # the non-kernel portion.
-        if self._attn_kernel is not None:
-            if self._kernel_unsupported is not None:
-                r = self._kernel_unsupported
-                self._kv_kernel_fallbacks[r] = self._kv_kernel_fallbacks.get(r, 0) + 1
-            else:
-                self._kv_kernel_dispatches += 1
-                if self.spec_k > 0:
-                    self._kv_kernel_fallbacks["spec_verify_rows"] = (
-                        self._kv_kernel_fallbacks.get("spec_verify_rows", 0) + 1
-                    )
         if self.spec_k > 0:
             params, head = self._current_params_and_head()
             self._pool, token, logprob, valid, finished = self._decode_fn(
@@ -1279,6 +1298,23 @@ class InferenceEngine:
             params = self._current_params()
             self._pool, token, logprob, valid, finished = self._decode_fn(params, self._pool)
         token, logprob, valid, finished = jax.device_get((token, logprob, valid, finished))
+        # kernel dispatch accounting (driver thread; read under _kv_lock
+        # by kv_stats), after the step has run: a decode dispatch either
+        # rode the fused kernel or fell back to the gather path for a
+        # counted reason. The spec path counts BOTH — its t=1 trunk draft
+        # steps use the kernel while the multi-position verify cannot, so
+        # every spec dispatch also logs a "spec_verify_rows" fallback
+        # explaining the non-kernel portion.
+        if self._attn_kernel is not None:
+            if self._kernel_unsupported is not None:
+                r = self._kernel_unsupported
+                self._kv_kernel_fallbacks[r] = self._kv_kernel_fallbacks.get(r, 0) + 1
+            else:
+                self._kv_kernel_dispatches += 1
+                if self.spec_k > 0:
+                    self._kv_kernel_fallbacks["spec_verify_rows"] = (
+                        self._kv_kernel_fallbacks.get("spec_verify_rows", 0) + 1
+                    )
         return (
             np.asarray(token),
             np.asarray(logprob, np.float32),

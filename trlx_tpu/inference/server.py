@@ -966,6 +966,10 @@ class InferenceServer:
                         "param_version": server.engine.param_version,
                         "checkpoint_step": server._effective_checkpoint_step(),
                         "reloads": watcher.reloads,
+                        # the decode read path the engine resolved to:
+                        # "pallas" | "interpret" | "xla"
+                        "decode_kernel": getattr(
+                            server.engine, "decode_path", None),
                         # paged-pool occupancy (empty dict when paging is
                         # off) — supervisors surface these per-replica
                         **({"kv": kv} if kv else {}),

@@ -48,6 +48,7 @@ supervisor must kill/respawn it via probe timeouts.
 """
 
 import json
+import os
 import subprocess
 import sys
 import threading
@@ -160,6 +161,19 @@ class SubprocessReplica(ReplicaHandle):
     @property
     def alive(self) -> bool:
         return self.proc is not None and self.proc.poll() is None
+
+    @property
+    def exit_reason(self) -> str:
+        """Exit code and the end of the log of a process that is gone. A
+        child that cannot get a chip another process holds (its parent
+        included) exits within seconds, and says why only here."""
+        code = None if self.proc is None else self.proc.poll()
+        reason = f"process exited with code {code}"
+        if self.log_path and os.path.exists(self.log_path):
+            with open(self.log_path, "rb") as f:
+                f.seek(max(os.path.getsize(self.log_path) - 400, 0))
+                reason += ": " + f.read().decode(errors="replace").strip()
+        return reason
 
     def kill(self) -> None:
         if self.proc is None or self.proc.poll() is not None:
@@ -482,6 +496,10 @@ class FleetSupervisor:
             seat.state = QUARANTINED
             self.counters["quarantines"] += 1
             self._event("quarantined", seat, deaths_in_window=recent)
+            logger.error(
+                f"fleet-supervisor: seat {seat.index} quarantined after "
+                f"{recent} deaths; last: {reason}"
+            )
             if self.postmortem_dir is not None:
                 from trlx_tpu.observability.postmortem import maybe_dump
                 maybe_dump(
@@ -567,7 +585,8 @@ class FleetSupervisor:
                     seat.handle.kill()
             if seat.state in (STARTING, SERVING):
                 if seat.handle is not None and not seat.handle.alive:
-                    self._mark_dead(seat, "process exited")
+                    self._mark_dead(seat, getattr(
+                        seat.handle, "exit_reason", "process exited"))
                     continue
                 due = (seat.state == STARTING
                        or now - seat.last_probe >= self.probe_interval_s)

@@ -295,24 +295,22 @@ class Attention(nn.Module):
         new_cache = None
         if layer_cache is not None and "table" in layer_cache:
             # Paged KV pool (inference/engine.py, kv_paging): the layer
-            # cache is a global block arena k/v [n_blocks, block_size,
-            # nkv, hd] shared by every slot plus a per-row block table
+            # cache is a global block arena shared by every slot (layout
+            # owned by ops/paged_attention.py) plus a per-row block table
             # [b, n_tbl] mapping logical token columns to physical
             # blocks. This step's K/V scatters to per-row columns
             # [cache_index, cache_index + t); positions with
             # attn_mask == 0 (right-pad, inactive slots) are redirected
             # to block index n_blocks, which the jitted scatter DROPS —
             # they never touch the arena, so stale block tables on freed
-            # rows are harmless. The read side gathers the row's blocks
-            # back into the dense [b, n_tbl*block_size, nkv, hd] layout
-            # and falls through to the same einsum as the fixed pool;
-            # int8 arenas carry per-token-per-head f32 scale planes and
-            # dequantize on the gather.
-            from trlx_tpu.ops import quant
+            # rows are harmless. The read side is either the fused kernel
+            # or a gather of the row's blocks back into the dense
+            # [b, n_tbl*block_size, nkv, hd] layout that falls through to
+            # the same einsum as the fixed pool.
+            from trlx_tpu.ops import paged_attention as paged
 
             table = layer_cache["table"]  # [b, n_tbl] int32
-            arena_k, arena_v = layer_cache["k"], layer_cache["v"]
-            n_blocks, blk_sz = arena_k.shape[0], arena_k.shape[1]
+            n_blocks, blk_sz = layer_cache["k"].shape[0], layer_cache["k"].shape[2]
             n_tbl = table.shape[1]
             idx = cache_index if jnp.ndim(cache_index) == 1 else jnp.full(
                 (b,), cache_index, jnp.int32
@@ -323,29 +321,15 @@ class Attention(nn.Module):
             off = cols % blk_sz
             if attn_mask is not None:
                 phys = jnp.where(attn_mask.astype(bool), phys, n_blocks)
-            if arena_k.dtype == jnp.int8:
-                kq, ks = quant.quantize_kv(k)
-                vq, vs = quant.quantize_kv(v)
-                new_cache = {
-                    "k": arena_k.at[phys, off].set(kq),
-                    "v": arena_v.at[phys, off].set(vq),
-                    "k_scale": layer_cache["k_scale"].at[phys, off].set(ks),
-                    "v_scale": layer_cache["v_scale"].at[phys, off].set(vs),
-                    "table": table,
-                }
-            else:
-                new_cache = {
-                    "k": arena_k.at[phys, off].set(k.astype(arena_k.dtype)),
-                    "v": arena_v.at[phys, off].set(v.astype(arena_v.dtype)),
-                    "table": table,
-                }
+            new_cache = paged.paged_kv_write(layer_cache, k, v, phys, off)
+            new_cache["table"] = table
             if attn_kernel is not None:
-                # Fused Pallas read side (ops/paged_attention.py): one pass
-                # per (slot, kv-head) walks the block table directly — no
-                # gathered dense copy, no materialized dequant, no kv-head
-                # repeat. The engine guarantees the shape is expressible
-                # (t == 1, no alibi/window/prefix bias terms) and counts a
-                # fallback to the gather path otherwise.
+                # Fused Pallas read side: one pass per (slot, table entry)
+                # walks the block table directly — no gathered dense copy,
+                # no materialized dequant, no kv-head repeat. The engine
+                # guarantees the shape is expressible (t == 1, no
+                # alibi/window/prefix bias terms) and counts a fallback to
+                # the gather path otherwise.
                 if t != 1:
                     raise ValueError(
                         "paged decode kernel takes single-position queries; "
@@ -356,13 +340,11 @@ class Attention(nn.Module):
                         "paged decode kernel cannot express alibi/window/"
                         "prefix bias terms (engine should have fallen back)"
                     )
-                from trlx_tpu.ops.paged_attention import paged_attention_decode
-
                 # decode_bias writes exactly 0.0 on attendable columns and
                 # -1e9 elsewhere, so key validity is recoverable from the
                 # bias row without widening the call signature.
                 key_mask = attn_bias[:, 0, 0, :] == 0.0
-                kernel_out = paged_attention_decode(
+                kernel_out = paged.paged_attention_decode(
                     q[:, 0],
                     new_cache["k"],
                     new_cache["v"],
@@ -375,20 +357,7 @@ class Attention(nn.Module):
                 )
                 out = dense(d, "o_proj")(kernel_out.reshape(b, 1, nh * hd))
                 return out, new_cache
-            if arena_k.dtype == jnp.int8:
-                k = quant.dequantize_kv(
-                    new_cache["k"][table].reshape(b, n_tbl * blk_sz, nkv, hd),
-                    new_cache["k_scale"][table].reshape(b, n_tbl * blk_sz, nkv),
-                    cfg.dtype,
-                )
-                v = quant.dequantize_kv(
-                    new_cache["v"][table].reshape(b, n_tbl * blk_sz, nkv, hd),
-                    new_cache["v_scale"][table].reshape(b, n_tbl * blk_sz, nkv),
-                    cfg.dtype,
-                )
-            else:
-                k = new_cache["k"][table].reshape(b, n_tbl * blk_sz, nkv, hd)
-                v = new_cache["v"][table].reshape(b, n_tbl * blk_sz, nkv, hd)
+            k, v = paged.paged_kv_gather(new_cache, table, cfg.dtype)
         elif layer_cache is not None:
             # Write this step's K/V into the cache at cache_index, then attend
             # over the whole (static-length) cache. cache_index is a scalar
@@ -1257,18 +1226,12 @@ def init_paged_kv_arena(
         raise NotImplementedError(
             "paged KV cache under prompt/prefix tuning is unsupported"
         )
-    shape = (num_blocks, block_size, cfg.kv_heads, cfg.head_dim)
-    layers = []
-    for _ in range(cfg.n_layers):
-        layer = {
-            "k": jnp.zeros(shape, dtype=dtype),
-            "v": jnp.zeros(shape, dtype=dtype),
-        }
-        if dtype == jnp.int8:
-            layer["k_scale"] = jnp.zeros(shape[:3], dtype=jnp.float32)
-            layer["v_scale"] = jnp.zeros(shape[:3], dtype=jnp.float32)
-        layers.append(layer)
-    return layers
+    from trlx_tpu.ops.paged_attention import init_paged_layer
+
+    return [
+        init_paged_layer(num_blocks, block_size, cfg.kv_heads, cfg.head_dim, dtype)
+        for _ in range(cfg.n_layers)
+    ]
 
 
 # ---------------------------------------------------------------------------

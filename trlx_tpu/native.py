@@ -1,9 +1,13 @@
 """ctypes bindings for the native host-side data engine (native/
 trlx_native.cpp).
 
-The shared library is compiled on first use (g++, cached beside the
-source); every entry point has a numpy fallback so the package works on
-machines without a toolchain. `TRLX_TPU_NO_NATIVE=1` forces the fallback.
+The shared library is compiled on first use (g++) from the tracked source
+into a per-user cache directory OUTSIDE the source tree, named by the
+source's hash — a library that does not match what git would commit can
+never be loaded, and nothing is written under `native/`. Every entry point
+has a numpy fallback so the package works on machines without a
+toolchain; `backend()` says which of the two is in use.
+`TRLX_TPU_NO_NATIVE=1` forces the fallback.
 
 Reference parity note: the reference's host-side collation runs inside
 torch's native DataLoader/tensor machinery (SURVEY.md §2.6); this module
@@ -11,6 +15,7 @@ is the explicit TPU-native equivalent of that surface.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import List, Optional
@@ -21,25 +26,44 @@ from trlx_tpu.utils import logging
 
 logger = logging.get_logger(__name__)
 
-_NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
-_SRC = os.path.join(_NATIVE_DIR, "trlx_native.cpp")
-_LIB_PATH = os.path.join(_NATIVE_DIR, "libtrlx_native.so")
+_SRC = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    "native", "trlx_native.cpp",
+)
 
 _lib = None
 _load_attempted = False
 
 
-def _build() -> bool:
+def _lib_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    cache = os.environ.get("XDG_CACHE_HOME") or os.path.expanduser("~/.cache")
+    return os.path.join(cache, "trlx_tpu", f"libtrlx_native-{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
+    os.makedirs(os.path.dirname(lib_path), exist_ok=True)
+    tmp = f"{lib_path}.{os.getpid()}.tmp"  # rename in: concurrent builders race safely
     try:
-        cmd = ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", _LIB_PATH]
+        cmd = ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", tmp]
         proc = subprocess.run(cmd, capture_output=True, timeout=120)
         if proc.returncode != 0:
             logger.warning(f"native build failed: {proc.stderr.decode()[:500]}")
             return False
+        os.replace(tmp, lib_path)
         return True
     except (OSError, subprocess.TimeoutExpired) as e:
         logger.warning(f"native build unavailable: {e}")
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+
+
+def backend() -> str:
+    """Which collate path this process runs: "native" or "numpy"."""
+    return "native" if get_lib() is not None else "numpy"
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -52,12 +76,11 @@ def get_lib() -> Optional[ctypes.CDLL]:
         return None
     if not os.path.exists(_SRC):
         return None
-    src_mtime = os.path.getmtime(_SRC)
-    if not os.path.exists(_LIB_PATH) or os.path.getmtime(_LIB_PATH) < src_mtime:
-        if not _build():
-            return None
+    lib_path = _lib_path()
+    if not os.path.exists(lib_path) and not _build(lib_path):
+        return None
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
+        lib = ctypes.CDLL(lib_path)
     except OSError as e:
         logger.warning(f"native library load failed: {e}")
         return None

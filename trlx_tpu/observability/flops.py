@@ -6,9 +6,8 @@ Moved verbatim out of bench.py so a running trainer can compute live MFU
 with EXACTLY the same itemized estimate the offline benchmark prints;
 any model change made here moves both numbers together.
 
-Dependency-free at import time: `chip_peak_flops()` imports jax lazily
-(and tolerates a missing backend by assuming a v5e-class chip), so this
-module can be imported by host-only tooling.
+Dependency-free at import time: `chip_peak_flops()` imports jax lazily,
+so this module can be imported by host-only tooling.
 """
 
 # bf16 peak FLOP/s per chip by device kind (dense; no sparsity).
@@ -22,19 +21,16 @@ PEAK_FLOPS = [
 
 
 def chip_peak_flops() -> float:
-    """Peak dense bf16 FLOP/s of device 0, by device_kind lookup.
-    Unknown devices (including CPU backends) assume v5e-class — the
-    resulting MFU is then a lower bound, never flattering."""
-    try:
-        import jax
+    """Peak dense bf16 FLOP/s of device 0, by device_kind lookup. A device
+    kind with no row (a CPU included) raises LookupError: an MFU priced at
+    some other chip's peak is not a measurement."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # no backend / host-only tooling
-        return 197e12
+    kind = jax.devices()[0].device_kind
     for tag, peak in PEAK_FLOPS:
-        if tag in kind:
+        if tag in kind.lower():
             return peak
-    return 197e12  # unknown TPU: assume v5e-class
+    raise LookupError(f"no peak-FLOP/s row for device kind {kind!r}")
 
 
 def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,

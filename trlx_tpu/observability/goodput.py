@@ -84,8 +84,12 @@ class GoodputLedger:
             except Exception:
                 n_chips = 1
         self.n_chips = max(int(n_chips), 1)
-        self.peak_flops = float(peak_flops if peak_flops is not None
-                                else chip_peak_flops())
+        if peak_flops is None:
+            try:
+                peak_flops = chip_peak_flops()
+            except LookupError:
+                pass  # a device with no peak row reports no MFU
+        self.peak_flops = None if peak_flops is None else float(peak_flops)
 
     # ------------------------------------------------------------------
     # Span intake (called by PhaseTimeline.add, outside its lock)
@@ -279,8 +283,14 @@ class GoodputLedger:
             else:  # tracing on but no phase seen yet / no compile split
                 steady_wall = wall
                 st_fl, st_tok, st_smp = total_fl, total_tok, total_smp
-            mfu = st_fl / steady_wall / self.n_chips / self.peak_flops
-            mfu_overall = total_fl / wall / self.n_chips / self.peak_flops
+            mfu_keys = {}
+            if self.peak_flops is not None:
+                mfu_keys = {
+                    "mfu": round(
+                        st_fl / steady_wall / self.n_chips / self.peak_flops, 6),
+                    "mfu_overall": round(
+                        total_fl / wall / self.n_chips / self.peak_flops, 6),
+                }
             return {
                 "wall_s": wall,
                 "seconds": {k: round(v, 6) for k, v in sorted(causes.items())},
@@ -289,8 +299,7 @@ class GoodputLedger:
                                       + causes.get("rollout_score", 0.0), 6),
                 "wasted_s": round(wasted, 6),
                 "goodput_fraction": round(1.0 - wasted / wall, 6),
-                "mfu": round(mfu, 6),
-                "mfu_overall": round(mfu_overall, 6),
+                **mfu_keys,
                 "tokens_per_sec_per_chip": round(
                     st_tok / steady_wall / self.n_chips, 3),
                 "samples_per_sec_per_chip": round(
@@ -310,8 +319,8 @@ class GoodputLedger:
         alongside the timeline's `timing/*`."""
         snap = self.snapshot()
         out: Dict[str, float] = {
-            "goodput/mfu": snap["mfu"],
-            "goodput/mfu_overall": snap["mfu_overall"],
+            **{f"goodput/{k}": snap[k]
+               for k in ("mfu", "mfu_overall") if k in snap},
             "goodput/tokens_per_sec_per_chip":
                 snap["tokens_per_sec_per_chip"],
             "goodput/samples_per_sec_per_chip":
@@ -342,6 +351,8 @@ class GoodputLedger:
             ("wasted_s", "wasted_seconds"),
             ("goodput_fraction", "fraction"),
         ):
+            if key not in snap:  # no MFU without a peak row for the device
+                continue
             lines.append(f"# HELP {ns}_{prom} goodput ledger {key}")
             lines.append(f"# TYPE {ns}_{prom} gauge")
             lines.append(f"{ns}_{prom} {snap[key]}")

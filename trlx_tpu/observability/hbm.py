@@ -102,11 +102,12 @@ def grads_bytes(n_trainable: int, dtype_bytes: int = 4) -> int:
 
 def kv_arena_bytes(n_layers: int, kv_heads: int, head_dim: int,
                    n_blocks: int, block_size: int, dtype="float32") -> int:
-    """Paged KV arena: per-layer K and V blocks of
-    `n_blocks x block_size x kv_heads x head_dim`, plus per-(block,
-    position, head) f32 scale planes when the cache quantizes to int8.
-    THE formula `engine.kv_stats` reports — the engine delegates here, so
-    the offline budget and the live counter can never drift."""
+    """Paged KV arena: per-layer K and V of `n_blocks x kv_heads x
+    block_size x head_dim` elements (layout: ops/paged_attention.py), plus
+    per-(block, head, position) f32 scale planes when the cache quantizes
+    to int8. Logical bytes: the TPU pads a head_dim under 128 up to a full
+    lane tile. THE formula `engine.kv_stats` reports — the engine delegates
+    here, so the offline budget and the live counter can never drift."""
     import numpy as np
 
     itemsize = _itemsize(dtype)

@@ -29,11 +29,15 @@ the kernel instead of an O(t^2) bias tensor.
 
 import functools
 import os
-from typing import Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from trlx_tpu.utils import logging
+
+logger = logging.get_logger(__name__)
 
 NEG_INF = -1e30
 
@@ -308,43 +312,102 @@ def _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k, interpret=False):
     return out.reshape(b, nh, tq, hd).transpose(0, 2, 1, 3)
 
 
-def kernel_mode() -> str:
-    """Single source of truth for Pallas kernel selection, shared by the
-    flash (prefill/train) dispatch below and the paged-attention decode
-    kernel (`ops/paged_attention.py` via `inference.decode_kernel`):
+KERNELS_ENV = "TRLX_TPU_KERNELS"
 
-    * ``"pallas"``    — compile the Mosaic TPU kernel. Only ever returned
-      when the backend really is a single TPU chip (the pallas_call
-      carries no GSPMD partitioning rule, so under a multi-device jit XLA
-      would replicate its operands instead of splitting the batch;
-      multi-chip goes through the shard_map wrappers or blockwise XLA,
-      and ring attention owns the sequence-sharded case).
-    * ``"interpret"`` — run the SAME kernel through the Pallas
-      interpreter (CPU-executable, same blockwise math). Never selected
-      by default: it exists for parity tests and the CI smoke.
-    * ``"off"``       — use the plain XLA paths.
+# The mesh the current trainer's programs run over, registered by
+# MeshRuntime.from_config (standard and pipe meshes alike). None means no
+# trainer has built a mesh: computations then run on the default device.
+_ACTIVE_MESH = None
 
-    The ``TRLX_TPU_KERNELS`` env var overrides: ``off``/``xla``/``0``
-    force the XLA paths, ``interpret`` forces the interpreter, and
-    ``pallas``/``1``/``force`` requests the compiled kernel — degraded to
-    ``interpret`` off-TPU, so a ``JAX_PLATFORMS=cpu`` run (tier-1 CI) can
-    never select a compiled TPU kernel no matter what the env says."""
-    env = os.environ.get("TRLX_TPU_KERNELS", "").strip().lower()
+#: kernel name -> path ("pallas" | "sharded" | "interpret" | "xla") -> the
+#: operand shapes its dispatch emitted that path for into traced programs.
+#: A path's first use is logged; `chip_smoke.py` reads the record to report
+#: which path the programs it ran were actually built with.
+KERNEL_PATHS: Dict[str, Dict[str, List[Tuple[int, ...]]]] = {}
+
+
+def set_active_pallas_mesh(mesh) -> None:
+    global _ACTIVE_MESH
+    _ACTIVE_MESH = mesh
+
+
+def note_kernel_path(kernel: str, path: str, shape) -> None:
+    shapes = KERNEL_PATHS.setdefault(kernel, {}).setdefault(path, [])
+    if not shapes:
+        logger.info(f"kernel {kernel}: {path} path (first at shape {tuple(shape)})")
+    if tuple(shape) not in shapes:
+        shapes.append(tuple(shape))
+
+
+def kernels_env() -> str:
+    """The `TRLX_TPU_KERNELS` request, normalized: "" (unset: choose from
+    the device), "off", "interpret" or "pallas"."""
+    env = os.environ.get(KERNELS_ENV, "").strip().lower()
     if env in ("off", "xla", "0"):
         return "off"
-    if env == "interpret":
-        return "interpret"
-    try:
-        on_single_tpu = jax.default_backend() == "tpu" and jax.device_count() == 1
-    except Exception:
-        on_single_tpu = False
     if env in ("pallas", "1", "force"):
-        return "pallas" if on_single_tpu else "interpret"
-    return "pallas" if on_single_tpu else "off"
+        return "pallas"
+    if env in ("", "interpret"):
+        return env
+    raise ValueError(
+        f"{KERNELS_ENV}={env!r} is not one of off|xla|0, interpret, pallas|1|force"
+    )
 
 
-def _use_pallas() -> bool:
-    return kernel_mode() == "pallas"
+def require_tpu(devices, what: str) -> None:
+    """A request for a compiled Mosaic kernel can only be met on a TPU."""
+    platform = devices[0].platform
+    if platform != "tpu":
+        raise RuntimeError(
+            f"{what} asks for the compiled Pallas kernel, but the computation "
+            f"runs on platform {platform!r}; ask for 'interpret' to run the "
+            "kernel through the Pallas interpreter"
+        )
+
+
+def kernel_mode() -> str:
+    """How the flash and fused-CE dispatch below lower their kernels,
+    decided from the devices the computation runs on — the registered
+    mesh's, or the default device when no trainer registered one:
+
+    * ``"pallas"``    — one TPU device: the Mosaic kernel, called directly.
+    * ``"sharded"``   — a standard (data, fsdp, tensor, sequence=1) mesh of
+      several TPU devices: the same kernel under `pallas_shard_map` (a
+      bare pallas_call inside a multi-device jit does not compile: "Mosaic
+      kernels cannot be automatically partitioned").
+    * ``"interpret"`` — the same kernels through the Pallas interpreter.
+      Only ever the literal request `TRLX_TPU_KERNELS=interpret`.
+    * ``"off"``       — the plain XLA paths: any non-TPU platform, pipe
+      and sequence-sharded meshes (their programs are already manual over
+      other axes; ring attention owns the sequence-sharded case), or
+      `TRLX_TPU_KERNELS=off`.
+
+    `TRLX_TPU_KERNELS=pallas` demands a compiled kernel and raises where
+    the rule above would give ``"off"``."""
+    env = kernels_env()
+    if env in ("off", "interpret"):
+        return env
+    mesh = _ACTIVE_MESH
+    devices = list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
+    if env == "pallas":
+        require_tpu(devices, f"{KERNELS_ENV}=pallas")
+    if devices[0].platform != "tpu":
+        return "off"
+    if len(devices) == 1:
+        return "pallas"
+    sizes = dict(mesh.shape)
+    if set(sizes) == {"data", "fsdp", "tensor", "sequence"} and sizes["sequence"] == 1:
+        return "sharded"
+    if env == "pallas":
+        raise RuntimeError(
+            f"{KERNELS_ENV}=pallas: no shard_map wrapper for mesh {sizes}"
+        )
+    return "off"
+
+
+def active_pallas_mesh():
+    """The registered mesh, when kernels run shard_map-wrapped over it."""
+    return _ACTIVE_MESH if kernel_mode() == "sharded" else None
 
 
 # ---------------------------------------------------------------------------
@@ -750,68 +813,30 @@ def _flash_bwd_xla(q, k, v, mask, out, lse, g, causal, block_k):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-# Standard ("data","fsdp","tensor","sequence") mesh registered by
-# MeshRuntime.from_config so kernel dispatch can shard_map the Pallas
-# calls under multi-chip GSPMD layouts. Pipe meshes are never registered
-# (their programs are already manual over data/pipe; nesting would clash).
-_ACTIVE_MESH = None
-
-
-def set_active_pallas_mesh(mesh) -> None:
-    global _ACTIVE_MESH
-    _ACTIVE_MESH = mesh
-
-
-def active_pallas_mesh():
-    """The registered mesh, if Pallas-via-shard_map is applicable: TPU
-    backend, standard 4-axis mesh, sequence axis unsharded."""
-    mesh = _ACTIVE_MESH
-    if mesh is None:
-        return None
-    try:
-        if jax.default_backend() != "tpu":
-            return None
-    except Exception:
-        return None
-    sizes = dict(mesh.shape)
-    if set(sizes) != {"data", "fsdp", "tensor", "sequence"} or sizes["sequence"] != 1:
-        return None
-    return mesh
-
-
 def pallas_shard_map(fn, mesh, in_specs, out_specs):
     """shard_map for shard-local Pallas kernels: disables the varying-axes
-    check (pallas_call outputs carry no vma metadata), handling the kwarg
-    rename across jax versions (check_vma, formerly check_rep). Shared by
+    check (pallas_call outputs carry no vma metadata). Shared by
     flash_attention_sharded and fused_ce.fused_logprobs_sharded."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                          check_vma=False)
-    except TypeError:  # pre-rename API
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                         check_rep=False)
 
 
 def flash_attention_sharded(mesh, q, k, v, mask, causal=True, block_q=None,
                             block_k=None, interpret=False):
     """The Pallas forward under a multi-chip mesh: batch shards over
     (data, fsdp) and heads over tensor, each shard running the kernel on
-    its local block — the multi-chip lift of the single-chip-only gate
-    (round-1 _use_pallas). Full-manual shard_map (every axis named), so
-    no partial-auto lowering is involved. Caller guarantees divisibility
+    its local block. Full-manual shard_map (every axis named), so no
+    partial-auto lowering is involved. Caller guarantees divisibility
     (`_sharded_flash_ok`).
 
-    VALIDATION STATUS: correctness is pinned by interpret-mode parity
-    tests on the CPU mesh (tests/test_pallas_sharded.py) and the kernel
-    itself runs on-chip in the single-chip bench, but this wrapper has
-    never EXECUTED on real multi-chip TPU hardware (the build environment
-    exposes one chip). First multi-chip deployment should confirm the
-    bench parity gate passes there; the blockwise XLA path is the
-    semantically-identical fallback if it doesn't."""
+    Validation: parity is pinned in interpret mode on the CPU mesh
+    (tests/test_pallas_sharded.py), the wrapper AOT-compiles for a v5e 2x2
+    on (data, fsdp, tensor) = (4,1,1), (2,1,2), (1,2,2)
+    (tests/test_kernels_compile_tpu.py), and it ran on four v5e chips in
+    PR 21's `chip_smoke.py` under data=4 and fsdp=2 x tensor=2 (score at
+    [128, 104, 12, 64], train at [32, 104, 12, 64]; 32 finite losses in
+    each layout). Its output on the chip has not been compared element by
+    element with the one-chip kernel's."""
     from jax.sharding import PartitionSpec as P
 
     qkv_spec = P(("data", "fsdp"), None, "tensor", None)
@@ -842,26 +867,32 @@ def _sharded_flash_ok(mesh, q, k) -> bool:
 def _flash_attention(q, k, v, mask, causal, block_q, block_k):
     mode = kernel_mode()
     if mode in ("pallas", "interpret"):
+        note_kernel_path("flash_fwd", mode, q.shape)
         return _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k,
                                  interpret=(mode == "interpret"))
-    mesh = active_pallas_mesh()
-    if mesh is not None and _sharded_flash_ok(mesh, q, k):
-        return flash_attention_sharded(mesh, q, k, v, mask, causal, block_q, block_k)
+    if mode == "sharded" and _sharded_flash_ok(_ACTIVE_MESH, q, k):
+        note_kernel_path("flash_fwd", "sharded", q.shape)
+        return flash_attention_sharded(_ACTIVE_MESH, q, k, v, mask, causal,
+                                       block_q, block_k)
+    note_kernel_path("flash_fwd", "xla", q.shape)
     return blockwise_attention(q, k, v, mask, causal, block_k)
 
 
 def _flash_fwd_rule(q, k, v, mask, causal, block_q, block_k):
     mode = kernel_mode()
     if mode in ("pallas", "interpret"):
+        note_kernel_path("flash_fwd", mode, q.shape)
         out, lse = _flash_fwd_pallas_lse(q, k, v, mask, causal, block_q, block_k,
                                          interpret=(mode == "interpret"))
         return out, (q, k, v, mask, out, lse)
-    mesh = active_pallas_mesh()
-    if mesh is not None and _sharded_flash_ok(mesh, q, k):
+    if mode == "sharded" and _sharded_flash_ok(_ACTIVE_MESH, q, k):
         # sharded fwd keeps the legacy recompute backward (lse would need
         # the shard_map plumbing); memory note in docs/parallelism.md
-        out = flash_attention_sharded(mesh, q, k, v, mask, causal, block_q, block_k)
+        note_kernel_path("flash_fwd", "sharded", q.shape)
+        out = flash_attention_sharded(_ACTIVE_MESH, q, k, v, mask, causal,
+                                      block_q, block_k)
         return out, (q, k, v, mask, None, None)
+    note_kernel_path("flash_fwd", "xla", q.shape)
     out, lse = blockwise_attention_lse(q, k, v, mask, causal, block_k)
     return out, (q, k, v, mask, out, lse)
 
@@ -871,6 +902,7 @@ def _flash_bwd_rule(causal, block_q, block_k, res, g):
     if lse is None:
         # legacy recompute path (sharded fwd): vjp through the blockwise
         # scan — O(t^2 / block_k) residual memory, fine at short context
+        note_kernel_path("flash_bwd", "xla", q.shape)
         _, vjp = jax.vjp(
             lambda q_, k_, v_: blockwise_attention(q_, k_, v_, mask, causal, block_k),
             q, k, v,
@@ -882,10 +914,12 @@ def _flash_bwd_rule(causal, block_q, block_k, res, g):
     # chip; the same algorithm as plain XLA scans elsewhere)
     mode = kernel_mode()
     if mode in ("pallas", "interpret"):
+        note_kernel_path("flash_bwd", mode, q.shape)
         dq, dk, dv = _flash_bwd_pallas(q, k, v, mask, out, lse, g,
                                        causal, block_q, block_k,
                                        interpret=(mode == "interpret"))
     else:
+        note_kernel_path("flash_bwd", "xla", q.shape)
         dq, dk, dv = _flash_bwd_xla(q, k, v, mask, out, lse, g, causal, block_k)
     return dq, dk, dv, None
 

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from trlx_tpu.ops.attention import _use_pallas
+from trlx_tpu.ops import attention
 
 NEG_INF = -1e30
 
@@ -179,13 +179,15 @@ def _fused_logprobs_2d(logits, labels):
 
 
 def _fused_fwd_dispatch(logits, labels):
-    if _use_pallas():
+    mode = attention.kernel_mode()
+    if mode == "pallas":
+        attention.note_kernel_path("fused_ce", "pallas", logits.shape)
         return _logprobs_pallas(logits, labels)
-    from trlx_tpu.ops.attention import active_pallas_mesh
-
-    mesh = active_pallas_mesh()
-    if mesh is not None and _sharded_ce_ok(mesh, logits.shape[0], logits.shape[1]):
+    mesh = attention.active_pallas_mesh() if mode == "sharded" else None
+    if mesh is not None and _sharded_ce_ok(mesh, *logits.shape):
+        attention.note_kernel_path("fused_ce", "sharded", logits.shape)
         return fused_logprobs_sharded(mesh, logits, labels)
+    attention.note_kernel_path("fused_ce", "xla", logits.shape)
     return _logprobs_xla(logits, labels)
 
 

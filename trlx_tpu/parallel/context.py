@@ -12,10 +12,7 @@ from typing import Optional
 
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from trlx_tpu.ops.ring_attention import ring_attention
@@ -47,16 +44,10 @@ def partial_shard_map(fn, mesh: Mesh, in_specs, out_specs, manual,
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     if all(sizes[a] == 1 for a in mesh.axis_names if a not in manual):
         return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        smapped = shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=manual,
-        )
-    except TypeError:  # older jax: auto= complement instead of axis_names=
-        smapped = shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            auto=frozenset(set(mesh.axis_names) - manual),
-        )
+    smapped = shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        axis_names=manual,
+    )
 
     def guarded(*args):
         import os

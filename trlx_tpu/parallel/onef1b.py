@@ -74,18 +74,10 @@ def _vary(x):
     types). Correctness of the whole engine depends on this NOT being a
     no-op — see the CRITICAL note in make_1f1b_grad_fn: an invariant
     input to jax.vjp gets its cotangent implicitly psummed over the
-    manual axes, which would corrupt gradients. So unlike pipeline.py's
-    forward-only `_varying` (where skipping is benign), a jax without
-    pcast/VMA refuses loudly instead of silently training wrong."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        raise NotImplementedError(
-            "the 1F1B engine requires jax.lax.pcast (VMA-typed shard_map); "
-            "this jax version lacks it — use pipeline_schedule='gpipe'"
-        )
+    manual axes, which would corrupt gradients."""
     have = getattr(getattr(x, "aval", None), "vma", None) or frozenset()
     missing = tuple(ax for ax in GRAD_AXES if ax not in have)
-    return pcast(x, missing, to="varying") if missing else x
+    return jax.lax.pcast(x, missing, to="varying") if missing else x
 
 
 def masked_sums(x, m):

@@ -33,10 +33,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from trlx_tpu.models.transformer import (
@@ -51,11 +47,8 @@ PIPE_AXIS = "pipe"
 
 def _varying(x, axis_name: str):
     """Mark a replicated value as device-varying over `axis_name` so it can
-    seed a shard_map scan carry whose outputs vary (jax>=0.8 VMA types)."""
-    try:
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-    except (AttributeError, TypeError):  # older jax: no VMA tracking
-        return x
+    seed a shard_map scan carry whose outputs vary (VMA types)."""
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def make_pipe_mesh(

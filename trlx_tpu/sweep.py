@@ -404,6 +404,12 @@ def run_sweep(
                     continue
                 out.close()
                 del running[slot]
+                if code != 0:
+                    # e.g. the trial could not get a chip this or another
+                    # process holds: it exits within seconds and says so
+                    with open(os.path.join(trial_dir, "stdout.log"), errors="replace") as f:
+                        tail = f.read()[-400:].strip()
+                    logger.error(f"[trial {i + 1}/{n_trials}] exited with code {code}: {tail}")
                 score = read_metric(trial_dir, metric, mode)
                 searcher.observe(hparams, sign * score)
                 results.append({
@@ -426,6 +432,10 @@ def run_sweep(
                     proc.wait()  # reap: no zombies from a long-lived caller
             out.close()
     results.sort(key=lambda r: r["trial"])
+    if results and all(r["returncode"] != 0 for r in results):
+        raise RuntimeError(
+            f"all {len(results)} trials failed; see trial_*/stdout.log under {sweep_dir}"
+        )
 
     reverse = mode == "max"
     ranked = sorted(results, key=lambda r: r[metric], reverse=reverse)

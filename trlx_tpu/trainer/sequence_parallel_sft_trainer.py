@@ -32,10 +32,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from trlx_tpu.data.configs import TRLConfig

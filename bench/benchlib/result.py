@@ -2,6 +2,7 @@
 
 import json
 import math
+import sys
 
 
 def percentile(values, q: float) -> float:
@@ -20,6 +21,7 @@ class Checks:
 
     def __init__(self):
         self.ok = True
+        self.lines = []
 
     def at_most(self, what: str, value: float, limit: float):
         good = bool(value <= limit) and math.isfinite(value)
@@ -33,8 +35,14 @@ class Checks:
 
     def _row(self, what, value, limit, good):
         self.ok = self.ok and good
-        print(f"[bench] check {what}: {value} (limit {limit}) "
-              f"{'ok' if good else 'NOT CORRECT'}", flush=True)
+        self.lines.append(f"[bench] check {what}: {value} (limit {limit}) "
+                          f"{'ok' if good else 'NOT CORRECT'}")
+        print(self.lines[-1], flush=True)
+
+    def to_stderr(self):
+        """Every row again, as the last lines of standard error: of a run that
+        is not correct the driver's record keeps the end of that."""
+        print("\n".join(self.lines), file=sys.stderr, flush=True)
 
 
 def print_result(correct, attempted, failed, metrics, units, device, breakdown=None):

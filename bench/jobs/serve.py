@@ -3,13 +3,18 @@ process that holds the chip, fed by this thread.
 
 Requests enter through `Scheduler.submit` with a stream sink. The traffic
 file decides the loop: `backlog` keeps the scheduler's queue at a fixed depth
-for the whole window (a trainer collecting rollouts through the engine);
-`poisson` is an open loop at a fixed rate, each request timed from when it
-was due. A ramp at the same load comes first and counts as set-up. Weights
-come from the seed in the type the configuration serves them in.
+(a trainer collecting rollouts through the engine) and times whole passes
+over the request pool, from one decode step's end to another's, as the `ppo`
+job times whole cycles: every seed's window then holds the same requests;
+`poisson` is an open loop at a fixed rate over `--seconds` of wall clock,
+each request timed from when it was due. A ramp at the same load comes first
+and counts as set-up. Weights come from the seed in the type the
+configuration serves them in.
 """
 
+import bisect
 import collections
+import threading
 import time
 
 import numpy as np
@@ -88,12 +93,16 @@ def check_kv_precision(ctx, engine, cfg, kv_held, checks: Checks):
                    f"precision's ({want}), relative difference", abs(kv_held - want) / want, limit)
 
 
+def round_up(n: int, bucket: int) -> int:
+    return -(-int(n) // bucket) * bucket
+
+
 def warm_up(engine, mix, rng):
     """Every prefill program the traffic can reach, (rows bucket) x (prompt
     width bucket), and the decode step, through the engine's own doors."""
     bucket = engine.prompt_bucket
-    lo = -(-int(mix["prompt_len"]["min"]) // bucket) * bucket
-    hi = -(-int(mix["prompt_len"]["max"]) // bucket) * bucket
+    lo = round_up(mix["prompt_len"]["min"], bucket)
+    hi = round_up(mix["prompt_len"]["max"], bucket)
     rows, n = [], 1
     while n <= engine.max_prefill_batch:
         rows.append(n)
@@ -135,6 +144,97 @@ class StepLog:
             return out
 
         engine.step = step
+
+
+class InsertLog:
+    """Wraps `scheduler._insert_batch` from outside: when every call began
+    and ended, its requests and the width its prompts were padded to; in a
+    traced run also a span. A counter and two clock reads a call."""
+
+    def __init__(self, scheduler, bucket: int, traced: bool):
+        self.rows = []  # (t_begin, t_end, requests, width)
+        self.admitted_at = {}
+        inner = scheduler._insert_batch
+
+        def insert_batch(batch, slots):
+            t0 = time.monotonic()
+            for req in batch:
+                self.admitted_at[req.id] = t0
+            try:
+                if traced:
+                    with tracing.span("scheduler.insert_batch"):
+                        return inner(batch, slots)
+                return inner(batch, slots)
+            finally:
+                self.rows.append((t0, time.monotonic(), len(batch),
+                                  round_up(max(len(r.prompt_ids) for r in batch), bucket)))
+
+        scheduler._insert_batch = insert_batch
+
+
+def backlog_window(step_ends, admissions, ramp_end, pool, seconds):
+    """The two edges of a backlog run's window, from the logs of one thread.
+
+    `step_ends`: when each decode step ended, ascending; `admissions`: (when
+    the `_insert_batch` call began, its requests), ascending. The window opens
+    at the end of the first step that ends at or after `ramp_end`, and closes
+    at the end of the first step after the admission that brought the count
+    since the opening to k x pool, k the smallest whole number that makes it
+    at least `seconds` long. Returns None while the logs do not reach that
+    far, else (index of the step whose end opens it, index of the step whose
+    end closes it, k, admissions since the opening)."""
+    first = bisect.bisect_left(step_ends, ramp_end)
+    if first >= len(step_ends):
+        return None
+    admitted, k = 0, 1
+    for began, n in admissions:
+        if began < step_ends[first]:
+            continue
+        admitted += n
+        if admitted < k * pool:
+            continue
+        last = bisect.bisect_right(step_ends, began)  # the step that decodes what was admitted
+        if last >= len(step_ends):
+            return None
+        if step_ends[last] - step_ends[first] >= seconds:
+            return first, last, k, admitted
+        k = admitted // pool + 1
+    return None
+
+
+LATE_S = 0.02  # a step this much over the median of its kind is counted as late
+
+
+def admission_series(step_rows, insert_rows):
+    """What admissions cost a backlog window, from its two logs. `step_rows`
+    are the `StepLog` rows from the step whose end opens the window to the one
+    whose end closes it, `insert_rows` the `InsertLog` rows inside it. The
+    prefill program is dispatched and not waited for, so its device time
+    shows in the step after it and not in `_insert_batch`. Hence, taking a
+    step with what led up to it, from the previous step's end to its own:
+    `engine.decode_step_s`, the `engine.step` seconds of the steps with no
+    admission before them (the decode program alone), and
+    `sched.admission_s`, for each step with one, how much longer it took end
+    to end than the median step without (the host part of `_insert_batch` and
+    the prefill the step waited behind). Also the steps that ended more than
+    `LATE_S` after the median of their kind, and by how much in all: the host
+    was late to return from a step the device had finished (PERF.md section 5)."""
+    began = [r[0] for r in insert_rows]
+    plain, plain_whole, behind = [], [], []
+    for prev, row in zip(step_rows, step_rows[1:]):
+        if bisect.bisect_left(began, prev[0]) == bisect.bisect_left(began, row[0]):
+            plain.append(row[1])
+            plain_whole.append(row[0] - prev[0])
+        else:
+            behind.append(row[0] - prev[0])
+
+    def over_median(xs):
+        return [x - float(np.median(xs)) for x in xs]
+
+    late = [x for x in over_median(plain_whole) + over_median(behind) if x > LATE_S]
+    return {"engine.decode_step_s": plain,
+            "sched.admission_s": [x - float(np.median(plain_whole)) for x in behind]
+            if plain_whole else []}, late
 
 
 def compare_outputs(ctx, cfg, params, done, engine, int8_reference=False):
@@ -183,13 +283,20 @@ def run(ctx):
     checks = Checks()
     mix = merge(ctx.traffic, ctx.traffic.get("rehearse") if ctx.rehearse else None)
     rng = np.random.default_rng(ctx.seed)
+
+    def since_start():  # the parts of set-up, so that a run says which of them moved
+        return f"{time.monotonic() - ctx.t_start:.2f} s after the process began"
+
+    ctx.log(f"set-up: imports done, the device up, {since_start()}")
     engine, scheduler, cfg, params, kv_held = build_engine(ctx, mix)
     check_kv_precision(ctx, engine, cfg, kv_held, checks)
     ctx.log(f"engine: decode path {engine.decode_path!r}, {engine.total_blocks} blocks, "
             f"kv {engine.kv_stats().get('kv_pool_bytes', 0) / 1e9:.2f} GB")
+    ctx.log(f"set-up: weights made, engine built, {since_start()}")
     n_programs = warm_up(engine, mix, rng)
     ctx.log(f"warmed {n_programs} prefill programs and the decode step; "
             f"{len(ctx.compiles.events)} backend compiles, {ctx.compiles.seconds():.1f} s")
+    ctx.log(f"set-up: programs warmed, {since_start()}")
 
     # the pool of requests: the same multiset of sizes for every seed
     n_pool = int(mix["pool"])
@@ -199,18 +306,8 @@ def run(ctx):
 
     live = collections.deque()
     steps = StepLog(engine, live, traced=bool(ctx.trace))
-    admitted_at = {}
+    inserts = InsertLog(scheduler, engine.prompt_bucket, traced=bool(ctx.trace))
     if ctx.trace:
-        inner_insert = scheduler._insert_batch
-
-        def insert_batch(batch, slots):
-            now = time.monotonic()
-            for req in batch:
-                admitted_at[req.id] = now
-            with tracing.span("scheduler.insert_batch"):
-                return inner_insert(batch, slots)
-
-        scheduler._insert_batch = insert_batch
         tracing.wrap(scheduler, "_admit", "scheduler.admit")
 
     from trlx_tpu.inference.scheduler import QueueFullError
@@ -231,6 +328,7 @@ def run(ctx):
     arrivals = mix["arrivals"]
     ramp = float(mix["ramp_seconds"])
     scheduler.start()
+    ctx.log(f"set-up: the ramp of {ramp} s begins {since_start()}")
     window = tracing.TracedWindow()
     trace_at, trace_for = float(ctx.cell["trace"]["start_s"]), float(ctx.cell["trace"]["seconds"])
     if ctx.rehearse:
@@ -240,29 +338,42 @@ def run(ctx):
         start = time.monotonic()
         t0 = start + ramp
         t1 = t0 + ctx.seconds
-        tracing_now = False
+        tracing_now, stopping = False, None
 
         def profiler_tick(now):
-            nonlocal tracing_now
-            if ctx.trace and not tracing_now and window.trace is None and now >= t0 + trace_at:
+            # the profiler is stopped, and its trace read, on a thread of its
+            # own: that takes seconds, and the thread that feeds may not stall
+            nonlocal tracing_now, stopping
+            if ctx.trace and not tracing_now and stopping is None and now >= t0 + trace_at:
                 window.start()
                 tracing_now = True
             elif tracing_now and now >= t0 + trace_at + trace_for:
-                window.stop()
+                stopping = threading.Thread(target=window.stop)
+                stopping.start()
                 tracing_now = False
 
         if arrivals["kind"] == "backlog":
             # the first request of each slot is cut to a different length, so
             # that the slots leave lockstep during the ramp and the window sees
-            # the steady mixture of a long-running actor, prefills spread out
+            # the steady mixture of a long-running actor, prefills spread out.
+            # The backlog is topped up until whole passes over the pool have
+            # been admitted for `--seconds`; the logs are looked at only once
+            # that long has passed, a few times a second, and the edges read
+            # from them afterwards
             i, n_slots = 0, engine.num_slots
-            while (now := time.monotonic()) < t1:
+            edges, look_at, give_up = None, t1, t1 + 2 * ctx.seconds + 30
+            while edges is None and (now := time.monotonic()) < give_up:
                 profiler_tick(now)
                 for _ in range(int(arrivals["depth"] - scheduler.metrics.get("queue_depth"))):
                     cap = int(o_lens[i % n_pool])
                     submit(i, max_new=max(cap * (i + 1) // n_slots, 1) if i < n_slots else None)
                     i += 1
                 time.sleep(0.002)
+                if now >= look_at:
+                    look_at = now + 0.05
+                    edges = backlog_window([r[0] for r in steps.rows],
+                                           [(r[0], r[2]) for r in inserts.rows],
+                                           t0, n_pool, ctx.seconds)
         else:
             due = start + traffic.arrival_times(arrivals, ramp + ctx.seconds, rng)
             for i, d in enumerate(due):
@@ -281,10 +392,30 @@ def run(ctx):
                     req.wait(max(deadline - time.monotonic(), 0.0))
         if tracing_now:
             window.stop()
-        setup_s = t0 - ctx.t_start
+        if stopping is not None:
+            stopping.join()
         t_end = time.monotonic()
     finally:
         scheduler.stop()
+
+    series, constants = {}, {"num_slots": engine.num_slots}
+    if arrivals["kind"] == "backlog":
+        if edges is None:
+            raise SystemExit(f"[bench] FAIL: no whole pass over the pool of {n_pool} was admitted "
+                             f"and decoded for {ctx.seconds} s before the run gave up")
+        first, last, k, admitted = edges
+        # the window: from the end of step `first` to the end of step `last`
+        t0, t1 = steps.rows[first][0], steps.rows[last][0]
+        in_win = steps.rows[first + 1:last + 1]
+        checks.equal(f"requests admitted inside the window against {k} passes over the pool",
+                     admitted, k * n_pool)
+        series, late_steps = admission_series(steps.rows[first:last + 1],
+                                              [r for r in inserts.rows if t0 <= r[0] < t1])
+        if series["sched.admission_s"]:
+            constants["window_s_per_insert"] = (t1 - t0) / len(series["sched.admission_s"])
+    else:
+        in_win = [r for r in steps.rows if t0 <= r[0] < t1]
+    setup_s = t0 - ctx.t_start
 
     in_window = ctx.compiles.between(t0, t_end)
     checks.equal("backend compiles inside the window", len(in_window), 0)
@@ -295,11 +426,19 @@ def run(ctx):
     if arrivals["kind"] == "backlog":
         # judged on tokens: every request that finished inside the window
         counted = [(r, d) for r, d in requests
-                   if r is not None and r.finish_time is not None and t0 <= r.finish_time < t1]
-        tokens = sum(n for t_end, _, n, _ in steps.rows if t0 <= t_end < t1)
-        end_to_end["serve_tokens_per_s"] = tokens / ctx.seconds
-        ctx.log(f"{tokens} tokens from {sum(1 for r in steps.rows if t0 <= r[0] < t1)} steps "
-                f"inside the window; {len(counted)} requests finished in it")
+                   if r is not None and r.finish_time is not None and t0 < r.finish_time <= t1]
+        tokens = sum(r[2] for r in in_win)
+        end_to_end["serve_tokens_per_s"] = tokens / (t1 - t0)
+        by_shape = collections.Counter((r[2], r[3]) for r in inserts.rows if t0 <= r[0] < t1)
+        ctx.log(f"window {t1 - t0:.3f} s, {k} passes: {tokens} tokens from {len(in_win)} steps "
+                f"({sum(1 for r in in_win if r[2] != engine.num_slots)} of them emitted other than "
+                f"{engine.num_slots}); {len(counted)} requests finished in it; "
+                f"{sum(by_shape.values())} `_insert_batch` calls by (rows, width) "
+                f"{dict(sorted(by_shape.items()))}")
+        ctx.log(f"steps behind an admission took {sum(series['sched.admission_s']):.3f} s more "
+                f"than as many of the {len(series['engine.decode_step_s'])} steps behind none; "
+                f"{len(late_steps)} steps ended over {LATE_S * 1e3:.0f} ms later than the median "
+                f"of their kind, {sum(late_steps):.3f} s in all")
     else:
         counted = [(r, d) for r, d in requests if d >= t0]
         ok = [(r, d) for r, d in counted if r is not None and r.ok and r.first_token_time]
@@ -326,9 +465,8 @@ def run(ctx):
     kv = engine.kv_stats()
     checks.equal("paged-kernel fallbacks", sum(kv["kv_kernel_fallbacks"].values()), 0)
 
-    in_win = [r for r in steps.rows if t0 <= r[0] < t1]
-    waits = [(admitted_at[r.id] - r.enqueue_time) * 1e3 for r, _ in counted
-             if r is not None and r.id in admitted_at]
+    waits = [(inserts.admitted_at[r.id] - r.enqueue_time) * 1e3 for r, _ in counted
+             if r is not None and r.id in inserts.admitted_at]
     tw = (window.t0, window.t1) if window.trace is not None else None
     return {
         "checks": checks, "attempted": attempted, "failed": failed,
@@ -337,8 +475,8 @@ def run(ctx):
             "trace": window.trace,
             "series": {"engine.step_s": [r[1] for r in in_win],
                        "engine.step_tokens": [r[2] for r in in_win],
-                       "sched.queue_wait_ms": waits},
-            "constants": {"num_slots": engine.num_slots},
+                       "sched.queue_wait_ms": waits, **series},
+            "constants": constants,
             # the paged kernel's calls inside the traced part: one per layer
             # per step (the decode program's only Pallas kernel), over the
             # tokens then resident

@@ -95,6 +95,7 @@ def main():
             else:
                 metrics[name] = value
     log(f"set-up {out['end_to_end']['setup_s']:.1f} s; compiles: {compiles.summary()}")
+    out["checks"].to_stderr()
     if args.rehearse_cpu:
         log(f"REHEARSAL on the CPU finished (checks ok: {out['checks'].ok}): not a result; "
             f"metric names that a chip run would report: {sorted(metrics)}")

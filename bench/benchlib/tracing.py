@@ -2,12 +2,13 @@
 
 `span(name)` is a `jax.profiler.TraceAnnotation` named `bench:<name>`; it
 costs next to nothing while no trace is being taken. `wrap(obj, attr, name)`
-puts such a span round a bound method from the outside, so that the program
-carries no name of the benchmark's."""
+puts such a span round a bound method from the outside (`--trace 1`; under
+`--trace 2` the program's own `trlx:` spans take their place). The profiler
+is started and stopped through the program's one control,
+`trlx_tpu.observability.tracing`."""
 
 import functools
 import shutil
-import tempfile
 import time
 
 from benchlib.files import load_module
@@ -32,37 +33,44 @@ def wrap(obj, attr: str, name: str):
     setattr(obj, attr, spanned)
 
 
+def _control():
+    """The program's one control of the profiler (imported late: this module
+    is loaded before the program is)."""
+    from trlx_tpu.observability import tracing
+
+    return tracing
+
+
 class TracedWindow:
     """start() ... stop() round the traced part of a run; `trace` is then the
     neutral structure bench/trace/reduce.py works on. The Python tracer is
-    off (it hooks every call); TraceMe spans and the device are on."""
+    off (it hooks every call); TraceMe spans and the device are on. The
+    `bench:window` span is what the reduction takes as the traced window: it
+    opens with start(), or later with open() where the run wants the
+    profiler's start-up left out of it."""
 
     def __init__(self):
-        self.dir = None
         self.trace = None
         self.t0 = self.t1 = None  # time.monotonic() just inside the window span
+        self._window = None
 
-    def start(self):
-        import jax
+    def start(self, open_window: bool = True):
+        _control().start()  # into a temporary directory of its own
+        if open_window:
+            self.open()
 
-        self.dir = tempfile.mkdtemp(prefix="bench_trace_")
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 2
-        jax.profiler.start_trace(self.dir, profiler_options=opts)
+    def open(self):
         self._window = span("window")
         self._window.__enter__()
         self.t0 = time.monotonic()
 
     def stop(self):
-        import jax
-
         self.t1 = time.monotonic()
         self._window.__exit__(None, None, None)
-        jax.profiler.stop_trace()
+        trace_dir = _control().stop()
         reduce = load_module("trace/reduce.py")
         try:
-            self.trace = reduce.load_xplane(reduce.find_xplane(self.dir))
+            self.trace = reduce.load_xplane(reduce.find_xplane(trace_dir))
         finally:
-            shutil.rmtree(self.dir, ignore_errors=True)
+            shutil.rmtree(trace_dir, ignore_errors=True)
         return self.trace

@@ -1,5 +1,8 @@
 """The `ppo` job: one PPOTrainer, whole collection-and-training cycles timed
-until the window has passed.
+until the window has passed. `--trace 1` profiles the first timed cycle,
+with spans put round the trainer's methods from outside; `--trace 2` times
+exactly as `--trace 0` does and then profiles one more whole cycle, in which
+the program's own `trlx:` spans say what the host did.
 
 A cycle is the classic path of `learn()` (after bench.py `run_cycle`): clear
 the store, `make_experience(num_rollouts)`, then `ppo_epochs` passes of
@@ -199,7 +202,7 @@ def run(ctx):
             f"{len(ctx.compiles.events)} backend compiles so far, "
             f"{ctx.compiles.seconds():.1f} s")
 
-    if ctx.trace:
+    if ctx.trace == 1:
         for attr, name in (("generate", "generate_dispatch"), ("_score_fn", "score_dispatch"),
                            ("reward_fn", "reward_fn"), ("decode", "host_decode"),
                            ("train_minibatch", "train_minibatch_dispatch")):
@@ -209,7 +212,7 @@ def run(ctx):
     setup_s = t0 - ctx.t_start
     losses, cycle_s = [], []
     while True:
-        traced = ctx.trace and not cycle_s  # the traced run profiles its first cycle
+        traced = ctx.trace == 1 and not cycle_s  # `--trace 1` profiles its first cycle
         if traced:
             window.start()
         c0 = time.monotonic()
@@ -221,6 +224,18 @@ def run(ctx):
             break
     t1 = time.monotonic()
     wall = sum(cycle_s)  # whole cycles only; reading the trace back is outside them
+    if ctx.trace == 2:
+        # measured first, traced afterwards: one more whole cycle under the
+        # profiler (what starting it costs lies inside `start()`, before the
+        # cycle). Its losses count for the finiteness check, not for the rate
+        window.start()
+        c0 = time.monotonic()
+        losses += run_cycle(trainer, config)
+        traced_s = time.monotonic() - c0
+        window.stop()
+        timed_s = float(np.median(cycle_s))
+        ctx.log(f"the traced cycle took {traced_s:.3f} s, the timed cycles' median "
+                f"{timed_s:.3f} s: tracing on cost {100 * (traced_s / timed_s - 1):.2f}%")
 
     in_window = ctx.compiles.between(t0, t1)
     checks.equal("backend compiles inside the window", len(in_window), 0)

@@ -9,7 +9,12 @@ job times whole cycles: every seed's window then holds the same requests;
 `poisson` is an open loop at a fixed rate over `--seconds` of wall clock,
 each request timed from when it was due. A ramp at the same load comes first
 and counts as set-up. Weights come from the seed in the type the
-configuration serves them in.
+configuration serves them in. `--trace 1` profiles some seconds inside the
+window, with spans put on from outside; `--trace 2` measures exactly as
+`--trace 0` does and, once the window has closed, keeps the same load on and
+profiles some seconds of it, in which the program's own `trlx:` spans say
+what the host did (a backlog only: the open loop, which no cell uses yet,
+refuses `--trace 2`).
 """
 
 import bisect
@@ -119,23 +124,26 @@ def warm_up(engine, mix, rng):
 
 class StepLog:
     """Wraps `engine.step` from outside: wall seconds and emitted tokens of
-    every step, with the time it ended; in a traced run also a span and the
-    tokens resident in the cache for the rows that decoded."""
+    every step, with the time it ended; with `spans` (`--trace 1`) also a
+    span; while `resident` is set (with the trace: `paged_decode_roofline`
+    prices the traced steps) also the tokens resident in the cache for the
+    rows that decoded."""
 
-    def __init__(self, engine, live, traced: bool):
+    def __init__(self, engine, live, spans: bool):
         self.rows = []  # (t_end, seconds, tokens emitted, resident tokens or -1)
+        self.resident = spans
         inner = engine.step
 
         def step():
             t0 = time.monotonic()
-            if traced:
+            if spans:
                 with tracing.span("engine.step"):
                     out = inner()
             else:
                 out = inner()
             t1 = time.monotonic()
             resident = -1
-            if traced:
+            if self.resident:
                 while live and live[0].finish_reason is not None:
                     live.popleft()
                 resident = sum(len(r.prompt_ids) + len(r.token_ids) for r in list(live)
@@ -148,10 +156,10 @@ class StepLog:
 
 class InsertLog:
     """Wraps `scheduler._insert_batch` from outside: when every call began
-    and ended, its requests and the width its prompts were padded to; in a
-    traced run also a span. A counter and two clock reads a call."""
+    and ended, its requests and the width its prompts were padded to; with
+    `spans` (`--trace 1`) also a span. A counter and two clock reads a call."""
 
-    def __init__(self, scheduler, bucket: int, traced: bool):
+    def __init__(self, scheduler, bucket: int, spans: bool):
         self.rows = []  # (t_begin, t_end, requests, width)
         self.admitted_at = {}
         inner = scheduler._insert_batch
@@ -161,7 +169,7 @@ class InsertLog:
             for req in batch:
                 self.admitted_at[req.id] = t0
             try:
-                if traced:
+                if spans:
                     with tracing.span("scheduler.insert_batch"):
                         return inner(batch, slots)
                 return inner(batch, slots)
@@ -283,6 +291,11 @@ def run(ctx):
     checks = Checks()
     mix = merge(ctx.traffic, ctx.traffic.get("rehearse") if ctx.rehearse else None)
     rng = np.random.default_rng(ctx.seed)
+    if ctx.trace == 2 and mix["arrivals"]["kind"] != "backlog":
+        # no cell offers an open loop yet, so tracing after one has no chip
+        # run behind it: the cell that brings the traffic brings that too
+        raise SystemExit(f"[bench] FAIL: --trace 2 traces a backlog after its window; "
+                         f"{mix['arrivals']['kind']!r} arrivals are measured with --trace 0 or 1")
 
     def since_start():  # the parts of set-up, so that a run says which of them moved
         return f"{time.monotonic() - ctx.t_start:.2f} s after the process began"
@@ -305,9 +318,9 @@ def run(ctx):
     prompts = traffic.token_ids(p_lens, {"low": 0, "high": cfg.vocab_size}, rng)
 
     live = collections.deque()
-    steps = StepLog(engine, live, traced=bool(ctx.trace))
-    inserts = InsertLog(scheduler, engine.prompt_bucket, traced=bool(ctx.trace))
-    if ctx.trace:
+    steps = StepLog(engine, live, spans=ctx.trace == 1)
+    inserts = InsertLog(scheduler, engine.prompt_bucket, spans=ctx.trace == 1)
+    if ctx.trace == 1:
         tracing.wrap(scheduler, "_admit", "scheduler.admit")
 
     from trlx_tpu.inference.scheduler import QueueFullError
@@ -344,13 +357,41 @@ def run(ctx):
             # the profiler is stopped, and its trace read, on a thread of its
             # own: that takes seconds, and the thread that feeds may not stall
             nonlocal tracing_now, stopping
-            if ctx.trace and not tracing_now and stopping is None and now >= t0 + trace_at:
+            if ctx.trace == 1 and not tracing_now and stopping is None and now >= t0 + trace_at:
                 window.start()
                 tracing_now = True
             elif tracing_now and now >= t0 + trace_at + trace_for:
                 stopping = threading.Thread(target=window.stop)
                 stopping.start()
                 tracing_now = False
+
+        def trace_after(keep_load_on):
+            """`--trace 2`, once the measured window has closed and its numbers
+            lie in the logs: with the same load kept on (`keep_load_on()`
+            offers what is due and sleeps a moment), start the profiler; open
+            the traced window at the end of the first step to end after that
+            (starting the profiler stalls the host: that step is no sample);
+            trace the cell's `trace.seconds`; stop on a thread of its own,
+            because stopping and reading the trace takes seconds and the thread
+            that feeds may not stall meanwhile."""
+            def load_on_until(done):
+                give_up = time.monotonic() + 120
+                while not done():
+                    if time.monotonic() > give_up:
+                        raise SystemExit("[bench] FAIL: the traced part of the run got stuck")
+                    keep_load_on()
+
+            steps.resident = True
+            window.start(open_window=False)
+            n_steps = len(steps.rows)
+            load_on_until(lambda: len(steps.rows) > n_steps)
+            window.open()
+            until = time.monotonic() + trace_for
+            load_on_until(lambda: time.monotonic() >= until)
+            stopper = threading.Thread(target=window.stop)
+            stopper.start()
+            load_on_until(lambda: not stopper.is_alive())
+            stopper.join()
 
         if arrivals["kind"] == "backlog":
             # the first request of each slot is cut to a different length, so
@@ -361,19 +402,26 @@ def run(ctx):
             # that long has passed, a few times a second, and the edges read
             # from them afterwards
             i, n_slots = 0, engine.num_slots
-            edges, look_at, give_up = None, t1, t1 + 2 * ctx.seconds + 30
-            while edges is None and (now := time.monotonic()) < give_up:
-                profiler_tick(now)
+
+            def top_up():
+                nonlocal i
                 for _ in range(int(arrivals["depth"] - scheduler.metrics.get("queue_depth"))):
                     cap = int(o_lens[i % n_pool])
                     submit(i, max_new=max(cap * (i + 1) // n_slots, 1) if i < n_slots else None)
                     i += 1
                 time.sleep(0.002)
+
+            edges, look_at, give_up = None, t1, t1 + 2 * ctx.seconds + 30
+            while edges is None and (now := time.monotonic()) < give_up:
+                profiler_tick(now)
+                top_up()
                 if now >= look_at:
                     look_at = now + 0.05
                     edges = backlog_window([r[0] for r in steps.rows],
                                            [(r[0], r[2]) for r in inserts.rows],
                                            t0, n_pool, ctx.seconds)
+            if ctx.trace == 2 and edges is not None:
+                trace_after(top_up)
         else:
             due = start + traffic.arrival_times(arrivals, ramp + ctx.seconds, rng)
             for i, d in enumerate(due):
@@ -468,6 +516,13 @@ def run(ctx):
     waits = [(inserts.admitted_at[r.id] - r.enqueue_time) * 1e3 for r, _ in counted
              if r is not None and r.id in inserts.admitted_at]
     tw = (window.t0, window.t1) if window.trace is not None else None
+    if tw and "serve_tokens_per_s" in end_to_end:
+        # what tracing costs while it is on: whole steps inside the traced window
+        traced = [r for r in steps.rows if tw[0] <= r[0] <= tw[1]]
+        if len(traced) > 1:
+            rate = sum(r[2] for r in traced[1:]) / (traced[-1][0] - traced[0][0])
+            ctx.log(f"with tracing on, {len(traced) - 1} steps emitted {rate:.2f} tokens/s, "
+                    f"{100 * (rate / end_to_end['serve_tokens_per_s'] - 1):+.2f}% against the window's")
     return {
         "checks": checks, "attempted": attempted, "failed": failed,
         "end_to_end": end_to_end,

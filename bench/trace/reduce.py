@@ -16,17 +16,20 @@ On a TPU the device planes are named `/device:TPU:<n>`; their line
 such event, named after its custom call), `XLA Modules` one event per
 executed program (`jit_<function>(<fingerprint>)`). Host threads are lines of
 the plane `/host:CPU`; `jax.profiler.TraceAnnotation` spans appear there
-under the name they were given. The benchmark's own spans start `bench:`.
+under the name they were given. The benchmark's own spans start `bench:`,
+the program's (trlx_tpu/observability/tracing.py `span`) `trlx:`.
 """
 
 import glob
 import os
+import re
 
 DEVICE_PREFIX = "/device:TPU:"
 HOST_PLANE = "/host:CPU"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "bench:"
+SPAN_PREFIXES = (SPAN_PREFIX, "trlx:")  # the benchmark's spans, the program's
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -36,7 +39,7 @@ def find_xplane(trace_dir: str) -> str:
     return paths[-1]
 
 
-def load_xplane(path: str, keep_host=lambda name: name.startswith(SPAN_PREFIX)) -> dict:
+def load_xplane(path: str, keep_host=lambda name: name.startswith(SPAN_PREFIXES)) -> dict:
     """Device planes whole; of the host plane only the events `keep_host`
     accepts (a host plane holds every Python call)."""
     from jax.profiler import ProfileData
@@ -66,13 +69,14 @@ def device_planes(trace: dict):
 
 
 def host_spans(trace: dict):
-    """[(name, start_ns, end_ns)] of the benchmark's spans on any host thread."""
+    """[(name, start_ns, end_ns)] of the benchmark's and the program's spans
+    on any host thread."""
     spans = []
     for plane in trace["planes"]:
         if plane["name"] != HOST_PLANE:
             continue
         for line in plane["lines"]:
-            spans += [(n, s, s + d) for n, s, d in line["events"] if n.startswith(SPAN_PREFIX)]
+            spans += [(n, s, s + d) for n, s, d in line["events"] if n.startswith(SPAN_PREFIXES)]
     return sorted(spans, key=lambda x: x[1])
 
 
@@ -136,15 +140,22 @@ def seconds_by_name(trace: dict, line_name: str, window=None) -> dict:
     return total
 
 
+# a Pallas kernel is called once a layer, each call an instruction of its own
+# (`%paged_decode.24`, `%paged_decode.25`, ...): one row a kernel, under the
+# `name=` of its `pallas_call`
+KERNEL_CALL = re.compile(r"^(%[A-Za-z_][\w-]*?)\.\d+ =(?= custom-call \[tpu_custom_call\])")
+
+
 def leaf_seconds_by_name(trace: dict, window=None) -> dict:
     """Seconds by shortened operation name on the first device plane's
-    `XLA Ops`, containers left out, so that the parts add up to busy time."""
+    `XLA Ops`, containers left out, so that the parts add up to busy time;
+    the calls of one Pallas kernel are one row."""
     lo, hi = window or window_of(trace)
     total = {}
     for n, s, d in leaves(_line(device_planes(trace)[0], OPS_LINE)):
         part = min(s + d, hi) - max(s, lo)
         if part > 0:
-            key = short_name(n)
+            key = KERNEL_CALL.sub(r"\1.* =", short_name(n))
             total[key] = total.get(key, 0.0) + part / 1e9
     return total
 
@@ -175,8 +186,9 @@ def module_at(trace: dict):
 
 
 def idle_gaps_by_span(trace: dict, window=None, min_gap_ns: int = 20_000) -> dict:
-    """Idle seconds of the first device plane by the innermost benchmark
-    span the host was in at the middle of each gap (`(no span)` if none)."""
+    """Idle seconds of the first device plane by the innermost span, of the
+    benchmark's or the program's, that the host was in at the middle of each
+    gap (`(no span)` if none)."""
     b = busy(trace, window)
     lo, hi = b["window"]
     ivs = b["intervals"][0]
@@ -197,8 +209,6 @@ def short_name(name: str, limit: int = 96) -> str:
     """An `XLA Ops` event is named by its whole HLO instruction; keep the
     result's name and the operation: `%fusion.12 = fusion`, and for a custom
     call its target or kernel name where the text gives one."""
-    import re
-
     head, _, rest = name.partition(" = ")
     if not rest:
         return name[:limit]

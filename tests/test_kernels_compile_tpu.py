@@ -45,6 +45,52 @@ def mosaic_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def kernel_names(compiled) -> list:
+    """The names of the Mosaic custom calls of a compiled program: the
+    `name=` of each `pl.pallas_call`, which is what the profiler's trace
+    calls the kernel's events (`%flash_fwd.1 = ... custom-call(...)`)."""
+    return [m.group(1) for m in re.finditer(
+        r'%([A-Za-z_][\w-]*?)(?:\.\d+)? = [^\n]*custom_call_target="tpu_custom_call"',
+        compiled.as_text())]
+
+
+def _flash_args(shape):
+    b, t, nh, hd = shape
+    qkv = S(shape, BF16)
+    return qkv, S((b, t), I32), S((b, nh, t), F32)
+
+
+def _named_kernels():
+    qkv, mask, lse = _flash_args((2, 256, 4, 64))
+    nkv, blk, hd = 2, 16, 64
+    arena = S((9, nkv, blk, hd), BF16)
+    return {
+        "flash_fwd": (lambda q, k, v, m: attention._flash_fwd_pallas(q, k, v, m, True, None, None),
+                      (qkv, qkv, qkv, mask), ["flash_fwd"]),
+        "flash_fwd_lse": (
+            lambda q, k, v, m: attention._flash_fwd_pallas_lse(q, k, v, m, True, None, None),
+            (qkv, qkv, qkv, mask), ["flash_fwd_lse"]),
+        "flash_bwd": (lambda q, k, v, m, o, l, g: attention._flash_bwd_pallas(
+            q, k, v, m, o, l, g, True, None, None),
+            (qkv, qkv, qkv, mask, qkv, lse, qkv), ["flash_bwd_dq", "flash_bwd_dkv"]),
+        "fused_ce_fwd": (fused_ce._logprobs_pallas, (S((256, 50257), BF16), S((256,), I32)),
+                         ["fused_ce_fwd"]),
+        "paged_decode": (paged_attention_decode,
+                         (S((4, 4, hd), BF16), arena, arena, S((4, 3), I32), S((4, 3 * blk), I32)),
+                         ["paged_decode"]),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_fwd_lse", "flash_bwd", "fused_ce_fwd",
+                                    "paged_decode"])
+def test_every_kernel_carries_its_name(v5e, kernel):
+    """A trace tells the kernels apart by these names (bench/metrics, PERF.md
+    section 3): the flash forward from the fused CE, dq from dk/dv."""
+    fn, args, names = _named_kernels()[kernel]
+    compiled = compile_for(fn, args, SingleDeviceSharding(v5e[0]))
+    assert sorted(kernel_names(compiled)) == sorted(names)
+
+
 # bench parity shape, and one PPO minibatch (32 rows of 64 + 40 tokens)
 FLASH_SHAPES = [(4, 1024, 12, 64), (32, 104, 12, 64)]
 

@@ -1,0 +1,333 @@
+"""The one tracing control (trlx_tpu/observability/tracing.py `start` / `stop`
+/ `active` / `span`) and the span sites of the trainer, the loader, the
+scheduler and the engine.
+
+A profiler session started and stopped in the running process writes an
+xplane file whose host plane holds the program's `trlx:` spans; on the CPU
+there is no device plane, the host plane is the same. Checked here: the
+names, that children lie inside their parents on their thread, the
+attributes that join spans, that a site that raises still closes, that a
+session changes no output, and that every timed site feeds each of its
+sinks once.
+"""
+
+import glob
+import os
+import threading
+
+import numpy as np
+import pytest
+
+from trlx_tpu.data.default_configs import default_ppo_config
+from trlx_tpu.inference import InferenceEngine, Scheduler
+from trlx_tpu.observability import tracing
+from trlx_tpu.ops.sampling import GenerationConfig
+from trlx_tpu.pipeline import MiniBatchIterator
+from trlx_tpu.pipeline.offline_pipeline import PromptPipeline
+from trlx_tpu.trainer.ppo_trainer import PPOTrainer
+
+MAX_NEW = 4
+PROMPTS = ["hello world", "jax tpu", "ppo", "trace"] * 2
+N_ROLLOUTS, CHUNK = 8, 4
+
+
+def _config(tmp_path, **train):
+    return default_ppo_config().evolve(
+        model=dict(model_path="random:gpt2-tiny", num_layers_unfrozen=1,
+                   model_extra_configs={"dtype": "float32"}),
+        tokenizer=dict(tokenizer_path="byte"),
+        train=dict(seq_length=32, batch_size=4, total_steps=4, tracker=None,
+                   checkpoint_dir=str(tmp_path), seed=11, **train),
+        method=dict(num_rollouts=N_ROLLOUTS, chunk_size=CHUNK, ppo_epochs=1,
+                    gen_kwargs=dict(max_new_tokens=MAX_NEW, do_sample=True)),
+    )
+
+
+def _trainer(tmp_path, reward_fn=None, **train):
+    reward_fn = reward_fn or (lambda samples, **kw: [float(len(s)) for s in samples])
+    trainer = PPOTrainer(_config(tmp_path, **train), reward_fn=reward_fn)
+    trainer.add_prompt_pipeline(
+        PromptPipeline(PROMPTS, max_prompt_length=8, tokenizer=trainer.tokenizer))
+    return trainer
+
+
+def run_cycle(trainer):
+    """make_experience, then one pass of train_minibatch over the store;
+    returns every optimizer step's loss."""
+    trainer.store.clear_history()
+    trainer.make_experience(N_ROLLOUTS, trainer.iter_count)
+    losses = []
+    loader = trainer.create_train_dataloader()
+    for minibatch in MiniBatchIterator(loader, trainer.mb_size, trainer.num_mb):
+        losses.append(float(trainer.train_minibatch(minibatch)["losses"]["total_loss"]))
+        trainer.iter_count += 1
+    return losses
+
+
+@pytest.fixture(scope="module")
+def trainer(tmp_path_factory):
+    trainer = _trainer(tmp_path_factory.mktemp("ctl"))
+    run_cycle(trainer)  # compile outside every session
+    return trainer
+
+
+def read_spans(log_dir):
+    """{thread line: [(name, start_ns, end_ns, attrs)]} of the `trlx:` spans
+    on the host plane of the newest xplane file under `log_dir`."""
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert paths, f"no .xplane.pb under {log_dir}"
+    by_line = {}
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for i, line in enumerate(plane.lines):
+            spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns, dict(ev.stats))
+                     for ev in line.events if ev.name.startswith(tracing.SPAN_PREFIX)]
+            if spans:
+                by_line[(line.name, i)] = spans
+    return by_line
+
+
+def named(spans, name):
+    return [s for s in spans if s[0] == tracing.SPAN_PREFIX + name]
+
+
+def assert_inside(spans, child, parent):
+    """Every `child` span lies inside some `parent` span of the same thread."""
+    kids, parents = named(spans, child), named(spans, parent)
+    assert kids and parents, (child, parent, sorted({s[0] for s in spans}))
+    for _, s, e, _ in kids:
+        assert any(ps <= s and e <= pe for _, ps, pe, _ in parents), (child, parent)
+
+
+def test_ppo_cycle_spans_share_the_profilers_trace(trainer, tmp_path):
+    assert not tracing.active()
+    assert tracing.start(str(tmp_path)) == str(tmp_path)
+    assert tracing.active()
+    step = trainer.iter_count
+    run_cycle(trainer)
+    assert tracing.stop() == str(tmp_path)
+    assert not tracing.active()
+
+    lines = read_spans(str(tmp_path))
+    spans = next(v for v in lines.values() if named(v, "ppo.make_experience"))
+    n_chunks = N_ROLLOUTS // CHUNK
+    for name, count in (("ppo.make_experience", 1), ("ppo.generate_dispatch", n_chunks),
+                        ("ppo.rollout_fetch", n_chunks), ("ppo.rollout_process", n_chunks),
+                        ("ppo.host_process", n_chunks), ("ppo.host_decode", n_chunks),
+                        ("ppo.reward", n_chunks), ("ppo.score_dispatch", n_chunks),
+                        ("ppo.train_minibatch", N_ROLLOUTS // 4)):
+        got = named(spans, name)
+        assert len(got) == count, (name, len(got))
+        assert all(e >= s for _, s, e, _ in got)  # every span closed
+    for child, parent in (("ppo.generate_dispatch", "ppo.make_experience"),
+                          ("ppo.rollout_fetch", "ppo.make_experience"),
+                          ("ppo.rollout_process", "ppo.make_experience"),
+                          ("ppo.host_process", "ppo.rollout_process"),
+                          ("ppo.host_decode", "ppo.host_process"),
+                          ("ppo.reward", "ppo.host_process"),
+                          ("ppo.score_dispatch", "ppo.rollout_process")):
+        assert_inside(spans, child, parent)
+    # the attributes that join spans
+    assert named(spans, "ppo.make_experience")[0][3]["step"] == step
+    assert [s[3]["chunk"] for s in named(spans, "ppo.generate_dispatch")] == list(range(n_chunks))
+    assert [s[3]["chunk"] for s in named(spans, "ppo.rollout_fetch")] == list(range(n_chunks))
+    assert all(s[3]["rows"] == CHUNK for s in named(spans, "ppo.generate_dispatch"))
+    assert [s[3]["step"] for s in named(spans, "ppo.train_minibatch")] == [step, step + 1]
+    # the loaders' collate: the prompt loader's inside make_experience, one
+    # a chunk, the store's loader's after it, one a minibatch
+    (_, lo, hi, _), = named(spans, "ppo.make_experience")
+    collates = named(spans, "pipeline.collate")
+    assert [s[3]["rows"] for s in collates if lo <= s[1] and s[2] <= hi] == [CHUNK] * n_chunks
+    assert [s[3]["rows"] for s in collates if s[1] >= hi] == [4] * (N_ROLLOUTS // 4)
+    # dispatch runs ahead: chunk 1 is dispatched before chunk 0 is fetched
+    assert named(spans, "ppo.generate_dispatch")[1][1] < named(spans, "ppo.rollout_fetch")[0][1]
+
+
+@pytest.fixture(scope="module")
+def paged_engine(trainer):
+    gen_cfg = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False,
+                               eos_token_id=10_000, pad_token_id=trainer.tokenizer.pad_token_id)
+    policy = {"lm": trainer.params["lm"]}
+    from trlx_tpu.models import CausalLMPolicy
+
+    return InferenceEngine(
+        CausalLMPolicy(trainer.model_cfg), trainer.model_cfg, policy, gen_cfg,
+        num_slots=2, max_prompt_len=16, kv_paging=True, kv_block_size=8)
+
+
+def test_scheduler_and_engine_spans(paged_engine, tmp_path):
+    scheduler = Scheduler(paged_engine, max_wait_s=0.0)
+    scheduler.start()
+    try:
+        # compile the prefill and decode programs outside the session
+        assert scheduler.submit(np.arange(1, 6, dtype=np.int32)).wait(120)
+        step0 = paged_engine._step_n
+        tracing.start(str(tmp_path))
+        reqs = [scheduler.submit(np.arange(1, 4 + i, dtype=np.int32)) for i in range(3)]
+        assert all(r.wait(120) for r in reqs)
+        tracing.stop()
+    finally:
+        scheduler.stop()
+    assert all(r.ok and len(r.token_ids) == MAX_NEW for r in reqs)
+
+    lines = read_spans(str(tmp_path))
+    spans = next(v for v in lines.values() if named(v, "engine.step"))
+    for child, parent in (("engine.dispatch", "engine.step"), ("engine.fetch", "engine.step"),
+                          ("engine.step", "sched.decode_once"), ("sched.emit", "sched.decode_once"),
+                          ("sched.insert_batch", "sched.admit"),
+                          ("engine.insert", "sched.insert_batch")):
+        assert_inside(spans, child, parent)
+    steps = named(spans, "engine.step")
+    assert len(steps) == len(named(spans, "engine.dispatch")) == len(named(spans, "engine.fetch"))
+    # the engine's step counter joins a span to a step: consecutive, from
+    # where the engine stood when the session began
+    ns = [s[3]["step_n"] for s in steps]
+    assert ns == list(range(ns[0], ns[0] + len(ns))) and ns[0] > step0
+    inserts = named(spans, "sched.insert_batch")
+    assert sum(s[3]["rows"] for s in inserts) == len(reqs)
+    assert all(s[3]["width"] >= 3 for s in inserts)
+    assert all(s[3]["rows"] >= 1 and s[3]["width"] % paged_engine.prompt_bucket == 0
+               for s in named(spans, "engine.insert"))
+    assert all(1 <= s[3]["rows"] <= 2 for s in named(spans, "sched.decode_once"))
+    # the counter beside the spans: calls of `_insert_batch` (their rows are
+    # the spans' `rows`)
+    assert scheduler.metrics.get("prefill_batches_total") >= len(inserts)
+
+
+def test_second_start_raises_and_stop_needs_a_session(tmp_path):
+    with pytest.raises(RuntimeError, match="no tracing session"):
+        tracing.stop()
+    tracing.start(str(tmp_path / "a"))
+    try:
+        with pytest.raises(RuntimeError, match="already active"):
+            tracing.start(str(tmp_path / "b"))
+        assert tracing.active()  # the refused start left the session as it was
+    finally:
+        assert tracing.stop() == str(tmp_path / "a")
+    assert not os.path.exists(tmp_path / "b")
+
+
+def test_control_is_safe_from_any_thread(tmp_path):
+    """Started on one thread and stopped on another, with a span on a third;
+    of two racing starts exactly one wins."""
+    errors, won = [], []
+
+    def start(i):
+        try:
+            won.append(tracing.start(str(tmp_path / f"s{i}")))
+        except RuntimeError as e:
+            errors.append(e)
+
+    threads = [threading.Thread(target=start, args=(i,)) for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30)
+    assert len(won) == 1 and len(errors) == 3
+
+    def work():
+        with tracing.span("test.thread", step=7):
+            pass
+
+    worker = threading.Thread(target=work)
+    worker.start()
+    worker.join(30)
+    stopped = []
+    stopper = threading.Thread(target=lambda: stopped.append(tracing.stop()))
+    stopper.start()
+    stopper.join(30)
+    assert stopped == won and not tracing.active()
+    spans = [s for v in read_spans(won[0]).values() for s in v]
+    assert named(spans, "test.thread")[0][3]["step"] == 7
+
+
+def test_a_site_that_raises_still_closes(tmp_path):
+    def bad_reward(samples, **kw):
+        raise ValueError("reward model down")
+
+    trainer = _trainer(tmp_path / "ckpt", reward_fn=bad_reward, tracing=True)
+    tracing.start(str(tmp_path / "trace"))
+    try:
+        with pytest.raises(ValueError, match="reward model down"):
+            trainer.make_experience(N_ROLLOUTS, 0)
+    finally:
+        tracing.stop()
+    spans = [s for v in read_spans(str(tmp_path / "trace")).values() for s in v]
+    for name in ("ppo.reward", "ppo.host_process", "ppo.rollout_process", "ppo.make_experience"):
+        assert len(named(spans, name)) == 1, name
+    assert_inside(spans, "ppo.reward", "ppo.make_experience")
+    # and the timeline got the phases of the sites that were open
+    phases = [s["name"] for s in trainer._timeline.spans]
+    assert phases == ["rollout_generate", "host_reward", "rollout_score", "rollout_process",
+                      "make_experience"]
+
+
+def test_a_session_changes_no_output(tmp_path):
+    """Two trainers from one seed, one cycle each, one under a session: the
+    rollouts stored and the losses are bitwise the same."""
+    out = {}
+    for traced in (False, True):
+        trainer = _trainer(tmp_path / f"ckpt{int(traced)}")
+        if traced:
+            tracing.start(str(tmp_path / "trace"))
+        try:
+            losses = run_cycle(trainer)
+        finally:
+            if traced:
+                tracing.stop()
+        out[traced] = (losses, [(e.query_tensor, e.response_tensor, e.logprobs, e.rewards)
+                                for e in trainer.store.history])
+    assert out[False][0] == out[True][0]
+    assert len(out[False][1]) == N_ROLLOUTS
+    for a, b in zip(out[False][1], out[True][1]):
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(x, y)
+
+
+def test_each_site_feeds_each_sink_once(tmp_path):
+    """With `train.tracing` on, the phases of the timeline (the names the
+    goodput ledger knows) come from the same sites as the spans, once each;
+    the `time/*` stats come from the same clock reads."""
+    trainer = _trainer(tmp_path, tracing=True)
+    logged = {}
+    trainer.tracker.log = lambda stats, step=None: logged.update(stats)
+    run_cycle(trainer)
+    n_chunks = N_ROLLOUTS // CHUNK
+    by_name = {}
+    for s in trainer._timeline.spans:
+        by_name.setdefault(s["name"], []).append(s)
+    assert {k: len(v) for k, v in by_name.items()} == {
+        "rollout_generate": n_chunks, "host_reward": n_chunks, "rollout_score": n_chunks,
+        "rollout_process": n_chunks, "make_experience": 1, "train_minibatch": N_ROLLOUTS // 4}
+    assert by_name["make_experience"][0]["attrs"]["step"] == 0
+    assert [s["attrs"]["rows"] for s in by_name["rollout_generate"]] == [CHUNK] * n_chunks
+    assert all(s["attrs"]["degraded"] is False for s in by_name["rollout_generate"])
+    # the trainer's stats are means over the chunks, in ms, of the same spans
+    reward_ms = 1e3 * np.mean([s["dur"] for s in by_name["host_reward"]])
+    process_ms = 1e3 * np.mean([s["dur"] for s in by_name["rollout_process"]])
+    assert logged["time/rollout_score"] == pytest.approx(reward_ms)
+    assert logged["time/rollout_time"] == pytest.approx(process_ms)
+    # dispatch to samples on the host: never shorter than the fetch alone
+    fetch_ms = 1e3 * np.mean([s["dur"] for s in by_name["rollout_generate"]])
+    assert logged["time/rollout_generate"] >= fetch_ms
+    assert logged["throughput/rollout_tokens_per_s"] > 0
+
+
+def test_profile_dir_goes_through_the_control(tmp_path, monkeypatch):
+    calls = []
+    real_start, real_stop = tracing.start, tracing.stop
+    monkeypatch.setattr(tracing, "start", lambda d=None: calls.append(("start", d)) or real_start(d))
+    monkeypatch.setattr(tracing, "stop", lambda: calls.append(("stop",)) or real_stop())
+    profile_dir = str(tmp_path / "profile")
+    trainer = _trainer(tmp_path / "ckpt", profile_dir=profile_dir, profile_start=0,
+                       profile_stop=1, eval_interval=100, checkpoint_interval=100)
+    trainer.add_eval_pipeline(
+        PromptPipeline(PROMPTS[:2], max_prompt_length=8, tokenizer=trainer.tokenizer))
+    trainer.learn()
+    assert calls == [("start", profile_dir), ("stop",)]
+    assert not tracing.active()
+    spans = [s for v in read_spans(profile_dir).values() for s in v]
+    assert named(spans, "ppo.train_minibatch")

@@ -33,6 +33,7 @@ from any thread (checkpoint hot-reload) and swaps atomically under a
 lock read at each dispatch.
 """
 
+import contextlib
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -44,6 +45,7 @@ import numpy as np
 from trlx_tpu.inference.adapters import adapter_salt
 from trlx_tpu.inference.paging import BlockPool, KVPoolExhaustedError, prefix_keys
 from trlx_tpu.models.transformer import init_kv_cache, init_paged_kv_arena
+from trlx_tpu.observability import tracing
 from trlx_tpu.ops.quant import dequantize_tree
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
@@ -755,30 +757,37 @@ class InferenceEngine:
                 ids_arr[len(chunk) :] = ids_arr[0]
                 mask_arr[len(chunk) :] = mask_arr[0]
 
-                t0 = time.monotonic() if self.trace_buf is not None else 0.0
-                if mt:
-                    aidx = jnp.asarray(aidx_arr)
-                    last_logits, cache = self._get_prefill(pb, plen)(
-                        params, jnp.asarray(ids_arr), jnp.asarray(mask_arr),
-                        stack, aidx,
-                    )
-                    self._pool = self._get_insert(pb)(
-                        self._pool, cache, last_logits,
-                        jnp.asarray(slots_arr), jnp.asarray(max_new_arr), aidx,
-                    )
-                else:
-                    last_logits, cache = self._get_prefill(pb, plen)(
-                        params, jnp.asarray(ids_arr), jnp.asarray(mask_arr)
-                    )
-                    self._pool = self._get_insert(pb)(
-                        self._pool, cache, last_logits,
-                        jnp.asarray(slots_arr), jnp.asarray(max_new_arr),
-                    )
-                if self.trace_buf is not None:
-                    self.trace_buf.append((
-                        "prefill_bucket", t0, time.monotonic(),
-                        {"bucket": plen, "rows": len(chunk)},
-                    ))
+                with self._insert_span(len(chunk), plen):
+                    if mt:
+                        aidx = jnp.asarray(aidx_arr)
+                        last_logits, cache = self._get_prefill(pb, plen)(
+                            params, jnp.asarray(ids_arr), jnp.asarray(mask_arr),
+                            stack, aidx,
+                        )
+                        self._pool = self._get_insert(pb)(
+                            self._pool, cache, last_logits,
+                            jnp.asarray(slots_arr), jnp.asarray(max_new_arr), aidx,
+                        )
+                    else:
+                        last_logits, cache = self._get_prefill(pb, plen)(
+                            params, jnp.asarray(ids_arr), jnp.asarray(mask_arr)
+                        )
+                        self._pool = self._get_insert(pb)(
+                            self._pool, cache, last_logits,
+                            jnp.asarray(slots_arr), jnp.asarray(max_new_arr),
+                        )
+
+    @contextlib.contextmanager
+    def _insert_span(self, rows: int, width: int):
+        """Round the dispatch of one prefill program: the `trlx:engine.insert`
+        span, and from the same clock reads the `prefill_bucket` entry of a
+        traced request's buffer."""
+        with tracing.timed_span("engine.insert", rows=rows, width=width) as sp:
+            yield
+        if self.trace_buf is not None:
+            self.trace_buf.append((
+                "prefill_bucket", sp.t0, sp.t1, {"bucket": width, "rows": rows},
+            ))
 
     def _check_row(self, ids, max_new: int) -> np.ndarray:
         ids = np.asarray(ids, np.int32).reshape(-1)
@@ -957,13 +966,8 @@ class InferenceEngine:
                 ]
                 if mt:
                     args += [stack, jnp.asarray(aidx_arr)]
-                t0 = time.monotonic() if self.trace_buf is not None else 0.0
-                self._pool = self._get_paged_insert(pb, plen)(*args)
-                if self.trace_buf is not None:
-                    self.trace_buf.append((
-                        "prefill_bucket", t0, time.monotonic(),
-                        {"bucket": plen, "rows": len(chunk)},
-                    ))
+                with self._insert_span(len(chunk), plen):
+                    self._pool = self._get_paged_insert(pb, plen)(*args)
 
     # ------------------------------------------------------------------
     # Decode
@@ -1263,15 +1267,15 @@ class InferenceEngine:
         contract); samples the HBM ledger every 64th decode step — often
         enough to catch the arena high-water mark, rare enough to stay off
         the hot path."""
+        self._step_n += 1
         try:
-            out = self._step_impl()
+            with tracing.span("engine.step", step_n=self._step_n):
+                out = self._step_impl()
         except Exception as e:
             self._maybe_oom_postmortem("engine.step", e)
             raise
-        if self.hbm is not None:
-            self._step_n += 1
-            if self._step_n % 64 == 1:
-                self.hbm.sample("engine.decode")
+        if self.hbm is not None and self._step_n % 64 == 1:
+            self.hbm.sample("engine.decode")
         return out
 
     def _step_impl(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
@@ -1284,20 +1288,22 @@ class InferenceEngine:
         the pool. The logprob is the policy's raw-logit log-probability
         of the emitted token (see `_sample_fused`), meaningful only where
         `emitted`."""
-        if self.spec_k > 0:
-            params, head = self._current_params_and_head()
-            self._pool, token, logprob, valid, finished = self._decode_fn(
-                params, self._pool, head[0], head[1]
-            )
-        elif self.multi_tenant:
-            params = self._current_params()
-            self._pool, token, logprob, valid, finished = self._decode_fn(
-                params, self._pool, self.adapter_store.stacked()
-            )
-        else:
-            params = self._current_params()
-            self._pool, token, logprob, valid, finished = self._decode_fn(params, self._pool)
-        token, logprob, valid, finished = jax.device_get((token, logprob, valid, finished))
+        with tracing.span("engine.dispatch"):
+            if self.spec_k > 0:
+                params, head = self._current_params_and_head()
+                self._pool, token, logprob, valid, finished = self._decode_fn(
+                    params, self._pool, head[0], head[1]
+                )
+            elif self.multi_tenant:
+                params = self._current_params()
+                self._pool, token, logprob, valid, finished = self._decode_fn(
+                    params, self._pool, self.adapter_store.stacked()
+                )
+            else:
+                params = self._current_params()
+                self._pool, token, logprob, valid, finished = self._decode_fn(params, self._pool)
+        with tracing.span("engine.fetch"):
+            token, logprob, valid, finished = jax.device_get((token, logprob, valid, finished))
         # kernel dispatch accounting (driver thread; read under _kv_lock
         # by kv_stats), after the step has run: a decode dispatch either
         # rode the fused kernel or fell back to the gather path for a

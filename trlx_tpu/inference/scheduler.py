@@ -38,6 +38,7 @@ import numpy as np
 from trlx_tpu.inference.adapters import AdapterCapacityError, AdapterError
 from trlx_tpu.inference.metrics import InferenceMetrics
 from trlx_tpu.inference.paging import KVPoolExhaustedError
+from trlx_tpu.observability import tracing
 from trlx_tpu.observability.tracing import Span
 from trlx_tpu.utils import logging
 
@@ -532,9 +533,11 @@ class Scheduler:
                     continue
             try:
                 self._expire_queued()
-                self._admit()
+                with tracing.span("sched.admit"):
+                    self._admit()
                 if self._slot_req:
-                    self._decode_once()
+                    with tracing.span("sched.decode_once", rows=len(self._slot_req)):
+                        self._decode_once()
             except Exception:  # pragma: no cover - defensive: keep serving
                 logger.exception("inference scheduler step failed")
                 time.sleep(0.05)
@@ -675,7 +678,9 @@ class Scheduler:
                 "admit", batch=len(batch), queue_depth=len(self._queue),
             )
         try:
-            self._insert_batch(batch, slots)
+            with tracing.span("sched.insert_batch", rows=len(batch),
+                              width=max(len(r.prompt_ids) for r in batch)):
+                self._insert_batch(batch, slots)
         finally:
             with self._cond:
                 self._admitting = []
@@ -815,53 +820,54 @@ class Scheduler:
         emitted = 0
         now = time.monotonic()
         eos = self.engine.gen_cfg.eos_token_id
-        for slot, req in list(self._slot_req.items()):
-            n_slot = 0
-            for j in range(tokens.shape[1]):
-                if valid[slot, j]:
-                    req.token_ids.append(int(tokens[slot, j]))
-                    req.token_logprobs.append(float(logprobs[slot, j]))
-                    n_slot += 1
-            emitted += n_slot
-            if n_slot and req.first_token_time is None:
-                req.first_token_time = now
-                self.metrics.observe(
-                    "ttft_seconds", req.first_token_time - req.enqueue_time,
-                    trace_id=(req.trace.trace_id if req.trace is not None
-                              else None),
-                )
-            if multi_tenant and n_slot:
-                t = self._tenant(req)
-                tenant_emitted[t] = tenant_emitted.get(t, 0) + n_slot
-            if spec and n_slot:
-                # accept-length per slot per speculative round (1 pending
-                # + accepted drafts) — the serving-side mirror of the
-                # trainer's rollout/spec_accept_rate
-                self.metrics.observe("spec_accepted_tokens", n_slot)
-            stopped = bool(n_slot) and self._apply_stop(req)
-            if stopped:
-                # a stop sequence matched: truncated, session retained,
-                # slot cancelled (release_slots deactivates + reclaims)
-                self._retain_session(slot, req)
-                self.engine.release_slots([slot])
-                self._release(slot)
-                self._finish_request(req, "stop")
-            elif finished[slot]:
-                last = req.token_ids[-1] if req.token_ids else -1
-                reason = "eos" if last == eos else "length"
-                # retention must run BEFORE reclaim frees the slot's
-                # blocks — the session's new pins piggyback on the
-                # request's still-live references
-                self._retain_session(slot, req)
-                self.engine.reclaim_slots([slot])
-                self._release(slot)
-                self._finish_request(req, reason)
-            elif req.deadline and now > req.deadline:
-                self.engine.release_slots([slot])
-                self._release(slot)
-                self._finish_request(req, "deadline")
-            elif n_slot:
-                self._stream_emit(req)
+        with tracing.span("sched.emit"):
+            for slot, req in list(self._slot_req.items()):
+                n_slot = 0
+                for j in range(tokens.shape[1]):
+                    if valid[slot, j]:
+                        req.token_ids.append(int(tokens[slot, j]))
+                        req.token_logprobs.append(float(logprobs[slot, j]))
+                        n_slot += 1
+                emitted += n_slot
+                if n_slot and req.first_token_time is None:
+                    req.first_token_time = now
+                    self.metrics.observe(
+                        "ttft_seconds", req.first_token_time - req.enqueue_time,
+                        trace_id=(req.trace.trace_id if req.trace is not None
+                                  else None),
+                    )
+                if multi_tenant and n_slot:
+                    t = self._tenant(req)
+                    tenant_emitted[t] = tenant_emitted.get(t, 0) + n_slot
+                if spec and n_slot:
+                    # accept-length per slot per speculative round (1 pending
+                    # + accepted drafts) — the serving-side mirror of the
+                    # trainer's rollout/spec_accept_rate
+                    self.metrics.observe("spec_accepted_tokens", n_slot)
+                stopped = bool(n_slot) and self._apply_stop(req)
+                if stopped:
+                    # a stop sequence matched: truncated, session retained,
+                    # slot cancelled (release_slots deactivates + reclaims)
+                    self._retain_session(slot, req)
+                    self.engine.release_slots([slot])
+                    self._release(slot)
+                    self._finish_request(req, "stop")
+                elif finished[slot]:
+                    last = req.token_ids[-1] if req.token_ids else -1
+                    reason = "eos" if last == eos else "length"
+                    # retention must run BEFORE reclaim frees the slot's
+                    # blocks — the session's new pins piggyback on the
+                    # request's still-live references
+                    self._retain_session(slot, req)
+                    self.engine.reclaim_slots([slot])
+                    self._release(slot)
+                    self._finish_request(req, reason)
+                elif req.deadline and now > req.deadline:
+                    self.engine.release_slots([slot])
+                    self._release(slot)
+                    self._finish_request(req, "deadline")
+                elif n_slot:
+                    self._stream_emit(req)
         self.metrics.add("tokens_generated_total", emitted)
         for t, n in tenant_emitted.items():
             self.metrics.add(

@@ -1,7 +1,21 @@
-"""Dependency-free request tracing: spans, per-request traces, a bounded
-tracer, and a Chrome-trace-event (Perfetto) exporter.
+"""Tracing: one control for the device profiler and the program's spans on
+its clock, plus the dependency-free request traces and the training phase
+timeline that were here before it.
 
-Design rules (ISSUE 13):
+**The control** (`start` / `stop` / `active` / `span`). `start()` opens a
+`jax.profiler` session in the running process, `stop()` closes it and
+returns the directory written; both may be called from any thread, and a
+second `start()` while one is active is an error. `span(name, **attrs)` is
+a `jax.profiler.TraceAnnotation` named `trlx:<name>`: while a session is
+active it lands on the host plane of the same xplane file as the device's
+operations, on the same clock, so an idle gap of the device can be charged
+to what the host was doing in it. While none is active the annotation
+checks one flag and formats nothing, so span sites are unconditional: no
+config field, no environment variable. Attributes carry what joins spans
+(`step`, `chunk`, `rows`, `width`, `step_n`); nesting on a thread gives the
+parent. The span names are listed in docs/observability.md.
+
+**Request traces and the phase timeline** (ISSUE 13):
 
 - **monotonic clocks** — every span timestamp is `time.monotonic()`; the
   wall-clock anchor (`EPOCH_OFFSET`, captured once at import) is applied
@@ -25,11 +39,72 @@ one cross-process timeline per request.
 
 import json
 import os
+import shutil
+import tempfile
 import threading
 import time
 import uuid
 from collections import deque
 from typing import Any, Dict, Iterable, List, Optional
+
+import jax
+
+SPAN_PREFIX = "trlx:"
+
+# The profiler admits one session a process, so the control's state is the
+# process's: the directory of the session that is open, under a lock.
+_session_lock = threading.Lock()
+_session_dir: Optional[str] = None
+
+
+def start(log_dir: Optional[str] = None) -> str:
+    """Start a profiler session in this process, writing under `log_dir` (a
+    new temporary directory if None); returns the directory. The device and
+    TraceMe spans are on, the Python tracer (a hook on every call) is off."""
+    global _session_dir
+    with _session_lock:
+        if _session_dir is not None:
+            raise RuntimeError(
+                f"a tracing session is already active (writing to {_session_dir})")
+        made = log_dir is None
+        if made:
+            log_dir = tempfile.mkdtemp(prefix="trlx_trace_")
+        os.makedirs(log_dir, exist_ok=True)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        try:
+            jax.profiler.start_trace(log_dir, profiler_options=opts)
+        except BaseException:
+            if made:  # a start that failed leaves no directory of its own behind
+                shutil.rmtree(log_dir, ignore_errors=True)
+            raise
+        _session_dir = log_dir
+    return log_dir
+
+
+def stop() -> str:
+    """Stop the active session and return the directory it wrote (the
+    `.xplane.pb` lies under `plugins/profile/<time>/`)."""
+    global _session_dir
+    with _session_lock:
+        if _session_dir is None:
+            raise RuntimeError("no tracing session is active")
+        # the session counts as active until the profiler has stopped: a
+        # stop that raises leaves the control saying what the profiler does
+        jax.profiler.stop_trace()
+        log_dir, _session_dir = _session_dir, None
+    return log_dir
+
+
+def active() -> bool:
+    return _session_dir is not None
+
+
+def span(name: str, **attrs):
+    """A context manager: the host span `trlx:<name>` in the active session's
+    trace, next to nothing while none is active."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **attrs)
 
 # Wall-clock anchor: monotonic t + EPOCH_OFFSET ~= time.time(). Captured
 # once so all spans in a process share one consistent mapping.
@@ -339,8 +414,9 @@ class PhaseTimeline:
         # phase's peak watermark.
         self.hbm = None
 
-    def phase(self, name: str, step: Optional[int] = None) -> "_PhaseCtx":
-        return _PhaseCtx(self, name, step)
+    def phase(self, name: str, step: Optional[int] = None) -> "timed_span":
+        attrs = {} if step is None else {"step": step}
+        return timed_span(name, timeline=self, phase=name, **attrs)
 
     def add(self, name: str, t0: float, t1: float,
             step: Optional[int] = None, **attrs) -> None:
@@ -394,16 +470,33 @@ class PhaseTimeline:
         return write_chrome_trace(path, self.to_chrome_trace())
 
 
-class _PhaseCtx:
-    __slots__ = ("_tl", "_name", "_step", "_t0")
+class timed_span:
+    """One site, every sink: `with timed_span(name, ...) as sp` opens the
+    `trlx:<name>` span, reads the clock once on each side (`sp.seconds`
+    after the block, for the caller's stats), and, given a `PhaseTimeline`,
+    adds the same interval to it as the phase `phase`. The attributes go to
+    the span and to the phase alike."""
 
-    def __init__(self, tl: PhaseTimeline, name: str, step: Optional[int]):
-        self._tl, self._name, self._step = tl, name, step
+    __slots__ = ("_annotation", "_timeline", "_phase", "_attrs", "t0", "t1")
+
+    def __init__(self, name: str, timeline: Optional[PhaseTimeline] = None,
+                 phase: Optional[str] = None, **attrs):
+        self._annotation = span(name, **attrs)
+        self._timeline, self._phase, self._attrs = timeline, phase, attrs
+        self.t0 = self.t1 = 0.0
 
     def __enter__(self):
-        self._t0 = time.monotonic()
+        self._annotation.__enter__()
+        self.t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        self._tl.add(self._name, self._t0, time.monotonic(), step=self._step)
+        self.t1 = time.monotonic()
+        self._annotation.__exit__(exc_type, exc, tb)
+        if self._timeline is not None:
+            self._timeline.add(self._phase, self.t0, self.t1, **self._attrs)
         return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0

@@ -308,6 +308,7 @@ def _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k, interpret=False):
             pltpu.VMEM((bq, hd), jnp.float32),   # acc
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qh, kh, vh, maskh)
     return out.reshape(b, nh, tq, hd).transpose(0, 2, 1, 3)
 
@@ -535,6 +536,7 @@ def _flash_fwd_pallas_lse(q, k, v, mask, causal, block_q, block_k, interpret=Fal
             pltpu.VMEM((bq, hd), jnp.float32),   # acc
         ],
         interpret=interpret,
+        name="flash_fwd_lse",
     )(qh, kh, vh, maskh)
     return (
         out.reshape(b, nh, tq, hd).transpose(0, 2, 1, 3),
@@ -683,6 +685,7 @@ def _flash_bwd_pallas(q, k, v, mask, out, lse, g, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b * nh, tq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qh, kh, vh, maskh, doh, lseh, delta)
 
     def kv_index_k(i, j, kk):
@@ -714,6 +717,7 @@ def _flash_bwd_pallas(q, k, v, mask, out, lse, g, causal, block_q, block_k,
             pltpu.VMEM((bk, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qh, kh, vh, maskh, doh, lseh, delta)
 
     if group > 1:  # GQA: per-q-head dk/dv fold back onto the kv heads

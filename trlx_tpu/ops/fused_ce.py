@@ -121,6 +121,7 @@ def _logprobs_pallas(logits, labels, block_rows=256, block_v=2048, interpret=Fal
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_ce_fwd",
     )(logits, labels_l)
     return out[:, 0], lse[:, 0]
 

@@ -280,6 +280,7 @@ def paged_attention_decode(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="paged_decode",
     )(table.astype(jnp.int32), *operands)
     return out.reshape(b, nh, hd)
 

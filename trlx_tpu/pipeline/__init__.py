@@ -16,6 +16,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional
 import jax
 import numpy as np
 
+from trlx_tpu.observability import tracing
 from trlx_tpu.utils import logging
 
 logger = logging.get_logger(__name__)
@@ -81,7 +82,9 @@ class DataLoader:
             chunk = indices[start : start + self.batch_size]
             if self.drop_last and len(chunk) < self.batch_size:
                 break
-            yield self.collate_fn([self.dataset[i] for i in chunk])
+            with tracing.span("pipeline.collate", rows=len(chunk)):
+                batch = self.collate_fn([self.dataset[i] for i in chunk])
+            yield batch
 
 
 def default_collate(items: List[Any]):

@@ -32,7 +32,7 @@ import optax
 from flax import traverse_util
 
 from trlx_tpu import resilience
-from trlx_tpu.observability import PhaseTimeline
+from trlx_tpu.observability import PhaseTimeline, tracing
 from trlx_tpu.sentinel import LAST_GOOD_NAME, HealthSentinel, SentinelRewind, StepWatchdog
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.models import resolve_split, trainable_mask
@@ -63,6 +63,10 @@ def merge_params(train: Dict, frozen: Dict) -> Dict:
 
 @register_trainer
 class TPUTrainer(BaseRLTrainer):
+    # the family of the trainer's spans in a profiler session:
+    # `trlx:<span_family>.train_minibatch` (PPOTrainer's is `ppo`)
+    span_family = "train"
+
     def __init__(
         self,
         config: TRLConfig,
@@ -845,13 +849,24 @@ class TPUTrainer(BaseRLTrainer):
         )
 
     def train_minibatch(self, minibatch: List[Any]) -> Dict[str, float]:
-        """One optimizer step over `num_mb` microbatches. OOM-guarded:
-        a RESOURCE_EXHAUSTED here leaves a memory postmortem bundle."""
+        """One optimizer step over `num_mb` microbatches (the dispatch: the
+        stats come back as device arrays). OOM-guarded: a RESOURCE_EXHAUSTED
+        here leaves a memory postmortem bundle."""
         try:
-            return self._train_minibatch_impl(minibatch)
+            with self._span(f"{self.span_family}.train_minibatch", phase="train_minibatch",
+                            step=self.iter_count):
+                return self._train_minibatch_impl(minibatch)
         except Exception as e:
             self._maybe_oom_postmortem("train_step", e)
             raise
+
+    def _span(self, name: str, phase: Optional[str] = None, **attrs):
+        """One site, every sink: the `trlx:<name>` span of a profiler session
+        (observability/tracing.py), the site's seconds (`.seconds` after the
+        block), and with `train.tracing` on a phase `phase` on the timeline
+        (and through it the goodput and HBM ledgers)."""
+        return tracing.timed_span(
+            name, timeline=self._timeline if phase else None, phase=phase, **attrs)
 
     def _train_minibatch_impl(self, minibatch: List[Any]) -> Dict[str, float]:
         if self._train_step_fn is None:
@@ -1059,7 +1074,7 @@ class TPUTrainer(BaseRLTrainer):
             if shutdown_fleet is not None:
                 shutdown_fleet()
             if getattr(self, "_profiling", False):
-                jax.profiler.stop_trace()
+                tracing.stop()
                 self._profiling = False
             if self._timeline is not None:
                 trace_dir = self.config.train.trace_dir or "logs/traces"
@@ -1207,15 +1222,9 @@ class TPUTrainer(BaseRLTrainer):
                     if mb_idx < skip_steps:
                         continue  # already trained before the preemption
                     self._maybe_profile_step()
-                    if self._timeline is not None:
-                        with self._timeline.phase(
-                            "train_minibatch", step=self.iter_count
-                        ):
-                            stats = self.train_minibatch(minibatch)
-                        if self._goodput is not None:
-                            self._goodput.note_train_rows(self.mb_size)
-                    else:
-                        stats = self.train_minibatch(minibatch)
+                    stats = self.train_minibatch(minibatch)
+                    if self._goodput is not None:
+                        self._goodput.note_train_rows(self.mb_size)
                     self.iter_count += 1
                     res, best_reward, done = self._post_step(stats, clock, best_reward)
                     results = res or results
@@ -1495,10 +1504,7 @@ class TPUTrainer(BaseRLTrainer):
             # the restore below plus every rollout phase until the first
             # post-rewind train step is repaid work — charge waste/rewind
             self._goodput.note_rewind()
-        if self._timeline is not None:
-            with self._timeline.phase("sentinel_restore", step=self.iter_count):
-                self.load(path)  # restores params/opt_state/PRNG/loop-pos bit-exactly
-        else:
+        with self._span("train.sentinel_restore", phase="sentinel_restore", step=self.iter_count):
             self.load(path)  # restores params/opt_state/PRNG/loop-pos bit-exactly
         sen.load_state_dict(ladder_state)
         sen.note_rewind(self.iter_count)
@@ -1514,18 +1520,18 @@ class TPUTrainer(BaseRLTrainer):
         experience under the post-rewind PRNG/cooldown)."""
 
     def _maybe_profile_step(self):
-        """Capture a jax.profiler trace over the configured step window
-        (train.profile_dir / profile_start / profile_stop)."""
+        """Capture a profiler trace over the configured step window
+        (train.profile_dir / profile_start / profile_stop), through the one
+        control in observability/tracing.py."""
         cfg = self.config.train
         if not cfg.profile_dir:
             return
         if cfg.profile_start <= self.iter_count < cfg.profile_stop and not getattr(self, "_profiling", False):
-            os.makedirs(cfg.profile_dir, exist_ok=True)
             logger.info(f"Starting profiler trace into {cfg.profile_dir}")
-            jax.profiler.start_trace(cfg.profile_dir)
+            tracing.start(cfg.profile_dir)
             self._profiling = True
         elif self.iter_count >= cfg.profile_stop and getattr(self, "_profiling", False):
-            jax.profiler.stop_trace()
+            tracing.stop()
             self._profiling = False
             logger.info(f"Profiler trace written to {cfg.profile_dir}")
 

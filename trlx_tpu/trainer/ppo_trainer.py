@@ -9,6 +9,7 @@ forward (ops in trlx_tpu/models/policy.py), and the user reward_fn stays on
 host between the two.
 """
 
+import itertools
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
@@ -126,6 +127,8 @@ class PPOConfig(MethodConfig):
 
 @register_trainer
 class PPOTrainer(TPUTrainer):
+    span_family = "ppo"
+
     def __init__(self, config: TRLConfig, **kwargs):
         super().__init__(config, **kwargs)
         self.seq2seq = config.model.model_arch_type == "seq2seq"
@@ -734,9 +737,13 @@ class PPOTrainer(TPUTrainer):
         logger.info("Collecting rollouts")
         if self._score_fn is None:
             self._build_score_fn()
+        with self._span("ppo.make_experience", phase="make_experience", step=iter_count):
+            self._collect_rollouts(num_rollouts, iter_count)
 
-        clock = Clock()
-        t_exp0 = time.monotonic()
+    def _collect_rollouts(self, num_rollouts: int, iter_count: int):
+        """The chunk loop of `make_experience`, inside its span. Every site
+        that is timed is one `_span`: the profiler's span, the phase on the
+        timeline and the `time/*` stat come from the same two clock reads."""
         ppo_rl_elements: List[PPORLElement] = []
         accumulated_stats: List[Dict] = []
         method = self.config.method
@@ -752,17 +759,23 @@ class PPOTrainer(TPUTrainer):
         # exactly one element per prompt, so "will another chunk be
         # needed" is decidable before processing this one.
         use_fleet = self._fleet_rollouts_enabled()
+        chunk_ids = itertools.count()
 
         def _dispatch_next():
+            """(batch, generation handles, chunk number, when it was dispatched)"""
             b = next(self.prompt_iterator)
-            if use_fleet:
-                return b, self._fleet_generate(b, gen_kwargs, trainer_step=iter_count)
-            # spec_k only travels when a speculative round is actually on:
-            # the parallel mixins' generate() has no spec_k parameter.
-            spec_k = self._spec_k_effective()
-            spec_kw = {"spec_k": spec_k} if spec_k else {}
-            return b, self.generate(b["input_ids"], b["attention_mask"], gen_kwargs,
-                                    **spec_kw)
+            chunk, t_dispatch = next(chunk_ids), time.monotonic()
+            with self._span("ppo.generate_dispatch", chunk=chunk, rows=len(b["input_ids"])):
+                if use_fleet:
+                    out = self._fleet_generate(b, gen_kwargs, trainer_step=iter_count)
+                else:
+                    # spec_k only travels when a speculative round is actually on:
+                    # the parallel mixins' generate() has no spec_k parameter.
+                    spec_k = self._spec_k_effective()
+                    spec_kw = {"spec_k": spec_k} if spec_k else {}
+                    out = self.generate(b["input_ids"], b["attention_mask"], gen_kwargs,
+                                        **spec_kw)
+            return b, out, chunk, t_dispatch
 
         pending = _dispatch_next()
 
@@ -776,127 +789,41 @@ class PPOTrainer(TPUTrainer):
                 # prefetch prediction below: dispatch another chunk
                 pending = _dispatch_next()
             stats: Dict[str, float] = {}
-            batch, out = pending
+            batch, out, chunk, t_dispatch = pending
             pending = None
             n_this = len(np.asarray(batch["input_ids"]))
             if len(ppo_rl_elements) + n_this < num_rollouts:
                 pending = _dispatch_next()
 
-            t_chunk0 = time.monotonic()
-            clock.tick()  # reset timer
-            samples = np.asarray(out["samples"])  # materialize (also syncs device)
-            stats["time/rollout_generate"] = clock.tick()
-            if self._timeline is not None:
-                self._timeline.add(
-                    "rollout_generate", t_chunk0, time.monotonic(),
-                    step=iter_count, rows=n_this,
-                    # a fleet chunk that fell back to local generation is
-                    # degraded capacity — the goodput ledger charges its
-                    # wall time to waste/fleet_degraded
-                    degraded=bool(use_fleet and not out.get("fleet")),
-                )
+            with self._span(
+                "ppo.rollout_fetch", phase="rollout_generate", step=iter_count,
+                chunk=chunk, rows=n_this,
+                # a fleet chunk that fell back to local generation is
+                # degraded capacity — the goodput ledger charges its
+                # wall time to waste/fleet_degraded
+                degraded=bool(use_fleet and not out.get("fleet")),
+            ) as fetch:
+                samples = np.asarray(out["samples"])  # materialize (also syncs device)
+            # from the chunk's dispatch to its samples on the host: with the
+            # next chunk dispatched ahead, the fetch alone is only the wait
+            # for what was left of the generation
+            gen_s = max(fetch.t1 - t_dispatch, 1e-9)
+            stats["time/rollout_generate"] = 1e3 * gen_s
             # throughput over REAL generated tokens (the validity mask —
-            # padding after eos doesn't count); tick() returns ms
-            gen_s = max(stats["time/rollout_generate"] / 1000.0, 1e-9)
+            # padding after eos doesn't count)
             real_tokens = int(np.asarray(out["response_mask"]).sum())
             stats["throughput/rollout_tokens_per_s"] = real_tokens / gen_s
             stats["throughput/rollout_requests_per_s"] = n_this / gen_s
             self._accum_spec_stats(out, stats)
 
-            t_proc0 = time.monotonic()
-            prompt_tensors, sample_outputs, outputs, scores, scores_mask = (
-                self._host_process_chunk(batch, samples, stats, clock)
-            )
-            if self._timeline is not None:
-                self._timeline.add(
-                    "rollout_score", t_proc0, time.monotonic(), step=iter_count
-                )
-
-            # Jitted precompute of logprobs/values/ref KL
-            if self.seq2seq:
-                logprobs, values, log_ratio, mean_kl, mean_kl_per_token = self._score_fn(
-                    self.train_params, self.frozen_params, self.ref_params,
-                    jnp.asarray(prompt_tensors), jnp.asarray(sample_outputs),
-                )
-            else:
-                all_tokens = np.concatenate([prompt_tensors, sample_outputs], axis=1)
-                logprobs, values, log_ratio, mean_kl, mean_kl_per_token = self._score_fn(
-                    self.train_params, self.frozen_params, self.ref_params,
-                    jnp.asarray(all_tokens),
-                )
-            h_cache = None
-            if self._trunk_cache_available():
-                # one frozen-prefix pass per chunk over the SAME retokenized
-                # tokens the scorer saw; amortized over ppo_epochs inner
-                # epochs of suffix-only training. Dispatched before the
-                # blocking fetch so it overlaps the stats transfer.
-                if self._trunk_cache_fn is None:
-                    self._trunk_cache_fn = self._build_trunk_cache_fn()
-                h_cache = self._trunk_cache_fn(
-                    self.train_params, self.frozen_params, jnp.asarray(all_tokens)
-                )
-            # ONE batched device->host fetch: sequential np.asarray calls
-            # each block until their own transfer lands, jax.device_get
-            # pipelines them together.
-            logprobs, values, log_ratio, mean_kl, mean_kl_per_token, h_cache = (
-                jax.device_get(
-                    (logprobs, values, log_ratio, mean_kl, mean_kl_per_token, h_cache)
-                )
-            )
-            mean_kl = float(mean_kl)
-            mean_kl_per_token = float(mean_kl_per_token)
-
-            if use_fleet:
-                # stats keys must be identical across chunks (the final
-                # averaging iterates the last chunk's keys), so both are
-                # set every chunk — including degraded ones
-                if out.get("fleet"):
-                    logprobs = np.array(logprobs)  # device_get can be read-only
-                    hits = self._apply_behavior_logprobs(
-                        logprobs, out, prompt_tensors, sample_outputs
-                    )
-                    stats["fleet/behavior_logprob_rows"] = float(hits)
-                    stats["fleet/degraded_chunks"] = 0.0
-                else:
-                    stats["fleet/behavior_logprob_rows"] = 0.0
-                    stats["fleet/degraded_chunks"] = 1.0
-
-            elements = self._chunk_to_elements(
-                prompt_tensors, sample_outputs, outputs, scores, scores_mask,
-                logprobs, values, log_ratio, h_cache,
-            )
-            if self._sentinel is not None:
-                # rollout quarantine + anomaly observation. Element-level
-                # (post-scorer) so dropping rows never changes the jitted
-                # score fn's shapes; stats keys are set on EVERY chunk
-                # (the final averaging iterates the last chunk's keys).
-                elements, n_dropped = self._quarantine_elements(
-                    elements, scores, scores_mask, outputs
-                )
-                stats["sentinel/quarantined_rows"] = float(n_dropped)
-                if n_dropped and self._goodput is not None:
-                    # the dropped rows' share of this chunk's wall time is
-                    # MOVED (not added) into waste/quarantined so the
-                    # ledger keeps summing to wall time
-                    self._goodput.note_quarantine(
-                        n_dropped,
-                        (n_dropped / max(n_this, 1))
-                        * (time.monotonic() - t_chunk0),
-                    )
-                stats["rollout/entropy"] = (
-                    float(np.mean([-np.mean(e.logprobs) for e in elements]))
-                    if elements else 0.0
-                )
-                self._sentinel.observe_rollout(stats)
-            ppo_rl_elements.extend(elements)
-
-            stats["time/rollout_time"] = clock.tick()
-            if self._timeline is not None:
-                self._timeline.add(
-                    "rollout_process", t_proc0, time.monotonic(), step=iter_count
-                )
+            with self._span("ppo.rollout_process", phase="rollout_process",
+                            step=iter_count, chunk=chunk) as process:
+                elements, mean_kl, mean_kl_per_token = self._process_chunk(
+                    batch, out, samples, stats, iter_count, chunk, use_fleet, fetch.t0)
+                ppo_rl_elements.extend(elements)
+            stats["time/rollout_time"] = 1e3 * process.seconds
             if self._goodput is not None:
-                self._goodput_configure(prompt_tensors.shape[1], max_new)
+                self._goodput_configure(np.asarray(batch["input_ids"]).shape[1], max_new)
                 self._goodput.note_rollout_chunk(n_this)
             stats["policy/sqrt_kl"] = float(np.sqrt(max(mean_kl, 0.0)))
             stats["policy/kl_per_token"] = float(np.sqrt(max(mean_kl_per_token, 0.0)))
@@ -921,12 +848,98 @@ class PPOTrainer(TPUTrainer):
                 if isinstance(v, (int, float)):
                     stats[f"fleet/{k}"] = float(v)
         self.mean_kl = stats["policy/sqrt_kl"] ** 2
-        if self._timeline is not None:
-            self._timeline.add(
-                "make_experience", t_exp0, time.monotonic(), step=iter_count
-            )
         self.tracker.log(stats, step=iter_count)
         self.push_to_store(ppo_rl_elements)
+
+    def _process_chunk(self, batch, out, samples, stats, iter_count, chunk,
+                       use_fleet, t_chunk0):
+        """One fetched chunk, from its samples on the host to its store
+        elements: host decode and reward, the scorer's dispatch and fetch,
+        the sentinel's quarantine. Returns (elements, mean_kl,
+        mean_kl_per_token) and writes the chunk's stats into `stats`."""
+        with self._span("ppo.host_process", phase="rollout_score", step=iter_count):
+            prompt_tensors, sample_outputs, outputs, scores, scores_mask = (
+                self._host_process_chunk(batch, samples, stats)
+            )
+
+        # Jitted precompute of logprobs/values/ref KL
+        with self._span("ppo.score_dispatch", chunk=chunk):
+            if self.seq2seq:
+                logprobs, values, log_ratio, mean_kl, mean_kl_per_token = self._score_fn(
+                    self.train_params, self.frozen_params, self.ref_params,
+                    jnp.asarray(prompt_tensors), jnp.asarray(sample_outputs),
+                )
+            else:
+                all_tokens = np.concatenate([prompt_tensors, sample_outputs], axis=1)
+                logprobs, values, log_ratio, mean_kl, mean_kl_per_token = self._score_fn(
+                    self.train_params, self.frozen_params, self.ref_params,
+                    jnp.asarray(all_tokens),
+                )
+        h_cache = None
+        if self._trunk_cache_available():
+            # one frozen-prefix pass per chunk over the SAME retokenized
+            # tokens the scorer saw; amortized over ppo_epochs inner
+            # epochs of suffix-only training. Dispatched before the
+            # blocking fetch so it overlaps the stats transfer.
+            if self._trunk_cache_fn is None:
+                self._trunk_cache_fn = self._build_trunk_cache_fn()
+            h_cache = self._trunk_cache_fn(
+                self.train_params, self.frozen_params, jnp.asarray(all_tokens)
+            )
+        # ONE batched device->host fetch: sequential np.asarray calls
+        # each block until their own transfer lands, jax.device_get
+        # pipelines them together.
+        logprobs, values, log_ratio, mean_kl, mean_kl_per_token, h_cache = (
+            jax.device_get(
+                (logprobs, values, log_ratio, mean_kl, mean_kl_per_token, h_cache)
+            )
+        )
+        mean_kl = float(mean_kl)
+        mean_kl_per_token = float(mean_kl_per_token)
+
+        if use_fleet:
+            # stats keys must be identical across chunks (the final
+            # averaging iterates the last chunk's keys), so both are
+            # set every chunk — including degraded ones
+            if out.get("fleet"):
+                logprobs = np.array(logprobs)  # device_get can be read-only
+                hits = self._apply_behavior_logprobs(
+                    logprobs, out, prompt_tensors, sample_outputs
+                )
+                stats["fleet/behavior_logprob_rows"] = float(hits)
+                stats["fleet/degraded_chunks"] = 0.0
+            else:
+                stats["fleet/behavior_logprob_rows"] = 0.0
+                stats["fleet/degraded_chunks"] = 1.0
+
+        elements = self._chunk_to_elements(
+            prompt_tensors, sample_outputs, outputs, scores, scores_mask,
+            logprobs, values, log_ratio, h_cache,
+        )
+        if self._sentinel is not None:
+            # rollout quarantine + anomaly observation. Element-level
+            # (post-scorer) so dropping rows never changes the jitted
+            # score fn's shapes; stats keys are set on EVERY chunk
+            # (the final averaging iterates the last chunk's keys).
+            elements, n_dropped = self._quarantine_elements(
+                elements, scores, scores_mask, outputs
+            )
+            stats["sentinel/quarantined_rows"] = float(n_dropped)
+            if n_dropped and self._goodput is not None:
+                # the dropped rows' share of this chunk's wall time is
+                # MOVED (not added) into waste/quarantined so the
+                # ledger keeps summing to wall time
+                self._goodput.note_quarantine(
+                    n_dropped,
+                    (n_dropped / max(len(samples), 1))
+                    * (time.monotonic() - t_chunk0),
+                )
+            stats["rollout/entropy"] = (
+                float(np.mean([-np.mean(e.logprobs) for e in elements]))
+                if elements else 0.0
+            )
+            self._sentinel.observe_rollout(stats)
+        return elements, mean_kl, mean_kl_per_token
 
     # ------------------------------------------------------------------
     # Multi-turn experience (tool-use environments over fleet sessions)
@@ -1242,7 +1255,7 @@ class PPOTrainer(TPUTrainer):
             gbuf, glens = gbuf[0], glens[0]  # everyone adopts rank 0's rows
         return [gbuf[i, : max(int(glens[i]), 1)] for i in range(n)]
 
-    def _host_process_chunk(self, batch, samples, stats=None, clock=None):
+    def _host_process_chunk(self, batch, samples, stats=None):
         """The host stage of one rollout chunk: decode -> reward_fn ->
         retokenize/right-pad the (possibly stop-trimmed) outputs ->
         clip -> running-moments reward scaling. Shared by make_experience
@@ -1258,20 +1271,19 @@ class PPOTrainer(TPUTrainer):
         prompt_tensors = np.asarray(batch["input_ids"])
         n_samples = len(samples)
         prompt_sizes = [prompt_tensors.shape[1]] * n_samples
-        str_samples, str_prompts, str_outputs = self.decode(
-            prompt_tensors, samples, prompt_sizes, append_eos_token=True
-        )
+        with self._span("ppo.host_decode"):
+            str_samples, str_prompts, str_outputs = self.decode(
+                prompt_tensors, samples, prompt_sizes, append_eos_token=True
+            )
         metadata = {
             k: v for k, v in batch.items() if k not in ("input_ids", "attention_mask")
         }
-        t_rw0 = time.monotonic()
-        score_rows = self._score_samples(str_samples, str_prompts, str_outputs, metadata)
-        if self._timeline is not None:
-            # the host reward round trip, split out of rollout_score so
-            # the goodput ledger can attribute reward RTT as its own cause
-            self._timeline.add("host_reward", t_rw0, time.monotonic())
-        if stats is not None and clock is not None:
-            stats["time/rollout_score"] = clock.tick()
+        # the host reward round trip, split out of rollout_score so the
+        # goodput ledger can attribute reward RTT as its own cause
+        with self._span("ppo.reward", phase="host_reward") as reward:
+            score_rows = self._score_samples(str_samples, str_prompts, str_outputs, metadata)
+        if stats is not None:
+            stats["time/rollout_score"] = 1e3 * reward.seconds
         S = max(len(r) for r in score_rows)
         scores = np.full((n_samples, S), -np.inf, dtype=np.float32)
         for i, r in enumerate(score_rows):
@@ -2107,16 +2119,12 @@ class PPOTrainer(TPUTrainer):
             fetch.extend(s[0] for s in specs)
         if prev is not None and not use_fast:
             fetch.extend(prev)
-        t_fetch0 = time.monotonic()
-        fetched = jax.device_get(tuple(fetch))
-        if self._timeline is not None:
-            # the cycle's blocking device->host sync: under the fast
-            # schedule this is where generation overlap is (or isn't)
-            # hiding the previous train step
-            self._timeline.add(
-                "pipelined_fetch", t_fetch0, time.monotonic(),
-                step=self.iter_count,
-            )
+        # the cycle's blocking device->host sync: under the fast schedule
+        # this is where generation overlap is (or isn't) hiding the
+        # previous train step
+        with self._span("ppo.pipelined_fetch", phase="pipelined_fetch",
+                        step=self.iter_count):
+            fetched = jax.device_get(tuple(fetch))
         samples_list = fetched[:k]
         trimmed_list = fetched[k:2 * k] if use_spec else [None] * k
         for _, o in gens:
@@ -2254,10 +2262,8 @@ class PPOTrainer(TPUTrainer):
 
     def _timed_train_epochs(self, full, n_epochs):
         """train_epochs_from_chunk under a "train_epochs" phase span (the
-        pipelined path bypasses _learn_loop's train_minibatch wrapper)."""
-        if self._timeline is None:
-            return self.train_epochs_from_chunk(full, n_epochs)
-        with self._timeline.phase("train_epochs", step=self.iter_count):
+        pipelined path does not go through `train_minibatch`)."""
+        with self._span("ppo.train_epochs", phase="train_epochs", step=self.iter_count):
             return self.train_epochs_from_chunk(full, n_epochs)
 
     def post_backward_callback(self):

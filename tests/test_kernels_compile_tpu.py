@@ -22,7 +22,12 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 pytest.importorskip("libtpu", reason="AOT compilation for the TPU needs libtpu")
 
 from trlx_tpu.ops import attention, fused_ce  # noqa: E402
-from trlx_tpu.ops.paged_attention import paged_attention_decode  # noqa: E402
+from trlx_tpu.ops.paged_attention import (  # noqa: E402
+    init_paged_layer,
+    paged_attention_decode,
+    paged_kv_gather,
+    paged_kv_write,
+)
 
 S = jax.ShapeDtypeStruct
 BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
@@ -125,8 +130,9 @@ def test_fused_ce_compiles(v5e, rows):
 
 
 # (n_heads, n_kv_heads, head_dim): GPT-2 small (group 1), a 7B-class GQA
-# shape (group 4), and llama-tiny, the repo's own group-2 preset
-PAGED_SHAPES = [(12, 12, 64), (32, 8, 128), (4, 2, 16)]
+# shape (group 4), llama-tiny, the repo's own group-2 preset, and the
+# benchmark's two configurations, pythia-1.4b and gpt2-xl
+PAGED_SHAPES = [(12, 12, 64), (32, 8, 128), (4, 2, 16), (16, 16, 128), (25, 25, 64)]
 
 
 @pytest.mark.parametrize("dtype", [BF16, I8], ids=["bf16", "int8"])
@@ -150,12 +156,133 @@ def test_paged_decode_compiles_without_copying_the_arena(v5e, heads, blk, dtype)
     if hd >= 64:
         # The kernel reads the arena and its scale planes where they lie: a
         # layout the TPU does not keep row-major would show up here as a
-        # whole-operand copy in front of the custom call, every step.
-        text = compiled.as_text()
-        for operand in (arena, *args[5:]):
-            dims = ",".join(map(str, operand.shape))
-            assert not re.search(rf"= \w+\[{dims}\]\S* copy\(", text), (
-                f"per-call copy of a [{dims}] arena operand")
+        # whole-operand copy in front of the custom call, every step. (The
+        # kernel alone: the write in front of it is held below.)
+        assert arena_rewrites(compiled, arena, *args[5:]) == []
+
+
+def arena_rewrites(compiled, *operands) -> list:
+    """The `copy` / `transpose` instructions of a compiled program whose
+    result has as many elements as one of `operands` (a K/V arena or an
+    int8 scale plane, under whatever shape the program views it): each is
+    the whole operand moved through HBM once more than the work needs."""
+    sizes = {int(np.prod(op.shape)) for op in operands}
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", line)
+        if m and int(np.prod([int(d) for d in m.group(1).split(",")])) in sizes:
+            found.append(line.strip()[:120])
+    return found
+
+
+def donated_outputs(compiled) -> int:
+    """How many outputs of the program live in a donated input's buffer."""
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry_computation_layout",
+                      compiled.as_text())
+    return alias.group(1).count("-alias)") if alias else 0
+
+
+def abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype, sharding=sharding), tree)
+
+
+# pythia-1.4b.rollout-batch (bench/workloads): 1,280 blocks of 32 tokens,
+# 16 kv heads of 128, 64 slots x 20 table entries
+CELL = dict(n_blocks=1280, nkv=16, blk=32, hd=128, slots=64, n_tbl=20)
+
+
+@pytest.mark.parametrize("dtype", [BF16, I8], ids=["bf16", "int8"])
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_paged_write_and_read_leave_the_arena_where_it_lies(v5e, form, dtype):
+    """The write and the read in ONE program, arenas donated, as a layer of
+    the engine's decode step (64 rows x 1 position, the kernel) and of a
+    prefill (1 row x 256 positions, the gather) run them: the step's keys
+    and values land in the donated arena in the layout the kernel and the
+    gather read, with no copy of an arena or a scale plane on the way.
+    `arena.at[phys, :, off].set(k)` put four arena copies a layer there:
+    49 ms of a 120 ms decode step on the chip (PERF.md, PR 28)."""
+    n_blocks, nkv, blk, hd = (CELL[k] for k in ("n_blocks", "nkv", "blk", "hd"))
+    b, t = (CELL["slots"], 1) if form == "decode" else (1, 256)
+    one = SingleDeviceSharding(v5e[0])
+
+    def layer_step(layer, q, k, v, table, start, valid, key_mask):
+        new = paged_kv_write(layer, k, v, table, start, valid)
+        if form == "decode":
+            out = paged_attention_decode(
+                q[:, 0], new["k"], new["v"], table, key_mask,
+                k_scale=new.get("k_scale"), v_scale=new.get("v_scale"))
+        else:
+            keys, values = paged_kv_gather(new, table, BF16)
+            bias = jnp.where(key_mask.astype(bool), 0.0, -1e9)[:, None, None, :]
+            probs = jax.nn.softmax(
+                jnp.einsum("bthd,bshd->bhts", q, keys).astype(F32) + bias, axis=-1)
+            out = jnp.einsum("bhts,bshd->bthd", probs.astype(BF16), values)
+        return new, out
+
+    layer = jax.eval_shape(lambda: init_paged_layer(n_blocks, blk, nkv, hd, dtype))
+    kv = S((b, t, nkv, hd), BF16)
+    args = (layer, kv, kv, kv, S((b, CELL["n_tbl"]), I32), S((b,), I32), S((b, t), I32),
+            S((b, CELL["n_tbl"] * blk), I32))
+    compiled = jax.jit(layer_step, donate_argnums=(0,)).trace(
+        *abstract(args, one)).lower(lowering_platforms=("tpu",)).compile()
+    assert mosaic_calls(compiled) == (1 if form == "decode" else 0)
+    assert arena_rewrites(compiled, *layer.values()) == []
+    assert donated_outputs(compiled) == len(layer)
+
+
+@pytest.fixture(scope="module")
+def cell_engine(v5e):
+    """A paged `InferenceEngine` as `pythia-1.4b.rollout-batch` builds it,
+    over two layers of pythia-1.4b's widths and no weights: its programs
+    are only compiled here. The engine picks the kernel by the device its
+    params live on, and there are no params, so the test answers for it."""
+    from trlx_tpu.inference import InferenceEngine
+    from trlx_tpu.models import CausalLMPolicy, config_from_preset
+    from trlx_tpu.ops.sampling import GenerationConfig
+
+    cfg = config_from_preset("pythia-1.4b", 50304, n_layers=2, attn_impl="flash",
+                             param_dtype=BF16, dtype=BF16)
+    model = CausalLMPolicy(cfg)
+    tokens = jnp.zeros((1, 32), I32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
+    gen_cfg = GenerationConfig(max_new_tokens=128, do_sample=True,
+                               eos_token_id=cfg.vocab_size + 1, pad_token_id=0)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(InferenceEngine, "_param_devices", lambda self: [v5e[0]])
+        engine = InferenceEngine(
+            model, cfg, None, gen_cfg, kv_paging=True, num_slots=CELL["slots"],
+            max_prompt_len=512, max_prefill_batch=8, prompt_bucket=128,
+            kv_block_size=CELL["blk"], kv_pool_blocks=CELL["n_blocks"], kv_cache_dtype="bf16")
+    assert engine.decode_path == "pallas"
+    return engine, params
+
+
+@pytest.mark.parametrize("program", ["decode", "paged_insert"])
+def test_engine_programs_leave_the_arena_where_it_lies(v5e, cell_engine, program):
+    """The same one level up, so that the call site in `Attention` is held
+    and not only `paged_kv_write`: the engine's own decode program and one
+    of its prefill programs (1 row x 256), pool donated."""
+    engine, params = cell_engine
+    one = SingleDeviceSharding(v5e[0])
+    pool = abstract(engine._pool, one)
+    params = abstract(params, one)
+    if program == "decode":
+        compiled = engine._decode_fn.trace(params, pool).lower(
+            lowering_platforms=("tpu",)).compile()
+    else:
+        rows, width = 1, 256
+        shapes = dict(ids=(rows, width), tmask=(rows, width), tables=(rows, CELL["n_tbl"]),
+                      slot_ids=(rows,), max_new=(rows,), shared_len=(rows,))
+        compiled = engine._get_paged_insert(rows, width).trace(
+            pool, params, *(S(shape, I32, sharding=one) for shape in shapes.values())
+        ).lower(lowering_platforms=("tpu",)).compile()
+    n_layers = len(engine._pool["layers"])
+    assert mosaic_calls(compiled) == (n_layers if program == "decode" else 0)
+    arenas = [a for layer in engine._pool["layers"] for a in layer.values()]
+    assert arena_rewrites(compiled, *arenas) == []
+    assert donated_outputs(compiled) >= len(arenas)
 
 
 LAYOUTS = [(4, 1, 1), (2, 1, 2), (1, 2, 2)]  # (data, fsdp, tensor)

@@ -8,6 +8,7 @@ counted reason surfaced through kv_stats."""
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from trlx_tpu.inference import InferenceEngine
@@ -16,6 +17,7 @@ from trlx_tpu.ops.attention import kernel_mode
 from trlx_tpu.ops.paged_attention import (
     paged_attention_decode,
     paged_attention_reference,
+    paged_kv_write,
 )
 from trlx_tpu.ops.sampling import GenerationConfig
 
@@ -80,6 +82,107 @@ def run_serial(engine, prompts, max_new=8, slot=0):
 BOUNDARY_PROMPTS = [
     list(range(60, 60 + n)) for n in (7, 8, 9, 15, 16, 17)
 ]
+
+
+# ----------------------------------------------------------------------
+# The arena write, bitwise against a plain numpy scatter
+# ----------------------------------------------------------------------
+
+BLK, N_TBL, N_BLOCKS = 8, 4, 20
+
+# name -> (arena dtype, nkv, hd, t, per-row start column, per-row valid
+# positions of t, per-row block table or None for a random one)
+WRITE_CASES = {
+    # a decode step: one position a row, rows at and round block edges,
+    # the last row inactive
+    "decode_step": ("bfloat16", 4, 16, 1, [BLK - 1, BLK, 2 * BLK + 3, 5], [1, 1, 1, 0], None),
+    # padding rows of an insert carry all-out-of-range tables (n_blocks
+    # and beyond), a freed row a stale table behind a zero mask
+    "out_of_range_blocks_dropped": (
+        "float32", 4, 16, 3, [0, 0, 4], [3, 3, 0],
+        [[N_BLOCKS] * N_TBL, [N_BLOCKS + 7] * N_TBL, [3, 4, 5, 6]]),
+    # a right-padded prefill: only the first `valid` positions land
+    "masked_right_pad": ("bfloat16", 4, 16, 12, [0, 0, 0], [12, 5, 0], None),
+    # a speculative-verify write and a resumed prefill: t > 1 from the
+    # middle of a block into the next two
+    "crosses_block_boundaries": ("bfloat16", 4, 16, 13, [BLK - 2, 2 * BLK - 1], [13, 9], None),
+    "gqa_two_kv_heads": ("bfloat16", 2, 16, 5, [6, 0, 11], [5, 2, 4], None),
+    "gqa_one_kv_head": ("float32", 1, 16, 5, [6, 0, 11], [5, 2, 4], None),
+    "int8_both_scale_planes": ("int8", 4, 16, 1, [BLK - 1, BLK, 2 * BLK + 3, 5], [1, 1, 1, 0], None),
+    "int8_prefill_pad_drop_boundary": (
+        "int8", 2, 16, 11, [0, BLK - 3, 0], [11, 7, 4],
+        [[1, 2, 3, 4], [5, 6, 7, 8], [N_BLOCKS] * N_TBL]),
+}
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({1: np.uint8, 2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+@pytest.mark.parametrize("case", sorted(WRITE_CASES))
+def test_arena_write_is_a_plain_scatter_bitwise(case):
+    """`paged_kv_write` under jit against a loop that stores each valid
+    position's [nkv, hd] keys at arena[phys, :, off] (and its nkv scales
+    at plane[phys, 0, h*blk + off]) and nothing else: every byte of the
+    arena, written or not, has to agree."""
+    dtype, nkv, hd, t, start, valid, table = WRITE_CASES[case]
+    rng = np.random.RandomState(sorted(WRITE_CASES).index(case))
+    b = len(start)
+    dtype = jnp.dtype(dtype)
+    # a non-zero arena: an untouched row must keep its bytes
+    shape = (N_BLOCKS, nkv, BLK, hd)
+    if dtype == jnp.int8:
+        layer = {"k": rng.randint(-127, 128, shape), "v": rng.randint(-127, 128, shape),
+                 "k_scale": rng.rand(N_BLOCKS, 1, nkv * BLK) + 0.5,
+                 "v_scale": rng.rand(N_BLOCKS, 1, nkv * BLK) + 0.5}
+    else:
+        layer = {"k": rng.randn(*shape), "v": rng.randn(*shape)}
+    layer = {name: jnp.asarray(a, jnp.float32 if "scale" in name else dtype)
+             for name, a in layer.items()}
+    k = jnp.asarray(rng.randn(b, t, nkv, hd), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(b, t, nkv, hd), jnp.bfloat16)
+    if table is None:  # every entry its own block, as the block pool hands them out
+        table = 1 + rng.permutation(N_BLOCKS - 1)[:b * N_TBL].reshape(b, N_TBL)
+    table = np.asarray(table, np.int32)
+    mask = np.arange(t)[None, :] < np.asarray(valid)[:, None]
+
+    got = jax.jit(paged_kv_write)(
+        layer, k, v, jnp.asarray(table), jnp.asarray(start, jnp.int32), jnp.asarray(mask))
+
+    # where the engine's tables put a row's columns (inference/engine.py:
+    # logical column c of row r is offset c % block of block table[r, c // block])
+    cols = np.asarray(start)[:, None] + np.arange(t)[None, :]
+    phys = np.take_along_axis(table, np.clip(cols // BLK, 0, N_TBL - 1), axis=1)
+    phys = np.where(mask & (cols < N_TBL * BLK), phys, N_BLOCKS)
+    off = cols % BLK
+
+    want = {name: np.array(a) for name, a in layer.items()}
+    stored = {"k": k, "v": v}
+    if dtype == jnp.int8:
+        # quantized as the write quantizes, under jit (eager rounds a
+        # scale one ulp apart); the scatter is what is under test
+        quantize = jax.jit(quant.quantize_kv)
+        (stored["k"], ks), (stored["v"], vs) = quantize(k), quantize(v)
+        stored.update(k_scale=ks, v_scale=vs)
+    stored = {name: np.asarray(a.astype(layer[name].dtype)) for name, a in stored.items()}
+    written = 0
+    for r in range(b):
+        for i in range(t):
+            if not 0 <= phys[r, i] < N_BLOCKS:
+                continue
+            written += 1
+            for name in ("k", "v"):
+                want[name][phys[r, i], :, off[r, i]] = stored[name][r, i]
+            for name in set(want) - {"k", "v"}:
+                for h in range(nkv):
+                    want[name][phys[r, i], 0, h * BLK + off[r, i]] = stored[name][r, i, h]
+    in_range = [n for n, row in zip(valid, table) if row[0] < N_BLOCKS]
+    assert written == sum(in_range), "the case writes what it says"
+    assert set(got) == set(want)
+    for name in want:
+        assert got[name].shape == want[name].shape and got[name].dtype == want[name].dtype
+        np.testing.assert_array_equal(_bits(got[name]), _bits(want[name]), err_msg=name)
 
 
 # ----------------------------------------------------------------------

@@ -298,30 +298,21 @@ class Attention(nn.Module):
             # cache is a global block arena shared by every slot (layout
             # owned by ops/paged_attention.py) plus a per-row block table
             # [b, n_tbl] mapping logical token columns to physical
-            # blocks. This step's K/V scatters to per-row columns
+            # blocks. This step's K/V lands at per-row columns
             # [cache_index, cache_index + t); positions with
-            # attn_mask == 0 (right-pad, inactive slots) are redirected
-            # to block index n_blocks, which the jitted scatter DROPS —
-            # they never touch the arena, so stale block tables on freed
-            # rows are harmless. The read side is either the fused kernel
-            # or a gather of the row's blocks back into the dense
-            # [b, n_tbl*block_size, nkv, hd] layout that falls through to
-            # the same einsum as the fixed pool.
+            # attn_mask == 0 (right-pad, inactive slots) never touch the
+            # arena (`paged_kv_write` drops them), so stale block tables
+            # on freed rows are harmless. The read side is either the
+            # fused kernel or a gather of the row's blocks back into the
+            # dense [b, n_tbl*block_size, nkv, hd] layout that falls
+            # through to the same einsum as the fixed pool.
             from trlx_tpu.ops import paged_attention as paged
 
             table = layer_cache["table"]  # [b, n_tbl] int32
-            n_blocks, blk_sz = layer_cache["k"].shape[0], layer_cache["k"].shape[2]
-            n_tbl = table.shape[1]
             idx = cache_index if jnp.ndim(cache_index) == 1 else jnp.full(
                 (b,), cache_index, jnp.int32
             )
-            cols = idx[:, None] + jnp.arange(t)[None, :]  # [b, t]
-            blk = jnp.clip(cols // blk_sz, 0, n_tbl - 1)
-            phys = jnp.take_along_axis(table, blk, axis=1)  # [b, t]
-            off = cols % blk_sz
-            if attn_mask is not None:
-                phys = jnp.where(attn_mask.astype(bool), phys, n_blocks)
-            new_cache = paged.paged_kv_write(layer_cache, k, v, phys, off)
+            new_cache = paged.paged_kv_write(layer_cache, k, v, table, idx, attn_mask)
             new_cache["table"] = table
             if attn_kernel is not None:
                 # Fused Pallas read side: one pass per (slot, table entry)

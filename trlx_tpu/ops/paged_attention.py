@@ -30,8 +30,16 @@ Hence:
 
 * K / V arenas are [n_blocks, nkv, block, hd] — a block's tile for one kv
   head is its last two dims. With hd >= 64 (every preset but the `-tiny`
-  test models) the kernel reads the arena in place; below that XLA
-  inserts the layout copy, which costs time but not correctness.
+  test models) the kernel's operand is the arena as it lies; below that
+  XLA inserts the layout copy, which costs time but not correctness.
+  That holds for the PROGRAM only if the write in front of the kernel
+  leaves the arena in that layout too: `paged_kv_write` scatters whole
+  blocks over the arena's major dimension for that reason (a scatter
+  into dims 0 and 2, `arena.at[phys, :, off]`, made XLA re-lay the whole
+  arena in front of the write and back in front of the kernel or the
+  gather, four arena copies a layer, in every decode step and every
+  prefill: tests/test_kernels_compile_tpu.py holds write and read in one
+  program).
 * int8 scale planes are [n_blocks, 1, nkv*block] f32, head-major along
   the lane axis (column h*block + offset), so a block's scales are one
   lane-dense row and each head's slice is a static lane window.
@@ -76,29 +84,74 @@ def init_paged_layer(
 
 def paged_kv_write(
     layer: Dict[str, jnp.ndarray],
-    k: jnp.ndarray,     # [b, t, nkv, hd]
-    v: jnp.ndarray,     # [b, t, nkv, hd]
-    phys: jnp.ndarray,  # [b, t] physical block id; >= n_blocks drops the write
-    off: jnp.ndarray,   # [b, t] column inside the block
+    k: jnp.ndarray,      # [b, t, nkv, hd]
+    v: jnp.ndarray,      # [b, t, nkv, hd]
+    table: jnp.ndarray,  # [b, n_tbl] physical block ids; >= n_blocks drops the write
+    start: jnp.ndarray,  # [b] logical column of each row's first position
+    valid: Optional[jnp.ndarray] = None,  # [b, t]; 0 keeps the position out of the arena
 ) -> Dict[str, jnp.ndarray]:
-    """Scatter one step's K/V into the arena (quantizing for int8 arenas).
-    Out-of-range `phys` rows are dropped by the scatter, which is how
-    masked positions and stale tables stay out of the arena."""
-    arena_k, arena_v = layer["k"], layer["v"]
-    if arena_k.dtype != jnp.int8:
-        return {
-            "k": arena_k.at[phys, :, off].set(k.astype(arena_k.dtype)),
-            "v": arena_v.at[phys, :, off].set(v.astype(arena_v.dtype)),
-        }
-    nkv, blk = arena_k.shape[1], arena_k.shape[2]
+    """Write one step's K/V into the arena (quantizing for int8 arenas):
+    row r's position i lands at logical column `start[r] + i`, i.e. in
+    block `table[r, column // block]` at offset `column % block`. Positions
+    with `valid == 0` (right-pad, inactive slots), columns past the table
+    and table entries >= n_blocks (padding rows of an insert, stale
+    tables) never touch the arena.
+
+    Whole blocks are patched: the blocks a row's run of t columns can
+    straddle are gathered, the new positions selected in, and the blocks
+    scattered back over the MAJOR dimension of the donated arena, which
+    XLA's TPU scatter updates where it lies (module docstring, "Layout")
+    at about a microsecond a block; a block with no valid position of this
+    call is not touched. (A scatter of [hd] rows is as free of copies but
+    serial at 70 ns a row: PERF.md section 6, PR 28.) This relies on the
+    block pool handing a block that is being written to one row only
+    (inference/paging.py: shared prefix blocks are full and read-only).
+    A scale plane takes its t x nkv elements one by one through the flat
+    view: patching its [1, nkv*block] rows makes XLA re-lay the plane."""
+    n_blocks, nkv, blk, _ = layer["k"].shape
+    b, t = k.shape[:2]
+    n_tbl = table.shape[1]
+    valid = jnp.ones((b, t), bool) if valid is None else valid.astype(bool)
+    rows = jnp.arange(b)[:, None]
+
+    n_touch = (t + blk - 2) // blk + 1  # blocks a run of t columns can straddle
+    entry = (start // blk)[:, None] + jnp.arange(n_touch)  # [b, n_touch]
+    phys = table[rows, jnp.clip(entry, 0, n_tbl - 1)]
+    # column p of the touched blocks holds this call's position src[p]
+    src = jnp.arange(n_touch * blk)[None, :] - (start % blk)[:, None]
+    live = (src >= 0) & (src < t)
+    src = jnp.clip(src, 0, t - 1)
+    live = (live & valid[rows, src]).reshape(b, n_touch, blk)
+    live &= ((entry < n_tbl) & (phys < n_blocks))[..., None]
+    phys = jnp.where(live.any(-1), phys, n_blocks)
+
+    def put(arena, values):
+        new = values[rows, src].reshape(b, n_touch, blk, nkv, -1).swapaxes(2, 3)
+        patched = jnp.where(live[:, :, None, :, None], new.astype(arena.dtype), arena[phys])
+        return arena.at[phys.reshape(-1)].set(
+            patched.reshape(-1, *arena.shape[1:]), mode="drop"
+        )
+
+    if layer["k"].dtype != jnp.int8:
+        return {"k": put(layer["k"], k), "v": put(layer["v"], v)}
+
+    cols = start[:, None] + jnp.arange(t)  # [b, t]
+    at = table[rows, jnp.clip(cols // blk, 0, n_tbl - 1)]
+    ok = valid & (cols < n_tbl * blk) & (at < n_blocks)
+    at = (at[..., None] * nkv + jnp.arange(nkv)) * blk + (cols % blk)[..., None]
+    at = jnp.where(ok[..., None], at, n_blocks * nkv * blk).reshape(-1)
+
+    def put_scales(plane, scales):  # [b, t, nkv] onto column h*blk + offset
+        flat = plane.reshape(-1).at[at].set(scales.reshape(-1), mode="drop")
+        return flat.reshape(plane.shape)
+
     kq, ks = quant.quantize_kv(k)
     vq, vs = quant.quantize_kv(v)
-    cols = jnp.arange(nkv) * blk + off[..., None]  # [b, t, nkv]
     return {
-        "k": arena_k.at[phys, :, off].set(kq),
-        "v": arena_v.at[phys, :, off].set(vq),
-        "k_scale": layer["k_scale"].at[phys[..., None], 0, cols].set(ks),
-        "v_scale": layer["v_scale"].at[phys[..., None], 0, cols].set(vs),
+        "k": put(layer["k"], kq),
+        "v": put(layer["v"], vq),
+        "k_scale": put_scales(layer["k_scale"], ks),
+        "v_scale": put_scales(layer["v_scale"], vs),
     }
 
 

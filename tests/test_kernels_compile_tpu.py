@@ -321,3 +321,131 @@ def test_sharded_fused_ce_wrapper_compiles(v5e, layout):
         functools.partial(fused_ce.fused_logprobs_sharded, mesh),
         (S((n, vocab), BF16), S((n,), I32)), (logits, rows), (rows, rows))
     assert mosaic_calls(compiled) == 1
+
+
+# lfm2-8b-a1b.ppo-hh (bench/workloads): d 2048, experts of width 1792, 8 of
+# 32 held, 4 a token; a train step's 16 x 1024 tokens (65,536 dispatch rows)
+# and a decode step's 64
+LFM2 = dict(vocab_size=16384, n_layers=10, moe_local_experts=8, attn_impl="flash")
+
+
+@pytest.fixture
+def pallas_mode(monkeypatch):
+    """The kernel rule looks at the process's devices, which are CPUs here:
+    the test answers for it, as `cell_engine` does for the engine."""
+    monkeypatch.setattr(attention, "kernel_mode", lambda: "pallas")
+
+
+def _expert_stacks(params) -> list:
+    return [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(params)
+            if any("expert_" in str(getattr(k, "key", k)) for k in path) and leaf.ndim == 2]
+
+
+@pytest.mark.parametrize("tokens", [16 * 1024, 64])
+def test_expert_layer_compiles_and_leaves_its_stacks_where_they_lie(v5e, pallas_mode, tokens):
+    """`SparseMoE` forward and backward at the cell's shapes: the three
+    grouped products carry their names, and no step re-lays an expert stack
+    or its gradient: the float32 leaves are `[fan_in, experts x fan_out]`,
+    the kernels read a column block of the bfloat16 cast and write a column
+    block of the gradient (a `[fan_in, experts, fan_out]` stack cost a
+    transposing copy of every gradient, 117 MB each, PR 29)."""
+    from trlx_tpu.models import config_from_preset
+    from trlx_tpu.models.transformer import SparseMoE
+
+    cfg = config_from_preset("lfm2-8b-a1b", **LFM2)
+    layer = SparseMoE(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    x = S((max(tokens // 1024, 1), min(tokens, 1024), cfg.d_model), BF16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0), jnp.zeros(x.shape, BF16))["params"])
+    stacks = _expert_stacks(params)
+    assert sorted(s.shape for s in stacks) == [(1792, 8 * 2048), (2048, 8 * 1792), (2048, 8 * 1792)]
+
+    def loss(p, h):
+        return (layer.apply({"params": p}, h).astype(F32) ** 2).sum()
+
+    args = abstract((params, x), one)
+    fwd = jax.jit(lambda p, h: layer.apply({"params": p}, h)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert sorted(kernel_names(fwd)) == ["moe_gmm"] * 3
+    assert arena_rewrites(fwd, *stacks) == []
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1))).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert sorted(kernel_names(bwd)) == ["moe_gmm"] * 3 + ["moe_gmm_dlhs"] * 3 + ["moe_tgmm"] * 3
+    assert arena_rewrites(bwd, *stacks) == []
+
+
+def test_lfm2_decode_step_compiles_without_copying_an_expert_stack(v5e, pallas_mode):
+    """One cached step of the cell's 10-layer model, 64 rows, through the
+    K/V tables and the convolution states."""
+    from trlx_tpu.models import config_from_preset, init_kv_cache
+    from trlx_tpu.models.transformer import TransformerLM
+
+    cfg = config_from_preset("lfm2-8b-a1b", **LFM2)
+    model = TransformerLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    b, total = 64, 1024
+    tokens = jnp.zeros((1, 8), I32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, b, total))
+    assert [sorted(layer) for layer in cache["layers"]] == [
+        ["conv"] if kind == "conv" else ["k", "v"] for kind in cfg.layer_types]
+
+    def step(p, tok, c, mask):
+        return model.apply({"params": p}, tok, c, mask, False, method=TransformerLM.decode_step)
+
+    compiled = jax.jit(step, donate_argnums=(2,)).trace(
+        *abstract((params, S((b, 1), I32), cache, S((b, 1), I32)), one)).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert kernel_names(compiled).count("moe_gmm") == 3 * 8
+    assert arena_rewrites(compiled, *_expert_stacks(params)) == []
+
+
+def test_lfm2_train_step_fits_the_chip_at_batch_16(v5e, pallas_mode, capsys):
+    """The cell's train step in outline (windowed head over the 128 response
+    positions, gradients of the top two blocks, AdamW), compiled for one
+    v5e chip at 16 x 1024: the compiler's own account of its memory, beside
+    the float32 leaves it is handed, has to leave room in 16 GB."""
+    import optax
+    from flax.traverse_util import flatten_dict, unflatten_dict
+
+    from trlx_tpu.models import CausalLMWithValueHead, config_from_preset
+    from trlx_tpu.models.policy import trainable_mask
+    from trlx_tpu.utils.modeling import logprobs_of_labels
+
+    cfg = config_from_preset("lfm2-8b-a1b", **LFM2)
+    model = CausalLMWithValueHead(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    b, t, new = 16, 1024, 128
+    probe = jnp.zeros((1, 8), I32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), probe, jnp.ones_like(probe))["params"])
+    flat, mask = flatten_dict(params), flatten_dict(trainable_mask(params, cfg, 2))
+    train = {k: v for k, v in flat.items() if mask[k]}
+    frozen = {k: v for k, v in flat.items() if not mask[k]}
+    assert not any("expert_bias" in k for k in train)
+    opt = optax.adamw(6e-6)
+    opt_state = jax.eval_shape(opt.init, train)
+
+    def train_step(train, frozen, opt_state, tokens, attn_mask):
+        def loss(train):
+            logits, values = model.apply(
+                {"params": unflatten_dict({**train, **frozen})}, tokens, attn_mask, None, t - new - 1, new,
+                method=CausalLMWithValueHead.forward_window)
+            return -logprobs_of_labels(logits, tokens[:, t - new:]).mean() + (values ** 2).mean()
+
+        grads = jax.grad(loss)(train)
+        updates, opt_state_new = opt.update(grads, opt_state, train)
+        return optax.apply_updates(train, updates), opt_state_new
+
+    compiled = jax.jit(train_step, donate_argnums=(0, 2)).trace(
+        *abstract((train, frozen, opt_state, S((b, t), I32), S((b, t), I32)), one)).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert arena_rewrites(compiled, *_expert_stacks(params)) == []
+    memory = compiled.memory_analysis()
+    held = sum(int(np.prod(v.shape)) * 4 for v in flat.values())
+    with capsys.disabled():
+        print(f"\nlfm2-8b-a1b train step at {b} x {t} for v5e: arguments "
+              f"{memory.argument_size_in_bytes / 1e9:.2f} GB, temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB, "
+              f"float32 leaves {held / 1e9:.2f} GB")
+    # beside this program the process holds the reference copy of the top
+    # blocks (0.84 GB) and the sampler's bfloat16 view while it runs
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5e9

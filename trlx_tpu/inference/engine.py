@@ -44,7 +44,7 @@ import numpy as np
 
 from trlx_tpu.inference.adapters import adapter_salt
 from trlx_tpu.inference.paging import BlockPool, KVPoolExhaustedError, prefix_keys
-from trlx_tpu.models.transformer import init_kv_cache, init_paged_kv_arena
+from trlx_tpu.models.transformer import init_kv_cache, init_paged_kv_arena, refuse_conv_state
 from trlx_tpu.observability import tracing
 from trlx_tpu.ops.quant import dequantize_tree
 from trlx_tpu.ops.sampling import (
@@ -167,6 +167,7 @@ class InferenceEngine:
             raise NotImplementedError(
                 "slot-pool decode under prompt/prefix tuning is unsupported"
             )
+        refuse_conv_state(model_cfg, "InferenceEngine")
         if gen_cfg.num_beams > 1:
             raise NotImplementedError("beam search is not servable slot-wise")
         if gen_cfg.repetition_penalty != 1.0:

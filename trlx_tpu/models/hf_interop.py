@@ -53,6 +53,8 @@ def _family_of(hf: Dict) -> str:
         "t5forconditionalgeneration", "mt5forconditionalgeneration"
     ):
         return "t5"
+    if mt == "lfm2_moe" or arch == "lfm2moeforcausallm":
+        return "lfm2_moe"
     for fam, keys in (
         ("gpt_bigcode", ("bigcode",)),
         ("gpt_neox", ("neox",)),
@@ -157,8 +159,30 @@ def config_from_hf(path: str, **overrides):
             tie_embeddings=True, use_bias=True,
             layer_norm_epsilon=hf.get("layer_norm_epsilon", 1e-5),
         )
+    elif fam == "lfm2_moe":
+        kwargs = dict(
+            vocab_size=hf["vocab_size"], d_model=hf["hidden_size"],
+            n_layers=hf["num_hidden_layers"], n_heads=hf["num_attention_heads"],
+            n_kv_heads=hf.get("num_key_value_heads"), d_ff=hf["intermediate_size"],
+            max_seq_len=hf.get("max_position_embeddings", 128000), pos_embed="rope",
+            rope_theta=hf.get("rope_theta", 1e6), norm="rmsnorm", activation="silu", glu=True,
+            # not in every published config: the family ties head and embedding
+            tie_embeddings=bool(hf.get("tie_embedding", hf.get("tie_word_embeddings", True))),
+            use_bias=False, layer_norm_epsilon=hf.get("norm_eps", 1e-5), qk_norm=True, flash_prefill=True,
+            conv_kernel=hf.get("conv_L_cache", 3),
+            layer_types=tuple("conv" if t == "conv" else "attention" for t in hf["layer_types"]),
+            moe_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_tok"],
+            moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=hf.get("num_dense_layers", 0),
+            moe_router="sigmoid",
+        )
+        for key, want in (("conv_bias", False), ("use_expert_bias", True), ("norm_topk_prob", True),
+                          ("routed_scaling_factor", 1)):
+            if hf.get(key, want) != want:
+                raise NotImplementedError(f"lfm2_moe with {key}={hf[key]!r} is not supported")
     kwargs["hf_family"] = fam
     kwargs.update(overrides)
+    if fam == "lfm2_moe" and "n_layers" in overrides and "layer_types" not in overrides:
+        kwargs["layer_types"] = kwargs["layer_types"][: kwargs["n_layers"]]
     return TransformerConfig(**kwargs)
 
 
@@ -366,6 +390,49 @@ def _load_llama(sd: Dict, cfg: TransformerConfig) -> Dict:
         }
     if not cfg.tie_embeddings:
         lm["lm_head"] = _dense(sd["lm_head.weight"].T)
+    return lm
+
+
+_LFM2_FFN = (("gate_proj", "w1"), ("up_proj", "w3"), ("down_proj", "w2"))
+
+
+def _load_lfm2_moe(sd: Dict, cfg: TransformerConfig) -> Dict:
+    """Lfm2MoeForCausalLM. The checkpoint holds every expert; the tree holds
+    the matrices of experts [moe_local_offset, + experts_held) side by side
+    (`SparseMoE`), so a process that holds a share loads its share."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lm: Dict = {
+        "embed_tokens": {"embedding": sd[f"{pre}embed_tokens.weight"]},
+        "ln_f": _ln(sd, f"{pre}embedding_norm", bias=False),
+    }
+    held = range(cfg.moe_local_offset, cfg.moe_local_offset + cfg.experts_held)
+    for i in range(cfg.n_layers):
+        p = f"{pre}layers.{i}."
+        block = {"ln_attn": _ln(sd, p + "operator_norm", bias=False),
+                 "ln_mlp": _ln(sd, p + "ffn_norm", bias=False)}
+        if cfg.layer_op(i) == "conv":
+            block["conv"] = {
+                "in_proj": _dense(sd[p + "conv.in_proj.weight"].T),
+                "kernel": sd[p + "conv.conv.weight"][:, 0, :].T,  # [d, 1, K] -> [K, d]
+                "out_proj": _dense(sd[p + "conv.out_proj.weight"].T),
+            }
+        else:
+            block["attn"] = {n: _dense(sd[p + f"self_attn.{hf_n}.weight"].T) for n, hf_n in
+                             (("q_proj", "q_proj"), ("k_proj", "k_proj"), ("v_proj", "v_proj"),
+                              ("o_proj", "out_proj"))}
+            block["attn"]["q_norm"] = _ln(sd, p + "self_attn.q_layernorm", bias=False)
+            block["attn"]["k_norm"] = _ln(sd, p + "self_attn.k_layernorm", bias=False)
+        if cfg.layer_ffn(i) == "dense":
+            block["mlp"] = {n: _dense(sd[p + f"feed_forward.{w}.weight"].T) for n, w in _LFM2_FFN}
+        else:
+            block["mlp"] = {
+                "router": _dense(sd[p + "feed_forward.gate.weight"].T),
+                "expert_bias": {"bias": sd[p + "feed_forward.expert_bias"]},
+                **{f"expert_{n.split('_')[0]}": _dense(np.concatenate(
+                    [sd[p + f"feed_forward.experts.{e}.{w}.weight"].T for e in held], axis=1))
+                   for n, w in _LFM2_FFN},
+            }
+        lm[f"block_{i}"] = block
     return lm
 
 
@@ -584,6 +651,7 @@ _LOADERS: Dict[str, Callable] = {
     "opt": _load_opt,
     "bloom": _load_bloom,
     "gpt_bigcode": _load_gpt_bigcode,
+    "lfm2_moe": _load_lfm2_moe,
 }
 
 
@@ -684,6 +752,41 @@ def _export_llama(lm: Dict, cfg: TransformerConfig) -> Dict:
         sd["lm_head.weight"] = _f32(lm["lm_head"]["kernel"]).T
     else:
         sd["lm_head.weight"] = sd["model.embed_tokens.weight"]
+    return sd
+
+
+def _export_lfm2_moe(lm: Dict, cfg: TransformerConfig) -> Dict:
+    """Inverse of `_load_lfm2_moe`: the experts held go out under their
+    indices in the whole model."""
+    sd = {
+        "model.embed_tokens.weight": _f32(lm["embed_tokens"]["embedding"]),
+        "model.embedding_norm.weight": _f32(lm["ln_f"]["scale"]),
+    }
+    sd["lm_head.weight"] = sd["model.embed_tokens.weight"]
+    for i in range(cfg.n_layers):
+        b, p = lm[f"block_{i}"], f"model.layers.{i}."
+        sd[p + "operator_norm.weight"] = _f32(b["ln_attn"]["scale"])
+        sd[p + "ffn_norm.weight"] = _f32(b["ln_mlp"]["scale"])
+        if "conv" in b:
+            sd[p + "conv.in_proj.weight"] = _f32(b["conv"]["in_proj"]["kernel"]).T
+            sd[p + "conv.conv.weight"] = _f32(b["conv"]["kernel"]).T[:, None, :]
+            sd[p + "conv.out_proj.weight"] = _f32(b["conv"]["out_proj"]["kernel"]).T
+        else:
+            for n, hf_n in (("q_proj", "q_proj"), ("k_proj", "k_proj"), ("v_proj", "v_proj"),
+                            ("o_proj", "out_proj")):
+                sd[p + f"self_attn.{hf_n}.weight"] = _f32(b["attn"][n]["kernel"]).T
+            sd[p + "self_attn.q_layernorm.weight"] = _f32(b["attn"]["q_norm"]["scale"])
+            sd[p + "self_attn.k_layernorm.weight"] = _f32(b["attn"]["k_norm"]["scale"])
+        if "router" not in b["mlp"]:
+            for n, w in _LFM2_FFN:
+                sd[p + f"feed_forward.{w}.weight"] = _f32(b["mlp"][n]["kernel"]).T
+            continue
+        sd[p + "feed_forward.gate.weight"] = _f32(b["mlp"]["router"]["kernel"]).T
+        sd[p + "feed_forward.expert_bias"] = _f32(b["mlp"]["expert_bias"]["bias"])
+        for n, w in _LFM2_FFN:
+            stack = _f32(b["mlp"][f"expert_{n.split('_')[0]}"]["kernel"])
+            for g, mat in enumerate(np.split(stack, cfg.experts_held, axis=1)):
+                sd[p + f"feed_forward.experts.{cfg.moe_local_offset + g}.{w}.weight"] = mat.T
     return sd
 
 
@@ -909,6 +1012,7 @@ _EXPORTERS: Dict[str, Callable] = {
     "opt": _export_opt,
     "bloom": _export_bloom,
     "gpt_bigcode": _export_gpt_bigcode,
+    "lfm2_moe": _export_lfm2_moe,
 }
 
 
@@ -917,6 +1021,8 @@ def infer_family(cfg) -> str:
     (used when exporting a model that wasn't loaded from an HF dir)."""
     if getattr(cfg, "is_seq2seq", False):
         return "t5"
+    if getattr(cfg, "has_conv_layers", False):
+        return "lfm2_moe"
     if cfg.alibi:
         return "bloom"
     if cfg.pos_offset:
@@ -976,6 +1082,19 @@ def config_to_hf(cfg: TransformerConfig, family: str = None) -> Dict:
             pad_token_id=(cfg.pad_token_id if cfg.pad_token_id is not None
                           else cfg.decoder_start_token_id),
             eos_token_id=cfg.eos_token_id if cfg.eos_token_id is not None else 1,
+        )
+    if family == "lfm2_moe":
+        return dict(
+            model_type="lfm2_moe", architectures=["Lfm2MoeForCausalLM"],
+            vocab_size=cfg.vocab_size, hidden_size=cfg.d_model, num_hidden_layers=cfg.n_layers,
+            num_attention_heads=cfg.n_heads, num_key_value_heads=cfg.kv_heads,
+            intermediate_size=cfg.d_ff, moe_intermediate_size=cfg.expert_d_ff,
+            max_position_embeddings=cfg.max_seq_len, rope_theta=cfg.rope_theta,
+            norm_eps=cfg.layer_norm_epsilon, conv_L_cache=cfg.conv_kernel, conv_bias=False,
+            layer_types=["conv" if t == "conv" else "full_attention" for t in cfg.layer_types],
+            num_dense_layers=cfg.moe_dense_layers, num_experts=cfg.moe_experts,
+            num_experts_per_tok=cfg.moe_top_k, norm_topk_prob=True, use_expert_bias=True,
+            routed_scaling_factor=1, tie_embedding=cfg.tie_embeddings,
         )
     if family == "gpt2":
         return dict(

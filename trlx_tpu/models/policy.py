@@ -47,7 +47,12 @@ class ValueBranch(nn.Module):
         # honor cfg.remat_blocks like the trunk (this call site never
         # passes the static use_prefix arg, so no static_argnums needed)
         block_cls = nn.remat(Block) if self.cfg.remat_blocks else Block
-        self.blocks = [block_cls(self.cfg, name=f"block_{i}") for i in range(self.n_branch_layers)]
+        first = self.cfg.n_layers - self.n_branch_layers  # the trunk blocks this branch clones
+        self.blocks = [
+            block_cls(self.cfg, op_kind=self.cfg.layer_op(first + i), ffn_kind=self.cfg.layer_ffn(first + i),
+                      name=f"block_{i}")
+            for i in range(self.n_branch_layers)
+        ]
         self.ln_f = make_norm(self.cfg, "ln_f")
         self.v_head = MLPHead(1, self.cfg.dtype, self.cfg.param_dtype, name="v_head")
 
@@ -429,6 +434,10 @@ def trainable_mask(params: Dict, cfg: TransformerConfig, num_layers_unfrozen: in
 
     def _mask(path_keys, leaf):
         parts = [getattr(k, "key", str(k)) for k in path_keys]
+        if "expert_bias" in parts:
+            # SparseMoE's selection bias: load balancing moves it, the
+            # gradient cannot (it only steers a top-k), so it never trains
+            return False
         if parts[0] != "lm":
             return True  # v_head / ilql_heads / any auxiliary head
         if prompt or prefix:

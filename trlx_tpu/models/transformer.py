@@ -67,8 +67,59 @@ class TransformerConfig:
     # added to the training loss (plain top-k routing collapses onto a
     # few experts without it).
     moe_aux_coef: float = 0.01
+    # Layers of more than one kind (LFM2-style hybrids). `layer_types` names
+    # each layer's operator, "attention" or "conv" (the gated short
+    # convolution, `ShortConv`); empty = attention everywhere, and then none
+    # of the fields below is read. `moe_dense_layers` leading layers keep
+    # the dense MLP (width d_ff) in a model whose other layers hold experts
+    # (width moe_d_ff, d_ff when None).
+    layer_types: Tuple[str, ...] = ()
+    conv_kernel: int = 3  # taps of the short convolution
+    qk_norm: bool = False  # RMSNorm over the head width on q and k, before rotary
+    # The fused sampler's prefill (`decode_step(is_prefill=True)`, each batch
+    # prefilled once into an empty cache) attends within the prompt through
+    # the fused kernel (attn_impl "flash") while its K/V go into the cache,
+    # instead of scoring the prompt against the whole cache: [rows, heads,
+    # prompt, prompt + new] float32 scores are 7.5 GB at 64 x 32 x 896 x 1024,
+    # which the chip cannot hold (and its compiler does not survive, PR 29).
+    # Off for the families that were there, whose programs stay as they are.
+    flash_prefill: bool = False
+    moe_dense_layers: int = 0
+    moe_d_ff: Optional[int] = None
+    # "softmax": MoEMLP below (renormalized softmax gates, auxiliary loss,
+    # every expert computes every token). "sigmoid": `SparseMoE` (float32
+    # sigmoid scores, top-k over scores + a selection bias that no gradient
+    # moves, grouped dispatch over the experts held here, no auxiliary loss).
+    moe_router: str = "softmax"
+    # Expert parallelism seen from one chip: of the model's `moe_experts`
+    # this process holds `moe_local_experts` (0 = all), the contiguous range
+    # starting at `moe_local_offset`. The router still scores all of them;
+    # the layer computes only what its own experts add (ops/moe.py).
+    moe_local_experts: int = 0
+    moe_local_offset: int = 0
 
     def __post_init__(self):
+        if self.layer_types:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types names {len(self.layer_types)} layers, n_layers is {self.n_layers}"
+                )
+            unknown = set(self.layer_types) - {"attention", "conv"}
+            if unknown:
+                raise ValueError(f"unknown layer_types {sorted(unknown)}")
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_router must be 'softmax' or 'sigmoid', got {self.moe_router!r}")
+        if self.moe_local_experts and self.moe_router != "sigmoid":
+            raise NotImplementedError(
+                "moe_local_experts (one chip's share of the experts) needs the "
+                "grouped dispatch of moe_router='sigmoid'"
+            )
+        if self.moe_local_offset + self.experts_held > max(self.moe_experts, 0) and self.moe_experts:
+            raise ValueError(
+                f"experts [{self.moe_local_offset}, {self.moe_local_offset + self.experts_held}) "
+                f"are not among the model's {self.moe_experts}"
+            )
         if self.moe_experts > 0 and self.lora_rank > 0:
             raise NotImplementedError(
                 "LoRA adapters on MoE expert weights are not supported; "
@@ -128,6 +179,45 @@ class TransformerConfig:
     def rotary_dim(self) -> int:
         rd = int(self.head_dim * self.rotary_pct)
         return rd - (rd % 2)
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_local_experts or self.moe_experts
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def has_conv_layers(self) -> bool:
+        return "conv" in self.layer_types
+
+    @property
+    def sows_moe_aux(self) -> bool:
+        """Whether a training forward sows an auxiliary loss that the
+        trainers must collect (which forces their full-width forward)."""
+        return self.moe_experts > 0 and self.moe_router == "softmax" and self.moe_aux_coef > 0
+
+    @property
+    def has_sparse_moe(self) -> bool:
+        """Whether some layer is a `SparseMoE` (which sows dispatch counters)."""
+        return self.moe_experts > 0 and self.moe_router == "sigmoid"
+
+    @property
+    def blocks_read_token_mask(self) -> bool:
+        """Whether a cached step must hand its blocks the validity of the
+        incoming tokens: a convolution state that a masked step may not
+        move, experts that masked tokens are not dispatched to."""
+        return self.has_conv_layers or self.has_sparse_moe
+
+    def layer_op(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else "attention"
+
+    def layer_ffn(self, i: int) -> str:
+        """"dense" | "moe" (MoEMLP) | "sparse_moe" (SparseMoE) for layer i."""
+        if self.moe_experts <= 0 or i < self.moe_dense_layers:
+            return "dense"
+        return "sparse_moe" if self.has_sparse_moe else "moe"
 
 
 def activation_fn(cfg: TransformerConfig):
@@ -277,7 +367,7 @@ class Attention(nn.Module):
         cache_index: Optional[jnp.ndarray] = None,
         attn_mask: Optional[jnp.ndarray] = None,  # [b, t] key validity (fused paths)
         use_prefix: bool = True,
-        attn_kernel: Optional[str] = None,  # paged decode: None | "pallas" | "interpret"
+        attn_kernel: Optional[str] = None,  # paged decode: "pallas" | "interpret"; "prefill": see flash_prefill
     ):
         cfg = self.cfg
         b, t, d = h.shape
@@ -288,6 +378,10 @@ class Attention(nn.Module):
         k = dense(nkv * hd, "k_proj")(h).reshape(b, t, nkv, hd)
         v = dense(nkv * hd, "v_proj")(h).reshape(b, t, nkv, hd)
 
+        if cfg.qk_norm:
+            head_norm = lambda name: nn.RMSNorm(
+                epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
+            q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
         if cfg.pos_embed == "rope":
             q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim)
             k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim)
@@ -367,7 +461,11 @@ class Attention(nn.Module):
             else:
                 ck = jax.lax.dynamic_update_slice(layer_cache["k"], kc, (0, cache_index, 0, 0))
                 cv = jax.lax.dynamic_update_slice(layer_cache["v"], vc, (0, cache_index, 0, 0))
-            k, v = ck, cv
+            if attn_kernel != "prefill":
+                # "prefill" (cfg.flash_prefill): the cache was empty, so the
+                # new block is all there is to attend to: k, v stay the block
+                # and the fused branch below reads them
+                k, v = ck, cv
             new_cache = {"k": ck, "v": cv}
 
         if cfg.prefix_tokens > 0:
@@ -393,7 +491,8 @@ class Attention(nn.Module):
                     axis=-1,
                 )
 
-        if fused_attention_ok(cfg, t) and layer_cache is None and attn_mask is not None:
+        if (fused_attention_ok(cfg, t) and attn_mask is not None
+                and (layer_cache is None or attn_kernel == "prefill")):
             # Fused training/scoring path: causal + key-padding structure is
             # computed inside the kernel from `attn_mask`; `attn_bias` is
             # ignored (it encodes exactly that structure, causal_bias below).
@@ -508,6 +607,128 @@ class MoEMLP(nn.Module):
         return jnp.einsum("bte,bted->btd", gates.astype(cfg.dtype), out)
 
 
+class ShortConv(nn.Module):
+    """Gated short convolution (LFM2's `conv` operator):
+
+        B, C, u = split(x W_in, 3);  z = B * u
+        c_t = sum_j w[j] * z_{t - (K-1) + j}      (depthwise, causal, K taps)
+        y = (C * c) W_out
+
+    no activation, no bias. A position whose mask bit is 0 is zeroed before
+    `W_in`, so its z is 0 and left padding reads as no history. Decode
+    state: the last K-1 values of z per channel, `layer_cache["conv"]`
+    [b, K-1, d]; a row whose step is masked keeps its state."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h, layer_cache=None, token_mask=None):
+        cfg = self.cfg
+        b, t, d = h.shape
+        taps = cfg.conv_kernel
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
+        # [K, d]: the fan-in (the taps) leads, like every `kernel`
+        w = self.param("kernel", nn.initializers.lecun_normal(), (taps, d), cfg.param_dtype)
+        w = w.astype(cfg.dtype)
+        if token_mask is not None:
+            h = h * token_mask[..., None].astype(h.dtype)
+        gate_b, gate_c, u = jnp.split(dense(3 * d, "in_proj")(h), 3, axis=-1)
+        z = gate_b * u
+        history = (jnp.zeros((b, taps - 1, d), z.dtype) if layer_cache is None
+                   else layer_cache["conv"].astype(z.dtype))
+        padded = jnp.concatenate([history, z], axis=1)  # [b, K-1+t, d]
+        conv = sum(w[j] * jax.lax.dynamic_slice_in_dim(padded, j, t, axis=1) for j in range(taps))
+        new_cache = None
+        if layer_cache is not None:
+            state = padded[:, t:].astype(layer_cache["conv"].dtype)  # the last K-1 of history + z
+            if t == 1 and token_mask is not None:
+                state = jnp.where(token_mask[:, :1, None] > 0, state, layer_cache["conv"])
+            new_cache = {"conv": state}
+        return dense(d, "out_proj")(gate_c * conv), new_cache
+
+
+class _Kernel(nn.Module):
+    """One `kernel` leaf under its own name: the expert stacks keep the
+    leaf name (and the fan-in-first shape) that every weight rule knows."""
+
+    shape: Tuple[int, ...]
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self):
+        init = lambda key, shape, dtype: (
+            jax.random.normal(key, shape, jnp.float32) / np.sqrt(shape[0])).astype(dtype)
+        return self.param("kernel", init, self.shape, self.param_dtype)
+
+
+class _Bias(nn.Module):
+    shape: Tuple[int, ...]
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self):
+        return self.param("bias", nn.initializers.zeros, self.shape, self.param_dtype)
+
+
+class SparseMoE(nn.Module):
+    """Sigmoid-routed experts with grouped dispatch (LFM2's expert ffn):
+
+        s = sigmoid(x W_r) in float32;  sel = top_k(s + b);  w = s[sel]
+        w /= sum(w) + 1e-6;  y = sum_{e in sel, e held here} w_e W2_e(act(W1_e x) * W3_e x)
+
+    `b` (`expert_bias/bias`) only steers the selection: load balancing
+    moves it, the gradient never does (stop_gradient in `route_sigmoid`,
+    frozen by `policy.trainable_mask`). The layer holds `cfg.experts_held` of the
+    model's `cfg.moe_experts`, scores all of them, and adds up what its own
+    experts give (ops/moe.py); nothing stands in for the absent ones.
+    A stack holds its experts' matrices side by side, `[fan_in, experts
+    held * fan_out]` (expert g is column block g): the kernels read a
+    block of it and write a block of its gradient where they lie, so no
+    step re-lays a stack. Positions whose mask bit is 0 are dispatched
+    nowhere and get 0."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h, token_mask=None):
+        from trlx_tpu.ops import moe
+
+        cfg = self.cfg
+        E, G, d, f = cfg.moe_experts, cfg.experts_held, cfg.d_model, cfg.expert_d_ff
+        b, t, _ = h.shape
+        router = _Kernel((d, E), cfg.param_dtype, name="router")()
+        bias = _Bias((E,), cfg.param_dtype, name="expert_bias")()
+        stack = lambda name, shape: _Kernel(shape, cfg.param_dtype, name=name)().astype(cfg.dtype)
+        out, stats = moe.sparse_moe(
+            h.reshape(b * t, d).astype(cfg.dtype), router, bias,
+            stack("expert_gate", (d, G * f)), stack("expert_up", (d, G * f)), stack("expert_down", (f, G * d)),
+            top_k=cfg.moe_top_k, offset=cfg.moe_local_offset, act=activation_fn(cfg),
+            token_mask=None if token_mask is None else token_mask.reshape(b * t),
+        )
+        self.sow("moe_stats", "stats", stats)
+        return out.reshape(b, t, d)
+
+
+def moe_stats_from_state(state) -> Dict[str, jnp.ndarray]:
+    """The dispatch counters every `SparseMoE` layer sowed during a
+    mutable=['moe_stats'] apply, reduced over layers: the mean share of
+    assignments that met an expert held here, the worst layer's most
+    loaded expert over the mean, the sum of dropped tokens (0 by
+    construction). Empty when nothing was sown."""
+    per_layer = [s for s in jax.tree_util.tree_leaves(
+        state.get("moe_stats", {}), is_leaf=lambda x: isinstance(x, dict) and "dropped_tokens" in x)
+        if isinstance(s, dict)]
+    if not per_layer:
+        return {}
+    stack = lambda name: jnp.stack([s[name] for s in per_layer])
+    return {
+        "local_assignment_share": stack("local_assignment_share").mean(),
+        "tokens_per_expert_max_over_mean": stack("tokens_per_expert_max_over_mean").max(),
+        "dropped_tokens": stack("dropped_tokens").sum(),
+    }
+
+
 def moe_aux_from_intermediates(state) -> jnp.ndarray:
     """Sum the moe_aux scalars sown by every MoEMLP during a
     mutable=['intermediates'] apply; 0 when nothing was sown."""
@@ -517,24 +738,37 @@ def moe_aux_from_intermediates(state) -> jnp.ndarray:
 
 class Block(nn.Module):
     cfg: TransformerConfig
+    # the layer's operator and ffn, for a model whose layers differ
+    # (`cfg.layer_op(i)` / `cfg.layer_ffn(i)`); the defaults are the one
+    # kind every other family has
+    op_kind: str = "attention"
+    ffn_kind: Optional[str] = None  # None: MoEMLP if cfg.moe_experts else MLP
 
     @nn.compact
     def __call__(self, h, attn_bias, positions, layer_cache=None, cache_index=None, attn_mask=None,
                  use_prefix=True, attn_kernel=None):
         cfg = self.cfg
         h_ln = make_norm(cfg, "ln_attn")(h)
-        attn_out, new_cache = Attention(cfg, name="attn")(
-            h_ln, attn_bias, positions, layer_cache, cache_index, attn_mask, use_prefix,
-            attn_kernel,
-        )
-        mlp_cls = MoEMLP if cfg.moe_experts > 0 else MLP
+        if self.op_kind == "conv":
+            attn_out, new_cache = ShortConv(cfg, name="conv")(h_ln, layer_cache, attn_mask)
+        else:
+            attn_out, new_cache = Attention(cfg, name="attn")(
+                h_ln, attn_bias, positions, layer_cache, cache_index, attn_mask, use_prefix,
+                attn_kernel,
+            )
+        if self.ffn_kind == "sparse_moe":
+            mlp = lambda x: SparseMoE(cfg, name="mlp")(x, attn_mask)
+        elif self.ffn_kind == "dense" or (self.ffn_kind is None and cfg.moe_experts <= 0):
+            mlp = MLP(cfg, name="mlp")
+        else:
+            mlp = MoEMLP(cfg, name="mlp")
         if cfg.parallel_residual:
             # GPT-NeoX: x + attn(ln1(x)) + mlp(ln2(x)); GPT-J shares ln1.
             mlp_in = h_ln if cfg.shared_ln else make_norm(cfg, "ln_mlp")(h)
-            h = h + attn_out + mlp_cls(cfg, name="mlp")(mlp_in)
+            h = h + attn_out + mlp(mlp_in)
         else:
             h = h + attn_out
-            h = h + mlp_cls(cfg, name="mlp")(make_norm(cfg, "ln_mlp")(h))
+            h = h + mlp(make_norm(cfg, "ln_mlp")(h))
         return h, new_cache
 
 
@@ -615,7 +849,10 @@ class TransformerLM(nn.Module):
         # use_prefix (arg 7 counting the module) and attn_kernel (arg 8)
         # are static python values
         block_cls = nn.remat(Block, static_argnums=(7, 8)) if cfg.remat_blocks else Block
-        self.blocks = [block_cls(cfg, name=f"block_{i}") for i in range(cfg.n_layers)]
+        self.blocks = [
+            block_cls(cfg, op_kind=cfg.layer_op(i), ffn_kind=cfg.layer_ffn(i), name=f"block_{i}")
+            for i in range(cfg.n_layers)
+        ]
         self.ln_f = make_norm(cfg, "ln_f")
         if not cfg.tie_embeddings:
             self.lm_head = nn.Dense(
@@ -941,11 +1178,18 @@ class TransformerLM(nn.Module):
             )
         else:
             h = self.embed(tokens, positions)
+        # the dense-cache attention never reads `attn_mask`; convolution
+        # state and sparse experts do (the per-row paths refuse such a model: `refuse_conv_state`)
+        step_mask = token_mask if self.cfg.blocks_read_token_mask else None
+        step_kernel = None
+        if (is_prefill and self.cfg.flash_prefill and self.cfg.prefix_tokens == 0 and P == 0
+                and fused_attention_ok(self.cfg, t)):
+            step_mask, step_kernel = token_mask, "prefill"
         if capture_split is None:
             h_cap = None
             h, new_layers = self.run_blocks(
                 h, bias, positions, 0, self.cfg.n_layers, cache=cache["layers"],
-                cache_index=index
+                cache_index=index, attn_mask=step_mask, attn_kernel=step_kernel
             )
         else:
             # split the block run so the activation entering block
@@ -953,12 +1197,12 @@ class TransformerLM(nn.Module):
             # so concatenating the two halves' new layers is exact
             h, low = self.run_blocks(
                 h, bias, positions, 0, capture_split, cache=cache["layers"],
-                cache_index=index
+                cache_index=index, attn_mask=step_mask, attn_kernel=step_kernel
             )
             h_cap = h
             h, high = self.run_blocks(
                 h, bias, positions, capture_split, self.cfg.n_layers,
-                cache=cache["layers"], cache_index=index
+                cache=cache["layers"], cache_index=index, attn_mask=step_mask, attn_kernel=step_kernel
             )
             new_layers = low + high
         logits, h = self.unembed(h[:, P:] if P > 0 else h)
@@ -993,6 +1237,7 @@ class TransformerLM(nn.Module):
             raise NotImplementedError(
                 "slot-pool decode under prompt/prefix tuning is unsupported"
             )
+        refuse_conv_state(self.cfg, "decode_step_rows")
         b, _ = tokens.shape
         row_index = cache["row_index"]
         positions = cache["pos"][:, None]
@@ -1048,6 +1293,7 @@ class TransformerLM(nn.Module):
             raise NotImplementedError(
                 "slot-pool prefill under prompt/prefix tuning is unsupported"
             )
+        refuse_conv_state(self.cfg, "prefill_rows")
         b, t = tokens.shape
         row_index = cache["row_index"]
         lens = token_mask.sum(-1).astype(jnp.int32)
@@ -1107,6 +1353,7 @@ class TransformerLM(nn.Module):
             raise NotImplementedError(
                 "speculative decode under prompt/prefix tuning is unsupported"
             )
+        refuse_conv_state(self.cfg, "spec_draft_step")
         b, _ = tokens.shape
         row_index = cache["row_index"]
         positions = cache["pos"][:, None]
@@ -1153,6 +1400,7 @@ class TransformerLM(nn.Module):
         (doubly-forbidden columns go to -2e9, still exactly 0 after
         softmax). Returns (logits, h_final, new_layers) where new_layers
         is the full per-layer cache list (trunk entries passed through)."""
+        refuse_conv_state(self.cfg, "spec_verify_rows")
         b, t, _ = h.shape
         new_mask = cache["mask"]
         positions_f = positions.astype(jnp.int32)
@@ -1182,24 +1430,40 @@ def position_ids(attn_mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.clip(jnp.cumsum(attn_mask.astype(jnp.int32), axis=-1) - 1, 0, None)
 
 
+def refuse_conv_state(cfg: TransformerConfig, what: str) -> None:
+    """The per-row cache (slot pool, paged arena, speculative rollback)
+    holds K/V tables only: a convolution state is a second kind of
+    per-slot state that it can neither place by row offset nor roll back
+    by clearing mask bits."""
+    if getattr(cfg, "has_conv_layers", False):
+        raise NotImplementedError(
+            f"{what}: the convolution state of a `conv` layer (layer_types) is not "
+            "supported here; only `decode_step` (the fused sampler) carries it"
+        )
+
+
 def init_kv_cache(cfg: TransformerConfig, batch_size: int, max_len: int, dtype=None):
-    """Allocate an empty functional KV cache. Under prompt tuning the soft
-    prompt occupies the first cfg.prompt_tokens cache slots (written by the
-    prefill), so the cache is allocated that much longer."""
+    """Allocate an empty functional cache: K/V tables for an attention
+    layer, the last `conv_kernel - 1` inputs of the convolution for a
+    `conv` layer. Under prompt tuning the soft prompt occupies the first
+    cfg.prompt_tokens cache slots (written by the prefill), so the cache is
+    allocated that much longer."""
     dtype = dtype or cfg.dtype
     max_len = max_len + getattr(cfg, "prompt_tokens", 0)
-    layers = [
-        {
+
+    def layer(i):
+        if cfg.layer_op(i) == "conv":
+            return {"conv": jnp.zeros((batch_size, cfg.conv_kernel - 1, cfg.d_model), dtype=dtype)}
+        return {
             "k": jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), dtype=dtype),
             "v": jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), dtype=dtype),
         }
-        for _ in range(cfg.n_layers)
-    ]
+
     return {
         "index": jnp.asarray(0, dtype=jnp.int32),
         "mask": jnp.zeros((batch_size, max_len), dtype=jnp.int32),
         "pos": jnp.zeros((batch_size,), dtype=jnp.int32),
-        "layers": layers,
+        "layers": [layer(i) for i in range(cfg.n_layers)],
     }
 
 
@@ -1217,6 +1481,7 @@ def init_paged_kv_arena(
         raise NotImplementedError(
             "paged KV cache under prompt/prefix tuning is unsupported"
         )
+    refuse_conv_state(cfg, "paged KV arena")
     from trlx_tpu.ops.paged_attention import init_paged_layer
 
     return [
@@ -1300,6 +1565,27 @@ PRESETS: Dict[str, Dict[str, Any]] = {
     "bigcode-tiny": dict(
         d_model=64, n_layers=2, n_heads=4, n_kv_heads=1, d_ff=256, max_seq_len=256,
     ),
+    # LFM2-8B-A1B (LiquidAI, `lfm2_moe`): gated short convolutions and GQA
+    # attention with QK-norm in one stack, 2 leading dense layers, then 32
+    # sigmoid-routed experts (4 a token) of width 1792. The published sizes;
+    # a cut (depth, experts held here, vocabulary) arrives as
+    # model_extra_configs (`n_layers` cuts `layer_types` to its first n).
+    "lfm2-8b-a1b": dict(
+        d_model=2048, n_layers=24, n_heads=32, n_kv_heads=8, d_ff=7168, max_seq_len=128000,
+        pos_embed="rope", rope_theta=1e6, norm="rmsnorm", activation="silu", glu=True,
+        use_bias=False, qk_norm=True, conv_kernel=3, flash_prefill=True,
+        layer_types=tuple("attention" if i in (2, 6, 10, 14, 18, 21) else "conv" for i in range(24)),
+        moe_experts=32, moe_top_k=4, moe_d_ff=1792, moe_dense_layers=2, moe_router="sigmoid",
+    ),
+    # the same stack at test size: one period behind the dense layers, 4
+    # experts (2 a token)
+    "lfm2-tiny": dict(
+        d_model=64, n_layers=6, n_heads=4, n_kv_heads=2, d_ff=128, max_seq_len=256,
+        pos_embed="rope", rope_theta=1e6, norm="rmsnorm", activation="silu", glu=True,
+        use_bias=False, qk_norm=True, conv_kernel=3, flash_prefill=True,
+        layer_types=("conv", "conv", "attention", "conv", "conv", "conv"),
+        moe_experts=4, moe_top_k=2, moe_d_ff=32, moe_dense_layers=2, moe_router="sigmoid",
+    ),
     # Mixture-of-experts (beyond the reference): experts shard over `tensor`
     "moe-tiny": dict(
         d_model=64, n_layers=2, n_heads=4, d_ff=256, max_seq_len=256,
@@ -1313,4 +1599,7 @@ def config_from_preset(name: str, vocab_size: int, **overrides) -> TransformerCo
         raise ValueError(f"Unknown model preset '{name}'. Available: {sorted(PRESETS)}")
     kwargs = dict(PRESETS[name])
     kwargs.update(overrides)
+    if kwargs.get("layer_types") and "layer_types" not in overrides:
+        # a cut in depth keeps the leading layers of the published pattern
+        kwargs["layer_types"] = tuple(kwargs["layer_types"])[: kwargs["n_layers"]]
     return TransformerConfig(vocab_size=vocab_size, **kwargs)

@@ -33,6 +33,27 @@ def chip_peak_flops() -> float:
     raise LookupError(f"no peak-FLOP/s row for device kind {kind!r}")
 
 
+def layer_matmul_flops(model_cfg, i: int) -> float:
+    """Matmul FLOPs a token of layer i's forward. A config that names no
+    layer kinds keeps the family-blind estimate it always had (8 d^2 + 4 d
+    d_ff). One that does is counted as built: GQA projections or the short
+    convolution's in/out projections and taps; a dense (gated) MLP, or the
+    router plus the experts a token meets HERE: top_k x held / experts of
+    width moe_d_ff (the active experts, not the resident ones)."""
+    d = model_cfg.d_model
+    if not getattr(model_cfg, "layer_types", ()):
+        return 8 * d * d + 4 * d * model_cfg.d_ff
+    if model_cfg.layer_op(i) == "conv":
+        op = 2 * d * 3 * d + 2 * d * d + 2 * model_cfg.conv_kernel * d
+    else:
+        op = 4 * d * d + 4 * d * model_cfg.kv_heads * model_cfg.head_dim
+    mats = 3 if model_cfg.glu else 2
+    if model_cfg.layer_ffn(i) == "dense":
+        return op + 2 * mats * d * model_cfg.d_ff
+    active = model_cfg.moe_top_k * model_cfg.experts_held / model_cfg.moe_experts
+    return op + 2 * d * model_cfg.moe_experts + active * 2 * mats * d * model_cfg.expert_d_ff
+
+
 def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
                     unfrozen, window_ok: bool = True,
                     fast_path: bool = False,
@@ -46,6 +67,9 @@ def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
       L*(8 d^2 + 4 d d_ff)   block matmuls (qkvo 2*4d^2 + mlp 2*2*d*d_ff)
       + L*4*c*d              attention scores + prob@V
       + 2 d V                lm_head logits
+    For a model whose layers differ (`layer_types`, experts) the block term
+    is summed layer by layer (`layer_matmul_flops`) and the attention term
+    counts the attention layers only.
     Backward stops at the freeze split (grads are taken w.r.t. the
     trainable partition only, base_trainer.py grad_fn; XLA prunes below):
     dX through the lm_head matmul + the `unfrozen` top blocks, plus dW
@@ -53,14 +77,17 @@ def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
     contributes dX but no dW). Generation decode counts the lm_head every
     step and prefill counts it on all prompt positions (that is what the
     engine computes)."""
-    d, L, dff, V = (model_cfg.d_model, model_cfg.n_layers,
-                    model_cfg.d_ff, model_cfg.vocab_size)
+    d, L, V = model_cfg.d_model, model_cfg.n_layers, model_cfg.vocab_size
     T = n_prompt + n_new
-    blk = 8 * d * d + 4 * d * dff
     head = 2 * d * V
+    per_layer = [layer_matmul_flops(model_cfg, i) for i in range(L)]
+    is_attn = [getattr(model_cfg, "layer_op", lambda i: "attention")(i) == "attention" for i in range(L)]
 
-    def fwd(tokens, avg_ctx, layers=L, with_head=True):
-        return tokens * (layers * blk + layers * 4 * avg_ctx * d
+    def fwd(tokens, avg_ctx, layers=L, with_head=True, top=True):
+        """`layers` of the stack: its top ones (the unfrozen suffix), or
+        with `top=False` its bottom ones (the frozen trunk)."""
+        lo, hi = (L - layers, L) if top else (0, layers)
+        return tokens * (sum(per_layer[lo:hi]) + sum(is_attn[lo:hi]) * 4 * avg_ctx * d
                          + (head if with_head else 0))
 
     # generation: prefill the prompt, then n_new cached decode steps
@@ -76,8 +103,8 @@ def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
         # instead of silently flattering the denominator.
         ctx = n_prompt + n_new / 2
         split_L = max(L - unfrozen, 1)
-        trunk_step = split_L * blk + split_L * 4 * ctx * d
-        suffix_pos = unfrozen * blk + unfrozen * 4 * ctx * d + head
+        trunk_step = fwd(1, ctx, layers=split_L, with_head=False, top=False)
+        suffix_pos = fwd(1, ctx, layers=unfrozen)
         draft_head = 2 * d * spec_rank + 2 * spec_rank * V
         per_round = ((spec_k + 1) * trunk_step + spec_k * draft_head
                      + (spec_k + 1) * suffix_pos)
@@ -102,7 +129,7 @@ def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
         # trunk cache on the classic schedule: ONE extra frozen-prefix pass
         # per chunk fills the cache (on the fast schedule the sampler's
         # in-loop capture makes it free — already counted under gen)
-        score = score + fwd(T, T / 2, layers=L - unfrozen, with_head=False)
+        score = score + fwd(T, T / 2, layers=L - unfrozen, with_head=False, top=False)
     # one train step: the trunk runs full-width fwd + dX/dW over the
     # unfrozen top. When the r5 windowed head applies (ppo_trainer
     # forward_window — no MoE, no deeper value branch, no soft prompt),

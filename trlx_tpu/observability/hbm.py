@@ -126,6 +126,18 @@ def kv_cache_bytes(n_layers: int, kv_heads: int, head_dim: int,
                * _itemsize(dtype))
 
 
+def decode_state_bytes(cfg, batch: int, cache_len: int, dtype="float32") -> int:
+    """The fused sampler's cache for `cfg` (`init_kv_cache`): K/V tables for
+    the attention layers only, plus the short convolution's state, the last
+    `conv_kernel - 1` inputs a channel, for each `conv` layer."""
+    kinds = list(getattr(cfg, "layer_types", ())) or ["attention"] * cfg.n_layers
+    kv = kv_cache_bytes(kinds.count("attention"), cfg.kv_heads, cfg.head_dim, batch, cache_len, dtype)
+    if "conv" not in kinds:
+        return int(kv)
+    conv = kinds.count("conv") * batch * (cfg.conv_kernel - 1) * cfg.d_model * _itemsize(dtype)
+    return int(kv + conv)
+
+
 def trunk_cache_bytes(rows: int, seq_len: int, d_model: int,
                       dtype="float32") -> int:
     """Frozen-trunk activation cache: one `[rows, seq_len, d_model]`
@@ -153,8 +165,7 @@ def analytic_train_components(
     (`compiled.memory_analysis()`), not modeled here."""
     kv = 0
     if rollout_rows and seq_length:
-        kv = kv_cache_bytes(cfg.n_layers, cfg.kv_heads, cfg.head_dim,
-                            rollout_rows, seq_length, kv_dtype)
+        kv = decode_state_bytes(cfg, rollout_rows, seq_length, kv_dtype)
     out = {
         "params_bytes": params_bytes(n_params, param_dtype_bytes),
         "optimizer_bytes": optimizer_bytes(n_trainable, 4),

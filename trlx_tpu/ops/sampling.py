@@ -212,6 +212,12 @@ def make_generate_fn(
                 "supported (the seen-token mask would need per-draft "
                 "rollback)"
             )
+        if getattr(model_cfg, "has_conv_layers", False):
+            raise NotImplementedError(
+                "speculative decode with a convolution state (layer_types "
+                "'conv') is not supported: rejected drafts roll back by "
+                "clearing mask bits, which does not undo a recurrent state"
+            )
         if getattr(model_cfg, "moe_experts", 0) > 0:
             raise NotImplementedError(
                 "speculative decode with MoE blocks is not supported "

@@ -146,6 +146,11 @@ def stack_block_params(params: Dict, n_layers: int, n_stages: int) -> Tuple[Dict
         raise ValueError(f"n_layers={n_layers} not divisible by n_stages={n_stages}")
     inner = params["params"] if "params" in params else params
     blocks = [inner[f"block_{i}"] for i in range(n_layers)]
+    if len({jax.tree_util.tree_structure(b) for b in blocks}) > 1:
+        raise NotImplementedError(
+            "pipeline stages scan ONE block over stacked layers; a model whose layers differ "
+            "(layer_types, leading dense layers before experts) cannot be stacked"
+        )
     stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *blocks)
     lps = n_layers // n_stages
     stacked = jax.tree_util.tree_map(

@@ -112,6 +112,12 @@ GPT_RULES = ShardingRules(
         # MoE: expert dim over `tensor` (expert parallelism); router
         # replicated so every device can gate every token.
         (r"mlp/router/kernel", (None, None)),
+        # SparseMoE stacks [fan_in, experts held x fan_out]: the experts a
+        # process holds are its own (cfg.moe_local_experts), so a stack is
+        # ZeRO-sharded over its fan-in and never by expert (the exchange
+        # across chips is not built: ROADMAP)
+        (r"mlp/expert_(gate|up|down)/kernel", ("fsdp", None)),
+        (r"mlp/expert_bias/bias", (None,)),
         (r"mlp/(up_proj|gate_proj)$", ("tensor", "fsdp", None)),
         (r"mlp/down_proj$", ("tensor", None, "fsdp")),
         (r"mlp/(up_bias|down_bias)$", ("tensor", None)),

@@ -230,7 +230,7 @@ class PPOTrainer(TPUTrainer):
             self.model_cfg, n_prompt, n_new,
             unfrozen=self.model_cfg.n_layers - self.split,
             window_ok=(self._window_loss_ok()
-                       and getattr(self.model_cfg, "moe_experts", 0) == 0),
+                       and not getattr(self.model_cfg, "sows_moe_aux", False)),
             fast_path=False,  # make_experience scores with the full fwd
             trunk_cache=self._trunk_cache_available(),
             spec_k=spec_k, spec_accept=accept,
@@ -293,6 +293,8 @@ class PPOTrainer(TPUTrainer):
 
             return seq2seq_loss_fn
 
+        sparse_moe = getattr(self.model_cfg, "has_sparse_moe", False)
+
         def loss_fn(train_params, frozen_params, batch: PPORLBatch):
             params = merge_params(train_params, frozen_params)
             query_tensors = batch.query_tensors
@@ -323,7 +325,7 @@ class PPOTrainer(TPUTrainer):
                 lp = logprobs_of_labels(logits[:, :-1, :], tokens[:, 1:])
                 return lp[:, start:end], values_full[:, :-1][:, start:end]
 
-            moe_aux = 0.0
+            moe_aux, moe_stats = 0.0, {}
             if batch.h_split is not None:
                 # Trunk-cache train path (method.cache_trunk_activations):
                 # resume the trainable suffix from the per-chunk cached
@@ -357,7 +359,7 @@ class PPOTrainer(TPUTrainer):
                         method=type(model).forward_from_cache,
                     )
                     logprobs, values_pred = window_from_full(logits, values_full)
-            elif getattr(self.model_cfg, "moe_experts", 0) > 0:
+            elif getattr(self.model_cfg, "sows_moe_aux", False):
                 from trlx_tpu.utils.modeling import apply_with_moe_aux
 
                 (logits, values_full, _), moe_aux = apply_with_moe_aux(
@@ -372,11 +374,18 @@ class PPOTrainer(TPUTrainer):
                 # slice, and the full-width head was the cycle's largest
                 # wasted matmul (tests/test_trainers.py pins equality with
                 # the full-forward loss)
-                logits_w, values_pred = model.apply(
+                out = model.apply(
                     {"params": params}, tokens, attention_mask, positions,
                     start, response_length,
                     method=type(model).forward_window,
+                    **({"mutable": ["moe_stats"]} if sparse_moe else {}),
                 )
+                if sparse_moe:  # SparseMoE layers sow their dispatch counters
+                    from trlx_tpu.models.transformer import moe_stats_from_state
+
+                    out, sown = out
+                    moe_stats = moe_stats_from_state(sown)
+                logits_w, values_pred = out
                 logprobs = logprobs_of_labels(
                     logits_w, tokens[:, start + 1:end + 1]
                 )
@@ -398,7 +407,10 @@ class PPOTrainer(TPUTrainer):
                 cliprange_value=method.cliprange_value,
                 vf_coef=method.vf_coef,
             )
-            if getattr(self.model_cfg, "moe_experts", 0) > 0:
+            if moe_stats:
+                # SparseMoE's dispatch counters (docs/observability.md)
+                stats = {**stats, "moe": jax.lax.stop_gradient(moe_stats)}
+            if getattr(self.model_cfg, "sows_moe_aux", False):
                 # the logged total must be the optimized objective
                 loss = loss + moe_aux
                 stats = {
@@ -1681,6 +1693,7 @@ class PPOTrainer(TPUTrainer):
             not self.seq2seq
             and self.split > 0
             and getattr(self.model_cfg, "moe_experts", 0) == 0
+            and not getattr(self.model_cfg, "has_conv_layers", False)
             and getattr(self.model_cfg, "prompt_tokens", 0) == 0
             and getattr(self.model_cfg, "prefix_tokens", 0) == 0
             and int(gen_kwargs.get("num_beams", 1) or 1) == 1
@@ -1765,7 +1778,7 @@ class PPOTrainer(TPUTrainer):
         return (
             not self.seq2seq
             and self.split > 0
-            and getattr(self.model_cfg, "moe_experts", 0) == 0
+            and not getattr(self.model_cfg, "sows_moe_aux", False)
             and self.model_cfg.n_layers - n_value >= self.split
         )
 

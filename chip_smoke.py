@@ -395,39 +395,57 @@ def kernel_phase(args, trainer):
         flash_backward_parity()
 
     # paged decode vs the gather reference: GPT-2 small's shape (group 1)
-    # and the one causal preset family with group > 1, bf16 and int8 arenas
+    # and the one causal preset family with group > 1, bf16 and int8 arenas.
+    # Ragged rows over a table of three tiles: a single token, block edges,
+    # the whole table, a mask with holes, an all-masked row. Table slack
+    # past a row's live entries names ids beyond the arena, and every block
+    # no live entry names (the zero block too) is poison in the kernel's
+    # copy of the arena: a dead entry that was fetched would show as NaN.
     shapes = {name: config_from_preset(name, vocab_size=50257)
               for name in ("gpt2-small", "llama-tiny")}
     rng = np.random.default_rng(1)
-    b, blk, n_tbl = 8, 32, 6
+    b, blk, n_tbl = 8, 32, 20
     n_blocks = b * n_tbl + 1
+    lens = np.array([1, blk - 1, blk, blk + 1, 9 * blk + 3, n_tbl * blk, 0, 150])
+    n_live = -(-lens // blk)
+    mask = (np.arange(n_tbl * blk)[None, :] < lens[:, None]).astype(np.int32)
+    mask[4, [2, blk, 5 * blk + 7]] = 0
+    ids = 1 + rng.permutation(n_blocks - 1)
+    table = np.full((b, n_tbl), n_blocks + 3, np.int32)
+    for r in range(b):
+        table[r, :n_live[r]] = ids[r * n_tbl:r * n_tbl + n_live[r]]
+    dead = np.setdiff1d(np.arange(n_blocks), table[table < n_blocks])
+    table_ref = jnp.asarray(np.where(table < n_blocks, table, 0))
+    table, mask = jnp.asarray(table), jnp.asarray(mask)
     for name, cfg in shapes.items():
         nh, nkv, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         q = jnp.asarray(rng.standard_normal((b, nh, hd)), jnp.bfloat16)
         ka = jnp.asarray(rng.standard_normal((n_blocks, nkv, blk, hd)), jnp.bfloat16)
         va = jnp.asarray(rng.standard_normal((n_blocks, nkv, blk, hd)), jnp.bfloat16)
         ka, va = ka.at[0].set(0), va.at[0].set(0)
-        table = jnp.asarray(rng.permutation(n_blocks - 1)[: b * n_tbl].reshape(b, n_tbl) + 1,
-                            jnp.int32)
-        lens = jnp.asarray([1, blk - 1, blk, blk + 1, 3 * blk, n_tbl * blk, 77, 150])
-        mask = (jnp.arange(n_tbl * blk)[None, :] < lens[:, None]).astype(jnp.int32)
         for dtype in ("bf16", "int8"):
-            kw = {}
+            kw, kw_poison = {}, {}
             k_in, v_in = ka, va
+            k_poison, v_poison = ka.at[dead].set(jnp.nan), va.at[dead].set(jnp.nan)
             if dtype == "int8":
                 k_in, ks = quant.quantize_kv(ka)
                 v_in, vs = quant.quantize_kv(va)
                 kw = dict(k_scale=ks.reshape(n_blocks, 1, -1),
                           v_scale=vs.reshape(n_blocks, 1, -1))
-            out = jax.jit(lambda *a, kw=kw: paged_attention_decode(
-                *a, interpret=args.rehearse_cpu, **kw))(q, k_in, v_in, table, mask)
+                k_poison, v_poison = k_in.at[dead].set(127), v_in.at[dead].set(127)
+                kw_poison = {key: plane.at[dead].set(jnp.nan) for key, plane in kw.items()}
+            out = jax.jit(lambda *a, kw=kw_poison: paged_attention_decode(
+                *a, interpret=args.rehearse_cpu, **kw))(q, k_poison, v_poison, table, mask)
             ref = jax.jit(lambda *a, kw=kw: paged_attention_reference(
-                *a, **kw))(q, k_in, v_in, table, mask)
+                *a, **kw))(q, k_in, v_in, table_ref, mask)
             out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
-            check(bool(np.isfinite(out).all()), f"paged {name}/{dtype}: non-finite output")
-            dev = float(np.abs(out - ref).max())
+            check(bool(np.isfinite(out).all()),
+                  f"paged {name}/{dtype}: non-finite output (a dead table entry was read)")
+            check(bool((out[lens == 0] == 0.0).all()), f"paged {name}/{dtype}: all-masked row not 0.0")
+            dev = float(np.abs(out - ref)[lens > 0].max())
             log(f"kernels: paged decode {name} (heads {nh}/{nkv} x {hd}, group "
-                f"{nh // nkv}) {dtype}: max|dev| {dev:.2e} (bound {paged_tol:.0e})")
+                f"{nh // nkv}) {dtype}, {int(n_live.sum())} live of {b * n_tbl} table entries: "
+                f"max|dev| {dev:.2e} (bound {paged_tol:.0e})")
             check(dev < paged_tol, f"paged {name}/{dtype} parity {dev} >= {paged_tol}")
 
 

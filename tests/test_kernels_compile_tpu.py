@@ -21,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 pytest.importorskip("libtpu", reason="AOT compilation for the TPU needs libtpu")
 
-from trlx_tpu.ops import attention, fused_ce  # noqa: E402
+from trlx_tpu.ops import attention, fused_ce, paged_attention  # noqa: E402
 from trlx_tpu.ops.paged_attention import (  # noqa: E402
     init_paged_layer,
     paged_attention_decode,
@@ -192,6 +192,38 @@ def abstract(tree, sharding):
 CELL = dict(n_blocks=1280, nkv=16, blk=32, hd=128, slots=64, n_tbl=20)
 
 
+# the cell's own call, and the open chat cell's, 44 table entries a slot
+# at `max_prompt_len` 1024 (PERF.md section 7)
+@pytest.mark.parametrize("n_tbl", [CELL["n_tbl"], 44], ids=["cell", "chat"])
+def test_paged_decode_fits_its_vmem_budget_at_the_cell_and_chat_shapes(v5e, n_tbl, monkeypatch):
+    """64 rows over 1,280 blocks of 32, 16 heads of 128, bfloat16: one
+    Mosaic call named `paged_decode`, the arena read where it lies, tiles
+    of 8 entries, and VMEM inside the budget the module docstring states.
+    The call carries `vmem_limit_bytes` and Mosaic refuses a kernel that
+    needs more: the cell's 4 MiB of tile buffers and the rest fit half the
+    12 MiB it is compiled under, and a quarter is refused, which shows the
+    limit is enforced and not merely stated."""
+    n_blocks, nkv, blk, hd, b = (CELL[k] for k in ("n_blocks", "nkv", "blk", "hd", "slots"))
+    one = SingleDeviceSharding(v5e[0])
+    arena = S((n_blocks, nkv, blk, hd), BF16)
+    args = (S((b, nkv, hd), BF16), arena, arena, S((b, n_tbl), I32), S((b, n_tbl * blk), I32))
+    assert paged_attention._tile_entries(n_tbl, nkv, blk, hd, BF16) == 8
+    assert paged_attention._VMEM_LIMIT_BYTES == 12 * 2 ** 20
+    assert 4 * 8 * paged_attention._vmem_block_bytes(nkv, blk, hd, BF16) == 4 * 2 ** 20
+
+    def compile_under(limit):
+        monkeypatch.setattr(paged_attention, "_VMEM_LIMIT_BYTES", limit)
+        # a fresh function object: jit's trace cache would hand back the last limit's program
+        return compile_for(lambda *a: paged_attention_decode(*a), args, one)
+
+    compiled = compile_under(12 * 2 ** 20)
+    assert kernel_names(compiled) == ["paged_decode"]
+    assert arena_rewrites(compiled, arena) == []
+    assert mosaic_calls(compile_under(6 * 2 ** 20)) == 1
+    with pytest.raises(Exception, match="vmem"):
+        compile_under(3 * 2 ** 20)
+
+
 @pytest.mark.parametrize("dtype", [BF16, I8], ids=["bf16", "int8"])
 @pytest.mark.parametrize("form", ["decode", "prefill"])
 def test_paged_write_and_read_leave_the_arena_where_it_lies(v5e, form, dtype):
@@ -280,6 +312,19 @@ def test_engine_programs_leave_the_arena_where_it_lies(v5e, cell_engine, program
         ).lower(lowering_platforms=("tpu",)).compile()
     n_layers = len(engine._pool["layers"])
     assert mosaic_calls(compiled) == (n_layers if program == "decode" else 0)
+    if program == "decode":
+        # one Pallas call a layer under the name the roofline's reader looks
+        # for; the walk they share (`_live_schedule`: table and mask are the
+        # step's, not a layer's) is computed once a step, not once a layer
+        assert kernel_names(compiled) == ["paged_decode"] * n_layers
+        scans = lambda c: c.as_text().count(" reduce-window(")  # noqa: E731  (cumsum, cummax)
+        arena = S(engine._pool["layers"][0]["k"].shape, BF16)
+        alone = compile_for(
+            lambda *a: paged_attention_decode(*a),
+            (S((CELL["slots"], CELL["nkv"], CELL["hd"]), BF16), arena, arena,
+             S((CELL["slots"], CELL["n_tbl"]), I32), S((CELL["slots"], CELL["n_tbl"] * CELL["blk"]), I32)),
+            one)
+        assert 0 < scans(alone) == scans(compiled)
     arenas = [a for layer in engine._pool["layers"] for a in layer.values()]
     assert arena_rewrites(compiled, *arenas) == []
     assert donated_outputs(compiled) >= len(arenas)

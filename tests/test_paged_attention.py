@@ -15,6 +15,8 @@ from trlx_tpu.inference import InferenceEngine
 from trlx_tpu.ops import quant
 from trlx_tpu.ops.attention import kernel_mode
 from trlx_tpu.ops.paged_attention import (
+    _live_schedule,
+    _tile_entries,
     paged_attention_decode,
     paged_attention_reference,
     paged_kv_write,
@@ -252,6 +254,128 @@ def test_kernel_requires_scales_for_int8():
 
 
 # ----------------------------------------------------------------------
+# The length-aware walk: ragged rows, tiles of several entries, slack
+# ----------------------------------------------------------------------
+
+N_TBL_RAGGED = 11  # a tile is 8 entries: the second tile is partial
+
+
+def _ragged_case(rng, group, dtype, blk, nkv=2, hd=16, n_blocks=64):
+    """One batch with every kind of row: a single token, exactly one
+    block, one past a block boundary, the whole table, all-masked, live
+    entries that are no multiple of the tile, and a mask with a hole
+    inside a live entry. Every live entry names a block of its own; table
+    slack past a row's live entries names an id >= n_blocks."""
+    n_tbl = N_TBL_RAGGED
+    lens = np.array([1, blk, blk + 1, n_tbl * blk, 0, 9 * blk + 3, 3 * blk - 2])
+    b, nh = len(lens), nkv * group
+    qdtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
+    q = jnp.asarray(rng.randn(b, nh, hd), qdtype)
+    arenas = [jnp.asarray(rng.randn(n_blocks, nkv, blk, hd), jnp.float32) for _ in range(2)]
+    mask = (np.arange(n_tbl * blk)[None, :] < lens[:, None]).astype(np.int32)
+    mask[5, [2, blk + 1, 4 * blk]] = 0  # holes, one at a block's first column
+    mask[6, blk:2 * blk] = 0            # a whole entry masked below the last live one
+    n_live = -(-lens // blk)
+    ids = 1 + rng.permutation(n_blocks - 1)
+    table = np.full((b, n_tbl), n_blocks, np.int32) + rng.randint(0, 5, (b, n_tbl))
+    at = 0
+    for r in range(b):
+        table[r, :n_live[r]] = ids[at:at + n_live[r]]
+        at += n_live[r]
+    scales = {}
+    if dtype == "int8":
+        (ka, scales["k_scale"]), (va, scales["v_scale"]) = map(_quantize_arena, arenas)
+    else:
+        ka, va = (a.astype(jnp.dtype(dtype)) for a in arenas)
+    return q, ka, va, jnp.asarray(table), jnp.asarray(mask), scales, n_live
+
+
+RAGGED = [(g, d, blk) for g in (1, 2, 4) for d in ("bfloat16", "float32", "int8") for blk in (16, 32)]
+
+
+@pytest.mark.parametrize("group,dtype,blk", RAGGED, ids=lambda v: str(v))
+def test_kernel_matches_reference_on_ragged_rows(group, dtype, blk):
+    rng = np.random.RandomState(RAGGED.index((group, dtype, blk)))
+    q, ka, va, table, mask, scales, n_live = _ragged_case(rng, group, dtype, blk)
+    assert _tile_entries(N_TBL_RAGGED, ka.shape[1], blk, ka.shape[3], ka.dtype) == 8
+    out_k = np.asarray(paged_attention_decode(
+        q, ka, va, table, mask, interpret=True, **scales).astype(jnp.float32))
+    # the reference gathers through the table: give slack the zero block
+    n_blocks = ka.shape[0]
+    out_r = np.asarray(paged_attention_reference(
+        q, ka, va, jnp.where(table < n_blocks, table, 0), mask, **scales).astype(jnp.float32))
+    active = n_live > 0
+    # bfloat16: both sides round the probabilities and the output to it
+    tol = 2e-2 if dtype == "bfloat16" else 1e-5
+    np.testing.assert_allclose(out_k[active], out_r[active], rtol=tol, atol=tol)
+    assert (out_k[~active] == 0.0).all()
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int8"])
+def test_table_slack_is_never_dereferenced(dtype):
+    """Everything a live entry does not name is poison: the zero block,
+    the blocks no row holds, the scale planes' rows of both, and table
+    slack names ids past the arena (the interpreter would clamp such an
+    id onto the last block, poisoned too). The output must not change by
+    a bit: a fetched dead entry would put 0 x NaN into p.v."""
+    rng = np.random.RandomState(7)
+    q, ka, va, table, mask, scales, n_live = _ragged_case(rng, 2, dtype, 16)
+    clean = paged_attention_decode(q, ka, va, table, mask, interpret=True, **scales)
+    live_ids = np.concatenate([np.asarray(table)[r, :n] for r, n in enumerate(n_live)])
+    dead = np.setdiff1d(np.arange(ka.shape[0]), live_ids)
+    assert 0 in dead and ka.shape[0] - 1 in dead
+    if dtype == "int8":
+        poison = lambda a: a.at[dead].set(127)  # noqa: E731
+        scales = {k: v.at[dead].set(jnp.nan) for k, v in scales.items()}
+    else:
+        poison = lambda a: a.at[dead].set(jnp.nan)  # noqa: E731
+    got = paged_attention_decode(q, poison(ka), poison(va), table, mask, interpret=True, **scales)
+    assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
+    np.testing.assert_array_equal(_bits(got), _bits(clean))
+
+
+@pytest.mark.parametrize("entries", [1, 4, 8])
+def test_live_schedule_walks_live_entries_only(entries):
+    """The grid's walk from the table and the mask: `n_live` per row, one
+    step a live tile (one for an all-masked row, to write its zeros), and
+    block ids of live entries only, each operand keeping what it held
+    where its entry is dead."""
+    rng = np.random.RandomState(3)
+    blk = 16
+    _, ka, _, table, mask, _, n_live = _ragged_case(rng, 1, "float32", blk)
+    blocks, row, tile, got_live, n_work, n_tiles = jax.jit(
+        _live_schedule, static_argnums=(2, 3))(table, mask, blk, entries)
+    np.testing.assert_array_equal(got_live, n_live)
+    steps = np.maximum(1, -(-n_live // entries))
+    assert int(n_work) == steps.sum() and n_tiles == -(-N_TBL_RAGGED // entries)
+    row, tile = np.asarray(row)[:int(n_work)], np.asarray(tile)[:int(n_work)]
+    np.testing.assert_array_equal(row, np.repeat(np.arange(len(n_live)), steps))
+    np.testing.assert_array_equal(tile, np.concatenate([np.arange(n) for n in steps]))
+    blocks = np.asarray(blocks).reshape(-1, entries)[:int(n_work)]
+    table = np.asarray(table)
+    assert (blocks < ka.shape[0]).all(), "an id past the arena would be fetched"
+    held = np.full(entries, table[0, 0])  # before its first live step: the call's first live block
+    for w, (r, t) in enumerate(zip(row, tile)):
+        for e in range(entries):
+            if t * entries + e < n_live[r]:
+                held[e] = table[r, t * entries + e]
+            assert blocks[w, e] == held[e], (w, e)
+
+
+def test_tile_follows_the_shapes():
+    """Tile entries adapt to block size, table length and VMEM: 256
+    tokens a step where the table and eight operands a side allow it."""
+    bf16, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
+    assert _tile_entries(20, 16, 32, 128, bf16) == 8   # the benchmark's cell
+    assert _tile_entries(44, 16, 32, 128, bf16) == 8   # the chat shape
+    assert _tile_entries(20, 16, 16, 128, bf16) == 8   # eight operands a side at most
+    assert _tile_entries(3, 2, 16, 64, bf16) == 3      # never past the table
+    assert _tile_entries(20, 8, 32, 128, i8) == 8      # the 7B GQA shape, int8
+    assert _tile_entries(20, 16, 32, 128, f32) == 8    # 8 MiB of tiles exactly
+    assert _tile_entries(20, 32, 32, 128, f32) == 4    # shrunk to fit VMEM
+
+
+# ----------------------------------------------------------------------
 # Engine-level greedy bit-identity: kernel (interpret) vs gather path
 # ----------------------------------------------------------------------
 
@@ -319,6 +443,32 @@ def test_kernel_dispatch_counters(trainers):
     stats = eng.kv_stats()
     assert stats["kv_kernel_dispatches"] > 0
     assert stats["kv_kernel_fallbacks"] == {}
+
+
+def test_live_entry_share_counts_the_columns_held(trainers, monkeypatch):
+    """`kv_live_entry_share` is the host's own count of table entries the
+    next decode step attends to, over slots x table entries; the
+    `trlx:engine.dispatch` span carries the count while a tracing session
+    is active, and nothing otherwise."""
+    from trlx_tpu.observability import tracing
+
+    eng = make_engine(trainers["llama-tiny"], "interpret", max_new=8)
+    bs, n_tbl = eng.kv_block_size, eng._n_tbl
+    assert eng.kv_stats()["kv_live_entry_share"] == 0.0
+    eng.insert_requests([(np.arange(60, 67, dtype=np.int32), 8)], [1])  # 7 columns
+    share = lambda cols: -(-cols // bs) / (2 * n_tbl)  # noqa: E731
+    assert eng.kv_stats()["kv_live_entry_share"] == share(7 + 1)  # the step writes one
+    seen = []
+    real_span = tracing.span
+    monkeypatch.setattr(tracing, "span", lambda name, **a: (seen.append((name, a)), real_span(name, **a))[1])
+    eng.step()  # writes column 8: the next step's 9th starts a second block
+    assert ("engine.dispatch", {}) in seen
+    assert eng.kv_stats()["kv_live_entry_share"] == share(8 + 1) == 2 / (2 * n_tbl)
+    monkeypatch.setattr(tracing, "active", lambda: True)
+    eng.step()
+    assert ("engine.dispatch", {"live_entries": 2}) in seen
+    eng.reclaim_slots([1])
+    assert eng.kv_stats()["kv_live_entry_share"] == 0.0
 
 
 def test_alibi_falls_back_with_reason():

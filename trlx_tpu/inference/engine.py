@@ -230,6 +230,9 @@ class InferenceEngine:
                 idle_capacity=int(prefix_cache_capacity),
             )
             self._slot_blocks: Dict[int, List[int]] = {}
+            # columns each slot's request holds in the arena (prompt +
+            # emitted), kept on the host for `kv_live_entry_share`
+            self._slot_cols = np.zeros((self.num_slots,), np.int64)
             # BlockPool is plain Python touched by the driver thread
             # (insert/reclaim) AND the hot-reload thread (flush_cached).
             # Re-entrant: the session store shares this lock and the
@@ -903,6 +906,7 @@ class InferenceEngine:
                             round_keys.add(keys[j])
                             registered.append(keys[j])
                         self._slot_blocks[slot] = blocks
+                        self._slot_cols[slot] = ids.size
                         journal.append((slot, blocks, registered))
                         T = len(shared) * bs
                         placed.append((ids[T:], T, blocks, max_new, slot, aslot))
@@ -1289,7 +1293,10 @@ class InferenceEngine:
         the pool. The logprob is the policy's raw-logit log-probability
         of the emitted token (see `_sample_fused`), meaningful only where
         `emitted`."""
-        with tracing.span("engine.dispatch"):
+        # how ragged the rows are is what the paged kernel's time follows;
+        # said on the span only while a tracing session listens
+        attrs = {"live_entries": self._live_entries()} if self.kv_paging and tracing.active() else {}
+        with tracing.span("engine.dispatch", **attrs):
             if self.spec_k > 0:
                 params, head = self._current_params_and_head()
                 self._pool, token, logprob, valid, finished = self._decode_fn(
@@ -1305,6 +1312,8 @@ class InferenceEngine:
                 self._pool, token, logprob, valid, finished = self._decode_fn(params, self._pool)
         with tracing.span("engine.fetch"):
             token, logprob, valid, finished = jax.device_get((token, logprob, valid, finished))
+        if self.kv_paging:
+            self._slot_cols += np.asarray(valid).reshape(self.num_slots, -1).sum(-1)
         # kernel dispatch accounting (driver thread; read under _kv_lock
         # by kv_stats), after the step has run: a decode dispatch either
         # rode the fused kernel or fell back to the gather path for a
@@ -1408,10 +1417,22 @@ class InferenceEngine:
         """Allocatable blocks (zero block excluded); 0 when paging is off."""
         return self._block_pool.total if self.kv_paging else 0
 
+    def _live_entries(self) -> int:
+        """Block-table entries that hold a column the next decode step
+        attends to, over the slots with a request: what the paged kernel
+        fetches (ops/paged_attention.py, `n_live`). From the host's own
+        count of each slot's columns; the step adds its one."""
+        with self._kv_lock:
+            slots = list(self._slot_blocks)
+        entries = -(-(self._slot_cols[slots] + 1) // self.kv_block_size)
+        return int(np.minimum(entries, self._n_tbl).sum())
+
     def kv_stats(self) -> Dict[str, Any]:
         """Host-side paged-pool counters for metrics/healthz; {} when
-        paging is off. `kv_kernel_fallbacks` is a {reason: count} dict;
-        everything else is an int."""
+        paging is off. `kv_kernel_fallbacks` is a {reason: count} dict,
+        `kv_live_entry_share` a float (live table entries over slots x
+        table entries: the part of the table walk the paged kernel does
+        not skip); everything else is an int."""
         if not self.kv_paging:
             return {}
         # single source of truth for arena bytes (incl. int8 scale
@@ -1438,6 +1459,7 @@ class InferenceEngine:
                 "prefix_cache_idle_blocks": pool.cached_idle(),
                 "kv_kernel_dispatches": self._kv_kernel_dispatches,
                 "kv_kernel_fallbacks": dict(self._kv_kernel_fallbacks),
+                "kv_live_entry_share": self._live_entries() / (self.num_slots * self._n_tbl),
             }
 
     # ------------------------------------------------------------------

@@ -409,9 +409,10 @@ class Attention(nn.Module):
             new_cache = paged.paged_kv_write(layer_cache, k, v, table, idx, attn_mask)
             new_cache["table"] = table
             if attn_kernel is not None:
-                # Fused Pallas read side: one pass per (slot, table entry)
-                # walks the block table directly — no gathered dense copy,
-                # no materialized dequant, no kv-head repeat. The engine
+                # Fused Pallas read side: one pass over each row's live
+                # table entries, several a grid step, fetches the blocks
+                # directly — no gathered dense copy, no materialized
+                # dequant, no kv-head repeat. The engine
                 # guarantees the shape is expressible (t == 1, no
                 # alibi/window/prefix bias terms) and counts a fallback to
                 # the gather path otherwise.
@@ -427,8 +428,13 @@ class Attention(nn.Module):
                     )
                 # decode_bias writes exactly 0.0 on attendable columns and
                 # -1e9 elsewhere, so key validity is recoverable from the
-                # bias row without widening the call signature.
+                # bias row without widening the call signature. A row with
+                # no token this step (a freed slot keeps its mask until the
+                # next insert) has nothing to attend with: all-masked, so
+                # the kernel walks none of its stale table.
                 key_mask = attn_bias[:, 0, 0, :] == 0.0
+                if attn_mask is not None:
+                    key_mask &= attn_mask > 0
                 kernel_out = paged.paged_attention_decode(
                     q[:, 0],
                     new_cache["k"],

@@ -5,21 +5,62 @@ The gather read path (`paged_kv_gather`) serves decode steps by copying
 every block of a slot's block table back into a dense [b, n_tbl*block,
 nkv, hd] view, dequantizing int8 arenas into a SECOND materialized copy
 and `jnp.repeat`-ing kv heads up to n_heads for GQA before softmax·V.
-The kernel collapses the read side into one Pallas pass per (slot,
-table-entry) grid cell:
+The kernel collapses the read side into one Pallas call whose time
+follows the tokens resident, not the table's length:
 
-* the slot's block table is a **scalar-prefetch** operand, so each KV
-  tile's BlockSpec index map dereferences `table[slot, j]` and the DMA
-  engine fetches the physical arena block directly (all kv heads of the
-  block in one contiguous transfer) — no gathered dense copy in HBM;
+* the physical blocks a call fetches ride **scalar prefetch**: each K/V
+  operand's BlockSpec index map reads its block id there and the DMA
+  engine fetches the arena block directly (all kv heads of the block in
+  one contiguous transfer) — no gathered dense copy in HBM;
 * int8 arenas are dequantized **in registers**: the per-token f32 scales
-  multiply the [group, block] score / probability tiles, which is the
+  multiply the [nkv, group, T] score / probability tiles, which is the
   `ops.quant.dequantize_kv` product re-associated — no dequantized copy;
 * an online flash-style softmax (same (acc, m, l) carry and NEG_INF
-  masking policy as `ops.attention._flash_fwd_kernel`) runs across the
-  table walk, so the [group, S] score matrix never materializes;
+  masking policy as `ops.attention._flash_fwd_kernel`) runs across a
+  row's tiles, so the [group, S] score matrix never materializes;
 * each kv head's whole q-head **group** multiplies against its fetched KV
   tile, so GQA divides KV bytes per step by the group factor.
+
+Grid and tile. A grid step is one **tile** of one row: `E` consecutive
+table entries (`_tile_entries`: 256 tokens' worth, at most 8, never past
+the table: 8 entries of 32 for the benchmark's cell), each entry's
+[nkv, blk, hd] block of K and of V an operand of its own, so a tile is E
+block DMAs a side and the fixed cost of a step is paid once per 256
+tokens. In the kernel the E blocks are laid side by side as one
+[nkv, T = E*blk, hd] tile (an aligned concatenation: no copy) and every
+kv head is contracted at once: scores are one `dot_general` batched over
+nkv with [group, hd] query rows against the tile, the output one batched
+`dot_general` of the probabilities against V's tile, K and V in the
+arena's own type with float32 accumulation (a bfloat16 x bfloat16 product
+accumulated in float32 is exact; a float32 arena keeps every operand
+float32; probabilities are rounded to the output type before p·v only for
+a bfloat16 or int8 arena, as the gather path rounds them). The same form
+serves every group size: for group == 1 a multiply-and-reduce on the VPU
+was measured beside it and took 1.8-2.0 times as long (PERF.md section 6,
+PR 30). Running max and denominator are [nkv, group, 1] float32 state
+written once per tile. VMEM: the tile's buffers are 2 sides x E entries x
+2 pipeline buffers x one block (the cell: 32 x 128 KiB = 4 MiB; the tile
+shrinks until they fit `_TILE_VMEM_BYTES` = 8 MiB), beside them q, mask,
+output, the scale planes' rows and the [nkv, group, T] scores; the call
+is compiled under `vmem_limit_bytes` = 12 MiB (`_VMEM_LIMIT_BYTES`; the
+v5e's default scoped limit is 16), which Mosaic enforces and
+tests/test_kernels_compile_tpu.py holds for the cell's and the chat
+shape.
+
+Schedule (`_live_schedule`, a few small XLA operations on the table and
+the mask, the same for every layer of a step). `n_live[i]` is row i's
+live table entries: (its last attendable column + 1) rounded up to
+blocks, 0 for an all-masked row. The grid is ONE dimension over the
+tiles that hold a live entry, rows in order (an all-masked row gets one
+step, which writes its zeros), and its length `n_work` is read at run
+time: lengths are data, not shapes, so one decode program serves every
+mix. A table entry j >= n_live[i] is neither fetched nor multiplied: in
+a row's last tile, an operand whose entry is not live names the block it
+held the step before, and a repeated block index is not fetched again;
+so table slack (the zero block, or an id >= n_blocks on a padding row)
+is never dereferenced. The mask still rules INSIDE live entries: a mask
+with holes, a partly filled last block, and shared read-only prefix
+blocks behave as on the gather path.
 
 Layout. The Pallas TPU lowering requires the last two dims of every block
 to be divisible by (8, 128) or equal to the array's, and the TPU's
@@ -43,12 +84,13 @@ Hence:
 * int8 scale planes are [n_blocks, 1, nkv*block] f32, head-major along
   the lane axis (column h*block + offset), so a block's scales are one
   lane-dense row and each head's slice is a static lane window.
-* the key mask enters as [b, n_tbl, 1, block]: one block per lane row.
+* the key mask enters as [b, n_tiles, 1, T]: one tile per lane row.
 
 `q` is [b, nh, hd] (ONE query position per row — the decode shape);
 `table` is [b, n_tbl] int32; `key_mask` is [b, n_tbl*block] key validity
-over logical columns. Rows whose mask is all-zero (inactive slots) return
-exact 0.0 — the engine overwrites their sampled token anyway.
+over logical columns. Rows whose mask is all-zero (inactive slots: the
+call site masks a row that has no token this step) return exact 0.0 —
+the engine overwrites their sampled token anyway.
 
 Kernel selection lives with the caller (`InferenceEngine`, through
 `ops.attention.kernel_mode`), which also counts per-dispatch fallbacks
@@ -181,52 +223,95 @@ def paged_kv_gather(
     )
 
 
-def _paged_decode_kernel(table_ref, q_ref, k_ref, v_ref, *rest, scale: float,
-                         quantized: bool):
-    """One (slot, table-entry) cell: the block the table names has landed
-    in VMEM for every kv head; mask invalid columns and fold each head's
-    tile into its online softmax.
+# VMEM budget (module docstring, "Grid and tile"): the tile's buffers may
+# take `_TILE_VMEM_BYTES` and the tile shrinks to fit; the whole call is
+# compiled under `_VMEM_LIMIT_BYTES`, which Mosaic enforces.
+_TILE_VMEM_BYTES = 8 * 1024 * 1024
+_VMEM_LIMIT_BYTES = 12 * 1024 * 1024
+_TILE_TOKENS = 256
+_MAX_TILE_ENTRIES = 8
 
-    table_ref  scalar prefetch [b, n_tbl] (unused here; drives index maps)
+
+def _vmem_block_bytes(nkv: int, blk: int, hd: int, dtype) -> int:
+    """VMEM bytes of one [nkv, blk, hd] arena block: the last two dims
+    padded to the type's (sublane, 128-lane) tile."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * (4 // itemsize)
+    rows = -(-blk // sublanes) * sublanes
+    return nkv * rows * (-(-hd // 128) * 128) * itemsize
+
+
+def _tile_entries(n_tbl: int, nkv: int, blk: int, hd: int, dtype) -> int:
+    """Table entries a grid step takes: `_TILE_TOKENS` tokens' worth, at
+    most `_MAX_TILE_ENTRIES` operands a side and the whole table, shrunk
+    until K and V tiles, double-buffered, fit `_TILE_VMEM_BYTES`."""
+    entries = max(1, min(_TILE_TOKENS // blk, _MAX_TILE_ENTRIES, n_tbl))
+    while entries > 1 and 4 * entries * _vmem_block_bytes(nkv, blk, hd, dtype) > _TILE_VMEM_BYTES:
+        entries -= 1
+    return entries
+
+
+def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, q_ref, *rest,
+                         entries: int, scale: float, quantized: bool, p_dtype):
+    """One grid step: tile `tile_ref[w]` of row `row_ref[w]`, whose
+    `entries` blocks have landed in VMEM for every kv head; mask invalid
+    columns and fold the tile into the row's online softmax, all kv heads
+    in one batched product.
+
+    blocks_ref scalar prefetch [n_steps * entries] (drives index maps)
+    row_ref / tile_ref scalar prefetch [n_steps]: the step's row and tile
+    n_live_ref scalar prefetch [b]: each row's live table entries
     q_ref      [1, nkv, group, hd]
-    k_ref/v_ref [1, nkv, blk, hd]
-    ks_ref/vs_ref [1, 1, nkv*blk] f32 (int8 arenas only)
-    mask_ref   [1, 1, 1, blk] int32 key validity of this block's columns
+    k_refs/v_refs `entries` x [1, nkv, blk, hd]
+    ks_refs/vs_refs `entries` x [1, 1, nkv*blk] f32 (int8 arenas only)
+    mask_ref   [1, 1, 1, entries*blk] int32 key validity of the tile
     o_ref      [1, nkv, group, hd]
-    m_scr/l_scr VMEM [nkv, group, 128] f32 running max / denominator
-               (lane-broadcast), acc_scr VMEM [nkv, group, hd] numerator
+    m_scr/l_scr VMEM [nkv, group, 1] f32 running max / denominator,
+               acc_scr VMEM [nkv, group, hd] numerator
     """
     import jax.experimental.pallas as pl
 
+    E = entries
+    k_refs, v_refs, rest = rest[:E], rest[E:2 * E], rest[2 * E:]
     if quantized:
-        ks_ref, vs_ref, mask_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        mask_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    j = pl.program_id(1)
-    nt = pl.num_programs(1)
-    nkv, blk = k_ref.shape[1], k_ref.shape[2]
+        ks_refs, vs_refs, rest = rest[:E], rest[E:2 * E], rest[2 * E:]
+    mask_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    w = pl.program_id(0)
+    first_entry = tile_ref[w] * E
+    n_live = n_live_ref[row_ref[w]]
+    nkv, blk = k_refs[0].shape[1], k_refs[0].shape[2]
 
-    @pl.when(j == 0)
+    @pl.when(first_entry == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    valid = mask_ref[0, 0] > 0  # [1, blk]
-    for h in range(nkv):
-        q = q_ref[0, h].astype(jnp.float32)  # [group, hd]
-        k = k_ref[0, h].astype(jnp.float32)  # [blk, hd]
-        v = v_ref[0, h].astype(jnp.float32)
+    def tile(refs, dtype):
+        """[nkv, entries*blk, hd]: the entries' blocks side by side."""
+        return jnp.concatenate([r[0].astype(dtype) for r in refs], axis=1)
+
+    def scale_tile(refs):
+        """[nkv, 1, entries*blk] from the planes' head-major lane rows."""
+        return jnp.stack([
+            jnp.concatenate([r[0, :, h * blk:(h + 1) * blk] for r in refs], axis=1)
+            for h in range(nkv)
+        ])
+
+    @pl.when(first_entry < n_live)
+    def _tile():
+        q = q_ref[0]  # [nkv, group, hd], already in the product's type
+        valid = mask_ref[0, 0] > 0  # [1, T]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [group, blk]
+            q, tile(k_refs, q.dtype), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [nkv, group, T]
         if quantized:
-            s = s * ks_ref[0, :, h * blk:(h + 1) * blk]
+            s = s * scale_tile(ks_refs)
         s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_scr[h, :, 0:1]  # [group, 1]
-        l_prev = l_scr[h, :, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_prev, l_prev = m_scr[:], l_scr[:]  # [nkv, group, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         # Fully-masked-so-far rows keep m == NEG_INF; clamp the shift so
         # the exp below cannot blow up to exp(0)=1 on masked entries.
         shift = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
@@ -234,20 +319,51 @@ def _paged_decode_kernel(table_ref, q_ref, k_ref, v_ref, *rest, scale: float,
         p = jnp.where(s <= NEG_INF / 2, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
         corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, corr)
-        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        l_scr[:] = l_prev * corr + jnp.sum(p, axis=2, keepdims=True)
+        m_scr[:] = m_new
         if quantized:
-            p = p * vs_ref[0, :, h * blk:(h + 1) * blk]
-        acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p = p * scale_tile(vs_refs)
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(p_dtype), tile(v_refs, p_dtype), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
         )
-        m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
-        l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
-    @pl.when(j == nt - 1)
+    @pl.when(first_entry + E >= n_live)  # the row's last tile
     def _finalize():
-        l = l_scr[:, :, 0:1]
+        l = l_scr[:]
         denom = jnp.where(l > 0, l, 1.0)
         o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+
+
+def _live_schedule(table, key_mask, blk: int, entries: int):
+    """The grid's walk, from the table and the mask (module docstring,
+    "Schedule"). Returns `blocks` [n_steps*entries], `row` and `tile`
+    [n_steps], `n_live` [b], the scalar `n_work`, and the static n_tiles."""
+    b, n_tbl = table.shape
+    n_tiles = -(-n_tbl // entries)
+    n_steps = b * n_tiles
+    cols = jnp.arange(n_tbl * blk, dtype=jnp.int32)
+    last_col = jnp.max(jnp.where(key_mask.astype(bool), cols + 1, 0), axis=1)
+    n_live = ((last_col + blk - 1) // blk).astype(jnp.int32)  # [b]
+    # a row's tiles, one even for an all-masked row: its step writes the zeros
+    tiles = jnp.maximum(1, (n_live + entries - 1) // entries)
+    ends = jnp.cumsum(tiles)
+    n_work = ends[-1]
+    step = jnp.arange(n_steps, dtype=jnp.int32)
+    at = jnp.minimum(step, n_work - 1)  # past the work: never walked
+    row = jnp.sum(at[:, None] >= ends[None, :], axis=1).astype(jnp.int32)
+    tile = at - (ends - tiles)[row]
+    entry = tile[:, None] * entries + jnp.arange(entries, dtype=jnp.int32)[None, :]
+    live = entry < n_live[row][:, None]  # [n_steps, entries]
+    table = jnp.pad(table.astype(jnp.int32), ((0, 0), (0, n_tiles * entries - n_tbl)))
+    named = table[row[:, None], entry]
+    # forward fill down each operand's column: where its entry is not
+    # live the operand keeps the block it held the step before
+    held = jax.lax.cummax(jnp.where(live, step[:, None], -1), axis=0)
+    first = named.reshape(-1)[jnp.argmax(live.reshape(-1))]
+    blocks = jnp.where(
+        held >= 0, jnp.take_along_axis(named, jnp.maximum(held, 0), axis=0), first)
+    return blocks.reshape(-1), row, tile, n_live, n_work, n_tiles
 
 
 def paged_attention_decode(
@@ -263,12 +379,7 @@ def paged_attention_decode(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Fused paged decode attention. Returns [b, nh, hd] in `out_dtype`
-    (defaults to q's dtype). Grid is (b, n_tbl): each cell walks one table
-    entry of one slot for every kv head, so a KV block is fetched once per
-    step and shared by its whole q-head group. `table` rides scalar
-    prefetch — the arena BlockSpec index maps dereference it, so block
-    fetches are direct HBM→VMEM DMAs of the physical blocks (the zero
-    block for never-written table slack, whose columns the mask kills)."""
+    (defaults to q's dtype). Grid, tile and schedule: module docstring."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -281,60 +392,72 @@ def paged_attention_decode(
     quantized = k_arena.dtype == jnp.int8
     if quantized and (k_scale is None or v_scale is None):
         raise ValueError("int8 arenas require k_scale/v_scale planes")
-    out_dtype = out_dtype or q.dtype
+    out_dtype = jnp.dtype(out_dtype or q.dtype)
 
+    # The products' operand types. K and V enter in the arena's own type
+    # (int8 converts exactly) unless q or the output is wider; a bfloat16
+    # x bfloat16 product accumulated in float32 is exact. Probabilities
+    # are rounded to the output type before p.v for a bfloat16 or int8
+    # arena, as the gather path rounds them (`paged_attention_reference`),
+    # and stay float32 for a float32 arena.
+    kv_dtype = q.dtype if quantized else k_arena.dtype
+    qk_dtype = jnp.promote_types(q.dtype, kv_dtype)
+    p_dtype = jnp.float32 if kv_dtype == jnp.float32 else jnp.promote_types(out_dtype, kv_dtype)
+
+    E = _tile_entries(n_tbl, nkv, blk, hd, k_arena.dtype)
+    blocks, row, tile, n_live, n_work, n_tiles = _live_schedule(table, key_mask, blk, E)
+    T = E * blk
     # Head order matches the dense path's jnp.repeat(k, group, axis=2):
     # q head h attends kv head h // group, so [b, nh, hd] -> [b, nkv,
     # group, hd] keeps each kv head's q-group contiguous.
-    qg = q.reshape(b, nkv, group, hd)
-    maskh = key_mask.astype(jnp.int32).reshape(b, n_tbl, 1, blk)
+    qg = q.reshape(b, nkv, group, hd).astype(qk_dtype)
+    maskh = jnp.pad(key_mask.astype(jnp.int32), ((0, 0), (0, n_tiles * T - n_tbl * blk)))
+    maskh = maskh.reshape(b, n_tiles, 1, T)
 
-    def slot_index(i, j, tbl_ref):
-        return (i, 0, 0, 0)
+    def slot_index(w, blocks_ref, row_ref, *_):
+        return (row_ref[w], 0, 0, 0)
 
-    def kv_index(i, j, tbl_ref):
-        return (tbl_ref[i, j], 0, 0, 0)
+    def entry_index(e, ndim):
+        def index(w, blocks_ref, *_):
+            return (blocks_ref[w * E + e],) + (0,) * (ndim - 1)
+        return index
 
-    in_specs = [
-        pl.BlockSpec((1, nkv, group, hd), slot_index),
-        pl.BlockSpec((1, nkv, blk, hd), kv_index),
-        pl.BlockSpec((1, nkv, blk, hd), kv_index),
-    ]
-    operands = [qg, k_arena, v_arena]
+    kv_specs = [pl.BlockSpec((1, nkv, blk, hd), entry_index(e, 4)) for e in range(E)]
+    in_specs = [pl.BlockSpec((1, nkv, group, hd), slot_index)] + kv_specs + kv_specs
+    operands = [qg] + [k_arena] * E + [v_arena] * E
     if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, 1, nkv * blk), lambda i, j, tbl_ref: (tbl_ref[i, j], 0, 0)
-        )
-        in_specs += [scale_spec, scale_spec]
-        operands += [k_scale, v_scale]
-    in_specs.append(
-        pl.BlockSpec((1, 1, 1, blk), lambda i, j, tbl_ref: (i, j, 0, 0))
-    )
+        plane_specs = [pl.BlockSpec((1, 1, nkv * blk), entry_index(e, 3)) for e in range(E)]
+        in_specs += plane_specs + plane_specs
+        operands += [k_scale] * E + [v_scale] * E
+    in_specs.append(pl.BlockSpec(
+        (1, 1, 1, T), lambda w, blocks_ref, row_ref, tile_ref, *_: (row_ref[w], tile_ref[w], 0, 0)
+    ))
     operands.append(maskh)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, n_tbl),
+        num_scalar_prefetch=4,
+        grid=(n_work,),  # the steps that hold work, read at run time
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, nkv, group, hd), slot_index),
         scratch_shapes=[
-            pltpu.VMEM((nkv, group, 128), jnp.float32),  # m (lane-broadcast)
-            pltpu.VMEM((nkv, group, 128), jnp.float32),  # l
-            pltpu.VMEM((nkv, group, hd), jnp.float32),   # acc
+            pltpu.VMEM((nkv, group, 1), jnp.float32),   # m
+            pltpu.VMEM((nkv, group, 1), jnp.float32),   # l
+            pltpu.VMEM((nkv, group, hd), jnp.float32),  # acc
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_decode_kernel, scale=1.0 / np.sqrt(hd), quantized=quantized
+            _paged_decode_kernel, entries=E, scale=1.0 / np.sqrt(hd),
+            quantized=quantized, p_dtype=p_dtype,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nkv, group, hd), out_dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT_BYTES
         ),
         interpret=interpret,
         name="paged_decode",
-    )(table.astype(jnp.int32), *operands)
+    )(blocks, row, tile, n_live, *operands)
     return out.reshape(b, nh, hd)
 
 

@@ -472,9 +472,9 @@ def test_lfm2_train_step_fits_the_chip_at_batch_16(v5e, pallas_mode, capsys):
 
     def train_step(train, frozen, opt_state, tokens, attn_mask):
         def loss(train):
-            logits, values = model.apply(
-                {"params": unflatten_dict({**train, **frozen})}, tokens, attn_mask, None, t - new - 1, new,
-                method=CausalLMWithValueHead.forward_window)
+            logits, values, _ = model.apply(
+                {"params": unflatten_dict({**train, **frozen})}, tokens, attn_mask,
+                window=(t - new - 1, new), method=CausalLMWithValueHead.forward)
             return -logprobs_of_labels(logits, tokens[:, t - new:]).mean() + (values ** 2).mean()
 
         grads = jax.grad(loss)(train)

@@ -328,15 +328,15 @@ def test_prompt_tuning_only_soft_prompt_trains():
 
 
 def test_prompt_tuning_ref_is_prompt_free():
-    """forward_ref_full skips the soft prompt: equals a prompt-free model
+    """The full reference forward skips the soft prompt: equals a prompt-free model
     on the same base weights, and differs from the prompted forward."""
     cfg, model, params, tokens, mask = _build_prompt()
     logits, _, _ = model.apply({"params": params}, tokens, mask)
     ref = ref_param_subtree(params, cfg, resolve_split(cfg, 2))
     assert resolve_split(cfg, 2) == 0  # prompt forces full-ref mode
-    ref_logits = model.apply(
-        {"params": {"lm": ref}}, tokens, mask,
-        method=CausalLMWithValueHead.forward_ref_full,
+    ref_logits, _, _ = model.apply(
+        {"params": {"lm": ref}}, tokens, mask, use_prompt=False, with_value=False,
+        method=CausalLMWithValueHead.forward,
     )
     assert not np.allclose(np.asarray(logits), np.asarray(ref_logits))
 
@@ -485,9 +485,9 @@ def test_prefix_tuning_ref_is_prefix_free():
     logits, _, _ = model.apply({"params": params}, tokens, mask)
     assert resolve_split(cfg, 2) == 0
     ref = ref_param_subtree(params, cfg, 0)
-    ref_logits = model.apply(
-        {"params": {"lm": ref}}, tokens, mask,
-        method=CausalLMWithValueHead.forward_ref_full,
+    ref_logits, _, _ = model.apply(
+        {"params": {"lm": ref}}, tokens, mask, use_prompt=False, with_value=False,
+        method=CausalLMWithValueHead.forward,
     )
     assert not np.allclose(np.asarray(logits), np.asarray(ref_logits))
 

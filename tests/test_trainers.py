@@ -350,7 +350,7 @@ def test_ppo_value_branch_full_loop(tmp_path):
 
 
 def test_ppo_windowed_loss_equals_full_forward(tmp_path):
-    """The r5 windowed-head train loss (forward_window: trunk full-width,
+    """The r5 windowed-head train loss (`forward(window=...)`: trunk full-width,
     50k-vocab unembed + CE + value head over the response window only)
     must produce the SAME loss and stats as the full-forward + slice
     path on identical params and chunk — the windowing is a pure

@@ -95,9 +95,9 @@ def _eager_trunk(trainer, chunk):
     tokens = jnp.concatenate([chunk.query_tensors, chunk.response_tensors], axis=1)
     amask = (tokens != pad).astype(jnp.int32)
     return trainer.model.apply(
-        {"params": params}, tokens, amask, position_ids(amask), trainer.split,
-        method=CausalLMWithValueHead.forward_trunk,
-    )
+        {"params": params}, tokens, amask, position_ids(amask), stop=trainer.split,
+        method=CausalLMWithValueHead.forward,
+    )[1]
 
 
 def _grads(trainer, loss_fn, batch):

@@ -11,7 +11,8 @@ that monolith into the two Orca/vLLM-style primitives:
   cache, returning the per-row KV cache rows + last-position logits;
 - ``decode_step``: jitted once — sample one token for every ACTIVE slot
   of the pool and advance each slot's own cache column
-  (`TransformerLM.decode_step_rows`; rows sit at different depths).
+  (`TransformerLM.decode_step` on a `row_index` cache; rows sit at
+  different depths).
 
 Slots are freed the step their request finishes (eos / length budget /
 cancel) and newly prefilled requests are scattered into free slots
@@ -92,9 +93,9 @@ _KV_DTYPES = {
 class InferenceEngine:
     """Generation over a fixed pool of `num_slots` KV-cache slots.
 
-    :param model: a flax module exposing `decode_step` (prefill) and
-        `decode_step_rows` (per-slot decode) — `CausalLMWithValueHead`
-        and friends.
+    :param model: a flax module exposing `decode_step` (prefill on a
+        scalar-`index` cache, per-slot decode and paged insert on a
+        `row_index` one) — `CausalLMWithValueHead` and friends.
     :param gen_cfg: engine-wide sampling knobs. Per-request overrides are
         limited to `max_new_tokens` (≤ the engine's, which sizes the
         cache); everything else is fixed at engine build time so the
@@ -353,7 +354,7 @@ class InferenceEngine:
 
     def _resolve_attn_kernel(self) -> Optional[str]:
         """Map the decode_kernel knob onto the per-dispatch attn_kernel
-        value threaded into decode_step_rows: None (gather path),
+        value threaded into the per-row decode_step: None (gather path),
         "pallas" (compiled Mosaic kernel) or "interpret" (same kernel
         through the Pallas interpreter — CPU-executable). The devices are
         those the params live on, i.e. where the decode program runs (with
@@ -576,7 +577,7 @@ class InferenceEngine:
 
     def _get_paged_insert(self, pb: int, plen: int) -> Callable:
         """Paged-mode prefill+insert, jitted per (rows, suffix-width)
-        bucket: one `prefill_rows` call writes each row's RIGHT-padded
+        bucket: one per-row `decode_step` of t > 1 writes each row's RIGHT-padded
         prompt suffix straight into the shared arena through its fresh
         block table (no per-request cache copy to scatter afterwards —
         the arena IS the pool), seeds rows behind a cached prefix at
@@ -607,10 +608,11 @@ class InferenceEngine:
                     "pos": shared_len,
                     "row_index": shared_len,
                 }
-                logits, new_cache = model.apply(
+                out = model.apply(
                     variables, ids, cache, tmask,
-                    method=type(model).prefill_rows,
+                    method=type(model).decode_step,
                 )
+                logits, new_cache = out[0], out[-1]
                 # per-row LAST-valid-position logits (right padding)
                 lens = tmask.sum(-1).astype(jnp.int32)
                 last = jnp.take_along_axis(
@@ -623,7 +625,7 @@ class InferenceEngine:
                     for layer in new_cache["layers"]
                 ]
                 # padding rows carry slot_id == num_slots and all-OOB
-                # tables: both their arena writes (inside prefill_rows)
+                # tables: both their arena writes (inside the model's step)
                 # and these pool scatters are dropped
                 new_pool = {
                     **pool,
@@ -1014,12 +1016,13 @@ class InferenceEngine:
                 # adapter's factors, gathered by the slot's stack index
                 # (Punica-style batched LoRA; slot 0 zeros = base policy)
                 variables["lora_rows"] = _gather_rows(stack, pool["adapter"])
-            logits, new_cache = model.apply(
+            out = model.apply(
                 variables, token[:, None], cache,
                 valid.astype(jnp.int32)[:, None],
-                method=type(model).decode_step_rows,
+                method=type(model).decode_step,
                 attn_kernel=ak,
             )
+            logits, new_cache = out[0], out[-1]
             if paged:
                 new_cache = dict(new_cache, layers=[
                     {k2: v2 for k2, v2 in layer.items() if k2 != "table"}
@@ -1097,10 +1100,12 @@ class InferenceEngine:
             f = f0
             h_rows, q_scores, draft_toks = [], [], []
             for j in range(k + 1):
-                h_j, hn_j, cache = model.apply(
+                # a trunk-only step: no head, the state entering block
+                # `split` raw (h_j) and through `ln_f` (hn_j)
+                _, hn_j, cache, h_j = model.apply(
                     {"params": params}, f[:, None], cache, act_i[:, None],
-                    split, method=type(model).spec_draft_step,
-                    attn_kernel=ak,
+                    stop=split, capture_split=split, attn_kernel=ak,
+                    method=type(model).decode_step,
                 )
                 h_rows.append(h_j)
                 if j < k:
@@ -1112,22 +1117,16 @@ class InferenceEngine:
                     draft_toks.append(f)
             h_block = jnp.concatenate(h_rows, axis=1)
             positions = pos_start[:, None] + jnp.arange(k + 1)[None, :]
-            if paged:
-                # gate the batched verify's arena writes on row liveness:
-                # a freed slot's stale block table may point at blocks now
-                # owned by other requests, so its writes must drop
-                out = model.apply(
-                    {"params": params}, h_block, cache, row_start, positions,
-                    split, method=type(model).spec_verify_rows,
-                    token_mask=jnp.broadcast_to(act_i[:, None], (P, k + 1)),
-                )
-            else:
-                out = model.apply(
-                    {"params": params}, h_block, cache, row_start, positions,
-                    split, method=type(model).spec_verify_rows,
-                )
-            logits_v, new_layers = out[0].astype(jnp.float32), out[2]
-            cache = dict(cache, layers=new_layers)
+            # gate the batched verify's arena writes on row liveness: a
+            # freed slot's stale block table may point at blocks now owned
+            # by other requests, so its writes must drop
+            out = model.apply(
+                {"params": params}, h_block, cache,
+                jnp.broadcast_to(act_i[:, None], (P, k + 1)) if paged else None,
+                start=split, block_start=row_start, positions=positions,
+                method=type(model).decode_step,
+            )
+            logits_v, cache = out[0].astype(jnp.float32), out[-1]
             p_scores = [warp(logits_v[:, j], step0 + 1 + j) for j in range(k + 1)]
             if greedy:
                 acc = [

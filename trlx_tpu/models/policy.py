@@ -83,182 +83,89 @@ class CausalLMWithValueHead(nn.Module):
     def __call__(self, tokens, attn_mask, positions=None, split: int = 0):
         """Returns (logits, values, h_split). `split` is the hydra branch
         point (0 = no split; h_split is then the embedding output)."""
-        if self.num_value_layers > 0:
-            value_split = self.cfg.n_layers - self.num_value_layers
-            logits, h_split, _, h_value = self.lm.forward_captures(
-                tokens, attn_mask, positions, split, value_split
-            )
-            if positions is None:
-                # the LM's position rule (ring attention offsets differ
-                # from a plain cumsum) — branch blocks must see the same
-                # rotary phases as the trunk blocks they were cloned from
-                positions = self.lm._default_positions(tokens, attn_mask)
-            values = self.value_branch(h_value, attn_mask, positions)
-            return logits, values, h_split
-        logits, h_split, h_final = self.lm(tokens, attn_mask, positions, split)
-        values = self.v_head(h_final)[..., 0]
-        return logits, values, h_split
+        logits, values, caps = self.forward(tokens, attn_mask, positions, capture=(split,))
+        return logits, values, caps[split]
 
-    def forward_window(self, tokens, attn_mask, positions=None,
-                       start: int = 0, length: int = 1):
-        """(logits_win, values_win) over positions [start, start+length)
-        only — exactly the slice the PPO train loss consumes (the
-        full-width 50k-vocab unembed was the cycle's largest wasted
-        matmul; TransformerLM.forward_window). The MLP value head reads
-        per-position hidden states, so windowing it is exact; the deeper
-        value BRANCH runs attention over the full sequence and cannot be
-        windowed."""
-        if self.num_value_layers > 0:
+    def _values(self, h_final, branch_input=None):
+        """How this policy makes values: the MLP head reads per-position
+        final hidden states; the deeper value branch attends over the full
+        sequence from `branch_input` = (the trunk activation entering its
+        first block, attn_mask, positions), which only a full-width forward
+        has."""
+        if self.num_value_layers == 0:
+            return self.v_head(h_final)[..., 0]
+        if branch_input is None:
             raise NotImplementedError(
-                "forward_window with a value branch is unsupported (branch "
-                "blocks attend over the full sequence)"
+                "per-step values during decode are not supported with a "
+                "value branch (values are computed in the scoring pass)"
             )
-        logits, h_final = self.lm.forward_window(
-            tokens, attn_mask, positions, start, length
-        )
-        return logits, self.v_head(h_final)[..., 0]
+        return self.value_branch(*branch_input)
 
-    def forward_ref_suffix(self, h_split, attn_mask, positions=None, start_layer: int = 0):
-        """Frozen-branch pass from the split point (apply with ref params)."""
-        return self.lm.forward_from(h_split, attn_mask, positions, start_layer)
-
-    def forward_ref_suffix_window(self, h_split, attn_mask, positions=None,
-                                  start_layer: int = 0, start: int = 0, length: int = 1):
-        """Frozen-branch pass from the split point, unembedding only
-        positions [start, start+length) — the score phase of the rollout
-        fast path, where the sampler already captured h_split and only the
-        response window of the reference logits is needed."""
-        return self.lm.forward_from_window(h_split, attn_mask, positions, start_layer,
-                                           start, length)[0]
-
-    def forward_trunk(self, tokens, attn_mask, positions=None, split: int = 0):
-        """Frozen-prefix pass: embeddings + blocks [0, split) only — the
-        activation entering the hydra split, with no heads. One jitted call
-        per rollout chunk fills the PPO trunk cache
-        (method.cache_trunk_activations) when the capture sampler didn't
-        already produce it."""
-        return self.lm.forward_trunk(tokens, attn_mask, positions, split)
-
-    def forward_from_cache(self, h_split, attn_mask, positions=None,
-                           start_layer: int = 0):
-        """(logits, values) resuming the TRAINABLE suffix from a cached
-        trunk activation — the trunk-cache train path's replacement for
-        __call__. Apply with the live (policy) params: blocks
-        [start_layer, n_layers) + unembed + value head all run, only the
-        frozen-prefix forward is skipped. Exact when the trunk is entirely
-        frozen (split > 0 implies it is). Supports the deeper value branch
-        as long as its tap point is at/above start_layer (the gate
-        guarantees this)."""
-        if self.num_value_layers > 0:
+    def forward(self, x, attn_mask, positions=None, *, with_value: bool = True,
+                stop=None, capture=(), window=None, **blocks):
+        """`TransformerLM.forward` with the values of what comes back:
+        (logits, values, caps). `with_value=False` runs the LM alone — the
+        frozen-reference branch, applied with `{"lm": ref_params}`: from the
+        hydra split (`start=split`, x = h_split) or, when every layer
+        trains, in full with `use_prompt=False` (the reference likewise gets
+        ref logits from the base model without the prompt adapter,
+        modeling_ppo.py:324-327). A forward that stops short of the head
+        (`stop`) has no values either and returns the LM's own (None, h,
+        caps). With the live params and `start=split`, x a cached trunk
+        activation, this is the trunk-cache train path: exact when the trunk
+        is entirely frozen (split > 0 implies it), and the value branch's
+        tap point must lie at or above `start` (the gate guarantees this)."""
+        value_split = None
+        if with_value and self.num_value_layers > 0 and stop is None:
+            if window is not None:
+                raise NotImplementedError(
+                    "a windowed head with a value branch is unsupported (branch "
+                    "blocks attend over the full sequence)"
+                )
             value_split = self.cfg.n_layers - self.num_value_layers
-            logits, _, h_value = self.lm.forward_from_captures(
-                h_split, attn_mask, positions, start_layer, value_split
-            )
-            if positions is None:
-                positions = self.lm._default_positions(h_split, attn_mask)
-            values = self.value_branch(h_value, attn_mask, positions)
-            return logits, values
-        logits, h_final, _ = self.lm.forward_from_captures(
-            h_split, attn_mask, positions, start_layer
+            capture = (*capture, value_split)
+        logits, h, caps = self.lm.forward(
+            x, attn_mask, positions, stop=stop, capture=capture, window=window, **blocks
         )
-        return logits, self.v_head(h_final)[..., 0]
+        if logits is None:
+            return None, h, caps
+        if not with_value:
+            return logits, None, caps
+        if value_split is None:
+            return logits, self._values(h), caps
+        if positions is None:
+            # the LM's position rule (ring attention offsets differ
+            # from a plain cumsum) — branch blocks must see the same
+            # rotary phases as the trunk blocks they were cloned from
+            positions = self.lm._default_positions(x, attn_mask)
+        return logits, self._values(h, (caps[value_split], attn_mask, positions)), caps
 
-    def forward_from_cache_window(self, h_split, attn_mask, positions=None,
-                                  start_layer: int = 0, start: int = 0,
-                                  length: int = 1):
-        """`forward_from_cache` composed with the windowed unembedding:
-        (logits_win, values_win) over [start, start+length) only. Same
-        value-branch restriction as forward_window."""
-        if self.num_value_layers > 0:
-            raise NotImplementedError(
-                "forward_from_cache_window with a value branch is "
-                "unsupported (branch blocks attend over the full sequence)"
-            )
-        logits, h_final = self.lm.forward_from_window(
-            h_split, attn_mask, positions, start_layer, start, length
-        )
-        return logits, self.v_head(h_final)[..., 0]
-
-    def forward_ref_full(self, tokens, attn_mask, positions=None):
-        """Full reference forward (used when every layer is trainable).
-        Skips the soft prompt under prompt tuning — the reference likewise
-        gets ref logits from the base model without the prompt adapter
-        (modeling_ppo.py:324-327)."""
-        logits, _, _ = self.lm(tokens, attn_mask, positions, 0, use_prompt=False)
-        return logits
-
-    def decode_step(self, tokens, cache, token_mask, is_prefill: bool = False,
-                    with_value: bool = False, capture_split=None):
-        """Cached decode. `capture_split` (rollout fast path) additionally
-        returns the activation entering that block, making the return a
-        4-tuple (logits, values, cache, h_cap)."""
-        if capture_split is not None:
-            logits, h, new_cache, h_cap = self.lm.decode_step(
-                tokens, cache, token_mask, is_prefill, capture_split
-            )
-        else:
-            logits, h, new_cache = self.lm.decode_step(tokens, cache, token_mask, is_prefill)
-            h_cap = None
+    def decode_step(self, x, cache, token_mask, is_prefill: bool = False,
+                    with_value: bool = False, **step):
+        """`TransformerLM.decode_step` with the value of each new position
+        when `with_value`: (logits, values | None, new_cache[, h_cap]). A
+        step that stops short of the head runs none here either and returns
+        the LM's own tuple."""
+        out = self.lm.decode_step(x, cache, token_mask, is_prefill, **step)
+        if out[0] is None:
+            return out
         values = None
         if with_value:
-            if self.num_value_layers > 0:
+            values = self._values(out[1])
+            if values is None:
                 raise NotImplementedError(
-                    "per-step values during decode are not supported with a "
-                    "value branch (values are computed in the scoring pass)"
+                    f"{type(self).__name__} has no value head; decode with with_value=False"
                 )
-            values = self.v_head(h)[..., 0]
-        if capture_split is not None:
-            return logits, values, new_cache, h_cap
-        return logits, values, new_cache
-
-    def decode_step_rows(self, tokens, cache, token_mask, attn_kernel=None):
-        """Per-row-offset cached decode (continuous-batching slot pool,
-        trlx_tpu/inference/engine.py). Returns (logits, new_cache)."""
-        return self.lm.decode_step_rows(tokens, cache, token_mask, attn_kernel)
-
-    def prefill_rows(self, tokens, cache, token_mask):
-        """Per-row-offset multi-token prefill (the paged engine's insert
-        path). Returns (logits, new_cache)."""
-        return self.lm.prefill_rows(tokens, cache, token_mask)
-
-    def spec_draft_step(self, tokens, cache, token_mask, split: int,
-                        attn_kernel=None):
-        """Trunk-only per-row draft step (self-speculative decode). Returns
-        (h_split, h_norm, new_cache) — no heads run during drafting."""
-        return self.lm.spec_draft_step(tokens, cache, token_mask, split,
-                                       attn_kernel)
-
-    def spec_verify_rows(self, h, cache, row_start, positions, split: int,
-                         with_value: bool = False, token_mask=None):
-        """Batched suffix verify from the trunk's own h_split rows. Returns
-        (logits, values | None, new_layers); values come from the MLP head
-        on h_final (the deeper value branch is computed in the scoring
-        pass, same restriction as decode_step's per-step values).
-        `token_mask` gates paged-arena cache writes (see
-        TransformerLM.spec_verify_rows); dense caches ignore it."""
-        logits, h_final, new_layers = self.lm.spec_verify_rows(
-            h, cache, row_start, positions, split, token_mask=token_mask
-        )
-        values = None
-        if with_value:
-            if self.num_value_layers > 0:
-                raise NotImplementedError(
-                    "per-step values during decode are not supported with a "
-                    "value branch (values are computed in the scoring pass)"
-                )
-            values = self.v_head(h_final)[..., 0]
-        return logits, values, new_layers
+        return (out[0], values) + out[2:]
 
 
 class CausalLMPolicy(CausalLMWithValueHead):
     """Critic-free policy: the LM alone, with NO value head anywhere in the
     param tree (GRPO/RLOO delete the critic, so the tree must too — a
     zero-init v_head would still allocate and train parameters, and the
-    tests assert its absence). Subclasses CausalLMWithValueHead so every
-    pure-`self.lm` delegate (reference forwards, cached decode, row
-    decode/prefill, spec draft) and `forward_policy_and_ref` work
-    unchanged; value-bearing surfaces return None in the values slot or
-    raise when a per-step value is explicitly requested."""
+    tests assert its absence). Subclasses CausalLMWithValueHead so the two
+    bodies and `forward_policy_and_ref` work unchanged; the values slot
+    holds None, and a per-step value explicitly asked for raises."""
 
     def setup(self):
         if self.num_value_layers > 0:
@@ -267,47 +174,8 @@ class CausalLMPolicy(CausalLMWithValueHead):
             )
         self.lm = TransformerLM(self.cfg, name="lm")
 
-    def __call__(self, tokens, attn_mask, positions=None, split: int = 0):
-        logits, h_split, _ = self.lm(tokens, attn_mask, positions, split)
-        return logits, None, h_split
-
-    def forward_window(self, tokens, attn_mask, positions=None,
-                       start: int = 0, length: int = 1):
-        logits, _ = self.lm.forward_window(tokens, attn_mask, positions, start, length)
-        return logits, None
-
-    def forward_from_cache(self, h_split, attn_mask, positions=None,
-                           start_layer: int = 0):
-        logits, _, _ = self.lm.forward_from_captures(
-            h_split, attn_mask, positions, start_layer
-        )
-        return logits, None
-
-    def forward_from_cache_window(self, h_split, attn_mask, positions=None,
-                                  start_layer: int = 0, start: int = 0,
-                                  length: int = 1):
-        logits, _ = self.lm.forward_from_window(
-            h_split, attn_mask, positions, start_layer, start, length
-        )
-        return logits, None
-
-    def decode_step(self, tokens, cache, token_mask, is_prefill: bool = False,
-                    with_value: bool = False, capture_split=None):
-        if with_value:
-            raise NotImplementedError(
-                "CausalLMPolicy has no value head; decode with with_value=False"
-            )
-        return super().decode_step(tokens, cache, token_mask, is_prefill,
-                                   False, capture_split)
-
-    def spec_verify_rows(self, h, cache, row_start, positions, split: int,
-                         with_value: bool = False, token_mask=None):
-        if with_value:
-            raise NotImplementedError(
-                "CausalLMPolicy has no value head; verify with with_value=False"
-            )
-        return super().spec_verify_rows(h, cache, row_start, positions, split,
-                                        False, token_mask)
+    def _values(self, h_final, branch_input=None):
+        return None
 
 
 class CausalLMWithILQLHeads(nn.Module):
@@ -325,22 +193,14 @@ class CausalLMWithILQLHeads(nn.Module):
         qs, target_qs, vs = self.ilql_heads(h_final, states_ixs, actions_ixs)
         return logits, qs, target_qs, vs, h_final
 
-    def decode_step(self, tokens, cache, token_mask, is_prefill: bool = False):
+    def decode_step(self, x, cache, token_mask, is_prefill: bool = False, **step):
         """Cached decode returning (logits, qs, target_qs, vs, cache) at the
-        new positions — feeds the beta*(Q-V) logit shift during generation."""
-        logits, h, new_cache = self.lm.decode_step(tokens, cache, token_mask, is_prefill)
+        new positions — feeds the beta*(Q-V) logit shift during generation.
+        (The engine reads the first and the last: the ILQL advantage shift
+        is a training-time sampler feature.)"""
+        logits, h, new_cache = self.lm.decode_step(x, cache, token_mask, is_prefill, **step)
         qs, target_qs, vs = self.ilql_heads(h)
         return logits, qs, target_qs, vs, new_cache
-
-    def decode_step_rows(self, tokens, cache, token_mask, attn_kernel=None):
-        """Per-row-offset cached decode (continuous-batching slot pool).
-        Plain-LM logits only — the ILQL advantage shift is a training-time
-        sampler feature; serve ILQL policies with the static engine."""
-        return self.lm.decode_step_rows(tokens, cache, token_mask, attn_kernel)
-
-    def prefill_rows(self, tokens, cache, token_mask):
-        """Per-row-offset multi-token prefill (paged engine insert)."""
-        return self.lm.prefill_rows(tokens, cache, token_mask)
 
 
 # ---------------------------------------------------------------------------
@@ -367,7 +227,7 @@ def resolve_split(cfg: TransformerConfig, num_layers_unfrozen: int) -> int:
     if getattr(cfg, "prompt_tokens", 0) > 0 or getattr(cfg, "prefix_tokens", 0) > 0:
         # prompt/prefix adapters change every hidden state from layer 0 on,
         # so the branch-point trick is invalid — ref logits come from a full
-        # adapter-free forward (forward_ref_full with use_prompt=False)
+        # adapter-free forward (`forward` with use_prompt=False)
         return 0
     if num_layers_unfrozen == -1:
         return 0
@@ -497,21 +357,15 @@ def forward_policy_and_ref(
     logits, values, h_split = model.apply(
         {"params": params}, tokens, attn_mask, positions, split
     )
+    ref = {"params": {"lm": ref_params}}
     if split > 0:
-        ref_logits = model.apply(
-            {"params": {"lm": ref_params}},
-            jax.lax.stop_gradient(h_split),
-            attn_mask,
-            positions,
-            split,
-            method=CausalLMWithValueHead.forward_ref_suffix,
+        ref_logits, _, _ = model.apply(
+            ref, jax.lax.stop_gradient(h_split), attn_mask, positions,
+            start=split, with_value=False, method=type(model).forward,
         )
     else:
-        ref_logits = model.apply(
-            {"params": {"lm": ref_params}},
-            tokens,
-            attn_mask,
-            positions,
-            method=CausalLMWithValueHead.forward_ref_full,
+        ref_logits, _, _ = model.apply(
+            ref, tokens, attn_mask, positions,
+            use_prompt=False, with_value=False, method=type(model).forward,
         )
     return logits, values, jax.lax.stop_gradient(ref_logits)

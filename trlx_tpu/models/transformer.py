@@ -8,7 +8,7 @@ trlx/models/modeling_ppo.py:502-1222): one `TransformerLM` parameterized by
 gelu, tied embeddings) and Llama-style (rotary positions, RMSNorm, swiglu,
 GQA) decoders. The per-arch "branch" classes collapse to a `start_layer`
 argument: `__call__(..., split=k)` also returns the hidden state entering
-block k, and `forward_from(h, start_layer=k)` resumes from there — applied
+block k, and `forward(h, ..., start=k)` resumes from there — applied
 with a frozen copy of the top-k params this IS the reference's hydra branch
 (modeling_ppo.py:385-499), but in the same jit graph as the policy pass.
 
@@ -290,7 +290,7 @@ def alibi_bias(key_mask: jnp.ndarray, n_heads: int) -> jnp.ndarray:
 def fused_attention_ok(cfg: TransformerConfig, seq_len: Optional[int] = None) -> bool:
     """Whether the fused (flash/ring) kernels can express cfg's attention
     structure for a length-`seq_len` forward. Single source of truth for
-    Attention, TransformerLM._train_bias, and the GPipe stage — the
+    Attention, `train_bias` (TransformerLM.forward), and the GPipe stage — the
     caller's bias=None decision must match Attention's branch exactly.
 
     A sliding window is a static no-op when seq_len <= window, so the
@@ -815,13 +815,43 @@ def window_bias(q_positions: jnp.ndarray, key_mask: jnp.ndarray, window: int) ->
     return jnp.where(delta >= window, -1e9, 0.0)[:, None].astype(jnp.float32)
 
 
-def decode_bias(cache_mask: jnp.ndarray, t: int) -> jnp.ndarray:
+def decode_bias(cache_mask: jnp.ndarray) -> jnp.ndarray:
     """Bias during cached decode: attend to every valid cache slot.
     cache_mask: [b, S] validity of cache slots (already includes the tokens
     being written this step). For t>1 prefill the causal structure within
-    the new block is handled by the caller via causal_bias."""
+    the new block is `cached_bias`'s to add."""
     allowed = cache_mask[:, None, None, :].astype(bool)
     return jnp.where(allowed, 0.0, -1e9).astype(jnp.float32)
+
+
+def cached_bias(cfg: TransformerConfig, new_mask: jnp.ndarray, positions: jnp.ndarray,
+                block_start=None) -> jnp.ndarray:
+    """The additive bias of a cached step, [b, 1, t, S] f32: every valid
+    cache column (`new_mask` already holds this step's positions), ALiBi,
+    the sliding window, and, for a block of new positions that starts at
+    column `block_start` (one scalar, or one offset per row), the causal
+    structure within it: query j may not see the keys written for queries
+    > j. A column forbidden twice goes to -2e9, still exactly 0 after the
+    softmax."""
+    bias = decode_bias(new_mask)
+    if cfg.alibi:
+        bias = bias + alibi_bias(new_mask, cfg.n_heads)
+    if cfg.sliding_window is not None:
+        bias = bias + window_bias(positions, new_mask, cfg.sliding_window)
+    if block_start is not None:
+        t, S = positions.shape[-1], new_mask.shape[-1]
+        if jnp.ndim(block_start) == 0:
+            q_ids = jnp.arange(t)[:, None]
+            k_ids = jnp.arange(S)[None, :]
+            within = (k_ids < block_start + t) & (k_ids >= block_start) & (k_ids - block_start > q_ids)
+            within = within[None, None]
+        else:
+            q_ids = jnp.arange(t)[None, :, None]
+            k_ids = jnp.arange(S)[None, None, :]
+            first = block_start[:, None, None]
+            within = ((k_ids >= first) & (k_ids - first > q_ids))[:, None]  # [b, 1, t, S]
+        bias = bias + jnp.where(within, -1e9, 0.0).astype(jnp.float32)
+    return bias
 
 
 class TransformerLM(nn.Module):
@@ -906,9 +936,6 @@ class TransformerLM(nn.Module):
             )
         return position_ids(attn_mask)
 
-    def _train_bias(self, attn_mask):
-        return train_bias(self.cfg, attn_mask)
-
     def run_blocks(self, h, attn_bias, positions, start: int, stop: int, cache=None, cache_index=None, attn_mask=None, use_prefix: bool = True, attn_kernel: Optional[str] = None):
         new_layers = [] if cache is not None else None
         for i in range(start, stop):
@@ -927,13 +954,11 @@ class TransformerLM(nn.Module):
         use_prompt: bool = True,
     ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
         """Training/scoring forward (no cache). Returns (logits, h_split,
-        h_final) where h_split is the activation entering block `split`.
-        `use_prompt=False` skips the soft prompt (the adapter-disabled
-        reference forward under prompt tuning)."""
-        logits, h_split, h_final, _ = self.forward_captures(
-            tokens, attn_mask, positions, split, use_prompt=use_prompt
+        h_final) where h_split is the activation entering block `split`."""
+        logits, h_final, caps = self.forward(
+            tokens, attn_mask, positions, capture=(split,), use_prompt=use_prompt
         )
-        return logits, h_split, h_final
+        return logits, caps[split], h_final
 
     def _embed_soft_prompt(self, b, positions_virt):
         """Soft-prompt rows as embeddings, with the same positional/LN
@@ -948,27 +973,58 @@ class TransformerLM(nn.Module):
             h = self.ln_embed(h)
         return h
 
-    def forward_captures(
+    def forward(
         self,
-        tokens: jnp.ndarray,
-        attn_mask: jnp.ndarray,
+        x: jnp.ndarray,  # [b, t] token ids; [b, t, d] the state entering block `start` when start > 0
+        attn_mask: jnp.ndarray,  # [b, t]
         positions: Optional[jnp.ndarray] = None,
-        split: int = 0,
-        value_split: int = 0,
+        *,
+        start: int = 0,
+        stop: Optional[int] = None,
+        capture: Tuple[int, ...] = (),
+        window: Optional[Tuple[Any, int]] = None,
         use_prompt: bool = True,
     ):
-        """Like __call__ but additionally captures the activation entering
-        block `value_split` — the input of the deeper value branch
-        (reference make_value_branch feeds hidden_states[-(k+1)],
-        modeling_ppo.py:255-263, 344-346). Returns (logits, h_split,
-        h_final, h_value). Under prompt tuning (cfg.prompt_tokens > 0 and
-        use_prompt) the soft prompt is prepended internally and sliced back
-        off before the unembedding, so logits/h_final keep the caller's
-        sequence length; the captured h_split/h_value carry the extended
-        length (their consumers force split == 0 under prompt tuning)."""
-        P = self.cfg.prompt_tokens if use_prompt else 0
+        """The one forward without a cache: embed (start == 0) or take the
+        hidden state entering block `start` (the hydra frozen branch,
+        reference forward_hydra, modeling_ppo.py:410-453, when applied with
+        reference params; the trunk-cache train path with the live ones),
+        run blocks [start, stop) over the full width, unembed. Returns
+        (logits, h_final, caps):
+
+        - `caps[i]`, for each layer in `capture`, is the activation ENTERING
+          block i (start <= i <= stop): the hydra split point, the input of
+          the deeper value branch (reference make_value_branch feeds
+          hidden_states[-(k+1)], modeling_ppo.py:255-263, 344-346).
+        - `stop` given (n_layers too: a model whose blocks are all frozen)
+          runs no head: logits is None and the second slot holds the raw
+          state entering block `stop` (the frozen-prefix pass that feeds the
+          PPO trunk cache).
+        - `window=(first, length)` puts the final norm and the head over
+          positions [first, first + length) only. The 2·d·V head matmul is
+          the single largest matmul in the model; a PPO train step only
+          reads the response window of it (~40 of ~1100 positions at bench
+          shapes), so computing it full-width and slicing after —
+          especially through the fused-CE kernel, which is opaque to XLA's
+          slice-through-matmul fusion — wastes ~27x the useful head FLOPs
+          (r5 phase breakdown, VERDICT r4 weak #1).
+        - `use_prompt=False` skips the soft prompt (the adapter-disabled
+          reference forward under prompt tuning). With it, the soft prompt
+          is prepended internally and sliced back off before the
+          unembedding, so logits/h_final keep the caller's sequence length;
+          captured activations carry the extended length (their consumers
+          force split == 0 under prompt tuning)."""
+        cfg = self.cfg
+        to_head, stop = stop is None, cfg.n_layers if stop is None else stop
+        if cfg.prompt_tokens > 0 and (window is not None or not to_head):
+            raise NotImplementedError(
+                "a windowed head or a forward that stops short of it is unsupported "
+                "under prompt tuning: the soft prompt shifts every position and widens "
+                "the captured rows (resolve_split gates the trunk cache off)"
+            )
+        P = cfg.prompt_tokens if use_prompt and start == 0 else 0
         if P > 0:
-            b = tokens.shape[0]
+            b = x.shape[0]
             attn_mask = jnp.concatenate(
                 [jnp.ones((b, P), attn_mask.dtype), attn_mask], axis=1
             )
@@ -979,454 +1035,208 @@ class TransformerLM(nn.Module):
                 positions = jnp.concatenate([virt, positions + P], axis=1)
             h = jnp.concatenate(
                 [self._embed_soft_prompt(b, positions[:, :P]),
-                 self.embed(tokens, positions[:, P:])],
+                 self.embed(x, positions[:, P:])],
                 axis=1,
             )
         else:
             if positions is None:
-                positions = self._default_positions(tokens, attn_mask)
-            h = self.embed(tokens, positions)
-        bias = self._train_bias(attn_mask)
+                positions = self._default_positions(x, attn_mask)
+            h = self.embed(x, positions) if start == 0 else x
+        bias = train_bias(cfg, attn_mask)
+        bounds = sorted({start, stop, *capture})
+        if bounds[0] < start or bounds[-1] > stop:
+            raise ValueError(f"capture {capture} outside the blocks run, [{start}, {stop}]")
         caps = {}
-        bounds = sorted({0, split, value_split, self.cfg.n_layers})
         for s, e in zip(bounds, bounds[1:]):
             caps[s] = h
             h, _ = self.run_blocks(h, bias, positions, s, e, attn_mask=attn_mask,
                                    use_prefix=use_prompt)
-        caps[self.cfg.n_layers] = h
-        logits, h_final = self.unembed(h[:, P:] if P > 0 else h)
-        return logits, caps[split], h_final, caps[value_split]
-
-    def forward_window(
-        self,
-        tokens: jnp.ndarray,
-        attn_mask: jnp.ndarray,
-        positions: Optional[jnp.ndarray] = None,
-        start: int = 0,
-        length: int = 1,
-    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Trunk forward over the FULL sequence, final norm + unembedding
-        over ONLY positions [start, start+length). Returns
-        (logits_win, h_final_win), both [b, length, ...].
-
-        The 2·d·V head matmul is the single largest matmul in the model;
-        a PPO train step only reads the response window of it (~40 of
-        ~1100 positions at bench shapes), so computing it full-width and
-        slicing after — especially through the fused-CE kernel, which is
-        opaque to XLA's slice-through-matmul fusion — wastes ~27x the
-        useful head FLOPs (r5 phase breakdown, VERDICT r4 weak #1)."""
-        if self.cfg.prompt_tokens > 0:
-            raise NotImplementedError(
-                "forward_window under prompt tuning is unsupported; use the "
-                "full forward (the soft prompt shifts every position)"
-            )
-        if positions is None:
-            positions = self._default_positions(tokens, attn_mask)
-        h = self.embed(tokens, positions)
-        bias = self._train_bias(attn_mask)
-        h, _ = self.run_blocks(h, bias, positions, 0, self.cfg.n_layers,
-                               attn_mask=attn_mask)
-        hw = jax.lax.dynamic_slice_in_dim(h, start, length, axis=1)
-        return self.unembed(hw)
-
-    def forward_from(
-        self,
-        h: jnp.ndarray,
-        attn_mask: jnp.ndarray,
-        positions: Optional[jnp.ndarray] = None,
-        start_layer: int = 0,
-    ) -> jnp.ndarray:
-        """Resume the forward pass from `start_layer` given its input hidden
-        state — the hydra frozen branch (reference forward_hydra,
-        modeling_ppo.py:410-453) when applied with reference params."""
-        if positions is None:
-            positions = self._default_positions(h, attn_mask)
-        bias = self._train_bias(attn_mask)
-        h, _ = self.run_blocks(h, bias, positions, start_layer, self.cfg.n_layers, attn_mask=attn_mask)
-        logits, _ = self.unembed(h)
-        return logits
-
-    def forward_trunk(
-        self,
-        tokens: jnp.ndarray,
-        attn_mask: jnp.ndarray,
-        positions: Optional[jnp.ndarray] = None,
-        split: int = 0,
-    ) -> jnp.ndarray:
-        """Embeddings + blocks [0, split) ONLY — the frozen-prefix pass
-        producing the activation entering block `split` (the same h_split
-        `__call__` captures), with no unembedding. One such pass per rollout
-        chunk feeds the PPO trunk cache (method.cache_trunk_activations)
-        when the sampler didn't already capture it in-loop."""
-        if self.cfg.prompt_tokens > 0:
-            raise NotImplementedError(
-                "forward_trunk under prompt tuning is unsupported (the soft "
-                "prompt widens the captured rows; resolve_split gates it off)"
-            )
-        if positions is None:
-            positions = self._default_positions(tokens, attn_mask)
-        h = self.embed(tokens, positions)
-        bias = self._train_bias(attn_mask)
-        h, _ = self.run_blocks(h, bias, positions, 0, split, attn_mask=attn_mask)
-        return h
-
-    def forward_from_captures(
-        self,
-        h: jnp.ndarray,
-        attn_mask: jnp.ndarray,
-        positions: Optional[jnp.ndarray] = None,
-        start_layer: int = 0,
-        value_split: Optional[int] = None,
-    ):
-        """`forward_from` keeping the hidden states a value head needs:
-        resume blocks [start_layer, n_layers) from a cached/captured hidden
-        state, full-width unembed. Returns (logits, h_final, h_value) where
-        h_value is the activation entering block `value_split` (the deeper
-        value branch's input; requires start_layer <= value_split). With
-        value_split=None, h_value is the input `h` (unused by callers)."""
-        if positions is None:
-            positions = self._default_positions(h, attn_mask)
-        bias = self._train_bias(attn_mask)
-        vs = start_layer if value_split is None else value_split
-        caps = {}
-        bounds = sorted({start_layer, vs, self.cfg.n_layers})
-        for s, e in zip(bounds, bounds[1:]):
-            caps[s] = h
-            h, _ = self.run_blocks(h, bias, positions, s, e, attn_mask=attn_mask)
-        caps[self.cfg.n_layers] = h
+        caps[stop] = h
+        caps = {i: caps[i] for i in capture}
+        if not to_head:
+            return None, h, caps
+        if P > 0:
+            h = h[:, P:]
+        if window is not None:
+            h = jax.lax.dynamic_slice_in_dim(h, window[0], window[1], axis=1)
         logits, h_final = self.unembed(h)
-        return logits, h_final, caps[vs]
-
-    def forward_from_window(
-        self,
-        h: jnp.ndarray,
-        attn_mask: jnp.ndarray,
-        positions: Optional[jnp.ndarray] = None,
-        start_layer: int = 0,
-        start: int = 0,
-        length: int = 1,
-    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """`forward_from` with the windowed unembedding of
-        `forward_window`: run blocks [start_layer, n_layers) over the full
-        width, then final norm + head over positions [start, start+length)
-        only. Returns (logits_win, h_final_win) like forward_window — the
-        rollout fast path reads just the response window of the
-        frozen-reference logits, the trunk-cache train path additionally
-        feeds h_final_win to the value head, and the 2·d·V head matmul
-        dominates the suffix at bench shapes."""
-        if positions is None:
-            positions = self._default_positions(h, attn_mask)
-        bias = self._train_bias(attn_mask)
-        h, _ = self.run_blocks(h, bias, positions, start_layer, self.cfg.n_layers,
-                               attn_mask=attn_mask)
-        hw = jax.lax.dynamic_slice_in_dim(h, start, length, axis=1)
-        return self.unembed(hw)
+        return logits, h_final, caps
 
     def decode_step(
         self,
-        tokens: jnp.ndarray,  # [b, t] (prefill) or [b, 1] (step)
+        x: jnp.ndarray,  # [b, t] token ids; [b, t, d] the state entering block `start` when start > 0
         cache: Dict[str, Any],
-        token_mask: jnp.ndarray,  # [b, t] validity of these tokens
+        token_mask: Optional[jnp.ndarray],  # [b, t] validity of these positions
         is_prefill: bool = False,
+        *,
+        start: int = 0,
+        stop: Optional[int] = None,
         capture_split: Optional[int] = None,
+        attn_kernel: Optional[str] = None,  # paged read path: None | "pallas" | "interpret"
+        block_start: Optional[jnp.ndarray] = None,  # [b]
+        positions: Optional[jnp.ndarray] = None,  # [b, t]
     ):
-        """One cached decode call. The cache pytree carries:
-        index (scalar write offset), mask [b, S], pos [b] (next position id
-        per row), layers (per-layer k/v). Under prompt tuning the prefill
-        prepends the soft prompt into the cache (init_kv_cache reserves the
-        extra slots); logits keep the caller's sequence length.
+        """The one cached step: blocks [start, stop) over t new positions
+        against the cache, which is moved on. Returns (logits, h_final,
+        new_cache), and the activation ENTERING block `capture_split` (the
+        same hydra split point as __call__'s h_split) as a fourth when that
+        is given. A step with `stop` given (n_layers too) is an early exit:
+        no head runs, logits is None, and h_final is `ln_f`'s reading of the
+        state entering block `stop`, which a low-rank draft head projects.
 
-        `capture_split` (rollout fast path) splits the block run at that
-        layer and additionally returns the activation ENTERING it — the
-        same hydra split point as __call__'s h_split — making the return a
-        4-tuple (logits, h_final, new_cache, h_cap)."""
-        b, t = tokens.shape
-        index = cache["index"]
-        P = self.cfg.prompt_tokens if is_prefill else 0
-        if capture_split is not None and self.cfg.prompt_tokens > 0:
+        The cache pytree says which family it is. Both carry mask [b, S],
+        pos [b] (next position id per row) and layers (K/V tables for an
+        attention layer, the convolution's last inputs for a `conv` one):
+
+        - **`index`** (a scalar write offset; `init_kv_cache`): every row
+          writes at the same column, the fused sampler's cache. With
+          `is_prefill` the block is a left-padded prompt (positions from
+          its own mask, causal within the block); under prompt tuning the
+          prefill prepends the soft prompt into the cache (init_kv_cache
+          reserves the extra slots) and logits keep the caller's sequence
+          length.
+        - **`row_index`** ([b]): every row carries its OWN write offset —
+          the continuous-batching slot pool and the paged arena
+          (trlx_tpu/inference/engine.py), speculative decode. Rows sit at
+          different depths, which the shared scalar cannot express; for a
+          live row the computation is bit-identical to the scalar one on an
+          aligned batch, because masked cache columns contribute exactly
+          0.0 to every softmax sum wherever they sit (exp(-1e9) == 0.0 in
+          f32). t == 1 is a decode step: a row whose token_mask is 0 writes
+          a 0 into the mask at its current column — a value-level no-op —
+          and does not advance. t > 1 is a RIGHT-padded prefill: row r's
+          valid tokens occupy columns [row_index_r, row_index_r + len_r),
+          a nonzero row_index resumes behind a shared prefix already
+          resident in the cache (prefix-cache hit) whose mask bits the
+          caller seeds, and the pad positions write nothing the model can
+          see (mask bit 0; paged arena writes are dropped via the mask).
+
+        Speculative decode is this step three ways. A draft step is a
+        per-row t == 1 step with `stop=split`: it writes trunk K/V and sets
+        each position's mask bit as it goes — a drafted position becomes a
+        visible key only once its K/V is in the cache, so later-rejected
+        drafts roll back by clearing bits, and stale K/V beyond the
+        frontier contributes exactly zero — and passes the suffix layers'
+        caches through. The verify pass resumes `start=split` from the
+        trunk's own rows (x = the drafts' captured states), writing suffix
+        K/V for all t candidates in ONE pass; the draft steps have already
+        set its mask bits and moved row_index on, so it names the block's
+        first column (`block_start`) and its `positions` itself and moves
+        nothing (`token_mask` then only gates paged-arena writes; dense
+        caches ignore it)."""
+        cfg = self.cfg
+        to_head, stop = stop is None, cfg.n_layers if stop is None else stop
+        b, t = x.shape[:2]
+        per_row = "row_index" in cache
+        if per_row:
+            if cfg.prompt_tokens > 0 or cfg.prefix_tokens > 0:
+                raise NotImplementedError(
+                    "a per-row cache (slot pool, paged insert, speculative decode) "
+                    "under prompt/prefix tuning is unsupported"
+                )
+            refuse_conv_state(cfg, "decode_step with a per-row cache")
+        if capture_split is not None and cfg.prompt_tokens > 0:
             raise NotImplementedError(
                 "split-activation capture under prompt tuning is unsupported "
                 "(the soft prompt widens the captured rows)"
             )
+        P = cfg.prompt_tokens if is_prefill else 0
         if P > 0:
             token_mask = jnp.concatenate(
                 [jnp.ones((b, P), token_mask.dtype), token_mask], axis=1
             )
-        t_ext = t + P
-        # positions of the incoming tokens
-        if is_prefill:
-            positions = position_ids(token_mask)
-            next_pos = token_mask.sum(-1).astype(jnp.int32)
-        else:
-            positions = cache["pos"][:, None]
-            next_pos = cache["pos"] + token_mask[:, 0].astype(jnp.int32)
-        new_mask = jax.lax.dynamic_update_slice(
-            cache["mask"], token_mask.astype(cache["mask"].dtype), (0, index)
-        )
-        bias = decode_bias(new_mask, t_ext)
-        if self.cfg.alibi:
-            bias = bias + alibi_bias(new_mask, self.cfg.n_heads)
-        if self.cfg.sliding_window is not None:
-            bias = bias + window_bias(positions, new_mask, self.cfg.sliding_window)
-        if is_prefill:
-            # causal structure within the prefill block
-            S = cache["mask"].shape[-1]
-            q_ids = jnp.arange(t_ext)[:, None]
-            k_ids = jnp.arange(S)[None, :]
-            within = (k_ids < index + t_ext) & (k_ids >= index) & (k_ids - index > q_ids)
-            bias = bias + jnp.where(within[None, None], -1e9, 0.0).astype(jnp.float32)
+            t = t + P
 
-        if P > 0:
+        # the one place that moves the cache on: the mask and where the new
+        # positions are here (the bias reads both), the counters at the end
+        mask_dtype = cache["mask"].dtype
+        if not per_row:
+            offset = cache["index"]
+            if is_prefill:
+                positions = position_ids(token_mask)
+                next_pos = token_mask.sum(-1).astype(jnp.int32)
+                block_start = offset
+            else:
+                positions = cache["pos"][:, None]
+                next_pos = cache["pos"] + token_mask[:, 0].astype(jnp.int32)
+            new_mask = jax.lax.dynamic_update_slice(
+                cache["mask"], token_mask.astype(mask_dtype), (0, offset)
+            )
+        elif positions is not None:
+            # a verify pass: the draft steps set its bits and moved the counters
+            offset, advance, new_mask = block_start, None, cache["mask"]
+            positions = positions.astype(jnp.int32)
+        elif t == 1:
+            offset = cache["row_index"]
+            positions = cache["pos"][:, None]
+            advance = token_mask[:, 0].astype(jnp.int32)
+            new_mask = cache["mask"].at[jnp.arange(b), offset].set(
+                token_mask[:, 0].astype(mask_dtype)
+            )
+        else:
+            offset = block_start = cache["row_index"]
+            advance = token_mask.sum(-1).astype(jnp.int32)
+            positions = cache["pos"][:, None] + position_ids(token_mask)
+            S = cache["mask"].shape[-1]
+            cols = offset[:, None] + jnp.arange(t)[None, :]  # [b, t]
+            # pad columns land on already-zero cells (or clip to S-1, also
+            # zero until decode begins), so the scatter of their 0 is a no-op
+            new_mask = cache["mask"].at[
+                jnp.arange(b)[:, None], jnp.clip(cols, 0, S - 1)
+            ].set(token_mask.astype(mask_dtype))
+        bias = cached_bias(cfg, new_mask, positions, block_start)
+
+        if start > 0:
+            h = x
+        elif P > 0:
             h = jnp.concatenate(
                 [self._embed_soft_prompt(b, positions[:, :P]),
-                 self.embed(tokens, positions[:, P:])],
+                 self.embed(x, positions[:, P:])],
                 axis=1,
             )
         else:
-            h = self.embed(tokens, positions)
-        # the dense-cache attention never reads `attn_mask`; convolution
-        # state and sparse experts do (the per-row paths refuse such a model: `refuse_conv_state`)
-        step_mask = token_mask if self.cfg.blocks_read_token_mask else None
-        step_kernel = None
-        if (is_prefill and self.cfg.flash_prefill and self.cfg.prefix_tokens == 0 and P == 0
-                and fused_attention_ok(self.cfg, t)):
-            step_mask, step_kernel = token_mask, "prefill"
-        if capture_split is None:
-            h_cap = None
-            h, new_layers = self.run_blocks(
-                h, bias, positions, 0, self.cfg.n_layers, cache=cache["layers"],
-                cache_index=index, attn_mask=step_mask, attn_kernel=step_kernel
-            )
+            h = self.embed(x, positions)
+        if per_row:
+            # the mask gates PAGED arena writes (inactive rows scatter out
+            # of bounds and are dropped); the dense cached path never reads
+            # it, so fixed-pool graphs are unchanged
+            step_mask = token_mask
         else:
-            # split the block run so the activation entering block
-            # `capture_split` comes out; cache layer indices are absolute,
-            # so concatenating the two halves' new layers is exact
-            h, low = self.run_blocks(
-                h, bias, positions, 0, capture_split, cache=cache["layers"],
-                cache_index=index, attn_mask=step_mask, attn_kernel=step_kernel
+            # the dense-cache attention never reads it; convolution state
+            # and sparse experts do
+            step_mask = token_mask if cfg.blocks_read_token_mask else None
+            if (is_prefill and cfg.flash_prefill and cfg.prefix_tokens == 0 and P == 0
+                    and fused_attention_ok(cfg, t)):
+                step_mask, attn_kernel = token_mask, "prefill"
+        # cache layer indices are absolute, so the segments' new layers
+        # concatenate exactly, behind and before the layers passed through
+        bounds = sorted({start, stop, capture_split} - {None})
+        new_layers, h_cap = list(cache["layers"][:start]), None
+        for s, e in zip(bounds, bounds[1:]):
+            if s == capture_split:
+                h_cap = h
+            h, segment = self.run_blocks(
+                h, bias, positions, s, e, cache=cache["layers"],
+                cache_index=offset, attn_mask=step_mask, attn_kernel=attn_kernel,
             )
+            new_layers += segment
+        if stop == capture_split:
             h_cap = h
-            h, high = self.run_blocks(
-                h, bias, positions, capture_split, self.cfg.n_layers,
-                cache=cache["layers"], cache_index=index, attn_mask=step_mask, attn_kernel=step_kernel
-            )
-            new_layers = low + high
-        logits, h = self.unembed(h[:, P:] if P > 0 else h)
-        new_cache = {
-            "index": index + t_ext,
-            "mask": new_mask,
-            "pos": next_pos,
-            "layers": new_layers,
-        }
+        new_layers += cache["layers"][stop:]
+        if to_head:
+            logits, h = self.unembed(h[:, P:] if P > 0 else h)
+        else:
+            logits, h = None, self.ln_f(h)
+        if not per_row:
+            counters = {"index": offset + t, "pos": next_pos}
+        elif advance is None:
+            counters = {"row_index": cache["row_index"], "pos": cache["pos"]}
+        else:
+            counters = {"row_index": cache["row_index"] + advance, "pos": cache["pos"] + advance}
+        new_cache = {**counters, "mask": new_mask, "layers": new_layers}
         if capture_split is not None:
             return logits, h, new_cache, h_cap
         return logits, h, new_cache
-
-    def decode_step_rows(
-        self,
-        tokens: jnp.ndarray,  # [b, 1]
-        cache: Dict[str, Any],
-        token_mask: jnp.ndarray,  # [b, 1] validity (0 = free/inactive slot)
-        attn_kernel: Optional[str] = None,  # paged read path: None | "pallas" | "interpret"
-    ):
-        """One cached decode step where every row carries its OWN write
-        offset (`cache["row_index"]`, [b]) — the continuous-batching slot
-        pool (trlx_tpu/inference/engine.py). Rows sit at different decode
-        depths, so the shared scalar `index` of `decode_step` cannot
-        express the cache write; per-row offsets can, and for a live row
-        the computation is bit-identical to `decode_step` on an aligned
-        batch (masked cache columns contribute exactly zero). Inactive
-        rows (token_mask 0) write a 0 into the mask at their current
-        column — a value-level no-op — and do not advance. Returns
-        (logits, new_cache)."""
-        if self.cfg.prompt_tokens > 0 or self.cfg.prefix_tokens > 0:
-            raise NotImplementedError(
-                "slot-pool decode under prompt/prefix tuning is unsupported"
-            )
-        refuse_conv_state(self.cfg, "decode_step_rows")
-        b, _ = tokens.shape
-        row_index = cache["row_index"]
-        positions = cache["pos"][:, None]
-        step_valid = token_mask[:, 0].astype(jnp.int32)
-        new_mask = cache["mask"].at[jnp.arange(b), row_index].set(
-            token_mask[:, 0].astype(cache["mask"].dtype)
-        )
-        bias = decode_bias(new_mask, 1)
-        if self.cfg.alibi:
-            bias = bias + alibi_bias(new_mask, self.cfg.n_heads)
-        if self.cfg.sliding_window is not None:
-            bias = bias + window_bias(positions, new_mask, self.cfg.sliding_window)
-        h = self.embed(tokens, positions)
-        # attn_mask gates PAGED arena writes (inactive rows scatter out of
-        # bounds and are dropped); the dense cached path never reads it,
-        # so fixed-pool graphs are unchanged
-        h, new_layers = self.run_blocks(
-            h, bias, positions, 0, self.cfg.n_layers,
-            cache=cache["layers"], cache_index=row_index, attn_mask=token_mask,
-            attn_kernel=attn_kernel,
-        )
-        logits, _ = self.unembed(h)
-        new_cache = {
-            "row_index": row_index + step_valid,
-            "mask": new_mask,
-            "pos": cache["pos"] + step_valid,
-            "layers": new_layers,
-        }
-        return logits, new_cache
-
-    def prefill_rows(
-        self,
-        tokens: jnp.ndarray,  # [b, t] RIGHT-padded prompt (suffix) tokens
-        cache: Dict[str, Any],
-        token_mask: jnp.ndarray,  # [b, t] validity (0 = right pad)
-    ):
-        """Multi-token cached prefill where every row carries its OWN write
-        offset (`cache["row_index"]`, [b]) — the paged engine's insert
-        path. Row r's valid tokens occupy cache columns
-        [row_index_r, row_index_r + len_r); a nonzero row_index means the
-        row resumes behind a shared prefix already resident in the cache
-        (prefix-cache hit), whose mask bits the caller seeds. Queries see
-        every valid cache column plus the causal prefix of their own
-        freshly-written span — the same within-block correction
-        `decode_step` applies at prefill, with per-row offsets like
-        `spec_verify_rows`. Right-pad positions write nothing the model
-        can see: their mask bit is 0 (exactly-zero attention weight) and
-        paged arena writes are dropped via `attn_mask`. Per-row values are
-        bit-identical to a left-padded `decode_step` prefill of the same
-        tokens — masked columns contribute exactly 0.0 to every softmax
-        sum regardless of where they sit. Returns (logits, new_cache)."""
-        if self.cfg.prompt_tokens > 0 or self.cfg.prefix_tokens > 0:
-            raise NotImplementedError(
-                "slot-pool prefill under prompt/prefix tuning is unsupported"
-            )
-        refuse_conv_state(self.cfg, "prefill_rows")
-        b, t = tokens.shape
-        row_index = cache["row_index"]
-        lens = token_mask.sum(-1).astype(jnp.int32)
-        positions = cache["pos"][:, None] + position_ids(token_mask)
-        S = cache["mask"].shape[-1]
-        cols = row_index[:, None] + jnp.arange(t)[None, :]  # [b, t]
-        # pad columns land on already-zero cells (or clip to S-1, also
-        # zero until decode begins), so the scatter of their 0 is a no-op
-        new_mask = cache["mask"].at[
-            jnp.arange(b)[:, None], jnp.clip(cols, 0, S - 1)
-        ].set(token_mask.astype(cache["mask"].dtype))
-        bias = decode_bias(new_mask, t)
-        if self.cfg.alibi:
-            bias = bias + alibi_bias(new_mask, self.cfg.n_heads)
-        if self.cfg.sliding_window is not None:
-            bias = bias + window_bias(positions, new_mask, self.cfg.sliding_window)
-        q_ids = jnp.arange(t)[None, :, None]
-        k_ids = jnp.arange(S)[None, None, :]
-        start = row_index[:, None, None]
-        within = (k_ids >= start) & (k_ids - start > q_ids)  # [b, t, S]
-        bias = bias + jnp.where(within[:, None], -1e9, 0.0).astype(jnp.float32)
-        h = self.embed(tokens, positions)
-        h, new_layers = self.run_blocks(
-            h, bias, positions, 0, self.cfg.n_layers,
-            cache=cache["layers"], cache_index=row_index, attn_mask=token_mask,
-        )
-        logits, _ = self.unembed(h)
-        new_cache = {
-            "row_index": row_index + lens,
-            "mask": new_mask,
-            "pos": cache["pos"] + lens,
-            "layers": new_layers,
-        }
-        return logits, new_cache
-
-    def spec_draft_step(
-        self,
-        tokens: jnp.ndarray,  # [b, 1]
-        cache: Dict[str, Any],
-        token_mask: jnp.ndarray,  # [b, 1] validity (0 = finished/inactive row)
-        split: int,
-        attn_kernel: Optional[str] = None,
-    ):
-        """One per-row cached TRUNK step (blocks [0, split) only) for
-        self-speculative drafting: embed + frozen-prefix blocks, no
-        unembedding. Writes trunk K/V at each row's own offset
-        (`cache["row_index"]`) exactly like `decode_step_rows`, leaves the
-        suffix layers' caches untouched (the verify pass writes those), and
-        returns the activation entering block `split` twice: raw (the same
-        h_split `decode_step(capture_split=split)` captures) and through
-        `ln_f` (the early-exit readout the low-rank draft head projects).
-        Mask bits are written incrementally — a drafted position becomes a
-        visible key only once its K/V is in the cache, so later-rejected
-        drafts roll back by clearing bits, and stale K/V beyond the
-        frontier contributes exactly zero (exp(-1e9) == 0.0 in f32)."""
-        if self.cfg.prompt_tokens > 0 or self.cfg.prefix_tokens > 0:
-            raise NotImplementedError(
-                "speculative decode under prompt/prefix tuning is unsupported"
-            )
-        refuse_conv_state(self.cfg, "spec_draft_step")
-        b, _ = tokens.shape
-        row_index = cache["row_index"]
-        positions = cache["pos"][:, None]
-        step_valid = token_mask[:, 0].astype(jnp.int32)
-        new_mask = cache["mask"].at[jnp.arange(b), row_index].set(
-            token_mask[:, 0].astype(cache["mask"].dtype)
-        )
-        bias = decode_bias(new_mask, 1)
-        if self.cfg.alibi:
-            bias = bias + alibi_bias(new_mask, self.cfg.n_heads)
-        if self.cfg.sliding_window is not None:
-            bias = bias + window_bias(positions, new_mask, self.cfg.sliding_window)
-        h = self.embed(tokens, positions)
-        h, trunk_layers = self.run_blocks(
-            h, bias, positions, 0, split, cache=cache["layers"],
-            cache_index=row_index, attn_mask=token_mask, attn_kernel=attn_kernel,
-        )
-        new_cache = {
-            "row_index": row_index + step_valid,
-            "mask": new_mask,
-            "pos": cache["pos"] + step_valid,
-            "layers": trunk_layers + cache["layers"][split:],
-        }
-        return h, self.ln_f(h), new_cache
-
-    def spec_verify_rows(
-        self,
-        h: jnp.ndarray,  # [b, t, d] trunk output at the t drafted positions
-        cache: Dict[str, Any],
-        row_start: jnp.ndarray,  # [b] cache offset of h's first position
-        positions: jnp.ndarray,  # [b, t]
-        split: int,
-        token_mask: Optional[jnp.ndarray] = None,  # [b, t] write validity
-    ):
-        """Batched suffix verify for self-speculative decode: resume blocks
-        [split, n_layers) from the trunk's own h_split rows (the
-        forward_from_captures schedule, but against the per-row KV cache),
-        writing suffix K/V for all t candidate positions in ONE pass, so
-        verify pays the suffix blocks only. Assumes mask bits for offsets
-        [row_start, row_start + t) were already set by the preceding
-        `spec_draft_step` calls; within that block, query j may not see
-        keys written for queries > j — the same within-block causal
-        correction `decode_step` applies at prefill, with per-row offsets
-        (doubly-forbidden columns go to -2e9, still exactly 0 after
-        softmax). Returns (logits, h_final, new_layers) where new_layers
-        is the full per-layer cache list (trunk entries passed through)."""
-        refuse_conv_state(self.cfg, "spec_verify_rows")
-        b, t, _ = h.shape
-        new_mask = cache["mask"]
-        positions_f = positions.astype(jnp.int32)
-        bias = decode_bias(new_mask, t)
-        if self.cfg.alibi:
-            bias = bias + alibi_bias(new_mask, self.cfg.n_heads)
-        if self.cfg.sliding_window is not None:
-            bias = bias + window_bias(positions_f, new_mask, self.cfg.sliding_window)
-        S = new_mask.shape[-1]
-        q_ids = jnp.arange(t)[None, :, None]
-        k_ids = jnp.arange(S)[None, None, :]
-        start = row_start[:, None, None]
-        within = (k_ids >= start) & (k_ids - start > q_ids)  # [b, t, S]
-        bias = bias + jnp.where(within[:, None], -1e9, 0.0).astype(jnp.float32)
-        h, suffix_layers = self.run_blocks(
-            h, bias, positions_f, split, self.cfg.n_layers,
-            cache=cache["layers"], cache_index=row_start, attn_mask=token_mask,
-        )
-        logits, h_final = self.unembed(h)
-        return logits, h_final, cache["layers"][:split] + suffix_layers
 
 
 def position_ids(attn_mask: jnp.ndarray) -> jnp.ndarray:
@@ -1444,7 +1254,8 @@ def refuse_conv_state(cfg: TransformerConfig, what: str) -> None:
     if getattr(cfg, "has_conv_layers", False):
         raise NotImplementedError(
             f"{what}: the convolution state of a `conv` layer (layer_types) is not "
-            "supported here; only `decode_step` (the fused sampler) carries it"
+            "supported here; only `decode_step` on a scalar-`index` cache (the fused "
+            "sampler) carries it"
         )
 
 

@@ -132,7 +132,7 @@ def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
         score = score + fwd(T, T / 2, layers=L - unfrozen, with_head=False, top=False)
     # one train step: the trunk runs full-width fwd + dX/dW over the
     # unfrozen top. When the r5 windowed head applies (ppo_trainer
-    # forward_window — no MoE, no deeper value branch, no soft prompt),
+    # `forward(window=...)` — no MoE, no deeper value branch, no soft prompt),
     # the 2·d·V unembedding (fwd + dX) only covers the n_new response
     # positions the loss reads; otherwise the step really computes the
     # full-width head and the estimate must charge all T positions.

@@ -176,7 +176,7 @@ def make_generate_fn(
       the batched scorer);
     - "h_split"   [b, plen + max_new, d] — activation entering block
       `capture_split`, so the frozen-reference branch can resume from the
-      hydra split (forward_ref_suffix) without re-running shared layers.
+      hydra split (`forward(start=split)`) without re-running shared layers.
 
     Single-beam causal LM only."""
     max_new = gen_cfg.max_new_tokens
@@ -482,26 +482,26 @@ def make_generate_fn(
             )
 
         def spec_draft(params, tokens, cache, token_mask):
-            return model.apply(
-                {"params": params}, tokens, cache, token_mask, spec_split,
-                method=type(model).spec_draft_step,
+            """One trunk-only step, blocks [0, split): (h_split, its `ln_f`
+            reading for the draft head, cache). No head runs."""
+            _, h_norm, cache, h_split = model.apply(
+                {"params": params}, tokens, cache, token_mask,
+                stop=spec_split, capture_split=spec_split,
+                method=type(model).decode_step,
             )
+            return h_split, h_norm, cache
 
         def spec_verify(params, h, cache, row_start, positions):
-            if capture:
-                return model.apply(
-                    {"params": params}, h, cache, row_start, positions,
-                    spec_split, with_value=True,
-                    method=type(model).spec_verify_rows,
-                )
+            """The suffix blocks over all k+1 drafted positions at once."""
             out = model.apply(
-                {"params": params}, h, cache, row_start, positions, spec_split,
-                method=type(model).spec_verify_rows,
+                {"params": params}, h, cache, None,
+                start=spec_split, block_start=row_start, positions=positions,
+                method=type(model).decode_step,
+                **({"with_value": True} if capture else {}),
             )
-            # policy wrapper returns (logits, None, layers); a bare
-            # TransformerLM returns (logits, h_final, layers) — slot 1 is
-            # unused either way without capture
-            return out[0], None, out[2]
+            # slot 1 is the policy wrapper's values (None without capture)
+            # or a bare TransformerLM's h_final: unused without capture
+            return out[0], out[1] if capture else None, out[2]
 
         def warp(raw_logits, prev_token, step):
             return process_logits(
@@ -594,11 +594,10 @@ def make_generate_fn(
                         toks_fed.append(f)
                 h_block = jnp.concatenate(h_rows, axis=1)  # [b, k+1, d]
                 positions = pos_start[:, None] + jnp.arange(k + 1)[None, :]
-                logits_v, values_v, new_layers = spec_verify(
+                logits_v, values_v, cache = spec_verify(
                     params, h_block, cache, row_start, positions
                 )
                 logits_v = logits_v.astype(jnp.float32)
-                cache = dict(cache, layers=new_layers)
                 p_scores = [
                     warp(logits_v[:, j], toks_fed[j], out_i + j)
                     for j in range(k + 1)

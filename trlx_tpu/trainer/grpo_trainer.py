@@ -164,21 +164,19 @@ class GRPOTrainer(PPOTrainer):
                 )
                 logprobs = logprobs_of_labels(logits[:, :-1, :], tokens[:, 1:])
                 logprobs = logprobs[:, start:end]
-            elif self._window_loss_ok():
-                logits_w, _ = model.apply(
-                    {"params": params}, tokens, attention_mask, positions,
-                    start, response_length,
-                    method=type(model).forward_window,
-                )
-                logprobs = logprobs_of_labels(
-                    logits_w, tokens[:, start + 1 : end + 1]
-                )
             else:
+                window = (start, response_length) if self._window_loss_ok() else None
                 logits, _, _ = model.apply(
-                    {"params": params}, tokens, attention_mask, positions
+                    {"params": params}, tokens, attention_mask, positions,
+                    window=window, method=type(model).forward,
                 )
-                logprobs = logprobs_of_labels(logits[:, :-1, :], tokens[:, 1:])
-                logprobs = logprobs[:, start:end]
+                if window is not None:
+                    logprobs = logprobs_of_labels(
+                        logits, tokens[:, start + 1 : end + 1]
+                    )
+                else:
+                    logprobs = logprobs_of_labels(logits[:, :-1, :], tokens[:, 1:])
+                    logprobs = logprobs[:, start:end]
 
             loss, stats = grpo_loss(
                 logprobs=logprobs,

@@ -109,7 +109,7 @@ class PipelinedPPOTrainer(PipelinedCausalMixin, PPOTrainer):
     def _fast_rollout_available(self) -> bool:
         """The rollout fast path is unavailable here: the frozen reference
         lives STACKED over the pipe axis (_build_ref_params above), and
-        the suffix resume (forward_ref_suffix_window) needs the unstacked
+        the suffix resume (`forward(start=split)`) needs the unstacked
         per-block layout — the speculative/classic scorer stays in
         charge."""
         if (
@@ -127,7 +127,7 @@ class PipelinedPPOTrainer(PipelinedCausalMixin, PPOTrainer):
     def _trunk_cache_available(self) -> bool:
         """The trunk cache is unavailable here for the same reason as the
         fast rollout path: params live STACKED over the pipe axis, and
-        the suffix resume (forward_from_cache) needs the unstacked
+        the suffix resume (`forward(start=split)`) needs the unstacked
         per-block layout — the full-forward train loss stays in charge."""
         if (
             getattr(self.config.method, "cache_trunk_activations", False)
@@ -144,7 +144,7 @@ class PipelinedPPOTrainer(PipelinedCausalMixin, PPOTrainer):
     def _spec_decode_available(self) -> bool:
         """Speculative decode is unavailable here for the same reason as
         the fast rollout path: the draft/verify split applies
-        (spec_draft_step / spec_verify_rows) need the unstacked per-block
+        (`decode_step(stop=split)` / `(start=split)`) need the unstacked per-block
         layout — the plain sampler stays in charge."""
         if (
             getattr(self.config.method, "speculative_decode", False)

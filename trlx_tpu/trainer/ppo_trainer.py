@@ -23,7 +23,6 @@ from trlx_tpu.data import PPORLBatch, PPORLElement
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.data.method_configs import MethodConfig, register_method
 from trlx_tpu.models import (
-    CausalLMWithValueHead,
     build_model,
     forward_policy_and_ref,
     forward_seq2seq_policy_and_ref,
@@ -84,7 +83,7 @@ class PPOConfig(MethodConfig):
     # chunk's tokens is invariant across all ppo_epochs inner epochs.
     # Capture h_split once per chunk (reusing the rollout fast path's
     # in-loop capture when available, else one jitted trunk pass) and
-    # train the suffix from it (forward_from_cache[_window]), skipping the
+    # train the suffix from it (`forward(start=split)`), skipping the
     # frozen-prefix forward every optimizer step. Default off: flag off is
     # bit-identical to the uncached loss. Extra fields vs the reference.
     cache_trunk_activations: bool = False
@@ -97,7 +96,7 @@ class PPOConfig(MethodConfig):
     # Self-speculative decode: the frozen hydra trunk plus a low-rank SVD
     # readout of the unembedding drafts spec_k tokens per round; one
     # batched suffix pass verifies all of them from the trunk's own
-    # h_split (forward_from_captures economics applied to sampling) and
+    # h_split (the trunk cache's economics applied to sampling) and
     # accepts the longest matching prefix with exact rejection-sampling
     # correction — greedy output stays bitwise the plain sampler's,
     # sampled output follows the identical distribution. Default off:
@@ -208,7 +207,7 @@ class PPOTrainer(TPUTrainer):
 
     def _window_loss_ok(self) -> bool:
         """Whether the train loss can use the windowed head
-        (forward_window): needs the plain MLP value head and no soft
+        (`forward`'s `window`): needs the plain MLP value head and no soft
         prompt (the branch attends full-width; the prompt shifts
         positions)."""
         return (
@@ -326,6 +325,7 @@ class PPOTrainer(TPUTrainer):
                 return lp[:, start:end], values_full[:, :-1][:, start:end]
 
             moe_aux, moe_stats = 0.0, {}
+            x, first = tokens, 0
             if batch.h_split is not None:
                 # Trunk-cache train path (method.cache_trunk_activations):
                 # resume the trainable suffix from the per-chunk cached
@@ -342,24 +342,8 @@ class PPOTrainer(TPUTrainer):
                     # would be a reshard (device_put) that perturbs backward
                     # reduction order and breaks the bitwise-equality contract
                     h0 = jax.lax.with_sharding_constraint(h0, cache_sharding)
-                h0 = jax.lax.stop_gradient(h0.astype(self.model_cfg.dtype))
-                if self._window_loss_ok():
-                    logits_w, values_pred = model.apply(
-                        {"params": params}, h0, attention_mask, positions,
-                        self.split, start, response_length,
-                        method=type(model).forward_from_cache_window,
-                    )
-                    logprobs = logprobs_of_labels(
-                        logits_w, tokens[:, start + 1:end + 1]
-                    )
-                else:
-                    logits, values_full = model.apply(
-                        {"params": params}, h0, attention_mask, positions,
-                        self.split,
-                        method=type(model).forward_from_cache,
-                    )
-                    logprobs, values_pred = window_from_full(logits, values_full)
-            elif getattr(self.model_cfg, "sows_moe_aux", False):
+                x, first = jax.lax.stop_gradient(h0.astype(self.model_cfg.dtype)), self.split
+            if batch.h_split is None and getattr(self.model_cfg, "sows_moe_aux", False):
                 from trlx_tpu.utils.modeling import apply_with_moe_aux
 
                 (logits, values_full, _), moe_aux = apply_with_moe_aux(
@@ -367,33 +351,32 @@ class PPOTrainer(TPUTrainer):
                     tokens, attention_mask, positions,
                 )
                 logprobs, values_pred = window_from_full(logits, values_full)
-            elif self._window_loss_ok():
-                # window the head (r5): trunk runs full-width, the
-                # 50k-vocab unembed + fused CE + value head run over the
-                # response window only — the loss reads exactly this
-                # slice, and the full-width head was the cycle's largest
-                # wasted matmul (tests/test_trainers.py pins equality with
-                # the full-forward loss)
+            else:
+                # window the head (r5) where it can be: the blocks run
+                # full-width, the 50k-vocab unembed + fused CE + value head
+                # over the response window only — the loss reads exactly
+                # this slice, and the full-width head was the cycle's
+                # largest wasted matmul (tests/test_trainers.py pins
+                # equality with the full-forward loss)
+                window = (start, response_length) if self._window_loss_ok() else None
+                sown = sparse_moe and batch.h_split is None and window is not None
                 out = model.apply(
-                    {"params": params}, tokens, attention_mask, positions,
-                    start, response_length,
-                    method=type(model).forward_window,
-                    **({"mutable": ["moe_stats"]} if sparse_moe else {}),
+                    {"params": params}, x, attention_mask, positions,
+                    start=first, window=window, method=type(model).forward,
+                    **({"mutable": ["moe_stats"]} if sown else {}),
                 )
-                if sparse_moe:  # SparseMoE layers sow their dispatch counters
+                if sown:  # SparseMoE layers sow their dispatch counters
                     from trlx_tpu.models.transformer import moe_stats_from_state
 
-                    out, sown = out
-                    moe_stats = moe_stats_from_state(sown)
-                logits_w, values_pred = out
-                logprobs = logprobs_of_labels(
-                    logits_w, tokens[:, start + 1:end + 1]
-                )
-            else:
-                logits, values_full, _ = model.apply(
-                    {"params": params}, tokens, attention_mask, positions
-                )
-                logprobs, values_pred = window_from_full(logits, values_full)
+                    out, state = out
+                    moe_stats = moe_stats_from_state(state)
+                logits, values_pred, _ = out
+                if window is not None:
+                    logprobs = logprobs_of_labels(
+                        logits, tokens[:, start + 1:end + 1]
+                    )
+                else:
+                    logprobs, values_pred = window_from_full(logits, values_pred)
 
             loss, stats = ppo_loss(
                 logprobs=logprobs,
@@ -1810,9 +1793,9 @@ class PPOTrainer(TPUTrainer):
             params = merge_params(train_params, frozen_params)
             attention_mask = (tokens != pad_id).astype(jnp.int32)
             positions = position_ids(attention_mask)
-            h = model.apply(
-                {"params": params}, tokens, attention_mask, positions, split,
-                method=CausalLMWithValueHead.forward_trunk,
+            _, h, _ = model.apply(
+                {"params": params}, tokens, attention_mask, positions, stop=split,
+                method=type(model).forward,
             )
             return h.astype(dtype)
 
@@ -1978,10 +1961,10 @@ class PPOTrainer(TPUTrainer):
             attention_mask = (samples != pad_id).astype(jnp.int32)
             positions = position_ids(attention_mask)
             start = q - 1
-            ref_logits_w = model.apply(
-                {"params": {"lm": ref_params}}, h_split, attention_mask,
-                positions, split, start, max_new,
-                method=CausalLMWithValueHead.forward_ref_suffix_window,
+            ref_logits_w, _, _ = model.apply(
+                {"params": {"lm": ref_params}}, h_split, attention_mask, positions,
+                start=split, window=(start, max_new), with_value=False,
+                method=type(model).forward,
             )
             labels = jax.lax.dynamic_slice_in_dim(samples, q, max_new, axis=1)
             ref_lp = logprobs_of_labels(ref_logits_w, labels)

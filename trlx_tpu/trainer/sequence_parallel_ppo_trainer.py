@@ -123,7 +123,7 @@ class SequenceParallelPPOTrainer(PPOTrainer):
     def _spec_decode_available(self) -> bool:
         """Speculative decode is unavailable here: rollouts run through
         the sharded generate layout, and the draft/verify applies
-        (spec_draft_step / spec_verify_rows) live outside it — the plain
+        (`decode_step(stop=split)` / `(start=split)`) live outside it — the plain
         sampler stays in charge."""
         if (
             getattr(self.config.method, "speculative_decode", False)

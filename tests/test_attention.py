@@ -406,3 +406,132 @@ def test_flash_backward_memory_is_not_quadratic():
     # O(t^2) in f32 would be >= t*t*4 = 64MB per head; linear-in-t buffers
     # at these shapes stay far below
     assert total < t * t * 4, f"backward temps look quadratic: {total}"
+
+
+# -- the dense path under grouped heads --------------------------------------
+#
+# `Attention`'s dense contraction (every cached or unfused call) keeps K and V
+# at n_kv_heads and folds the group into the query's axes. The reference is
+# what it did before: K and V repeated to the query heads, `jnp.repeat(k, g,
+# axis=2)`, in front of the multi-head products. Here that is the same module
+# at n_kv_heads == n_heads whose K/V projections, prefix slots and cache are
+# the grouped ones repeated head by head, so the reference runs through the
+# multi-head branch, which holds no grouping at all.
+
+HD = 8
+
+
+def _attn_cfg(nh, nkv, **extra):
+    from trlx_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(**{**dict(
+        vocab_size=32, d_model=nh * HD, n_layers=1, n_heads=nh, n_kv_heads=nkv, d_ff=16,
+        pos_embed="rope", attn_impl="xla", dtype=jnp.float32, param_dtype=jnp.float32), **extra})
+
+
+def _repeat_kv_heads(tree, nkv, g):
+    """The grouped module's parameters (or cache) with every kv head
+    repeated g times: what `jnp.repeat(k, g, axis=2)` made of K and V."""
+    def leaf(path, a):
+        name = "/".join(str(getattr(k, "key", k)) for k in path)
+        if name.endswith(("k_proj/kernel", "v_proj/kernel", "k_proj/bias", "v_proj/bias")):
+            heads = a.reshape(a.shape[:-1] + (nkv, HD))
+            return jnp.repeat(heads, g, axis=-2).reshape(a.shape[:-1] + (nkv * g * HD,))
+        if name.endswith(("prefix_k", "prefix_v")):  # [P, nkv, hd]
+            return jnp.repeat(a, g, axis=1)
+        if name in ("k", "v"):  # a layer's cache [b, S, nkv, hd]
+            return jnp.repeat(a, g, axis=2)
+        return a
+
+    return jax.tree_util.tree_map_with_path(leaf, tree)
+
+
+GROUPS = [(4, 2), (8, 2), (4, 1)]  # groups of 2 and 4, and MQA
+VARIANTS = {
+    "plain": {},
+    "alibi": dict(alibi=True, pos_embed="none"),
+    "window": dict(sliding_window=5),
+    "prefix": dict(prefix_tokens=3),
+}
+# every group plain; ALiBi, the window and the prefix on one group each
+GROUPED_CASES = [(nh, nkv, "plain") for nh, nkv in GROUPS] + [
+    (4, 2, "alibi"), (8, 2, "window"), (4, 1, "prefix")]
+
+
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar_index", "row_index"])
+@pytest.mark.parametrize("t", [1, 3], ids=["one_position", "block_of_3"])
+@pytest.mark.parametrize("nh,nkv,variant", GROUPED_CASES,
+                         ids=[f"{nh}q{nkv}kv-{v}" for nh, nkv, v in GROUPED_CASES])
+def test_dense_cached_attention_keeps_kv_heads_and_matches_repeated_kv(nh, nkv, variant, t, per_row):
+    from trlx_tpu.models.transformer import Attention, cached_bias
+
+    g = nh // nkv
+    cfg, ref_cfg = _attn_cfg(nh, nkv, **VARIANTS[variant]), _attn_cfg(nh, nh, **VARIANTS[variant])
+    b, S = 3, 12
+    keys = jax.random.split(jax.random.PRNGKey(nh * 10 + nkv + t), 4)
+    h = jax.random.normal(keys[0], (b, t, cfg.d_model), jnp.float32)
+    # rows at their own depths (the slot pool) or all at one (the sampler)
+    depth = jnp.asarray([4, 7, 2] if per_row else [5, 5, 5], jnp.int32)
+    cache_index = depth if per_row else depth[0]
+    cols = jnp.arange(S)[None, :]
+    new_mask = (cols < depth[:, None] + t).astype(jnp.int32)
+    if not per_row:
+        new_mask = new_mask.at[1, :2].set(0)  # a left-padded row
+    positions = depth[:, None] + jnp.arange(t)[None, :] - (1 - new_mask[:, :1]) * 2
+    bias = cached_bias(cfg, new_mask, positions, block_start=cache_index if t > 1 else None)
+    filled = (cols < depth[:, None])[:, :, None, None]
+    cache = {name: jnp.where(filled, jax.random.normal(key, (b, S, nkv, HD), jnp.float32), 0.0)
+             for name, key in zip("kv", keys[1:3])}
+    args = (h, bias, positions)
+    params = Attention(cfg).init(keys[3], *args, cache, cache_index)["params"]
+    if variant == "prefix":  # the slots start near 0: make them count
+        params = jax.tree_util.tree_map_with_path(
+            lambda p, a: a * 50.0 if "prefix" in str(p[-1]) else a, params)
+
+    out, new_cache = Attention(cfg).apply({"params": params}, *args, cache, cache_index)
+    ref, ref_cache = Attention(ref_cfg).apply(
+        {"params": _repeat_kv_heads(params, nkv, g)}, *args, _repeat_kv_heads(cache, nkv, g), cache_index)
+
+    assert out.shape == (b, t, cfg.d_model)
+    assert new_cache["k"].shape == (b, S, nkv, HD)  # the cache stays at n_kv_heads
+    np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+    for name in "kv":
+        np.testing.assert_allclose(jnp.repeat(new_cache[name], g, axis=2), ref_cache[name], atol=1e-6)
+
+
+@pytest.mark.parametrize("nh,nkv,variant", [(4, 2, "plain"), (4, 1, "alibi")],
+                         ids=["4q2kv-plain", "4q1kv-alibi"])
+def test_dense_forward_gradients_match_repeated_kv(nh, nkv, variant):
+    """A whole `attn_impl="xla"` forward of a grouped model: its logits and
+    every gradient against the multi-head model with the K/V projections
+    repeated. A repeated head's gradient is spread over its g copies, so the
+    reference's are summed back over them."""
+    from trlx_tpu.models.transformer import TransformerLM
+
+    g = nh // nkv
+    extra = dict(VARIANTS[variant], n_layers=2)
+    cfg, ref_cfg = _attn_cfg(nh, nkv, **extra), _attn_cfg(nh, nh, **extra)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 9), 0, cfg.vocab_size)
+    mask = jnp.ones_like(tokens).at[0, :3].set(0)
+    params = TransformerLM(cfg).init(jax.random.PRNGKey(1), tokens, mask)["params"]
+    weight = jax.random.normal(jax.random.PRNGKey(2), (2, 9, cfg.vocab_size), jnp.float32)
+
+    def loss(model_cfg):
+        return lambda p: (TransformerLM(model_cfg).apply({"params": p}, tokens, mask)[0] * weight).sum()
+
+    value, grads = jax.value_and_grad(loss(cfg))(params)
+    ref_value, ref_grads = jax.value_and_grad(loss(ref_cfg))(_repeat_kv_heads(params, nkv, g))
+    np.testing.assert_allclose(value, ref_value, rtol=1e-5)
+
+    def fold(a, like):
+        if a.shape == like.shape:
+            return a
+        heads = a.reshape(a.shape[:-1] + (nkv, g, HD))  # a k_proj / v_proj leaf
+        return heads.sum(axis=-2).reshape(like.shape)
+
+    folded = jax.tree_util.tree_map(fold, ref_grads, params)
+    moved = 0
+    for (path, got), want in zip(jax.tree_util.tree_leaves_with_path(grads), jax.tree_util.tree_leaves(folded)):
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-4, err_msg=str(path))
+        moved += bool(np.abs(got).max() > 0)
+    assert moved == len(jax.tree_util.tree_leaves(grads))

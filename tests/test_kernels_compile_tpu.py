@@ -161,18 +161,32 @@ def test_paged_decode_compiles_without_copying_the_arena(v5e, heads, blk, dtype)
         assert arena_rewrites(compiled, arena, *args[5:]) == []
 
 
+def _instructions(compiled):
+    """(elements of the result, opcode, text) of every instruction of a
+    compiled program whose result is one array."""
+    for line in compiled.as_text().splitlines():
+        m = re.search(r"= \w+\[([\d,]+)\]\S* ([\w-]+)\(", line)
+        if m:
+            yield int(np.prod([int(d) for d in m.group(1).split(",")])), m.group(2), line.strip()
+
+
 def arena_rewrites(compiled, *operands) -> list:
     """The `copy` / `transpose` instructions of a compiled program whose
     result has as many elements as one of `operands` (a K/V arena or an
     int8 scale plane, under whatever shape the program views it): each is
     the whole operand moved through HBM once more than the work needs."""
     sizes = {int(np.prod(op.shape)) for op in operands}
-    found = []
-    for line in compiled.as_text().splitlines():
-        m = re.search(r"= \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", line)
-        if m and int(np.prod([int(d) for d in m.group(1).split(",")])) in sizes:
-            found.append(line.strip()[:120])
-    return found
+    return [text[:120] for n, op, text in _instructions(compiled)
+            if op in ("copy", "transpose") and n in sizes]
+
+
+def instructions_of_at_least(compiled, elements: int) -> list:
+    """The instructions of a compiled program whose result has `elements`
+    elements or more, other than a buffer's update in place and what only
+    names a buffer."""
+    names_or_updates = ("parameter", "bitcast", "get-tuple-element", "tuple", "dynamic-update-slice")
+    return [text[:160] for n, op, text in _instructions(compiled)
+            if n >= elements and op not in names_or_updates]
 
 
 def donated_outputs(compiled) -> int:
@@ -419,9 +433,11 @@ def test_expert_layer_compiles_and_leaves_its_stacks_where_they_lie(v5e, pallas_
     assert arena_rewrites(bwd, *stacks) == []
 
 
-def test_lfm2_decode_step_compiles_without_copying_an_expert_stack(v5e, pallas_mode):
-    """One cached step of the cell's 10-layer model, 64 rows, through the
-    K/V tables and the convolution states."""
+def _lfm2_decode_step(v5e, leaves=F32):
+    """One cached step of the cell's 10-layer model, 64 rows over a cache of
+    1024, through the K/V tables and the convolution states, compiled for
+    one v5e chip with parameters of type `leaves`: the program, the
+    parameters' shapes and the cache's."""
     from trlx_tpu.models import config_from_preset, init_kv_cache
     from trlx_tpu.models.transformer import TransformerLM
 
@@ -431,6 +447,7 @@ def test_lfm2_decode_step_compiles_without_copying_an_expert_stack(v5e, pallas_m
     b, total = 64, 1024
     tokens = jnp.zeros((1, 8), I32)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
+    params = jax.tree_util.tree_map(lambda a: S(a.shape, leaves), params)
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, b, total))
     assert [sorted(layer) for layer in cache["layers"]] == [
         ["conv"] if kind == "conv" else ["k", "v"] for kind in cfg.layer_types]
@@ -441,8 +458,28 @@ def test_lfm2_decode_step_compiles_without_copying_an_expert_stack(v5e, pallas_m
     compiled = jax.jit(step, donate_argnums=(2,)).trace(
         *abstract((params, S((b, 1), I32), cache, S((b, 1), I32)), one)).lower(
         lowering_platforms=("tpu",)).compile()
+    return compiled, params, cache
+
+
+def test_lfm2_decode_step_compiles_without_copying_an_expert_stack(v5e, pallas_mode):
+    compiled, params, _ = _lfm2_decode_step(v5e)
     assert kernel_names(compiled).count("moe_gmm") == 3 * 8
     assert arena_rewrites(compiled, *_expert_stacks(params)) == []
+
+
+def test_lfm2_decode_step_reads_its_kv_cache_once_for_all_query_heads(v5e, pallas_mode):
+    """The two attention layers contract 32 query heads against a cache of 8
+    kv heads. Repeating K and V to the query heads first made every step
+    write and read `[64, 1024, 8, 4, 64]` broadcasts, float32 and bfloat16
+    (5.83 GB a step by the compiler's count, over the sampler's bfloat16
+    copy of the parameters); the grouped contraction reads the cache where
+    it lies (3.03 GB): nothing the size of a repeated cache is left."""
+    compiled, _, cache = _lfm2_decode_step(v5e, BF16)
+    k = next(layer["k"] for layer in cache["layers"] if "k" in layer)
+    assert k.shape == (64, 1024, 8, 64)
+    repeated = int(np.prod(k.shape)) * 4  # 8 kv heads -> 32 query heads
+    assert instructions_of_at_least(compiled, repeated) == []
+    assert compiled.cost_analysis()["bytes accessed"] < 3.5e9
 
 
 def test_lfm2_train_step_fits_the_chip_at_batch_16(v5e, pallas_mode, capsys):

@@ -527,16 +527,26 @@ class Attention(nn.Module):
                 out = flash_attention(q, k, v, mask=attn_mask, causal=True)
             out = out.astype(cfg.dtype)
         else:
-            if nkv != nh:  # GQA: repeat kv heads for the dense einsum path
-                rep = nh // nkv
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
+            qk, pv = "bthd,bshd->bhts", "bhts,bshd->bthd"
+            if nkv != nh:
+                # GQA/MQA: K/V stay at n_kv_heads — query head h reads kv
+                # head h // g, so the g query heads of a kv head are one more
+                # axis of the same two products and the cache is read once
+                # for all of them, never repeated to n_heads.
+                g = nh // nkv
+                q = q.reshape(b, t, nkv, g, hd)
+                qk, pv = "btkgd,bskd->bkgts", "bkgts,bskd->btkgd"
+                # [b, 1, t, S] broadcasts over both head axes; a per-head
+                # bias (ALiBi's [b, nh, 1, S]) splits its heads as q did
+                attn_bias = (
+                    attn_bias[:, :, None] if attn_bias.shape[1] == 1
+                    else attn_bias.reshape(attn_bias.shape[0], nkv, g, *attn_bias.shape[2:]))
             scale = 1.0 / np.sqrt(hd)
-            # [b, h, t, S] — accumulate scores in f32 for stability.
-            scores = jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32) * scale
+            # [b, h, t, S] ([b, nkv, g, t, S]) — accumulate scores in f32 for stability.
+            scores = jnp.einsum(qk, q, k, preferred_element_type=jnp.float32) * scale
             scores = scores + attn_bias  # bias is f32, -inf on masked
             probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            out = jnp.einsum("bhts,bshd->bthd", probs, v)
+            out = jnp.einsum(pv, probs, v)
         out = out.reshape(b, t, nh * hd)
         out = dense(d, "o_proj")(out)
         return out, new_cache

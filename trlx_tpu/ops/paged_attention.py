@@ -3,8 +3,8 @@ and the paged KV arena's layout, which this module alone knows.
 
 The gather read path (`paged_kv_gather`) serves decode steps by copying
 every block of a slot's block table back into a dense [b, n_tbl*block,
-nkv, hd] view, dequantizing int8 arenas into a SECOND materialized copy
-and `jnp.repeat`-ing kv heads up to n_heads for GQA before softmax·V.
+nkv, hd] view and dequantizing int8 arenas into a SECOND materialized copy
+before the dense contraction (which keeps K/V at n_kv_heads under GQA).
 The kernel collapses the read side into one Pallas call whose time
 follows the tokens resident, not the table's length:
 
@@ -407,7 +407,7 @@ def paged_attention_decode(
     E = _tile_entries(n_tbl, nkv, blk, hd, k_arena.dtype)
     blocks, row, tile, n_live, n_work, n_tiles = _live_schedule(table, key_mask, blk, E)
     T = E * blk
-    # Head order matches the dense path's jnp.repeat(k, group, axis=2):
+    # Head order matches the dense path's (and jnp.repeat(k, group, axis=2)):
     # q head h attends kv head h // group, so [b, nh, hd] -> [b, nkv,
     # group, hd] keeps each kv head's q-group contiguous.
     qg = q.reshape(b, nkv, group, hd).astype(qk_dtype)

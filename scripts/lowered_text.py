@@ -16,7 +16,7 @@ Needs no chip: every program is traced at its cell's widths and recipe
 platform with the Pallas kernels on, never compiled or run. The PPO cells'
 trainers are built as `bench/jobs/ppo.py` builds them and stopped at the
 first call of each jitted program (`_ljit` is where a trainer makes one);
-the serve cell's engine as `tests/test_kernels_compile_tpu.py` builds it.
+the serve cells' engines as `tests/test_kernels_compile_tpu.py` builds them.
 """
 
 import argparse
@@ -36,7 +36,9 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 PPO_CELLS = ("pythia-1.4b.ppo-hh", "gpt2-xl.ppo-sentiments", "lfm2-8b-a1b.ppo-hh")
-SERVE_CELL = "pythia-1.4b.rollout-batch"
+# serve cell -> the (rows, width) of the insert programs whose text is taken
+SERVE_CELLS = {"pythia-1.4b.rollout-batch": ((1, 256), (8, 512)),
+               "laguna-xs.2.rollout-code": ((1, 2048), (2, 4096))}
 
 
 class Lowered(Exception):
@@ -111,24 +113,27 @@ def ppo_programs(workload: str, layers: int) -> dict:
     return texts
 
 
-def serve_programs(layers: int) -> dict:
+def serve_programs(workload: str, layers: int) -> dict:
     from benchlib import files
     from jax.sharding import SingleDeviceSharding
     from trlx_tpu.inference import InferenceEngine
     from trlx_tpu.models import CausalLMPolicy, config_from_preset
+    from trlx_tpu.models.transformer import prefill_fuses
     from trlx_tpu.ops.sampling import GenerationConfig
 
-    _, cell, config, _ = files.load_cell(SERVE_CELL)
+    _, cell, config, mix = files.load_cell(workload)
     eng = cell["engine"]
-    extra = config["program"]["model_extra_configs"]
-    cfg = config_from_preset(config["program"]["model_path"].split(":")[1], extra["vocab_size"],
-                             n_layers=layers, attn_impl=extra["attn_impl"],
-                             param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
+    # the configuration as `bench/jobs/serve.py` builds it, its per-layer lists cut with the depth
+    extra = {k: v[:layers] if isinstance(v, list) else v
+             for k, v in config["program"]["model_extra_configs"].items()}
+    extra["n_layers"] = layers
+    cfg = config_from_preset(config["program"]["model_path"].split(":")[1], extra.pop("vocab_size"),
+                             **extra, param_dtype=jnp.bfloat16, dtype=jnp.bfloat16)
     model = CausalLMPolicy(cfg)
     tokens = jnp.zeros((1, 32), jnp.int32)
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
-    gen_cfg = GenerationConfig(max_new_tokens=128, do_sample=True,
+    gen_cfg = GenerationConfig(max_new_tokens=int(mix["output_len"]["max"]), do_sample=True,
                                eos_token_id=cfg.vocab_size + 1, pad_token_id=0)
     tpu = types.SimpleNamespace(platform="tpu")  # the engine picks the kernel by its params' device
     InferenceEngine._param_devices = lambda self: [tpu]
@@ -144,12 +149,13 @@ def serve_programs(layers: int) -> dict:
     pool, params = abstract(engine._pool), abstract(params)
     texts = {"engine.decode": lower_text(engine._decode_fn, (params, pool))}
     n_tbl = engine._pool["table"].shape[1]
-    for rows, width in ((1, 256), (8, 512)):
+    for rows, width in SERVE_CELLS[workload]:
         shapes = dict(ids=(rows, width), tmask=(rows, width), tables=(rows, n_tbl),
                       slot_ids=(rows,), max_new=(rows,), shared_len=(rows,))
         args = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one) for s in shapes.values()]
-        texts[f"engine.paged_insert[b{rows},p{width}]"] = lower_text(
-            engine._get_paged_insert(rows, width), (pool, params, *args))
+        fresh = prefill_fuses(cfg, width)  # the program a prompt with no cached prefix takes
+        texts[f"engine.paged_insert[b{rows},p{width}{',fresh' if fresh else ''}]"] = lower_text(
+            engine._get_paged_insert(rows, width, fresh), (pool, params, *args))
     return texts
 
 
@@ -199,13 +205,13 @@ def main():
     parser.add_argument("--compare", nargs=2, metavar=("A", "B"))
     parser.add_argument("--out")
     parser.add_argument("--layers", type=int, default=4)
-    parser.add_argument("--cells", nargs="*", default=[*PPO_CELLS, SERVE_CELL])
+    parser.add_argument("--cells", nargs="*", default=[*PPO_CELLS, *SERVE_CELLS])
     args = parser.parse_args()
     if args.compare:
         return compare(*args.compare)
     os.makedirs(args.out, exist_ok=True)
     for cell in args.cells:
-        texts = serve_programs(args.layers) if cell == SERVE_CELL else ppo_programs(cell, args.layers)
+        texts = (serve_programs if cell in SERVE_CELLS else ppo_programs)(cell, args.layers)
         for name, text in texts.items():
             path = os.path.join(args.out, re.sub(r"[^\w.\-]+", "_", f"{cell}.{name}") + ".txt")
             with open(path, "w") as f:

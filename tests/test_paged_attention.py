@@ -447,9 +447,11 @@ def test_kernel_dispatch_counters(trainers):
 
 def test_live_entry_share_counts_the_columns_held(trainers, monkeypatch):
     """`kv_live_entry_share` is the host's own count of table entries the
-    next decode step attends to, over slots x table entries; the
-    `trlx:engine.dispatch` span carries the count while a tracing session
-    is active, and nothing otherwise."""
+    next decode step to be dispatched attends to, over slots x table
+    entries: the columns fetched, the one the step in flight writes, and its
+    own. The `trlx:engine.dispatch` span carries the count, and `ahead=1`
+    where a step was in flight, while a tracing session is active, and
+    nothing otherwise."""
     from trlx_tpu.observability import tracing
 
     eng = make_engine(trainers["llama-tiny"], "interpret", max_new=8)
@@ -461,12 +463,15 @@ def test_live_entry_share_counts_the_columns_held(trainers, monkeypatch):
     seen = []
     real_span = tracing.span
     monkeypatch.setattr(tracing, "span", lambda name, **a: (seen.append((name, a)), real_span(name, **a))[1])
-    eng.step()  # writes column 8: the next step's 9th starts a second block
-    assert ("engine.dispatch", {}) in seen
-    assert eng.kv_stats()["kv_live_entry_share"] == share(8 + 1) == 2 / (2 * n_tbl)
+    eng.step()  # its own step writes column 8, the one it leaves in flight column 9
+    assert seen.count(("engine.dispatch", {})) == 2
+    assert eng.kv_stats()["kv_live_entry_share"] == share(8 + 1 + 1) == 2 / (2 * n_tbl)
     monkeypatch.setattr(tracing, "active", lambda: True)
     eng.step()
-    assert ("engine.dispatch", {"live_entries": 2}) in seen
+    assert ("engine.dispatch", {"live_entries": 2, "ahead": 1}) in seen
+    stats = eng.kv_stats()
+    assert (stats["decode_steps_total"], stats["decode_steps_ahead_total"],
+            stats["decode_outputs_masked_total"]) == (2, 2, 0)
     eng.reclaim_slots([1])
     assert eng.kv_stats()["kv_live_entry_share"] == 0.0
 

@@ -15,6 +15,7 @@ import glob
 import os
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -181,7 +182,11 @@ def test_scheduler_and_engine_spans(paged_engine, tmp_path):
                           ("engine.insert", "sched.insert_batch")):
         assert_inside(spans, child, parent)
     steps = named(spans, "engine.step")
-    assert len(steps) == len(named(spans, "engine.dispatch")) == len(named(spans, "engine.fetch"))
+    # a call fetches one step and dispatches the next ahead of that fetch;
+    # a call with nothing in flight dispatches its own step first
+    dispatches = named(spans, "engine.dispatch")
+    assert len(steps) == len(named(spans, "engine.fetch")) == sum(1 for d in dispatches if d[3].get("ahead"))
+    assert len(steps) < len(dispatches) <= 2 * len(steps)
     # the engine's step counter joins a span to a step: consecutive, from
     # where the engine stood when the session began
     ns = [s[3]["step_n"] for s in steps]
@@ -195,6 +200,56 @@ def test_scheduler_and_engine_spans(paged_engine, tmp_path):
     # the counter beside the spans: calls of `_insert_batch` (their rows are
     # the spans' `rows`)
     assert scheduler.metrics.get("prefill_batches_total") >= len(inserts)
+
+
+@pytest.mark.parametrize("jax_as_installed", [True, False], ids=["own_stop", "another_jax"])
+def test_stop_writes_the_xplane_and_nothing_else(tmp_path, monkeypatch, jax_as_installed):
+    """`stop()` ends the session itself where JAX keeps it as the installed
+    one does: the one file readers open, and not the gzipped JSON copy of the
+    whole trace that `jax.profiler.stop_trace()` adds (most of a stop's time
+    after seconds of a busy device). Under a JAX that keeps its session
+    otherwise the stop is the public one, copy and all. Either way readers
+    find the spans and the profiler is free for the next session."""
+    if not jax_as_installed:
+        monkeypatch.setattr(tracing, "_own_session", lambda: None)
+    for name in ("a", "b"):
+        tracing.start(str(tmp_path / name))
+        with tracing.span("unit.work"):
+            pass
+        out = tracing.stop()
+        files = [f for _, _, fs in os.walk(out) for f in fs]
+        assert sum(f.endswith(".xplane.pb") for f in files) == 1
+        assert (len(files) == 1) == jax_as_installed
+        assert any(s[0] == "trlx:unit.work" for line in read_spans(out).values() for s in line)
+
+
+@pytest.mark.parametrize("case", ["nothing_open", "another_shape", "no_state", "another_release"])
+def test_own_session_is_taken_by_release_and_shape(tmp_path, monkeypatch, case):
+    """The private place is used only under a JAX release the stop was run
+    against on the chip and while it looks as it does there: with no session
+    open, a state without its `reset`, no state at all, or a session open
+    under another release, the control stops the public way."""
+    from jax._src import profiler as jax_profiler
+
+    if case == "nothing_open":
+        assert tracing._own_session() is None
+        return
+    if case == "another_shape":
+        monkeypatch.setattr(jax_profiler, "_profile_state", object())
+    elif case == "no_state":
+        monkeypatch.delattr(jax_profiler, "_profile_state")
+    if case != "another_release":
+        assert tracing._own_session() is None
+        return
+    tracing.start(str(tmp_path / "a"))
+    try:
+        assert tracing._own_session() is jax_profiler._profile_state
+        monkeypatch.setattr(jax, "__version__", "0.10.0")
+        assert tracing._own_session() is None
+    finally:
+        out = tracing.stop()  # the public stop, copy and all
+    files = [f for _, _, fs in os.walk(out) for f in fs]
+    assert sum(f.endswith(".xplane.pb") for f in files) == 1 and len(files) > 1
 
 
 def test_second_start_raises_and_stop_needs_a_session(tmp_path):

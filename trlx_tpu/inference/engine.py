@@ -16,7 +16,11 @@ that monolith into the two Orca/vLLM-style primitives:
 
 Slots are freed the step their request finishes (eos / length budget /
 cancel) and newly prefilled requests are scattered into free slots
-mid-flight, so the decode batch stays full under mixed lengths. Prompt
+mid-flight, so the decode batch stays full under mixed lengths. `step`
+keeps one decode program in flight: it dispatches the next step before it
+waits for the tokens of the one dispatched a call earlier, so whatever the
+host does between two calls runs while the device works (a freed slot is
+refilled one step later for it). Prompt
 widths are bucketed to multiples of 32 and prefill rows to powers of two
 (the `_bucket_prompts` idiom from base_trainer.py) to bound
 recompilation.
@@ -37,7 +41,7 @@ lock read at each dispatch.
 import contextlib
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +107,17 @@ _KV_DTYPES = {
 }
 
 
+class _InFlight(NamedTuple):
+    """A decode program that was dispatched and whose outputs the host has
+    not fetched. `rows[slot]` says whether the slot's output still belongs
+    to the request that held the slot at dispatch: release, reclaim and
+    insert clear it (in place), and the fetch hands out nothing for a row
+    that lost it."""
+
+    out: tuple  # device arrays: token, logprob, emitted, finished[, moe stats]
+    rows: np.ndarray  # [num_slots] bool
+
+
 class InferenceEngine:
     """Generation over a fixed pool of `num_slots` KV-cache slots.
 
@@ -150,6 +165,14 @@ class InferenceEngine:
         self.compile_ledger = compile_ledger
         self.hbm = hbm_ledger
         self._step_n = 0
+        # the decode program dispatched and not yet fetched (`_InFlight`),
+        # or None; `_live` is the host's own book of the slots whose request
+        # still decodes (insert sets a slot, a fetched `finished` and
+        # release / reclaim clear it)
+        self._ahead: Optional[_InFlight] = None
+        self._live = np.zeros((int(num_slots),), bool)
+        self._steps_ahead = 0
+        self._outputs_masked = 0
         if getattr(model_cfg, "is_seq2seq", False):
             raise NotImplementedError(
                 "the continuous-batching engine serves causal LMs only"
@@ -447,7 +470,8 @@ class InferenceEngine:
 
     def set_params(self, params) -> int:
         """Atomically swap the served params. In-flight requests continue
-        on the new weights from their next decode step — the KV cache
+        on the new weights from the next decode step to be dispatched: the
+        step already in flight (`step`) ends on the old ones — the KV cache
         keeps the old prefix's keys/values, exactly like serving a live
         policy mid-update. Under speculative decode the low-rank draft
         head is recomputed from the fresh unembedding (host-side SVD) so
@@ -1288,22 +1312,31 @@ class InferenceEngine:
             },
         )
 
-    def insert_requests(self, *args, **kwargs) -> None:
+    def insert_requests(self, rows, slot_ids, **kwargs) -> None:
         """OOM-guarded wrapper over `_insert_requests_impl` (see there for
         the contract); samples the HBM ledger at the prefill boundary."""
+        # the step in flight was dispatched before these rows: whatever it
+        # holds for their slots is not theirs
+        self._disown(slot_ids)
         try:
-            self._insert_requests_impl(*args, **kwargs)
+            self._insert_requests_impl(rows, slot_ids, **kwargs)
         except Exception as e:
             self._maybe_oom_postmortem("engine.insert", e)
             raise
+        self._live[np.asarray(slot_ids, np.int64)] = True
         if self.hbm is not None:
             self.hbm.sample("engine.insert")
 
     def step(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """OOM-guarded wrapper over `_step_impl` (see there for the return
-        contract); samples the HBM ledger every 64th decode step — often
-        enough to catch the arena high-water mark, rare enough to stay off
-        the hot path."""
+        """One decode step's outputs, one step a call, in order (`_step_impl`
+        has the return contract and what runs ahead). OOM-guarded; samples
+        the HBM ledger every 64th decode step — often enough to catch the
+        arena high-water mark, rare enough to stay off the hot path. An
+        error of a step surfaces at the call that fetches it, and costs the
+        requests that step's token and no other: the step dispatched behind
+        it stays in flight and is what the next call returns (a dispatch
+        that fails raises at once and leaves the step in flight where it
+        was)."""
         self._step_n += 1
         try:
             with tracing.span("engine.step", step_n=self._step_n):
@@ -1324,31 +1357,46 @@ class InferenceEngine:
         by the emitted mask. Finished slots are already deactivated in
         the pool. The logprob is the policy's raw-logit log-probability
         of the emitted token (see `_sample_fused`), meaningful only where
-        `emitted`."""
-        # how ragged the rows are is what the paged kernel's time follows;
-        # said on the span only while a tracing session listens
-        traced = self.kv_paging and tracing.active()
-        attrs = {"live_entries": self._live_entries()} if traced else {}
-        if traced:
-            tracing.counters("engine.kv_walk", **self._kv_walk())
-        with tracing.span("engine.dispatch", **attrs):
-            if self.spec_k > 0:
-                params, head = self._current_params_and_head()
-                self._pool, *out = self._decode_fn(params, self._pool, head[0], head[1])
-            elif self.multi_tenant:
-                params = self._current_params()
-                self._pool, *out = self._decode_fn(params, self._pool, self.adapter_store.stacked())
-            else:
-                params = self._current_params()
-                self._pool, *out = self._decode_fn(params, self._pool)
+        `emitted`.
+
+        One decode program stays in flight: a call dispatches the NEXT step
+        first and only then waits for the step dispatched a call earlier,
+        which it returns. The program takes its input token from the pool
+        on the device, deactivates finished rows itself and never remaps a
+        block, so it needs nothing the host learns from the step before it;
+        what the caller does between two calls (emit, reclaim, insert,
+        release, `set_params`) runs while the device works and queues
+        behind the step in flight. With nothing in flight (the first call,
+        or every row of the step in flight released, reclaimed or refilled
+        since) the call dispatches its own step, then the one after, and
+        waits for its own. A slot's output belongs to the request that held
+        the slot when the step was dispatched: for a slot released,
+        reclaimed or inserted into since, `emitted` and `finished` come
+        back false."""
+        if self._ahead is not None and not self._ahead.rows.any():
+            self._ahead = None  # nobody waits for it: the call's own step comes first
+        if self._ahead is None:
+            self._ahead = self._dispatch_decode()
+        # still `_ahead` while the next step is dispatched, which counts the
+        # columns this one writes as in flight
+        due = self._ahead
+        self._ahead = self._dispatch_decode()
         with tracing.span("engine.fetch"):
-            token, logprob, valid, finished, *moe = jax.device_get(tuple(out))
+            token, logprob, valid, finished, *moe = jax.device_get(due.out)
         if moe:  # a model with `SparseMoE` layers: the step's dispatch counters
             self._moe_stats = {k: float(v) for k, v in moe[0].items()}
-            if traced:
+            if self.kv_paging and tracing.active():
                 tracing.counters("engine.moe", **self._moe_stats)
+        rows = due.rows if valid.ndim == 1 else due.rows[:, None]
+        self._outputs_masked += int((valid & ~rows).sum())
+        valid = valid & rows
+        finished = finished & due.rows
+        # the device deactivated these rows itself: the step in flight
+        # holds nothing for them
+        self._live[finished] = False
+        self._ahead.rows[finished] = False
         if self.kv_paging:
-            self._slot_cols += np.asarray(valid).reshape(self.num_slots, -1).sum(-1)
+            self._slot_cols += valid.reshape(self.num_slots, -1).sum(-1)
         # kernel dispatch accounting (driver thread; read under _kv_lock
         # by kv_stats), after the step has run: a decode dispatch either
         # rode the fused kernel or fell back to the gather path for a
@@ -1366,15 +1414,47 @@ class InferenceEngine:
                     self._kv_kernel_fallbacks["spec_verify_rows"] = (
                         self._kv_kernel_fallbacks.get("spec_verify_rows", 0) + 1
                     )
-        return (
-            np.asarray(token),
-            np.asarray(logprob, np.float32),
-            np.asarray(valid).astype(bool),
-            np.asarray(finished).astype(bool),
-        )
+        return np.asarray(token), np.asarray(logprob, np.float32), valid, finished
+
+    def _dispatch_decode(self) -> _InFlight:
+        """Queue one decode program on the device and start its outputs'
+        copy to the host; wait for nothing."""
+        ahead = self._ahead is not None
+        self._steps_ahead += int(ahead)
+        # how ragged the rows are is what the paged kernel's time follows;
+        # said on the span only while a tracing session listens
+        attrs = {}
+        if tracing.active():
+            if self.kv_paging:
+                attrs["live_entries"] = self._live_entries()
+                tracing.counters("engine.kv_walk", **self._kv_walk())
+            if ahead:
+                attrs["ahead"] = 1
+        with tracing.span("engine.dispatch", **attrs):
+            if self.spec_k > 0:
+                params, head = self._current_params_and_head()
+                self._pool, *out = self._decode_fn(params, self._pool, head[0], head[1])
+            elif self.multi_tenant:
+                params = self._current_params()
+                self._pool, *out = self._decode_fn(params, self._pool, self.adapter_store.stacked())
+            else:
+                params = self._current_params()
+                self._pool, *out = self._decode_fn(params, self._pool)
+            # or the copy starts only when the fetch asks for it, and the
+            # device stands still meanwhile (PERF.md section 5)
+            for leaf in jax.tree_util.tree_leaves(out):
+                leaf.copy_to_host_async()
+        return _InFlight(tuple(out), self._live.copy())
+
+    def _disown(self, slots: Sequence[int]) -> None:
+        """The step in flight no longer speaks for these slots."""
+        if self._ahead is not None:
+            self._ahead.rows[np.asarray(slots, np.int64)] = False
 
     def release_slots(self, slots: Sequence[int]) -> None:
-        """Deactivate slots host-side (deadline cancel / shutdown)."""
+        """Deactivate slots host-side (stop sequence / deadline cancel /
+        shutdown). The deactivation queues behind the step in flight, which
+        still decodes a token for each of them: `step` drops it."""
         if not len(slots):
             return
         idx = jnp.asarray(np.asarray(slots, np.int32))
@@ -1385,8 +1465,11 @@ class InferenceEngine:
         """Return a finished slot's blocks to the pool and drop its
         adapter pin (host bookkeeping only — no device op; a freed slot's
         stale table is harmless because inactive rows' arena writes are
-        gated out). Idempotent; the scheduler calls this for natural
+        gated out). What the step in flight still holds for the slot
+        reaches nobody. Idempotent; the scheduler calls this for natural
         finishes; `release_slots` folds it into cancels."""
+        self._disown(slots)
+        self._live[np.asarray(slots, np.int64)] = False
         if self.multi_tenant:
             self._release_adapters(slots)
         if not self.kv_paging:
@@ -1460,11 +1543,17 @@ class InferenceEngine:
         return int((-(-self._next_columns() // self.kv_block_size)).sum())
 
     def _next_columns(self) -> np.ndarray:
-        """Columns the next decode step attends over, for each slot with a
-        request: what the slot holds and the step's own one."""
+        """Columns the next decode step to be dispatched attends over, for
+        each slot with a request: what the host has counted for the slot,
+        the one column that the step in flight writes for a row it still
+        speaks for, and the step's own one."""
         with self._kv_lock:
             slots = list(self._slot_blocks)
-        return np.minimum(self._slot_cols[slots] + 1, self._cache_len)
+        cols = self._slot_cols[slots] + 1
+        ahead = self._ahead
+        if ahead is not None:
+            cols += ahead.rows[slots]
+        return np.minimum(cols, self._cache_len)
 
     def _kv_walk(self) -> Dict[str, int]:
         """Key positions the next decode step reads against the positions
@@ -1532,6 +1621,13 @@ class InferenceEngine:
                 "kv_kernel_dispatches": self._kv_kernel_dispatches,
                 "kv_kernel_fallbacks": dict(self._kv_kernel_fallbacks),
                 "kv_live_entry_share": self._live_entries() / (self.num_slots * self._n_tbl),
+                # decode steps dispatched while their predecessor's outputs
+                # were unfetched, of `decode_steps_total` calls of `step`;
+                # slot outputs a fetch dropped because the slot had been
+                # released, reclaimed or refilled since the dispatch
+                "decode_steps_total": self._step_n,
+                "decode_steps_ahead_total": self._steps_ahead,
+                "decode_outputs_masked_total": self._outputs_masked,
             }
 
     # ------------------------------------------------------------------
@@ -1605,11 +1701,7 @@ class InferenceEngine:
 
     @property
     def active_slots(self) -> int:
-        try:
-            n = int(np.asarray(self._pool["active"]).sum())
-        except RuntimeError:
-            # a jitted step donated the pool out from under this reader
-            # (healthz probe racing decode) — serve the last observed count
-            return getattr(self, "_active_snapshot", self.num_slots)
-        self._active_snapshot = n
-        return n
+        """Slots whose request still decodes, by the host's own book: a
+        step behind the device, and never a wait for it (any thread may
+        ask while a step is in flight)."""
+        return int(self._live.sum())

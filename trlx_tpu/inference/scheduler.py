@@ -185,6 +185,9 @@ class Scheduler:
         self._thread: Optional[threading.Thread] = None
         # EWMA of decode-step wall time, feeding Retry-After predictions
         self._decode_ewma = 0.0
+        # when `engine.step()` last returned, while the loop has decoded in
+        # every turn since (`_decode_once` times a step by it)
+        self._step_returned: Optional[float] = None
         self._slots_active_peak = 0
         self._last_session_sweep = 0.0
 
@@ -529,6 +532,7 @@ class Scheduler:
                 # paused with nothing in flight: queued requests must
                 # wait for resume_admission, so don't busy-spin on them
                 if idle or (self._paused and not self._slot_req):
+                    self._step_returned = None
                     self._cond.wait(timeout=0.05)
                     continue
             try:
@@ -538,8 +542,11 @@ class Scheduler:
                 if self._slot_req:
                     with tracing.span("sched.decode_once", rows=len(self._slot_req)):
                         self._decode_once()
+                else:
+                    self._step_returned = None
             except Exception:  # pragma: no cover - defensive: keep serving
                 logger.exception("inference scheduler step failed")
+                self._step_returned = None
                 time.sleep(0.05)
 
     def _expire_queued(self) -> None:
@@ -802,7 +809,15 @@ class Scheduler:
         t0 = time.perf_counter()
         m0 = time.monotonic() if self.tracer is not None else 0.0
         tokens, logprobs, valid, finished = self.engine.step()
-        dt = time.perf_counter() - t0
+        t1 = time.perf_counter()
+        # a step's time is the spacing of two successive returns of
+        # `engine.step()`: the engine keeps a step in flight, so the call
+        # alone is the wait for a step dispatched a call earlier and leaves
+        # out the host's work between calls (emit, admit), whichever of the
+        # two sides is the slower. The first step after a turn without one
+        # has only its own call
+        dt = t1 - (t0 if self._step_returned is None else self._step_returned)
+        self._step_returned = t1
         self.metrics.observe("decode_step_latency_seconds", dt)
         self._decode_ewma = (
             dt if self._decode_ewma == 0.0 else 0.8 * self._decode_ewma + 0.2 * dt
@@ -879,7 +894,7 @@ class Scheduler:
                 Span(
                     "decode_step", t0=m0,
                     attrs={"slots": len(self._slot_req), "tokens": emitted},
-                ).end(m0 + dt)
+                ).end(m0 + t1 - t0)
             )
         self._sync_kv_metrics()
 
@@ -975,6 +990,11 @@ class Scheduler:
                 self.metrics.set_gauge(name, stats[name])
         for name in (
             "prefix_cache_hits", "prefix_cache_misses", "prefix_cache_evictions",
+            # `engine.step` keeps one decode program in flight: steps
+            # dispatched behind an unfetched one, and slot outputs dropped
+            # because the slot changed hands meanwhile
+            "decode_steps_total", "decode_steps_ahead_total",
+            "decode_outputs_masked_total",
         ):
             self.metrics.set_counter(name, stats[name])
         # paged decode kernel dispatch accounting (absolute-synced like

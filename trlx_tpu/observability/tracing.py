@@ -40,6 +40,7 @@ one cross-process timeline per request.
 import json
 import os
 import shutil
+import socket
 import tempfile
 import threading
 import time
@@ -85,16 +86,65 @@ def start(log_dir: Optional[str] = None) -> str:
 
 def stop() -> str:
     """Stop the active session and return the directory it wrote (the
-    `.xplane.pb` lies under `plugins/profile/<time>/`)."""
+    `.xplane.pb` lies under `plugins/profile/<time>/`, where TensorBoard and
+    `jax.profiler.ProfileData.from_file` look for it).
+
+    `jax.profiler.stop_trace()` also converts the whole trace to a gzipped
+    JSON copy (`<host>.trace.json.gz`) that nothing of this repo reads:
+    69 of the 115 s a stop takes after 4 s of a 24-layer engine's decode
+    steps on a TPU (PERF.md section 6, PR 36). Under the JAX release this
+    was run against, and while it keeps its one session as 0.9.0 does
+    (`_own_session`), the session is stopped and the `.xplane.pb` alone
+    written here; under any other JAX the stop is
+    `jax.profiler.stop_trace()`, copy and all."""
     global _session_dir
     with _session_lock:
         if _session_dir is None:
             raise RuntimeError("no tracing session is active")
         # the session counts as active until the profiler has stopped: a
         # stop that raises leaves the control saying what the profiler does
-        jax.profiler.stop_trace()
+        state = _own_session()
+        if state is None:
+            jax.profiler.stop_trace()
+            xspace = None
+        else:
+            with state.lock:
+                xspace = state.profile_session.stop()
+                state.reset()
         log_dir, _session_dir = _session_dir, None
+    if xspace is not None:
+        run_dir = os.path.join(log_dir, "plugins", "profile", time.strftime("%Y_%m_%d_%H_%M_%S"))
+        os.makedirs(run_dir, exist_ok=True)
+        with open(os.path.join(run_dir, f"{socket.gethostname()}.xplane.pb"), "wb") as f:
+            f.write(xspace)
     return log_dir
+
+
+# the JAX releases whose private session `stop()` was run against on the chip
+# (PERF.md section 6, PR 36); any other release stops the public way
+_OWN_STOP_JAX = ("0.9.",)
+
+
+def _own_session():
+    """Where `jax.profiler.start_trace` keeps the session it opened, under a
+    JAX release this was run against (`_OWN_STOP_JAX`) and only while it
+    keeps it as 0.9.0 does (a private module: checked by version and by
+    shape at every stop, never assumed): `jax._src.profiler._profile_state`
+    with a `lock`, a `reset()` and a `profile_session` whose `stop()` returns
+    the serialized trace. None otherwise, and the caller stops the public
+    way."""
+    if not jax.__version__.startswith(_OWN_STOP_JAX):
+        return None
+    try:
+        from jax._src import profiler as jax_profiler
+    except ImportError:
+        return None
+    state = getattr(jax_profiler, "_profile_state", None)
+    session = getattr(state, "profile_session", None)
+    if (session is None or not callable(getattr(session, "stop", None))
+            or not callable(getattr(state, "reset", None)) or not hasattr(state, "lock")):
+        return None
+    return state
 
 
 def active() -> bool:

@@ -442,6 +442,127 @@ def test_code_cell_programs_compile_for_the_chip_and_fit_it(v5e, code_cell_engin
     assert held < 16 * 2 ** 30, held
 
 
+# openpangu-ultra-moe-718b.rollout-longctx (bench/workloads): 12,288 blocks of
+# 32 tokens, one latent plane of 512 + 64 values a token a layer, 64 slots x
+# 288 table entries, 128 query heads of 128 + 64 against values of 128
+LONGCTX_CELL = dict(n_blocks=12288, blk=32, width=576, values=512, heads=128, slots=64, n_tbl=288,
+                    qk_dim=192, v_dim=128)
+
+
+@pytest.mark.parametrize("form", ["decode", "prefill"])
+def test_latent_write_and_read_leave_the_arena_where_it_lies(v5e, form):
+    """The latent arena's write and the absorbed decode kernel in one program
+    at the long-context cell's shape: Mosaic accepts the kernel under its own
+    name, and the one plane (two tokens a row: 1,152 columns are whole lane
+    tiles, so the arena's default layout is the declared one) is neither
+    re-laid in front of the kernel nor behind the scatter."""
+    from trlx_tpu.ops.paged_attention import init_paged_latent_layer, paged_attention_latent, paged_latent_write
+
+    c = LONGCTX_CELL
+    one = SingleDeviceSharding(v5e[0])
+    b, t = (c["slots"], 1) if form == "decode" else (1, 8192)
+    layer = jax.eval_shape(lambda: init_paged_latent_layer(c["n_blocks"] + 1, c["blk"], c["width"], BF16))
+
+    def step(layer, latent, table, start, q, mask):
+        new = paged_latent_write(layer, latent, table, start, None, values=c["values"])
+        out = paged_attention_latent(q, new["latent"], table, mask, values=c["values"],
+                                     scale=c["qk_dim"] ** -0.5, out_dtype=BF16)
+        return new, out
+
+    args = (layer, S((b, t, c["width"]), BF16), S((b, c["n_tbl"]), I32), S((b,), I32),
+            S((b, c["heads"], c["width"]), BF16), S((b, c["n_tbl"] * c["blk"]), I32))
+    compiled = jax.jit(step, donate_argnums=(0,), in_shardings=one).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert kernel_names(compiled) == ["paged_decode_latent"]
+    assert arena_rewrites(compiled, layer["latent"]) == []
+    assert donated_outputs(compiled) >= 1
+
+
+def test_flash_forward_with_narrower_value_heads_compiles_under_its_own_name(v5e):
+    """The latent layer's decompressed prefill at the cell's widest prompt:
+    query/key heads of 192 against value heads of 128, one row of 8,192."""
+    c = LONGCTX_CELL
+    shape = lambda width: S((1, 8192, c["heads"], width), BF16)  # noqa: E731
+    compiled = compile_for(lambda q, k, v, m: attention._flash_fwd_pallas(q, k, v, m, True, None, None),
+                           (shape(c["qk_dim"]), shape(c["qk_dim"]), shape(c["v_dim"]), S((1, 8192), I32)),
+                           SingleDeviceSharding(v5e[0]))
+    assert kernel_names(compiled) == ["flash_fwd_latent"]
+    assert "bf16[128,8192,128]" in compiled.as_text()  # the output is as wide as the values
+
+
+@pytest.fixture(scope="module")
+def longctx_cell_engine(v5e):
+    """A paged `InferenceEngine` as `openpangu-ultra-moe-718b.rollout-longctx`
+    builds it, at the configuration file's own cut (5 layers, 8 of 256 experts
+    held, an eighth of the vocabulary) and no weights."""
+    import json
+    import os
+
+    from trlx_tpu.inference import InferenceEngine
+    from trlx_tpu.models import CausalLMPolicy, config_from_preset
+    from trlx_tpu.ops.sampling import GenerationConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = "openpangu-ultra-moe-718b"
+    bench = json.load(open(os.path.join(root, "bench", "configs", f"{name}.json")))["bench"]
+    cell = json.load(open(os.path.join(root, "bench", "workloads", f"{name}.rollout-longctx.json")))["engine"]
+    extra = dict(bench["program"]["model_extra_configs"])
+    cfg = config_from_preset(name, extra.pop("vocab_size"), **extra, param_dtype=BF16, dtype=BF16)
+    model = CausalLMPolicy(cfg)
+    tokens = jnp.zeros((1, 32), I32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
+    gen_cfg = GenerationConfig(max_new_tokens=1024, do_sample=True,
+                               eos_token_id=cfg.vocab_size + 1, pad_token_id=0)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(InferenceEngine, "_param_devices", lambda self: [v5e[0]])
+        engine = InferenceEngine(
+            model, cfg, None, gen_cfg, kv_paging=True, num_slots=cell["num_slots"],
+            max_prompt_len=cell["max_prompt_len"], max_prefill_batch=cell["max_prefill_batch"],
+            prompt_bucket=cell["prompt_bucket"], kv_block_size=cell["kv_block_size"],
+            kv_pool_blocks=cell["kv_pool_blocks"], kv_cache_dtype=cell["kv_cache_dtype"])
+    assert engine.decode_path == "pallas" and engine._n_tbl == LONGCTX_CELL["n_tbl"]
+    return engine, params
+
+
+@pytest.mark.parametrize("program", ["decode", "paged_insert"])
+def test_longctx_cell_programs_compile_for_the_chip_and_fit_it(v5e, longctx_cell_engine, pallas_mode, program):
+    """`openpangu-ultra-moe-718b.rollout-longctx`'s decode step and its widest
+    prefill (one row of 8,192, the fresh-prompt program) at the published
+    widths: one absorbed paged call a layer under its own name, the prompt
+    through the flash forward with narrower values and not a [heads, 8192,
+    9216] score tensor, three grouped products an expert layer, no arena
+    copied, and arguments plus temporaries under 15.0 GB: the 9.08 GB resident
+    (weights 6.82, the latent arena 2.26) and the program's own."""
+    engine, params = longctx_cell_engine
+    one = SingleDeviceSharding(v5e[0])
+    pool = abstract(engine._pool, one)
+    params = abstract(params, one)
+    if program == "decode":
+        compiled = engine._decode_fn.trace(params, pool).lower(lowering_platforms=("tpu",)).compile()
+        want = {"paged_decode_latent": 5, "moe_gmm": 12}
+    else:
+        rows, width = 1, 8192
+        shapes = dict(ids=(rows, width), tmask=(rows, width), tables=(rows, LONGCTX_CELL["n_tbl"]),
+                      slot_ids=(rows,), max_new=(rows,), shared_len=(rows,))
+        compiled = engine._get_paged_insert(rows, width, True).trace(
+            pool, params, *(S(shape, I32, sharding=one) for shape in shapes.values())
+        ).lower(lowering_platforms=("tpu",)).compile()
+        want = {"flash_fwd_latent": 5, "moe_gmm": 12}
+        assert instructions_of_at_least(compiled, 128 * 8192 * 9216) == []
+    names = kernel_names(compiled)
+    assert {n: names.count(n) for n in set(names)} == want
+    arenas = [a for layer in engine._pool["layers"] for a in layer.values()]
+    assert arena_rewrites(compiled, *arenas) == []
+    assert donated_outputs(compiled) >= len(arenas)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    print(f"{program}: arguments {memory.argument_size_in_bytes}, temporaries {memory.temp_size_in_bytes}, "
+          f"held {held}")
+    assert held < 15.0e9, held
+
+
 LAYOUTS = [(4, 1, 1), (2, 1, 2), (1, 2, 2)]  # (data, fsdp, tensor)
 
 

@@ -81,7 +81,15 @@ def _pow2_bucket(n: int, cap: int) -> int:
     return min(b, cap)
 
 
+def _refuse_over_latent_cache(model_cfg, what: str) -> None:
+    """What no test holds over a latent cache (`latent_attention` layers:
+    one plane a token a layer, read absorbed) is refused by name."""
+    if getattr(model_cfg, "has_latent_layers", False):
+        raise NotImplementedError(f"{what} over a latent cache (latent_attention layers) is not supported")
+
+
 def _refuse_over_attention_kinds(model_cfg, what: str) -> None:
+    _refuse_over_latent_cache(model_cfg, what)
     kinds = getattr(model_cfg, "attention_kinds", ())
     if kinds:
         raise NotImplementedError(
@@ -212,6 +220,8 @@ class InferenceEngine:
                          (not kv_paging, "the dense slot pool (kv_paging=False)")):
             if on:
                 _refuse_over_attention_kinds(model_cfg, what)
+        if _KV_DTYPES.get(kv_cache_dtype) == jnp.int8:
+            _refuse_over_latent_cache(model_cfg, "an int8 arena (kv_cache_dtype='int8')")
         if gen_cfg.num_beams > 1:
             raise NotImplementedError("beam search is not servable slot-wise")
         if gen_cfg.repetition_penalty != 1.0:
@@ -1559,16 +1569,19 @@ class InferenceEngine:
         """Key positions the next decode step reads against the positions
         resident, over the slots with a request, summed over the attention
         layers: `resident` (every layer could read all of a row's columns),
-        `walked_full` and `walked_window` (what the layers without and with
-        a window do read: the paged kernel walks whole table entries, from
-        the one that holds a row's first column inside the window to the
-        one that holds its last; the gather path reads a row's whole
-        table), and `layers`. From the host's own count of each slot's
-        columns; the step adds its one."""
+        `walked_full` and `walked_window` (what the K/V layers without and
+        with a window do read: the paged kernel walks whole table entries,
+        from the one that holds a row's first column inside the window to
+        the one that holds its last; the gather path reads a row's whole
+        table), `walked_latent` (the same for the latent layers, which have
+        no window), `bytes` (what those walks read from the arena: positions
+        x what a position holds in each layer's planes) and `layers`. From
+        the host's own count of each slot's columns; the step adds its one."""
         cfg, blk = self.model_cfg, self.kv_block_size
         cols = self._next_columns()
-        windows = [cfg.window_of(cfg.layer_op(i)) for i in range(cfg.n_layers)
-                   if cfg.layer_op(i) != "conv"]
+        ops = [cfg.layer_op(i) for i in range(cfg.n_layers) if cfg.layer_op(i) != "conv"]
+        n_latent = ops.count("latent_attention")
+        windows = [cfg.window_of(op) for op in ops if op != "latent_attention"]
         last = -(-cols // blk)
 
         def walked(window) -> int:  # by one layer
@@ -1577,12 +1590,18 @@ class InferenceEngine:
             first = 0 if window is None else np.maximum(cols - window, 0) // blk
             return int(((last - first) * blk).sum())
 
-        return {
-            "resident": int(cols.sum()) * len(windows),
+        walk = {
+            "resident": int(cols.sum()) * len(ops),
             "walked_full": windows.count(None) * walked(None),
             "walked_window": sum(walked(w) for w in windows if w is not None),
-            "layers": len(windows),
+            "walked_latent": n_latent * walked(None),
+            "layers": len(ops),
         }
+        itemsize = jnp.dtype(self.kv_cache_dtype).itemsize
+        walk["bytes"] = itemsize * (
+            (walk["walked_full"] + walk["walked_window"]) * 2 * cfg.kv_heads * cfg.head_dim
+            + walk["walked_latent"] * cfg.latent_width)
+        return walk
 
     def kv_stats(self) -> Dict[str, Any]:
         """Host-side paged-pool counters for metrics/healthz; {} when
@@ -1595,21 +1614,21 @@ class InferenceEngine:
         # single source of truth for arena bytes (incl. int8 scale
         # planes): observability/hbm.py — the same function the offline
         # budget checker and the live HBM ledger price the arena with
-        from trlx_tpu.observability.hbm import kv_arena_bytes
+        from trlx_tpu.observability.hbm import paged_arena_bytes
 
         cfg = self.model_cfg
-        kv_bytes = kv_arena_bytes(
-            cfg.n_layers, cfg.kv_heads, cfg.head_dim,
-            self._n_blocks, self.kv_block_size,
-            dtype=jnp.dtype(self.kv_cache_dtype),
-        )
+        kv_bytes = paged_arena_bytes(
+            cfg, self._n_blocks, self.kv_block_size, dtype=jnp.dtype(self.kv_cache_dtype))
         walk = self._kv_walk()
         with self._kv_lock:
             pool = self._block_pool
             return {
                 # the expert layers' dispatch counters of the last decode step
                 **{f"moe_{k}": v for k, v in self._moe_stats.items()},
-                "kv_walked_share": (walk["walked_full"] + walk["walked_window"]) / max(walk["resident"], 1),
+                "kv_walked_share": (walk["walked_full"] + walk["walked_window"] + walk["walked_latent"])
+                / max(walk["resident"], 1),
+                # what one token holds in the arena, over all layers (scale planes aside)
+                "kv_bytes_per_token": cfg.cached_values_per_token * jnp.dtype(self.kv_cache_dtype).itemsize,
                 "kv_blocks_total": pool.total,
                 "kv_blocks_free": pool.available(),
                 "kv_blocks_used": pool.in_use(),

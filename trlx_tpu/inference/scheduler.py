@@ -980,7 +980,7 @@ class Scheduler:
             return
         for name in (
             "kv_blocks_total", "kv_blocks_free", "kv_blocks_used",
-            "kv_pool_bytes", "prefix_cache_idle_blocks", "kv_live_entry_share",
+            "kv_pool_bytes", "kv_bytes_per_token", "prefix_cache_idle_blocks", "kv_live_entry_share",
         ):
             self.metrics.set_gauge(name, stats[name])
         for name in stats:

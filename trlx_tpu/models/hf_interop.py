@@ -57,6 +57,8 @@ def _family_of(hf: Dict) -> str:
         return "lfm2_moe"
     if mt == "laguna":
         return "laguna"
+    if mt == "pangu_ultra_moe":
+        return "pangu_ultra_moe"
     for fam, keys in (
         ("gpt_bigcode", ("bigcode",)),
         ("gpt_neox", ("neox",)),
@@ -183,6 +185,8 @@ def config_from_hf(path: str, **overrides):
                 raise NotImplementedError(f"lfm2_moe with {key}={hf[key]!r} is not supported")
     elif fam == "laguna":
         kwargs = _laguna_kwargs(hf)
+    elif fam == "pangu_ultra_moe":
+        kwargs = _pangu_kwargs(hf)
     kwargs["hf_family"] = fam
     kwargs.update(overrides)
     return TransformerConfig(**cut_to_depth(kwargs, overrides))
@@ -226,6 +230,33 @@ def _laguna_kwargs(hf: Dict) -> Dict:
         moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=dense, moe_router="sigmoid",
         moe_shared_d_ff=hf.get("shared_expert_intermediate_size", 0),
         moe_routed_scale=float(hf.get("moe_routed_scaling_factor", 1.0)),
+    )
+
+
+def _pangu_kwargs(hf: Dict) -> Dict:
+    """`pangu_ultra_moe` config keys (openPangu-Ultra-MoE) -> TransformerConfig
+    fields: latent attention in every layer, sandwich norms, DeepSeek-V3's
+    sigmoid router without groups (the config names no scoring function;
+    bench/reference/pangu_ultra_moe.py lists what is assumed)."""
+    for key, want in (("attention_bias", False), ("norm_topk_prob", True), ("n_shared_experts", 1),
+                      ("hidden_act", "silu"), ("rope_scaling", None)):
+        if hf.get(key, want) != want:
+            raise NotImplementedError(f"pangu_ultra_moe with {key}={hf[key]!r} is not supported")
+    return dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=hf["num_hidden_layers"],
+        n_heads=hf["num_attention_heads"], d_ff=hf["intermediate_size"],
+        max_seq_len=hf["max_position_embeddings"], pos_embed="rope", rope_theta=float(hf["rope_theta"]),
+        norm="rmsnorm", layer_norm_epsilon=hf.get("rms_norm_eps", 1e-5), activation="silu", glu=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)), use_bias=False, flash_prefill=True,
+        layer_types=("latent_attention",) * hf["num_hidden_layers"],
+        sandwich_norm=bool(hf.get("sandwich_norm", False)), mtp_layers=int(hf.get("num_nextn_predict_layers", 0)),
+        q_lora_rank=hf["q_lora_rank"], kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"], qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        moe_experts=hf["n_routed_experts"], moe_top_k=hf["num_experts_per_tok"],
+        moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=hf["first_k_dense_replace"],
+        moe_router="sigmoid", moe_shared_d_ff=hf["moe_intermediate_size"] * hf.get("n_shared_experts", 1),
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
     )
 
 
@@ -479,6 +510,63 @@ def _load_lfm2_moe(sd: Dict, cfg: TransformerConfig) -> Dict:
     return lm
 
 
+_PANGU_ATTN = (("q_a_proj", "q_a_proj"), ("q_b_proj", "q_b_proj"), ("kv_a_proj", "kv_a_proj_with_mqa"),
+               ("kv_b_proj", "kv_b_proj"), ("o_proj", "o_proj"))
+_PANGU_ATTN_NORMS = (("q_a_norm", "q_a_layernorm"), ("kv_a_norm", "kv_a_layernorm"))
+_PANGU_NORMS = (("ln_attn", "input_layernorm"), ("ln_post_attn", "post_attention_layernorm"),
+                ("ln_mlp", "pre_mlp_layernorm"), ("ln_post_mlp", "post_mlp_layernorm"))
+_GLU = ("gate_proj", "up_proj", "down_proj")
+
+
+def _load_pangu_block(sd: Dict, p: str, cfg: TransformerConfig, dense_ffn: bool) -> Dict:
+    block = {n: _ln(sd, p + hf_n, bias=False) for n, hf_n in _PANGU_NORMS}
+    block["attn"] = {n: _dense(sd[p + f"self_attn.{hf_n}.weight"].T) for n, hf_n in _PANGU_ATTN}
+    block["attn"].update({n: _ln(sd, p + f"self_attn.{hf_n}", bias=False) for n, hf_n in _PANGU_ATTN_NORMS})
+    if dense_ffn:
+        block["mlp"] = {n: _dense(sd[p + f"mlp.{n}.weight"].T) for n in _GLU}
+        return block
+    held = range(cfg.moe_local_offset, cfg.moe_local_offset + cfg.experts_held)
+    block["mlp"] = {
+        "router": _dense(sd[p + "mlp.gate.weight"].T),
+        # the family's gate has no selection bias; the program's leaf stays, at zero
+        "expert_bias": {"bias": np.zeros((cfg.moe_experts,), np.float32)},
+        **{f"expert_{n.split('_')[0]}": _dense(np.concatenate(
+            [sd[p + f"mlp.experts.{e}.{n}.weight"].T for e in held], axis=1)) for n in _GLU},
+        **{f"shared_{n.split('_')[0]}": _dense(sd[p + f"mlp.shared_experts.{n}.weight"].T) for n in _GLU},
+    }
+    return block
+
+
+def _load_pangu_ultra_moe(sd: Dict, cfg: TransformerConfig) -> Dict:
+    """`pangu_ultra_moe` (openPangu-Ultra-MoE), in DeepSeek-V3's tensor
+    names with the two further norms of `sandwich_norm`
+    (`pre_mlp_layernorm`, `post_mlp_layernorm`); a multi-token block is
+    layer `num_hidden_layers + k` with `enorm`, `hnorm`, `eh_proj` (its
+    `shared_head` is the model's own norm and head, which are used). The
+    rotary dimensions are taken as they lie (the half-split layout is
+    ASSUMED; an interleaved checkpoint would need `_permute_rotary_cols` on
+    q_b_proj's and kv_a_proj_with_mqa's rotary columns). The tree holds
+    experts [moe_local_offset, + experts_held) side by side. UNCHECKED against
+    the published weights: no checkpoint of the family was at hand, the
+    round trip in tests/test_pangu_mla.py is over a random state dict."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lm: Dict = {
+        "embed_tokens": {"embedding": sd[f"{pre}embed_tokens.weight"]},
+        "ln_f": _ln(sd, f"{pre}norm", bias=False),
+        "lm_head": _dense(sd["lm_head.weight"].T),
+    }
+    for i in range(cfg.n_layers):
+        lm[f"block_{i}"] = _load_pangu_block(sd, f"{pre}layers.{i}.", cfg, cfg.layer_ffn(i) == "dense")
+    for k in range(cfg.mtp_layers):
+        p = f"{pre}layers.{cfg.n_layers + k}."
+        lm[f"mtp_{k}"] = {
+            "enorm": _ln(sd, p + "enorm", bias=False), "hnorm": _ln(sd, p + "hnorm", bias=False),
+            "eh_proj": _dense(sd[p + "eh_proj.weight"].T),
+            "block": _load_pangu_block(sd, p, cfg, cfg.layer_ffn(cfg.n_layers - 1) == "dense"),
+        }
+    return lm
+
+
 def _load_gpt_neox(sd: Dict, cfg: TransformerConfig) -> Dict:
     sd = _strip_prefix(sd, "gpt_neox.")
     lm: Dict = {
@@ -695,6 +783,7 @@ _LOADERS: Dict[str, Callable] = {
     "bloom": _load_bloom,
     "gpt_bigcode": _load_gpt_bigcode,
     "lfm2_moe": _load_lfm2_moe,
+    "pangu_ultra_moe": _load_pangu_ultra_moe,
 }
 
 
@@ -834,6 +923,45 @@ def _export_lfm2_moe(lm: Dict, cfg: TransformerConfig) -> Dict:
             stack = _f32(b["mlp"][f"expert_{n.split('_')[0]}"]["kernel"])
             for g, mat in enumerate(np.split(stack, cfg.experts_held, axis=1)):
                 sd[p + f"feed_forward.experts.{cfg.moe_local_offset + g}.{w}.weight"] = mat.T
+    return sd
+
+
+def _export_pangu_block(b: Dict, p: str, cfg: TransformerConfig, sd: Dict) -> None:
+    for n, hf_n in _PANGU_NORMS:
+        sd[p + f"{hf_n}.weight"] = _f32(b[n]["scale"])
+    for n, hf_n in _PANGU_ATTN:
+        sd[p + f"self_attn.{hf_n}.weight"] = _f32(b["attn"][n]["kernel"]).T
+    for n, hf_n in _PANGU_ATTN_NORMS:
+        sd[p + f"self_attn.{hf_n}.weight"] = _f32(b["attn"][n]["scale"])
+    if "router" not in b["mlp"]:
+        for n in _GLU:
+            sd[p + f"mlp.{n}.weight"] = _f32(b["mlp"][n]["kernel"]).T
+        return
+    if np.any(_f32(b["mlp"]["expert_bias"]["bias"]) != 0):
+        raise NotImplementedError("pangu_ultra_moe has no selection bias: a nonzero expert_bias cannot be exported")
+    sd[p + "mlp.gate.weight"] = _f32(b["mlp"]["router"]["kernel"]).T
+    for n in _GLU:
+        stack = _f32(b["mlp"][f"expert_{n.split('_')[0]}"]["kernel"])
+        for g, mat in enumerate(np.split(stack, cfg.experts_held, axis=1)):
+            sd[p + f"mlp.experts.{cfg.moe_local_offset + g}.{n}.weight"] = mat.T
+        sd[p + f"mlp.shared_experts.{n}.weight"] = _f32(b["mlp"][f"shared_{n.split('_')[0]}"]["kernel"]).T
+
+
+def _export_pangu_ultra_moe(lm: Dict, cfg: TransformerConfig) -> Dict:
+    """Inverse of `_load_pangu_ultra_moe` (as unchecked against the published
+    weights): the experts held go out under their indices in the whole model."""
+    sd = {
+        "model.embed_tokens.weight": _f32(lm["embed_tokens"]["embedding"]),
+        "model.norm.weight": _f32(lm["ln_f"]["scale"]),
+        "lm_head.weight": _f32(lm["lm_head"]["kernel"]).T,
+    }
+    for i in range(cfg.n_layers):
+        _export_pangu_block(lm[f"block_{i}"], f"model.layers.{i}.", cfg, sd)
+    for k in range(cfg.mtp_layers):
+        m, p = lm[f"mtp_{k}"], f"model.layers.{cfg.n_layers + k}."
+        sd[p + "enorm.weight"], sd[p + "hnorm.weight"] = _f32(m["enorm"]["scale"]), _f32(m["hnorm"]["scale"])
+        sd[p + "eh_proj.weight"] = _f32(m["eh_proj"]["kernel"]).T
+        _export_pangu_block(m["block"], p, cfg, sd)
     return sd
 
 
@@ -1060,6 +1188,7 @@ _EXPORTERS: Dict[str, Callable] = {
     "bloom": _export_bloom,
     "gpt_bigcode": _export_gpt_bigcode,
     "lfm2_moe": _export_lfm2_moe,
+    "pangu_ultra_moe": _export_pangu_ultra_moe,
 }
 
 
@@ -1070,6 +1199,8 @@ def infer_family(cfg) -> str:
         return "t5"
     if getattr(cfg, "has_conv_layers", False):
         return "lfm2_moe"
+    if getattr(cfg, "has_latent_layers", False):
+        return "pangu_ultra_moe"
     if getattr(cfg, "attention_kinds", ()):
         return "laguna"
     if cfg.alibi:
@@ -1160,6 +1291,20 @@ def config_to_hf(cfg: TransformerConfig, family: str = None) -> Dict:
             mlp_layer_types=["dense" if i < cfg.moe_dense_layers else "sparse" for i in range(cfg.n_layers)],
             moe_routed_scaling_factor=cfg.moe_routed_scale,
             num_attention_heads_per_layer=list(cfg.layer_heads),
+        )
+    if family == "pangu_ultra_moe":
+        return dict(
+            model_type="pangu_ultra_moe", vocab_size=cfg.vocab_size, hidden_size=cfg.d_model,
+            intermediate_size=cfg.d_ff, num_hidden_layers=cfg.n_layers, num_attention_heads=cfg.n_heads,
+            num_key_value_heads=cfg.n_heads, max_position_embeddings=cfg.max_seq_len, attention_bias=False,
+            hidden_act="silu", rms_norm_eps=cfg.layer_norm_epsilon, rope_theta=cfg.rope_theta,
+            q_lora_rank=cfg.q_lora_rank, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim, qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim, sandwich_norm=cfg.sandwich_norm,
+            num_nextn_predict_layers=cfg.mtp_layers, first_k_dense_replace=cfg.moe_dense_layers,
+            n_routed_experts=cfg.moe_experts, n_shared_experts=1, num_experts_per_tok=cfg.moe_top_k,
+            moe_intermediate_size=cfg.expert_d_ff, norm_topk_prob=True,
+            routed_scaling_factor=cfg.moe_routed_scale, tie_word_embeddings=cfg.tie_embeddings,
         )
     if family == "lfm2_moe":
         return dict(

@@ -40,7 +40,7 @@ class RopeSpec:
     attention_factor: Optional[float] = None  # on cos and sin; None: 0.1 ln(factor) + 1 under YaRN
 
 
-ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
+ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention", "latent_attention")
 
 
 @dataclass(frozen=True)
@@ -134,6 +134,29 @@ class TransformerConfig:
     # every token meets, computed whole on every chip.
     moe_shared_d_ff: int = 0
     moe_routed_scale: float = 1.0
+    # Latent attention (MLA, DeepSeek-V2; `layer_types` kind
+    # "latent_attention", `LatentAttention` below): queries through a
+    # low-rank pair of `q_lora_rank`, keys and values through one shared
+    # latent of `kv_lora_rank` plus `qk_rope_head_dim` rotary dimensions that
+    # all heads share; a query/key head is `qk_nope_head_dim +
+    # qk_rope_head_dim` wide, a value head `v_head_dim`. Such a layer caches
+    # ONE plane of `latent_width` values a token, not K and V by head:
+    # `cache_planes(i)` says what layer i caches, and `kv_heads` / `head_dim`
+    # describe the K/V layers only.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # a = x + N(Attn(N(x))); y = a + N(FFN(N(a))): a norm after the operator
+    # and after the feed-forward too (`ln_post_attn`, `ln_post_mlp`), before
+    # each residual add (openPangu's `sandwich_norm`)
+    sandwich_norm: bool = False
+    # Multi-token prediction blocks behind the stack (DeepSeek-V3's form,
+    # `MTPBlock`): block k reads the state under the final norm and the
+    # embedding of the next token and gives logits for the token after it.
+    # `forward(..., mtp=True)` runs them; no cached step does.
+    mtp_layers: int = 0
 
     def __post_init__(self):
         if self.layer_types:
@@ -158,6 +181,25 @@ class TransformerConfig:
                 (k, r if isinstance(r, RopeSpec) else RopeSpec(**r)) for k, r in self.rope_kinds))
         if self.attn_gate not in ("none", "per_head"):
             raise ValueError(f"attn_gate must be 'none' or 'per_head', got {self.attn_gate!r}")
+        if self.has_latent_layers:
+            sizes = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
+            missing = [n for n in sizes if getattr(self, n) <= 0]
+            if missing:
+                raise ValueError(f"latent_attention layers need {missing} (a full-rank query, "
+                                 "q_lora_rank=0, is not written)")
+            if self.qk_rope_head_dim % 2:
+                raise ValueError(f"qk_rope_head_dim {self.qk_rope_head_dim} must be even")
+            unsupported = [what for on, what in (
+                (self.pos_embed != "rope", f"pos_embed={self.pos_embed!r}"), (self.alibi, "alibi"),
+                (self.lora_rank > 0, "lora_rank"), (self.prefix_tokens > 0, "prefix_tokens"),
+                (self.attn_gate != "none", "attn_gate"), (self.qk_norm, "qk_norm"),
+                (self.sliding_window is not None, "sliding_window"),
+                (self.attn_impl in ("ring", "blockwise"), f"attn_impl={self.attn_impl!r}"),
+            ) if on]
+            if unsupported:
+                raise NotImplementedError(f"latent_attention layers with {', '.join(unsupported)} are not supported")
+        if self.sandwich_norm and self.parallel_residual:
+            raise NotImplementedError("sandwich_norm under parallel_residual is not supported")
         if (self.moe_shared_d_ff or self.moe_routed_scale != 1.0) and self.moe_router != "sigmoid":
             raise NotImplementedError("a shared expert and a routed scale need moe_router='sigmoid'")
         if self.moe_router not in ("softmax", "sigmoid"):
@@ -243,6 +285,31 @@ class TransformerConfig:
     @property
     def has_conv_layers(self) -> bool:
         return "conv" in self.layer_types
+
+    @property
+    def has_latent_layers(self) -> bool:
+        return "latent_attention" in self.layer_types
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent layer caches a token: the normed latent and the
+        rotated key all heads share."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def cache_planes(self, i: int) -> Tuple[int, ...]:
+        """What a token caches in layer i, one width a plane: K and V by
+        head for an attention layer, the one latent plane for a latent one,
+        nothing a token for a `conv` layer (its state is a row's)."""
+        op = self.layer_op(i)
+        if op == "conv":
+            return ()
+        if op == "latent_attention":
+            return (self.latent_width,)
+        return (self.kv_heads * self.head_dim,) * 2
+
+    @property
+    def cached_values_per_token(self) -> int:
+        return sum(sum(self.cache_planes(i)) for i in range(self.n_layers))
 
     @property
     def sows_moe_aux(self) -> bool:
@@ -407,10 +474,14 @@ def fused_attention_ok(cfg: TransformerConfig, seq_len: Optional[int] = None,
     layer of a model with sliding ones has no window. `forward_only` (a
     cached prefill: nothing differentiates it) admits an active window
     under "flash", whose forward kernel takes the band; its backward does
-    not, so a training forward longer than the window keeps the dense bias."""
+    not, so a training forward longer than the window keeps the dense bias.
+    A latent layer's value heads are narrower than its query/key heads, which
+    the forward kernel takes and the backward kernels do not: the same rule."""
     window = cfg.window_of(kind)
     if cfg.attn_impl not in ("flash", "ring", "blockwise"):
         return False
+    if kind == "latent_attention":
+        return forward_only and cfg.attn_impl == "flash" and seq_len is not None
     if window is not None and cfg.attn_impl == "ring":
         raise NotImplementedError(
             "sliding_window with ring attention is not supported; use "
@@ -689,6 +760,133 @@ class Attention(nn.Module):
         return project_out(out), new_cache
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA: DeepSeek-V2, as `pangu_ultra_moe`
+    configures it). With N an RMSNorm and no bias anywhere:
+
+        c_q = N(x W_qa);  [q_nope_h ; q_rope_h] = c_q W_qb      (heads of dn + dr)
+        [c_kv ; k_r] = x W_kva;  c = N(c_kv)                     (dc + dr)
+        [k_nope_h ; v_h] = c W_kvb                               (dn + dv a head)
+        q_rope_h, k_r rotated at the token's position; k_r is ONE vector for all heads
+        s_h,t = (q_nope_h . k_nope_h,t + q_rope_h . k_r,t) / sqrt(dn + dr), causal softmax
+        o_h = sum_t p_h,t v_h,t;  y = [o_1 .. o_H] W_o
+
+    A token caches `[c ; rotated k_r]`, `cfg.latent_width` values, and nothing
+    else (`layer_cache["latent"]`: `[b, S, width]` in the dense families,
+    two tokens a row of one plane in the paged arena, whose layout
+    ops/paged_attention.py owns). The two bodies meet it two
+    ways. Without a cache, and in a prefill into an empty one
+    (`attn_kernel="prefill"`), keys and values are DECOMPRESSED for the block
+    at hand and go through the fused forward (query/key width dn + dr, value
+    width dv) or the dense products. Every other cached step runs ABSORBED
+    over the latents: with W_kvb split by head into W_uk,h and W_uv,h,
+
+        q_lat_h = W_uk,h^T q_nope_h;  s_h,t = (q_lat_h . c_t + q_rope_h . k_r,t) / sqrt(dn + dr)
+        o_h = W_uv,h (sum_t p_h,t c_t)
+
+    the same numbers, never a per-head key or value in memory: all heads read
+    the same latent rows (one K/V head of width dc + dr whose values are its
+    first dc columns), which is what `paged_attention_latent` is built on."""
+
+    cfg: TransformerConfig
+    kind: Optional[str] = None
+    n_heads: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, h, attn_bias, positions, layer_cache=None, cache_index=None, attn_mask=None,
+                 use_prefix=True, attn_kernel=None):
+        cfg = self.cfg
+        b, t, d = h.shape
+        nh = self.n_heads or cfg.n_heads
+        dc, dn, dr, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
+        norm = lambda name: nn.RMSNorm(
+            epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
+        # the float64 frequency table (`rope_tables`), whatever the kind names
+        rope = cfg.rope_of(self.kind) or RopeSpec(theta=cfg.rope_theta)
+        scale = 1.0 / np.sqrt(dn + dr)
+
+        q = dense(nh * (dn + dr), "q_b_proj")(norm("q_a_norm")(dense(cfg.q_lora_rank, "q_a_proj")(h)))
+        q = q.reshape(b, t, nh, dn + dr)
+        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta, spec=rope)
+        kv_a = dense(dc + dr, "kv_a_proj")(h)
+        c = norm("kv_a_norm")(kv_a[..., :dc])
+        k_rope = apply_rope(kv_a[:, :, None, dc:], positions, cfg.rope_theta, spec=rope)  # [b, t, 1, dr]
+        # W_uk and W_uv are views of this one leaf, cut where they are used
+        w_kvb = _Kernel((dc, nh * (dn + dv)), cfg.param_dtype, name="kv_b_proj")()
+        w_kvb = w_kvb.astype(cfg.dtype).reshape(dc, nh, dn + dv)
+        project_out = lambda out: dense(d, "o_proj")(out.reshape(b, t, nh * dv))
+        latent = jnp.concatenate([c, k_rope[:, :, 0]], axis=-1)  # [b, t, dc + dr]: what is cached
+
+        def absorbed_query():
+            q_lat = jnp.einsum("bthn,chn->bthc", q_nope, w_kvb[..., :dn])
+            return jnp.concatenate([q_lat, q_rope], axis=-1)  # [b, t, nh, dc + dr]
+
+        def values_up(o_lat):  # [b, t, nh, dc] -> [b, t, nh, dv]
+            return jnp.einsum("bthc,chv->bthv", o_lat, w_kvb[..., dn:])
+
+        new_cache, cached = None, None
+        if layer_cache is not None and "table" in layer_cache:
+            # the paged latent arena: as Attention's paged branch, one plane
+            from trlx_tpu.ops import paged_attention as paged
+
+            table = layer_cache["table"]
+            idx = cache_index if jnp.ndim(cache_index) == 1 else jnp.full((b,), cache_index, jnp.int32)
+            new_cache = paged.paged_latent_write(layer_cache, latent, table, idx, attn_mask, values=dc)
+            new_cache["table"] = table
+            if attn_kernel is not None and attn_kernel != "prefill":
+                if t != 1:
+                    raise ValueError(
+                        "paged decode kernel takes single-position queries; "
+                        f"got t={t} (engine should have fallen back)"
+                    )
+                key_mask = attn_bias[:, 0, 0, :] == 0.0  # as Attention reads it
+                if attn_mask is not None:
+                    key_mask &= attn_mask > 0
+                o_lat = paged.paged_attention_latent(
+                    absorbed_query()[:, 0], new_cache["latent"], table, key_mask,
+                    values=dc, scale=scale, out_dtype=cfg.dtype,
+                    interpret=(attn_kernel == "interpret"),
+                )
+                return project_out(values_up(o_lat[:, None])), new_cache
+            if attn_kernel != "prefill":
+                cached = paged.paged_latent_gather(new_cache, table, values=dc)
+        elif layer_cache is not None:
+            lc = latent.astype(layer_cache["latent"].dtype)
+            if jnp.ndim(cache_index) == 1:
+                cached = jax.vmap(lambda row, x, i: jax.lax.dynamic_update_slice(row, x, (i, 0)))(
+                    layer_cache["latent"], lc, cache_index)
+            else:
+                cached = jax.lax.dynamic_update_slice(layer_cache["latent"], lc, (0, cache_index, 0))
+            new_cache = {"latent": cached}
+            if attn_kernel == "prefill":
+                cached = None  # the cache was empty: the block is all there is to attend to
+
+        if cached is not None:
+            # absorbed, over every cached latent: [b, nh, t, S] scores in f32
+            scores = jnp.einsum("bthc,bsc->bhts", absorbed_query(), cached,
+                                preferred_element_type=jnp.float32) * scale
+            probs = jax.nn.softmax(scores + attn_bias, axis=-1).astype(cfg.dtype)
+            out = values_up(jnp.einsum("bhts,bsc->bthc", probs, cached[..., :dc]))
+            return project_out(out), new_cache
+
+        kv = jnp.einsum("btc,chm->bthm", c, w_kvb)
+        k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope, (b, t, nh, dr))], axis=-1)
+        v = kv[..., dn:]
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        if (fused_attention_ok(cfg, t, self.kind, forward_only=attn_kernel == "prefill")
+                and attn_mask is not None):
+            from trlx_tpu.ops.attention import flash_attention
+
+            out = flash_attention(q, k, v, mask=attn_mask, causal=True).astype(cfg.dtype)
+        else:
+            scores = jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32) * scale
+            probs = jax.nn.softmax(scores + attn_bias, axis=-1).astype(cfg.dtype)
+            out = jnp.einsum("bhts,bshd->bthd", probs, v)
+        return project_out(out), new_cache
+
+
 class MLP(nn.Module):
     cfg: TransformerConfig
 
@@ -917,10 +1115,13 @@ class Block(nn.Module):
         else:
             if isinstance(attn_bias, dict):  # a bias for each kind of attention layer
                 attn_bias = attn_bias[self.op_kind]
-            attn_out, new_cache = Attention(cfg, kind=self.op_kind, n_heads=self.n_heads, name="attn")(
+            attn_cls = LatentAttention if self.op_kind == "latent_attention" else Attention
+            attn_out, new_cache = attn_cls(cfg, kind=self.op_kind, n_heads=self.n_heads, name="attn")(
                 h_ln, attn_bias, positions, layer_cache, cache_index, attn_mask, use_prefix,
                 attn_kernel,
             )
+        # the sandwich: a norm on what each operator gives, before its residual add
+        post = (lambda name, x: make_norm(cfg, name)(x)) if cfg.sandwich_norm else (lambda name, x: x)
         if self.ffn_kind == "sparse_moe":
             mlp = lambda x: SparseMoE(cfg, name="mlp")(x, attn_mask)
         elif self.ffn_kind == "dense" or (self.ffn_kind is None and cfg.moe_experts <= 0):
@@ -932,9 +1133,32 @@ class Block(nn.Module):
             mlp_in = h_ln if cfg.shared_ln else make_norm(cfg, "ln_mlp")(h)
             h = h + attn_out + mlp(mlp_in)
         else:
-            h = h + attn_out
-            h = h + mlp(make_norm(cfg, "ln_mlp")(h))
+            h = h + post("ln_post_attn", attn_out)
+            h = h + post("ln_post_mlp", mlp(make_norm(cfg, "ln_mlp")(h)))
         return h, new_cache
+
+
+class MTPBlock(nn.Module):
+    """One multi-token-prediction block (DeepSeek-V3's form): with h_t the
+    state under the model's final norm and x_t+1 the next token,
+
+        h'_t = W_eh [N_e(Emb(x_t+1)) ; N_h(h_t)]      (2 d_model -> d_model)
+
+    then one block of the stack's last kind over h'; the model's own final
+    norm and head read its output as logits for position t + 2."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, h, next_embed, attn_bias, positions, attn_mask):
+        cfg = self.cfg
+        joined = jnp.concatenate(
+            [make_norm(cfg, "enorm")(next_embed), make_norm(cfg, "hnorm")(h)], axis=-1)
+        x = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                     name="eh_proj")(joined)
+        out, _ = Block(cfg, **cfg.block_kwargs(cfg.n_layers - 1), name="block")(
+            x, attn_bias, positions, attn_mask=attn_mask)
+        return out
 
 
 def causal_bias(attn_mask: jnp.ndarray, sliding_window: Optional[int] = None) -> jnp.ndarray:
@@ -1068,6 +1292,7 @@ class TransformerLM(nn.Module):
                 cfg.vocab_size, use_bias=cfg.lm_head_bias,
                 dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="lm_head"
             )
+        self.mtp = [MTPBlock(cfg, name=f"mtp_{k}") for k in range(cfg.mtp_layers)]
 
     def embed(self, tokens, positions):
         h = self.embed_tokens(tokens)
@@ -1157,6 +1382,7 @@ class TransformerLM(nn.Module):
         capture: Tuple[int, ...] = (),
         window: Optional[Tuple[Any, int]] = None,
         use_prompt: bool = True,
+        mtp: bool = False,
     ):
         """The one forward without a cache: embed (start == 0) or take the
         hidden state entering block `start` (the hydra frozen branch,
@@ -1186,9 +1412,21 @@ class TransformerLM(nn.Module):
           is prepended internally and sliced back off before the
           unembedding, so logits/h_final keep the caller's sequence length;
           captured activations carry the extended length (their consumers
-          force split == 0 under prompt tuning)."""
+          force split == 0 under prompt tuning).
+        - `mtp=True` (a whole forward of token ids, `cfg.mtp_layers` > 0) also
+          runs the multi-token-prediction blocks: `caps["mtp"]` is a list, one
+          `[b, t, vocab]` a block, in which block k's position i holds the
+          logits for token i + k + 2 (its last k + 1 positions have no next
+          token to read and are not to be used)."""
         cfg = self.cfg
         to_head, stop = stop is None, cfg.n_layers if stop is None else stop
+        if cfg.mtp_layers and self.is_initializing() and start == 0 and to_head and window is None:
+            mtp = True  # `init` walks the multi-token blocks too, or they get no leaves
+        if mtp and (start > 0 or not to_head or window is not None or cfg.prompt_tokens > 0
+                    or not cfg.mtp_layers):
+            raise NotImplementedError(
+                "mtp=True takes a whole forward of token ids (no start, stop, window or "
+                "soft prompt) of a model with mtp_layers > 0")
         if cfg.prompt_tokens > 0 and (window is not None or not to_head):
             raise NotImplementedError(
                 "a windowed head or a forward that stops short of it is unsupported "
@@ -1233,6 +1471,15 @@ class TransformerLM(nn.Module):
         if window is not None:
             h = jax.lax.dynamic_slice_in_dim(h, window[0], window[1], axis=1)
         logits, h_final = self.unembed(h)
+        if mtp:
+            caps["mtp"] = []
+            tokens, mask, state = x, attn_mask, h
+            for block in self.mtp:
+                # position i reads token i + 1: shift left, the last column masked
+                tokens = jnp.pad(tokens[:, 1:], ((0, 0), (0, 1)))
+                mask = mask * jnp.pad(mask[:, 1:], ((0, 0), (0, 1)))
+                state = block(state, self.embed(tokens, positions), train_bias(cfg, mask), positions, mask)
+                caps["mtp"].append(self.unembed(state)[0])
         return logits, h_final, caps
 
     def decode_step(
@@ -1443,6 +1690,8 @@ def init_kv_cache(cfg: TransformerConfig, batch_size: int, max_len: int, dtype=N
     def layer(i):
         if cfg.layer_op(i) == "conv":
             return {"conv": jnp.zeros((batch_size, cfg.conv_kernel - 1, cfg.d_model), dtype=dtype)}
+        if cfg.layer_op(i) == "latent_attention":
+            return {"latent": jnp.zeros((batch_size, max_len, cfg.latent_width), dtype=dtype)}
         return {
             "k": jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), dtype=dtype),
             "v": jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), dtype=dtype),
@@ -1461,7 +1710,8 @@ def init_paged_kv_arena(
 ):
     """Allocate the per-layer paged KV arenas: `num_blocks` blocks of
     `block_size` token columns each, shared by every slot through per-row
-    block tables (Attention's paged branch). Block 0 is reserved by the
+    block tables (Attention's paged branch; a latent layer's is one plane
+    of `cfg.latent_width` values a token, in a floating type only). Block 0 is reserved by the
     engine as a permanent zero block backing padding table entries, so it
     is never allocated to a request. int8 arenas carry f32 scale planes
     (per token per kv head, ops/quant.quantize_kv)."""
@@ -1471,11 +1721,13 @@ def init_paged_kv_arena(
             "paged KV cache under prompt/prefix tuning is unsupported"
         )
     refuse_conv_state(cfg, "paged KV arena")
-    from trlx_tpu.ops.paged_attention import init_paged_layer
+    from trlx_tpu.ops.paged_attention import init_paged_latent_layer, init_paged_layer
 
     return [
-        init_paged_layer(num_blocks, block_size, cfg.kv_heads, cfg.head_dim, dtype)
-        for _ in range(cfg.n_layers)
+        init_paged_latent_layer(num_blocks, block_size, cfg.latent_width, dtype)
+        if cfg.layer_op(i) == "latent_attention"
+        else init_paged_layer(num_blocks, block_size, cfg.kv_heads, cfg.head_dim, dtype)
+        for i in range(cfg.n_layers)
     ]
 
 
@@ -1614,6 +1866,35 @@ PRESETS: Dict[str, Dict[str, Any]] = {
                                         attention_factor=1.4158883083359672)),
             ("sliding_attention", RopeSpec(theta=10000.0, pct=1.0)),
         ),
+        moe_experts=8, moe_top_k=2, moe_d_ff=32, moe_dense_layers=1, moe_router="sigmoid",
+        moe_shared_d_ff=32, moe_routed_scale=2.5,
+    ),
+    # openPangu-Ultra-MoE-718B (`pangu_ultra_moe`; 718B parameters, ~39B
+    # active): latent attention in every layer (128 heads of 128 + 64 against
+    # values of 128, a latent of 512 + 64 rotary dimensions a token), a norm
+    # after attention and after the feed-forward too, 3 dense SwiGLU layers,
+    # then 256 sigmoid-routed experts (8 a token, normalised, scaled 2.5)
+    # beside one shared expert, one multi-token-prediction block. The
+    # published sizes; a cut (depth, dense layers, experts held here,
+    # vocabulary, the multi-token block) arrives as model_extra_configs.
+    "openpangu-ultra-moe-718b": dict(
+        d_model=7680, n_layers=61, n_heads=128, d_ff=18432, max_seq_len=131072,
+        pos_embed="rope", rope_theta=25600000.0, norm="rmsnorm", layer_norm_epsilon=1e-5,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=("latent_attention",) * 61, sandwich_norm=True, mtp_layers=1,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        moe_experts=256, moe_top_k=8, moe_d_ff=2048, moe_dense_layers=3, moe_router="sigmoid",
+        moe_shared_d_ff=2048, moe_routed_scale=2.5,
+    ),
+    # the same stack at test size: one dense and three expert layers, 8
+    # experts (2 a token) beside a shared one, 4 heads, widths that keep
+    # qk_nope != qk_rope != v; no multi-token block unless a test asks
+    "openpangu-ultra-moe-tiny": dict(
+        d_model=64, n_layers=4, n_heads=4, d_ff=128, max_seq_len=256,
+        pos_embed="rope", rope_theta=25600000.0, norm="rmsnorm", layer_norm_epsilon=1e-5,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=("latent_attention",) * 4, sandwich_norm=True,
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=12,
         moe_experts=8, moe_top_k=2, moe_d_ff=32, moe_dense_layers=1, moe_router="sigmoid",
         moe_shared_d_ff=32, moe_routed_scale=2.5,
     ),

@@ -41,12 +41,23 @@ def layer_matmul_flops(model_cfg, i: int) -> float:
     gate), or the short convolution's in/out projections and taps; a dense
     (gated) MLP, or the router plus the experts a token meets HERE: top_k x
     held / experts of width moe_d_ff (the active experts, not the resident
-    ones) and the shared expert whole."""
+    ones) and the shared expert whole. A latent-attention layer is counted
+    decompressed (a forward's and a prefill's form; an absorbed decode step
+    trades the per-head keys and values for two products of heads x
+    qk_nope x kv_lora and heads x kv_lora x v)."""
     d = model_cfg.d_model
     if not getattr(model_cfg, "layer_types", ()):
         return 8 * d * d + 4 * d * model_cfg.d_ff
     if model_cfg.layer_op(i) == "conv":
         op = 2 * d * 3 * d + 2 * d * d + 2 * model_cfg.conv_kernel * d
+    elif model_cfg.layer_op(i) == "latent_attention":
+        # decompressed, as a forward or a prefill runs it: the query's
+        # low-rank pair, the latent and shared rotary key, the per-head keys
+        # and values out of the latent, the output projection
+        c, heads = model_cfg, _layer_heads(model_cfg, i)
+        qk = c.qk_nope_head_dim + c.qk_rope_head_dim
+        op = 2 * (d * c.q_lora_rank + c.q_lora_rank * heads * qk + d * c.latent_width
+                  + c.kv_lora_rank * heads * (c.qk_nope_head_dim + c.v_head_dim) + heads * c.v_head_dim * d)
     else:
         heads = _layer_heads(model_cfg, i)
         op = 4 * d * heads * model_cfg.head_dim + 4 * d * model_cfg.kv_heads * model_cfg.head_dim
@@ -75,6 +86,9 @@ def layer_attention_flops(model_cfg, i: int, ctx: float) -> float:
     kind = model_cfg.layer_op(i)
     if kind == "conv":
         return 0.0
+    if kind == "latent_attention":  # scores at the query/key width, values at their own
+        return 2 * ctx * _layer_heads(model_cfg, i) * (
+            model_cfg.qk_nope_head_dim + model_cfg.qk_rope_head_dim + model_cfg.v_head_dim)
     window = model_cfg.window_of(kind)
     keys = ctx if window is None else min(ctx, window)
     return 4 * keys * _layer_heads(model_cfg, i) * model_cfg.head_dim

@@ -118,6 +118,22 @@ def kv_arena_bytes(n_layers: int, kv_heads: int, head_dim: int,
     return int(n)
 
 
+def latent_arena_bytes(n_layers: int, latent_width: int, n_blocks: int,
+                       block_size: int, dtype="bfloat16") -> int:
+    """Paged latent arena: ONE plane of `n_blocks x block_size x
+    latent_width` elements a latent-attention layer (layout:
+    ops/paged_attention.py, "Latent"); no int8 form, so no scale planes."""
+    return int(n_layers * n_blocks * block_size * latent_width * _itemsize(dtype))
+
+
+def paged_arena_bytes(cfg, n_blocks: int, block_size: int, dtype="float32") -> int:
+    """The paged arena `init_paged_kv_arena` allocates for `cfg`: K and V by
+    head for its attention layers, one latent plane for its latent ones."""
+    n_latent = list(getattr(cfg, "layer_types", ())).count("latent_attention")
+    return (kv_arena_bytes(cfg.n_layers - n_latent, cfg.kv_heads, cfg.head_dim, n_blocks, block_size, dtype)
+            + latent_arena_bytes(n_latent, getattr(cfg, "latent_width", 0), n_blocks, block_size, dtype))
+
+
 def kv_cache_bytes(n_layers: int, kv_heads: int, head_dim: int,
                    batch: int, cache_len: int, dtype="float32") -> int:
     """Dense (non-paged) per-slot KV pool: K and V of
@@ -128,12 +144,17 @@ def kv_cache_bytes(n_layers: int, kv_heads: int, head_dim: int,
 
 def decode_state_bytes(cfg, batch: int, cache_len: int, dtype="float32") -> int:
     """The fused sampler's cache for `cfg` (`init_kv_cache`): K/V tables for
-    the attention layers only, plus the short convolution's state, the last
-    `conv_kernel - 1` inputs a channel, for each `conv` layer."""
+    the attention layers only, one latent plane for each latent layer, plus
+    the short convolution's state, the last `conv_kernel - 1` inputs a
+    channel, for each `conv` layer."""
     kinds = list(getattr(cfg, "layer_types", ())) or ["attention"] * cfg.n_layers
     # full and sliding layers alike hold a table of `cache_len` (a sliding
     # layer gives nothing back yet: ROADMAP R4)
-    kv = kv_cache_bytes(len(kinds) - kinds.count("conv"), cfg.kv_heads, cfg.head_dim, batch, cache_len, dtype)
+    n_latent = kinds.count("latent_attention")
+    kv = kv_cache_bytes(len(kinds) - kinds.count("conv") - n_latent, cfg.kv_heads, cfg.head_dim,
+                        batch, cache_len, dtype)
+    if n_latent:
+        kv += n_latent * batch * cache_len * cfg.latent_width * _itemsize(dtype)
     if "conv" not in kinds:
         return int(kv)
     conv = kinds.count("conv") * batch * (cfg.conv_kernel - 1) * cfg.d_model * _itemsize(dtype)

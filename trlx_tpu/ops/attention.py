@@ -277,7 +277,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_scr, l_scr, acc_sc
 
 
 def _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k, interpret=False, window=None):
-    """`window` (causal only): query i sees keys j with 0 <= i - j < window.
+    """Value heads may be narrower than query/key heads (a latent layer's
+    decompressed prefill: 192 against 128): the scores are scaled by the
+    query/key width and the output is as wide as v. Such a call is named
+    `flash_fwd_latent` in the device trace.
+    `window` (causal only): query i sees keys j with 0 <= i - j < window.
     The kv blocks outside a q block's band are neither computed (the
     kernel's `run`) nor fetched: their index is held on the band's nearest
     block, and a repeated block index moves no data. Such a call is named
@@ -288,7 +292,7 @@ def _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k, interpret=False, 
     from jax.experimental.pallas import tpu as pltpu
 
     b, tq, nh, hd = q.shape
-    tk, nkv = k.shape[1], k.shape[2]
+    tk, nkv, hv = k.shape[1], k.shape[2], v.shape[3]
     group = nh // nkv
     target = FWD_BLOCK if window is None else min(FWD_BLOCK, max(WINDOW_BLOCK, window))
     bq = _auto_block(tq, block_q, target)
@@ -301,10 +305,15 @@ def _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k, interpret=False, 
     # batch-head slot to its batch's mask row, with zero duplication in HBM.
     qh = q.transpose(0, 2, 1, 3).reshape(b * nh, tq, hd)
     kh = k.transpose(0, 2, 1, 3).reshape(b * nkv, tk, hd)
-    vh = v.transpose(0, 2, 1, 3).reshape(b * nkv, tk, hd)
+    vh = v.transpose(0, 2, 1, 3).reshape(b * nkv, tk, hv)
     if mask is None:
         mask = jnp.ones((b, tk), jnp.int32)
     maskh = mask.astype(jnp.int32)[:, None, :]  # [b, 1, tk]
+    name = "flash_fwd" if window is None else "flash_fwd_window"
+    if hv != hd:
+        if window is not None:
+            raise NotImplementedError("a windowed flash forward with narrower value heads is not written")
+        name = "flash_fwd_latent"
 
     def kv_index(i, j, kk):
         if window is not None:
@@ -321,20 +330,20 @@ def _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k, interpret=False, 
         in_specs=[
             pl.BlockSpec((1, bq, hd), lambda i, j, kk: (i, j, 0)),
             pl.BlockSpec((1, bk, hd), kv_index),
-            pl.BlockSpec((1, bk, hd), kv_index),
+            pl.BlockSpec((1, bk, hv), kv_index),
             pl.BlockSpec((1, 1, bk), lambda i, j, kk: (i // nh, 0, kk)),
         ],
-        out_specs=pl.BlockSpec((1, bq, hd), lambda i, j, kk: (i, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * nh, tq, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, hv), lambda i, j, kk: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * nh, tq, hv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),  # m (broadcast over lanes)
             pltpu.VMEM((bq, 128), jnp.float32),  # l
-            pltpu.VMEM((bq, hd), jnp.float32),   # acc
+            pltpu.VMEM((bq, hv), jnp.float32),   # acc
         ],
         interpret=interpret,
-        name="flash_fwd" if window is None else "flash_fwd_window",
+        name=name,
     )(qh, kh, vh, maskh)
-    return out.reshape(b, nh, tq, hd).transpose(0, 2, 1, 3)
+    return out.reshape(b, nh, tq, hv).transpose(0, 2, 1, 3)
 
 
 KERNELS_ENV = "TRLX_TPU_KERNELS"
@@ -971,10 +980,38 @@ def flash_attention(
     block_q/block_k default to the tuned auto sizes (FWD_BLOCK for the
     forward, BWD_BLOCK for the backward kernels). `window` bands a causal
     call (query i sees keys j with 0 <= i - j < window): the forward only,
-    for a cached prefill; differentiating it is refused by name."""
+    for a cached prefill; differentiating it is refused by name. So is a v
+    narrower than q and k (a latent layer's decompressed prefill), whose
+    output is as wide as v."""
     if window is not None:
         return _flash_window_forward(q, k, v, mask, window, block_q, block_k)
+    if v.shape[-1] != q.shape[-1]:
+        return _flash_latent_forward(q, k, v, mask, causal, block_q, block_k)
     return _flash_attention(q, k, v, mask, causal, block_q, block_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flash_latent_forward(q, k, v, mask, causal, block_q, block_k):
+    mode = kernel_mode()
+    if mode in ("pallas", "interpret"):
+        note_kernel_path("flash_fwd_latent", mode, q.shape)
+        return _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k,
+                                 interpret=(mode == "interpret"))
+    # off the one-chip kernel: the blockwise scan carries q's width, so the
+    # values ride padded to it and the padding is cut off the output
+    note_kernel_path("flash_fwd_latent", "xla", q.shape)
+    hv = v.shape[-1]
+    v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - hv),))
+    return blockwise_attention(q, k, v, mask, causal, block_k)[..., :hv]
+
+
+def _flash_latent_fwd_rule(q, k, v, mask, causal, block_q, block_k):
+    raise NotImplementedError(
+        "the flash backward with value heads narrower than query/key heads is not written: a "
+        "training forward over latent layers takes the dense bias (models/transformer.fused_attention_ok)")
+
+
+_flash_latent_forward.defvjp(_flash_latent_fwd_rule, lambda *a: None)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))

@@ -97,6 +97,27 @@ mask rules the columns. Time then follows min(tokens resident, window). Such
 a call is named `paged_decode_window` in the device trace; a call without a
 window is the program it was before the window existed.
 
+Latent. A latent-attention layer (`models/transformer.LatentAttention`)
+caches ONE plane of `width` values a token: its normed latent (the value,
+`values` columns) and the rotated key all heads share, 512 + 64 = 576 at
+the published sizes. The arena holds two tokens a row, [n_blocks,
+block / 2, 2 * width], as [v_2r | v_2r+1 | k_2r | k_2r+1] (`_pack_latent`):
+a [block, 576] plane would be the same bytes, but 576 is 4.5 lane tiles,
+and the TPU's default layout then makes the BLOCK dimension minor to save
+the padding, so that XLA re-lays the whole arena in front of the kernel
+and behind the write on every call (AOT for v5e, PR 37: two copies of
+453 MB a layer a step); 1,152 is 9 lane tiles, the arena lies row-major
+as it is declared, a block is one contiguous transfer of 36,864 bytes,
+and every column slice the kernel takes starts on a lane tile. Its decode
+runs absorbed: every one of the layer's query heads, `width` wide, reads
+the SAME latent rows, and the values are the rows' leading columns, so
+`paged_attention_latent` fetches a tile once (`_LATENT_TILE_TOKENS` tokens)
+and feeds the MXU [heads, width] x [width, T] and [heads, T] x [T, values]: 2 x heads x (width + values) operations for
+`width` x itemsize bytes a cached position, on the v5e's ridge at 128 heads
+in bfloat16. Schedule, masking and the online softmax are the K/V kernel's
+(`_live_schedule` as it is); there is no window and no int8 form. The call
+is named `paged_decode_latent` in the device trace.
+
 `q` is [b, nh, hd] (ONE query position per row — the decode shape);
 `table` is [b, n_tbl] int32; `key_mask` is [b, n_tbl*block] key validity
 over logical columns. Rows whose mask is all-zero (inactive slots: the
@@ -135,6 +156,81 @@ def init_paged_layer(
     return layer
 
 
+def init_paged_latent_layer(num_blocks: int, block_size: int, width: int, dtype) -> Dict[str, jnp.ndarray]:
+    """One latent layer's zeroed arena: ONE plane of `width` values a token,
+    two tokens a row, `[blocks, block / 2, 2 * width]` (module docstring,
+    "Latent"). An int8 plane is refused: its scales, write and kernel are
+    not written."""
+    if jnp.dtype(dtype) == jnp.int8:
+        raise NotImplementedError(
+            "an int8 latent arena (kv_cache_dtype='int8' over latent_attention layers) is not "
+            "supported: the latent plane is held in a floating type")
+    if block_size % 2:
+        raise ValueError(f"a latent arena holds two tokens a row: kv_block_size {block_size} must be even")
+    return {"latent": jnp.zeros((num_blocks, block_size // 2, 2 * width), dtype)}
+
+
+def _pack_latent(x, values: int):
+    """[..., block, width] token rows -> [..., block / 2, 2 * width] arena
+    rows: tokens 2r and 2r + 1 side by side, their leading `values` columns
+    first, then their other columns: [v_2r | v_2r+1 | k_2r | k_2r+1]."""
+    *lead, blk, width = x.shape
+    pairs = x.reshape(*lead, blk // 2, 2, width)
+    return jnp.concatenate([pairs[..., :values].reshape(*lead, blk // 2, 2 * values),
+                            pairs[..., values:].reshape(*lead, blk // 2, 2 * (width - values))], axis=-1)
+
+
+def _unpack_latent(rows, values: int):
+    """`_pack_latent`'s inverse: [..., block / 2, 2 * width] -> [..., block, width]."""
+    *lead, half, w2 = rows.shape
+    head = rows[..., :2 * values].reshape(*lead, half, 2, values)
+    tail = rows[..., 2 * values:].reshape(*lead, half, 2, w2 // 2 - values)
+    return jnp.concatenate([head, tail], axis=-1).reshape(*lead, 2 * half, w2 // 2)
+
+
+def _touched_blocks(table, start, valid, rows, t: int, n_blocks: int, blk: int):
+    """The whole blocks a call's runs of t columns can straddle (`paged_kv_write`):
+    their physical ids `phys` [b, n_touch] (n_blocks where nothing of this call
+    lands), for each of their columns the call's position `src` [b, n_touch*blk]
+    it holds, and whether one does, `live` [b, n_touch, blk]. `rows`: arange(b)[:, None]."""
+    b, n_tbl = table.shape
+    n_touch = (t + blk - 2) // blk + 1  # blocks a run of t columns can straddle
+    entry = (start // blk)[:, None] + jnp.arange(n_touch)  # [b, n_touch]
+    phys = table[rows, jnp.clip(entry, 0, n_tbl - 1)]
+    # column p of the touched blocks holds this call's position src[p]
+    src = jnp.arange(n_touch * blk)[None, :] - (start % blk)[:, None]
+    live = (src >= 0) & (src < t)
+    src = jnp.clip(src, 0, t - 1)
+    live = (live & valid[rows, src]).reshape(b, n_touch, blk)
+    live &= ((entry < n_tbl) & (phys < n_blocks))[..., None]
+    phys = jnp.where(live.any(-1), phys, n_blocks)
+    return phys, src, live
+
+
+def paged_latent_write(layer, latent, table, start, valid=None, *, values: int) -> Dict[str, jnp.ndarray]:
+    """`paged_kv_write` for a latent layer: `latent` [b, t, width] into the
+    one plane, whole blocks patched over the arena's major dimension.
+    `values`: the leading columns of a latent that are its value (`_pack_latent`)."""
+    arena = layer["latent"]
+    n_blocks, half, w2 = arena.shape
+    blk, width = 2 * half, w2 // 2
+    b, t = latent.shape[:2]
+    valid = jnp.ones((b, t), bool) if valid is None else valid.astype(bool)
+    rows = jnp.arange(b)[:, None]
+    phys, src, live = _touched_blocks(table, start, valid, rows, t, n_blocks, blk)
+    new = latent[rows, src].reshape(b, -1, blk, width)
+    keep = jnp.broadcast_to(live[..., None], new.shape)
+    patched = jnp.where(_pack_latent(keep, values), _pack_latent(new.astype(arena.dtype), values), arena[phys])
+    return {"latent": arena.at[phys.reshape(-1)].set(patched.reshape(-1, half, w2), mode="drop")}
+
+
+def paged_latent_gather(layer, table, *, values: int) -> jnp.ndarray:
+    """The gather read path of a latent layer: each row's table blocks as
+    one dense `[b, n_tbl*block, width]` view, a token a row."""
+    rows = _unpack_latent(layer["latent"][table], values)  # [b, n_tbl, block, width]
+    return rows.reshape(rows.shape[0], -1, rows.shape[-1])
+
+
 def paged_kv_write(
     layer: Dict[str, jnp.ndarray],
     k: jnp.ndarray,      # [b, t, nkv, hd]
@@ -166,17 +262,8 @@ def paged_kv_write(
     n_tbl = table.shape[1]
     valid = jnp.ones((b, t), bool) if valid is None else valid.astype(bool)
     rows = jnp.arange(b)[:, None]
-
-    n_touch = (t + blk - 2) // blk + 1  # blocks a run of t columns can straddle
-    entry = (start // blk)[:, None] + jnp.arange(n_touch)  # [b, n_touch]
-    phys = table[rows, jnp.clip(entry, 0, n_tbl - 1)]
-    # column p of the touched blocks holds this call's position src[p]
-    src = jnp.arange(n_touch * blk)[None, :] - (start % blk)[:, None]
-    live = (src >= 0) & (src < t)
-    src = jnp.clip(src, 0, t - 1)
-    live = (live & valid[rows, src]).reshape(b, n_touch, blk)
-    live &= ((entry < n_tbl) & (phys < n_blocks))[..., None]
-    phys = jnp.where(live.any(-1), phys, n_blocks)
+    phys, src, live = _touched_blocks(table, start, valid, rows, t, n_blocks, blk)
+    n_touch = phys.shape[1]
 
     def put(arena, values):
         new = values[rows, src].reshape(b, n_touch, blk, nkv, -1).swapaxes(2, 3)
@@ -511,6 +598,162 @@ def paged_attention_decode(
         name="paged_decode_window" if windowed else "paged_decode",
     )(blocks, row, tile, n_live, *first, *operands)
     return out.reshape(b, nh, hd)
+
+
+# A latent tile is one plane and its rows are 4.5 lane tiles wide: twice the
+# tokens of a K/V tile for the same count of block DMAs a grid step.
+_LATENT_TILE_TOKENS = 512
+_MAX_LATENT_TILE_ENTRIES = 16
+
+
+def _paged_latent_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, q_ref, qr_ref, *rest,
+                         entries: int, scale: float, values: int, p_dtype):
+    """One grid step of `paged_attention_latent`: tile `tile_ref[w]` of row
+    `row_ref[w]`, its `entries` latent blocks in VMEM one under another as
+    [T / 2, 2 * width] rows of two tokens (`_pack_latent`). Every head's
+    absorbed query multiplies the tile, and the value product reads the
+    same tile's value columns: one fetch serves both. The tile's T columns
+    are its even tokens, then its odd ones (the mask arrives in that order;
+    a softmax does not mind). Scalar prefetch as `_paged_decode_kernel`.
+
+    q_ref      [1, nh, values]: the queries against the value columns
+    qr_ref     [1, 2 * nh, 2 * rest]: against the other columns of a row's two
+               tokens, [q_rest | 0] for the even token over [0 | q_rest]
+    c_refs     `entries` x [1, blk / 2, 2 * width]
+    mask_ref   [1, 1, 1, T] int32 key validity, even tokens then odd
+    o_ref      [1, nh, values]
+    m_scr/l_scr VMEM [nh, 1] f32, acc_scr VMEM [nh, values] f32
+    """
+    import jax.experimental.pallas as pl
+
+    E = entries
+    c_refs, (mask_ref, o_ref, m_scr, l_scr, acc_scr) = rest[:E], rest[E:]
+    w = pl.program_id(0)
+    first_entry = tile_ref[w] * E
+    n_live = n_live_ref[row_ref[w]]
+    nh = q_ref.shape[1]
+
+    @pl.when(first_entry == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(first_entry < n_live)
+    def _tile():
+        q, qr = q_ref[0], qr_ref[0]  # already in the product's type
+        rows = jnp.concatenate([r[0].astype(q.dtype) for r in c_refs], axis=0)  # [T / 2, 2 * width]
+        # [T, values]: the even tokens' value columns over the odd tokens'
+        vals = jnp.concatenate([rows[:, :values], rows[:, values:2 * values]], axis=0)
+        nt = (((1,), (1,)), ((), ()))
+        s_rest = jax.lax.dot_general(qr, rows[:, 2 * values:], nt, preferred_element_type=jnp.float32)
+        s = jax.lax.dot_general(q, vals, nt, preferred_element_type=jnp.float32)  # [nh, T]
+        s = (s + jnp.concatenate([s_rest[:nh], s_rest[nh:]], axis=1)) * scale
+        s = jnp.where(mask_ref[0, 0] > 0, s, NEG_INF)
+
+        m_prev, l_prev = m_scr[:], l_scr[:]  # [nh, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # as `_paged_decode_kernel`: masked-so-far rows keep m == NEG_INF
+        shift = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+        p = jnp.exp(s - shift)
+        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+        corr = jnp.exp(m_prev - m_new)
+        corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, corr)
+        l_scr[:] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[:] = m_new
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(p_dtype), vals.astype(p_dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    @pl.when(first_entry + E >= n_live)  # the row's last tile
+    def _finalize():
+        l = l_scr[:]
+        o_ref[0] = (acc_scr[:] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+
+def paged_attention_latent(
+    q: jnp.ndarray,          # [b, nh, width] absorbed queries
+    arena: jnp.ndarray,      # [n_blocks, blk / 2, 2 * width]
+    table: jnp.ndarray,      # [b, n_tbl] int32 physical block ids
+    key_mask: jnp.ndarray,   # [b, n_tbl*blk] key validity (1 = attend)
+    *,
+    values: int,             # the leading columns of a latent that are its value
+    scale: float,            # on the scores: 1 / sqrt(the decompressed query/key width)
+    out_dtype=None,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Fused paged decode attention over a latent arena (module docstring,
+    "Latent"). Returns [b, nh, values] in `out_dtype` (defaults to q's):
+    softmax(q . latent * scale) over a row's attendable latents, times their
+    first `values` columns. Grid, schedule and masking as
+    `paged_attention_decode`; named `paged_decode_latent` in the device
+    trace."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, nh, width = q.shape
+    n_blocks, half, w2 = arena.shape
+    blk, n_tbl = 2 * half, table.shape[1]
+    out_dtype = jnp.dtype(out_dtype or q.dtype)
+    qk_dtype = jnp.promote_types(q.dtype, arena.dtype)
+    p_dtype = jnp.float32 if arena.dtype == jnp.float32 else jnp.promote_types(out_dtype, arena.dtype)
+
+    E = max(1, min(_LATENT_TILE_TOKENS // blk, _MAX_LATENT_TILE_ENTRIES, n_tbl))
+    blocks, row, tile, n_live, n_work, n_tiles = _live_schedule(table, key_mask, blk, E)
+    T = E * blk
+    maskh = jnp.pad(key_mask.astype(jnp.int32), ((0, 0), (0, n_tiles * T - n_tbl * blk)))
+    # a tile's even tokens, then its odd ones: the order the kernel's scores come in
+    maskh = maskh.reshape(b, n_tiles, T // 2, 2).swapaxes(2, 3).reshape(b, n_tiles, 1, T)
+    q = q.astype(qk_dtype)
+    q_rest, zeros = q[..., values:], jnp.zeros((b, nh, width - values), qk_dtype)
+    qr = jnp.concatenate([jnp.concatenate([q_rest, zeros], axis=-1),
+                          jnp.concatenate([zeros, q_rest], axis=-1)], axis=1)  # [b, 2 nh, 2 rest]
+
+    def slot_index(w, blocks_ref, row_ref, *_):
+        return (row_ref[w], 0, 0)
+
+    def entry_index(e):
+        return lambda w, blocks_ref, *_: (blocks_ref[w * E + e], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_work,),  # the steps that hold work, read at run time
+        in_specs=[pl.BlockSpec((1, nh, values), slot_index),
+                  pl.BlockSpec((1, 2 * nh, 2 * (width - values)), slot_index)]
+        + [pl.BlockSpec((1, half, w2), entry_index(e)) for e in range(E)]
+        + [pl.BlockSpec((1, 1, 1, T),
+                        lambda w, blocks_ref, row_ref, tile_ref, *_: (row_ref[w], tile_ref[w], 0, 0))],
+        out_specs=pl.BlockSpec((1, nh, values), slot_index),
+        scratch_shapes=[
+            pltpu.VMEM((nh, 1), jnp.float32),       # m
+            pltpu.VMEM((nh, 1), jnp.float32),       # l
+            pltpu.VMEM((nh, values), jnp.float32),  # acc
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_latent_kernel, entries=E, scale=scale, values=values, p_dtype=p_dtype),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, nh, values), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT_BYTES
+        ),
+        interpret=interpret,
+        name="paged_decode_latent",
+    )(blocks, row, tile, n_live, q[..., :values], qr, *([arena] * E), maskh)
+
+
+def paged_latent_reference(q, arena, table, key_mask, *, values: int, scale: float, out_dtype=None):
+    """XLA shadow of a latent layer's gather read path (`LatentAttention`'s
+    absorbed branch at t == 1): gather the table back to a dense view, dense
+    softmax with the -1e9 additive bias, the value product on the leading
+    `values` columns."""
+    out_dtype = out_dtype or q.dtype
+    cached = paged_latent_gather({"latent": arena}, table, values=values)  # [b, S, width]
+    bias = jnp.where(key_mask.astype(bool), 0.0, -1e9)[:, None, :]
+    scores = jnp.einsum("bhc,bsc->bhs", q, cached, preferred_element_type=jnp.float32) * scale
+    probs = jax.nn.softmax(scores + bias, axis=-1).astype(out_dtype)
+    return jnp.einsum("bhs,bsc->bhc", probs, cached[..., :values]).astype(out_dtype)
 
 
 def paged_attention_reference(

@@ -87,21 +87,6 @@ def fast_rollout_requested(argv) -> bool:
     )
 
 
-def trunk_cache_requested(argv) -> bool:
-    """The frozen-trunk activation cache (h_split captured once per
-    rollout chunk, every train epoch runs the suffix only) is ON by
-    default in the bench harness — the library default stays off, but the
-    headline measurement exercises the cached train schedule, and the
-    flag-off number is still reported every run via the same-process
-    `train_full` phase. Opt out with `--no-trunk-cache` (or
-    `method.cache_trunk_activations=false`)."""
-    return not any(
-        a.replace(" ", "") in ("method.cache_trunk_activations=false",
-                               "--no-trunk-cache")
-        for a in argv
-    )
-
-
 def spec_decode_requested(argv) -> bool:
     """Self-speculative decode (frozen-trunk draft + one suffix verify
     pass per round) is ON by default in the bench harness — the library
@@ -118,7 +103,7 @@ def spec_decode_requested(argv) -> bool:
 
 def int8_requested(argv) -> bool:
     """Int8 weight-only decode for the frozen trunk is ON by default in
-    the bench harness (same convention as the trunk cache: library
+    the bench harness (same convention as speculative decode: library
     default off, headline on). Opt out with `--no-int8` (or
     `method.quantize_frozen_trunk=false`)."""
     return not any(
@@ -129,8 +114,7 @@ def int8_requested(argv) -> bool:
 
 
 def build_trainer(smoke: bool = False, fast: bool = False,
-                  trunk_cache: bool = False, spec_decode: bool = False,
-                  int8: bool = False):
+                  spec_decode: bool = False, int8: bool = False):
     from trlx_tpu.data.default_configs import default_ppo_config
     from trlx_tpu.pipeline.offline_pipeline import PromptPipeline
     from trlx_tpu.trainer.ppo_trainer import PPOTrainer
@@ -138,8 +122,6 @@ def build_trainer(smoke: bool = False, fast: bool = False,
     config = default_ppo_config()
     if fast:
         config = config.evolve(method=dict(capture_rollout_stats=True))
-    if trunk_cache:
-        config = config.evolve(method=dict(cache_trunk_activations=True))
     if spec_decode:
         config = config.evolve(method=dict(speculative_decode=True))
     if int8:
@@ -464,9 +446,10 @@ def measure_phases(trainer, config, flops, n_chips, peak, reps=3):
     extra = {"train_schedule": "full"}
     trunk_cache = trainer._trunk_cache_available()
     if trunk_cache:
-        # attach the frozen-trunk cache exactly like the cycle does (reuse
-        # of the sampler's capture on the fast schedule, else one jitted
-        # trunk pass) and time it as its own phase
+        # the schedule trains from the trunk cache (the trainer's own
+        # decision): attach it exactly like the cycle does (reuse of the
+        # sampler's capture on the fast schedule, else one jitted trunk
+        # pass) and time it as its own phase
         t, chunk = timed(
             lambda: trainer._attach_trunk_cache(
                 chunk, captured=out.get("trunk_cache")
@@ -476,7 +459,7 @@ def measure_phases(trainer, config, flops, n_chips, peak, reps=3):
         times["cache_trunk"] = t
         extra["train_schedule"] = "trunk_cache"
         extra["trunk_cache_hbm_bytes"] = int(
-            chunk.h_split.size * chunk.h_split.dtype.itemsize
+            chunk.trunk_cache.size * chunk.trunk_cache.dtype.itemsize
         )
     t, _ = timed(
         lambda: trainer.train_epochs_from_chunk(chunk, method.ppo_epochs),
@@ -486,7 +469,7 @@ def measure_phases(trainer, config, flops, n_chips, peak, reps=3):
     if trunk_cache:
         # same-process A/B for the acceptance gate: the identical chunk
         # trained WITHOUT the cache (full forward every epoch)
-        full_chunk = chunk.replace(h_split=None)
+        full_chunk = chunk.replace(trunk_rows=None, trunk_cache=None)
         t, _ = timed(
             lambda: trainer.train_epochs_from_chunk(full_chunk, method.ppo_epochs),
         )
@@ -530,10 +513,9 @@ def main():
 
     classic = "--classic" in sys.argv
     fast = fast_rollout_requested(sys.argv[1:])
-    trunk_cache = trunk_cache_requested(sys.argv[1:])
     spec_decode = spec_decode_requested(sys.argv[1:])
     int8 = int8_requested(sys.argv[1:])
-    trainer, config = build_trainer(smoke, fast=fast, trunk_cache=trunk_cache,
+    trainer, config = build_trainer(smoke, fast=fast,
                                     spec_decode=spec_decode, int8=int8)
     # Compile/HBM forensics for the run: bench keeps train.tracing OFF
     # (the headline measures the flag-off hot path), but the ledgers are
@@ -546,7 +528,7 @@ def main():
 
     trainer._compile_ledger = CompileLedger()
     # the same-process A/Bs in measure_phases compile a second variant on
-    # purpose (train with h_split=None for the trunk-cache A/B; plain
+    # purpose (train without the trunk cache for its A/B; plain
     # generate for the spec-decode A/B), so two train programs are
     # expected here even though the library-wide budget is 1
     trainer._compile_ledger.declare_budget("train_scan", 2)
@@ -631,7 +613,6 @@ def main():
         config.method.ppo_epochs, config.model.num_layers_unfrozen,
         window_ok=window_ok,
         fast_path=(not classic) and trainer._fast_rollout_available(),
-        trunk_cache=trainer._trunk_cache_available(),
         spec_k=spec_k_eff, spec_accept=accept_rate,
         spec_rank=int(getattr(config.method, "spec_draft_rank", 64)),
     )

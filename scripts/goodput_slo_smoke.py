@@ -159,7 +159,6 @@ def check_goodput(artifact_dir: str) -> str:
         window_ok=(trainer._window_loss_ok()
                    and getattr(trainer.model_cfg, "moe_experts", 0) == 0),
         fast_path=False,
-        trunk_cache=trainer._trunk_cache_available(),
         spec_k=spec_k, spec_accept=accept,
         spec_rank=int(getattr(trainer.config.method, "spec_draft_rank", 64)),
     )

@@ -15,7 +15,9 @@ Needs no chip: every program is traced at its cell's widths and recipe
 (depth cut to `--layers`, which changes no shape) and lowered for the TPU
 platform with the Pallas kernels on, never compiled or run. The PPO cells'
 trainers are built as `bench/jobs/ppo.py` builds them and stopped at the
-first call of each jitted program (`_ljit` is where a trainer makes one);
+first call of each jitted program (`_ljit` is where a trainer makes one;
+where the schedule trains from the trunk cache, its fill and the resumed
+train step are taken beside the whole-forward step);
 the serve cells' engines as `tests/test_kernels_compile_tpu.py` builds them.
 """
 
@@ -109,6 +111,19 @@ def ppo_programs(workload: str, layers: int) -> dict:
                            response_tensors=np.ones((b, max_new), np.int32),
                            logprobs=zeros, values=zeros, rewards=zeros)
     stop_at_program(trainer.train_minibatch, [minibatch])
+    if trainer._trunk_cache_available():
+        # the schedule trains from the trunk cache: one chunk's fill, and the
+        # step over a batch that names its rows of the cycle's array
+        trainer._open_trunk_cache()
+        trainer._note_trunk_chunk(ids, np.ones((ids.shape[0], max_new), np.int32))
+        stop_at_program(trainer._close_trunk_cache)
+        trainer._trunk_cache = jax.ShapeDtypeStruct(
+            (cfg.method.num_rollouts, ids.shape[1] + max_new, trainer.model_cfg.d_model),
+            trainer.model_cfg.dtype)
+        whole = texts["train_step"]
+        stop_at_program(trainer.train_minibatch,
+                        [minibatch.replace(trunk_rows=np.arange(b, dtype=np.int32))])
+        texts["train_step.from_trunk_cache"], texts["train_step"] = texts["train_step"], whole
     uninstall()
     return texts
 

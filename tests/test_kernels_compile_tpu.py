@@ -750,3 +750,111 @@ def test_lfm2_train_step_fits_the_chip_at_batch_16(v5e, pallas_mode, capsys):
     # beside this program the process holds the reference copy of the top
     # blocks (0.84 GB) and the sampler's bfloat16 view while it runs
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 14.5e9
+
+
+# pythia-1.4b.ppo-hh (bench/workloads): chunks of 16 x (896 + 128) tokens,
+# batches of 8, 64 rollouts a cycle, 2 blocks trained. Every width is the
+# cell's; the depth is cut to 2 frozen blocks under the 2 trained ones (the
+# 22 of the cell are one block's program 22 times, and a minute to compile)
+PPO_HH = dict(vocab_size=50304, attn_impl="flash", n_layers=4)
+
+
+@pytest.fixture(scope="module")
+def ppo_hh_trainer(tmp_path_factory):
+    """A `PPOTrainer` whose loss and trunk-cache fill are the dense PPO
+    cell's: built at test size, then handed pythia-1.4b's model at the
+    cell's widths, so `make_loss_fn` and `_build_trunk_cache_fn` trace the
+    programs the cell runs over shapes and no array."""
+    from trlx_tpu.data.default_configs import default_ppo_config
+    from trlx_tpu.models import CausalLMWithValueHead, config_from_preset
+    from trlx_tpu.trainer.ppo_trainer import PPOTrainer
+
+    config = default_ppo_config().evolve(
+        model=dict(model_path="random:gpt2-tiny", num_layers_unfrozen=1),
+        tokenizer=dict(tokenizer_path="byte"),
+        train=dict(seq_length=1024, batch_size=8, tracker=None,
+                   checkpoint_dir=str(tmp_path_factory.mktemp("ppo_hh"))),
+        method=dict(num_rollouts=64, chunk_size=16, ppo_epochs=4,
+                    gen_kwargs=dict(max_new_tokens=128, top_k=0, top_p=1.0, do_sample=True)),
+    )
+    trainer = PPOTrainer(config, reward_fn=lambda samples, **kw: [0.0] * len(samples),
+                         devices=jax.devices()[:1])
+    cfg = config_from_preset("pythia-1.4b", **PPO_HH)
+    trainer.model, trainer.model_cfg, trainer.split = CausalLMWithValueHead(cfg), cfg, cfg.n_layers - 2
+    # the trainer's mesh is this process's CPU; the programs are placed
+    # where the test compiles them
+    trainer._trunk_cache_sharding = lambda shape=None: None
+    assert trainer._trunk_cache_available()
+    return trainer
+
+
+def _ppo_hh_params(trainer):
+    """(trainable, frozen) flat float32 leaves of the cell's model, as shapes."""
+    from flax.traverse_util import flatten_dict
+
+    from trlx_tpu.models.policy import trainable_mask
+
+    probe = jnp.zeros((1, 8), I32)
+    params = jax.eval_shape(
+        lambda: trainer.model.init(jax.random.PRNGKey(0), probe, jnp.ones_like(probe))["params"])
+    flat = flatten_dict(params)
+    mask = flatten_dict(trainable_mask(params, trainer.model_cfg, 2))
+    return ({k: v for k, v in flat.items() if mask[k]},
+            {k: v for k, v in flat.items() if not mask[k]})
+
+
+def test_dense_ppo_cell_trunk_cache_fill_compiles_under_its_name(v5e, pallas_mode, ppo_hh_trainer):
+    """`jit_trunk_cache_fill` over one chunk of the cell, 16 x 1,024 tokens:
+    the frozen blocks' flash forwards and no head, the state in bfloat16,
+    the forward's own dtype."""
+    trainer = ppo_hh_trainer
+    one = SingleDeviceSharding(v5e[0])
+    train, frozen = _ppo_hh_params(trainer)
+    fill = trainer._build_trunk_cache_fn()
+    traced = fill.trace(*abstract((train, frozen, S((16, 1024), I32)), one))
+    assert traced.out_info.shape == (16, 1024, 2048) and traced.out_info.dtype == BF16
+    lowered = traced.lower(lowering_platforms=("tpu",))
+    assert "module @jit_trunk_cache_fill" in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    assert kernel_names(compiled) == ["flash_fwd"] * trainer.split
+
+
+def test_dense_ppo_cell_train_step_resumes_from_the_trunk_cache(v5e, pallas_mode, ppo_hh_trainer):
+    """The cell's train step in outline (the trainer's own loss, gradients
+    of the top two blocks, AdamW) over a batch of 8 that names its rows of
+    the cycle's cache `[64, 1024, 2048]`: the gather, the two trained
+    blocks forward and backward and the windowed head lower and compile
+    for one v5e chip, and no frozen block runs: two flash forwards, where
+    the whole forward of the same step runs one a block."""
+    import optax
+
+    from trlx_tpu.data import PPORLBatch
+
+    trainer = ppo_hh_trainer
+    one = SingleDeviceSharding(v5e[0])
+    train, frozen = _ppo_hh_params(trainer)
+    loss_fn = trainer.make_loss_fn()
+    opt = optax.adamw(6e-6)
+    opt_state = jax.eval_shape(opt.init, train)
+    b, q, new = 8, 896, 128
+    batch = PPORLBatch(
+        query_tensors=S((b, q), I32), response_tensors=S((b, new), I32),
+        logprobs=S((b, new), F32), values=S((b, new), F32), rewards=S((b, new), F32),
+        trunk_rows=S((b,), I32), trunk_cache=S((64, q + new, 2048), BF16))
+
+    def train_step(train, frozen, opt_state, batch):
+        grads = jax.grad(lambda p: loss_fn(p, frozen, batch)[0])(train)
+        updates, opt_state = opt.update(grads, opt_state, train)
+        return optax.apply_updates(train, updates), opt_state
+
+    def flash_forwards(batch):
+        compiled = jax.jit(train_step, donate_argnums=(0, 2)).trace(
+            *abstract((train, frozen, opt_state, batch), one)).lower(
+            lowering_platforms=("tpu",)).compile()
+        return sum(name.startswith("flash_fwd") for name in kernel_names(compiled)), compiled
+
+    resumed, compiled = flash_forwards(batch)
+    whole, _ = flash_forwards(batch.replace(trunk_rows=None, trunk_cache=None))
+    assert (resumed, whole) == (2, trainer.model_cfg.n_layers)
+    # the cache is an argument the step reads and hands back to nobody
+    assert donated_outputs(compiled) == len(jax.tree_util.tree_leaves((train, opt_state)))

@@ -1,20 +1,23 @@
-"""Frozen-trunk activation cache (method.cache_trunk_activations): the
-hydra trunk below the split is entirely frozen, so its output for a
-chunk's tokens is invariant across all PPO inner epochs — capture it once
-and train the suffix from it.
+"""Frozen-trunk activation cache: the hydra trunk below the split is
+entirely frozen, so the state entering block `split` is the same in every
+optimizer step of a cycle. The PPO schedule computes it once for each
+rollout chunk, keeps it on the device for the cycle, and resumes every
+step from it; `_trunk_cache_available` decides from the model, the recipe
+and the chip, and no flag does.
 
-Exactness contract pinned here:
-- f32 cache, eager evaluation: the cached-suffix loss AND gradients are
-  BITWISE equal to the full-forward loss path (the resumed suffix runs
-  the identical op sequence; padded cache rows are attention-masked and
-  exp(-1e9) underflows to exactly 0.0, so zero-filled collation padding
-  contributes nothing).
-- bf16 cache: one rounding of h_split (~8e-3 relative per value) through
-  the suffix; loss agrees to ~1e-4 relative at this scale, pinned with
-  an order of magnitude of headroom.
-- The end-to-end jitted path (store -> collate -> scan) is additionally
-  subject to XLA fusion drift between the jitted trunk pass and the
-  in-loss trunk, so e2e checks are finite/parity, not bitwise.
+Pinned here:
+- a whole classic cycle (`make_experience`, then `ppo_epochs` passes of
+  `create_train_dataloader` / `train_minibatch`, as bench/jobs/ppo.py
+  drives it) gives, under float32, every step's loss and the final
+  trainable leaves BITWISE equal to the same cycle run from the whole
+  forward: the fill lays a chunk's tokens out as the train batches will,
+  so a cached row is column for column what the whole forward computes;
+- the cycle's cache is one `jax.Array` that no `device_get` and no
+  collator touches: a batch carries `int32[b]` row numbers;
+- the arbiter's table; the cache's dtype (the forward's own);
+- `SparseMoE`'s dispatch counters survive a cached step; a quarantined
+  row leaves the other rows' numbers right; a restored store trains from
+  the whole forward.
 """
 
 import dataclasses
@@ -27,73 +30,112 @@ import pytest
 from trlx_tpu.data.default_configs import default_ppo_config
 from trlx_tpu.models import CausalLMWithValueHead
 from trlx_tpu.models.transformer import position_ids
+from trlx_tpu.observability import hbm
 from trlx_tpu.ops.ppo import get_advantages_and_returns
+from trlx_tpu.pipeline import MiniBatchIterator
 from trlx_tpu.pipeline.offline_pipeline import PromptPipeline
+from trlx_tpu.trainer import ppo_trainer
 from trlx_tpu.trainer.base_trainer import merge_params
-from trlx_tpu.trainer.ppo_trainer import PPOTrainer
+from trlx_tpu.trainer.ppo_trainer import PPOTrainer, _to_batch_columns
 
 MAX_NEW = 6
 SUPPRESS = [i for i in range(259) if not (32 <= i < 127 or i == 258)]
+PROMPTS = ["hello world", "jax tpu", "ppo", "fast"] * 2
 
 
-def _make_trainer(tmp_path, **method):
+def _make_trainer(tmp_path, model=None, train=None, prompts=PROMPTS, cls=PPOTrainer,
+                  tokenizer="byte", devices=None, **method):
     method = {
         "num_rollouts": 8, "chunk_size": 8, "ppo_epochs": 2,
-        "cache_trunk_activations": True, "trunk_cache_dtype": "float32",
         "gen_kwargs": dict(max_new_tokens=MAX_NEW, do_sample=True,
                            suppress_tokens=SUPPRESS),
         **method,
     }
     config = default_ppo_config().evolve(
-        # float32 end to end so the f32-cache test can assert BITWISE
-        # equality (bf16 rounding would mask the exactness claim)
-        model=dict(model_path="random:gpt2-tiny", num_layers_unfrozen=1,
-                   model_extra_configs={"dtype": "float32"}),
-        tokenizer=dict(tokenizer_path="byte"),
-        train=dict(seq_length=32, batch_size=8, total_steps=4, tracker=None,
-                   checkpoint_dir=str(tmp_path), seed=11),
+        # float32 end to end so the tests can assert BITWISE equality
+        # (bf16 rounding would mask the exactness claim)
+        model={**dict(model_path="random:gpt2-tiny", num_layers_unfrozen=1,
+                      model_extra_configs={"dtype": "float32"}), **(model or {})},
+        tokenizer=dict(tokenizer_path=tokenizer),
+        train={**dict(seq_length=32, batch_size=8, total_steps=4, tracker=None,
+                      checkpoint_dir=str(tmp_path), seed=11), **(train or {})},
         method=dict(**method),
     )
-    trainer = PPOTrainer(
+    # (one device: the benchmark's PPO cells, and no partitioner between
+    # two programs that should add up in the same order)
+    trainer = cls(
         config,
         reward_fn=lambda samples, **kw: [float(len(s)) for s in samples],
+        devices=None if devices is None else jax.devices()[:devices],
     )
-    pipeline = PromptPipeline(["hello world", "jax tpu", "ppo", "fast"] * 2,
-                              max_prompt_length=8, tokenizer=trainer.tokenizer)
+    pipeline = PromptPipeline(prompts, max_prompt_length=8, tokenizer=trainer.tokenizer)
     trainer.add_prompt_pipeline(pipeline)
     return trainer
 
 
+def _whole_forward(trainer):
+    """The same trainer with the arbiter saying no: every step runs the
+    whole forward."""
+    trainer._trunk_cache_available = lambda: False
+    return trainer
+
+
+def _cycle(trainer):
+    """One whole classic PPO cycle, as bench/jobs/ppo.py `run_cycle` drives
+    it; every optimizer step's loss and stats."""
+    method = trainer.config.method
+    trainer.store.clear_history()
+    trainer.make_experience(method.num_rollouts, trainer.iter_count)
+    losses, stats = [], None
+    for _ in range(method.ppo_epochs):
+        loader = trainer.create_train_dataloader()
+        for minibatch in MiniBatchIterator(loader, trainer.mb_size, trainer.num_mb):
+            stats = trainer.train_minibatch(minibatch)
+            trainer.iter_count += 1
+            losses.append(stats["losses"]["total_loss"])
+        trainer.post_backward_callback()
+    return [float(x) for x in jax.device_get(losses)], stats
+
+
+def _assert_same_leaves(a, b):
+    assert sorted(a.train_params) == sorted(b.train_params)
+    for k in a.train_params:
+        np.testing.assert_array_equal(
+            np.asarray(a.train_params[k]), np.asarray(b.train_params[k]), err_msg=str(k))
+
+
 @pytest.fixture(scope="module")
 def trainer(tmp_path_factory):
-    """Shared trainer (classic sampler, cache gate on, f32 cache) with one
-    collected store — the loss-level tests all read the same batch."""
+    """Shared trainer (classic sampler, f32) with one collected store — the
+    loss-level tests all read the same batch."""
     tr = _make_trainer(tmp_path_factory.mktemp("trunk_cache"))
+    assert tr._trunk_cache_available()
     tr.make_experience(8)
     return tr
 
 
 @pytest.fixture(scope="module")
 def chunk(trainer):
-    """One collated device batch from the store (h_split attached by the
-    loader's trunk-cache collation)."""
-    batch = next(iter(trainer.create_train_dataloader()))
-    assert batch.h_split is not None
-    assert batch.h_split.shape[:2] == (
-        batch.query_tensors.shape[0],
-        batch.query_tensors.shape[1] + batch.response_tensors.shape[1],
-    )
-    return jax.tree_util.tree_map(jnp.asarray, batch)
+    """One collated batch from the store: row numbers from the collator,
+    the cycle's cache from the trainer (here on one device, for the eager
+    loss-level tests)."""
+    host = next(iter(trainer.create_train_dataloader()))
+    assert host.trunk_cache is None and host.trunk_rows.dtype == np.int32
+    assert sorted(host.trunk_rows.tolist()) == list(range(8))
+    placed = trainer.batch_to_device(host)
+    assert placed.trunk_cache is trainer._trunk_cache
+    assert placed.trunk_cache.shape == (
+        8, host.query_tensors.shape[1] + host.response_tensors.shape[1],
+        trainer.model_cfg.d_model)
+    return jax.tree_util.tree_map(jnp.asarray, host).replace(
+        trunk_cache=jnp.asarray(np.asarray(trainer._trunk_cache)))
 
 
-def _eager_trunk(trainer, chunk):
-    """h_split recomputed EAGERLY with the exact op sequence the full
-    forward runs — the bitwise-equality reference (the store's cache went
-    through a jitted pass, which XLA may fuse differently)."""
+def _eager_trunk(trainer, tokens):
+    """The state entering block `split`, recomputed EAGERLY with the exact
+    op sequence the full forward runs."""
     params = merge_params(trainer.train_params, trainer.frozen_params)
-    pad = trainer.tokenizer.pad_token_id
-    tokens = jnp.concatenate([chunk.query_tensors, chunk.response_tensors], axis=1)
-    amask = (tokens != pad).astype(jnp.int32)
+    amask = (tokens != trainer.tokenizer.pad_token_id).astype(jnp.int32)
     return trainer.model.apply(
         {"params": params}, tokens, amask, position_ids(amask), stop=trainer.split,
         method=CausalLMWithValueHead.forward,
@@ -106,103 +148,266 @@ def _grads(trainer, loss_fn, batch):
     )(trainer.train_params)
 
 
+def _uncached(batch):
+    return batch.replace(trunk_rows=None, trunk_cache=None)
+
+
+# ----------------------------------------------------------------------
+# The whole classic cycle
+# ----------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("recipe", [
+    dict(chunk_size=8, devices=1),                                  # one chunk a cycle
+    dict(chunk_size=8),                                             # rows over 8 devices
+    dict(chunk_size=4, train=dict(batch_size=4), devices=1),        # two chunks: one array from both
+    dict(chunk_size=8, train=dict(minibatch_size=4), devices=1),    # the accumulation step
+], ids=["one_chunk", "one_chunk_8_devices", "two_chunks", "accumulation"])
+def test_classic_cycle_bitwise_equals_the_whole_forward(tmp_path, monkeypatch, recipe):
+    """Two cycles with the cache against two cycles from the whole forward:
+    every step's loss and the final trainable leaves bitwise equal; the
+    cycle's cache is a device array that nothing copies to the host, and
+    the collator builds row numbers, never a [b, T, d] array."""
+    recipe = dict(recipe)
+    train = recipe.pop("train", None)
+    cached = _make_trainer(tmp_path / "cached", train=train, **recipe)
+    whole = _whole_forward(_make_trainer(tmp_path / "whole", train=train, **recipe))
+    assert cached._trunk_cache_available() and not whole._trunk_cache_available()
+
+    fetched = []
+    real_get = jax.device_get
+
+    def spy_get(tree):
+        fetched.extend(np.shape(x) for x in jax.tree_util.tree_leaves(tree))
+        return real_get(tree)
+
+    for cycle in range(2):
+        monkeypatch.setattr(jax, "device_get", spy_get)
+        got, _ = _cycle(cached)
+        monkeypatch.setattr(jax, "device_get", real_get)
+        want, _ = _cycle(whole)
+        assert got == want and np.all(np.isfinite(got)), (cycle, got, want)
+        assert len(got) == 2 * (8 // cached.config.train.batch_size)
+
+        cache = cached._trunk_cache
+        assert isinstance(cache, jax.Array) and cache.dtype == jnp.float32
+        assert cache.shape == (8, 32, cached.model_cfg.d_model)
+        assert sorted(e.trunk_row for e in cached.store.history) == list(range(8))
+        for host in cached.create_train_dataloader():
+            leaves = jax.tree_util.tree_leaves(host)
+            assert all(isinstance(x, np.ndarray) and x.ndim <= 2 for x in leaves)
+            assert host.trunk_rows.shape == (cached.config.train.batch_size,)
+    assert fetched and all(len(shape) <= 2 for shape in fetched), fetched
+    assert whole._trunk_cache is None and whole._trunk_cache_fn is None
+    assert all(e.trunk_row is None for e in whole.store.history)
+    _assert_same_leaves(cached, whole)
+
+
+def test_the_next_collection_over_an_empty_store_drops_the_cache(tmp_path):
+    tr = _make_trainer(tmp_path)
+    tr.make_experience(8)
+    first = tr._trunk_cache
+    # a second collection over the SAME store adds its rows behind the first's
+    tr.make_experience(8)
+    assert tr._trunk_cache.shape[0] == 16
+    assert [e.trunk_row for e in tr.store.history] == list(range(16))
+    np.testing.assert_array_equal(np.asarray(tr._trunk_cache[:8]), np.asarray(first))
+    tr.store.clear_history()
+    tr._open_trunk_cache()
+    assert tr._trunk_cache is None and tr._trunk_chunks == []
+
+
+def test_one_epoch_fills_nothing_and_trains_from_the_whole_forward(tmp_path):
+    """One optimizer pass over a chunk's rows: the fill would cost what it
+    saves, so the schedule is today's, program for program."""
+    tr = _make_trainer(tmp_path, ppo_epochs=1)
+    assert not tr._trunk_cache_available()
+    losses, _ = _cycle(tr)
+    assert np.all(np.isfinite(losses))
+    assert tr._trunk_cache is None and tr._trunk_cache_fn is None
+    assert all(e.trunk_row is None for e in tr.store.history)
+    host = next(iter(tr.create_train_dataloader()))
+    assert host.trunk_rows is None and tr.batch_to_device(host).trunk_cache is None
+
+
+def test_a_restored_store_trains_from_the_whole_forward(tmp_path):
+    """The rows a checkpointed store held went with the process that
+    filled the cache: the restored rollouts carry none."""
+    tr = _make_trainer(tmp_path)
+    tr.make_experience(8)
+    state = tr._extra_resume_state()
+    assert all(e.trunk_row is not None for e in state["store_history"])
+    tr._load_extra_resume_state(state)
+    assert tr._trunk_cache is None
+    assert all(e.trunk_row is None for e in tr.store.history)
+    host = next(iter(tr.create_train_dataloader()))
+    assert host.trunk_rows is None
+    # rows that outlive their cache some other way are dropped at the door
+    orphan = tr.batch_to_device(host.replace(trunk_rows=np.arange(8, dtype=np.int32)))
+    assert orphan.trunk_rows is None and orphan.trunk_cache is None
+
+
+# ----------------------------------------------------------------------
+# The arbiter
+# ----------------------------------------------------------------------
+
+V5E = 16 * hbm.GiB
+
+
+class _OwnLoss(PPOTrainer):
+    def make_loss_fn(self):
+        return super().make_loss_fn()
+
+
+def _patch(trainer, monkeypatch, *, method=None, model_cfg=None, train=None, budget=0, **attrs):
+    if method or train:
+        monkeypatch.setattr(trainer, "config", trainer.config.evolve(
+            method=dict(method or {}), train=dict(train or {})))
+    if model_cfg:
+        monkeypatch.setattr(
+            trainer, "model_cfg", dataclasses.replace(trainer.model_cfg, **model_cfg))
+    monkeypatch.setattr(trainer, "_trunk_cache_budget", budget)
+    for name, value in attrs.items():
+        monkeypatch.setattr(trainer, name, value)
+
+
+CELL = dict(budget=int(ppo_trainer.TRUNK_CACHE_HBM_SHARE * V5E))
+ARBITER = {
+    # what the schedule observes -> (patch, engages, bytes a device holds)
+    "as_built": (dict(), True, 8 * 32 * 64 * 4),
+    "one_epoch": (dict(method=dict(ppo_epochs=1)), False, None),
+    "nothing_frozen": (dict(split=0), False, None),
+    "seq2seq": (dict(seq2seq=True), False, None),
+    # MoEMLP's softmax router sows an auxiliary loss from the full forward
+    "sows_moe_aux": (dict(model_cfg=dict(moe_experts=2)), False, None),
+    # n_layers=2, split=1, 2 value layers: the branch taps at layer 0 < split
+    "value_branch_below_split": (dict(method=dict(num_value_layers_unfrozen=2)), False, None),
+    "over_hbm_budget": (dict(budget=8 * 32 * 64 * 4 - 1), False, None),
+    "just_inside_hbm_budget": (dict(budget=8 * 32 * 64 * 4), True, None),
+    # a backend that reports no capacity (the CPU) bounds nothing
+    "no_capacity_known": (dict(budget=0), True, None),
+    # the benchmark's three PPO cells on a v5e's 16 GiB
+    "pythia-1.4b.ppo-hh": (dict(
+        CELL, method=dict(num_rollouts=64, chunk_size=16, ppo_epochs=4), train=dict(seq_length=1024),
+        model_cfg=dict(d_model=2048, n_layers=24, dtype=jnp.bfloat16), split=22), True, 268435456),
+    "gpt2-xl.ppo-sentiments": (dict(
+        CELL, method=dict(num_rollouts=128, chunk_size=128, ppo_epochs=4), train=dict(seq_length=104),
+        model_cfg=dict(d_model=1600, n_layers=48, dtype=jnp.bfloat16), split=46), True, 42598400),
+    "lfm2-8b-a1b.ppo-hh": (dict(
+        CELL, method=dict(num_rollouts=64, chunk_size=64, ppo_epochs=4), train=dict(seq_length=1024),
+        model_cfg=dict(d_model=2048, n_layers=10, dtype=jnp.bfloat16), split=8), True, 268435456),
+    # the same recipe at a width and depth of rollouts one chip cannot hold
+    "too_many_rollouts": (dict(
+        CELL, method=dict(num_rollouts=1024, chunk_size=16, ppo_epochs=4), train=dict(seq_length=1024),
+        model_cfg=dict(d_model=2048, n_layers=24, dtype=jnp.bfloat16), split=22), False, 4294967296),
+}
+
+
+@pytest.mark.parametrize("case", list(ARBITER))
+def test_arbiter_table(trainer, monkeypatch, case):
+    patch, engages, nbytes = ARBITER[case]
+    # one chip's numbers: the rows of the fixture's cache lie on one device
+    monkeypatch.setattr(trainer, "_trunk_cache_sharding", lambda shape=None: None)
+    _patch(trainer, monkeypatch, **patch)
+    assert trainer._trunk_cache_available() is engages
+    if nbytes is not None:
+        assert trainer._trunk_cache_device_bytes() == nbytes
+
+
+def test_a_device_holds_its_share_of_the_rows(trainer):
+    assert trainer.runtime.dp_size == 8
+    assert trainer._trunk_cache_device_bytes() == 8 * 32 * 64 * 4 // 8
+    assert len(trainer._trunk_cache.sharding.device_set) == 8
+
+
+def test_arbiter_reads_the_devices_capacity_and_says_no_to_a_trainer_with_its_own_loss(
+        trainer, monkeypatch, tmp_path):
+    monkeypatch.setattr(trainer, "_trunk_cache_budget", None)
+    monkeypatch.setattr(hbm, "device_hbm_bytes", lambda device=None: V5E)
+    assert trainer._trunk_cache_available()
+    assert trainer._trunk_cache_budget == V5E // 8
+    # a trainer that builds its own loss has no resumed forward (GRPO; the
+    # pipelined and sequence-parallel trainers say no themselves)
+    own = _make_trainer(tmp_path, cls=_OwnLoss)
+    assert not own._trunk_cache_available()
+    own.make_experience(8)
+    assert own._trunk_cache is None and all(e.trunk_row is None for e in own.store.history)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_cache_holds_the_dtype_the_forward_hands_to_the_split(tmp_path, dtype):
+    """Never a narrower one: nothing is rounded that was not rounded."""
+    tr = _make_trainer(tmp_path, model=dict(model_extra_configs={"dtype": dtype}))
+    tokens = jax.ShapeDtypeStruct((8, 32), jnp.int32)
+    fill = tr._build_trunk_cache_fn()
+    out = jax.eval_shape(fill, tr.train_params, tr.frozen_params, tokens)
+    assert out.dtype == jnp.dtype(dtype) == jnp.dtype(tr.model_cfg.dtype)
+    assert out.shape == (8, 32, tr.model_cfg.d_model)
+    # the fill's program is found by its name in a device trace
+    lowered = jax.jit(fill).lower(tr.train_params, tr.frozen_params, tokens)
+    assert "jit_trunk_cache_fill" in lowered.as_text()[:200]
+
+
+# ----------------------------------------------------------------------
+# The loss
+# ----------------------------------------------------------------------
+
+
 def test_f32_cache_loss_and_grads_exact(trainer, chunk):
-    """f32 cache: cached-suffix loss and EVERY gradient leaf bitwise equal
-    to the full-forward path (eager evaluation on both sides)."""
+    """The resumed loss and EVERY gradient leaf bitwise equal to the
+    full-forward path (eager evaluation on both sides, the trunk recomputed
+    eagerly); from the rows the cycle's jitted fill left, the same loss
+    (jit against jit is the whole-cycle test's to hold bitwise)."""
     loss_fn = trainer.make_loss_fn()
-    h = _eager_trunk(trainer, chunk)
-    cached = chunk.replace(h_split=h)
-    full = chunk.replace(h_split=None)
-    l_c, _ = loss_fn(trainer.train_params, trainer.frozen_params, cached)
+    tokens = jnp.concatenate([chunk.query_tensors, chunk.response_tensors], axis=1)
+    eager = chunk.replace(trunk_rows=jnp.arange(8, dtype=jnp.int32),
+                          trunk_cache=_eager_trunk(trainer, tokens))
+    full = _uncached(chunk)
     l_f, _ = loss_fn(trainer.train_params, trainer.frozen_params, full)
+    l_c, _ = loss_fn(trainer.train_params, trainer.frozen_params, eager)
     np.testing.assert_array_equal(np.asarray(l_c), np.asarray(l_f))
-    g_c = _grads(trainer, loss_fn, cached)
+    g_c = _grads(trainer, loss_fn, eager)
     g_f = _grads(trainer, loss_fn, full)
     for a, b in zip(jax.tree_util.tree_leaves(g_c), jax.tree_util.tree_leaves(g_f)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    l_j, _ = loss_fn(trainer.train_params, trainer.frozen_params, chunk)
+    np.testing.assert_allclose(float(l_j), float(l_f), rtol=1e-6)
 
 
-def test_bf16_cache_within_tolerance(trainer, chunk):
-    """bf16 cache: one rounding of h_split through the suffix. Measured
-    loss deviation ~1e-4 relative at this scale; pinned at 2e-3 (10x
-    headroom). Gradients within a loose atol relative to their scale."""
+@pytest.mark.parametrize("left", [True, False], ids=["left_queries", "right_queries"])
+@pytest.mark.parametrize("gap", [0, 3])
+def test_rows_move_to_a_batch_that_pads_queries_wider(left, gap):
+    """Rows filled at one query width in a batch that pads queries wider
+    (a store whose bucket grew): where the old host collator put them."""
+    q, r, d = 4, 3, 2
+    h = np.arange(2 * (q + r) * d, dtype=np.float32).reshape(2, q + r, d) + 1
+    want = np.zeros((2, q + gap + r, d), np.float32)
+    want[:, (gap if left else 0):(gap if left else 0) + q] = h[:, :q]
+    want[:, q + gap:] = h[:, q:]
+    got = _to_batch_columns(jnp.asarray(h), q, q + gap, q + gap + r, left)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # token ids on the host take the same road, pads in the gap
+    ids = _to_batch_columns(np.ones((2, q + r), np.int32), q, q + gap, None, left, fill=7)
+    assert isinstance(ids, np.ndarray) and ids.shape == (2, q + gap + r)
+    assert (ids == 7).sum() == 2 * gap and (want[..., 0] == 0).tolist() == (ids == 7).tolist()
+    with pytest.raises(ValueError, match="under the rows'"):
+        _to_batch_columns(jnp.asarray(h), q, q - 1, q + r, left)
+
+
+def test_rows_filled_at_a_narrower_query_width_train_the_same_loss(trainer, chunk):
+    """The fill's layout is the train batch's by construction; if a batch
+    still comes wider than the cache, the rows move inside the step."""
     loss_fn = trainer.make_loss_fn()
-    h = _eager_trunk(trainer, chunk).astype(jnp.bfloat16)
-    cached = chunk.replace(h_split=h)
-    full = chunk.replace(h_split=None)
+    q = chunk.query_tensors.shape[1]
+    narrow = chunk.query_tensors[:, 8:]  # the prompts are 8 wide, left-padded to 26
+    assert bool(jnp.all(chunk.query_tensors[:, :8] == trainer.tokenizer.pad_token_id))
+    tokens = jnp.concatenate([narrow, chunk.response_tensors], axis=1)
+    cached = chunk.replace(trunk_rows=jnp.arange(8, dtype=jnp.int32),
+                           trunk_cache=_eager_trunk(trainer, tokens))
+    assert cached.trunk_cache.shape[1] == q - 8 + MAX_NEW
     l_c, _ = loss_fn(trainer.train_params, trainer.frozen_params, cached)
-    l_f, _ = loss_fn(trainer.train_params, trainer.frozen_params, full)
-    np.testing.assert_allclose(float(l_c), float(l_f), rtol=2e-3)
-    g_c = _grads(trainer, loss_fn, cached)
-    g_f = _grads(trainer, loss_fn, full)
-    for a, b in zip(jax.tree_util.tree_leaves(g_c), jax.tree_util.tree_leaves(g_f)):
-        a, b = np.asarray(a), np.asarray(b)
-        scale = max(float(np.abs(b).max()), 1e-3)
-        np.testing.assert_allclose(a, b, atol=5e-2 * scale)
-
-
-def test_flag_off_bit_identity(trainer, chunk):
-    """cache_trunk_activations off -> the loss graph is unchanged: the
-    flag never enters loss_fn (only whether h_split rides on the batch
-    does), so the flag-off loss on the same data is bitwise identical."""
-    full = chunk.replace(h_split=None)
-    loss_on, _ = trainer.make_loss_fn()(
-        trainer.train_params, trainer.frozen_params, full
-    )
-    on_config = trainer.config
-    try:
-        trainer.config = trainer.config.evolve(
-            method=dict(cache_trunk_activations=False)
-        )
-        assert not trainer._trunk_cache_available()
-        loss_off, _ = trainer.make_loss_fn()(
-            trainer.train_params, trainer.frozen_params, full
-        )
-    finally:
-        trainer.config = on_config
-    np.testing.assert_array_equal(np.asarray(loss_on), np.asarray(loss_off))
-
-
-def test_gate_refusals(trainer):
-    """Gate mirrors _fast_rollout_available's geometry: refuses MoE,
-    split == 0, a value branch below the split, seq2seq, and flag off."""
-    assert trainer._trunk_cache_available()
-    on_config = trainer.config
-    model_cfg = trainer.model_cfg
-    try:
-        trainer.config = on_config.evolve(
-            method=dict(cache_trunk_activations=False)
-        )
-        assert not trainer._trunk_cache_available()
-        trainer.config = on_config
-
-        # MoE: routing recomputes the aux loss from the full forward
-        trainer.model_cfg = dataclasses.replace(model_cfg, moe_experts=2)
-        assert not trainer._trunk_cache_available()
-        trainer.model_cfg = model_cfg
-
-        # split 0 (e.g. num_layers_unfrozen=-1 / LoRA): nothing is frozen
-        split = trainer.split
-        trainer.split = 0
-        assert not trainer._trunk_cache_available()
-        trainer.split = split
-
-        # value branch tapping BELOW the split (n_layers=2, split=1,
-        # 2 value layers -> tap at layer 0 < split): h_split can't feed it
-        trainer.config = on_config.evolve(
-            method=dict(num_value_layers_unfrozen=2)
-        )
-        assert not trainer._trunk_cache_available()
-        trainer.config = on_config
-
-        trainer.seq2seq = True
-        assert not trainer._trunk_cache_available()
-        trainer.seq2seq = False
-    finally:
-        trainer.config = on_config
-        trainer.model_cfg = model_cfg
-        trainer.seq2seq = False
-    assert trainer._trunk_cache_available()
+    l_f, _ = loss_fn(trainer.train_params, trainer.frozen_params, _uncached(chunk))
+    np.testing.assert_allclose(float(l_c), float(l_f), rtol=1e-5)
 
 
 def test_whiten_with_mask_both_behaviors(trainer, chunk):
@@ -233,7 +438,7 @@ def test_whiten_with_mask_both_behaviors(trainer, chunk):
     assert abs(masked_mean) < 1e-5
     assert not np.allclose(np.asarray(adv_u), np.asarray(adv_m))
 
-    full = chunk.replace(h_split=None)
+    full = _uncached(chunk)
     loss_off, _ = trainer.make_loss_fn()(
         trainer.train_params, trainer.frozen_params, full
     )
@@ -248,26 +453,60 @@ def test_whiten_with_mask_both_behaviors(trainer, chunk):
     assert float(loss_on) != float(loss_off)
 
 
-def test_store_path_trains_from_cache(trainer):
-    """Classic store path end to end: make_experience attached h_split to
-    every element, the loader collated it, and the fused scan train path
-    consumes the extended batch (finite loss, params move)."""
-    assert all(e.h_split is not None for e in trainer.store.history)
-    batch = next(iter(trainer.create_train_dataloader()))
-    chunk = jax.tree_util.tree_map(jnp.asarray, batch)
-    p0 = jax.device_get(next(iter(trainer.train_params.values())))
-    stats = trainer.train_epochs_from_chunk(chunk, 2)
-    loss = float(np.asarray(stats["losses"]["total_loss"]))
-    assert np.isfinite(loss)
-    p1 = jax.device_get(next(iter(trainer.train_params.values())))
-    assert not np.allclose(p0, p1)
+def test_the_counter_span_says_what_every_dispatch_resumed_from(trainer, monkeypatch):
+    """`trlx:ppo.trunk_cache blocks=.. cached_blocks=.. rows=..` in front of
+    every train-step dispatch while a profiler session listens (read by
+    bench/metrics/ppo.trunk_cached_share.json), and never otherwise."""
+    from trlx_tpu.observability import tracing
+
+    seen = []
+    monkeypatch.setattr(tracing, "counters", lambda name, **kv: seen.append((name, kv)))
+    host = next(iter(trainer.create_train_dataloader()))
+    trainer.batch_to_device(host)
+    assert seen == []
+    monkeypatch.setattr(tracing, "active", lambda: True)
+    trainer.batch_to_device(host)
+    trainer.batch_to_device(_uncached(host))
+    assert seen == [
+        ("ppo.trunk_cache", dict(blocks=2, cached_blocks=1, rows=8)),
+        ("ppo.trunk_cache", dict(blocks=2, cached_blocks=0, rows=8)),
+    ]
+
+
+# ----------------------------------------------------------------------
+# The scan paths: the store's batches and the fused cycle's device chunks
+# carry the cache one way
+# ----------------------------------------------------------------------
+
+
+def test_store_path_trains_from_cache(tmp_path):
+    """The store's batches through both scans (`train_inner_epoch_fused`
+    stacks host batches; `train_epochs_from_chunk` gathers a device
+    chunk): the cache rides beside the stack, the steps gather their rows,
+    and the losses are the whole forward's."""
+    cached = _make_trainer(tmp_path / "a", devices=1)
+    whole = _whole_forward(_make_trainer(tmp_path / "b", devices=1))
+    for tr in (cached, whole):
+        tr.config = tr.config.evolve(train=dict(batch_size=4))
+        tr.make_experience(8)
+    assert all(e.trunk_row is not None for e in cached.store.history)
+    stats = [tr.train_inner_epoch_fused(tr.create_train_dataloader())[0] for tr in (cached, whole)]
+    got, want = (float(np.asarray(s["losses"]["total_loss"])) for s in stats)
+    assert np.isfinite(got) and got == pytest.approx(want, rel=1e-6)
+
+    for tr in (cached, whole):
+        tr.config = tr.config.evolve(train=dict(batch_size=8))
+    chunks = [tr.batch_to_device(next(iter(tr.create_train_dataloader()))) for tr in (cached, whole)]
+    assert chunks[0].trunk_cache is not None and chunks[1].trunk_cache is None
+    stats = [tr.train_epochs_from_chunk(c, 2) for tr, c in zip((cached, whole), chunks)]
+    got, want = (float(np.asarray(s["losses"]["total_loss"])) for s in stats)
+    assert np.isfinite(got) and got == pytest.approx(want, rel=1e-5)
 
 
 def test_pipelined_cycle_with_capture_reuses_h_split(tmp_path_factory):
-    """2-cycle end-to-end PPO with the cache on + the rollout fast path:
-    the sampler's captured h_split is handed to the trunk cache (the cast
-    fn compiles; the trunk recompute fn never does), losses are finite,
-    and training moves the params."""
+    """2-cycle end-to-end PPO with the rollout fast path: the sampler's
+    captured state IS the trunk cache (the fill never compiles), losses
+    are finite, and training moves the params."""
     tr = _make_trainer(tmp_path_factory.mktemp("tc_fast"),
                        capture_rollout_stats=True)
     assert tr._fast_rollout_available() and tr._trunk_cache_available()
@@ -278,20 +517,111 @@ def test_pipelined_cycle_with_capture_reuses_h_split(tmp_path_factory):
     assert isinstance(loss1, float) and np.isfinite(loss1)
     assert np.isfinite(float(np.asarray(pending[2][0])))
     # zero extra forwards: the captured activations fed the cache
-    assert tr._cache_cast_fn is not None
     assert tr._trunk_cache_fn is None
     assert getattr(tr, "spec_fallbacks", 0) == 0
     p1 = jax.device_get(next(iter(tr.train_params.values())))
     assert not np.allclose(p0, p1)
 
 
-def test_pipelined_cycle_classic_computes_trunk(tmp_path_factory):
-    """2-cycle end-to-end with the cache on but NO capture: the cycle
-    fills the cache with the jitted trunk pass instead."""
-    tr = _make_trainer(tmp_path_factory.mktemp("tc_classic"))
+@pytest.mark.parametrize("chunks", [1, 2])
+def test_pipelined_cycle_classic_computes_trunk(tmp_path_factory, chunks):
+    """2-cycle end-to-end with NO capture: the cycle fills the cache with
+    the jitted trunk pass, one fill a chunk; k chunks' caches are one array
+    whose rows the concatenated chunk numbers anew."""
+    tr = _make_trainer(tmp_path_factory.mktemp("tc_classic"), num_rollouts=8 * chunks)
     assert not tr._fast_rollout_available() and tr._trunk_cache_available()
+    seen = []
+    real = tr.train_epochs_from_chunk
+    tr.train_epochs_from_chunk = lambda full, n: (seen.append(full), real(full, n))[1]
     loss0, pending = tr.pipelined_cycle()
     assert loss0 is None
     loss1, pending = tr.pipelined_cycle(pending)
     assert isinstance(loss1, float) and np.isfinite(loss1)
     assert tr._trunk_cache_fn is not None
+    full = seen[-1]
+    assert full.trunk_cache.shape[0] == 8 * chunks
+    assert np.asarray(full.trunk_rows).tolist() == list(range(8 * chunks))
+
+
+# ----------------------------------------------------------------------
+# SparseMoE's counters; the sentinel's quarantine
+# ----------------------------------------------------------------------
+
+
+def test_sparse_moe_trains_from_the_cache_and_keeps_its_counters(tmp_path):
+    """lfm2's preset at tiny size (convolution layers in the trunk, both
+    trained blocks expert layers): the cached cycle's losses are the whole
+    forward's, and `moe/*` ride every step, reduced over the blocks the
+    step runs."""
+    from trlx_tpu.ops import moe
+
+    def build(path):
+        return _make_trainer(
+            path, tokenizer="char:abcdefgh", prompts=["ab", "cdefg", "e", "ghab"] * 2,
+            model=dict(model_path="random:lfm2-tiny", num_layers_unfrozen=2,
+                       model_extra_configs=dict(moe_local_experts=2, dtype="float32")),
+            train=dict(seq_length=24, batch_size=4), devices=1,
+            gen_kwargs=dict(max_new_tokens=MAX_NEW, top_k=0, top_p=1.0, do_sample=True))
+
+    cached, whole = build(tmp_path / "cached"), _whole_forward(build(tmp_path / "whole"))
+    cfg = cached.model_cfg
+    assert cached._trunk_cache_available() and cfg.has_sparse_moe and cached.split == 4
+    assert "conv" in cfg.layer_types[:cached.split]
+    (got, stats), (want, whole_stats) = _cycle(cached), _cycle(whole)
+    assert got == want and np.all(np.isfinite(got)) and len(got) == 4
+    assert cached._trunk_cache.shape == (8, 24, cfg.d_model)
+    counters = {k: float(v) for k, v in stats["moe"].items()}
+    assert sorted(counters) == sorted(moe.STATS)
+    assert counters["dropped_tokens"] == 0.0 and 0.0 < counters["local_assignment_share"] < 1.0
+    # blocks 4 and 5 of the whole forward's four expert layers
+    assert float(whole_stats["moe"]["dropped_tokens"]) == 0.0
+    _assert_same_leaves(cached, whole)
+
+
+class _DropOneRow:
+    """A sentinel that quarantines the second row of the first chunk."""
+
+    def __init__(self):
+        self.chunks = 0
+
+    def quarantine_mask(self, scores, lens, reps):
+        self.chunks += 1
+        drop = np.zeros(len(scores), bool)
+        drop[1] = self.chunks == 1
+        return drop
+
+    def kl_scale(self, step):
+        return 1.0
+
+    def lr_scale(self, step):
+        return 1.0
+
+    def observe_rollout(self, stats):
+        pass
+
+
+def test_a_quarantined_row_leaves_the_other_rows_right(tmp_path):
+    """A dropped row keeps its slot in the cache and no element names it;
+    every other element still names the state of ITS tokens."""
+    tr = _make_trainer(tmp_path, chunk_size=4, train=dict(batch_size=4))
+    tr._sentinel = _DropOneRow()
+    tr.make_experience(8)
+    rows = [e.trunk_row for e in tr.store.history]
+    # chunk 0 lost row 1, so a third chunk made up the count
+    assert rows == [0, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11]
+    assert tr._trunk_cache.shape[0] == 12
+    pad, q = tr.tokenizer.pad_token_id, tr._train_query_width(8)
+    for e in tr.store.history:
+        tokens = np.full((1, q + MAX_NEW), pad, np.int32)
+        tokens[0, q - len(e.query_tensor):q] = e.query_tensor
+        tokens[0, q:q + len(e.response_tensor)] = e.response_tensor
+        want = _eager_trunk(tr, jnp.asarray(tokens))[0]
+        real = tokens[0] != pad
+        np.testing.assert_allclose(
+            np.asarray(tr._trunk_cache[e.trunk_row])[real], np.asarray(want)[real],
+            rtol=1e-5, atol=1e-6)
+    # and the batches train from them
+    host = next(iter(tr.create_train_dataloader()))
+    assert set(host.trunk_rows.tolist()) <= set(rows)
+    stats = tr.train_minibatch([host])
+    assert np.isfinite(float(stats["losses"]["total_loss"]))

@@ -61,10 +61,10 @@ class PPORLElement:
     logprobs: np.ndarray  # [response_size]
     values: np.ndarray  # [response_size]
     rewards: np.ndarray  # [response_size]
-    # frozen-trunk activation entering the hydra split, full sample width
-    # [query_size + response_size(+1), d_model]; only populated when
-    # method.cache_trunk_activations is on (None otherwise)
-    h_split: Optional[np.ndarray] = None
+    # this rollout's row in the trainer's cycle-wide trunk cache (the
+    # state entering the first trainable block, kept on the device for the
+    # cycle); None where the schedule trains from the whole forward
+    trunk_row: Optional[int] = None
     # GRPO/RLOO: id of the G-completion prompt group this rollout belongs
     # to — rides the store so group-relative normalization happens per
     # prompt group, not per chunk (None for PPO)
@@ -86,11 +86,14 @@ class PPORLBatch:
     logprobs: Any  # f32 [b, padded_response]
     values: Any  # f32 [b, padded_response]
     rewards: Any  # f32 [b, padded_response]
-    # optional frozen-trunk activation cache aligned with
-    # concat(query_tensors, response_tensors): [b, padded_q + padded_r, d]
-    # in method.trunk_cache_dtype; None (no pytree leaf) when the trunk
-    # cache is off, so every existing 5-field constructor/scan still works
-    h_split: Any = None
+    # optional int32 [b]: each rollout's row in the cycle's trunk cache, and
+    # the cache itself, [rollouts, query + response, d] in the forward's own
+    # dtype: ONE device array for the cycle, the same in every batch (the
+    # trainer attaches it to a placed batch; a collator never sees it).
+    # None (no pytree leaf) where the step runs the whole forward, so every
+    # 5-field constructor/scan still works
+    trunk_rows: Any = None
+    trunk_cache: Any = None
     # optional int32 [b] prompt-group ids (GRPO/RLOO); None for PPO
     group_ids: Any = None
     # optional f32 [b, padded_response] policy-token masks (multi-turn

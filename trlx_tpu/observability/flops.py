@@ -97,7 +97,6 @@ def layer_attention_flops(model_cfg, i: int, ctx: float) -> float:
 def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
                     unfrozen, window_ok: bool = True,
                     fast_path: bool = False,
-                    trunk_cache: bool = False,
                     spec_k: int = 0, spec_accept: float = 0.0,
                     spec_rank: int = 64) -> dict:
     """Itemized FLOP estimate for one PPO cycle (documented approximations;
@@ -165,26 +164,19 @@ def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
         # scoring: full policy+value fwd, plus the in-graph frozen-reference
         # branch re-running the top `unfrozen` blocks + lm_head
         score = fwd(T, T / 2) + fwd(T, T / 2, layers=unfrozen)
-    if trunk_cache and not fast_path:
-        # trunk cache on the classic schedule: ONE extra frozen-prefix pass
-        # per chunk fills the cache (on the fast schedule the sampler's
-        # in-loop capture makes it free — already counted under gen)
-        score = score + fwd(T, T / 2, layers=L - unfrozen, with_head=False, top=False)
-    # one train step: the trunk runs full-width fwd + dX/dW over the
-    # unfrozen top. When the r5 windowed head applies (ppo_trainer
+    # one train step: fwd + dX/dW over the unfrozen top. What a step
+    # REQUIRES of the frozen blocks below is nothing: their state for these
+    # tokens is what the scorer's forward already computed, so a trunk that
+    # a schedule runs again (the PPO trainer's once-a-cycle cache fill; the
+    # whole forward of every step where `_trunk_cache_available` says no)
+    # is recomputation and is not charged, like rematerialization.
+    # When the r5 windowed head applies (ppo_trainer
     # `forward(window=...)` — no MoE, no deeper value branch, no soft prompt),
     # the 2·d·V unembedding (fwd + dX) only covers the n_new response
     # positions the loss reads; otherwise the step really computes the
     # full-width head and the estimate must charge all T positions.
     head_tokens = n_new if window_ok else T
-    if trunk_cache:
-        # cached schedule (r6): the frozen prefix comes from the per-chunk
-        # cache, so each inner epoch's forward is suffix-only — the top
-        # `unfrozen` blocks + head — while backward is unchanged (grads
-        # already stop at the first trainable layer)
-        train_fwd = fwd(T, T / 2, layers=unfrozen, with_head=False)
-    else:
-        train_fwd = fwd(T, T / 2, with_head=False)
+    train_fwd = fwd(T, T / 2, layers=unfrozen, with_head=False)
     train = (train_fwd + head_tokens * head
              + fwd(T, T / 2, layers=unfrozen, with_head=False) + head_tokens * head
              + fwd(T, T / 2, layers=unfrozen, with_head=False))

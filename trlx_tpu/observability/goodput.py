@@ -170,7 +170,6 @@ class GoodputLedger:
     def configure_unit_flops(self, model_cfg, n_prompt: int, n_new: int,
                              unfrozen: int, window_ok: bool = True,
                              fast_path: bool = False,
-                             trunk_cache: bool = False,
                              spec_k: int = 0, spec_accept: float = 0.0,
                              spec_rank: int = 64) -> None:
         """Price one sample with the bench FLOP model. ppo_epochs=1: the
@@ -179,7 +178,7 @@ class GoodputLedger:
         unit = flops_per_sample(
             model_cfg, n_prompt, n_new, ppo_epochs=1, unfrozen=unfrozen,
             window_ok=window_ok, fast_path=fast_path,
-            trunk_cache=trunk_cache, spec_k=spec_k,
+            spec_k=spec_k,
             spec_accept=spec_accept, spec_rank=spec_rank,
         )
         with self._lock:

@@ -163,9 +163,10 @@ def decode_state_bytes(cfg, batch: int, cache_len: int, dtype="float32") -> int:
 
 def trunk_cache_bytes(rows: int, seq_len: int, d_model: int,
                       dtype="float32") -> int:
-    """Frozen-trunk activation cache: one `[rows, seq_len, d_model]`
-    split tensor per cached chunk (ppo_trainer trunk cache / bench
-    `trunk_cache_hbm_bytes`)."""
+    """Frozen-trunk activation cache: the `[rows, seq_len, d_model]` state
+    entering the first trainable block, kept for a PPO cycle
+    (`PPOTrainer._trunk_cache_available` holds it to a share of the
+    device; bench.py's `trunk_cache_hbm_bytes`)."""
     return int(rows) * int(seq_len) * int(d_model) * _itemsize(dtype)
 
 

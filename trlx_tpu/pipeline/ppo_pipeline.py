@@ -87,24 +87,11 @@ class PPORolloutStorage(BaseRolloutStore):
             queries, responses, logprobs, values, rewards = ppo_collate(
                 elems, max_q, max_r, max_p, pad_id, left_queries
             )
-            h_split = None
-            if all(e.h_split is not None for e in elems):
-                # Trunk-cache collation: align each element's rows with the
-                # padded concat(query, response) layout. Zero-filled pad
-                # rows are EXACT — padded columns are attention-masked and
-                # exp(-1e9) underflows to 0.0, so their values are never
-                # read by the resumed suffix.
-                d = elems[0].h_split.shape[-1]
-                dt = elems[0].h_split.dtype
-                h_split = np.zeros((len(elems), max_q + max_r, d), dtype=dt)
-                for i, e in enumerate(elems):
-                    qi = len(e.query_tensor)
-                    w = min(e.h_split.shape[0] - qi, max_r)
-                    if left_queries:
-                        h_split[i, max_q - qi:max_q] = e.h_split[:qi]
-                    else:
-                        h_split[i, :qi] = e.h_split[:qi]
-                    h_split[i, max_q:max_q + w] = e.h_split[qi:qi + w]
+            trunk_rows = None
+            if all(e.trunk_row is not None for e in elems):
+                # the trunk cache stays on the device: a batch names its
+                # rows of it and the train step gathers them
+                trunk_rows = np.asarray([e.trunk_row for e in elems], dtype=np.int32)
             group_ids = None
             if all(e.group_id is not None for e in elems):
                 group_ids = np.asarray([e.group_id for e in elems], dtype=np.int32)
@@ -122,7 +109,7 @@ class PPORolloutStorage(BaseRolloutStore):
                 logprobs=logprobs,
                 values=values,
                 rewards=rewards,
-                h_split=h_split,
+                trunk_rows=trunk_rows,
                 group_ids=group_ids,
                 loss_masks=loss_masks,
             )

@@ -689,9 +689,11 @@ class TPUTrainer(BaseRLTrainer):
             one per step; the functional analogue has no reference
             equivalent — torch must step the optimizer from Python)."""
 
+            stacked_batches, rejoin = self._split_shared(stacked_batches)
+
             def body(carry, batch):
                 train_params, opt_state = carry
-                _, stats, grads = grad_fn(train_params, frozen_params, batch)
+                _, stats, grads = grad_fn(train_params, frozen_params, rejoin(batch))
                 updates, opt_state = optimizer.update(grads, opt_state, train_params)
                 train_params = optax.apply_updates(train_params, masked(updates))
                 return (train_params, opt_state), stats
@@ -757,9 +759,11 @@ class TPUTrainer(BaseRLTrainer):
                 return train_params, opt_state, guard_stats
 
             def train_scan(train_params, frozen_params, opt_state, stacked_batches, lr_scale):
+                stacked_batches, rejoin = self._split_shared(stacked_batches)
+
                 def body(carry, batch):
                     train_params, opt_state = carry
-                    _, stats, grads = grad_fn(train_params, frozen_params, batch)
+                    _, stats, grads = grad_fn(train_params, frozen_params, rejoin(batch))
                     train_params, opt_state, guard_stats = guarded_update(
                         grads, opt_state, train_params, lr_scale
                     )
@@ -785,7 +789,18 @@ class TPUTrainer(BaseRLTrainer):
 
     def batch_to_device(self, batch):
         """Place a host batch onto the mesh, batch-dim sharded over DP axes."""
-        return self.runtime.shard_batch(batch)
+        return self._bind_shared(self.runtime.shard_batch(batch))
+
+    def _bind_shared(self, batch):
+        """A placed batch (one step's, or a [n_steps, batch, ...] stack) with
+        what every step of the cycle shares and no collator carries: nothing
+        here; the PPO trainer's cycle-wide trunk cache."""
+        return batch
+
+    def _split_shared(self, stacked_batches):
+        """(what `train_scan` walks step by step, how a step's slice gets
+        back what `_bind_shared` put on the stack)."""
+        return stacked_batches, lambda batch: batch
 
     def _normalize_state_shardings(self):
         """Re-commit train state to the canonical sharding objects. Jitted
@@ -950,7 +965,7 @@ class TPUTrainer(BaseRLTrainer):
         for run in runs:
             if len(run) > 1:
                 stacked = jax.tree_util.tree_map(lambda *xs: np.stack(xs), *run)
-                stacked = self.runtime.shard_batch_stacked(stacked)
+                stacked = self._bind_shared(self.runtime.shard_batch_stacked(stacked))
                 self.train_params, self.opt_state, stats = self._train_scan_fn(
                     self.train_params, self.frozen_params, self.opt_state, stacked,
                     *self._sentinel_args(),

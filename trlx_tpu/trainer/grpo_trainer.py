@@ -321,7 +321,7 @@ class GRPOTrainer(PPOTrainer):
 
     def _chunk_to_elements(self, prompt_tensors, sample_outputs, outputs,
                            scores, scores_mask, logprobs, values, log_ratio,
-                           h_cache=None):
+                           trunk_row0=None):
         """Group-relative advantages instead of per-token rewards + GAE.
         Each group's G rows are adjacent (the expanded batch guarantees
         it); the sequence-level advantage is broadcast over the response

@@ -129,16 +129,6 @@ class PipelinedPPOTrainer(PipelinedCausalMixin, PPOTrainer):
         fast rollout path: params live STACKED over the pipe axis, and
         the suffix resume (`forward(start=split)`) needs the unstacked
         per-block layout — the full-forward train loss stays in charge."""
-        if (
-            getattr(self.config.method, "cache_trunk_activations", False)
-            and not getattr(self, "_warned_no_trunk_cache", False)
-        ):
-            self._warned_no_trunk_cache = True
-            logger.warning(
-                "method.cache_trunk_activations is ignored under pipeline "
-                "parallelism (stacked params cannot run the suffix resume); "
-                "training with the full forward"
-            )
         return False
 
     def _spec_decode_available(self) -> bool:

@@ -9,6 +9,7 @@ forward (ops in trlx_tpu/models/policy.py), and the user reward_fn stays on
 host between the two.
 """
 
+import dataclasses
 import itertools
 import time
 from concurrent.futures import ThreadPoolExecutor
@@ -22,6 +23,7 @@ import numpy as np
 from trlx_tpu.data import PPORLBatch, PPORLElement
 from trlx_tpu.data.configs import TRLConfig
 from trlx_tpu.data.method_configs import MethodConfig, register_method
+from trlx_tpu.observability import tracing
 from trlx_tpu.models import (
     build_model,
     forward_policy_and_ref,
@@ -44,6 +46,40 @@ from trlx_tpu.utils import logging
 from trlx_tpu.utils.modeling import RunningMoments, logprobs_of_labels
 
 logger = logging.get_logger(__name__)
+
+# The share of one device's memory a cycle's trunk cache may take
+# (`PPOTrainer._trunk_cache_available`): it is held through the train phase
+# and while the next chunks are scored, beside the weights, the optimizer
+# and the scorer's activations. 1.6% in the benchmark's PPO cells (268 MB).
+TRUNK_CACHE_HBM_SHARE = 0.125
+
+
+def _to_batch_columns(x, q_from: int, q_to: int, width: Optional[int],
+                      left_queries: bool, fill=0):
+    """Rows [b, q_from + r, ...] (token ids on the host, or cached states
+    [.., d] inside a jit), whose queries are padded to `q_from` columns, in
+    the columns of a batch that pads queries to `q_to` and is `width` wide
+    (None: as wide as it comes out): the padding a wider query block adds
+    sits in front of a left-padded query and between a right-padded query
+    and its response. Added columns hold `fill`: a state of zeros there is,
+    like the pad tokens' states in the others, attention-masked and
+    loss-masked."""
+    if q_to < q_from:
+        raise ValueError(f"a batch pads queries to {q_to}, under the rows' {q_from}")
+    xp = np if isinstance(x, np.ndarray) else jnp
+    cols = lambda before, after: (
+        ((0, 0), (before, after)) + ((0, 0),) * (x.ndim - 2))
+    gap = q_to - q_from
+    if gap and left_queries:
+        x = xp.pad(x, cols(gap, 0), constant_values=fill)
+    elif gap:
+        x = xp.concatenate(
+            [xp.pad(x[:, :q_from], cols(0, gap), constant_values=fill), x[:, q_from:]], axis=1)
+    if width is None:
+        return x
+    if x.shape[1] < width:
+        x = xp.pad(x, cols(0, width - x.shape[1]), constant_values=fill)
+    return x[:, :width]
 
 
 @dataclass
@@ -78,16 +114,6 @@ class PPOConfig(MethodConfig):
     # off: the classic path stays bit-identical (tests/test_pipelined_cycle
     # pinning). Extra field vs the reference config set.
     capture_rollout_stats: bool = False
-    # Frozen-trunk activation cache: the hydra trunk (embeddings + blocks
-    # below the split) is entirely frozen, so its output for a rollout
-    # chunk's tokens is invariant across all ppo_epochs inner epochs.
-    # Capture h_split once per chunk (reusing the rollout fast path's
-    # in-loop capture when available, else one jitted trunk pass) and
-    # train the suffix from it (`forward(start=split)`), skipping the
-    # frozen-prefix forward every optimizer step. Default off: flag off is
-    # bit-identical to the uncached loss. Extra fields vs the reference.
-    cache_trunk_activations: bool = False
-    trunk_cache_dtype: str = "bfloat16"
     # Whiten advantages over real response tokens only (GAE whitening
     # currently normalizes across padded positions too, biasing mean/std
     # for short responses). Default off to preserve reference-parity
@@ -158,8 +184,14 @@ class PPOTrainer(TPUTrainer):
             self.setup_rollout_logging(config)
 
         self._score_fn = None
+        # the cycle's trunk cache (`_trunk_cache_available`): the fill's
+        # program, the chunks of the collection under way, and the one
+        # device array [rollouts, query + response, d] the store's rows index
         self._trunk_cache_fn = None
-        self._cache_cast_fn = None
+        self._trunk_concat_fn = None
+        self._trunk_cache_budget = None
+        self._trunk_chunks = None
+        self._trunk_cache = None
         # Disaggregated rollouts (train.rollout_backend="fleet"): lazy
         # ReplicaRouter over the inference replicas; None under the
         # default "local" backend (bit-identical pre-fleet path). With
@@ -231,7 +263,6 @@ class PPOTrainer(TPUTrainer):
             window_ok=(self._window_loss_ok()
                        and not getattr(self.model_cfg, "sows_moe_aux", False)),
             fast_path=False,  # make_experience scores with the full fwd
-            trunk_cache=self._trunk_cache_available(),
             spec_k=spec_k, spec_accept=accept,
             spec_rank=int(getattr(self.config.method, "spec_draft_rank", 64)),
         )
@@ -326,24 +357,29 @@ class PPOTrainer(TPUTrainer):
 
             moe_aux, moe_stats = 0.0, {}
             x, first = tokens, 0
-            if batch.h_split is not None:
-                # Trunk-cache train path (method.cache_trunk_activations):
-                # resume the trainable suffix from the per-chunk cached
-                # activation entering block `split`. Exact: the trunk is
-                # entirely frozen (split > 0 implies it), padded columns
-                # are attention-masked (exp(-1e9) == 0.0 in f32, so
-                # zero-filled cache rows contribute exactly nothing), and
-                # gradients already stopped at the first trainable layer —
-                # backward is unchanged.
-                h0 = batch.h_split
-                cache_sharding = self._trunk_cache_sharding()
-                if cache_sharding is not None and isinstance(h0, jax.core.Tracer):
+            if batch.trunk_cache is not None:
+                # Trunk-cache train path: resume the trainable suffix from
+                # this batch's rows of the cycle's cache, the state entering
+                # block `split` as the fill's forward left it (its own
+                # dtype: nothing is rounded that was not rounded before).
+                # Exact: the trunk is entirely frozen (split > 0 implies
+                # it), the columns a row does not own hold the state of pad
+                # tokens or zeros and are attention-masked (exp(-1e9) ==
+                # 0.0 in f32, so they contribute exactly nothing) and
+                # loss-masked, and gradients already stopped at the first
+                # trainable layer: backward is unchanged.
+                h0 = _to_batch_columns(
+                    jnp.take(batch.trunk_cache, batch.trunk_rows, axis=0),
+                    batch.trunk_cache.shape[1] - self._trunk_response_width(),
+                    query_tensors.shape[1], tokens.shape[1], self._left_queries(),
+                )
+                if isinstance(h0, jax.core.Tracer):
                     # inside jit this is a pure layout hint; in eager mode it
                     # would be a reshard (device_put) that perturbs backward
                     # reduction order and breaks the bitwise-equality contract
-                    h0 = jax.lax.with_sharding_constraint(h0, cache_sharding)
-                x, first = jax.lax.stop_gradient(h0.astype(self.model_cfg.dtype)), self.split
-            if batch.h_split is None and getattr(self.model_cfg, "sows_moe_aux", False):
+                    h0 = self._place_trunk_cache(h0)
+                x, first = jax.lax.stop_gradient(h0), self.split
+            if getattr(self.model_cfg, "sows_moe_aux", False):
                 from trlx_tpu.utils.modeling import apply_with_moe_aux
 
                 (logits, values_full, _), moe_aux = apply_with_moe_aux(
@@ -359,7 +395,9 @@ class PPOTrainer(TPUTrainer):
                 # largest wasted matmul (tests/test_trainers.py pins
                 # equality with the full-forward loss)
                 window = (start, response_length) if self._window_loss_ok() else None
-                sown = sparse_moe and batch.h_split is None and window is not None
+                # (a step resumed from the trunk cache sows them for the
+                # blocks it runs, [split, n_layers))
+                sown = sparse_moe and window is not None
                 out = model.apply(
                     {"params": params}, x, attention_mask, positions,
                     start=first, window=window, method=type(model).forward,
@@ -741,10 +779,9 @@ class PPOTrainer(TPUTrainer):
         timeline and the `time/*` stat come from the same two clock reads."""
         ppo_rl_elements: List[PPORLElement] = []
         accumulated_stats: List[Dict] = []
-        method = self.config.method
-        pad_id = self.tokenizer.pad_token_id
         gen_kwargs = self.generate_experience_kwargs or self.generate_kwargs
         max_new = int(gen_kwargs.get("max_new_tokens", 40))
+        self._open_trunk_cache()
 
         # Double-buffered generation: the NEXT chunk's sampling is
         # dispatched before the current chunk's device->host sync, so the
@@ -844,6 +881,7 @@ class PPOTrainer(TPUTrainer):
                     stats[f"fleet/{k}"] = float(v)
         self.mean_kl = stats["policy/sqrt_kl"] ** 2
         self.tracker.log(stats, step=iter_count)
+        self._close_trunk_cache()
         self.push_to_store(ppo_rl_elements)
 
     def _process_chunk(self, batch, out, samples, stats, iter_count, chunk,
@@ -870,25 +908,17 @@ class PPOTrainer(TPUTrainer):
                     self.train_params, self.frozen_params, self.ref_params,
                     jnp.asarray(all_tokens),
                 )
-        h_cache = None
-        if self._trunk_cache_available():
-            # one frozen-prefix pass per chunk over the SAME retokenized
-            # tokens the scorer saw; amortized over ppo_epochs inner
-            # epochs of suffix-only training. Dispatched before the
-            # blocking fetch so it overlaps the stats transfer.
-            if self._trunk_cache_fn is None:
-                self._trunk_cache_fn = self._build_trunk_cache_fn()
-            h_cache = self._trunk_cache_fn(
-                self.train_params, self.frozen_params, jnp.asarray(all_tokens)
-            )
         # ONE batched device->host fetch: sequential np.asarray calls
         # each block until their own transfer lands, jax.device_get
         # pipelines them together.
-        logprobs, values, log_ratio, mean_kl, mean_kl_per_token, h_cache = (
-            jax.device_get(
-                (logprobs, values, log_ratio, mean_kl, mean_kl_per_token, h_cache)
-            )
+        logprobs, values, log_ratio, mean_kl, mean_kl_per_token = jax.device_get(
+            (logprobs, values, log_ratio, mean_kl, mean_kl_per_token)
         )
+        trunk_row0 = None
+        if self._trunk_chunks is not None:
+            # the chunk's rows of the cycle's trunk cache: its elements
+            # carry their numbers, and the collection's end fills them
+            trunk_row0 = self._note_trunk_chunk(prompt_tensors, sample_outputs)
         mean_kl = float(mean_kl)
         mean_kl_per_token = float(mean_kl_per_token)
 
@@ -909,7 +939,7 @@ class PPOTrainer(TPUTrainer):
 
         elements = self._chunk_to_elements(
             prompt_tensors, sample_outputs, outputs, scores, scores_mask,
-            logprobs, values, log_ratio, h_cache,
+            logprobs, values, log_ratio, trunk_row0,
         )
         if self._sentinel is not None:
             # rollout quarantine + anomaly observation. Element-level
@@ -1326,13 +1356,15 @@ class PPOTrainer(TPUTrainer):
 
     def _chunk_to_elements(self, prompt_tensors, sample_outputs, outputs,
                            scores, scores_mask, logprobs, values, log_ratio,
-                           h_cache=None):
+                           trunk_row0=None):
         """Slice per-sample response windows into PPORLElements (host
         numpy). logprob[i] is the (log)prob with which all_tokens[i+1] was
         sampled; for seq2seq everything is decoder-relative, so the window
         starts at 0. The in-graph reward construction of the pipelined
         cycle (_build_score_reward_fn) mirrors this block exactly — the
-        parity test ties them together."""
+        parity test ties them together. `trunk_row0` is the row the
+        chunk's first sample holds in the cycle's trunk cache (None: the
+        chunk was not cached)."""
         pad_id = self.tokenizer.pad_token_id
         start = 0 if self.seq2seq else prompt_tensors.shape[1] - 1
         kl_coef = self.kl_ctl.value
@@ -1370,13 +1402,7 @@ class PPOTrainer(TPUTrainer):
                     logprobs=logprobs[ix, start:end],
                     values=values[ix, start:end],
                     rewards=rewards,
-                    # trunk cache rows for exactly this element's
-                    # query + response tokens (the loader's collation
-                    # re-pads them into the batch layout)
-                    h_split=(
-                        None if h_cache is None
-                        else h_cache[ix, : prompt_tensors.shape[1] + n_resp]
-                    ),
+                    trunk_row=None if trunk_row0 is None else trunk_row0 + ix,
                 )
             )
         return elements
@@ -1437,7 +1463,11 @@ class PPOTrainer(TPUTrainer):
         super()._load_extra_resume_state(state)
         if "store_history" in state:
             self.store.clear_history()
-            self.store.push(state["store_history"])
+            # the rows these rollouts held in a trunk cache went with the
+            # process that filled it: they train from the whole forward
+            self._trunk_cache = None
+            self.store.push(
+                [dataclasses.replace(e, trunk_row=None) for e in state["store_history"]])
         if "kl_ctl_value" in state:
             self.kl_ctl.value = state["kl_ctl_value"]
         self.mean_kl = state.get("mean_kl", self.mean_kl)
@@ -1608,7 +1638,9 @@ class PPOTrainer(TPUTrainer):
         idx = np.concatenate(
             [rng.permutation(n) for _ in range(n_epochs)]
         ).reshape(n_epochs * steps, bs)
-        stacked = jax.tree_util.tree_map(lambda a: a[jnp.asarray(idx)], chunk)
+        # (the trunk cache is not per-row: the steps gather their rows of it)
+        per_row, rejoin = self._split_shared(chunk)
+        stacked = rejoin(jax.tree_util.tree_map(lambda a: a[jnp.asarray(idx)], per_row))
         self.train_params, self.opt_state, stats = self._train_scan_fn(
             self.train_params, self.frozen_params, self.opt_state, stacked,
             *self._sentinel_args(),
@@ -1739,57 +1771,108 @@ class PPOTrainer(TPUTrainer):
         return merge_params(self.train_params, quant)
 
     # ------------------------------------------------------------------
-    # Frozen-trunk activation cache (method.cache_trunk_activations)
+    # Frozen-trunk activation cache: the trunk runs once a cycle
     # ------------------------------------------------------------------
 
     def _trunk_cache_available(self) -> bool:
-        """Whether the train phase may run from cached trunk activations.
-        Mirrors _fast_rollout_available's preconditions on the model
-        geometry (but not on the sampler — the cache works on the classic
-        schedule too, via one extra jitted trunk pass per chunk): a real
-        hydra split (split > 0 means blocks [0, split) are entirely
-        frozen, so the cache can never go stale within a collection), a
+        """Whether the cycle trains from cached trunk activations: the
+        state entering block `split` is computed once for each rollout
+        chunk, stays on the device for the cycle, and every optimizer step
+        resumes from it (`forward(start=split)`). The schedule decides from
+        what it can observe; there is no flag. Of the model and layout: a
+        real hydra split (split > 0 means blocks [0, split) are entirely
+        frozen, so the cache never goes stale within a collection), a
         causal LM (seq2seq's encoder/decoder split has no single trunk
-        activation), no MoE (expert routing recomputes the aux loss from
-        the full forward), and a value branch tapping at/above the split
-        (its input must be derivable from h_split). Overridden to False
-        by the pipelined/sequence-parallel trainers, whose param layouts
-        can't run the unstacked suffix resume."""
-        if not getattr(self.config.method, "cache_trunk_activations", False):
-            return False
-        n_value = getattr(self.config.method, "num_value_layers_unfrozen", 0)
-        return (
+        activation), no auxiliary router loss (`MoEMLP` recomputes it from
+        the full forward), a value branch tapping at/above the split (its
+        input must be derivable from the cached state), and this class's
+        own loss (a trainer that builds its own, GRPO's, has no resumed
+        forward; the pipelined/sequence-parallel trainers, whose param
+        layouts cannot run the unstacked suffix resume, say so themselves).
+        Of the recipe: more than one optimizer pass over a chunk's rows
+        (with one epoch the fill costs what it saves). Of the chip: the
+        cycle's cache within its share of one device's memory. Where this
+        says no, every step runs the whole forward."""
+        method = self.config.method
+        n_value = getattr(method, "num_value_layers_unfrozen", 0)
+        if not (
             not self.seq2seq
             and self.split > 0
             and not getattr(self.model_cfg, "sows_moe_aux", False)
             and self.model_cfg.n_layers - n_value >= self.split
-        )
+            and type(self).make_loss_fn is PPOTrainer.make_loss_fn
+            and method.ppo_epochs > 1
+        ):
+            return False
+        if self._trunk_cache_budget is None:
+            from trlx_tpu.observability.hbm import device_hbm_bytes
 
-    def _trunk_cache_sharding(self):
+            self._trunk_cache_budget = int(
+                TRUNK_CACHE_HBM_SHARE * device_hbm_bytes(self.runtime.mesh.devices.flat[0]))
+        # 0: a backend that does not say what it holds (the CPU) bounds nothing
+        return not self._trunk_cache_budget or (
+            self._trunk_cache_device_bytes() <= self._trunk_cache_budget)
+
+    def _trunk_cache_device_bytes(self) -> int:
+        """What one device holds of a cycle's cache at its widest: whole
+        chunks of `num_rollouts` rows of `seq_length` states in the
+        forward's dtype, over the devices `_trunk_cache_sharding` spreads
+        rows and columns on."""
+        from trlx_tpu.observability.hbm import trunk_cache_bytes
+
+        method = self.config.method
+        chunk = max(int(method.chunk_size), 1)
+        shape = (-(-int(method.num_rollouts) // chunk) * chunk,
+                 self.config.train.seq_length, self.model_cfg.d_model)
+        sharding = self._trunk_cache_sharding(shape)
+        if sharding is not None:
+            shape = sharding.shard_shape(shape)
+        return trunk_cache_bytes(*shape, self.model_cfg.dtype)
+
+    def _trunk_cache_sharding(self, shape=None):
         """NamedSharding for a [b, T, d] activation cache: batch over the
         DP axes, sequence over the sequence axis, features replicated — an
         EXPLICIT constraint so param donation in the train step never
         relayouts the cache between epochs. None when the mesh doesn't
         carry the standard axes (the pipe mesh; those trainers gate the
-        cache off anyway)."""
-        axes = self.runtime.mesh.axis_names
-        if "data" not in axes:
+        cache off anyway), or when `shape` does not divide over them (a
+        chunk of fewer rows than data-parallel ways: the compiler places it)."""
+        mesh = self.runtime.mesh
+        if "data" not in mesh.axis_names:
             return None
-        batch_axes = ("data", "fsdp") if "fsdp" in axes else ("data",)
-        seq_axis = "sequence" if "sequence" in axes else None
+        batch_axes = ("data", "fsdp") if "fsdp" in mesh.axis_names else ("data",)
+        seq_axis = "sequence" if "sequence" in mesh.axis_names else None
+        if shape is not None and (
+                shape[0] % int(np.prod([mesh.shape[a] for a in batch_axes]))
+                or (seq_axis and shape[1] % mesh.shape[seq_axis])):
+            return None
         return self.runtime.sharding(batch_axes, seq_axis, None)
 
+    def _place_trunk_cache(self, h):
+        """Inside a jit: `h` [b, T, d] held to `_trunk_cache_sharding`."""
+        sharding = self._trunk_cache_sharding(h.shape)
+        return h if sharding is None else jax.lax.with_sharding_constraint(h, sharding)
+
+    def _trunk_response_width(self) -> int:
+        """Columns of a cached row past its query: what the sampler may add."""
+        gen_kwargs = self.generate_experience_kwargs or self.generate_kwargs
+        return int(gen_kwargs.get("max_new_tokens", 40))
+
+    def _left_queries(self) -> bool:
+        return self.store.padding_side == "left"
+
     def _build_trunk_cache_fn(self):
-        """Jitted frozen-prefix pass: concat(query, response) tokens ->
-        h_split in method.trunk_cache_dtype, placed per
-        _trunk_cache_sharding. One call per rollout chunk — amortized over
-        ppo_epochs inner epochs of suffix-only training."""
+        """Jitted frozen-prefix pass: concat(query, response) tokens -> the
+        state entering block `split` in the dtype the forward hands that
+        block, placed per _trunk_cache_sharding. One call per rollout chunk,
+        amortized over ppo_epochs inner epochs of suffix-only training."""
         model = self.model
         split = self.split
         pad_id = self.tokenizer.pad_token_id
-        dtype = getattr(self.config.method, "trunk_cache_dtype", "bfloat16")
 
-        def trunk(train_params, frozen_params, tokens):
+        # the function's name is the program's in a device trace
+        # (`jit_trunk_cache_fill`: bench/metrics/ppo.trunk_fill_s.json)
+        def trunk_cache_fill(train_params, frozen_params, tokens):
             params = merge_params(train_params, frozen_params)
             attention_mask = (tokens != pad_id).astype(jnp.int32)
             positions = position_ids(attention_mask)
@@ -1797,43 +1880,133 @@ class PPOTrainer(TPUTrainer):
                 {"params": params}, tokens, attention_mask, positions, stop=split,
                 method=type(model).forward,
             )
-            return h.astype(dtype)
+            return self._place_trunk_cache(h)
 
-        return self._ljit(trunk, "trunk_cache_fill", budget=2,
-                          out_shardings=self._trunk_cache_sharding())
+        return self._ljit(trunk_cache_fill, "trunk_cache_fill", budget=2)
 
-    def _build_cache_cast_fn(self):
-        """Jitted cast + placement for an ALREADY-captured h_split (the
-        rollout fast path's in-loop capture) — no forward at all."""
-        dtype = getattr(self.config.method, "trunk_cache_dtype", "bfloat16")
-        return self._ljit(
-            lambda h: h.astype(dtype), "trunk_cache_cast", budget=2,
-            out_shardings=self._trunk_cache_sharding(),
-        )
+    def _open_trunk_cache(self):
+        """A collection starts. Over an empty store the last cycle's cache
+        goes and the rows count from 0; over a store whose rollouts all
+        hold a row of the live cache the collection adds to it; over any
+        other store (restored from a checkpoint, or not cached) it does not
+        cache, because a batch trains from the cache only if all its rows
+        are there."""
+        self._trunk_chunks = None
+        if (self._trunk_cache_available() and self._trunk_cache is not None and len(self.store)
+                and all(e.trunk_row is not None for e in self.store.history)):
+            self._trunk_chunks = [self._trunk_cache]
+            return
+        if self._trunk_cache is not None:
+            # gone before the collection's first program is dispatched: a
+            # dispatch takes its buffers at once and does not wait for
+            # memory, and a recipe sized to the chip leaves the sampler's
+            # programs no room beside the cache (so the steps that read it
+            # have to have ended, and no stray reference keeps it)
+            jax.block_until_ready(self.train_params)
+            self._trunk_cache.delete()
+            self._trunk_cache = None
+        if self._trunk_cache_available() and len(self.store) == 0:
+            self._trunk_chunks = []
 
-    def _attach_trunk_cache(self, chunk: PPORLBatch, captured=None) -> PPORLBatch:
-        """Attach the frozen-trunk activation cache to a device-resident
-        chunk. `captured` is the sampler's in-loop h_split (rollout fast
-        path, satellite of the same schedule) — reused when its width
-        matches the chunk's concat(query, response) layout (a fast-path
-        spec hit guarantees raw == retokenized, so it does); otherwise one
-        jitted trunk pass recomputes it. Called for EVERY chunk when the
-        gate is on, so k>1 concatenation sees a uniform pytree structure."""
-        if not self._trunk_cache_available():
-            return chunk
-        width = chunk.query_tensors.shape[1] + chunk.response_tensors.shape[1]
-        if captured is not None and captured.shape[1] == width:
-            if self._cache_cast_fn is None:
-                self._cache_cast_fn = self._build_cache_cast_fn()
-            return chunk.replace(h_split=self._cache_cast_fn(captured))
+    def _note_trunk_chunk(self, prompt_tensors, sample_outputs) -> int:
+        """One scored chunk's tokens, kept on the host until the collection
+        ends (`_close_trunk_cache` fills them); returns the row its first
+        sample will hold in the cycle's cache. The tokens are laid out as
+        the loader will lay out the batches that train on them (queries
+        padded to the width `create_train_dataloader` buckets them to), so a
+        cached row is the state the whole forward of such a batch computes,
+        column for column, and the step has nothing to move."""
+        q = prompt_tensors.shape[1]
+        tokens = _to_batch_columns(
+            np.concatenate([prompt_tensors, sample_outputs], axis=1), q,
+            self._train_query_width(q), None, self._left_queries(),
+            fill=self.tokenizer.pad_token_id)
+        row0 = sum(len(c) for c in self._trunk_chunks)
+        self._trunk_chunks.append(tokens)
+        return row0
+
+    def _close_trunk_cache(self):
+        """The collection has ended: one frozen-prefix pass for each of its
+        chunks, over the SAME retokenized tokens the scorer saw, amortized
+        over ppo_epochs inner epochs of suffix-only training, and the
+        results as ONE device array [rollouts, query + response, d], which
+        every train step of the cycle takes beside its batch and which never
+        leaves the device. The fills wait for the end because only then do
+        the sampler and the scorer hold nothing: while a generation is in
+        flight its buffers stand, and the benchmark's `pythia-1.4b.ppo-hh`
+        has 17 MB free beside them (my chip runs, PR 38). The device is as
+        busy either way; the train steps queue behind the fills. Chunks of
+        different query widths (a prompt pipeline that pads batch by batch)
+        move to the widest first."""
+        chunks, self._trunk_chunks = self._trunk_chunks, None
+        if not chunks:
+            return
         if self._trunk_cache_fn is None:
             self._trunk_cache_fn = self._build_trunk_cache_fn()
-        tokens = jnp.concatenate(
-            [jnp.asarray(chunk.query_tensors), jnp.asarray(chunk.response_tensors)],
-            axis=1,
-        )
-        h = self._trunk_cache_fn(self.train_params, self.frozen_params, tokens)
-        return chunk.replace(h_split=h)
+        chunks = [
+            c if isinstance(c, jax.Array) else self._trunk_cache_fn(
+                self.train_params, self.frozen_params, jnp.asarray(c))
+            for c in chunks]
+        if len(chunks) > 1:
+            if self._trunk_concat_fn is None:
+                r, left = self._trunk_response_width(), self._left_queries()
+
+                def trunk_cache_concat(hs):
+                    q = max(h.shape[1] for h in hs) - r
+                    return self._place_trunk_cache(jnp.concatenate(
+                        [_to_batch_columns(h, h.shape[1] - r, q, q + r, left) for h in hs]))
+
+                self._trunk_concat_fn = self._ljit(
+                    trunk_cache_concat, "trunk_cache_concat", budget=2)
+            chunks = [self._trunk_concat_fn(chunks)]
+        self._trunk_cache = chunks[0]
+
+    def _bind_shared(self, batch):
+        """The cycle's trunk cache beside the rows a placed batch names (one
+        more argument of the train and accumulation steps, not donated);
+        rows without a live cache (a restored store) are dropped and the
+        step runs the whole forward. While a profiler session listens, the
+        counter span that says which it was."""
+        rows = batch.trunk_rows
+        if rows is not None:
+            batch = (batch.replace(trunk_rows=None) if self._trunk_cache is None
+                     else batch.replace(trunk_cache=self._trunk_cache))
+        if tracing.active():
+            tracing.counters(
+                "ppo.trunk_cache", blocks=self.model_cfg.n_layers,
+                cached_blocks=self.split if batch.trunk_cache is not None else 0,
+                rows=int(np.prod(batch.query_tensors.shape[:-1])))
+        return batch
+
+    def _split_shared(self, stacked_batches):
+        cache = stacked_batches.trunk_cache
+        return (stacked_batches.replace(trunk_cache=None),
+                lambda batch: batch.replace(trunk_cache=cache))
+
+    def _attach_trunk_cache(self, chunk: PPORLBatch, captured=None) -> PPORLBatch:
+        """A device-resident chunk of the fused cycle with its trunk cache,
+        carried as the store's batches carry theirs: `trunk_rows` naming
+        rows of one `trunk_cache` array. `captured` is the sampler's
+        in-loop capture of the same state (rollout fast path), reused when
+        its width matches the chunk's concat(query, response) layout (a
+        fast-path spec hit guarantees raw == retokenized, so it does);
+        otherwise one jitted trunk pass computes it. No-op where the
+        schedule trains from the whole forward."""
+        if not self._trunk_cache_available():
+            return chunk
+        n = chunk.query_tensors.shape[0]
+        width = chunk.query_tensors.shape[1] + chunk.response_tensors.shape[1]
+        if captured is not None and captured.shape[1] == width:
+            h = captured
+        else:
+            if self._trunk_cache_fn is None:
+                self._trunk_cache_fn = self._build_trunk_cache_fn()
+            tokens = jnp.concatenate(
+                [jnp.asarray(chunk.query_tensors), jnp.asarray(chunk.response_tensors)],
+                axis=1,
+            )
+            h = self._trunk_cache_fn(self.train_params, self.frozen_params, tokens)
+        return chunk.replace(trunk_rows=jnp.arange(n, dtype=jnp.int32), trunk_cache=h)
 
     def _build_spec_trim_fn(self, q: int, max_new: int):
         """Tiny jit: device-retokenize the raw responses. Kept SEPARATE
@@ -2224,7 +2397,8 @@ class PPOTrainer(TPUTrainer):
                 )
             # Trunk cache: reuse the sampler's captured h_split on a fast
             # spec hit (raw == retokenized, so the rows align 1:1 with the
-            # chunk); otherwise one jitted trunk pass. No-op when gated off.
+            # chunk); otherwise one jitted trunk pass. No-op where the
+            # schedule trains from the whole forward.
             chunk = self._attach_trunk_cache(
                 chunk, captured=out.get("trunk_cache") if spec_hit else None
             )
@@ -2237,6 +2411,10 @@ class PPOTrainer(TPUTrainer):
             full = jax.tree_util.tree_map(
                 lambda *xs: jnp.concatenate(xs, axis=0), *chunks
             )
+            if full.trunk_rows is not None:
+                # the chunks' caches are one array now, row for row
+                full = full.replace(
+                    trunk_rows=jnp.arange(full.trunk_rows.shape[0], dtype=jnp.int32))
             # cycle KL = mean over chunks (classic make_experience averages
             # its per-chunk stats the same way)
             mean_kl = jnp.mean(jnp.stack(kl_handles))
@@ -2279,17 +2457,23 @@ class PPOTrainer(TPUTrainer):
         # Responses/stats use the experience budget (tight already).
         exp_kwargs = self.generate_experience_kwargs or self.generate_kwargs
         exp_max_new = int(exp_kwargs.get("max_new_tokens", 40))
-        eval_max_new = int(self.generate_kwargs.get("max_new_tokens", 40))
-        budget_q = self.config.train.seq_length - eval_max_new
         obs_q = max((len(e.query_tensor) for e in self.store.history), default=0)
-        bucket_q = min(budget_q, -(-obs_q // 64) * 64)
         return self.store.create_loader(
             self.config.train.batch_size, shuffle=True, drop_last=drop_last,
             seed=self.config.train.seed + self.iter_count + seed_offset,
-            max_query_len=bucket_q,
+            max_query_len=self._train_query_width(obs_q),
             max_response_len=exp_max_new + (1 if self.seq2seq else 0),
             max_stat_len=exp_max_new,
         )
+
+    def _train_query_width(self, obs_q: int) -> int:
+        """The width a train batch pads queries to, given the widest query
+        of its store: the next 64-token bucket, capped by the config budget
+        and never under the query itself (the collator raises a hint to
+        what it observes). The trunk cache is filled in this layout."""
+        eval_max_new = int(self.generate_kwargs.get("max_new_tokens", 40))
+        budget_q = self.config.train.seq_length - eval_max_new
+        return max(obs_q, min(budget_q, -(-obs_q // 64) * 64))
 
     def prepare_learning(self):
         self.eval_dataloader = self.eval_pipeline.create_loader(self.config.method.chunk_size)

@@ -108,16 +108,6 @@ class SequenceParallelPPOTrainer(PPOTrainer):
         """The trunk cache is unavailable here: the train loss runs inside
         a shard_map over the sequence axis, and the cached-split resume
         lives outside that layout — the full-forward loss stays in charge."""
-        if (
-            getattr(self.config.method, "cache_trunk_activations", False)
-            and not getattr(self, "_warned_no_trunk_cache", False)
-        ):
-            self._warned_no_trunk_cache = True
-            logger.warning(
-                "method.cache_trunk_activations is ignored under sequence "
-                "parallelism (sharded loss cannot consume the cached split "
-                "activations); training with the full forward"
-            )
         return False
 
     def _spec_decode_available(self) -> bool:

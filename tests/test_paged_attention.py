@@ -449,9 +449,11 @@ def test_live_entry_share_counts_the_columns_held(trainers, monkeypatch):
     """`kv_live_entry_share` is the host's own count of table entries the
     next decode step to be dispatched attends to, over slots x table
     entries: the columns fetched, the one the step in flight writes, and its
-    own. The `trlx:engine.dispatch` span carries the count, and `ahead=1`
-    where a step was in flight, while a tracing session is active, and
-    nothing otherwise."""
+    own. The `trlx:engine.dispatch` span carries nothing: while a tracing
+    session is active the step being queued says who it is in the counter
+    span `trlx:engine.queued` in front of it (its `seq`, `ahead=1` where a
+    step was in flight, the rows with a request), and the table walk in
+    `trlx:engine.kv_walk`; off a session neither is formatted."""
     from trlx_tpu.observability import tracing
 
     eng = make_engine(trainers["llama-tiny"], "interpret", max_new=8)
@@ -460,15 +462,18 @@ def test_live_entry_share_counts_the_columns_held(trainers, monkeypatch):
     eng.insert_requests([(np.arange(60, 67, dtype=np.int32), 8)], [1])  # 7 columns
     share = lambda cols: -(-cols // bs) / (2 * n_tbl)  # noqa: E731
     assert eng.kv_stats()["kv_live_entry_share"] == share(7 + 1)  # the step writes one
-    seen = []
+    seen, counted = [], []
     real_span = tracing.span
     monkeypatch.setattr(tracing, "span", lambda name, **a: (seen.append((name, a)), real_span(name, **a))[1])
+    monkeypatch.setattr(tracing, "counters", lambda name, **values: counted.append((name, values)))
     eng.step()  # its own step writes column 8, the one it leaves in flight column 9
-    assert seen.count(("engine.dispatch", {})) == 2
+    assert seen.count(("engine.dispatch", {})) == 2 and counted == []
     assert eng.kv_stats()["kv_live_entry_share"] == share(8 + 1 + 1) == 2 / (2 * n_tbl)
     monkeypatch.setattr(tracing, "active", lambda: True)
     eng.step()
-    assert ("engine.dispatch", {"live_entries": 2, "ahead": 1}) in seen
+    assert seen.count(("engine.dispatch", {})) == 3
+    assert [name for name, _ in counted] == ["engine.kv_walk", "engine.queued", "engine.fetched"]
+    assert counted[1][1] == {"seq": 3, "ahead": 1, "rows": 1} and counted[2][1] == {"seq": 2}
     stats = eng.kv_stats()
     assert (stats["decode_steps_total"], stats["decode_steps_ahead_total"],
             stats["decode_outputs_masked_total"]) == (2, 2, 0)

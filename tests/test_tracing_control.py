@@ -12,8 +12,11 @@ sinks once.
 """
 
 import glob
+import json
 import os
+import sys
 import threading
+import types
 
 import jax
 import numpy as np
@@ -165,7 +168,7 @@ def test_scheduler_and_engine_spans(paged_engine, tmp_path):
     try:
         # compile the prefill and decode programs outside the session
         assert scheduler.submit(np.arange(1, 6, dtype=np.int32)).wait(120)
-        step0 = paged_engine._step_n
+        dispatch0 = paged_engine._dispatches
         tracing.start(str(tmp_path))
         reqs = [scheduler.submit(np.arange(1, 4 + i, dtype=np.int32)) for i in range(3)]
         assert all(r.wait(120) for r in reqs)
@@ -185,21 +188,285 @@ def test_scheduler_and_engine_spans(paged_engine, tmp_path):
     # a call fetches one step and dispatches the next ahead of that fetch;
     # a call with nothing in flight dispatches its own step first
     dispatches = named(spans, "engine.dispatch")
-    assert len(steps) == len(named(spans, "engine.fetch")) == sum(1 for d in dispatches if d[3].get("ahead"))
+    queued = counter_spans(spans, "engine.queued")
+    assert len(steps) == len(named(spans, "engine.fetch")) == sum(q["ahead"] for q in queued)
     assert len(steps) < len(dispatches) <= 2 * len(steps)
-    # the engine's step counter joins a span to a step: consecutive, from
-    # where the engine stood when the session began
-    ns = [s[3]["step_n"] for s in steps]
-    assert ns == list(range(ns[0], ns[0] + len(ns))) and ns[0] > step0
+    # a step says who it is in a counter span's name, not in attributes: the
+    # engine's count of decode dispatches joins a dispatch to its fetch,
+    # consecutive, from where the engine stood when the session began
+    assert not any(s[3] for s in steps + dispatches + named(spans, "engine.fetch"))
+    seqs = [q["seq"] for q in queued]
+    assert len(seqs) == len(dispatches)
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs))) and seqs[0] > dispatch0
+    assert seqs[-1] == paged_engine._dispatches
+    fetched = [f["seq"] for f in counter_spans(spans, "engine.fetched")]
+    assert len(fetched) == len(steps) and set(fetched) <= set(seqs)
+    # an admission's numbers live once, in the counter span in front of its
+    # first program's dispatch; the spans round it carry none
     inserts = named(spans, "sched.insert_batch")
-    assert sum(s[3]["rows"] for s in inserts) == len(reqs)
-    assert all(s[3]["width"] >= 3 for s in inserts)
-    assert all(s[3]["rows"] >= 1 and s[3]["width"] % paged_engine.prompt_bucket == 0
-               for s in named(spans, "engine.insert"))
+    assert not any(s[3] for s in inserts + named(spans, "engine.insert"))
+    counts = counter_spans(spans, "sched.insert")
+    assert len(counts) == len(inserts) and sum(c["rows"] for c in counts) == len(reqs)
+    assert all(c["padded_tokens"] % paged_engine.prompt_bucket == 0 for c in counts)
+    for (_, start, _, _), (_, lo, hi, _) in zip(spans_named(spans, "sched.insert"), inserts):
+        assert lo <= start <= hi
     assert all(1 <= s[3]["rows"] <= 2 for s in named(spans, "sched.decode_once"))
-    # the counter beside the spans: calls of `_insert_batch` (their rows are
-    # the spans' `rows`)
     assert scheduler.metrics.get("prefill_batches_total") >= len(inserts)
+
+
+def spans_named(spans, name):
+    """The counter spans `trlx:<name> k=v ..`, whole, in start order."""
+    return sorted((s for s in spans if s[0].startswith(tracing.SPAN_PREFIX + name + " ")),
+                  key=lambda s: s[1])
+
+
+def counter_spans(spans, name):
+    """[{key: number}] of the counter spans `trlx:<name> k=v ..`, in start order."""
+    return [{k: float(v) if "." in v else int(v)
+             for k, v in (kv.split("=", 1) for kv in s[0].split()[1:])}
+            for s in spans_named(spans, name)]
+
+
+def engine_spans(log_dir):
+    """The thread's spans that hold the engine's, in start order (a span
+    that contains another starts first or, on a tie, ends last)."""
+    spans = next(v for v in read_spans(log_dir).values() if named(v, "engine.dispatch"))
+    return sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+def traced(tmp_path, fn):
+    tracing.start(str(tmp_path))
+    try:
+        fn()
+    finally:
+        tracing.stop()
+    return engine_spans(str(tmp_path))
+
+
+def idle(engine):
+    """Every slot freed: whatever is in flight speaks for nobody."""
+    engine.release_slots(list(range(engine.num_slots)))
+
+
+def prompt_of(n, seed=0):
+    return np.random.RandomState(seed).randint(1, 200, size=n).astype(np.int32)
+
+
+def first_call(engine):
+    engine.insert_requests([(prompt_of(5), MAX_NEW)], [0])
+    engine.step()
+
+
+def steady_run(engine):
+    engine.insert_requests([(prompt_of(5), MAX_NEW), (prompt_of(9, 1), MAX_NEW)], [0, 1])
+    for _ in range(MAX_NEW):
+        engine.step()
+
+
+def release_reclaim_refill(engine):
+    """Each of the three between a step's dispatch and the fetch of it."""
+    engine.insert_requests([(prompt_of(5), MAX_NEW), (prompt_of(9, 1), MAX_NEW)], [0, 1])
+    engine.step()
+    engine.release_slots([1])
+    engine.step()
+    engine.insert_requests([(prompt_of(7, 2), MAX_NEW)], [1])
+    engine.step()
+    engine.reclaim_slots([0])
+    engine.step()
+
+
+def all_rows_disowned(engine):
+    """The step in flight loses its last row: the next call drops it and
+    dispatches its own step first, so one `seq` is queued and never fetched."""
+    engine.insert_requests([(prompt_of(5), MAX_NEW)], [0])
+    engine.step()
+    engine.release_slots([0])
+    engine.insert_requests([(prompt_of(9, 1), MAX_NEW)], [1])
+    engine.step()
+    engine.step()
+
+
+# scenario -> (calls of `step`, seqs queued and never fetched before the last call's own)
+PIPELINES = {"first_call": (first_call, 1, 0), "steady_run": (steady_run, MAX_NEW, 0),
+             "release_reclaim_refill": (release_reclaim_refill, 4, 0),
+             "all_rows_disowned": (all_rows_disowned, 3, 1)}
+
+
+@pytest.mark.parametrize("scenario", list(PIPELINES))
+def test_every_fetched_seq_was_queued_once_and_earlier(paged_engine, tmp_path, scenario):
+    run, calls, dropped = PIPELINES[scenario]
+    idle(paged_engine)
+    spans = traced(tmp_path, lambda: run(paged_engine))
+    idle(paged_engine)
+    mine = [s for s in spans if s[0].split()[0] in (
+        "trlx:engine.queued", "trlx:engine.dispatch", "trlx:engine.fetch", "trlx:engine.fetched")]
+    kinds = [s[0].split()[0].rpartition(".")[2] for s in mine]
+    # the counter span stands directly in front of its dispatch, and
+    # directly behind the fetch that waited: nothing of the four between
+    for i, kind in enumerate(kinds):
+        if kind == "queued":
+            assert kinds[i + 1] == "dispatch" and mine[i][2] <= mine[i + 1][1]
+        if kind == "fetched":
+            assert kinds[i - 1] == "fetch" and mine[i - 1][2] <= mine[i][1]
+    assert kinds.count("queued") == kinds.count("dispatch")
+    assert kinds.count("fetched") == kinds.count("fetch") == calls
+    queued = {q["seq"]: s for q, s in zip(counter_spans(spans, "engine.queued"),
+                                          spans_named(spans, "engine.queued"))}
+    seqs = sorted(queued)
+    assert seqs == list(range(seqs[0], seqs[0] + len(seqs)))  # each dispatch once, in order
+    assert seqs[-1] == paged_engine._dispatches
+    fetched = [f["seq"] for f in counter_spans(spans, "engine.fetched")]
+    assert fetched == sorted(set(fetched)) and set(fetched) <= set(seqs)  # once each, in order
+    for seq, span in zip(fetched, spans_named(spans, "engine.fetched")):
+        assert queued[seq][2] <= span[1]  # queued before it was fetched
+    # what was queued and not fetched: the step still in flight at the end,
+    # and a step whose every row was disowned
+    assert len(seqs) - len(fetched) == 1 + dropped
+    aheads = [q["ahead"] for q in counter_spans(spans, "engine.queued")]
+    if scenario == "first_call":
+        assert kinds == ["queued", "dispatch", "queued", "dispatch", "fetch", "fetched"]
+        assert aheads == [0, 1] and fetched == [seqs[0]]
+    elif scenario == "steady_run":
+        assert kinds[6:] == ["queued", "dispatch", "fetch", "fetched"] * (MAX_NEW - 1)
+        assert fetched == seqs[:-1] and aheads == [0] + [1] * MAX_NEW
+        assert [q["rows"] for q in counter_spans(spans, "engine.queued")][:2] == [2, 2]
+    elif scenario == "release_reclaim_refill":
+        assert fetched == seqs[:-1]
+        # rows with a request when each step was queued: the release, the
+        # refill and the reclaim show one dispatch later
+        assert [q["rows"] for q in counter_spans(spans, "engine.queued")] == [2, 2, 1, 2, 1]
+    else:
+        assert fetched == [seqs[0], seqs[2], seqs[3]]
+        assert aheads == [0, 1, 0, 1, 1]
+
+
+def test_insert_counter_spans_add_up_to_the_counters(paged_engine, tmp_path):
+    idle(paged_engine)
+    scheduler = Scheduler(paged_engine, max_wait_s=0.0)
+    names = ("prefill_batches_total", "prefill_rows_total", "prefill_tokens_total",
+             "prefill_padded_tokens_total")
+    scheduler.start()
+    try:
+        assert scheduler.submit(prompt_of(5)).wait(120)
+        before = [scheduler.metrics.get(n) for n in names]
+        assert before[:3] == [1, 1, 5] and before[3] == paged_engine.prompt_bucket
+        tracing.start(str(tmp_path))
+        reqs = [scheduler.submit(prompt_of(3 + 2 * i, i)) for i in range(5)]
+        assert all(r.wait(120) for r in reqs)
+        tracing.stop()
+    finally:
+        scheduler.stop()
+    delta = [scheduler.metrics.get(n) - b for n, b in zip(names, before)]
+    counts = counter_spans(engine_spans(str(tmp_path)), "sched.insert")
+    assert [sum(c[k] for c in counts) for k in ("calls", "rows", "prompt_tokens", "padded_tokens")] == delta
+    assert delta[1] == len(reqs) and delta[2] == sum(len(r.prompt_ids) for r in reqs)
+    assert all(c["calls"] == 1 and c["pad_tokens"] == c["padded_tokens"] - c["prompt_tokens"] for c in counts)
+
+
+@pytest.mark.parametrize("kv_paging", [True, False], ids=["paged", "dense"])
+def test_padding_of_a_batch_over_two_buckets(trainer, tmp_path, kv_paging):
+    """Lengths 3, 5, 7 share the bucket of 8 (three rows are dispatched as
+    four) and 12 takes the bucket of 16 alone: 4 x 8 + 1 x 16 positions for
+    27 prompt tokens."""
+    from trlx_tpu.models import CausalLMPolicy
+
+    gen_cfg = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False, eos_token_id=10_000,
+                               pad_token_id=trainer.tokenizer.pad_token_id)
+    engine = InferenceEngine(
+        CausalLMPolicy(trainer.model_cfg), trainer.model_cfg, {"lm": trainer.params["lm"]}, gen_cfg,
+        num_slots=4, max_prompt_len=16, prompt_bucket=8, kv_paging=kv_paging, kv_block_size=8)
+    rows = [(prompt_of(n, n), MAX_NEW) for n in (3, 12, 5, 7)]
+    tracing.start(str(tmp_path))
+    try:
+        counts = engine.insert_requests(rows, [0, 1, 2, 3])
+    finally:
+        tracing.stop()
+    assert counts == (4, 27, 48)
+    spans = [s for v in read_spans(str(tmp_path)).values() for s in v]
+    assert counter_spans(spans, "sched.insert") == [
+        {"calls": 1, "rows": 4, "prompt_tokens": 27, "padded_tokens": 48, "pad_tokens": 21}]
+    # in front of the first of the admission's two programs
+    programs = sorted(named(spans, "engine.insert"), key=lambda s: s[1])
+    assert len(programs) == 2 and spans_named(spans, "sched.insert")[0][2] <= programs[0][1]
+
+
+def test_a_shared_prefix_is_not_counted_as_prefilled(trainer):
+    """`prompt_tokens` is what the prefill programs compute: a prompt that
+    finds its first two blocks in the prefix store prefills the rest, and
+    `pad_tokens` stays the padding of what was dispatched."""
+    from trlx_tpu.models import CausalLMPolicy
+
+    gen_cfg = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False, eos_token_id=10_000,
+                               pad_token_id=trainer.tokenizer.pad_token_id)
+    engine = InferenceEngine(
+        CausalLMPolicy(trainer.model_cfg), trainer.model_cfg, {"lm": trainer.params["lm"]}, gen_cfg,
+        num_slots=2, max_prompt_len=24, prompt_bucket=8, kv_paging=True, kv_block_size=8,
+        prefix_cache=True)
+    prompt = prompt_of(20)
+    assert engine.insert_requests([(prompt, MAX_NEW)], [0]) == (1, 20, 24)
+    # blocks [0, 16) are resident under their keys: 4 tokens left, one bucket of 8
+    assert engine.insert_requests([(prompt, MAX_NEW)], [1]) == (1, 4, 8)
+
+
+def serve(engine, seeds):
+    scheduler = Scheduler(engine, max_wait_s=0.0)
+    scheduler.start()
+    try:
+        reqs = [scheduler.submit(prompt_of(4 + i, i)) for i in seeds]
+        assert all(r.wait(120) for r in reqs)
+    finally:
+        scheduler.stop()
+    return [(r.token_ids, np.asarray(r.token_logprobs)) for r in reqs]
+
+
+def test_no_session_formats_no_counter_span(paged_engine, monkeypatch):
+    """Off a session the engine and the scheduler never reach
+    `tracing.counters`, which formats its name whoever listens."""
+    def raises(name, **values):
+        raise AssertionError(f"tracing.counters({name!r}) called with no session active")
+
+    idle(paged_engine)
+    monkeypatch.setattr(tracing, "counters", raises)
+    assert not tracing.active()
+    out = serve(paged_engine, range(4))
+    assert all(len(tokens) == MAX_NEW for tokens, _ in out)
+
+
+def test_a_session_changes_no_token_and_no_logprob(paged_engine, tmp_path):
+    idle(paged_engine)
+    plain = serve(paged_engine, range(4))
+    idle(paged_engine)
+    tracing.start(str(tmp_path))
+    try:
+        under_session = serve(paged_engine, range(4))
+    finally:
+        tracing.stop()
+    for (tokens, logprobs), (tokens_s, logprobs_s) in zip(plain, under_session):
+        assert tokens == tokens_s
+        np.testing.assert_array_equal(logprobs, logprobs_s)
+    assert counter_spans(engine_spans(str(tmp_path)), "engine.queued")  # and the session listened
+
+
+def test_step_pipeline_reader_on_its_fixture():
+    """The benchmark's reader of the `seq=` counter spans, loaded as
+    `bench/run.py` loads it, on the trace built by hand beside it (the
+    cases are bench/tests/test_step_pipeline.py's; this one keeps the reader
+    under `pytest tests/`)."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "bench")
+    sys.path[:0] = [p for p in (bench,) if p not in sys.path]
+    from benchlib.files import load_module
+
+    reader = load_module("metrics/readers/step_pipeline.py")
+    with open(os.path.join(bench, "trace", "fixture_step_pipeline.json")) as f:
+        trace = json.load(f)
+    lines = []
+    ctx = types.SimpleNamespace(log=lines.append)
+    got = {stat: reader.read({"trace": trace}, {"stat": stat}, ctx)
+           for stat in ("slack_ms", "host_loop_ms", "fetch_late_ms")}
+    assert got == pytest.approx({"slack_ms": 7.6, "host_loop_ms": 3.15, "fetch_late_ms": 0.35})
+    assert any("identity over 3 steps" in ln and "= 12.6667 ms" in ln for ln in lines)
+    # the names the engine writes are the names the reader looks for
+    assert (reader.QUEUED, reader.FETCHED) == ("trlx:engine.queued ", "trlx:engine.fetched ")
 
 
 @pytest.mark.parametrize("jax_as_installed", [True, False], ids=["own_stop", "another_jax"])

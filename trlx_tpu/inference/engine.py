@@ -124,6 +124,7 @@ class _InFlight(NamedTuple):
 
     out: tuple  # device arrays: token, logprob, emitted, finished[, moe stats]
     rows: np.ndarray  # [num_slots] bool
+    seq: int  # the engine's count of decode dispatches when this one was queued
 
 
 class InferenceEngine:
@@ -179,6 +180,7 @@ class InferenceEngine:
         # release / reclaim clear it)
         self._ahead: Optional[_InFlight] = None
         self._live = np.zeros((int(num_slots),), bool)
+        self._dispatches = 0  # decode programs queued: a step's `seq`, dispatch to fetch
         self._steps_ahead = 0
         self._outputs_masked = 0
         if getattr(model_cfg, "is_seq2seq", False):
@@ -724,7 +726,7 @@ class InferenceEngine:
         rows: Sequence[Tuple],  # (unpadded prompt ids, max_new[, adapter_id])
         slot_ids: Sequence[int],
         sessions: Optional[Sequence] = None,  # per-row Session or None
-    ) -> None:
+    ) -> Tuple[int, int, int]:
         """Prefill `rows` (length-bucketed, left-padded) and scatter them
         into the given free slots. Requests are grouped by prompt-width
         bucket; each group prefills as one jitted call. Paged mode routes
@@ -735,7 +737,7 @@ class InferenceEngine:
         and the prefill program applies per-row factors. `sessions`
         (paged only) attaches a row to a chat session: its retained
         blocks seed the shared prefix, so only the conversation's delta
-        tokens prefill."""
+        tokens prefill. Returns what was prefilled (`_count_admission`)."""
         assert len(rows) == len(slot_ids)
         if sessions is not None and any(s is not None for s in sessions):
             if not self.kv_paging:
@@ -748,9 +750,8 @@ class InferenceEngine:
             aslots = self._acquire_adapters(norm, slot_ids)
         try:
             if self.kv_paging:
-                self._insert_paged(norm, slot_ids, aslots, sessions)
-            else:
-                self._insert_dense(norm, slot_ids, aslots)
+                return self._insert_paged(norm, slot_ids, aslots, sessions)
+            return self._insert_dense(norm, slot_ids, aslots)
         except Exception:
             if self.multi_tenant:
                 self._release_adapters(slot_ids)
@@ -788,65 +789,89 @@ class InferenceEngine:
             if int(slot) in self._slot_adapter:
                 self.adapter_store.release(self._slot_adapter.pop(int(slot)))
 
-    def _insert_dense(self, norm, slot_ids, aslots: Optional[List[int]]) -> None:
+    def _insert_dense(self, norm, slot_ids, aslots: Optional[List[int]]) -> Tuple[int, int, int]:
         pad_id = self.gen_cfg.pad_token_id
         mt = self.multi_tenant
-        groups: Dict[int, List[Tuple[np.ndarray, int, int, int]]] = {}
-        for i, ((ids, max_new, _name), slot) in enumerate(zip(norm, slot_ids)):
-            ids = self._check_row(ids, max_new)
-            plen = _round_up(ids.size, self.prompt_bucket)
-            groups.setdefault(plen, []).append(
-                (ids, int(max_new), int(slot), aslots[i] if mt else 0)
-            )
-
+        programs = self._prefill_programs([
+            (self._check_row(ids, max_new), int(max_new), int(slot), aslots[i] if mt else 0)
+            for i, ((ids, max_new, _name), slot) in enumerate(zip(norm, slot_ids))
+        ])
         params = self._current_params()
         stack = self.adapter_store.stacked() if mt else None
-        for plen, members in groups.items():
-            for i in range(0, len(members), self.max_prefill_batch):
-                chunk = members[i : i + self.max_prefill_batch]
-                pb = _pow2_bucket(len(chunk), self.max_prefill_batch)
-                ids_arr = np.full((pb, plen), pad_id, np.int32)
-                mask_arr = np.zeros((pb, plen), np.int32)
-                # padding rows repeat row 0 (a real prompt; fully-masked
-                # rows are avoided) and scatter out of bounds
-                slots_arr = np.full((pb,), self.num_slots, np.int32)
-                max_new_arr = np.full((pb,), self.gen_cfg.max_new_tokens, np.int32)
-                aidx_arr = np.zeros((pb,), np.int32)  # padding rows gather base
-                for j, (ids, max_new, slot, aslot) in enumerate(chunk):
-                    ids_arr[j, plen - ids.size :] = ids  # left-padded (decode convention)
-                    mask_arr[j, plen - ids.size :] = 1
-                    slots_arr[j] = slot
-                    max_new_arr[j] = max_new
-                    aidx_arr[j] = aslot
-                ids_arr[len(chunk) :] = ids_arr[0]
-                mask_arr[len(chunk) :] = mask_arr[0]
+        counts = self._count_admission(programs)
+        for plen, chunk, pb in programs:
+            ids_arr = np.full((pb, plen), pad_id, np.int32)
+            mask_arr = np.zeros((pb, plen), np.int32)
+            # padding rows repeat row 0 (a real prompt; fully-masked
+            # rows are avoided) and scatter out of bounds
+            slots_arr = np.full((pb,), self.num_slots, np.int32)
+            max_new_arr = np.full((pb,), self.gen_cfg.max_new_tokens, np.int32)
+            aidx_arr = np.zeros((pb,), np.int32)  # padding rows gather base
+            for j, (ids, max_new, slot, aslot) in enumerate(chunk):
+                ids_arr[j, plen - ids.size :] = ids  # left-padded (decode convention)
+                mask_arr[j, plen - ids.size :] = 1
+                slots_arr[j] = slot
+                max_new_arr[j] = max_new
+                aidx_arr[j] = aslot
+            ids_arr[len(chunk) :] = ids_arr[0]
+            mask_arr[len(chunk) :] = mask_arr[0]
 
-                with self._insert_span(len(chunk), plen):
-                    if mt:
-                        aidx = jnp.asarray(aidx_arr)
-                        last_logits, cache = self._get_prefill(pb, plen)(
-                            params, jnp.asarray(ids_arr), jnp.asarray(mask_arr),
-                            stack, aidx,
-                        )
-                        self._pool = self._get_insert(pb)(
-                            self._pool, cache, last_logits,
-                            jnp.asarray(slots_arr), jnp.asarray(max_new_arr), aidx,
-                        )
-                    else:
-                        last_logits, cache = self._get_prefill(pb, plen)(
-                            params, jnp.asarray(ids_arr), jnp.asarray(mask_arr)
-                        )
-                        self._pool = self._get_insert(pb)(
-                            self._pool, cache, last_logits,
-                            jnp.asarray(slots_arr), jnp.asarray(max_new_arr),
-                        )
+            with self._insert_span(len(chunk), plen):
+                if mt:
+                    aidx = jnp.asarray(aidx_arr)
+                    last_logits, cache = self._get_prefill(pb, plen)(
+                        params, jnp.asarray(ids_arr), jnp.asarray(mask_arr),
+                        stack, aidx,
+                    )
+                    self._pool = self._get_insert(pb)(
+                        self._pool, cache, last_logits,
+                        jnp.asarray(slots_arr), jnp.asarray(max_new_arr), aidx,
+                    )
+                else:
+                    last_logits, cache = self._get_prefill(pb, plen)(
+                        params, jnp.asarray(ids_arr), jnp.asarray(mask_arr)
+                    )
+                    self._pool = self._get_insert(pb)(
+                        self._pool, cache, last_logits,
+                        jnp.asarray(slots_arr), jnp.asarray(max_new_arr),
+                    )
+        return counts
+
+    def _prefill_programs(self, members: Sequence[Tuple]) -> List[Tuple[int, List[Tuple], int]]:
+        """The prefill programs that dispatch `members` (rows whose first
+        element is the tokens to prefill), as (width bucket, rows, row-count
+        bucket): the rows grouped by the bucket their width is padded to, a
+        group in chunks of `max_prefill_batch`."""
+        groups: Dict[int, List[Tuple]] = {}
+        for member in members:
+            groups.setdefault(_round_up(len(member[0]), self.prompt_bucket), []).append(member)
+        step = self.max_prefill_batch
+        return [(plen, rows[i : i + step], _pow2_bucket(len(rows[i : i + step]), step))
+                for plen, rows in groups.items() for i in range(0, len(rows), step)]
+
+    def _count_admission(self, programs: Sequence[Tuple[int, List[Tuple], int]]) -> Tuple[int, int, int]:
+        """What one call of `insert_requests` is about to prefill, from the
+        programs it will dispatch: the rows, the prompt tokens they compute
+        (a prompt's, less what it shares from the prefix store or its
+        session) and the positions the programs are dispatched at (rows
+        padded to their bucket x the width bucket, summed over the
+        programs). The scheduler adds them to its counters; while a tracing
+        session listens they stand on the trace too, one counter span an
+        admission, in front of its first program's dispatch."""
+        rows = sum(len(chunk) for _, chunk, _ in programs)
+        tokens = sum(len(member[0]) for _, chunk, _ in programs for member in chunk)
+        padded = sum(pb * plen for plen, _, pb in programs)
+        if tracing.active():
+            tracing.counters("sched.insert", calls=1, rows=rows, prompt_tokens=tokens,
+                             padded_tokens=padded, pad_tokens=padded - tokens)
+        return rows, tokens, padded
 
     @contextlib.contextmanager
     def _insert_span(self, rows: int, width: int):
         """Round the dispatch of one prefill program: the `trlx:engine.insert`
         span, and from the same clock reads the `prefill_bucket` entry of a
         traced request's buffer."""
-        with tracing.timed_span("engine.insert", rows=rows, width=width) as sp:
+        with tracing.timed_span("engine.insert") as sp:
             yield
         if self.trace_buf is not None:
             self.trace_buf.append((
@@ -881,7 +906,7 @@ class InferenceEngine:
     def _insert_paged(
         self, rows, slot_ids, aslots: Optional[List[int]] = None,
         sessions: Optional[Sequence] = None,
-    ) -> None:
+    ) -> Tuple[int, int, int]:
         """Paged insert: allocate each request's blocks up front
         (prompt + max_new + spec_k — no mid-decode OOM, no preemption),
         probing the prefix store for resident leading blocks first. In
@@ -987,53 +1012,49 @@ class InferenceEngine:
         # dispatch order between rounds is what makes same-call sharing
         # sound: a round-2 suffix prefill gathers blocks the round-1
         # program has already written by the time it runs
-        for placed in rounds:
-            self._flush_paged(placed, params)
+        programs = [p for placed in rounds for p in self._prefill_programs(placed)]
+        counts = self._count_admission(programs)
+        self._flush_paged(programs, params)
+        return counts
 
-    def _flush_paged(self, placed, params) -> None:
-        """Dispatch one placement round's prefills, grouped by suffix
-        width bucket and chunked to `max_prefill_batch`."""
+    def _flush_paged(self, programs, params) -> None:
+        """Dispatch the placement rounds' prefills, round by round, each
+        round grouped by suffix width bucket and chunked to
+        `max_prefill_batch` (`_prefill_programs`)."""
         pad_id = self.gen_cfg.pad_token_id
         mt = self.multi_tenant
         stack = self.adapter_store.stacked() if mt else None
-        groups: Dict[int, List] = {}
-        for item in placed:
-            plen = _round_up(len(item[0]), self.prompt_bucket)
-            groups.setdefault(plen, []).append(item)
-        for plen, members in groups.items():
-            for i in range(0, len(members), self.max_prefill_batch):
-                chunk = members[i : i + self.max_prefill_batch]
-                pb = _pow2_bucket(len(chunk), self.max_prefill_batch)
-                ids_arr = np.full((pb, plen), pad_id, np.int32)
-                tmask = np.zeros((pb, plen), np.int32)
-                tables = np.full((pb, self._n_tbl), self._n_blocks, np.int32)
-                slots_arr = np.full((pb,), self.num_slots, np.int32)
-                max_new_arr = np.full((pb,), self.gen_cfg.max_new_tokens, np.int32)
-                shared_arr = np.zeros((pb,), np.int32)
-                aidx_arr = np.zeros((pb,), np.int32)  # padding rows gather base
-                for j, (suffix, T, blocks, max_new, slot, aslot) in enumerate(chunk):
-                    ids_arr[j, : len(suffix)] = suffix  # RIGHT-padded
-                    tmask[j, : len(suffix)] = 1
-                    tables[j, : len(blocks)] = blocks
-                    tables[j, len(blocks) :] = 0  # zero-block padding
-                    slots_arr[j] = slot
-                    max_new_arr[j] = max_new
-                    shared_arr[j] = T
-                    aidx_arr[j] = aslot
-                # padding rows repeat row 0's tokens but keep all-OOB
-                # tables and OOB slot ids — every write they make drops
-                ids_arr[len(chunk) :] = ids_arr[0]
-                tmask[len(chunk) :] = tmask[0]
-                args = [
-                    self._pool, params, jnp.asarray(ids_arr), jnp.asarray(tmask),
-                    jnp.asarray(tables), jnp.asarray(slots_arr),
-                    jnp.asarray(max_new_arr), jnp.asarray(shared_arr),
-                ]
-                if mt:
-                    args += [stack, jnp.asarray(aidx_arr)]
-                fresh = not shared_arr.any() and prefill_fuses(self.model_cfg, plen)
-                with self._insert_span(len(chunk), plen):
-                    self._pool = self._get_paged_insert(pb, plen, fresh)(*args)
+        for plen, chunk, pb in programs:
+            ids_arr = np.full((pb, plen), pad_id, np.int32)
+            tmask = np.zeros((pb, plen), np.int32)
+            tables = np.full((pb, self._n_tbl), self._n_blocks, np.int32)
+            slots_arr = np.full((pb,), self.num_slots, np.int32)
+            max_new_arr = np.full((pb,), self.gen_cfg.max_new_tokens, np.int32)
+            shared_arr = np.zeros((pb,), np.int32)
+            aidx_arr = np.zeros((pb,), np.int32)  # padding rows gather base
+            for j, (suffix, T, blocks, max_new, slot, aslot) in enumerate(chunk):
+                ids_arr[j, : len(suffix)] = suffix  # RIGHT-padded
+                tmask[j, : len(suffix)] = 1
+                tables[j, : len(blocks)] = blocks
+                tables[j, len(blocks) :] = 0  # zero-block padding
+                slots_arr[j] = slot
+                max_new_arr[j] = max_new
+                shared_arr[j] = T
+                aidx_arr[j] = aslot
+            # padding rows repeat row 0's tokens but keep all-OOB
+            # tables and OOB slot ids — every write they make drops
+            ids_arr[len(chunk) :] = ids_arr[0]
+            tmask[len(chunk) :] = tmask[0]
+            args = [
+                self._pool, params, jnp.asarray(ids_arr), jnp.asarray(tmask),
+                jnp.asarray(tables), jnp.asarray(slots_arr),
+                jnp.asarray(max_new_arr), jnp.asarray(shared_arr),
+            ]
+            if mt:
+                args += [stack, jnp.asarray(aidx_arr)]
+            fresh = not shared_arr.any() and prefill_fuses(self.model_cfg, plen)
+            with self._insert_span(len(chunk), plen):
+                self._pool = self._get_paged_insert(pb, plen, fresh)(*args)
 
     # ------------------------------------------------------------------
     # Decode
@@ -1322,20 +1343,23 @@ class InferenceEngine:
             },
         )
 
-    def insert_requests(self, rows, slot_ids, **kwargs) -> None:
+    def insert_requests(self, rows, slot_ids, **kwargs) -> Tuple[int, int, int]:
         """OOM-guarded wrapper over `_insert_requests_impl` (see there for
-        the contract); samples the HBM ledger at the prefill boundary."""
+        the contract); samples the HBM ledger at the prefill boundary.
+        Returns the admission's (rows, prompt tokens prefilled, positions
+        dispatched with the padding): `_count_admission`."""
         # the step in flight was dispatched before these rows: whatever it
         # holds for their slots is not theirs
         self._disown(slot_ids)
         try:
-            self._insert_requests_impl(rows, slot_ids, **kwargs)
+            counts = self._insert_requests_impl(rows, slot_ids, **kwargs)
         except Exception as e:
             self._maybe_oom_postmortem("engine.insert", e)
             raise
         self._live[np.asarray(slot_ids, np.int64)] = True
         if self.hbm is not None:
             self.hbm.sample("engine.insert")
+        return counts
 
     def step(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
         """One decode step's outputs, one step a call, in order (`_step_impl`
@@ -1349,7 +1373,7 @@ class InferenceEngine:
         was)."""
         self._step_n += 1
         try:
-            with tracing.span("engine.step", step_n=self._step_n):
+            with tracing.span("engine.step"):
                 out = self._step_impl()
         except Exception as e:
             self._maybe_oom_postmortem("engine.step", e)
@@ -1391,11 +1415,16 @@ class InferenceEngine:
         # columns this one writes as in flight
         due = self._ahead
         self._ahead = self._dispatch_decode()
+        # the span ends when the host has the step's outputs, and covers
+        # nothing else: its end is what a reader sets against the device's
         with tracing.span("engine.fetch"):
             token, logprob, valid, finished, *moe = jax.device_get(due.out)
+        traced = tracing.active()
+        if traced:  # which step that fetch waited for: the `seq` it was queued under
+            tracing.counters("engine.fetched", seq=due.seq)
         if moe:  # a model with `SparseMoE` layers: the step's dispatch counters
             self._moe_stats = {k: float(v) for k, v in moe[0].items()}
-            if self.kv_paging and tracing.active():
+            if self.kv_paging and traced:
                 tracing.counters("engine.moe", **self._moe_stats)
         rows = due.rows if valid.ndim == 1 else due.rows[:, None]
         self._outputs_masked += int((valid & ~rows).sum())
@@ -1431,16 +1460,18 @@ class InferenceEngine:
         copy to the host; wait for nothing."""
         ahead = self._ahead is not None
         self._steps_ahead += int(ahead)
-        # how ragged the rows are is what the paged kernel's time follows;
-        # said on the span only while a tracing session listens
-        attrs = {}
+        self._dispatches += 1
+        seq = self._dispatches
+        # said only while a tracing session listens, as counter spans (a
+        # reader keeps names, not attributes): how ragged the rows are, which
+        # the paged kernel's time follows, and directly in front of the
+        # dispatch's span who the step is, so that a reader joins this
+        # dispatch, the device's program and the fetch that waits for it
         if tracing.active():
             if self.kv_paging:
-                attrs["live_entries"] = self._live_entries()
                 tracing.counters("engine.kv_walk", **self._kv_walk())
-            if ahead:
-                attrs["ahead"] = 1
-        with tracing.span("engine.dispatch", **attrs):
+            tracing.counters("engine.queued", seq=seq, ahead=int(ahead), rows=int(self._live.sum()))
+        with tracing.span("engine.dispatch"):
             if self.spec_k > 0:
                 params, head = self._current_params_and_head()
                 self._pool, *out = self._decode_fn(params, self._pool, head[0], head[1])
@@ -1454,7 +1485,7 @@ class InferenceEngine:
             # device stands still meanwhile (PERF.md section 5)
             for leaf in jax.tree_util.tree_leaves(out):
                 leaf.copy_to_host_async()
-        return _InFlight(tuple(out), self._live.copy())
+        return _InFlight(tuple(out), self._live.copy(), seq)
 
     def _disown(self, slots: Sequence[int]) -> None:
         """The step in flight no longer speaks for these slots."""

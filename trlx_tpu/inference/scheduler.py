@@ -685,8 +685,7 @@ class Scheduler:
                 "admit", batch=len(batch), queue_depth=len(self._queue),
             )
         try:
-            with tracing.span("sched.insert_batch", rows=len(batch),
-                              width=max(len(r.prompt_ids) for r in batch)):
+            with tracing.span("sched.insert_batch"):
                 self._insert_batch(batch, slots)
         finally:
             with self._cond:
@@ -731,7 +730,7 @@ class Scheduler:
             )
             t0 = time.perf_counter()
             try:
-                self.engine.insert_requests(rows, slots, sessions=sessions)
+                prefilled = self.engine.insert_requests(rows, slots, sessions=sessions)
                 break
             except AdapterCapacityError:
                 # the batch needs more distinct adapters pinned at once
@@ -783,7 +782,14 @@ class Scheduler:
             trace_id=next((r.trace.trace_id for r in batch
                            if r.trace is not None), None),
         )
+        # what the admission cost, as the engine built it (rows, the prompt
+        # tokens its programs compute, the positions they are dispatched at
+        # with the padding); the same numbers stand on a tracing session's
+        # trace as `trlx:sched.insert`
         self.metrics.inc("prefill_batches_total")
+        for name, n in zip(("prefill_rows_total", "prefill_tokens_total",
+                            "prefill_padded_tokens_total"), prefilled):
+            self.metrics.inc(name, n)
         if traced:
             ts1 = time.monotonic()
             buf = getattr(self.engine, "trace_buf", None) or []

@@ -11,9 +11,11 @@ active it lands on the host plane of the same xplane file as the device's
 operations, on the same clock, so an idle gap of the device can be charged
 to what the host was doing in it. While none is active the annotation
 checks one flag and formats nothing, so span sites are unconditional: no
-config field, no environment variable. Attributes carry what joins spans
-(`step`, `chunk`, `rows`, `width`, `step_n`); nesting on a thread gives the
-parent. The span names are listed in docs/observability.md.
+config field, no environment variable. Attributes carry what joins the
+trainer's spans (`step`, `chunk`, `rows`); nesting on a thread gives the
+parent; what a reader of names alone must see (a decode step's `seq`, an
+admission's rows and tokens) stands in a counter span's name (`counters`).
+The span names are listed in docs/observability.md.
 
 **Request traces and the phase timeline** (ISSUE 13):
 

@@ -15,9 +15,12 @@ Needs no chip: every program is traced at its cell's widths and recipe
 (depth cut to `--layers`, which changes no shape) and lowered for the TPU
 platform with the Pallas kernels on, never compiled or run. The PPO cells'
 trainers are built as `bench/jobs/ppo.py` builds them and stopped at the
-first call of each jitted program (`_ljit` is where a trainer makes one;
-where the schedule trains from the trunk cache, its fill and the resumed
-train step are taken beside the whole-forward step);
+first call of each jitted program (`_ljit` is where a trainer makes one,
+so the score program is reached through `_score_fn` whether or not that is
+a door in front of it; where the schedule trains from the trunk cache, the
+resumed train step is taken beside the whole-forward step, and the fill
+where the schedule runs one: not where the score program hands the state
+out);
 the serve cells' engines as `tests/test_kernels_compile_tpu.py` builds them.
 """
 
@@ -112,11 +115,14 @@ def ppo_programs(workload: str, layers: int) -> dict:
                            logprobs=zeros, values=zeros, rewards=zeros)
     stop_at_program(trainer.train_minibatch, [minibatch])
     if trainer._trunk_cache_available():
-        # the schedule trains from the trunk cache: one chunk's fill, and the
-        # step over a batch that names its rows of the cycle's array
-        trainer._open_trunk_cache()
-        trainer._note_trunk_chunk(ids, np.ones((ids.shape[0], max_new), np.int32))
-        stop_at_program(trainer._close_trunk_cache)
+        # the schedule trains from the trunk cache: one chunk's fill (none
+        # where the score program has handed the chunk's state out: a tree
+        # from before that fills always), and the step over a batch that
+        # names its rows of the cycle's array
+        if not getattr(trainer, "_score_with_trunk_state", False):
+            trainer._open_trunk_cache()
+            trainer._note_trunk_chunk(ids, np.ones((ids.shape[0], max_new), np.int32))
+            stop_at_program(trainer._close_trunk_cache)
         trainer._trunk_cache = jax.ShapeDtypeStruct(
             (cfg.method.num_rollouts, ids.shape[1] + max_new, trainer.model_cfg.d_model),
             trainer.model_cfg.dtype)

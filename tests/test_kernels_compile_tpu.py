@@ -759,12 +759,12 @@ def test_lfm2_train_step_fits_the_chip_at_batch_16(v5e, pallas_mode, capsys):
 PPO_HH = dict(vocab_size=50304, attn_impl="flash", n_layers=4)
 
 
-@pytest.fixture(scope="module")
-def ppo_hh_trainer(tmp_path_factory):
-    """A `PPOTrainer` whose loss and trunk-cache fill are the dense PPO
-    cell's: built at test size, then handed pythia-1.4b's model at the
-    cell's widths, so `make_loss_fn` and `_build_trunk_cache_fn` trace the
-    programs the cell runs over shapes and no array."""
+def _cell_trainer(checkpoint_dir, preset, extra, *, batch_size, num_rollouts, chunk_size, max_new,
+                  seq_length=1024):
+    """A `PPOTrainer` whose scorer, loss and trunk-cache fill are a PPO
+    cell's: built at test size, then handed the cell's model at the cell's
+    widths, so `_build_score_fn`, `make_loss_fn` and `_build_trunk_cache_fn`
+    trace the programs the cell runs over shapes and no array."""
     from trlx_tpu.data.default_configs import default_ppo_config
     from trlx_tpu.models import CausalLMWithValueHead, config_from_preset
     from trlx_tpu.trainer.ppo_trainer import PPOTrainer
@@ -772,14 +772,14 @@ def ppo_hh_trainer(tmp_path_factory):
     config = default_ppo_config().evolve(
         model=dict(model_path="random:gpt2-tiny", num_layers_unfrozen=1),
         tokenizer=dict(tokenizer_path="byte"),
-        train=dict(seq_length=1024, batch_size=8, tracker=None,
-                   checkpoint_dir=str(tmp_path_factory.mktemp("ppo_hh"))),
-        method=dict(num_rollouts=64, chunk_size=16, ppo_epochs=4,
-                    gen_kwargs=dict(max_new_tokens=128, top_k=0, top_p=1.0, do_sample=True)),
+        train=dict(seq_length=seq_length, batch_size=batch_size, tracker=None,
+                   checkpoint_dir=str(checkpoint_dir)),
+        method=dict(num_rollouts=num_rollouts, chunk_size=chunk_size, ppo_epochs=4,
+                    gen_kwargs=dict(max_new_tokens=max_new, top_k=0, top_p=1.0, do_sample=True)),
     )
     trainer = PPOTrainer(config, reward_fn=lambda samples, **kw: [0.0] * len(samples),
                          devices=jax.devices()[:1])
-    cfg = config_from_preset("pythia-1.4b", **PPO_HH)
+    cfg = config_from_preset(preset, **extra)
     trainer.model, trainer.model_cfg, trainer.split = CausalLMWithValueHead(cfg), cfg, cfg.n_layers - 2
     # the trainer's mesh is this process's CPU; the programs are placed
     # where the test compiles them
@@ -788,19 +788,43 @@ def ppo_hh_trainer(tmp_path_factory):
     return trainer
 
 
-def _ppo_hh_params(trainer):
-    """(trainable, frozen) flat float32 leaves of the cell's model, as shapes."""
+@pytest.fixture(scope="module")
+def ppo_hh_trainer(tmp_path_factory):
+    return _cell_trainer(tmp_path_factory.mktemp("ppo_hh"), "pythia-1.4b", PPO_HH,
+                         batch_size=8, num_rollouts=64, chunk_size=16, max_new=128)
+
+
+def _ppo_hh_params(trainer, with_ref=False):
+    """(trainable, frozen) flat float32 leaves of the cell's model, as
+    shapes; with the reference branch's subtree where asked."""
     from flax.traverse_util import flatten_dict
 
-    from trlx_tpu.models.policy import trainable_mask
+    from trlx_tpu.models.policy import ref_param_subtree, trainable_mask
 
     probe = jnp.zeros((1, 8), I32)
     params = jax.eval_shape(
         lambda: trainer.model.init(jax.random.PRNGKey(0), probe, jnp.ones_like(probe))["params"])
     flat = flatten_dict(params)
     mask = flatten_dict(trainable_mask(params, trainer.model_cfg, 2))
-    return ({k: v for k, v in flat.items() if mask[k]},
-            {k: v for k, v in flat.items() if not mask[k]})
+    train = {k: v for k, v in flat.items() if mask[k]}
+    frozen = {k: v for k, v in flat.items() if not mask[k]}
+    if not with_ref:
+        return train, frozen
+    return train, frozen, jax.eval_shape(
+        lambda p: ref_param_subtree(p, trainer.model_cfg, trainer.split), params)
+
+
+def _traced_score(trainer, device, rows, width, hands_out):
+    """The one program behind `_score_fn`, from where the trainer makes it,
+    traced over a chunk of the cell for one described chip."""
+    programs, ljit = {}, type(trainer)._ljit
+    trainer._ljit = lambda fn, name, **kw: programs.setdefault(name, ljit(trainer, fn, name, **kw))
+    trainer._score_hands_out_trunk_state = lambda: hands_out
+    trainer._build_score_fn()
+    assert trainer._score_with_trunk_state is hands_out and list(programs) == ["score"]
+    assert (trainer._score_fn is programs["score"]) is not hands_out
+    return programs["score"].trace(*abstract(
+        (*_ppo_hh_params(trainer, with_ref=True), S((rows, width), I32)), SingleDeviceSharding(device)))
 
 
 def test_dense_ppo_cell_trunk_cache_fill_compiles_under_its_name(v5e, pallas_mode, ppo_hh_trainer):
@@ -858,3 +882,55 @@ def test_dense_ppo_cell_train_step_resumes_from_the_trunk_cache(v5e, pallas_mode
     assert (resumed, whole) == (2, trainer.model_cfg.n_layers)
     # the cache is an argument the step reads and hands back to nobody
     assert donated_outputs(compiled) == len(jax.tree_util.tree_leaves((train, opt_state)))
+
+
+def test_lfm2_score_program_with_the_trunk_state_compiles_and_says_what_it_holds(
+        v5e, pallas_mode, tmp_path, capsys):
+    """`lfm2-8b-a1b.ppo-hh` collects in one chunk of 64 x 1,024, so its
+    score program hands out the state entering block 8 as a sixth output
+    (`_score_hands_out_trunk_state`): under the name the device trace knows
+    (`jit_score`), compiled for one v5e chip, its outputs are the
+    five-output program's and 64 x 1,024 x 2,048 bfloat16 states, 268 MB
+    that stand on the device from the score to the cycle's last step in
+    the fill's place."""
+    b, t = 64, 1024
+    trainer = _cell_trainer(tmp_path, "lfm2-8b-a1b", LFM2, batch_size=16, num_rollouts=b,
+                            chunk_size=b, max_new=128)
+    assert trainer._score_hands_out_trunk_state()
+    five, six = (_traced_score(trainer, v5e[0], b, t, hands_out) for hands_out in (False, True))
+    assert [(o.shape, o.dtype) for o in six.out_info] == [
+        *((o.shape, o.dtype) for o in five.out_info), ((b, t, trainer.model_cfg.d_model), BF16)]
+    five_bytes = sum(int(np.prod(o.shape)) * o.dtype.itemsize for o in five.out_info)
+    assert five_bytes == 3 * b * (t - 1) * 4 + 2 * 4
+    lowered = six.lower(lowering_platforms=("tpu",))
+    assert "module @jit_score " in lowered.as_text()[:200]
+    memory = lowered.compile().memory_analysis()
+    with capsys.disabled():
+        print(f"\nlfm2-8b-a1b score at {b} x {t} for v5e with the trunk state: outputs "
+              f"{memory.output_size_in_bytes / 1e6:.1f} MB ({five_bytes / 1e6:.1f} MB without it), "
+              f"temporaries {memory.temp_size_in_bytes / 1e9:.2f} GB, arguments "
+              f"{memory.argument_size_in_bytes / 1e9:.2f} GB")
+    want = five_bytes + b * t * trainer.model_cfg.d_model * 2
+    # (the compiler rounds every output buffer up to its tile)
+    assert want <= memory.output_size_in_bytes <= want + 64 * 1024
+
+
+def test_gpt2_xl_score_keeps_its_weight_prefetches_with_the_trunk_state(v5e, pallas_mode, tmp_path):
+    """`gpt2-xl.ppo-sentiments` scores one chunk of 128 x 104, whose whole
+    residual stream (43 MB) fits a v5e's fast memory. Handed out as a plain
+    sixth output the state made the compiler keep that stream there and
+    stop prefetching the frozen blocks' MLP weights (sliced copies joined by
+    `ConcatBitcast`): 0.676 against 0.566 s a chunk on the chip (PERF.md
+    section 6, PR 40). `score` hands it out behind a barrier for that; this
+    holds the plan, at the cell's widths and a depth of 4 frozen blocks:
+    the six-output program prefetches what the five-output program does."""
+    trainer = _cell_trainer(tmp_path, "gpt2-xl", dict(vocab_size=50257, attn_impl="flash", n_layers=6),
+                            batch_size=32, num_rollouts=128, chunk_size=128, max_new=40)
+    assert trainer._score_hands_out_trunk_state()
+
+    def prefetched_weights(hands_out):
+        traced = _traced_score(trainer, v5e[0], 128, 104, hands_out)
+        return traced.lower(lowering_platforms=("tpu",)).compile().as_text().count("ConcatBitcast")
+
+    five, six = prefetched_weights(False), prefetched_weights(True)
+    assert five >= 4 * 6 and six >= five, (five, six)

@@ -74,7 +74,7 @@ def test_hydra_equivalence_at_init(nlu):
     ref = ref_param_subtree(params, cfg, split)
     tokens = jnp.asarray([[1, 2, 3, 4, 5, 6]], dtype=jnp.int32)
     mask = jnp.ones_like(tokens)
-    logits, values, ref_logits = forward_policy_and_ref(model, params, ref, tokens, mask, split)
+    logits, values, ref_logits, _ = forward_policy_and_ref(model, params, ref, tokens, mask, split)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(ref_logits), atol=1e-5)
 
 
@@ -85,14 +85,14 @@ def test_hydra_diverges_after_update():
     ref = ref_param_subtree(params, cfg, split)
     tokens = jnp.asarray([[1, 2, 3, 4]], dtype=jnp.int32)
     mask = jnp.ones_like(tokens)
-    _, _, ref_logits0 = forward_policy_and_ref(model, params, ref, tokens, mask, split)
+    _, _, ref_logits0, _ = forward_policy_and_ref(model, params, ref, tokens, mask, split)
 
     mutated = jax.tree_util.tree_map(lambda x: x, params)
     tm = trainable_mask(params, cfg, 1)
     mutated = jax.tree_util.tree_map(
         lambda p, m: p + 0.01 if m else p, mutated, tm
     )
-    logits1, _, ref_logits1 = forward_policy_and_ref(model, mutated, ref, tokens, mask, split)
+    logits1, _, ref_logits1, _ = forward_policy_and_ref(model, mutated, ref, tokens, mask, split)
     np.testing.assert_allclose(np.asarray(ref_logits0), np.asarray(ref_logits1), atol=1e-5)
     assert float(jnp.abs(logits1 - ref_logits1).max()) > 1e-3
 
@@ -205,7 +205,7 @@ def test_value_branch_model():
     # hydra composition still works
     split = resolve_split(cfg, 1)
     ref = ref_param_subtree(params, cfg, split)
-    lg, vals, rlg = forward_policy_and_ref(model, params, ref, tokens, mask, split)
+    lg, vals, rlg, _ = forward_policy_and_ref(model, params, ref, tokens, mask, split)
     np.testing.assert_allclose(np.asarray(lg), np.asarray(rlg), atol=1e-5)
 
     # trainable mask: whole branch trains

@@ -118,7 +118,7 @@ def test_ref_logits_are_adapter_disabled():
 
     perturbed = _perturb_lora(params)
     ref = ref_param_subtree({"lm": perturbed["lm"], "v_head": perturbed["v_head"]}, cfg, 0)
-    logits, values, ref_logits = forward_policy_and_ref(
+    logits, values, ref_logits, _ = forward_policy_and_ref(
         model, perturbed, ref, tokens, mask, split=0
     )
     base_logits, *_ = model.apply({"params": zero_lora(perturbed)}, tokens, mask)

@@ -128,7 +128,7 @@ def test_ppo_rewards_affect_training(tmp_path):
     mask = jnp.ones_like(tokens)
     from trlx_tpu.models import forward_policy_and_ref
 
-    logits, _, ref_logits = forward_policy_and_ref(
+    logits, _, ref_logits, _ = forward_policy_and_ref(
         trainer.model, trainer.params, trainer.ref_params, tokens, mask, trainer.split
     )
     assert float(jnp.abs(logits - ref_logits).max()) > 1e-4

@@ -12,6 +12,12 @@ Pinned here:
   trainable leaves BITWISE equal to the same cycle run from the whole
   forward: the fill lays a chunk's tokens out as the train batches will,
   so a cached row is column for column what the whole forward computes;
+- where a collection is one chunk the score program hands the state out
+  and nothing is filled (`_score_hands_out_trunk_state`): bitwise the
+  whole forward's where the scorer's layout is the train batches' (the
+  benchmark's cells), and to the last bits where the loader pads queries
+  wider than the scorer saw them (attention over 14 columns and over 32,
+  18 of them masked, add up in another order);
 - the cycle's cache is one `jax.Array` that no `device_get` and no
   collator touches: a batch carries `int32[b]` row numbers;
 - the arbiter's table; the cache's dtype (the forward's own);
@@ -41,10 +47,14 @@ from trlx_tpu.trainer.ppo_trainer import PPOTrainer, _to_batch_columns
 MAX_NEW = 6
 SUPPRESS = [i for i in range(259) if not (32 <= i < 127 or i == 258)]
 PROMPTS = ["hello world", "jax tpu", "ppo", "fast"] * 2
+# the widest prompt fills the loader's query bucket (seq_length 32 - 6 new):
+# the scorer's layout is then the train batches', as in the benchmark's cells
+SCORER_LAYOUT = dict(prompts=["hello world, jax on a tpu!", "jax tpu", "ppo", "fast"] * 2,
+                     max_prompt_length=26)
 
 
 def _make_trainer(tmp_path, model=None, train=None, prompts=PROMPTS, cls=PPOTrainer,
-                  tokenizer="byte", devices=None, **method):
+                  tokenizer="byte", devices=None, max_prompt_length=8, **method):
     method = {
         "num_rollouts": 8, "chunk_size": 8, "ppo_epochs": 2,
         "gen_kwargs": dict(max_new_tokens=MAX_NEW, do_sample=True,
@@ -68,7 +78,8 @@ def _make_trainer(tmp_path, model=None, train=None, prompts=PROMPTS, cls=PPOTrai
         reward_fn=lambda samples, **kw: [float(len(s)) for s in samples],
         devices=None if devices is None else jax.devices()[:devices],
     )
-    pipeline = PromptPipeline(prompts, max_prompt_length=8, tokenizer=trainer.tokenizer)
+    pipeline = PromptPipeline(prompts, max_prompt_length=max_prompt_length,
+                              tokenizer=trainer.tokenizer)
     trainer.add_prompt_pipeline(pipeline)
     return trainer
 
@@ -142,6 +153,38 @@ def _eager_trunk(trainer, tokens):
     )[1]
 
 
+def _filled_trunk(trainer, tokens):
+    """The same state as the cycle's fill computes it (one jitted program)."""
+    return trainer._build_trunk_cache_fn()(trainer.train_params, trainer.frozen_params, tokens)
+
+
+def _count_fills(trainer):
+    """The token shapes of every `trunk_cache_fill` call from here on."""
+    fills, build = [], trainer._build_trunk_cache_fn
+
+    def counting_fill():
+        fill = build()
+        return lambda *args: (fills.append(args[2].shape), fill(*args))[1]
+
+    trainer._build_trunk_cache_fn = counting_fill
+    return fills
+
+
+def _assert_rows_hold_their_tokens_state(tr):
+    """Every element of the store names the state of ITS tokens, laid out
+    as the loader will lay them out."""
+    pad, q = tr.tokenizer.pad_token_id, tr._train_query_width(8)
+    for e in tr.store.history:
+        tokens = np.full((1, q + MAX_NEW), pad, np.int32)
+        tokens[0, q - len(e.query_tensor):q] = e.query_tensor
+        tokens[0, q:q + len(e.response_tensor)] = e.response_tensor
+        want = _eager_trunk(tr, jnp.asarray(tokens))[0]
+        real = tokens[0] != pad
+        np.testing.assert_allclose(
+            np.asarray(tr._trunk_cache[e.trunk_row])[real], np.asarray(want)[real],
+            rtol=1e-5, atol=1e-6)
+
+
 def _grads(trainer, loss_fn, batch):
     return jax.grad(
         lambda p: loss_fn(p, trainer.frozen_params, batch)[0]
@@ -162,17 +205,32 @@ def _uncached(batch):
     dict(chunk_size=8),                                             # rows over 8 devices
     dict(chunk_size=4, train=dict(batch_size=4), devices=1),        # two chunks: one array from both
     dict(chunk_size=8, train=dict(minibatch_size=4), devices=1),    # the accumulation step
-], ids=["one_chunk", "one_chunk_8_devices", "two_chunks", "accumulation"])
+    dict(SCORER_LAYOUT, chunk_size=8, devices=1),
+    dict(SCORER_LAYOUT, chunk_size=8),
+    dict(SCORER_LAYOUT, chunk_size=8, train=dict(minibatch_size=4), devices=1),
+], ids=["one_chunk", "one_chunk_8_devices", "two_chunks", "accumulation",
+        "one_chunk_scorer_layout", "one_chunk_8_devices_scorer_layout",
+        "accumulation_scorer_layout"])
 def test_classic_cycle_bitwise_equals_the_whole_forward(tmp_path, monkeypatch, recipe):
     """Two cycles with the cache against two cycles from the whole forward:
     every step's loss and the final trainable leaves bitwise equal; the
     cycle's cache is a device array that nothing copies to the host, and
-    the collator builds row numbers, never a [b, T, d] array."""
+    the collator builds row numbers, never a [b, T, d] array. One chunk a
+    collection: the rows come from the score program and no fill is ever
+    built; on one device in the scorer's layout they are the fill's to the
+    bit, otherwise to the last bits."""
     recipe = dict(recipe)
     train = recipe.pop("train", None)
     cached = _make_trainer(tmp_path / "cached", train=train, **recipe)
     whole = _whole_forward(_make_trainer(tmp_path / "whole", train=train, **recipe))
     assert cached._trunk_cache_available() and not whole._trunk_cache_available()
+    scored = recipe["chunk_size"] == 8
+    assert cached._score_hands_out_trunk_state() is scored
+    assert not whole._score_hands_out_trunk_state()
+    # the score program's state is the fill's to the bit on one device in
+    # the scorer's own layout; partitioned over 8 devices beside the heads
+    # and the reference branch, or moved under wider queries, to the last bits
+    bitwise = not scored or ("prompts" in recipe and recipe.get("devices") == 1)
 
     fetched = []
     real_get = jax.device_get
@@ -186,13 +244,20 @@ def test_classic_cycle_bitwise_equals_the_whole_forward(tmp_path, monkeypatch, r
         got, _ = _cycle(cached)
         monkeypatch.setattr(jax, "device_get", real_get)
         want, _ = _cycle(whole)
-        assert got == want and np.all(np.isfinite(got)), (cycle, got, want)
-        assert len(got) == 2 * (8 // cached.config.train.batch_size)
+        assert np.all(np.isfinite(got)) and len(got) == 2 * (8 // cached.config.train.batch_size)
+        if bitwise:
+            assert got == want, (cycle, got, want)
+        else:
+            np.testing.assert_allclose(got, want, rtol=1e-6, err_msg=str(cycle))
 
         cache = cached._trunk_cache
         assert isinstance(cache, jax.Array) and cache.dtype == jnp.float32
         assert cache.shape == (8, 32, cached.model_cfg.d_model)
         assert sorted(e.trunk_row for e in cached.store.history) == list(range(8))
+        # where the rows came from: the score program's sixth output, or
+        # one fill a chunk when the collection has ended
+        assert cached._trunk_scored_rows == (8 if scored else 0)
+        assert (cached._trunk_cache_fn is None) is scored
         for host in cached.create_train_dataloader():
             leaves = jax.tree_util.tree_leaves(host)
             assert all(isinstance(x, np.ndarray) and x.ndim <= 2 for x in leaves)
@@ -200,7 +265,77 @@ def test_classic_cycle_bitwise_equals_the_whole_forward(tmp_path, monkeypatch, r
     assert fetched and all(len(shape) <= 2 for shape in fetched), fetched
     assert whole._trunk_cache is None and whole._trunk_cache_fn is None
     assert all(e.trunk_row is None for e in whole.store.history)
-    _assert_same_leaves(cached, whole)
+    if bitwise:
+        _assert_same_leaves(cached, whole)
+    else:
+        # (a key bias has no gradient but rounding, which Adam turns into
+        # steps of the learning rate's size: 4 steps either way)
+        for k in cached.train_params:
+            np.testing.assert_allclose(
+                np.asarray(cached.train_params[k]), np.asarray(whole.train_params[k]),
+                rtol=1e-4, atol=8 * cached.config.optimizer.kwargs["lr"], err_msg=str(k))
+
+
+def _score_args(tr, rows=8, width=8 + MAX_NEW):
+    return (tr.train_params, tr.frozen_params, tr.ref_params,
+            jax.ShapeDtypeStruct((rows, width), jnp.int32))
+
+
+def test_two_chunks_score_with_five_outputs_and_fill_both_at_the_end(tmp_path):
+    """A recipe that collects in two chunks dispatches the second chunk's
+    generation before the first is scored: the arbiter says no, the score
+    program is the one a trainer without a trunk cache builds, and both
+    chunks are filled when the collection has ended."""
+    tr = _make_trainer(tmp_path / "two", chunk_size=4, train=dict(batch_size=4), devices=1)
+    plain = _make_trainer(tmp_path / "plain", chunk_size=4, train=dict(batch_size=4), devices=1,
+                          ppo_epochs=1)
+    assert tr._trunk_cache_available() and not tr._score_hands_out_trunk_state()
+    assert not plain._trunk_cache_available()
+    for t in (tr, plain):
+        t._build_score_fn()
+        assert not t._score_with_trunk_state
+    fills = _count_fills(tr)
+    texts = [t._score_fn.lower(*_score_args(t, rows=4)).as_text() for t in (tr, plain)]
+    assert texts[0] == texts[1] and "jit_score" in texts[0][:200]
+    assert len(jax.eval_shape(tr._score_fn, *_score_args(tr, rows=4))) == 5
+    tr.make_experience(8)
+    assert fills == [(4, 32), (4, 32)] and tr._trunk_scored_rows == 0
+    assert tr._trunk_cache.shape == (8, 32, tr.model_cfg.d_model)
+
+
+@pytest.mark.parametrize("chunk_size", [8, 4], ids=["handing_out", "five_outputs"])
+def test_score_fn_is_a_five_output_door_under_both_answers(tmp_path, chunk_size):
+    """What bench/jobs/ppo.py `compare_outputs`, scripts/lowered_text.py and
+    the multi-turn collection unpack. Behind the door stands ONE program
+    named `score`; the sixth result goes to the caller that asks for it."""
+    tr = _make_trainer(tmp_path, chunk_size=chunk_size, devices=1)
+    names = []
+    ljit = tr._ljit
+    tr._ljit = lambda fn, name, **kw: (names.append(name), ljit(fn, name, **kw))[1]
+    tr._build_score_fn()
+    assert names == ["score"] and tr._score_with_trunk_state is (chunk_size == 8)
+    tokens = np.full((8, 8 + MAX_NEW), 65, np.int32)
+    out = tr._score_fn(tr.train_params, tr.frozen_params, tr.ref_params, jnp.asarray(tokens))
+    logprobs, values, log_ratio, mean_kl, mean_kl_per_token = out
+    assert logprobs.shape == values.shape == log_ratio.shape == (8, 8 + MAX_NEW - 1)
+    if chunk_size == 8:
+        six = tr._score_fn(tr.train_params, tr.frozen_params, tr.ref_params,
+                           jnp.asarray(tokens), trunk_state=True)
+        assert len(six) == 6 and six[5].shape == (8, 8 + MAX_NEW, tr.model_cfg.d_model)
+        np.testing.assert_array_equal(np.asarray(six[0]), np.asarray(logprobs))
+        np.testing.assert_array_equal(
+            np.asarray(six[5]), np.asarray(_filled_trunk(tr, jnp.asarray(tokens))))
+
+    # the multi-turn collection scores whole conversations through the door
+    # (`_episodes_to_elements`), at a width of its own
+    stats = {}
+    episodes = [([65, 66, 67], [("policy", [68, 69], [-0.5, -0.25], 1.0),
+                                ("env", [70], None, 0.0),
+                                ("policy", [71, 72, 73], [-0.1, -0.2, -0.3], 2.0)], 1)] * 2
+    elements = tr._episodes_to_elements(episodes, stats)
+    assert len(elements) == 2 and np.isfinite(stats["policy/sqrt_kl"])
+    assert all(e.trunk_row is None and len(e.response_tensor) == 6 for e in elements)
+    assert tr._trunk_cache is None and tr._trunk_chunks is None
 
 
 def test_the_next_collection_over_an_empty_store_drops_the_cache(tmp_path):
@@ -271,45 +406,62 @@ def _patch(trainer, monkeypatch, *, method=None, model_cfg=None, train=None, bud
         monkeypatch.setattr(trainer, name, value)
 
 
+class _OwnScore(PPOTrainer):
+    def _build_score_fn(self):
+        return super()._build_score_fn()
+
+
 CELL = dict(budget=int(ppo_trainer.TRUNK_CACHE_HBM_SHARE * V5E))
 ARBITER = {
-    # what the schedule observes -> (patch, engages, bytes a device holds)
-    "as_built": (dict(), True, 8 * 32 * 64 * 4),
-    "one_epoch": (dict(method=dict(ppo_epochs=1)), False, None),
-    "nothing_frozen": (dict(split=0), False, None),
-    "seq2seq": (dict(seq2seq=True), False, None),
+    # what the schedule observes -> (patch, the cycle trains from the trunk
+    # cache, bytes a device holds of it, the score program hands the state out)
+    "as_built": (dict(), True, 8 * 32 * 64 * 4, True),
+    "one_epoch": (dict(method=dict(ppo_epochs=1)), False, None, False),
+    "nothing_frozen": (dict(split=0), False, None, False),
+    "seq2seq": (dict(seq2seq=True), False, None, False),
     # MoEMLP's softmax router sows an auxiliary loss from the full forward
-    "sows_moe_aux": (dict(model_cfg=dict(moe_experts=2)), False, None),
+    "sows_moe_aux": (dict(model_cfg=dict(moe_experts=2)), False, None, False),
     # n_layers=2, split=1, 2 value layers: the branch taps at layer 0 < split
-    "value_branch_below_split": (dict(method=dict(num_value_layers_unfrozen=2)), False, None),
-    "over_hbm_budget": (dict(budget=8 * 32 * 64 * 4 - 1), False, None),
-    "just_inside_hbm_budget": (dict(budget=8 * 32 * 64 * 4), True, None),
+    "value_branch_below_split": (
+        dict(method=dict(num_value_layers_unfrozen=2)), False, None, False),
+    "over_hbm_budget": (dict(budget=8 * 32 * 64 * 4 - 1), False, None, False),
+    "just_inside_hbm_budget": (dict(budget=8 * 32 * 64 * 4), True, None, True),
     # a backend that reports no capacity (the CPU) bounds nothing
-    "no_capacity_known": (dict(budget=0), True, None),
+    "no_capacity_known": (dict(budget=0), True, None, True),
+    # the scorer's own reasons: a second chunk's generation is in flight
+    # while the first is scored; a trainer that builds its own scorer
+    "two_chunks": (dict(method=dict(chunk_size=4)), True, None, False),
+    "one_row_over_a_chunk": (dict(method=dict(num_rollouts=9)), True, None, False),
+    "own_score": (dict(__class__=_OwnScore), True, None, False),
     # the benchmark's three PPO cells on a v5e's 16 GiB
     "pythia-1.4b.ppo-hh": (dict(
         CELL, method=dict(num_rollouts=64, chunk_size=16, ppo_epochs=4), train=dict(seq_length=1024),
-        model_cfg=dict(d_model=2048, n_layers=24, dtype=jnp.bfloat16), split=22), True, 268435456),
+        model_cfg=dict(d_model=2048, n_layers=24, dtype=jnp.bfloat16), split=22),
+        True, 268435456, False),
     "gpt2-xl.ppo-sentiments": (dict(
         CELL, method=dict(num_rollouts=128, chunk_size=128, ppo_epochs=4), train=dict(seq_length=104),
-        model_cfg=dict(d_model=1600, n_layers=48, dtype=jnp.bfloat16), split=46), True, 42598400),
+        model_cfg=dict(d_model=1600, n_layers=48, dtype=jnp.bfloat16), split=46),
+        True, 42598400, True),
     "lfm2-8b-a1b.ppo-hh": (dict(
         CELL, method=dict(num_rollouts=64, chunk_size=64, ppo_epochs=4), train=dict(seq_length=1024),
-        model_cfg=dict(d_model=2048, n_layers=10, dtype=jnp.bfloat16), split=8), True, 268435456),
+        model_cfg=dict(d_model=2048, n_layers=10, dtype=jnp.bfloat16), split=8),
+        True, 268435456, True),
     # the same recipe at a width and depth of rollouts one chip cannot hold
     "too_many_rollouts": (dict(
         CELL, method=dict(num_rollouts=1024, chunk_size=16, ppo_epochs=4), train=dict(seq_length=1024),
-        model_cfg=dict(d_model=2048, n_layers=24, dtype=jnp.bfloat16), split=22), False, 4294967296),
+        model_cfg=dict(d_model=2048, n_layers=24, dtype=jnp.bfloat16), split=22),
+        False, 4294967296, False),
 }
 
 
 @pytest.mark.parametrize("case", list(ARBITER))
 def test_arbiter_table(trainer, monkeypatch, case):
-    patch, engages, nbytes = ARBITER[case]
+    patch, engages, nbytes, scored = ARBITER[case]
     # one chip's numbers: the rows of the fixture's cache lie on one device
     monkeypatch.setattr(trainer, "_trunk_cache_sharding", lambda shape=None: None)
     _patch(trainer, monkeypatch, **patch)
     assert trainer._trunk_cache_available() is engages
+    assert trainer._score_hands_out_trunk_state() is scored
     if nbytes is not None:
         assert trainer._trunk_cache_device_bytes() == nbytes
 
@@ -346,6 +498,13 @@ def test_cache_holds_the_dtype_the_forward_hands_to_the_split(tmp_path, dtype):
     # the fill's program is found by its name in a device trace
     lowered = jax.jit(fill).lower(tr.train_params, tr.frozen_params, tokens)
     assert "jit_trunk_cache_fill" in lowered.as_text()[:200]
+    # and the state the score program hands out is the fill's, dtype and shape
+    tr._build_score_fn()
+    assert tr._score_with_trunk_state
+    state = jax.eval_shape(
+        lambda *args: tr._score_fn(*args, trunk_state=True),
+        tr.train_params, tr.frozen_params, tr.ref_params, tokens)[5]
+    assert (state.dtype, state.shape) == (out.dtype, out.shape)
 
 
 # ----------------------------------------------------------------------
@@ -410,6 +569,30 @@ def test_rows_filled_at_a_narrower_query_width_train_the_same_loss(trainer, chun
     np.testing.assert_allclose(float(l_c), float(l_f), rtol=1e-5)
 
 
+def test_scored_rows_under_queries_padded_wider_train_the_same_loss(trainer, chunk):
+    """The scorer sees queries 8 wide and the loader pads them to its
+    bucket, 26: the state the score program handed out moves on the device
+    (`trunk_cache_concat`, as chunks of two widths do), its added columns
+    hold zeros that nothing reads, and the moved rows train the whole
+    forward's loss."""
+    assert trainer._score_with_trunk_state and trainer._trunk_scored_rows == 8
+    assert trainer._trunk_cache_fn is None and trainer._trunk_concat_fn is not None
+    q = chunk.query_tensors.shape[1]
+    assert (q, trainer._trunk_cache.shape) == (26, (8, 26 + MAX_NEW, trainer.model_cfg.d_model))
+    cache = np.asarray(trainer._trunk_cache)
+    assert not cache[:, :q - 8].any() and cache[:, q - 8:].any(axis=-1).all()
+    # row for row the fill's state over the tokens as the batch lays them out
+    rows = np.asarray(chunk.trunk_rows)
+    tokens = jnp.concatenate([chunk.query_tensors, chunk.response_tensors], axis=1)
+    want = np.asarray(_filled_trunk(trainer, tokens))
+    real = np.asarray(tokens) != trainer.tokenizer.pad_token_id
+    np.testing.assert_allclose(cache[rows][real], want[real], rtol=1e-5, atol=1e-6)
+    loss_fn = jax.jit(trainer.make_loss_fn())
+    l_c, _ = loss_fn(trainer.train_params, trainer.frozen_params, chunk)
+    l_f, _ = loss_fn(trainer.train_params, trainer.frozen_params, _uncached(chunk))
+    np.testing.assert_allclose(float(l_c), float(l_f), rtol=1e-6)
+
+
 def test_whiten_with_mask_both_behaviors(trainer, chunk):
     """Satellite: method.whiten_with_mask. Default OFF keeps the
     reference's unmasked whitening (advantage mean ~0 over ALL positions
@@ -471,6 +654,35 @@ def test_the_counter_span_says_what_every_dispatch_resumed_from(trainer, monkeyp
         ("ppo.trunk_cache", dict(blocks=2, cached_blocks=1, rows=8)),
         ("ppo.trunk_cache", dict(blocks=2, cached_blocks=0, rows=8)),
     ]
+
+
+@pytest.mark.parametrize("chunk_size, scored", [(8, 8), (4, 0)], ids=["one_chunk", "two_chunks"])
+def test_the_counter_span_says_where_the_caches_rows_came_from(
+        tmp_path, monkeypatch, chunk_size, scored):
+    """`trlx:ppo.trunk_rows rows=.. scored=..` once a collection while a
+    profiler session listens (read by bench/metrics/ppo.trunk_scored_share.json);
+    off a session the hot path formats nothing."""
+    from trlx_tpu.observability import tracing
+
+    tr = _make_trainer(tmp_path, chunk_size=chunk_size, devices=1)
+
+    def no_counters(name, **kv):
+        raise AssertionError(f"{name} formatted off a session")
+
+    monkeypatch.setattr(tracing, "counters", no_counters)
+    tr.make_experience(8)
+    tr.store.clear_history()
+    seen = []
+    monkeypatch.setattr(
+        tracing, "counters",
+        lambda name, **kv: name == "ppo.trunk_rows" and seen.append(kv))
+    monkeypatch.setattr(tracing, "active", lambda: True)
+    tr.make_experience(8)
+    assert seen == [dict(rows=8, scored=scored)]
+    # a collection that caches nothing writes nothing
+    _whole_forward(tr).store.clear_history()
+    tr.make_experience(8)
+    assert len(seen) == 1 and tr._trunk_cache is None
 
 
 # ----------------------------------------------------------------------
@@ -610,18 +822,34 @@ def test_a_quarantined_row_leaves_the_other_rows_right(tmp_path):
     # chunk 0 lost row 1, so a third chunk made up the count
     assert rows == [0, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11]
     assert tr._trunk_cache.shape[0] == 12
-    pad, q = tr.tokenizer.pad_token_id, tr._train_query_width(8)
-    for e in tr.store.history:
-        tokens = np.full((1, q + MAX_NEW), pad, np.int32)
-        tokens[0, q - len(e.query_tensor):q] = e.query_tensor
-        tokens[0, q:q + len(e.response_tensor)] = e.response_tensor
-        want = _eager_trunk(tr, jnp.asarray(tokens))[0]
-        real = tokens[0] != pad
-        np.testing.assert_allclose(
-            np.asarray(tr._trunk_cache[e.trunk_row])[real], np.asarray(want)[real],
-            rtol=1e-5, atol=1e-6)
+    _assert_rows_hold_their_tokens_state(tr)
     # and the batches train from them
     host = next(iter(tr.create_train_dataloader()))
     assert set(host.trunk_rows.tolist()) <= set(rows)
     stats = tr.train_minibatch([host])
     assert np.isfinite(float(stats["losses"]["total_loss"]))
+
+
+def test_a_quarantine_that_under_fills_one_chunk_adds_a_filled_block(tmp_path):
+    """A one-chunk collection that a quarantine leaves one row short: the
+    first chunk's state came from the score program, the chunk that makes
+    up the count is dispatched after it and filled at the end, and the two
+    become one cache whose rows train the whole forward's loss."""
+    tr = _make_trainer(tmp_path, devices=1)
+    assert tr._score_hands_out_trunk_state()
+    tr._sentinel = _DropOneRow()
+    fills = _count_fills(tr)
+    tr.make_experience(8)
+    rows = [e.trunk_row for e in tr.store.history]
+    assert rows == [0, *range(2, 16)]
+    assert fills == [(8, 32)] and tr._trunk_scored_rows == 8
+    assert tr._trunk_cache.shape == (16, 32, tr.model_cfg.d_model)
+    _assert_rows_hold_their_tokens_state(tr)
+    loss_fn = jax.jit(tr.make_loss_fn())
+    for host in tr.create_train_dataloader():
+        assert set(host.trunk_rows.tolist()) <= set(rows)
+        batch = tr.batch_to_device(host)
+        assert batch.trunk_cache is tr._trunk_cache
+        l_c, _ = loss_fn(tr.train_params, tr.frozen_params, batch)
+        l_f, _ = loss_fn(tr.train_params, tr.frozen_params, _uncached(batch))
+        np.testing.assert_allclose(float(l_c), float(l_f), rtol=1e-6)

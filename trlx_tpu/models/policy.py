@@ -352,7 +352,10 @@ def forward_policy_and_ref(
     graph. The trunk below `split` runs once; the reference runs only the
     cloned top branch (or, when split == 0, a full pass with the reference
     copy). The reference framework needs two or three separate module
-    forwards for this (accelerate_ppo_trainer.py:414-438)."""
+    forwards for this (accelerate_ppo_trainer.py:414-438). The fourth
+    result is the state entering block `split`, which both branches start
+    from: what a PPO cycle's trunk cache holds (a caller with no use for it
+    drops it, and the compiler with it)."""
     logits, values, h_split = model.apply(
         {"params": params}, tokens, attn_mask, positions, split
     )
@@ -367,4 +370,4 @@ def forward_policy_and_ref(
             ref, tokens, attn_mask, positions,
             use_prompt=False, with_value=False, method=type(model).forward,
         )
-    return logits, values, jax.lax.stop_gradient(ref_logits)
+    return logits, values, jax.lax.stop_gradient(ref_logits), h_split

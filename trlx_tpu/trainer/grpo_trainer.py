@@ -211,7 +211,7 @@ class GRPOTrainer(PPOTrainer):
             params = merge_params(train_params, frozen_params)
             attention_mask = (all_tokens != pad_id).astype(jnp.int32)
             positions = position_ids(attention_mask)
-            logits, _, ref_logits = forward_policy_and_ref(
+            logits, _, ref_logits, _ = forward_policy_and_ref(
                 model, params, ref_params, all_tokens, attention_mask, split, positions
             )
             logprobs = logprobs_of_labels(logits[:, :-1, :], all_tokens[:, 1:])

@@ -184,6 +184,9 @@ class PPOTrainer(TPUTrainer):
             self.setup_rollout_logging(config)
 
         self._score_fn = None
+        # whether `_score_fn` hands out a sixth result on request, the
+        # chunk's trunk state (`_score_hands_out_trunk_state`)
+        self._score_with_trunk_state = False
         # the cycle's trunk cache (`_trunk_cache_available`): the fill's
         # program, the chunks of the collection under way, and the one
         # device array [rollouts, query + response, d] the store's rows index
@@ -191,6 +194,7 @@ class PPOTrainer(TPUTrainer):
         self._trunk_concat_fn = None
         self._trunk_cache_budget = None
         self._trunk_chunks = None
+        self._trunk_scored_rows = 0
         self._trunk_cache = None
         # Disaggregated rollouts (train.rollout_backend="fleet"): lazy
         # ReplicaRouter over the inference replicas; None under the
@@ -449,7 +453,11 @@ class PPOTrainer(TPUTrainer):
     def _build_score_fn(self):
         """Jitted rollout scorer: policy logprobs + values + frozen-ref
         logprobs in one compiled program (the reference runs 2-3 torch
-        forwards, accelerate_ppo_trainer.py:414-446)."""
+        forwards, accelerate_ppo_trainer.py:414-446). Where
+        `_score_hands_out_trunk_state` says so, that one program has a sixth
+        output, the state entering block `split`, and `_score_fn` is the
+        door in front of it: five results to every caller, all six to the
+        one that asks (`_process_chunk`)."""
         model = self.model
         split = self.split
         pad_id = self.tokenizer.pad_token_id
@@ -479,7 +487,7 @@ class PPOTrainer(TPUTrainer):
             params = merge_params(train_params, frozen_params)
             attention_mask = (all_tokens != pad_id).astype(jnp.int32)
             positions = position_ids(attention_mask)
-            logits, values, ref_logits = forward_policy_and_ref(
+            logits, values, ref_logits, h_split = forward_policy_and_ref(
                 model, params, ref_params, all_tokens, attention_mask, split, positions
             )
             logprobs = logprobs_of_labels(logits[:, :-1, :], all_tokens[:, 1:])
@@ -489,9 +497,38 @@ class PPOTrainer(TPUTrainer):
             kl = jnp.exp(log_ratio) - 1 - log_ratio
             mean_kl_per_token = kl.mean()
             mean_kl = kl.sum(1).mean()
-            return logprobs, values[:, :-1], log_ratio, mean_kl, mean_kl_per_token
+            if with_trunk_state:
+                # The state (what `trunk_cache_fill` returns for these
+                # tokens) leaves behind a barrier it shares with the
+                # reference logits, and nobody reads the barrier's logits: a
+                # nudge, and a measured one. gpt2-xl's chunk (128 x 104 x
+                # 1600 bfloat16, 43 MB) fits the chip's fast memory; with the
+                # state as a plain sixth output the TPU compiler keeps the
+                # residual stream there and no longer prefetches the 46
+                # frozen blocks' MLP weights, 2.2 ms a block and 0.109 s a
+                # chunk (0.676 against 0.566 s), and with the barrier it
+                # plans as it does for five outputs (0.570 s). lfm2's chunk
+                # (268 MB) compiles to one plan either way. PERF.md section
+                # 6, PR 40; tests/test_kernels_compile_tpu.py holds the plan.
+                h_split, _ = jax.lax.optimization_barrier((h_split, ref_logits))
+            scored = (logprobs, values[:, :-1], log_ratio, mean_kl, mean_kl_per_token)
+            return (*scored, self._place_trunk_cache(h_split)) if with_trunk_state else scored
 
-        self._score_fn = self._ljit(score, "score", budget=2)
+        # the function's name is the program's in a device trace
+        # (`jit_score`: bench/metrics/ppo.score_s.json), with either width
+        with_trunk_state = self._score_with_trunk_state = self._score_hands_out_trunk_state()
+        program = self._ljit(score, "score", budget=2)
+        if not with_trunk_state:
+            self._score_fn = program
+            return
+
+        def score_door(*args, trunk_state=False):
+            # the state dropped here is freed at once: it never stands
+            # beside a caller that did not ask for it
+            out = program(*args)
+            return out if trunk_state else out[:5]
+
+        self._score_fn = score_door
 
     # ------------------------------------------------------------------
     # Disaggregated rollouts: the fleet backend (train.rollout_backend)
@@ -898,27 +935,34 @@ class PPOTrainer(TPUTrainer):
         # Jitted precompute of logprobs/values/ref KL
         with self._span("ppo.score_dispatch", chunk=chunk):
             if self.seq2seq:
-                logprobs, values, log_ratio, mean_kl, mean_kl_per_token = self._score_fn(
+                scored = self._score_fn(
                     self.train_params, self.frozen_params, self.ref_params,
                     jnp.asarray(prompt_tensors), jnp.asarray(sample_outputs),
                 )
             else:
                 all_tokens = np.concatenate([prompt_tensors, sample_outputs], axis=1)
-                logprobs, values, log_ratio, mean_kl, mean_kl_per_token = self._score_fn(
+                # the collection's first chunk, which a one-chunk recipe
+                # expects to be its last (`_score_hands_out_trunk_state`):
+                # its trunk state stays on the device as the score program
+                # leaves it. A chunk that a quarantine made necessary after
+                # it is filled at the collection's end like any other.
+                take_state = (self._score_with_trunk_state and chunk == 0
+                              and self._trunk_chunks is not None)
+                scored = self._score_fn(
                     self.train_params, self.frozen_params, self.ref_params,
-                    jnp.asarray(all_tokens),
+                    jnp.asarray(all_tokens), **({"trunk_state": True} if take_state else {}),
                 )
         # ONE batched device->host fetch: sequential np.asarray calls
         # each block until their own transfer lands, jax.device_get
-        # pipelines them together.
-        logprobs, values, log_ratio, mean_kl, mean_kl_per_token = jax.device_get(
-            (logprobs, values, log_ratio, mean_kl, mean_kl_per_token)
-        )
+        # pipelines them together. The trunk state is not in it.
+        trunk_state = scored[5:]
+        logprobs, values, log_ratio, mean_kl, mean_kl_per_token = jax.device_get(scored[:5])
         trunk_row0 = None
         if self._trunk_chunks is not None:
             # the chunk's rows of the cycle's trunk cache: its elements
-            # carry their numbers, and the collection's end fills them
-            trunk_row0 = self._note_trunk_chunk(prompt_tensors, sample_outputs)
+            # carry their numbers, and the collection's end fills those
+            # whose state the score program did not hand out
+            trunk_row0 = self._note_trunk_chunk(prompt_tensors, sample_outputs, *trunk_state)
         mean_kl = float(mean_kl)
         mean_kl_per_token = float(mean_kl_per_token)
 
@@ -1580,7 +1624,7 @@ class PPOTrainer(TPUTrainer):
             all_tokens = jnp.concatenate([prompt_tensors, sample_outputs], axis=1)
             attention_mask = (all_tokens != pad_id).astype(jnp.int32)
             positions = position_ids(attention_mask)
-            logits, values, ref_logits = forward_policy_and_ref(
+            logits, values, ref_logits, _ = forward_policy_and_ref(
                 model, params, ref_params, all_tokens, attention_mask, split, positions
             )
             logprobs = logprobs_of_labels(logits[:, :-1, :], all_tokens[:, 1:])
@@ -1813,6 +1857,32 @@ class PPOTrainer(TPUTrainer):
         return not self._trunk_cache_budget or (
             self._trunk_cache_device_bytes() <= self._trunk_cache_budget)
 
+    def _score_hands_out_trunk_state(self) -> bool:
+        """Whether the score program returns the state entering block
+        `split` as one more output, which `_process_chunk` puts into the
+        cycle's trunk cache in the tokens' place: the scorer runs blocks
+        [0, split) over exactly the tokens a fill would, so the collection's
+        end then has nothing to fill. Read once, when the scorer is built
+        (one compiled program a trainer). Of the schedule: the cycle trains
+        from the trunk cache (`_trunk_cache_available`). Of the recipe: a
+        collection is one chunk (`num_rollouts <= chunk_size`). Generation
+        is double-buffered (`_collect_rollouts` dispatches the next chunk's
+        before it fetches this one's), so with one chunk, and only then,
+        nothing is dispatched after a chunk has been scored; a dispatch
+        takes its buffers at once, and a recipe sized to the chip has no
+        room for a chunk's state beside a generation in flight (the
+        benchmark's `pythia-1.4b.ppo-hh`, four chunks: 17-53 MB free, a
+        chunk's state 67 MB; PERF.md section 6, PR 38). Of the trainer: this
+        class's own `score` (GRPO, the pipelined and the sequence-parallel
+        trainers build theirs). Where this says no, the score program has
+        its five outputs and `_close_trunk_cache` fills every chunk."""
+        method = self.config.method
+        return (
+            type(self)._build_score_fn is PPOTrainer._build_score_fn
+            and int(method.num_rollouts) <= max(int(method.chunk_size), 1)
+            and self._trunk_cache_available()
+        )
+
     def _trunk_cache_device_bytes(self) -> int:
         """What one device holds of a cycle's cache at its widest: whole
         chunks of `num_rollouts` rows of `seq_length` states in the
@@ -1896,6 +1966,7 @@ class PPOTrainer(TPUTrainer):
                 and all(e.trunk_row is not None for e in self.store.history)):
             self._trunk_chunks = [self._trunk_cache]
             return
+        self._trunk_scored_rows = 0
         if self._trunk_cache is not None:
             # gone before the collection's first program is dispatched: a
             # dispatch takes its buffers at once and does not wait for
@@ -1908,26 +1979,34 @@ class PPOTrainer(TPUTrainer):
         if self._trunk_cache_available() and len(self.store) == 0:
             self._trunk_chunks = []
 
-    def _note_trunk_chunk(self, prompt_tensors, sample_outputs) -> int:
-        """One scored chunk's tokens, kept on the host until the collection
-        ends (`_close_trunk_cache` fills them); returns the row its first
-        sample will hold in the cycle's cache. The tokens are laid out as
+    def _note_trunk_chunk(self, prompt_tensors, sample_outputs, state=None) -> int:
+        """One scored chunk's place in the cycle's cache; returns the row
+        its first sample will hold there. `state` is the chunk's trunk
+        state where the score program handed it out (a device array in the
+        scorer's layout, which stays where it is); without it the chunk's
+        tokens are kept on the host until the collection ends
+        (`_close_trunk_cache` fills them). The tokens are laid out as
         the loader will lay out the batches that train on them (queries
         padded to the width `create_train_dataloader` buckets them to), so a
         cached row is the state the whole forward of such a batch computes,
         column for column, and the step has nothing to move."""
+        row0 = sum(len(c) for c in self._trunk_chunks)
+        if state is not None:
+            self._trunk_chunks.append(state)
+            self._trunk_scored_rows += len(state)
+            return row0
         q = prompt_tensors.shape[1]
         tokens = _to_batch_columns(
             np.concatenate([prompt_tensors, sample_outputs], axis=1), q,
             self._train_query_width(q), None, self._left_queries(),
             fill=self.tokenizer.pad_token_id)
-        row0 = sum(len(c) for c in self._trunk_chunks)
         self._trunk_chunks.append(tokens)
         return row0
 
     def _close_trunk_cache(self):
         """The collection has ended: one frozen-prefix pass for each of its
-        chunks, over the SAME retokenized tokens the scorer saw, amortized
+        chunks whose state the score program did not hand out, over the
+        SAME retokenized tokens the scorer saw, amortized
         over ppo_epochs inner epochs of suffix-only training, and the
         results as ONE device array [rollouts, query + response, d], which
         every train step of the cycle takes beside its batch and which never
@@ -1936,23 +2015,28 @@ class PPOTrainer(TPUTrainer):
         flight its buffers stand, and the benchmark's `pythia-1.4b.ppo-hh`
         has 17 MB free beside them (my chip runs, PR 38). The device is as
         busy either way; the train steps queue behind the fills. Chunks of
-        different query widths (a prompt pipeline that pads batch by batch)
-        move to the widest first."""
+        different query widths (a prompt pipeline that pads batch by batch),
+        and a scored state whose queries the loader will pad wider, move to
+        the widest first. While a profiler session listens, the counter
+        span that says how many of the cache's rows the score program made."""
         chunks, self._trunk_chunks = self._trunk_chunks, None
         if not chunks:
             return
-        if self._trunk_cache_fn is None:
-            self._trunk_cache_fn = self._build_trunk_cache_fn()
-        chunks = [
-            c if isinstance(c, jax.Array) else self._trunk_cache_fn(
-                self.train_params, self.frozen_params, jnp.asarray(c))
-            for c in chunks]
-        if len(chunks) > 1:
+
+        def fill(tokens):
+            if self._trunk_cache_fn is None:
+                self._trunk_cache_fn = self._build_trunk_cache_fn()
+            return self._trunk_cache_fn(self.train_params, self.frozen_params, jnp.asarray(tokens))
+
+        chunks = [c if isinstance(c, jax.Array) else fill(c) for c in chunks]
+        r = self._trunk_response_width()
+        train_q = lambda h: self._train_query_width(h.shape[1] - r)  # noqa: E731
+        if len(chunks) > 1 or train_q(chunks[0]) + r != chunks[0].shape[1]:
             if self._trunk_concat_fn is None:
-                r, left = self._trunk_response_width(), self._left_queries()
+                left = self._left_queries()
 
                 def trunk_cache_concat(hs):
-                    q = max(h.shape[1] for h in hs) - r
+                    q = max(train_q(h) for h in hs)
                     return self._place_trunk_cache(jnp.concatenate(
                         [_to_batch_columns(h, h.shape[1] - r, q, q + r, left) for h in hs]))
 
@@ -1960,6 +2044,9 @@ class PPOTrainer(TPUTrainer):
                     trunk_cache_concat, "trunk_cache_concat", budget=2)
             chunks = [self._trunk_concat_fn(chunks)]
         self._trunk_cache = chunks[0]
+        if tracing.active():
+            tracing.counters("ppo.trunk_rows", rows=len(self._trunk_cache),
+                             scored=self._trunk_scored_rows)
 
     def _bind_shared(self, batch):
         """The cycle's trunk cache beside the rows a placed batch names (one
@@ -2040,7 +2127,7 @@ class PPOTrainer(TPUTrainer):
             all_tokens = jnp.concatenate([prompt_tensors, trimmed], axis=1)
             attention_mask = (all_tokens != pad_id).astype(jnp.int32)
             positions = position_ids(attention_mask)
-            logits, values, ref_logits = forward_policy_and_ref(
+            logits, values, ref_logits, _ = forward_policy_and_ref(
                 model, params, ref_params, all_tokens, attention_mask, split, positions
             )
             logprobs = logprobs_of_labels(logits[:, :-1, :], all_tokens[:, 1:])

@@ -242,7 +242,7 @@ class SequenceParallelPPOTrainer(PPOTrainer):
         spec = self._sp_spec()
 
         def local_score(params, ref_params, tokens, mask, positions, labels):
-            logits, values, ref_logits = forward_policy_and_ref(
+            logits, values, ref_logits, _ = forward_policy_and_ref(
                 model, params, ref_params, tokens, mask, split, positions
             )
             lp = logprobs_of_labels(logits, labels)

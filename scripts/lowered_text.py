@@ -43,7 +43,10 @@ import numpy as np  # noqa: E402
 PPO_CELLS = ("pythia-1.4b.ppo-hh", "gpt2-xl.ppo-sentiments", "lfm2-8b-a1b.ppo-hh")
 # serve cell -> the (rows, width) of the insert programs whose text is taken
 SERVE_CELLS = {"pythia-1.4b.rollout-batch": ((1, 256), (8, 512)),
-               "laguna-xs.2.rollout-code": ((1, 2048), (2, 4096))}
+               "laguna-xs.2.rollout-code": ((1, 2048), (2, 4096)),
+               "openpangu-ultra-moe-718b.rollout-longctx": ((1, 8192),),
+               # a tree from before PR 41 has no such preset: name the other cells with --cells there
+               "ling-3.0-flash-vl.rollout-reason": ((1, 1024),)}
 
 
 class Lowered(Exception):

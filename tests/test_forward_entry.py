@@ -192,7 +192,10 @@ PER_ROW_USES = {
 
 
 @pytest.mark.parametrize("use", PER_ROW_USES)
-def test_conv_state_is_refused_by_the_per_row_branch_and_by_no_other(lms, use):
+def test_conv_state_goes_through_the_per_row_branch_but_for_speculative_decode(lms, use):
+    """A per-row cache carries a `conv` layer's state as the scalar one does
+    (the engine's pool holds it a slot); a draft step and a verify pass are
+    refused by name, because a mask bit does not roll a state back."""
     cfg, model, params, tokens, _ = lms["lfm2"]
     spec = dict(PER_ROW_USES[use])
     t, verify = spec.pop("t"), spec.pop("verify", False)
@@ -201,8 +204,14 @@ def test_conv_state_is_refused_by_the_per_row_branch_and_by_no_other(lms, use):
     x = jnp.zeros((B, t, cfg.d_model), jnp.float32) if spec.get("start") else tokens[:, :t]
     if verify:
         spec.update(block_start=jnp.zeros((B,), jnp.int32), positions=jnp.zeros((B, t), jnp.int32))
-    with pytest.raises(NotImplementedError, match="convolution state"):
-        _step(model, params, x, _row_cache(scalar), ones, **spec)
+    if use in ("draft", "verify"):
+        with pytest.raises(NotImplementedError, match="speculative decode .* over slot state"):
+            _step(model, params, x, _row_cache(scalar), ones, **spec)
+    else:
+        want = _step(model, params, x, scalar, ones, t > 1)
+        got = _step(model, params, x, _row_cache(scalar), ones)
+        _same(got[0], want[0])
+        _same_cache(got[2], want[2])
     # the scalar cache carries the convolution state through the same ranges of blocks
     if not verify:
         out = _step(model, params, x, scalar, ones, t > 1, **spec)

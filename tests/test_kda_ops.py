@@ -1,0 +1,125 @@
+"""Kimi delta attention three ways (`trlx_tpu/ops/linear_attention.py`): the
+scan over tokens that defines it, the chunked form a forward and a prefill
+run, and the decode kernel (through the Pallas interpreter) stepped over the
+same tokens, on seeded inputs in float32."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from trlx_tpu.ops import linear_attention as la
+
+B, T, H, DK, DV = 2, 83, 4, 16, 8  # 83: a whole chunk of 64, a sub-chunk of 16 and 3 more
+
+
+def inputs(seed, t=T, b=B, slow=False, floor=-5.0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], (b, t, H, DK))) * DK ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, t, H, DK)))
+    v = jax.random.normal(ks[2], (b, t, H, DV))
+    # `slow`: decays near -0.09 a step, so a state forty tokens back still counts
+    g = floor * jax.nn.sigmoid(jax.random.normal(ks[3], (b, t, H, DK)) - (4.0 if slow else 0.0))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, H)))
+    return q, k, v, g, beta
+
+
+def step_through_the_kernel(x, live=None, state=None):
+    b, t = x[0].shape[:2]
+    state = jnp.zeros((b, H, DK, DV), jnp.float32) if state is None else state
+    live = jnp.ones((b, t), jnp.int32) if live is None else live
+    outs = []
+    step = jax.jit(lambda state, *now: la.kda_decode(state, *now, interpret=True))  # one trace for every token
+    for i in range(t):
+        o, state = step(state, *(a[:, i] for a in x), live[:, i])
+        outs.append(o)
+    return jnp.stack(outs, 1), state
+
+
+@pytest.mark.parametrize("slow", [False, True], ids=["published_decay", "slow_decay"])
+def test_scan_chunks_and_kernel_agree(slow):
+    x = inputs(0, slow=slow)
+    o_scan, s_scan = la.kda_recurrent(*x)
+    o_chunk, s_chunk = jax.jit(la.kda_chunked)(*x)
+    o_step, s_step = step_through_the_kernel(tuple(a[:, :40] for a in x))
+    assert float(jnp.abs(o_scan).max()) > 0.1
+    assert np.abs(o_scan - o_chunk).max() < 1e-5 and np.abs(s_scan - s_chunk).max() < 1e-5
+    o_40, s_40 = la.kda_recurrent(*(a[:, :40] for a in x))
+    assert np.abs(o_40 - o_step).max() < 1e-5 and np.abs(s_40 - s_step).max() < 1e-5
+    if slow:  # the first tokens are still in the state forty tokens on
+        _, without = la.kda_recurrent(*(a[:, 4:40] for a in x))
+        assert np.abs(s_40 - without).max() > 1e-2
+
+
+def test_a_chunk_edge_and_a_carried_state():
+    """Two calls of the chunked form, the second from the first's state, cut
+    inside a sub-chunk: the whole sequence's numbers."""
+    x = inputs(1, slow=True)
+    o_whole, s_whole = la.kda_recurrent(*x)
+    o_a, s_a = la.kda_chunked(*(a[:, :37] for a in x))
+    o_b, s_b = la.kda_chunked(*(a[:, 37:] for a in x), state=s_a)
+    assert np.abs(jnp.concatenate([o_a, o_b], 1) - o_whole).max() < 1e-5
+    assert np.abs(s_b - s_whole).max() < 1e-5
+    for chunk in (16, 32):  # other chunk sizes, the same numbers
+        assert np.abs(la.kda_chunked(*x, chunk=chunk)[0] - o_whole).max() < 1e-5
+    with pytest.raises(ValueError, match="multiple of 16"):
+        la.kda_chunked(*x, chunk=24)
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+def test_a_padded_position_is_the_identity(side):
+    """beta = 0 and g = 0 at the padding, on either side of the real tokens:
+    the real tokens' outputs and the final state are the unpadded sequence's."""
+    x = inputs(2, t=50, slow=True)
+    pad = lambda a: jnp.pad(a, ((0, 0), (13, 0) if side == "left" else (0, 13)) + ((0, 0),) * (a.ndim - 2))
+    q, k, v, g, beta = (pad(a) for a in x)
+    real = slice(13, None) if side == "left" else slice(0, 50)
+    # garbage where the padding is, but for what makes it the identity
+    noise = jax.random.normal(jax.random.PRNGKey(9), q.shape)
+    q, k = (jnp.where(jnp.zeros_like(a).at[:, real].set(1) > 0, a, noise) for a in (q, k))
+    o_want, s_want = la.kda_recurrent(*x)
+    for form in (la.kda_recurrent, la.kda_chunked):
+        o, s = form(q, k, v, g, beta)
+        assert np.abs(o[:, real] - o_want).max() < 1e-5 and np.abs(s - s_want).max() < 1e-5
+
+
+def test_the_published_lower_bound_on_every_channel_does_not_overflow():
+    """g = -5 on every channel for 64 positions: a `k / cumprod` over the chunk
+    would need e^320; the sub-chunk references keep every factor within e^+-40."""
+    q, k, v, g, beta = inputs(3, t=64, b=1)
+    g = jnp.full_like(g, -5.0)
+    o_scan, s_scan = la.kda_recurrent(q, k, v, g, beta)
+    o, s = la.kda_chunked(q, k, v, g, beta)
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
+    assert np.abs(o - o_scan).max() < 1e-5 and np.abs(s - s_scan).max() < 1e-5
+    grads = jax.grad(lambda g: la.kda_chunked(q, k, v, g, beta)[0].sum())(g)
+    assert bool(jnp.isfinite(grads).all())
+
+
+def test_the_chunked_form_differentiates_like_the_scan():
+    x = inputs(4, t=40, slow=True)
+    loss = lambda form: lambda *a: (form(*a)[0] ** 2).sum() + form(*a)[1].sum()
+    want = jax.grad(loss(la.kda_recurrent), argnums=(0, 1, 2, 3, 4))(*x)
+    got = jax.grad(loss(la.kda_chunked), argnums=(0, 1, 2, 3, 4))(*x)
+    for a, b, name in zip(want, got, "q k v g beta".split()):
+        assert float(jnp.abs(a).max()) > 1e-3, name
+        assert np.abs(a - b).max() < 1e-5, name
+
+
+def test_the_kernel_leaves_a_masked_row_s_state_to_the_bit_and_the_plain_step_agrees():
+    x = inputs(5, t=6, b=3, slow=True)
+    state = jax.random.normal(jax.random.PRNGKey(7), (3, H, DK, DV))
+    live = jnp.asarray([1, 0, 1], jnp.int32)
+    now = tuple(a[:, 0] for a in x)
+    o, new = la.kda_decode(state, *now, live, interpret=True)
+    assert np.array_equal(new[1], state[1]) and not np.array_equal(new[0], state[0])
+    assert float(jnp.abs(o[1]).max()) == 0.0
+    o_plain, new_plain = la.kda_decode_step(state, *now, live, None)
+    assert np.abs(o - o_plain).max() < 1e-6 and np.abs(new - new_plain).max() < 1e-6
+    assert np.array_equal(new_plain[1], state[1])
+    # the compiled kernel's tiling: whole groups of 32 heads of 128-multiples; a caller that
+    # asks for it elsewhere is told (the engine asks `decode_kernel_takes` first and counts)
+    assert la.decode_kernel_takes(32, 128, 128) and not la.decode_kernel_takes(4, 16, 16)
+    with pytest.raises(ValueError, match="groups of 32 heads"):
+        la.kda_decode_step(state, *now, live, "pallas")

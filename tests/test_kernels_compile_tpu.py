@@ -563,6 +563,105 @@ def test_longctx_cell_programs_compile_for_the_chip_and_fit_it(v5e, longctx_cell
     assert held < 15.0e9, held
 
 
+def test_kda_decode_compiles_at_the_cell_s_slot_pool_and_writes_the_state_in_place(v5e):
+    """`kda_decode` at `ling-3.0-flash-vl.rollout-reason`'s pool, [128 slots, 32
+    heads, 128, 128] float32: Mosaic takes the in-kernel transpose and the lane
+    broadcasts, the donated state is the result's (268 MB aliased, no temporary
+    of that size) and the call carries its name."""
+    from trlx_tpu.ops import linear_attention
+
+    rows, heads, dim = 128, 32, 128
+    one = SingleDeviceSharding(v5e[0])
+    vec = lambda *shape, dtype=F32: S(shape, dtype, sharding=one)
+    args = (vec(rows, heads, dim, dim), vec(rows, heads, dim), vec(rows, heads, dim), vec(rows, heads, dim),
+            vec(rows, heads, dim), vec(rows, heads), vec(rows, dtype=I32))
+    compiled = jax.jit(linear_attention.kda_decode, donate_argnums=(0,)).trace(*args).lower(
+        lowering_platforms=("tpu",)).compile()
+    assert kernel_names(compiled) == ["kda_decode"]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == rows * heads * dim * dim * 4
+    assert memory.temp_size_in_bytes < 1 << 20, memory.temp_size_in_bytes
+
+
+REASON_CELL = dict(n_tbl=(1024 + 2048) // 32)
+
+
+@pytest.fixture(scope="module")
+def reason_cell_engine(v5e):
+    """A paged `InferenceEngine` as `ling-3.0-flash-vl.rollout-reason` builds
+    it, at the configuration file's own cut (one period of 6 layers, 64 of 512
+    experts held, an eighth of the vocabulary) and no weights."""
+    import json
+    import os
+
+    from trlx_tpu.inference import InferenceEngine
+    from trlx_tpu.models import CausalLMPolicy, config_from_preset
+    from trlx_tpu.ops.sampling import GenerationConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    name = "ling-3.0-flash-vl"
+    bench = json.load(open(os.path.join(root, "bench", "configs", f"{name}.json")))["bench"]
+    cell = json.load(open(os.path.join(root, "bench", "workloads", f"{name}.rollout-reason.json")))["engine"]
+    extra = dict(bench["program"]["model_extra_configs"])
+    cfg = config_from_preset(name, extra.pop("vocab_size"), **extra, param_dtype=BF16, dtype=BF16)
+    model = CausalLMPolicy(cfg)
+    tokens = jnp.zeros((1, 32), I32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
+    gen_cfg = GenerationConfig(max_new_tokens=2048, do_sample=True,
+                               eos_token_id=cfg.vocab_size + 1, pad_token_id=0)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(InferenceEngine, "_param_devices", lambda self: [v5e[0]])
+        engine = InferenceEngine(
+            model, cfg, None, gen_cfg, kv_paging=True, num_slots=cell["num_slots"],
+            max_prompt_len=cell["max_prompt_len"], max_prefill_batch=cell["max_prefill_batch"],
+            prompt_bucket=cell["prompt_bucket"], kv_block_size=cell["kv_block_size"],
+            kv_pool_blocks=cell["kv_pool_blocks"], kv_cache_dtype=cell["kv_cache_dtype"])
+    assert engine.decode_path == "pallas" and engine._n_tbl == REASON_CELL["n_tbl"]
+    return engine, params
+
+
+@pytest.mark.parametrize("program", ["decode", "paged_insert"])
+def test_reason_cell_programs_compile_for_the_chip_and_fit_it(v5e, reason_cell_engine, pallas_mode, program):
+    """`ling-3.0-flash-vl.rollout-reason`'s decode step and its widest prefill
+    (one row of 1,024, the fresh-prompt program) at the published widths: one
+    `kda_decode` a linear layer and one absorbed paged call for the latent one,
+    the prompt's recurrence in chunks under XLA and its latent layer through
+    the flash forward, three grouped products an expert layer, neither the
+    arena nor a slot-state array copied, and arguments plus temporaries under
+    15.0 GB: 4.73 GB of weights, 1.39 GB of slot state, 0.45 GB of arena and
+    the program's own."""
+    engine, params = reason_cell_engine
+    one = SingleDeviceSharding(v5e[0])
+    pool = abstract(engine._pool, one)
+    params = abstract(params, one)
+    if program == "decode":
+        compiled = engine._decode_fn.trace(params, pool).lower(lowering_platforms=("tpu",)).compile()
+        want = {"kda_decode": 5, "paged_decode_latent": 1, "moe_gmm": 15}
+    else:
+        rows, width = 1, 1024
+        shapes = dict(ids=(rows, width), tmask=(rows, width), tables=(rows, REASON_CELL["n_tbl"]),
+                      slot_ids=(rows,), max_new=(rows,), shared_len=(rows,))
+        compiled = engine._get_paged_insert(rows, width, True).trace(
+            pool, params, *(S(shape, I32, sharding=one) for shape in shapes.values())
+        ).lower(lowering_platforms=("tpu",)).compile()
+        want = {"flash_fwd_latent": 1, "moe_gmm": 15}
+    names = kernel_names(compiled)
+    assert {n: names.count(n) for n in set(names)} == want
+    # the convolutions' tails (9 MB a layer) are shifted, so written anew, every step by
+    # their nature; the arena and the recurrent matrices must stay where they lie
+    arenas = [a for layer in engine._pool["layers"] for name, a in layer.items() if name != "tails"]
+    assert len(arenas) == 5 + 1
+    assert arena_rewrites(compiled, *arenas) == []
+    assert donated_outputs(compiled) >= len(arenas)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    print(f"{program}: arguments {memory.argument_size_in_bytes}, temporaries {memory.temp_size_in_bytes}, "
+          f"held {held}")
+    assert held < 15.0e9, held
+
+
 LAYOUTS = [(4, 1, 1), (2, 1, 2), (1, 2, 2)]  # (data, fsdp, tensor)
 
 

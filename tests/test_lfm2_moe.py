@@ -276,16 +276,24 @@ def test_hf_round_trip_on_a_random_state_dict(tmp_path):
         assert getattr(again, field) == getattr(cfg, field), field
 
 
-def test_the_per_row_paths_refuse_the_convolution_state_by_name():
+def test_the_per_row_paths_carry_the_convolution_state_and_refuse_what_cannot_follow_it():
+    """The paged engine holds a `conv` layer's last inputs a slot beside its
+    arena (`cfg.layer_keeps`); what would share a slot's state through block
+    tables or roll it back by mask bits is refused by name (the engine's own
+    refusals over slot state: tests/test_ling_flash.py, both presets)."""
     from trlx_tpu.inference import InferenceEngine
     from trlx_tpu.models import CausalLMPolicy
     from trlx_tpu.ops.sampling import GenerationConfig, make_generate_fn
 
     cfg = tiny_cfg()
     gen_cfg = GenerationConfig(max_new_tokens=4, eos_token_id=VOCAB + 1)
-    with pytest.raises(NotImplementedError, match="convolution state"):
+    engine = InferenceEngine(CausalLMPolicy(cfg), cfg, None, gen_cfg, num_slots=2, max_prompt_len=8, kv_paging=True)
+    assert [sorted(layer) for layer in engine._pool["layers"]] == [
+        ["conv"], ["conv"], ["k", "v"], ["conv"], ["conv"], ["conv"]]
+    assert engine._pool["layers"][0]["conv"].shape == (2, 2, 64)
+    with pytest.raises(NotImplementedError, match="dense slot pool .* over slot state"):
         InferenceEngine(CausalLMPolicy(cfg), cfg, None, gen_cfg, num_slots=2, max_prompt_len=8)
-    with pytest.raises(NotImplementedError, match="convolution state"):
+    with pytest.raises(NotImplementedError, match="needs its number of slots"):
         init_paged_kv_arena(cfg, 4, 8)
     with pytest.raises(NotImplementedError, match="convolution state"):
         make_generate_fn(CausalLMWithValueHead(cfg), cfg, gen_cfg, spec_k=2, spec_split=4,
@@ -295,6 +303,45 @@ def test_the_per_row_paths_refuse_the_convolution_state_by_name():
     with pytest.raises(NotImplementedError, match="MoE"):
         make_generate_fn(CausalLMWithValueHead(moe_only), moe_only, gen_cfg, spec_k=2, spec_split=4,
                          spec_draft_head=(jnp.zeros((64, 4)), jnp.zeros((4, VOCAB))))
+
+
+@pytest.mark.parametrize("path", ["interpret", "xla"])
+def test_the_engine_gives_what_generate_gives(path):
+    """`lfm2-tiny` through the paged engine (a right-padded fresh-prompt
+    insert that leaves each row's convolution tails in its slot, then decode
+    steps that shift them in place) against the fused sampler over its
+    scalar-index cache: the same greedy tokens, the same logprobs."""
+    from trlx_tpu.inference import InferenceEngine
+    from trlx_tpu.models import CausalLMPolicy
+    from trlx_tpu.ops.sampling import GenerationConfig, make_generate_fn
+
+    cfg = tiny_cfg(attn_impl="flash")
+    model = CausalLMWithValueHead(cfg)
+    rng = np.random.default_rng(21)
+    lens, width, new = [13, 2, 7], 13, 12
+    tokens = rng.integers(1, VOCAB, size=(3, width)).astype(np.int32)
+    mask = np.asarray([[0] * (width - n) + [1] * n for n in lens], np.int32)
+    tokens = tokens * mask
+    params = seeded_params(model, 21, jnp.asarray(tokens), jnp.asarray(mask))
+    gen_cfg = GenerationConfig(max_new_tokens=new, do_sample=False, eos_token_id=VOCAB + 1, pad_token_id=0)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(make_generate_fn(model, cfg, gen_cfg, capture=True))(
+            params, jnp.asarray(tokens), jnp.asarray(mask), jax.random.PRNGKey(0))
+        engine = InferenceEngine(CausalLMPolicy(cfg), cfg, {"lm": params["lm"]}, gen_cfg, kv_paging=True,
+                                 num_slots=3, max_prompt_len=16, max_prefill_batch=2, prompt_bucket=8,
+                                 kv_block_size=4, decode_kernel=path)
+        engine.insert_requests([(tokens[r, width - n:], new) for r, n in enumerate(lens)], [0, 1, 2])
+        got_tokens, got_lp = [[] for _ in lens], [[] for _ in lens]
+        for _ in range(new):
+            tok, lp, emitted, _ = engine.step()
+            for r in range(3):
+                if emitted[r]:
+                    got_tokens[r].append(int(tok[r]))
+                    got_lp[r].append(float(lp[r]))
+    assert engine.kv_stats()["kv_kernel_fallbacks"] == {} and engine.decode_path == path
+    assert np.array_equal(np.asarray(got_tokens), np.asarray(want["samples"])[:, width:])
+    assert np.abs(np.asarray(got_lp) - np.asarray(want["logprobs"])).max() < 1e-5
+    assert engine.kv_stats()["slot_state_bytes_per_slot"] == 5 * 2 * 64 * 4
 
 
 def test_flops_and_cache_bytes_follow_the_layer_kinds():

@@ -488,7 +488,7 @@ def test_paths_that_cannot_follow_refuse_by_name():
     with pytest.raises(NotImplementedError, match="value heads narrower"):
         jax.grad(lambda a: flash_attention(a, q, v).sum())(q)
     with pytest.raises(ValueError, match="q_lora_rank"):
-        tiny_cfg(q_lora_rank=0)
+        tiny_cfg(q_lora_rank=-1)  # 0 or None is a full-rank query (tests/test_ling_flash.py)
     with pytest.raises(NotImplementedError, match="latent_attention layers with lora_rank"):
         tiny_cfg(lora_rank=4, moe_experts=0, moe_router="softmax", moe_shared_d_ff=0, moe_routed_scale=1.0,
                  moe_local_experts=0)

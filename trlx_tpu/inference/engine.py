@@ -54,7 +54,6 @@ from trlx_tpu.models.transformer import (
     init_paged_kv_arena,
     moe_stats_from_state,
     prefill_fuses,
-    refuse_conv_state,
 )
 from trlx_tpu.observability import tracing
 from trlx_tpu.ops.quant import dequantize_tree
@@ -88,7 +87,18 @@ def _refuse_over_latent_cache(model_cfg, what: str) -> None:
         raise NotImplementedError(f"{what} over a latent cache (latent_attention layers) is not supported")
 
 
+def _refuse_over_slot_state(model_cfg, what: str) -> None:
+    """A layer that keeps a state a slot (`LayerKeeps.slot`: a convolution's
+    last inputs, a recurrent matrix) has nothing a block table can share and
+    nothing a mask bit can roll back: what would need either is refused by name."""
+    if getattr(model_cfg, "has_slot_state", False):
+        raise NotImplementedError(
+            f"{what} over slot state (conv / linear_attention layers keep a state a slot, "
+            "not planes a token) is not supported")
+
+
 def _refuse_over_attention_kinds(model_cfg, what: str) -> None:
+    _refuse_over_slot_state(model_cfg, what)
     _refuse_over_latent_cache(model_cfg, what)
     kinds = getattr(model_cfg, "attention_kinds", ())
     if kinds:
@@ -214,7 +224,6 @@ class InferenceEngine:
             raise NotImplementedError(
                 "slot-pool decode under prompt/prefix tuning is unsupported"
             )
-        refuse_conv_state(model_cfg, "InferenceEngine")
         # untested over sliding layers, so refused by name: a cached or
         # retained prefix resumes a prefill behind blocks it did not write,
         # and a draft's rollback clears mask bits a band reads
@@ -223,6 +232,7 @@ class InferenceEngine:
             if on:
                 _refuse_over_attention_kinds(model_cfg, what)
         if _KV_DTYPES.get(kv_cache_dtype) == jnp.int8:
+            _refuse_over_slot_state(model_cfg, "an int8 arena (kv_cache_dtype='int8')")
             _refuse_over_latent_cache(model_cfg, "an int8 arena (kv_cache_dtype='int8')")
         if gen_cfg.num_beams > 1:
             raise NotImplementedError("beam search is not servable slot-wise")
@@ -307,6 +317,15 @@ class InferenceEngine:
         # below keep the hot path allocation-free.
         self.trace_buf: Optional[List] = None
 
+        # what each layer keeps (`LayerKeeps`): `token` planes are read
+        # through a block table, `slot` arrays lie one row a slot beside the
+        # arena. A seq2seq config names no layer kinds and is never paged.
+        keeps = getattr(model_cfg, "layer_keeps", None)
+        self._layer_keeps = [keeps(i) for i in range(model_cfg.n_layers)] if keeps else []
+        self._slot_state_layers = sum(1 for k in self._layer_keeps if k.slot)
+        # bytes of slot state a row holds over all layers (0 for K/V and latent layers)
+        self._slot_state_bytes_per_slot = (
+            model_cfg.slot_state_bytes_per_slot(self.kv_cache_dtype) if self._slot_state_layers else 0)
         self._params = params
         self._param_lock = threading.Lock()
         self._param_version = 0
@@ -325,10 +344,13 @@ class InferenceEngine:
         if self.kv_paging:
             # paged mode: per-layer arenas shared by every slot + one
             # block table per slot; mask/pos/row_index stay dense per-slot
-            # (they are tiny). Table entries default to the zero block.
+            # (they are tiny). Table entries default to the zero block. A
+            # layer's slot state (`cfg.layer_keeps(i).slot`) lies beside its
+            # arena, one row a slot: an insert overwrites the row, the decode
+            # program moves it on in place, nobody ever clears it.
             layers = init_paged_kv_arena(
                 model_cfg, self._n_blocks, self.kv_block_size,
-                dtype=self.kv_cache_dtype,
+                dtype=self.kv_cache_dtype, num_slots=P,
             )
             cache = {
                 "layers": layers,
@@ -422,7 +444,7 @@ class InferenceEngine:
         env = kernels_env()
         if self.decode_kernel == "xla" or env == "off":
             return None
-        if "interpret" in (self.decode_kernel, env):
+        if self._interprets_kernels():
             return "interpret"
         devices = self._param_devices()
         demanded = ("inference.decode_kernel='pallas'" if self.decode_kernel == "pallas"
@@ -466,7 +488,19 @@ class InferenceEngine:
             return "kv_paging_off"
         if getattr(cfg, "alibi", False):
             return "alibi"
+        if getattr(cfg, "has_linear_layers", False) and not self._interprets_kernels():
+            from trlx_tpu.ops.linear_attention import decode_kernel_takes
+
+            # the compiled `kda_decode` tiles whole groups of heads; the
+            # interpreter takes any shape
+            if not decode_kernel_takes(cfg.n_heads, cfg.head_dim, cfg.head_dim):
+                return "kda_decode_tiling"
         return None
+
+    def _interprets_kernels(self) -> bool:
+        from trlx_tpu.ops.attention import kernels_env
+
+        return "interpret" in (self.decode_kernel, kernels_env())
 
     def _ljit(self, fn, name: str, budget: int = 1, **jit_kwargs):
         """Engine jit entry point — plain jax.jit when no compile ledger
@@ -646,6 +680,7 @@ class InferenceEngine:
             model, S, P = self.model, self._cache_len, self.num_slots
             sample_fused = self._sample_fused
             mt = self.multi_tenant
+            layer_keeps = self._layer_keeps
 
             def insert(pool, params, ids, tmask, tables, slot_ids, max_new,
                        shared_len, stack=None, aidx=None):
@@ -657,7 +692,13 @@ class InferenceEngine:
                 # a cached prefix is already resident in blocks
                 # tables[:, : shared_len // block], so only its mask bits
                 # need seeding — prefill resumes at column shared_len
-                layers = [dict(al, table=tables) for al in pool["layers"]]
+                # a layer's slot state starts from nothing (a fresh prompt:
+                # nothing is shared over slot state), one row a request
+                layers = [
+                    {**{k2: v2 for k2, v2 in al.items() if k2 not in keeps.slot_names},
+                     **{k2: jnp.zeros((pb, *al[k2].shape[1:]), al[k2].dtype) for k2 in keeps.slot_names},
+                     **({"table": tables} if keeps.token else {})}
+                    for al, keeps in zip(pool["layers"], layer_keeps)]
                 seed_mask = (
                     jnp.arange(S)[None, :] < shared_len[:, None]
                 ).astype(jnp.int32)
@@ -680,9 +721,12 @@ class InferenceEngine:
                 )[:, 0].astype(jnp.float32)
                 rng, key_ = jax.random.split(pool["rng"])
                 token, lp = sample_fused(last, key_, 0)
+                # the arena is the pool's; a row's final slot state goes into
+                # its slot, over whatever a finished request left there
                 arena = [
-                    {k2: v2 for k2, v2 in layer.items() if k2 != "table"}
-                    for layer in new_cache["layers"]
+                    {k2: (al[k2].at[slot_ids].set(v2) if k2 in keeps.slot_names else v2)
+                     for k2, v2 in layer.items() if k2 != "table"}
+                    for layer, al, keeps in zip(new_cache["layers"], pool["layers"], layer_keeps)
                 ]
                 # padding rows carry slot_id == num_slots and all-OOB
                 # tables: both their arena writes (inside the model's step)
@@ -1089,7 +1133,8 @@ class InferenceEngine:
                 # route every layer through the slot block tables; decode
                 # never remaps blocks, so the tables pass through
                 cache["layers"] = [
-                    dict(al, table=pool["table"]) for al in cache["layers"]
+                    dict(al, table=pool["table"]) if keeps.token else al
+                    for al, keeps in zip(cache["layers"], self._layer_keeps)
                 ]
             variables = {"params": params}
             if mt:
@@ -1470,6 +1515,8 @@ class InferenceEngine:
         if tracing.active():
             if self.kv_paging:
                 tracing.counters("engine.kv_walk", **self._kv_walk())
+            if self._slot_state_layers:
+                tracing.counters("engine.slot_state", **self._slot_state_step())
             tracing.counters("engine.queued", seq=seq, ahead=int(ahead), rows=int(self._live.sum()))
         with tracing.span("engine.dispatch"):
             if self.spec_k > 0:
@@ -1596,6 +1643,15 @@ class InferenceEngine:
             cols += ahead.rows[slots]
         return np.minimum(cols, self._cache_len)
 
+    def _slot_state_step(self) -> Dict[str, int]:
+        """What the decode step being dispatched does to slot state: every
+        live row's arrays a slot are read whole and written whole (`bytes` is
+        both, over the live rows and the layers that keep such state; a row
+        with no request is left as it lies)."""
+        live = int(self._live.sum())
+        return {"steps": 1, "slots": self.num_slots, "live": live, "layers": self._slot_state_layers,
+                "bytes": 2 * live * self._slot_state_bytes_per_slot}
+
     def _kv_walk(self) -> Dict[str, int]:
         """Key positions the next decode step reads against the positions
         resident, over the slots with a request, summed over the attention
@@ -1610,7 +1666,7 @@ class InferenceEngine:
         the host's own count of each slot's columns; the step adds its one."""
         cfg, blk = self.model_cfg, self.kv_block_size
         cols = self._next_columns()
-        ops = [cfg.layer_op(i) for i in range(cfg.n_layers) if cfg.layer_op(i) != "conv"]
+        ops = [cfg.layer_op(i) for i in range(cfg.n_layers) if self._layer_keeps[i].token]
         n_latent = ops.count("latent_attention")
         windows = [cfg.window_of(op) for op in ops if op != "latent_attention"]
         last = -(-cols // blk)
@@ -1651,6 +1707,7 @@ class InferenceEngine:
         kv_bytes = paged_arena_bytes(
             cfg, self._n_blocks, self.kv_block_size, dtype=jnp.dtype(self.kv_cache_dtype))
         walk = self._kv_walk()
+        per_slot = self._slot_state_bytes_per_slot
         with self._kv_lock:
             pool = self._block_pool
             return {
@@ -1664,6 +1721,9 @@ class InferenceEngine:
                 "kv_blocks_free": pool.available(),
                 "kv_blocks_used": pool.in_use(),
                 "kv_pool_bytes": int(kv_bytes),
+                # what the layers keep a slot beside the arena (0 for K/V and latent layers)
+                "slot_state_bytes_per_slot": per_slot,
+                "slot_state_bytes": per_slot * self.num_slots,
                 "prefix_cache_hits": pool.hits,
                 "prefix_cache_misses": pool.misses,
                 "prefix_cache_evictions": pool.evictions,

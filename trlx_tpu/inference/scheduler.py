@@ -345,6 +345,10 @@ class Scheduler:
         the prompt's KV blocks. All-or-nothing against queue depth."""
         if n < 1:
             raise ValueError(f"n must be >= 1, got {n}")
+        if n > 1 and getattr(getattr(self.engine, "model_cfg", None), "has_slot_state", False):
+            raise NotImplementedError(
+                "submit_n's shared prompt over slot state (conv / linear_attention layers) is not supported: "
+                "a state a slot cannot be shared through block tables; submit the prompt n times")
         ids, max_new = self._validate(
             prompt_ids, max_new_tokens, adapter_id, stop_sequences
         )
@@ -989,6 +993,8 @@ class Scheduler:
             "kv_pool_bytes", "kv_bytes_per_token", "prefix_cache_idle_blocks", "kv_live_entry_share",
         ):
             self.metrics.set_gauge(name, stats[name])
+        for name in ("slot_state_bytes", "slot_state_bytes_per_slot"):
+            self.metrics.set_gauge(name, stats.get(name, 0))
         for name in stats:
             # by layer kind: key positions read over positions resident; a
             # sparse-expert model's dispatch counters of the last decode step

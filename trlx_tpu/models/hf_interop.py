@@ -59,6 +59,8 @@ def _family_of(hf: Dict) -> str:
         return "laguna"
     if mt == "pangu_ultra_moe":
         return "pangu_ultra_moe"
+    if "layer_group_size" in hf and "kda_lower_bound" in hf:  # the published config names no model_type here
+        return "ling_flash"
     for fam, keys in (
         ("gpt_bigcode", ("bigcode",)),
         ("gpt_neox", ("neox",)),
@@ -187,6 +189,8 @@ def config_from_hf(path: str, **overrides):
         kwargs = _laguna_kwargs(hf)
     elif fam == "pangu_ultra_moe":
         kwargs = _pangu_kwargs(hf)
+    elif fam == "ling_flash":
+        kwargs = _ling_kwargs(hf)
     kwargs["hf_family"] = fam
     kwargs.update(overrides)
     return TransformerConfig(**cut_to_depth(kwargs, overrides))
@@ -257,6 +261,52 @@ def _pangu_kwargs(hf: Dict) -> Dict:
         moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=hf["first_k_dense_replace"],
         moe_router="sigmoid", moe_shared_d_ff=hf["moe_intermediate_size"] * hf.get("n_shared_experts", 1),
         moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+    )
+
+
+def _ling_kwargs(hf: Dict) -> Dict:
+    """Ling-3.0-flash's config keys (the language model of Ling-3.0-flash-VL)
+    -> TransformerConfig fields: periods of `layer_group_size` layers, the last
+    of each latent attention and the others Kimi delta attention, group-limited
+    sigmoid routing. Config keys only: the checkpoint's tensor names are not
+    public, so `load_params_from_hf` and the export refuse the family by name.
+    What the keys do not settle is listed in bench/reference/ling_flash.py.
+    A key that would change a layer's equations from what is written here is
+    refused by name, a non-zero `swiglu_limit` among them (its form is not in
+    the config)."""
+    for key, want in (("score_function", "sigmoid"), ("moe_router_enable_expert_bias", True),
+                      ("norm_topk_prob", True), ("use_qk_norm", True), ("linear_silu", True),
+                      ("gated_attention_proj_granularity_type", "head_wise"), ("group_norm_size", 1),
+                      ("kda_safe_gate", True), ("no_kda_lora", True), ("use_kda_lora", False),
+                      ("num_kv_heads_for_linear_attn", 0), ("use_mla_nope", False), ("use_nGPT", False),
+                      ("scale_router_input", False), ("value_norm", False), ("up_proj_norm", False)):
+        if hf.get(key, want) != want:
+            raise NotImplementedError(f"ling_flash with {key}={hf[key]!r} is not supported")
+    n = hf["num_hidden_layers"]
+    for key in ("expert_swiglu_limit_list", "share_expert_swiglu_limit_list"):
+        if any(hf.get(key, ())[:n]):
+            raise NotImplementedError(
+                f"ling_flash with a non-zero swiglu limit ({key}={list(hf[key][:n])}) is not supported: "
+                "the clamp's form is not in the config")
+    if hf["head_dim"] != hf["qk_nope_head_dim"] or hf["head_dim"] != hf["v_head_dim"]:
+        raise NotImplementedError("ling_flash with a linear-attention head unlike the latent layers' is not supported")
+    period = hf["layer_group_size"]
+    return dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], head_width=hf["head_dim"], d_ff=hf["intermediate_size"],
+        max_seq_len=hf["max_position_embeddings"], pos_embed="rope", rope_theta=float(hf["rope_theta"]),
+        norm="rmsnorm", layer_norm_epsilon=hf.get("rms_norm_eps", 1e-6), activation="silu", glu=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)), use_bias=False, flash_prefill=True,
+        layer_types=tuple("latent_attention" if (i + 1) % period == 0 else "linear_attention" for i in range(n)),
+        q_lora_rank=hf.get("q_lora_rank") or 0, kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"], qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"], qk_norm=True, attn_gate="per_head",
+        conv_kernel=hf["short_conv_kernel_size"], kda_lower_bound=float(hf["kda_lower_bound"]),
+        moe_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_tok"],
+        moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=hf["first_k_dense_replace"],
+        moe_router="sigmoid", moe_shared_d_ff=hf.get("moe_shared_expert_intermediate_size", 0),
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_n_group=hf.get("n_group", 0), moe_topk_group=hf.get("topk_group", 0),
     )
 
 
@@ -1199,6 +1249,8 @@ def infer_family(cfg) -> str:
         return "t5"
     if getattr(cfg, "has_conv_layers", False):
         return "lfm2_moe"
+    if getattr(cfg, "has_linear_layers", False):
+        return "ling_flash"
     if getattr(cfg, "has_latent_layers", False):
         return "pangu_ultra_moe"
     if getattr(cfg, "attention_kinds", ()):
@@ -1291,6 +1343,27 @@ def config_to_hf(cfg: TransformerConfig, family: str = None) -> Dict:
             mlp_layer_types=["dense" if i < cfg.moe_dense_layers else "sparse" for i in range(cfg.n_layers)],
             moe_routed_scaling_factor=cfg.moe_routed_scale,
             num_attention_heads_per_layer=list(cfg.layer_heads),
+        )
+    if family == "ling_flash":
+        period = next((i + 1 for i, kind in enumerate(cfg.layer_types) if kind == "latent_attention"), cfg.n_layers + 1)
+        return dict(
+            vocab_size=cfg.vocab_size, hidden_size=cfg.d_model, intermediate_size=cfg.d_ff,
+            num_hidden_layers=cfg.n_layers, num_attention_heads=cfg.n_heads, num_key_value_heads=cfg.n_heads,
+            head_dim=cfg.head_dim, max_position_embeddings=cfg.max_seq_len, rms_norm_eps=cfg.layer_norm_epsilon,
+            rope_theta=cfg.rope_theta, q_lora_rank=cfg.q_lora_rank or None, kv_lora_rank=cfg.kv_lora_rank,
+            qk_nope_head_dim=cfg.qk_nope_head_dim, qk_rope_head_dim=cfg.qk_rope_head_dim,
+            v_head_dim=cfg.v_head_dim, rotary_dim=cfg.qk_rope_head_dim, use_qk_norm=cfg.qk_norm,
+            layer_group_size=period, short_conv_kernel_size=cfg.conv_kernel, linear_silu=True,
+            kda_safe_gate=True, kda_lower_bound=cfg.kda_lower_bound,
+            no_kda_lora=True, use_kda_lora=False, group_norm_size=1, num_kv_heads_for_linear_attn=0,
+            gated_attention_proj_granularity_type="head_wise",
+            first_k_dense_replace=cfg.moe_dense_layers, num_experts=cfg.moe_experts,
+            num_experts_per_tok=cfg.moe_top_k, moe_intermediate_size=cfg.expert_d_ff,
+            moe_shared_expert_intermediate_size=cfg.moe_shared_d_ff, n_group=cfg.moe_n_group,
+            topk_group=cfg.moe_topk_group, score_function="sigmoid", moe_router_enable_expert_bias=True,
+            norm_topk_prob=True, routed_scaling_factor=cfg.moe_routed_scale,
+            expert_swiglu_limit_list=[0] * cfg.n_layers, share_expert_swiglu_limit_list=[0] * cfg.n_layers,
+            tie_word_embeddings=cfg.tie_embeddings,
         )
     if family == "pangu_ultra_moe":
         return dict(

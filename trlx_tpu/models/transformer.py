@@ -41,6 +41,31 @@ class RopeSpec:
 
 
 ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention", "latent_attention")
+# operators that keep a state a ROW (a slot), not planes a token
+SLOT_STATE_KINDS = ("conv", "linear_attention")
+
+
+@dataclass(frozen=True)
+class LayerKeeps:
+    """What one layer keeps between steps, the one description every cache
+    is built from (`init_kv_cache`, `init_paged_kv_arena`, the engine's pool,
+    `observability.hbm`): `token` planes, (name, shape a token), in the
+    cache's type; `slot` arrays, (name, shape a row, type or None for the
+    cache's), which a row owns whole and a step overwrites."""
+
+    token: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
+    slot: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
+
+    @property
+    def slot_names(self) -> Tuple[str, ...]:
+        return tuple(name for name, _, _ in self.slot)
+
+    def slot_arrays(self, rows: int, cache_dtype) -> Dict[str, jnp.ndarray]:
+        return {name: jnp.zeros((rows, *shape), dtype or cache_dtype) for name, shape, dtype in self.slot}
+
+    def slot_bytes(self, cache_dtype) -> int:
+        """Bytes a row holds here."""
+        return sum(int(np.prod(shape)) * jnp.dtype(dtype or cache_dtype).itemsize for _, shape, dtype in self.slot)
 
 
 @dataclass(frozen=True)
@@ -86,8 +111,9 @@ class TransformerConfig:
     # few experts without it).
     moe_aux_coef: float = 0.01
     # Layers of more than one kind (LFM2-style hybrids). `layer_types` names
-    # each layer's operator, "attention" or "conv" (the gated short
-    # convolution, `ShortConv`); empty = attention everywhere, and then none
+    # each layer's operator, "attention", "conv" (the gated short
+    # convolution, `ShortConv`) or "linear_attention" (`KimiDeltaAttention`,
+    # below); empty = attention everywhere, and then none
     # of the fields below is read. `moe_dense_layers` leading layers keep
     # the dense MLP (width d_ff) in a model whose other layers hold experts
     # (width moe_d_ff, d_ff when None).
@@ -143,7 +169,8 @@ class TransformerConfig:
     # ONE plane of `latent_width` values a token, not K and V by head:
     # `cache_planes(i)` says what layer i caches, and `kv_heads` / `head_dim`
     # describe the K/V layers only.
-    q_lora_rank: int = 0
+    # `q_lora_rank` 0 or None: one full-rank `q_proj` in place of the pair.
+    q_lora_rank: Optional[int] = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -157,15 +184,31 @@ class TransformerConfig:
     # embedding of the next token and gives logits for the token after it.
     # `forward(..., mtp=True)` runs them; no cached step does.
     mtp_layers: int = 0
+    # Group-limited routing (`SparseMoE`, DeepSeek-V3's `noaux_tc`): the
+    # experts lie in `moe_n_group` equal groups, a group scores the sum of its
+    # two largest biased scores, and a token chooses its `moe_top_k` among the
+    # experts of its `moe_topk_group` best groups. 0: no groups.
+    moe_n_group: int = 0
+    moe_topk_group: int = 0
+    # Kimi delta attention (`layer_types` kind "linear_attention",
+    # `KimiDeltaAttention`): `n_heads` heads of `head_dim` for keys and values
+    # alike, causal depthwise convolutions of `conv_kernel` taps on q, k and
+    # v, a log-decay a key channel bounded below by `kda_lower_bound`.
+    # Such a layer keeps a matrix a head and the convolutions' last inputs a
+    # ROW, nothing a token (`layer_keeps`); `kda_state_dtype` is the matrix's.
+    kda_lower_bound: float = -5.0
+    kda_state_dtype: Any = jnp.float32
 
     def __post_init__(self):
+        if self.q_lora_rank is None:
+            object.__setattr__(self, "q_lora_rank", 0)
         if self.layer_types:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if len(self.layer_types) != self.n_layers:
                 raise ValueError(
                     f"layer_types names {len(self.layer_types)} layers, n_layers is {self.n_layers}"
                 )
-            unknown = set(self.layer_types) - {"conv", *ATTENTION_KINDS}
+            unknown = set(self.layer_types) - {*SLOT_STATE_KINDS, *ATTENTION_KINDS}
             if unknown:
                 raise ValueError(f"unknown layer_types {sorted(unknown)}")
         if self.layer_heads:
@@ -182,22 +225,38 @@ class TransformerConfig:
         if self.attn_gate not in ("none", "per_head"):
             raise ValueError(f"attn_gate must be 'none' or 'per_head', got {self.attn_gate!r}")
         if self.has_latent_layers:
-            sizes = ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
+            sizes = ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
             missing = [n for n in sizes if getattr(self, n) <= 0]
-            if missing:
-                raise ValueError(f"latent_attention layers need {missing} (a full-rank query, "
-                                 "q_lora_rank=0, is not written)")
+            if missing or self.q_lora_rank < 0:
+                raise ValueError(f"latent_attention layers need {missing or ['q_lora_rank']} "
+                                 "(q_lora_rank 0 or None is a full-rank query, never a negative rank)")
             if self.qk_rope_head_dim % 2:
                 raise ValueError(f"qk_rope_head_dim {self.qk_rope_head_dim} must be even")
             unsupported = [what for on, what in (
                 (self.pos_embed != "rope", f"pos_embed={self.pos_embed!r}"), (self.alibi, "alibi"),
                 (self.lora_rank > 0, "lora_rank"), (self.prefix_tokens > 0, "prefix_tokens"),
-                (self.attn_gate != "none", "attn_gate"), (self.qk_norm, "qk_norm"),
                 (self.sliding_window is not None, "sliding_window"),
                 (self.attn_impl in ("ring", "blockwise"), f"attn_impl={self.attn_impl!r}"),
             ) if on]
             if unsupported:
                 raise NotImplementedError(f"latent_attention layers with {', '.join(unsupported)} are not supported")
+        if self.has_linear_layers:
+            unsupported = [what for on, what in (
+                (self.lora_rank > 0, "lora_rank"), (self.prefix_tokens > 0, "prefix_tokens"),
+                (self.prompt_tokens > 0, "prompt_tokens"), (self.attn_impl == "ring", "attn_impl='ring'"),
+            ) if on]
+            if unsupported:
+                raise NotImplementedError(f"linear_attention layers with {', '.join(unsupported)} are not supported")
+        if bool(self.moe_n_group) != bool(self.moe_topk_group) or self.moe_n_group < 0:
+            raise ValueError(f"moe_n_group {self.moe_n_group} and moe_topk_group {self.moe_topk_group} go together")
+        if self.moe_n_group:
+            if self.moe_router != "sigmoid":
+                raise NotImplementedError("group-limited routing (moe_n_group) needs moe_router='sigmoid'")
+            if self.moe_experts % self.moe_n_group or self.moe_topk_group > self.moe_n_group \
+                    or self.moe_top_k > self.moe_topk_group * (self.moe_experts // self.moe_n_group):
+                raise ValueError(
+                    f"{self.moe_experts} experts in {self.moe_n_group} groups, {self.moe_topk_group} groups and "
+                    f"{self.moe_top_k} experts a token do not fit")
         if self.sandwich_norm and self.parallel_residual:
             raise NotImplementedError("sandwich_norm under parallel_residual is not supported")
         if (self.moe_shared_d_ff or self.moe_routed_scale != 1.0) and self.moe_router != "sigmoid":
@@ -291,21 +350,51 @@ class TransformerConfig:
         return "latent_attention" in self.layer_types
 
     @property
+    def has_linear_layers(self) -> bool:
+        return "linear_attention" in self.layer_types
+
+    @property
+    def has_slot_state(self) -> bool:
+        """Whether some layer keeps a state a row (`LayerKeeps.slot`): what a
+        block table cannot share, a mask bit cannot roll back and a pool must
+        hold beside its arena."""
+        return any(k in SLOT_STATE_KINDS for k in self.layer_types)
+
+    @property
+    def kda_width(self) -> int:
+        """Channels of the three short convolutions of a KDA layer together."""
+        return 3 * self.n_heads * self.head_dim
+
+    @property
     def latent_width(self) -> int:
         """Values a latent layer caches a token: the normed latent and the
         rotated key all heads share."""
         return self.kv_lora_rank + self.qk_rope_head_dim
 
-    def cache_planes(self, i: int) -> Tuple[int, ...]:
-        """What a token caches in layer i, one width a plane: K and V by
-        head for an attention layer, the one latent plane for a latent one,
-        nothing a token for a `conv` layer (its state is a row's)."""
+    def layer_keeps(self, i: int) -> LayerKeeps:
+        """What layer i keeps: K and V by head a token for an attention
+        layer, the one latent plane a token for a latent one; the last
+        `conv_kernel - 1` inputs a row for a `conv` layer; a matrix a head
+        (`kda_state_dtype`) and the three convolutions' last inputs a row for
+        a `linear_attention` one."""
         op = self.layer_op(i)
         if op == "conv":
-            return ()
+            return LayerKeeps(slot=(("conv", (self.conv_kernel - 1, self.d_model), None),))
+        if op == "linear_attention":
+            return LayerKeeps(slot=(
+                ("state", (self.n_heads, self.head_dim, self.head_dim), self.kda_state_dtype),
+                ("tails", (self.conv_kernel - 1, self.kda_width), None)))
         if op == "latent_attention":
-            return (self.latent_width,)
-        return (self.kv_heads * self.head_dim,) * 2
+            return LayerKeeps(token=(("latent", (self.latent_width,)),))
+        return LayerKeeps(token=(("k", (self.kv_heads, self.head_dim)), ("v", (self.kv_heads, self.head_dim))))
+
+    def cache_planes(self, i: int) -> Tuple[int, ...]:
+        """What a token caches in layer i, one width a plane (`layer_keeps`)."""
+        return tuple(int(np.prod(shape)) for _, shape in self.layer_keeps(i).token)
+
+    def slot_state_bytes_per_slot(self, cache_dtype) -> int:
+        """Bytes of slot state one row holds over all layers."""
+        return sum(self.layer_keeps(i).slot_bytes(cache_dtype) for i in range(self.n_layers))
 
     @property
     def cached_values_per_token(self) -> int:
@@ -327,7 +416,7 @@ class TransformerConfig:
         """Whether a cached step must hand its blocks the validity of the
         incoming tokens: a convolution state that a masked step may not
         move, experts that masked tokens are not dispatched to."""
-        return self.has_conv_layers or self.has_sparse_moe
+        return self.has_slot_state or self.has_sparse_moe
 
     def layer_op(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "attention"
@@ -807,16 +896,34 @@ class LatentAttention(nn.Module):
         rope = cfg.rope_of(self.kind) or RopeSpec(theta=cfg.rope_theta)
         scale = 1.0 / np.sqrt(dn + dr)
 
-        q = dense(nh * (dn + dr), "q_b_proj")(norm("q_a_norm")(dense(cfg.q_lora_rank, "q_a_proj")(h)))
+        if cfg.q_lora_rank:
+            q = dense(nh * (dn + dr), "q_b_proj")(norm("q_a_norm")(dense(cfg.q_lora_rank, "q_a_proj")(h)))
+        else:
+            q = dense(nh * (dn + dr), "q_proj")(h)
         q = q.reshape(b, t, nh, dn + dr)
+        # `qk_norm`: over each head's whole query and over the shared rotary key, before
+        # rotation: the cached latent holds the normed key, the absorbed form stays exact
+        if cfg.qk_norm:
+            q = norm("q_norm")(q)
         q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta, spec=rope)
         kv_a = dense(dc + dr, "kv_a_proj")(h)
         c = norm("kv_a_norm")(kv_a[..., :dc])
-        k_rope = apply_rope(kv_a[:, :, None, dc:], positions, cfg.rope_theta, spec=rope)  # [b, t, 1, dr]
+        k_rope = kv_a[:, :, None, dc:]  # [b, t, 1, dr]
+        if cfg.qk_norm:
+            k_rope = norm("k_norm")(k_rope)
+        k_rope = apply_rope(k_rope, positions, cfg.rope_theta, spec=rope)
         # W_uk and W_uv are views of this one leaf, cut where they are used
         w_kvb = _Kernel((dc, nh * (dn + dv)), cfg.param_dtype, name="kv_b_proj")()
         w_kvb = w_kvb.astype(cfg.dtype).reshape(dc, nh, dn + dv)
-        project_out = lambda out: dense(d, "o_proj")(out.reshape(b, t, nh * dv))
+        gate = None
+        if cfg.attn_gate == "per_head":  # as `Attention`'s: sigmoid(x W_g)_h on head h's output
+            gate = jax.nn.sigmoid(dense(nh, "gate_proj")(h).astype(jnp.float32)).astype(cfg.dtype)
+
+        def project_out(out):  # [b, t, nh, dv]
+            if gate is not None:
+                out = out * gate[..., None]
+            return dense(d, "o_proj")(out.reshape(b, t, nh * dv))
+
         latent = jnp.concatenate([c, k_rope[:, :, 0]], axis=-1)  # [b, t, dc + dr]: what is cached
 
         def absorbed_query():
@@ -973,7 +1080,7 @@ class ShortConv(nn.Module):
     cfg: TransformerConfig
 
     @nn.compact
-    def __call__(self, h, layer_cache=None, token_mask=None):
+    def __call__(self, h, layer_cache=None, token_mask=None, cache_index=None):
         cfg = self.cfg
         b, t, d = h.shape
         taps = cfg.conv_kernel
@@ -992,11 +1099,110 @@ class ShortConv(nn.Module):
         conv = sum(w[j] * jax.lax.dynamic_slice_in_dim(padded, j, t, axis=1) for j in range(taps))
         new_cache = None
         if layer_cache is not None:
-            state = padded[:, t:].astype(layer_cache["conv"].dtype)  # the last K-1 of history + z
-            if t == 1 and token_mask is not None:
-                state = jnp.where(token_mask[:, :1, None] > 0, state, layer_cache["conv"])
+            if t > 1 and jnp.ndim(cache_index) == 1:
+                # a per-row cache's prefill is RIGHT-padded: a row's tail ends at its own last token
+                state = tail_inputs(padded, token_mask, taps).astype(layer_cache["conv"].dtype)
+            else:
+                state = padded[:, t:].astype(layer_cache["conv"].dtype)  # the last K-1 of history + z
+                if t == 1 and token_mask is not None:
+                    state = jnp.where(token_mask[:, :1, None] > 0, state, layer_cache["conv"])
             new_cache = {"conv": state}
         return dense(d, "out_proj")(gate_c * conv), new_cache
+
+
+def tail_inputs(padded, token_mask, taps: int):
+    """The `taps - 1` inputs that end at each row's last real position:
+    `padded` [b, taps - 1 + t, w] is the row's history followed by the
+    block's inputs (0 at masked positions), `token_mask` [b, t] or None (all
+    real). Left padding, right padding and a row with no token at all (its
+    history is kept) are one rule: the slice starts `end` entries in, `end`
+    the number of positions up to the last real one."""
+    t = padded.shape[1] - (taps - 1)
+    if token_mask is None:
+        return padded[:, t:]
+    end = ((token_mask > 0) * (jnp.arange(t) + 1)).max(-1)
+    return jax.vmap(lambda row, e: jax.lax.dynamic_slice_in_dim(row, e, taps - 1, axis=0))(padded, end)
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi delta attention (KDA, `ops/linear_attention.py`). With x the
+    normed input, H heads of d = `cfg.head_dim` and no bias on any product:
+
+        q~, k~, v~ = x W_q, x W_k, x W_v
+        q, k, v = SiLU(conv(q~)), SiLU(conv(k~)), SiLU(conv(v~))      depthwise, causal, `conv_kernel` taps
+        q^ = q / |q|_2 * d^-1/2,  k^ = k / |k|_2                       a head
+        g = kda_lower_bound * sigmoid(exp(a_h) * (x W_f + b_dt))      a key channel
+        beta = sigmoid(x W_b)                                          a head
+        S_t = (I - beta k^ k^^T) Diag(exp(g)) S_{t-1} + beta k^ v^T;  o = S_t^T q^
+        y = W_o [ RMSNorm(o_h) * sigmoid(x W_g)_h ]
+
+    A position whose mask bit is 0 is the identity: its input is zeroed
+    before the products (so its convolution input is 0) and its beta and g
+    are 0. The layer keeps `state` [b, H, d, d] and `tails` [b, taps - 1, 3 H d]
+    (the last inputs of the three convolutions side by side) a ROW, and nothing a
+    token (`cfg.layer_keeps`). A block of positions goes through the chunked
+    form from the row's state; one position a row with a kernel asked for
+    (`attn_kernel` "pallas" | "interpret") through `kda_decode`, in place."""
+
+    cfg: TransformerConfig
+    kind: Optional[str] = None
+    n_heads: Optional[int] = None
+
+    @nn.compact
+    def __call__(self, h, attn_bias, positions, layer_cache=None, cache_index=None, attn_mask=None,
+                 use_prefix=True, attn_kernel=None):
+        from trlx_tpu.ops import linear_attention as kda
+
+        cfg = self.cfg
+        b, t, d = h.shape
+        nh, hd, taps = cfg.n_heads, cfg.head_dim, cfg.conv_kernel
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
+        valid = None if attn_mask is None else (attn_mask > 0)
+        if valid is not None:
+            h = h * valid[..., None].astype(h.dtype)
+        z = jnp.concatenate([dense(nh * hd, n)(h) for n in ("q_proj", "k_proj", "v_proj")], axis=-1)
+        # [taps, channels] each, the fan-in leading like every `kernel`. From here to the
+        # gate everything is float32 (elementwise over 3 H d channels): the recurrence
+        # takes its inputs in float32 anyway, and the tails keep the products' own type
+        w = jnp.concatenate([_Kernel((taps, nh * hd), cfg.param_dtype, name=n)()
+                             for n in ("q_conv", "k_conv", "v_conv")], axis=-1).astype(jnp.float32)
+        history = (jnp.zeros((b, taps - 1, 3 * nh * hd), z.dtype) if layer_cache is None
+                   else layer_cache["tails"].astype(z.dtype))
+        padded = jnp.concatenate([history, z], axis=1)
+        conv = sum(w[j] * jax.lax.dynamic_slice_in_dim(padded, j, t, axis=1).astype(jnp.float32)
+                   for j in range(taps))
+        q, k, v = (x.reshape(b, t, nh, hd) for x in jnp.split(jax.nn.silu(conv), 3, axis=-1))
+        unit = lambda x: x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+        q, k = unit(q) * hd ** -0.5, unit(k)
+        a = _Bias((nh,), cfg.param_dtype, name="a_log")().astype(jnp.float32)
+        f = dense(nh * hd, "f_proj")(h).astype(jnp.float32) \
+            + _Bias((nh * hd,), cfg.param_dtype, name="dt_bias")().astype(jnp.float32)
+        f = f.reshape(b, t, nh, hd)
+        rate = jnp.exp(a)[:, None]
+        g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * f)
+        beta = jax.nn.sigmoid(dense(nh, "b_proj")(h).astype(jnp.float32))
+        if valid is not None:
+            g, beta = g * valid[..., None, None], beta * valid[..., None]
+
+        state = None if layer_cache is None else layer_cache["state"]
+        if state is not None and t == 1:
+            live = jnp.ones((b,), jnp.int32) if valid is None else valid[:, 0].astype(jnp.int32)
+            o, new_state = kda.kda_decode_step(
+                state.astype(jnp.float32), q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], live,
+                attn_kernel if attn_kernel in ("pallas", "interpret") else None)
+            o = o[:, None]
+        else:
+            o, new_state = kda.kda_chunked(q, k, v, g, beta, state)
+        new_cache = None
+        if layer_cache is not None:
+            new_cache = {"state": new_state.astype(state.dtype),
+                         "tails": tail_inputs(padded, valid, taps).astype(layer_cache["tails"].dtype)}
+        scale = _Scale((hd,), cfg.param_dtype, name="o_norm")().astype(jnp.float32)
+        o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.layer_norm_epsilon) * scale
+        gate = jax.nn.sigmoid(dense(nh, "gate_proj")(h).astype(jnp.float32))
+        out = (o * gate[..., None]).astype(cfg.dtype).reshape(b, t, nh * hd)
+        return dense(d, "o_proj")(out), new_cache
 
 
 class _Kernel(nn.Module):
@@ -1020,6 +1226,15 @@ class _Bias(nn.Module):
     @nn.compact
     def __call__(self):
         return self.param("bias", nn.initializers.zeros, self.shape, self.param_dtype)
+
+
+class _Scale(nn.Module):
+    shape: Tuple[int, ...]
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self):
+        return self.param("scale", nn.initializers.ones, self.shape, self.param_dtype)
 
 
 class SparseMoE(nn.Module):
@@ -1056,6 +1271,7 @@ class SparseMoE(nn.Module):
             stack("expert_gate", (d, G * f)), stack("expert_up", (d, G * f)), stack("expert_down", (f, G * d)),
             top_k=cfg.moe_top_k, offset=cfg.moe_local_offset, act=activation_fn(cfg),
             token_mask=None if token_mask is None else token_mask.reshape(b * t),
+            n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
         )
         self.sow("moe_stats", "stats", stats)
         out = out.reshape(b, t, d)
@@ -1111,11 +1327,12 @@ class Block(nn.Module):
         cfg = self.cfg
         h_ln = make_norm(cfg, "ln_attn")(h)
         if self.op_kind == "conv":
-            attn_out, new_cache = ShortConv(cfg, name="conv")(h_ln, layer_cache, attn_mask)
+            attn_out, new_cache = ShortConv(cfg, name="conv")(h_ln, layer_cache, attn_mask, cache_index)
         else:
             if isinstance(attn_bias, dict):  # a bias for each kind of attention layer
-                attn_bias = attn_bias[self.op_kind]
-            attn_cls = LatentAttention if self.op_kind == "latent_attention" else Attention
+                attn_bias = attn_bias.get(self.op_kind)
+            attn_cls = {"latent_attention": LatentAttention,
+                        "linear_attention": KimiDeltaAttention}.get(self.op_kind, Attention)
             attn_out, new_cache = attn_cls(cfg, kind=self.op_kind, n_heads=self.n_heads, name="attn")(
                 h_ln, attn_bias, positions, layer_cache, cache_index, attn_mask, use_prefix,
                 attn_kernel,
@@ -1554,7 +1771,10 @@ class TransformerLM(nn.Module):
                     "a per-row cache (slot pool, paged insert, speculative decode) "
                     "under prompt/prefix tuning is unsupported"
                 )
-            refuse_conv_state(cfg, "decode_step with a per-row cache")
+            if cfg.has_slot_state and (positions is not None or not to_head):
+                raise NotImplementedError(
+                    "speculative decode (a draft step, a verify pass) over slot state: rejected drafts "
+                    "roll back by clearing mask bits, which does not undo a convolution's or a recurrence's state")
         if capture_split is not None and cfg.prompt_tokens > 0:
             raise NotImplementedError(
                 "split-activation capture under prompt tuning is unsupported "
@@ -1665,37 +1885,19 @@ def position_ids(attn_mask: jnp.ndarray) -> jnp.ndarray:
     return jnp.clip(jnp.cumsum(attn_mask.astype(jnp.int32), axis=-1) - 1, 0, None)
 
 
-def refuse_conv_state(cfg: TransformerConfig, what: str) -> None:
-    """The per-row cache (slot pool, paged arena, speculative rollback)
-    holds K/V tables only: a convolution state is a second kind of
-    per-slot state that it can neither place by row offset nor roll back
-    by clearing mask bits."""
-    if getattr(cfg, "has_conv_layers", False):
-        raise NotImplementedError(
-            f"{what}: the convolution state of a `conv` layer (layer_types) is not "
-            "supported here; only `decode_step` on a scalar-`index` cache (the fused "
-            "sampler) carries it"
-        )
-
-
 def init_kv_cache(cfg: TransformerConfig, batch_size: int, max_len: int, dtype=None):
-    """Allocate an empty functional cache: K/V tables for an attention
-    layer, the last `conv_kernel - 1` inputs of the convolution for a
-    `conv` layer. Under prompt tuning the soft prompt occupies the first
-    cfg.prompt_tokens cache slots (written by the prefill), so the cache is
-    allocated that much longer."""
+    """Allocate an empty functional cache from what each layer keeps
+    (`cfg.layer_keeps`): its planes a token, `[batch, max_len, *shape]`, and
+    its arrays a row, `[batch, *shape]`. Under prompt tuning the soft prompt
+    occupies the first cfg.prompt_tokens cache slots (written by the
+    prefill), so the cache is allocated that much longer."""
     dtype = dtype or cfg.dtype
     max_len = max_len + getattr(cfg, "prompt_tokens", 0)
 
     def layer(i):
-        if cfg.layer_op(i) == "conv":
-            return {"conv": jnp.zeros((batch_size, cfg.conv_kernel - 1, cfg.d_model), dtype=dtype)}
-        if cfg.layer_op(i) == "latent_attention":
-            return {"latent": jnp.zeros((batch_size, max_len, cfg.latent_width), dtype=dtype)}
-        return {
-            "k": jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), dtype=dtype),
-            "v": jnp.zeros((batch_size, max_len, cfg.kv_heads, cfg.head_dim), dtype=dtype),
-        }
+        keeps = cfg.layer_keeps(i)
+        planes = {name: jnp.zeros((batch_size, max_len, *shape), dtype=dtype) for name, shape in keeps.token}
+        return {**planes, **keeps.slot_arrays(batch_size, dtype)}
 
     return {
         "index": jnp.asarray(0, dtype=jnp.int32),
@@ -1706,12 +1908,14 @@ def init_kv_cache(cfg: TransformerConfig, batch_size: int, max_len: int, dtype=N
 
 
 def init_paged_kv_arena(
-    cfg: TransformerConfig, num_blocks: int, block_size: int, dtype=None
+    cfg: TransformerConfig, num_blocks: int, block_size: int, dtype=None, num_slots: int = 0
 ):
-    """Allocate the per-layer paged KV arenas: `num_blocks` blocks of
-    `block_size` token columns each, shared by every slot through per-row
-    block tables (Attention's paged branch; a latent layer's is one plane
-    of `cfg.latent_width` values a token, in a floating type only). Block 0 is reserved by the
+    """Allocate what each layer of a paged pool keeps (`cfg.layer_keeps`):
+    for its planes a token, an arena of `num_blocks` blocks of `block_size`
+    token columns shared by every slot through per-row block tables
+    (Attention's paged branch; a latent layer's is one plane of
+    `cfg.latent_width` values a token, in a floating type only); for its
+    arrays a row, `[num_slots, *shape]`, a slot's own. Block 0 is reserved by the
     engine as a permanent zero block backing padding table entries, so it
     is never allocated to a request. int8 arenas carry f32 scale planes
     (per token per kv head, ops/quant.quantize_kv)."""
@@ -1720,15 +1924,24 @@ def init_paged_kv_arena(
         raise NotImplementedError(
             "paged KV cache under prompt/prefix tuning is unsupported"
         )
-    refuse_conv_state(cfg, "paged KV arena")
+    if cfg.has_slot_state and (num_slots <= 0 or jnp.dtype(dtype) == jnp.int8):
+        raise NotImplementedError(
+            "a paged pool over slot state (conv / linear_attention layers) needs its number of slots "
+            "and a floating cache type (an int8 arena would hold the rows' state in int8 too)")
     from trlx_tpu.ops.paged_attention import init_paged_latent_layer, init_paged_layer
 
-    return [
-        init_paged_latent_layer(num_blocks, block_size, cfg.latent_width, dtype)
-        if cfg.layer_op(i) == "latent_attention"
-        else init_paged_layer(num_blocks, block_size, cfg.kv_heads, cfg.head_dim, dtype)
-        for i in range(cfg.n_layers)
-    ]
+    def layer(i):
+        keeps = cfg.layer_keeps(i)
+        names = tuple(name for name, _ in keeps.token)
+        if names == ("latent",):
+            arena = init_paged_latent_layer(num_blocks, block_size, cfg.latent_width, dtype)
+        elif names:
+            arena = init_paged_layer(num_blocks, block_size, cfg.kv_heads, cfg.head_dim, dtype)
+        else:
+            arena = {}
+        return {**arena, **keeps.slot_arrays(num_slots, dtype)}
+
+    return [layer(i) for i in range(cfg.n_layers)]
 
 
 # ---------------------------------------------------------------------------
@@ -1897,6 +2110,39 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=12,
         moe_experts=8, moe_top_k=2, moe_d_ff=32, moe_dense_layers=1, moe_router="sigmoid",
         moe_shared_d_ff=32, moe_routed_scale=2.5,
+    ),
+    # Ling-3.0-flash-VL's language model (inclusionAI; ~125B parameters, ~5.5B
+    # active): periods of five Kimi-delta linear-attention layers (32 heads of
+    # 128, convolutions of 4 taps, a log-decay a key channel bounded below by
+    # -5) and one latent-attention layer (a full-rank query of 128 + 64, a
+    # latent of 512 + 64 rotary dimensions a token, RMSNorm on query and
+    # rotary key), a per-head sigmoid gate on both; 2 dense SwiGLU layers, then
+    # 512 sigmoid-routed experts in 8 groups (4 groups and 8 experts a token,
+    # normalised, scaled 2.5) beside one shared expert. The published sizes; a
+    # cut (depth, dense layers, experts held here, vocabulary) arrives as
+    # model_extra_configs. The vision tower is not part of this preset.
+    "ling-3.0-flash-vl": dict(
+        d_model=2560, n_layers=42, n_heads=32, head_width=128, d_ff=6144, max_seq_len=131072,
+        pos_embed="rope", rope_theta=6000000.0, norm="rmsnorm", layer_norm_epsilon=1e-6,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=tuple("latent_attention" if (i + 1) % 6 == 0 else "linear_attention" for i in range(42)),
+        q_lora_rank=0, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        qk_norm=True, attn_gate="per_head", conv_kernel=4, kda_lower_bound=-5.0,
+        moe_experts=512, moe_top_k=8, moe_d_ff=768, moe_dense_layers=2, moe_router="sigmoid",
+        moe_shared_d_ff=768, moe_routed_scale=2.5, moe_n_group=8, moe_topk_group=4,
+    ),
+    # the same stack at test size: one period of three (two linear layers, one
+    # latent), one dense layer, 16 experts in 4 groups (2 groups and 2 experts a
+    # token) beside a shared one, 4 heads of 16
+    "ling-flash-tiny": dict(
+        d_model=64, n_layers=3, n_heads=4, head_width=16, d_ff=128, max_seq_len=256,
+        pos_embed="rope", rope_theta=6000000.0, norm="rmsnorm", layer_norm_epsilon=1e-6,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=("linear_attention", "linear_attention", "latent_attention"),
+        q_lora_rank=0, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        qk_norm=True, attn_gate="per_head", conv_kernel=4, kda_lower_bound=-5.0,
+        moe_experts=16, moe_top_k=2, moe_d_ff=32, moe_dense_layers=1, moe_router="sigmoid",
+        moe_shared_d_ff=32, moe_routed_scale=2.5, moe_n_group=4, moe_topk_group=2,
     ),
     # Mixture-of-experts (beyond the reference): experts shard over `tensor`
     "moe-tiny": dict(

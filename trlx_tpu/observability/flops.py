@@ -50,14 +50,26 @@ def layer_matmul_flops(model_cfg, i: int) -> float:
         return 8 * d * d + 4 * d * model_cfg.d_ff
     if model_cfg.layer_op(i) == "conv":
         op = 2 * d * 3 * d + 2 * d * d + 2 * model_cfg.conv_kernel * d
+    elif model_cfg.layer_op(i) == "linear_attention":
+        # q, k, v, the decay's and the output projection (5 of d x heads x
+        # width), the write strength and the output gate a head, the three
+        # convolutions' taps, and the recurrence itself: 7 d_k d_v a head a
+        # token (`ops/linear_attention.kda_step`), whatever the context
+        c, hw = model_cfg, model_cfg.n_heads * model_cfg.head_dim
+        op = 2 * 5 * d * hw + 2 * 2 * d * c.n_heads + 2 * c.conv_kernel * c.kda_width \
+            + 7 * c.n_heads * c.head_dim * c.head_dim
     elif model_cfg.layer_op(i) == "latent_attention":
         # decompressed, as a forward or a prefill runs it: the query's
-        # low-rank pair, the latent and shared rotary key, the per-head keys
-        # and values out of the latent, the output projection
+        # low-rank pair (or its one full-rank product), the latent and shared
+        # rotary key, the per-head keys and values out of the latent, the
+        # output projection
         c, heads = model_cfg, _layer_heads(model_cfg, i)
         qk = c.qk_nope_head_dim + c.qk_rope_head_dim
-        op = 2 * (d * c.q_lora_rank + c.q_lora_rank * heads * qk + d * c.latent_width
+        query = d * c.q_lora_rank + c.q_lora_rank * heads * qk if c.q_lora_rank else d * heads * qk
+        op = 2 * (query + d * c.latent_width
                   + c.kv_lora_rank * heads * (c.qk_nope_head_dim + c.v_head_dim) + heads * c.v_head_dim * d)
+        if getattr(model_cfg, "attn_gate", "none") == "per_head":
+            op += 2 * d * heads
     else:
         heads = _layer_heads(model_cfg, i)
         op = 4 * d * heads * model_cfg.head_dim + 4 * d * model_cfg.kv_heads * model_cfg.head_dim
@@ -84,7 +96,7 @@ def layer_attention_flops(model_cfg, i: int, ctx: float) -> float:
     if not getattr(model_cfg, "layer_types", ()):
         return 4 * ctx * model_cfg.d_model
     kind = model_cfg.layer_op(i)
-    if kind == "conv":
+    if kind in ("conv", "linear_attention"):  # nothing grows with the context
         return 0.0
     if kind == "latent_attention":  # scores at the query/key width, values at their own
         return 2 * ctx * _layer_heads(model_cfg, i) * (

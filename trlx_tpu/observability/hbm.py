@@ -127,11 +127,22 @@ def latent_arena_bytes(n_layers: int, latent_width: int, n_blocks: int,
 
 
 def paged_arena_bytes(cfg, n_blocks: int, block_size: int, dtype="float32") -> int:
-    """The paged arena `init_paged_kv_arena` allocates for `cfg`: K and V by
-    head for its attention layers, one latent plane for its latent ones."""
-    n_latent = list(getattr(cfg, "layer_types", ())).count("latent_attention")
-    return (kv_arena_bytes(cfg.n_layers - n_latent, cfg.kv_heads, cfg.head_dim, n_blocks, block_size, dtype)
+    """The paged arena `init_paged_kv_arena` allocates for `cfg`, from what
+    each layer keeps a token (`cfg.layer_keeps`): K and V by head for its
+    attention layers, one latent plane for its latent ones, nothing for a
+    layer whose state is a slot's (`slot_state_bytes` counts that)."""
+    planes = [tuple(name for name, _ in cfg.layer_keeps(i).token) for i in range(cfg.n_layers)]
+    n_latent = planes.count(("latent",))
+    return (kv_arena_bytes(len(planes) - n_latent - planes.count(()), cfg.kv_heads, cfg.head_dim,
+                           n_blocks, block_size, dtype)
             + latent_arena_bytes(n_latent, getattr(cfg, "latent_width", 0), n_blocks, block_size, dtype))
+
+
+def slot_state_bytes(cfg, num_slots: int, dtype="float32") -> int:
+    """What `init_paged_kv_arena` allocates beside the arena: each layer's
+    arrays a slot (`cfg.layer_keeps(i).slot`: a convolution's last inputs in
+    the cache's type, a recurrent matrix in its own), `num_slots` rows."""
+    return int(num_slots) * cfg.slot_state_bytes_per_slot(dtype)
 
 
 def kv_cache_bytes(n_layers: int, kv_heads: int, head_dim: int,
@@ -147,18 +158,12 @@ def decode_state_bytes(cfg, batch: int, cache_len: int, dtype="float32") -> int:
     the attention layers only, one latent plane for each latent layer, plus
     the short convolution's state, the last `conv_kernel - 1` inputs a
     channel, for each `conv` layer."""
-    kinds = list(getattr(cfg, "layer_types", ())) or ["attention"] * cfg.n_layers
     # full and sliding layers alike hold a table of `cache_len` (a sliding
     # layer gives nothing back yet: ROADMAP R4)
-    n_latent = kinds.count("latent_attention")
-    kv = kv_cache_bytes(len(kinds) - kinds.count("conv") - n_latent, cfg.kv_heads, cfg.head_dim,
-                        batch, cache_len, dtype)
-    if n_latent:
-        kv += n_latent * batch * cache_len * cfg.latent_width * _itemsize(dtype)
-    if "conv" not in kinds:
-        return int(kv)
-    conv = kinds.count("conv") * batch * (cfg.conv_kernel - 1) * cfg.d_model * _itemsize(dtype)
-    return int(kv + conv)
+    if not hasattr(cfg, "layer_keeps"):  # a config that names no layer kinds at all
+        return kv_cache_bytes(cfg.n_layers, cfg.kv_heads, cfg.head_dim, batch, cache_len, dtype)
+    return int(batch * cache_len * cfg.cached_values_per_token * _itemsize(dtype)
+               + slot_state_bytes(cfg, batch, dtype))
 
 
 def trunk_cache_bytes(rows: int, seq_len: int, d_model: int,

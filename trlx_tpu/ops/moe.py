@@ -47,14 +47,25 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 # ---------------------------------------------------------------------------
 
 
-def route_sigmoid(x, router_kernel, select_bias, top_k: int):
+def route_sigmoid(x, router_kernel, select_bias, top_k: int, n_group: int = 0, topk_group: int = 0):
     """(top_i [T, k] int32, top_w [T, k] float32). Scores in float32 at
     `highest` precision: a near-tie between two experts decides which
-    matrices a token meets, so the scores may not carry bfloat16's error."""
+    matrices a token meets, so the scores may not carry bfloat16's error.
+    With `n_group` groups (DeepSeek-V3's `noaux_tc`): the experts lie in
+    equal contiguous groups, a group scores the sum of its two largest biased
+    scores, and only the experts of the `topk_group` best groups can be chosen."""
     logits = jnp.matmul(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, top_i = lax.top_k(scores + lax.stop_gradient(select_bias.astype(jnp.float32)), top_k)
+    biased = scores + lax.stop_gradient(select_bias.astype(jnp.float32))
+    if n_group:
+        tokens, experts = biased.shape
+        by_group = biased.reshape(tokens, n_group, experts // n_group)
+        group_score = lax.top_k(by_group, 2)[0].sum(-1)  # [T, n_group]
+        _, kept = lax.top_k(group_score, topk_group)
+        allowed = (kept[:, :, None] == jnp.arange(n_group)[None, None, :]).any(1)  # [T, n_group]
+        biased = jnp.where(allowed[:, :, None], by_group, -jnp.inf).reshape(tokens, experts)
+    _, top_i = lax.top_k(biased, top_k)
     top_w = jnp.take_along_axis(scores, top_i, axis=-1)
     top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-6)
     return top_i.astype(jnp.int32), top_w
@@ -338,7 +349,8 @@ def dispatch(top_i, n_experts_held: int, offset: int, token_mask=None):
 
 
 def sparse_moe(x, router_kernel, select_bias, w_gate, w_up, w_down, *, top_k: int, offset: int = 0,
-               act: Callable = jax.nn.silu, token_mask=None, mode: Optional[str] = None):
+               act: Callable = jax.nn.silu, token_mask=None, mode: Optional[str] = None,
+               n_group: int = 0, topk_group: int = 0):
     """The expert layer on flat tokens: x [T, d]; router_kernel [d, E];
     select_bias [E]; w_gate, w_up [d, G * f] and w_down [f, G * d], the
     matrices of experts [offset, offset + G) side by side, in the compute
@@ -346,7 +358,7 @@ def sparse_moe(x, router_kernel, select_bias, w_gate, w_up, w_down, *, top_k: in
     (y [T, d], stats): y is the part of sum_e w_e W2_e(act(W1_e x) * W3_e
     x) that the experts held give; `stats` are scalars named in STATS."""
     tokens, k, held_n = x.shape[0], top_k, w_down.shape[1] // x.shape[1]
-    top_i, top_w = route_sigmoid(x, router_kernel, select_bias, k)
+    top_i, top_w = route_sigmoid(x, router_kernel, select_bias, k, n_group, topk_group)
     order, inverse, held, group_sizes = dispatch(top_i, held_n, offset, token_mask)
 
     rows = _gather_sorted(x, order, inverse, k)  # [T * k, d]

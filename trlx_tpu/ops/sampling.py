@@ -212,11 +212,11 @@ def make_generate_fn(
                 "supported (the seen-token mask would need per-draft "
                 "rollback)"
             )
-        if getattr(model_cfg, "has_conv_layers", False):
+        if getattr(model_cfg, "has_slot_state", False):
             raise NotImplementedError(
-                "speculative decode with a convolution state (layer_types "
-                "'conv') is not supported: rejected drafts roll back by "
-                "clearing mask bits, which does not undo a recurrent state"
+                "speculative decode with a convolution state or a recurrent matrix "
+                "(layer_types 'conv', 'linear_attention') is not supported: rejected "
+                "drafts roll back by clearing mask bits, which does not undo a state a row"
             )
         if getattr(model_cfg, "moe_experts", 0) > 0:
             raise NotImplementedError(

@@ -1752,7 +1752,7 @@ class PPOTrainer(TPUTrainer):
             not self.seq2seq
             and self.split > 0
             and getattr(self.model_cfg, "moe_experts", 0) == 0
-            and not getattr(self.model_cfg, "has_conv_layers", False)
+            and not getattr(self.model_cfg, "has_slot_state", False)
             and getattr(self.model_cfg, "prompt_tokens", 0) == 0
             and getattr(self.model_cfg, "prefix_tokens", 0) == 0
             and int(gen_kwargs.get("num_beams", 1) or 1) == 1

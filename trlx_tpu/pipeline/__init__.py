@@ -47,6 +47,11 @@ class DataLoader:
 
     Yields collated batches; `collate_fn` defaults to numpy stacking of
     dict fields. Deterministic shuffling via a seed bumped per epoch.
+
+    `group_window` > `batch_size` with a `group_key` (index -> sort key)
+    keeps the epoch's order in windows of that many items and stable-sorts
+    each window by the key before it is cut into batches: a window holds
+    the items it would have held, and items of like key share a batch.
     """
 
     def __init__(
@@ -57,6 +62,8 @@ class DataLoader:
         collate_fn: Optional[Callable] = None,
         drop_last: bool = False,
         seed: int = 0,
+        group_window: int = 0,
+        group_key: Optional[Callable[[int], Any]] = None,
     ):
         self.dataset = dataset
         self.batch_size = batch_size
@@ -64,6 +71,8 @@ class DataLoader:
         self.collate_fn = collate_fn or default_collate
         self.drop_last = drop_last
         self.seed = seed
+        self.group_window = group_window if group_key is not None else 0
+        self.group_key = group_key
         self._epoch = 0
 
     def __len__(self):
@@ -73,18 +82,64 @@ class DataLoader:
         return (n + self.batch_size - 1) // self.batch_size
 
     def __iter__(self):
+        return self.batches()
+
+    def batches(self, skip: int = 0, epoch: Optional[int] = None):
+        """One epoch's batches; the first `skip` are passed over uncollated.
+        `epoch` names the epoch (the shuffle's seed is `seed + epoch`); left
+        out, it is the one after the last."""
+        if epoch is not None:
+            self._epoch = epoch
         indices = list(range(len(self.dataset)))
         if self.shuffle:
             rng = random.Random(self.seed + self._epoch)
             rng.shuffle(indices)
             self._epoch += 1
-        for start in range(0, len(indices), self.batch_size):
+        if self.group_window > self.batch_size:
+            w = self.group_window
+            indices = [i for start in range(0, len(indices), w)
+                       for i in sorted(indices[start : start + w], key=self.group_key)]
+        for start in range(skip * self.batch_size, len(indices), self.batch_size):
             chunk = indices[start : start + self.batch_size]
             if self.drop_last and len(chunk) < self.batch_size:
                 break
             with tracing.span("pipeline.collate", rows=len(chunk)):
                 batch = self.collate_fn([self.dataset[i] for i in chunk])
             yield batch
+
+
+class LoaderStream:
+    """`infinite_dataloader` that knows where it is: batches forever, the
+    loader restarted at exhaustion, with the epoch under way and the
+    batches handed out of it as a `state()` that `restore()` returns to
+    (exact resume: the same batches after a restart as before it)."""
+
+    def __init__(self, loader: DataLoader):
+        self.loader = loader
+        self.epoch = self.position = 0
+        self._batches = None
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        while True:
+            if self._batches is None:
+                self._batches = self.loader.batches(skip=self.position, epoch=self.epoch)
+            batch = next(self._batches, None)
+            if batch is not None:
+                self.position += 1
+                return batch
+            if not self.position:
+                raise StopIteration  # a loader with no batch at all
+            self._batches, self.epoch, self.position = None, self.epoch + 1, 0
+
+    def state(self) -> Dict[str, int]:
+        return {"epoch": self.epoch, "position": self.position}
+
+    def restore(self, state: Dict[str, int]) -> None:
+        self.epoch, self.position = int(state["epoch"]), int(state["position"])
+        self._batches = None
 
 
 def default_collate(items: List[Any]):

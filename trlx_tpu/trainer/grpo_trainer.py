@@ -40,7 +40,7 @@ from trlx_tpu.ops.ppo import group_relative_advantages, grpo_loss
 from trlx_tpu.trainer import register_trainer
 from trlx_tpu.trainer.base_trainer import merge_params
 from trlx_tpu.trainer.ppo_trainer import PPOTrainer
-from trlx_tpu.utils import infinite_dataloader, logging
+from trlx_tpu.utils import logging
 from trlx_tpu.utils.modeling import logprobs_of_labels
 
 logger = logging.get_logger(__name__)
@@ -235,8 +235,7 @@ class GRPOTrainer(PPOTrainer):
         scoring, and scorer all see one row per completion."""
         G = int(self.config.method.group_size)
         prompts_per_chunk = max(self.config.method.chunk_size // G, 1)
-        loader = pipeline.create_loader(prompts_per_chunk, shuffle=True)
-        base = infinite_dataloader(loader)
+        base = self._rollout_stream(pipeline, prompts_per_chunk)
 
         def repeat_rows(v):
             if isinstance(v, np.ndarray):

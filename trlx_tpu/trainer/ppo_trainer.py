@@ -38,10 +38,13 @@ from trlx_tpu.ops.ppo import (
     ppo_loss,
 )
 from trlx_tpu.parallel import infer_param_shardings
+from trlx_tpu.observability.compile_ledger import prepare_jit
+from trlx_tpu.pipeline import LoaderStream
+from trlx_tpu.pipeline.offline_pipeline import prompt_width_ladder
 from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage
 from trlx_tpu.trainer import register_trainer
 from trlx_tpu.trainer.base_trainer import TPUTrainer, merge_params
-from trlx_tpu.utils import Clock, infinite_dataloader
+from trlx_tpu.utils import Clock
 from trlx_tpu.utils import logging
 from trlx_tpu.utils.modeling import RunningMoments, logprobs_of_labels
 
@@ -183,6 +186,12 @@ class PPOTrainer(TPUTrainer):
         if self.log_rollouts:
             self.setup_rollout_logging(config)
 
+        # the rollout loader's stream (`_rollout_stream`), its countdown to
+        # the ladder's programs all being made, and the collection's count of
+        # generate dispatches: [calls, widths, positions, padding positions]
+        self._prompt_stream = None
+        self._ladder_countdown = 0
+        self._prefill_tally = np.zeros(4, np.int64)
         self._score_fn = None
         # whether `_score_fn` hands out a sixth result on request, the
         # chunk's trunk state (`_score_hands_out_trunk_state`)
@@ -819,6 +828,7 @@ class PPOTrainer(TPUTrainer):
         gen_kwargs = self.generate_experience_kwargs or self.generate_kwargs
         max_new = int(gen_kwargs.get("max_new_tokens", 40))
         self._open_trunk_cache()
+        self._prefill_tally[:] = 0
 
         # Double-buffered generation: the NEXT chunk's sampling is
         # dispatched before the current chunk's device->host sync, so the
@@ -842,8 +852,7 @@ class PPOTrainer(TPUTrainer):
                     # the parallel mixins' generate() has no spec_k parameter.
                     spec_k = self._spec_k_effective()
                     spec_kw = {"spec_k": spec_k} if spec_k else {}
-                    out = self.generate(b["input_ids"], b["attention_mask"], gen_kwargs,
-                                        **spec_kw)
+                    out = self._rollout_generate(b, gen_kwargs, **spec_kw)
             return b, out, chunk, t_dispatch
 
         pending = _dispatch_next()
@@ -904,6 +913,10 @@ class PPOTrainer(TPUTrainer):
             for k in accumulated_stats[-1]
         }
         stats["kl_ctl_value"] = self.kl_ctl.value
+        calls, widths, padded, pad = (int(x) for x in self._prefill_tally)
+        if calls:
+            stats["rollout/prefill_width"] = widths / calls
+            stats["rollout/prefill_padding_share"] = pad / padded
         if use_fleet and self._rollout_router is not None:
             # router lifetime counters (not per-chunk, so merged after
             # the per-chunk averaging above)
@@ -1467,8 +1480,72 @@ class PPOTrainer(TPUTrainer):
         return kept, int(drop.sum())
 
     def add_prompt_pipeline(self, pipeline):
-        loader = pipeline.create_loader(self.config.method.chunk_size, shuffle=True)
-        self.prompt_iterator = infinite_dataloader(loader)
+        self.prompt_iterator = self._rollout_stream(pipeline, self.config.method.chunk_size)
+
+    #: whether `generate` narrows a rollout chunk to a rung of
+    #: `_prompt_ladder` (the pipelined trainers' own `generate` does not)
+    _narrows_rollout_chunks = True
+
+    def _rollout_stream(self, pipeline, rows: int, **loader_kwargs) -> LoaderStream:
+        """The rollout loader's chunks of `rows` prompts, forever. A pipeline
+        that knows its prompts' lengths has every collection's prompts (a
+        window of the shuffled order) sorted by length before they are cut
+        into chunks, and gives the trainer the few prompt widths such
+        chunks are generated at (`prompt_width_ladder`; one width, and the
+        loader of before, where a collection is one chunk). The stream's
+        place is part of the resume state."""
+        method = self.config.method
+        window = rows * -(-int(method.num_rollouts) // max(int(method.chunk_size), 1))
+        lengths = getattr(pipeline, "prompt_lengths", None)
+        ladder = ()
+        if lengths is not None:
+            loader_kwargs["group_window"] = window
+            ladder = prompt_width_ladder(lengths, window, rows)
+        # (seq2seq prompts are the encoder's: its samples hold no prompt block)
+        narrows = self._narrows_rollout_chunks and not self.seq2seq and getattr(
+            self.config.train, "bucket_generation", True)
+        self._prompt_ladder = ladder if len(ladder) > 1 and narrows else None
+        # dispatches until the other rungs' programs are made (`_rollout_generate`)
+        self._ladder_countdown = 2 if self._prompt_ladder else 0
+        self._prompt_stream = LoaderStream(pipeline.create_loader(rows, shuffle=True, **loader_kwargs))
+        return self._prompt_stream
+
+    def _rollout_generate(self, batch, gen_kwargs, **generate_kwargs):
+        """`generate` for one rollout chunk. Its prefill is counted first:
+        the rows and width the program runs at, the prompt tokens among
+        those positions, and the rest, padding. A rung's program compiles
+        when a chunk first runs at it, as any program does; once the first
+        two chunks are dispatched (generation is double-buffered: the host
+        would now wait for the first, and the device has two chunks' work,
+        the longest's, to hide a start-up's tracing behind) the other rungs'
+        are compiled too, so that no width is first met cycles later."""
+        input_ids = np.asarray(batch["input_ids"])
+        attention_mask = np.asarray(batch["attention_mask"])
+        rows, width = self._bucket_shape(
+            len(input_ids), self._ladder_width(attention_mask) or attention_mask.shape[1])
+        padded, tokens = rows * width, int(attention_mask.sum())
+        self._prefill_tally += (1, width, padded, padded - tokens)
+        if tracing.active():
+            tracing.counters("ppo.prefill", calls=1, rows=rows, width=width,
+                             prompt_tokens=tokens, padded_tokens=padded,
+                             pad_tokens=padded - tokens)
+        out = self.generate(input_ids, attention_mask, gen_kwargs, **generate_kwargs)
+        if self._ladder_countdown:
+            self._ladder_countdown -= 1
+            if not self._ladder_countdown:
+                self._prepare_ladder_programs(len(input_ids), gen_kwargs, **generate_kwargs)
+        return out
+
+    def _prepare_ladder_programs(self, rows: int, gen_kwargs, **generate_kwargs):
+        """Trace, lower and compile (or read back) the `generate` program
+        of every rung for chunks of `rows`, without running any; next to
+        nothing for a rung that a chunk has run at."""
+        ladder, params = self._prompt_ladder, self._decode_params()
+        for width in ladder:
+            rows, cols = self._bucket_shape(rows, width)
+            fn = self.get_generate_fn(rows, cols, gen_kwargs, widen_to=ladder[-1], **generate_kwargs)
+            tokens = jax.ShapeDtypeStruct((rows, cols), jnp.int32)
+            prepare_jit(fn, params, tokens, tokens, jax.ShapeDtypeStruct(self.rng.shape, self.rng.dtype))
 
     def post_epoch_callback(self):
         if self.log_rollouts:
@@ -1490,6 +1567,8 @@ class PPOTrainer(TPUTrainer):
         never drew), the KL controller, and the reward running moments —
         composed with the base trainer's state (sentinel ladder)."""
         extra = super()._extra_resume_state()
+        if self._prompt_stream is not None:
+            extra["prompt_stream"] = self._prompt_stream.state()
         extra.update({
             "store_history": list(self.store.history),
             "kl_ctl_value": float(self.kl_ctl.value),
@@ -1512,6 +1591,8 @@ class PPOTrainer(TPUTrainer):
             self._trunk_cache = None
             self.store.push(
                 [dataclasses.replace(e, trunk_row=None) for e in state["store_history"]])
+        if "prompt_stream" in state and self._prompt_stream is not None:
+            self._prompt_stream.restore(state["prompt_stream"])
         if "kl_ctl_value" in state:
             self.kl_ctl.value = state["kl_ctl_value"]
         self.mean_kl = state.get("mean_kl", self.mean_kl)
@@ -1534,9 +1615,8 @@ class PPOTrainer(TPUTrainer):
         gen_kwargs = self.generate_experience_kwargs or self.generate_kwargs
         batch = next(self.prompt_iterator)
         spec_k = self._spec_k_effective()
-        out = self.generate(batch["input_ids"], batch["attention_mask"], gen_kwargs,
-                            capture=self._fast_rollout_available(),
-                            **({"spec_k": spec_k} if spec_k else {}))
+        out = self._rollout_generate(batch, gen_kwargs, capture=self._fast_rollout_available(),
+                                     **({"spec_k": spec_k} if spec_k else {}))
         return batch, out
 
     def _build_score_reward_fn(self, scalar_scores: bool):

@@ -13,7 +13,7 @@ from trlx_tpu.ops import linear_attention as la
 B, T, H, DK, DV = 2, 83, 4, 16, 8  # 83: a whole chunk of 64, a sub-chunk of 16 and 3 more
 
 
-def inputs(seed, t=T, b=B, slow=False, floor=-5.0):
+def inputs(seed, t=T, b=B, slow=False, floor=-5.0, g_step=None, beta_max=1.0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
     q = unit(jax.random.normal(ks[0], (b, t, H, DK))) * DK ** -0.5
@@ -21,8 +21,19 @@ def inputs(seed, t=T, b=B, slow=False, floor=-5.0):
     v = jax.random.normal(ks[2], (b, t, H, DV))
     # `slow`: decays near -0.09 a step, so a state forty tokens back still counts
     g = floor * jax.nn.sigmoid(jax.random.normal(ks[3], (b, t, H, DK)) - (4.0 if slow else 0.0))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, H)))
+    if g_step is not None:  # an unbounded gate's range: about `g_step` a step on every channel
+        g = g_step * (0.5 + jax.random.uniform(ks[3], (b, t, H, DK)))
+    beta = beta_max * jax.nn.sigmoid(jax.random.normal(ks[4], (b, t, H)))
     return q, k, v, g, beta
+
+
+# the bounded gate's two ranges (beta in (0, 1)), and an unbounded gate's ends with beta in (0, 2):
+# a channel that forgets everything in a step (e^-30; a product about a reference in the middle
+# of a sub-chunk would need e^+240) and one that forgets nothing in a chunk
+DECAYS = {"published_decay": {}, "slow_decay": {"slow": True},
+          "forgets_in_a_step_beta_to_2": {"g_step": -30.0, "beta_max": 2.0},
+          "forgets_nothing_beta_to_2": {"g_step": -1e-4, "beta_max": 2.0}}
+by_decay = pytest.mark.parametrize("decay", list(DECAYS.values()), ids=list(DECAYS))
 
 
 def step_through_the_kernel(x, live=None, state=None):
@@ -37,14 +48,19 @@ def step_through_the_kernel(x, live=None, state=None):
     return jnp.stack(outs, 1), state
 
 
-@pytest.mark.parametrize("slow", [False, True], ids=["published_decay", "slow_decay"])
-def test_scan_chunks_and_kernel_agree(slow):
-    x = inputs(0, slow=slow)
+@by_decay
+def test_scan_chunks_and_kernel_agree(decay):
+    x = inputs(0, **decay)
+    slow = decay.get("slow", False)
     o_scan, s_scan = la.kda_recurrent(*x)
     o_chunk, s_chunk = jax.jit(la.kda_chunked)(*x)
     o_step, s_step = step_through_the_kernel(tuple(a[:, :40] for a in x))
     assert float(jnp.abs(o_scan).max()) > 0.1
+    assert bool(jnp.isfinite(o_chunk).all()) and bool(jnp.isfinite(s_chunk).all())
     assert np.abs(o_scan - o_chunk).max() < 1e-5 and np.abs(s_scan - s_chunk).max() < 1e-5
+    # spans of two chunks, the state carried from one to the next: the same numbers
+    o_span, s_span = jax.jit(lambda *a: la.kda_chunked(*a, chunk=16, span=32))(*x)
+    assert np.abs(o_scan - o_span).max() < 1e-5 and np.abs(s_scan - s_span).max() < 1e-5
     o_40, s_40 = la.kda_recurrent(*(a[:, :40] for a in x))
     assert np.abs(o_40 - o_step).max() < 1e-5 and np.abs(s_40 - s_step).max() < 1e-5
     if slow:  # the first tokens are still in the state forty tokens on
@@ -86,7 +102,7 @@ def test_a_padded_position_is_the_identity(side):
 
 def test_the_published_lower_bound_on_every_channel_does_not_overflow():
     """g = -5 on every channel for 64 positions: a `k / cumprod` over the chunk
-    would need e^320; the sub-chunk references keep every factor within e^+-40."""
+    would need e^320; no factor the chunked form takes exceeds 1."""
     q, k, v, g, beta = inputs(3, t=64, b=1)
     g = jnp.full_like(g, -5.0)
     o_scan, s_scan = la.kda_recurrent(q, k, v, g, beta)
@@ -97,14 +113,25 @@ def test_the_published_lower_bound_on_every_channel_does_not_overflow():
     assert bool(jnp.isfinite(grads).all())
 
 
-def test_the_chunked_form_differentiates_like_the_scan():
-    x = inputs(4, t=40, slow=True)
+@pytest.mark.parametrize("decay", [d for name, d in DECAYS.items() if name != "published_decay"],
+                         ids=[name for name in DECAYS if name != "published_decay"])
+def test_the_chunked_form_differentiates_like_the_scan(decay):
+    """One span, and (at the slow decay, where a state crosses spans) spans of
+    one chunk each: a gradient through the scan over spans recomputes a span
+    from the state it kept."""
+    x = inputs(4, t=40, **decay)
     loss = lambda form: lambda *a: (form(*a)[0] ** 2).sum() + form(*a)[1].sum()
     want = jax.grad(loss(la.kda_recurrent), argnums=(0, 1, 2, 3, 4))(*x)
-    got = jax.grad(loss(la.kda_chunked), argnums=(0, 1, 2, 3, 4))(*x)
-    for a, b, name in zip(want, got, "q k v g beta".split()):
-        assert float(jnp.abs(a).max()) > 1e-3, name
-        assert np.abs(a - b).max() < 1e-5, name
+    by_spans = lambda *a: la.kda_chunked(*a, chunk=16, span=16)
+    for form in (la.kda_chunked, by_spans) if decay.get("slow") else (la.kda_chunked,):
+        got = jax.grad(loss(form), argnums=(0, 1, 2, 3, 4))(*x)
+        for a, b, name in zip(want, got, "q k v g beta".split()):
+            if not (name == "g" and decay.get("g_step") == -30.0):  # nothing survives a step: no gradient to the decay
+                assert float(jnp.abs(a).max()) > 1e-3, name
+            assert bool(jnp.isfinite(b).all()), name
+            # where nothing decays a gradient sums 40 tokens' terms and reaches 3: float32's 1e-5 is of that
+            scale = max(1.0, float(jnp.abs(a).max())) if "g_step" in decay else 1.0
+            assert np.abs(a - b).max() < 1e-5 * scale, name
 
 
 def test_the_kernel_leaves_a_masked_row_s_state_to_the_bit_and_the_plain_step_agrees():

@@ -372,11 +372,9 @@ def test_paged_decode_with_a_window_and_groups_of_six_compiles_at_the_code_cell_
     assert arena_rewrites(compiled, arena, *args[5:]) == []
 
 
-@pytest.fixture(scope="module")
-def code_cell_engine(v5e):
-    """A paged `InferenceEngine` as `laguna-xs.2.rollout-code` builds it, at
-    the configuration file's own cut (8 layers, 64 of 256 experts held) and no
-    weights: its programs are only compiled here."""
+def serve_cell_engine(v5e, name: str, traffic: str, max_new: int, n_tbl: int):
+    """A paged `InferenceEngine` as the cell `<name>.<traffic>` builds it, at
+    the configuration file's own cut and no weights: (engine, abstract params)."""
     import json
     import os
 
@@ -385,15 +383,16 @@ def code_cell_engine(v5e):
     from trlx_tpu.ops.sampling import GenerationConfig
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    bench = json.load(open(os.path.join(root, "bench", "configs", "laguna-xs.2.json")))["bench"]
-    cell = json.load(open(os.path.join(root, "bench", "workloads", "laguna-xs.2.rollout-code.json")))["engine"]
+    bench = json.load(open(os.path.join(root, "bench", "configs", f"{name}.json")))["bench"]
+    cell = json.load(open(os.path.join(root, "bench", "workloads", f"{name}.{traffic}.json")))["engine"]
     extra = dict(bench["program"]["model_extra_configs"])
-    cfg = config_from_preset("laguna-xs.2", extra.pop("vocab_size"), **extra, param_dtype=BF16, dtype=BF16)
+    cfg = config_from_preset(bench["program"]["model_path"].split(":", 1)[1], extra.pop("vocab_size"), **extra,
+                             param_dtype=BF16, dtype=BF16)
     model = CausalLMPolicy(cfg)
     tokens = jnp.zeros((1, 32), I32)
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
-    gen_cfg = GenerationConfig(max_new_tokens=1024, do_sample=True,
+    gen_cfg = GenerationConfig(max_new_tokens=max_new, do_sample=True,
                                eos_token_id=cfg.vocab_size + 1, pad_token_id=0)
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(InferenceEngine, "_param_devices", lambda self: [v5e[0]])
@@ -402,8 +401,14 @@ def code_cell_engine(v5e):
             max_prompt_len=cell["max_prompt_len"], max_prefill_batch=cell["max_prefill_batch"],
             prompt_bucket=cell["prompt_bucket"], kv_block_size=cell["kv_block_size"],
             kv_pool_blocks=cell["kv_pool_blocks"], kv_cache_dtype=cell["kv_cache_dtype"])
-    assert engine.decode_path == "pallas" and engine._n_tbl == CODE_CELL["n_tbl"]
+    assert engine.decode_path == "pallas" and engine._n_tbl == n_tbl
     return engine, params
+
+
+@pytest.fixture(scope="module")
+def code_cell_engine(v5e):
+    """`laguna-xs.2.rollout-code`'s engine: 8 layers, 64 of 256 experts held."""
+    return serve_cell_engine(v5e, "laguna-xs.2", "rollout-code", 1024, CODE_CELL["n_tbl"])
 
 
 @pytest.mark.parametrize("program", ["decode", "paged_insert"])
@@ -492,37 +497,9 @@ def test_flash_forward_with_narrower_value_heads_compiles_under_its_own_name(v5e
 
 @pytest.fixture(scope="module")
 def longctx_cell_engine(v5e):
-    """A paged `InferenceEngine` as `openpangu-ultra-moe-718b.rollout-longctx`
-    builds it, at the configuration file's own cut (5 layers, 8 of 256 experts
-    held, an eighth of the vocabulary) and no weights."""
-    import json
-    import os
-
-    from trlx_tpu.inference import InferenceEngine
-    from trlx_tpu.models import CausalLMPolicy, config_from_preset
-    from trlx_tpu.ops.sampling import GenerationConfig
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    name = "openpangu-ultra-moe-718b"
-    bench = json.load(open(os.path.join(root, "bench", "configs", f"{name}.json")))["bench"]
-    cell = json.load(open(os.path.join(root, "bench", "workloads", f"{name}.rollout-longctx.json")))["engine"]
-    extra = dict(bench["program"]["model_extra_configs"])
-    cfg = config_from_preset(name, extra.pop("vocab_size"), **extra, param_dtype=BF16, dtype=BF16)
-    model = CausalLMPolicy(cfg)
-    tokens = jnp.zeros((1, 32), I32)
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
-    gen_cfg = GenerationConfig(max_new_tokens=1024, do_sample=True,
-                               eos_token_id=cfg.vocab_size + 1, pad_token_id=0)
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(InferenceEngine, "_param_devices", lambda self: [v5e[0]])
-        engine = InferenceEngine(
-            model, cfg, None, gen_cfg, kv_paging=True, num_slots=cell["num_slots"],
-            max_prompt_len=cell["max_prompt_len"], max_prefill_batch=cell["max_prefill_batch"],
-            prompt_bucket=cell["prompt_bucket"], kv_block_size=cell["kv_block_size"],
-            kv_pool_blocks=cell["kv_pool_blocks"], kv_cache_dtype=cell["kv_cache_dtype"])
-    assert engine.decode_path == "pallas" and engine._n_tbl == LONGCTX_CELL["n_tbl"]
-    return engine, params
+    """`openpangu-ultra-moe-718b.rollout-longctx`'s engine: 5 layers, 8 of 256
+    experts held, an eighth of the vocabulary."""
+    return serve_cell_engine(v5e, "openpangu-ultra-moe-718b", "rollout-longctx", 1024, LONGCTX_CELL["n_tbl"])
 
 
 @pytest.mark.parametrize("program", ["decode", "paged_insert"])
@@ -588,37 +565,9 @@ REASON_CELL = dict(n_tbl=(1024 + 2048) // 32)
 
 @pytest.fixture(scope="module")
 def reason_cell_engine(v5e):
-    """A paged `InferenceEngine` as `ling-3.0-flash-vl.rollout-reason` builds
-    it, at the configuration file's own cut (one period of 6 layers, 64 of 512
-    experts held, an eighth of the vocabulary) and no weights."""
-    import json
-    import os
-
-    from trlx_tpu.inference import InferenceEngine
-    from trlx_tpu.models import CausalLMPolicy, config_from_preset
-    from trlx_tpu.ops.sampling import GenerationConfig
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    name = "ling-3.0-flash-vl"
-    bench = json.load(open(os.path.join(root, "bench", "configs", f"{name}.json")))["bench"]
-    cell = json.load(open(os.path.join(root, "bench", "workloads", f"{name}.rollout-reason.json")))["engine"]
-    extra = dict(bench["program"]["model_extra_configs"])
-    cfg = config_from_preset(name, extra.pop("vocab_size"), **extra, param_dtype=BF16, dtype=BF16)
-    model = CausalLMPolicy(cfg)
-    tokens = jnp.zeros((1, 32), I32)
-    params = jax.eval_shape(
-        lambda: model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"])
-    gen_cfg = GenerationConfig(max_new_tokens=2048, do_sample=True,
-                               eos_token_id=cfg.vocab_size + 1, pad_token_id=0)
-    with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(InferenceEngine, "_param_devices", lambda self: [v5e[0]])
-        engine = InferenceEngine(
-            model, cfg, None, gen_cfg, kv_paging=True, num_slots=cell["num_slots"],
-            max_prompt_len=cell["max_prompt_len"], max_prefill_batch=cell["max_prefill_batch"],
-            prompt_bucket=cell["prompt_bucket"], kv_block_size=cell["kv_block_size"],
-            kv_pool_blocks=cell["kv_pool_blocks"], kv_cache_dtype=cell["kv_cache_dtype"])
-    assert engine.decode_path == "pallas" and engine._n_tbl == REASON_CELL["n_tbl"]
-    return engine, params
+    """`ling-3.0-flash-vl.rollout-reason`'s engine: one period of 6 layers, 64
+    of 512 experts held, an eighth of the vocabulary."""
+    return serve_cell_engine(v5e, "ling-3.0-flash-vl", "rollout-reason", 2048, REASON_CELL["n_tbl"])
 
 
 @pytest.mark.parametrize("program", ["decode", "paged_insert"])
@@ -660,6 +609,81 @@ def test_reason_cell_programs_compile_for_the_chip_and_fit_it(v5e, reason_cell_e
     print(f"{program}: arguments {memory.argument_size_in_bytes}, temporaries {memory.temp_size_in_bytes}, "
           f"held {held}")
     assert held < 15.0e9, held
+
+
+KV_HYBRID_CELL = dict(n_tbl=(8192 + 1024) // 32)
+
+
+@pytest.fixture(scope="module")
+def kv_hybrid_cell_engine(v5e):
+    """`solar-open2-250b.rollout-longctx`'s engine: one period of 4 layers (G K
+    K K), 40 of 320 experts held, an eighth of the vocabulary."""
+    return serve_cell_engine(v5e, "solar-open2-250b", "rollout-longctx", 1024, KV_HYBRID_CELL["n_tbl"])
+
+
+@pytest.mark.parametrize("program", ["decode", "paged_insert"])
+def test_kv_hybrid_cell_programs_compile_for_the_chip_and_fit_it(v5e, kv_hybrid_cell_engine, pallas_mode, program):
+    """`solar-open2-250b.rollout-longctx`'s decode step and its widest prefill
+    (one row of 8,192, the fresh-prompt program) at the published widths: one
+    `kda_decode` a linear layer over 64 heads and one `paged_decode` for the
+    GQA layer (8 query heads a K/V head, no rotation in front), the prompt's
+    recurrence in chunks under XLA a span of 1,024 positions at a time (the
+    test below holds its temporaries to a span's) and its GQA layer through the
+    flash forward, three grouped products an expert layer, neither the arena
+    nor a recurrent matrix copied, and arguments plus temporaries under 15.5
+    GB: 6.62 GB of weights, 0.83 GB of slot state, 1.61 GB of arena and the
+    program's own."""
+    engine, params = kv_hybrid_cell_engine
+    one = SingleDeviceSharding(v5e[0])
+    pool = abstract(engine._pool, one)
+    params = abstract(params, one)
+    if program == "decode":
+        compiled = engine._decode_fn.trace(params, pool).lower(lowering_platforms=("tpu",)).compile()
+        want = {"kda_decode": 3, "paged_decode": 1, "moe_gmm": 12}
+    else:
+        rows, width = 1, 8192
+        shapes = dict(ids=(rows, width), tmask=(rows, width), tables=(rows, KV_HYBRID_CELL["n_tbl"]),
+                      slot_ids=(rows,), max_new=(rows,), shared_len=(rows,))
+        compiled = engine._get_paged_insert(rows, width, True).trace(
+            pool, params, *(S(shape, I32, sharding=one) for shape in shapes.values())
+        ).lower(lowering_platforms=("tpu",)).compile()
+        want = {"flash_fwd": 1, "moe_gmm": 12}
+        assert instructions_of_at_least(compiled, 64 * 8192 * 8192) == []  # no [heads, 8192, 8192] score tensor
+    names = kernel_names(compiled)
+    assert {n: names.count(n) for n in set(names)} == want
+    arenas = [a for layer in engine._pool["layers"] for name, a in layer.items() if name != "tails"]
+    assert len(arenas) == 2 + 3
+    # a recurrent matrix pool [64, 64, 128, 128] has as many elements as one of the chunked form's
+    # inputs over 8,192 positions: the pools are told by their shape, K and V by their size
+    assert arena_rewrites(compiled, *engine._pool["layers"][0].values()) == []
+    assert [i for i in instructions_of_at_least(compiled, 64 * 64 * 128 * 128)
+            if "= f32[64,64,128,128]" in i and (" copy(" in i or " transpose(" in i)] == []
+    assert donated_outputs(compiled) >= len(arenas)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    print(f"{program}: arguments {memory.argument_size_in_bytes}, temporaries {memory.temp_size_in_bytes}, "
+          f"held {held}")
+    assert held < 15.5e9, held
+
+
+def test_the_chunked_form_s_temporaries_do_not_grow_with_the_prompt(v5e):
+    """`kda_chunked` over 64 heads of 128 at 2,048 and at 8,192 positions: what
+    the program holds beside its arguments and results is a span's (SPAN
+    positions' pair terms, inverse and float32 copies), the same at both."""
+    from trlx_tpu.ops import linear_attention
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def temporaries(t):
+        vec = lambda *shape, dtype=BF16: S((1, t, 64) + shape, dtype, sharding=one)
+        args = (vec(128), vec(128), vec(128), vec(128, dtype=F32), vec(dtype=F32))
+        compiled = jax.jit(linear_attention.kda_chunked).trace(*args).lower(lowering_platforms=("tpu",)).compile()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    short, long = temporaries(2048), temporaries(8192)
+    print(f"temporaries at 2,048 positions {short}, at 8,192 {long}")
+    assert long < 1.05 * short and long < 512e6, (short, long)
 
 
 LAYOUTS = [(4, 1, 1), (2, 1, 2), (1, 2, 2)]  # (data, fsdp, tensor)

@@ -56,6 +56,7 @@ from trlx_tpu.models.transformer import (
     prefill_fuses,
 )
 from trlx_tpu.observability import tracing
+from trlx_tpu.ops.linear_attention import CHUNK
 from trlx_tpu.ops.quant import dequantize_tree
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
@@ -323,6 +324,8 @@ class InferenceEngine:
         keeps = getattr(model_cfg, "layer_keeps", None)
         self._layer_keeps = [keeps(i) for i in range(model_cfg.n_layers)] if keeps else []
         self._slot_state_layers = sum(1 for k in self._layer_keeps if k.slot)
+        self._linear_layers = sum(1 for i in range(len(self._layer_keeps))
+                                  if model_cfg.layer_op(i) == "linear_attention")
         # bytes of slot state a row holds over all layers (0 for K/V and latent layers)
         self._slot_state_bytes_per_slot = (
             model_cfg.slot_state_bytes_per_slot(self.kv_cache_dtype) if self._slot_state_layers else 0)
@@ -908,6 +911,11 @@ class InferenceEngine:
         if tracing.active():
             tracing.counters("sched.insert", calls=1, rows=rows, prompt_tokens=tokens,
                              padded_tokens=padded, pad_tokens=padded - tokens)
+            if self._linear_layers:
+                # what the chunked recurrence is about to run, a layer: every padded position, in chunks
+                tracing.counters("engine.prefill_state", tokens=tokens, padded_tokens=padded,
+                                 linear_layers=self._linear_layers,
+                                 chunks=sum(pb * -(-plen // CHUNK) for plen, _, pb in programs))
         return rows, tokens, padded
 
     @contextlib.contextmanager

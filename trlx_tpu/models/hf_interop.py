@@ -61,6 +61,8 @@ def _family_of(hf: Dict) -> str:
         return "pangu_ultra_moe"
     if "layer_group_size" in hf and "kda_lower_bound" in hf:  # the published config names no model_type here
         return "ling_flash"
+    if mt == "solar_open2":
+        return "solar_open2"
     for fam, keys in (
         ("gpt_bigcode", ("bigcode",)),
         ("gpt_neox", ("neox",)),
@@ -191,6 +193,8 @@ def config_from_hf(path: str, **overrides):
         kwargs = _pangu_kwargs(hf)
     elif fam == "ling_flash":
         kwargs = _ling_kwargs(hf)
+    elif fam == "solar_open2":
+        kwargs = _solar_kwargs(hf)
     kwargs["hf_family"] = fam
     kwargs.update(overrides)
     return TransformerConfig(**cut_to_depth(kwargs, overrides))
@@ -260,6 +264,40 @@ def _pangu_kwargs(hf: Dict) -> Dict:
         moe_experts=hf["n_routed_experts"], moe_top_k=hf["num_experts_per_tok"],
         moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=hf["first_k_dense_replace"],
         moe_router="sigmoid", moe_shared_d_ff=hf["moe_intermediate_size"] * hf.get("n_shared_experts", 1),
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+    )
+
+
+def _solar_kwargs(hf: Dict) -> Dict:
+    """upstage's `solar_open2` config keys -> TransformerConfig fields: GQA
+    layers without positions (`gqa_layers`) among Kimi-delta layers in Kimi
+    Linear's own form (`linear_attn_config`), sigmoid-routed experts in every
+    layer. Config keys only: the checkpoint's tensor names are not public, so
+    `load_params_from_hf` and the export refuse the family by name. What the
+    keys do not settle is listed in bench/reference/solar_open2.py. A key that
+    would change a layer's equations from what is written there is refused by
+    name."""
+    for key, want in (("use_rope", False), ("use_gqa_gate", True), ("kda_use_full_proj", False),
+                      ("norm_topk_prob", True), ("n_shared_experts", 1), ("first_k_dense_replace", 0)):
+        if hf.get(key, want) != want:
+            raise NotImplementedError(f"solar_open2 with {key}={hf[key]!r} is not supported")
+    linear = hf["linear_attn_config"]
+    if linear["num_heads"] != hf["num_attention_heads"] or linear["head_dim"] != hf["head_dim"] \
+            or linear.get("num_kv_heads") not in (None, linear["num_heads"]):
+        raise NotImplementedError("solar_open2 with linear-attention heads unlike the query heads is not supported")
+    n = hf["num_hidden_layers"]
+    return dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"], head_width=hf["head_dim"],
+        d_ff=hf["intermediate_size"], max_seq_len=hf["max_position_embeddings"], pos_embed="none",
+        norm="rmsnorm", layer_norm_epsilon=hf.get("rms_norm_eps", 1e-5), activation="silu", glu=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)), use_bias=False, flash_prefill=True,
+        layer_types=tuple("attention" if i in hf["gqa_layers"] else "linear_attention" for i in range(n)),
+        attn_gate="elementwise", conv_kernel=linear["short_conv_kernel_size"], kda_decay="softplus",
+        kda_gate_rank=linear["head_dim"], kda_beta_max=2.0 if hf.get("kda_allow_neg_eigval", False) else 1.0,
+        moe_experts=hf["n_routed_experts"], moe_top_k=hf["num_experts_per_tok"],
+        moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=0, moe_router="sigmoid",
+        moe_shared_d_ff=hf["moe_intermediate_size"] * hf.get("n_shared_experts", 1),
         moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
     )
 
@@ -1250,7 +1288,7 @@ def infer_family(cfg) -> str:
     if getattr(cfg, "has_conv_layers", False):
         return "lfm2_moe"
     if getattr(cfg, "has_linear_layers", False):
-        return "ling_flash"
+        return "ling_flash" if cfg.has_latent_layers else "solar_open2"
     if getattr(cfg, "has_latent_layers", False):
         return "pangu_ultra_moe"
     if getattr(cfg, "attention_kinds", ()):
@@ -1364,6 +1402,20 @@ def config_to_hf(cfg: TransformerConfig, family: str = None) -> Dict:
             norm_topk_prob=True, routed_scaling_factor=cfg.moe_routed_scale,
             expert_swiglu_limit_list=[0] * cfg.n_layers, share_expert_swiglu_limit_list=[0] * cfg.n_layers,
             tie_word_embeddings=cfg.tie_embeddings,
+        )
+    if family == "solar_open2":
+        return dict(
+            model_type="solar_open2", vocab_size=cfg.vocab_size, hidden_size=cfg.d_model,
+            intermediate_size=cfg.d_ff, num_hidden_layers=cfg.n_layers, num_attention_heads=cfg.n_heads,
+            num_key_value_heads=cfg.kv_heads, head_dim=cfg.head_dim, max_position_embeddings=cfg.max_seq_len,
+            rms_norm_eps=cfg.layer_norm_epsilon, use_rope=False, use_gqa_gate=True,
+            gqa_layers=[i for i, kind in enumerate(cfg.layer_types) if kind == "attention"],
+            linear_attn_config=dict(short_conv_kernel_size=cfg.conv_kernel, head_dim=cfg.head_dim,
+                                    num_heads=cfg.n_heads, num_kv_heads=None),
+            kda_use_full_proj=False, kda_allow_neg_eigval=cfg.kda_beta_max > 1.0,
+            first_k_dense_replace=0, n_routed_experts=cfg.moe_experts, n_shared_experts=1,
+            num_experts_per_tok=cfg.moe_top_k, moe_intermediate_size=cfg.expert_d_ff, norm_topk_prob=True,
+            routed_scaling_factor=cfg.moe_routed_scale, tie_word_embeddings=cfg.tie_embeddings,
         )
     if family == "pangu_ultra_moe":
         return dict(

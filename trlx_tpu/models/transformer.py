@@ -130,7 +130,9 @@ class TransformerConfig:
     # A stated head width, where it is not d_model // n_heads.
     head_width: Optional[int] = None
     # "per_head": a = sigmoid(x W_g)_h * a_h on the attention output before
-    # o_proj, W_g [d_model, heads] (`gate_proj`).
+    # o_proj, W_g [d_model, heads] (`gate_proj`). "elementwise": a value of
+    # its own for every channel of every head, W_g [d_model, heads * head_dim]
+    # (K/V attention layers; a KDA layer's output gate is `kda_gate_rank`'s).
     attn_gate: str = "none"
     conv_kernel: int = 3  # taps of the short convolution
     qk_norm: bool = False  # RMSNorm over the head width on q and k, before rotary
@@ -196,8 +198,20 @@ class TransformerConfig:
     # v, a log-decay a key channel bounded below by `kda_lower_bound`.
     # Such a layer keeps a matrix a head and the convolutions' last inputs a
     # ROW, nothing a token (`layer_keeps`); `kda_state_dtype` is the matrix's.
+    # The published forms differ in three places, each a field:
+    # `kda_decay` "bounded": g = kda_lower_bound * sigmoid(exp(a_h) f), in
+    # [kda_lower_bound, 0]; "softplus": g = -exp(a_h) softplus(f), unbounded
+    # (Kimi Linear's own). `kda_gate_rank` 0: f = x W_f + b_dt with W_f full
+    # rank and a sigmoid output gate a HEAD (`gate_proj`); r > 0: f through a
+    # low-rank pair (`f_a_proj` [d, r], `f_b_proj` [r, H d]) and an
+    # ELEMENTWISE output gate through another (`g_a_proj`, `g_b_proj`, with a
+    # bias `g_bias`). `kda_beta_max`: beta = kda_beta_max * sigmoid(x W_b); 2
+    # admits a negative eigenvalue of the transition I - beta k k^T.
     kda_lower_bound: float = -5.0
     kda_state_dtype: Any = jnp.float32
+    kda_decay: str = "bounded"
+    kda_gate_rank: int = 0
+    kda_beta_max: float = 1.0
 
     def __post_init__(self):
         if self.q_lora_rank is None:
@@ -222,8 +236,13 @@ class TransformerConfig:
         if self.rope_kinds:
             object.__setattr__(self, "rope_kinds", tuple(
                 (k, r if isinstance(r, RopeSpec) else RopeSpec(**r)) for k, r in self.rope_kinds))
-        if self.attn_gate not in ("none", "per_head"):
-            raise ValueError(f"attn_gate must be 'none' or 'per_head', got {self.attn_gate!r}")
+        if self.attn_gate not in ("none", "per_head", "elementwise"):
+            raise ValueError(f"attn_gate must be 'none', 'per_head' or 'elementwise', got {self.attn_gate!r}")
+        if self.kda_decay not in ("bounded", "softplus"):
+            raise ValueError(f"kda_decay must be 'bounded' or 'softplus', got {self.kda_decay!r}")
+        if self.kda_gate_rank < 0 or not 0.0 < self.kda_beta_max <= 2.0:
+            raise ValueError(f"kda_gate_rank {self.kda_gate_rank} must be >= 0 and kda_beta_max "
+                             f"{self.kda_beta_max} in (0, 2]")
         if self.has_latent_layers:
             sizes = ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
             missing = [n for n in sizes if getattr(self, n) <= 0]
@@ -237,6 +256,7 @@ class TransformerConfig:
                 (self.lora_rank > 0, "lora_rank"), (self.prefix_tokens > 0, "prefix_tokens"),
                 (self.sliding_window is not None, "sliding_window"),
                 (self.attn_impl in ("ring", "blockwise"), f"attn_impl={self.attn_impl!r}"),
+                (self.attn_gate == "elementwise", "attn_gate='elementwise'"),
             ) if on]
             if unsupported:
                 raise NotImplementedError(f"latent_attention layers with {', '.join(unsupported)} are not supported")
@@ -669,12 +689,15 @@ class Attention(nn.Module):
             q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim, rope)
             k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim, rope)
         gate = None
-        if cfg.attn_gate == "per_head":
-            gate = jax.nn.sigmoid(dense(nh, "gate_proj")(h).astype(jnp.float32)).astype(cfg.dtype)
+        if cfg.attn_gate != "none":  # [b, t, nh] a head, [b, t, nh * hd] elementwise
+            width = nh if cfg.attn_gate == "per_head" else nh * hd
+            gate = jax.nn.sigmoid(dense(width, "gate_proj")(h).astype(jnp.float32)).astype(cfg.dtype)
 
         def project_out(out):  # [b, t, nh * hd] or [b, t, nh, hd]
-            if gate is not None:
+            if cfg.attn_gate == "per_head":
                 out = out.reshape(b, t, nh, hd) * gate[..., None]
+            elif gate is not None:
+                out = out.reshape(b, t, nh * hd) * gate
             return dense(d, "o_proj")(out.reshape(b, t, nh * hd))
 
         new_cache = None
@@ -1131,10 +1154,13 @@ class KimiDeltaAttention(nn.Module):
         q~, k~, v~ = x W_q, x W_k, x W_v
         q, k, v = SiLU(conv(q~)), SiLU(conv(k~)), SiLU(conv(v~))      depthwise, causal, `conv_kernel` taps
         q^ = q / |q|_2 * d^-1/2,  k^ = k / |k|_2                       a head
-        g = kda_lower_bound * sigmoid(exp(a_h) * (x W_f + b_dt))      a key channel
-        beta = sigmoid(x W_b)                                          a head
+        f = x W_f + b_dt   (`kda_gate_rank` r > 0: x W_fa W_fb + b_dt, through r)     a key channel
+        g = kda_lower_bound * sigmoid(exp(a_h) * f)                    `kda_decay` "bounded", in [kda_lower_bound, 0]
+        g = -exp(a_h) * softplus(f)                                    `kda_decay` "softplus", in (-inf, 0]
+        beta = kda_beta_max * sigmoid(x W_b)                           a head, in (0, 1) or (0, 2)
         S_t = (I - beta k^ k^^T) Diag(exp(g)) S_{t-1} + beta k^ v^T;  o = S_t^T q^
-        y = W_o [ RMSNorm(o_h) * sigmoid(x W_g)_h ]
+        y = W_o [ RMSNorm(o_h) * sigmoid(x W_g)_h ]                    r = 0: the gate a head
+        y = W_o [ RMSNorm(o_h) * sigmoid(x W_ga W_gb + b_g) ]          r > 0: elementwise, through r
 
     A position whose mask bit is 0 is the identity: its input is zeroed
     before the products (so its convolution input is 0) and its beta and g
@@ -1155,9 +1181,13 @@ class KimiDeltaAttention(nn.Module):
 
         cfg = self.cfg
         b, t, d = h.shape
-        nh, hd, taps = cfg.n_heads, cfg.head_dim, cfg.conv_kernel
+        nh, hd, taps, rank = cfg.n_heads, cfg.head_dim, cfg.conv_kernel, cfg.kda_gate_rank
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
+        bias = lambda width, name: _Bias((width,), cfg.param_dtype, name=name)().astype(jnp.float32)
+        # x W, or x W_a W_b through `rank`
+        through = lambda feats, name: (dense(feats, f"{name}_proj")(h) if not rank else
+                                       dense(feats, f"{name}_b_proj")(dense(rank, f"{name}_a_proj")(h)))
         valid = None if attn_mask is None else (attn_mask > 0)
         if valid is not None:
             h = h * valid[..., None].astype(h.dtype)
@@ -1175,13 +1205,16 @@ class KimiDeltaAttention(nn.Module):
         q, k, v = (x.reshape(b, t, nh, hd) for x in jnp.split(jax.nn.silu(conv), 3, axis=-1))
         unit = lambda x: x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
         q, k = unit(q) * hd ** -0.5, unit(k)
-        a = _Bias((nh,), cfg.param_dtype, name="a_log")().astype(jnp.float32)
-        f = dense(nh * hd, "f_proj")(h).astype(jnp.float32) \
-            + _Bias((nh * hd,), cfg.param_dtype, name="dt_bias")().astype(jnp.float32)
-        f = f.reshape(b, t, nh, hd)
+        a = bias(nh, "a_log")
+        f = (through(nh * hd, "f").astype(jnp.float32) + bias(nh * hd, "dt_bias")).reshape(b, t, nh, hd)
         rate = jnp.exp(a)[:, None]
-        g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * f)
+        if cfg.kda_decay == "softplus":
+            g = -rate * jax.nn.softplus(f)
+        else:
+            g = cfg.kda_lower_bound * jax.nn.sigmoid(rate * f)
         beta = jax.nn.sigmoid(dense(nh, "b_proj")(h).astype(jnp.float32))
+        if cfg.kda_beta_max != 1.0:
+            beta = cfg.kda_beta_max * beta
         if valid is not None:
             g, beta = g * valid[..., None, None], beta * valid[..., None]
 
@@ -1200,8 +1233,12 @@ class KimiDeltaAttention(nn.Module):
                          "tails": tail_inputs(padded, valid, taps).astype(layer_cache["tails"].dtype)}
         scale = _Scale((hd,), cfg.param_dtype, name="o_norm")().astype(jnp.float32)
         o = o * jax.lax.rsqrt((o * o).mean(-1, keepdims=True) + cfg.layer_norm_epsilon) * scale
-        gate = jax.nn.sigmoid(dense(nh, "gate_proj")(h).astype(jnp.float32))
-        out = (o * gate[..., None]).astype(cfg.dtype).reshape(b, t, nh * hd)
+        if rank:
+            gate = jax.nn.sigmoid((through(nh * hd, "g").astype(jnp.float32) + bias(nh * hd, "g_bias"))
+                                  .reshape(b, t, nh, hd))
+        else:
+            gate = jax.nn.sigmoid(dense(nh, "gate_proj")(h).astype(jnp.float32))[..., None]
+        out = (o * gate).astype(cfg.dtype).reshape(b, t, nh * hd)
         return dense(d, "o_proj")(out), new_cache
 
 
@@ -2143,6 +2180,35 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         qk_norm=True, attn_gate="per_head", conv_kernel=4, kda_lower_bound=-5.0,
         moe_experts=16, moe_top_k=2, moe_d_ff=32, moe_dense_layers=1, moe_router="sigmoid",
         moe_shared_d_ff=32, moe_routed_scale=2.5, moe_n_group=4, moe_topk_group=2,
+    ),
+    # Solar-Open2-250B (upstage, `solar_open2`; 250B parameters, ~15B active):
+    # periods of one GQA layer (64 query heads over 8 K/V heads of 128, NO
+    # positions of any kind, an elementwise sigmoid gate on the attention
+    # output) and three Kimi-delta layers in the published Kimi Linear form (64
+    # heads of 128, convolutions of 4 taps, an unbounded softplus log-decay
+    # through a low-rank pair, write strengths up to 2, an elementwise low-rank
+    # output gate); experts in every layer: 320 sigmoid-routed (8 a token,
+    # normalised, no groups) beside one shared expert. The published sizes; a
+    # cut (depth, experts held here, vocabulary) arrives as model_extra_configs.
+    "solar-open2-250b": dict(
+        d_model=4096, n_layers=48, n_heads=64, n_kv_heads=8, head_width=128, d_ff=10240, max_seq_len=1048576,
+        pos_embed="none", norm="rmsnorm", layer_norm_epsilon=1e-5,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=tuple("attention" if i % 4 == 0 else "linear_attention" for i in range(48)),
+        attn_gate="elementwise", conv_kernel=4, kda_decay="softplus", kda_gate_rank=128, kda_beta_max=2.0,
+        moe_experts=320, moe_top_k=8, moe_d_ff=1280, moe_dense_layers=0, moe_router="sigmoid",
+        moe_shared_d_ff=1280, moe_routed_scale=1.0,
+    ),
+    # the same stack at test size: one period, 4 query heads over 2 K/V heads
+    # of 16, 16 experts (2 a token) beside a shared one
+    "solar-open2-tiny": dict(
+        d_model=64, n_layers=4, n_heads=4, n_kv_heads=2, head_width=16, d_ff=128, max_seq_len=256,
+        pos_embed="none", norm="rmsnorm", layer_norm_epsilon=1e-5,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=("attention", "linear_attention", "linear_attention", "linear_attention"),
+        attn_gate="elementwise", conv_kernel=4, kda_decay="softplus", kda_gate_rank=16, kda_beta_max=2.0,
+        moe_experts=16, moe_top_k=2, moe_d_ff=32, moe_dense_layers=0, moe_router="sigmoid",
+        moe_shared_d_ff=32, moe_routed_scale=1.0,
     ),
     # Mixture-of-experts (beyond the reference): experts shard over `tensor`
     "moe-tiny": dict(

@@ -2,7 +2,9 @@
 
 One head keeps a matrix S in R^{d_k x d_v}, float32. A token brings a query
 and a key (unit length; the query also scaled by d_k^-1/2), a value, a
-log-decay g in (-inf, 0]^{d_k} and a write strength beta in (0, 1):
+log-decay g in (-inf, 0]^{d_k} and a write strength beta in (0, 2) (above 1
+the transition I - beta k k^T has a negative eigenvalue along k; the forms
+below take beta as it is handed to them):
 
     S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
     o_t = S_t^T q_t
@@ -22,16 +24,22 @@ Three forms, equal on the same inputs (tests/test_kda_ops.py):
                       O = (Q * exp(G)) S_0 + B W,         B_ij = beta_j (q_i * exp(G_i - G_j)) . k_j, j <= i
                       S_C = exp(G_C) * S_0 + (K * beta * exp(G_C - G))^T W
 
-                  Every exponent that is used is a difference of cumulated
-                  log-decays, at most 0, taken in float32; A and B are formed
-                  as products about a reference in the middle of each row's
-                  sub-chunk of `SUB` positions, so that every factor stays
-                  within exp(+-SUB / 2 * |g|_max), e^+-40 at the published
-                  lower bound of -5: a `k / cumprod` over a whole chunk would
-                  reach e^320, and a reference at the sub-chunk's start e^-80,
-                  where a small component of a unit vector leaves float32's
-                  normal range.
-                  (I + A)^-1 is exact: forward substitution, nothing dropped.
+                  Exact for ANY g <= 0: every exponent that is taken is a
+                  difference of cumulated log-decays that is at most 0, in
+                  float32, so no factor exceeds 1 however fast a channel
+                  forgets (a product about a reference in the middle of a
+                  row's sub-chunk, the form this file had, needs exp(+8 |g|),
+                  an `inf` at g = -12 a step). Inside a sub-chunk of `SUB`
+                  positions A and B take exp(G_i - G_j) pair by pair; across
+                  sub-chunks, J before I, they are products about the
+                  cumulated decay at I's first position: exp(G_i - first_I) *
+                  exp(first_I - G_j), each at most 1, whose product underflows
+                  only where the pair's own decay does.
+                  (I + A)^-1 is exact: forward substitution inside the
+                  sub-chunks, the blocks below from those, nothing dropped.
+                  A prompt longer than `SPAN` goes a span at a time, the
+                  state carried: the pair terms, the inverse and the float32
+                  copies are a span's, not the prompt's.
 `kda_decode`      one position for every row of a slot pool as ONE Pallas
                   kernel: a row's state is read once and written once, in
                   place (`input_output_aliases`); a row whose `live` bit is 0
@@ -47,7 +55,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-SUB = 16  # positions about one reference, 8 on either side: 8 * 5 = 40, far inside float32's +-87
+CHUNK = 64  # positions a step of the chunked form's scan over the state takes
+SUB = 16  # positions whose decays are taken pair by pair; a chunk is whole sub-chunks
+SPAN = 1024  # positions whose pair terms are formed at once
 _HEADS_PER_CALL = 32  # 4 vectors x 32 heads fill the 128 rows one in-kernel transpose takes
 _VMEM_LIMIT = 64 * 1024 * 1024
 _HIGHEST = lax.Precision.HIGHEST
@@ -95,46 +105,62 @@ def _unit_lower_inverse(a):
     return inv
 
 
-def kda_chunked(q, k, v, g, beta, state=None, chunk: int = 64):
-    """`kda_recurrent`'s numbers, a chunk at a time. The same arguments;
-    t is padded up to whole chunks with identity positions."""
-    b, t, h, dk = q.shape
+def _unit_lower_inverse_blocks(a):
+    """(I + a)^-1 for strictly lower triangular a [..., ns, SUB, ns, SUB],
+    block (I, J) at [..., I, :, J, :]: the diagonal blocks by forward
+    substitution (`SUB` steps for all of them at once), the blocks below by
+    T_IJ = -T_II sum_{J <= K < I} a_IK T_KJ. Exact; returns [..., c, c]."""
+    ns = a.shape[-4]
+    mm = lambda x, y: jnp.einsum("...ij,...jk->...ik", x, y, precision=_HIGHEST)
+    diag = _unit_lower_inverse(jnp.stack([a[..., s, :, s, :] for s in range(ns)], axis=-3))  # [..., ns, SUB, SUB]
+    t = [[None] * ns for _ in range(ns)]
+    for i in range(ns):
+        t[i][i] = diag[..., i, :, :]
+        for j in range(i):
+            t[i][j] = -mm(t[i][i], sum(mm(a[..., i, :, k, :], t[k][j]) for k in range(j, i)))
+    zero = jnp.zeros_like(t[0][0])
+    return jnp.concatenate([jnp.concatenate([t[i][j] if j <= i else zero for j in range(ns)], axis=-1)
+                            for i in range(ns)], axis=-2)
+
+
+def _span_chunked(state, q, k, v, g, beta, chunk: int):
+    """`kda_chunked` over one span: q, k, g [b, L, h, d_k], v [b, L, h, d_v],
+    beta [b, L, h], L whole chunks; state [b, h, d_k, d_v] float32. Every
+    temporary here is the span's, whatever the prompt's length."""
+    b, L, h, dk = q.shape
     dv = v.shape[-1]
-    chunk = min(chunk, -(-t // SUB) * SUB)
-    if chunk % SUB:
-        raise ValueError(f"chunk {chunk} is not a multiple of {SUB}")
-    pad = -t % chunk
-    q, k, v, g, beta = (jnp.pad(x.astype(jnp.float32), ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
-                        for x in (q, k, v, g, beta))
-    n, c, ns = (t + pad) // chunk, chunk, chunk // SUB
-    # [b, h, n, c, .]: a head's chunks side by side
-    split = lambda x: jnp.moveaxis(x.reshape(b, n, c, h, -1), 3, 1)
-    q, k, v, g = split(q), split(k), split(v), split(g)
-    beta = split(beta[..., None])  # [b, h, n, c, 1]
-    G = jnp.cumsum(g, axis=-2)  # includes the position's own decay
-    # the reference of a position: the log-decay cumulated up to the middle of its sub-chunk
-    middle = G[..., SUB // 2 - 1::SUB, :]  # [.., ns, dk]
-    toward = jnp.exp(G - jnp.repeat(middle, SUB, axis=-2))  # from the reference on (or back) to the position
-    q_in, k_in = q * toward, k * toward
-    # keys carried to each reference: exp(ref_I - G_j), within exp(+-SUB / 2 * |g|) for j in
-    # sub-chunk I, below 1 for earlier j; later j are never used, and are held at a cap
-    cap = lax.stop_gradient(1.0 - SUB // 2 * jnp.min(g))  # above every exponent that is used: no tie
-    back = jnp.exp(jnp.minimum(middle[..., :, None, :] - G[..., None, :, :], cap))
-    k_out = (k * beta)[..., None, :, :] * back  # [.., ns, c, dk]
-    rows = lambda x: x.reshape(*x.shape[:-2], ns, SUB, dk)
-    pair = lambda x: jnp.einsum("...sid,...sjd->...sij", rows(x), k_out, precision=_HIGHEST).reshape(
-        *x.shape[:-2], c, c)
-    i = jnp.arange(c)
-    A = jnp.where(i[:, None] > i[None, :], pair(k_in), 0.0)
-    B = jnp.where(i[:, None] >= i[None, :], pair(q_in), 0.0)
-    T = _unit_lower_inverse(A)
+    n, c, ns = L // chunk, chunk, chunk // SUB
+    # [b, h, n, ns, SUB, .]: a head's chunks side by side, a chunk's sub-chunks too
+    split = lambda x: jnp.moveaxis(x.astype(jnp.float32).reshape(b, n, ns, SUB, h, -1), 4, 1)
+    q, k, v, g, beta = split(q), split(k), split(v), split(g), split(beta[..., None])
+    whole = lambda x: x.reshape(b, h, n, c, x.shape[-1])
+    G = jnp.cumsum(whole(g), axis=-2).reshape(g.shape)  # from the chunk's start, the position's own decay included
+    first = G[..., :1, :] - g[..., :1, :]  # [.., ns, 1, dk]: cumulated up to each sub-chunk's first position
+    local = G - first  # from the sub-chunk's start on to the position: <= 0
+    kb = k * beta
+    i = jnp.arange(SUB)
+    # inside a sub-chunk, pair by pair: exp(G_i - G_j) itself, j <= i
+    decay = jnp.where((i[:, None] >= i[None, :])[..., None],
+                      jnp.exp(jnp.minimum(local[..., :, None, :] - local[..., None, :, :], 0.0)), 0.0)
+    inside = lambda x: (x[..., :, None, :] * kb[..., None, :, :] * decay).sum(-1)  # [.., ns, SUB, SUB]
+    # across sub-chunks, J < I: exp(G_i - first_I) * exp(first_I - G_j), each factor at most 1
+    toward = jnp.exp(local)
+    back = jnp.exp(jnp.minimum(first - whole(G)[..., None, :, :], 0.0))  # [.., ns, c, dk]
+    k_out = whole(kb)[..., None, :, :] * back
+    across = lambda x: jnp.einsum("...sid,...sjd->...sij", x * toward, k_out, precision=_HIGHEST).reshape(
+        b, h, n, ns, SUB, ns, SUB)
+    s = jnp.arange(ns)
+    earlier, same = s[:, None, None, None] > s[None, None, :, None], s[:, None, None, None] == s[None, None, :, None]
+    pair = lambda x, keep: (jnp.where(earlier, across(x), 0.0)
+                            + jnp.where(same, jnp.where(keep, inside(x), 0.0)[..., :, :, None, :], 0.0))
+    T = _unit_lower_inverse_blocks(pair(k, i[:, None] > i[None, :]))  # [b, h, n, c, c]
+    B = pair(q, i[:, None] >= i[None, :]).reshape(b, h, n, c, c)
+    q, k, v, G, kb = whole(q), whole(k), whole(v), whole(G), whole(kb)
     decay_in = jnp.exp(G)  # from the chunk's start
-    k_end = k * beta * jnp.exp(G[..., -1:, :] - G)  # on to the chunk's end
-    if state is None:
-        state = jnp.zeros((b, h, dk, dv), jnp.float32)
+    k_end = kb * jnp.exp(G[..., -1:, :] - G)  # on to the chunk's end
 
     def one(s, x):
-        q_c, k_c, v_c, T_c, B_c, qd, kd, ke, last = x
+        v_c, T_c, B_c, qd, kd, ke, last = x
         w = jnp.einsum("...ij,...jv->...iv", T_c,
                        v_c - jnp.einsum("...ik,...kv->...iv", kd, s, precision=_HIGHEST), precision=_HIGHEST)
         o = (jnp.einsum("...ik,...kv->...iv", qd, s, precision=_HIGHEST)
@@ -143,10 +169,38 @@ def kda_chunked(q, k, v, g, beta, state=None, chunk: int = 64):
         return s, o
 
     by_chunk = tuple(jnp.moveaxis(x, 2, 0) for x in (
-        q, k, v, T, B, q * decay_in, k * decay_in, k_end, decay_in[..., -1, :]))
-    state, o = lax.scan(one, state.astype(jnp.float32), by_chunk)  # o [n, b, h, c, dv]
-    o = jnp.moveaxis(o, 0, 2).reshape(b, h, n * c, dv)
-    return jnp.moveaxis(o, 1, 2)[:, :t], state
+        v, T, B, q * decay_in, k * decay_in, k_end, decay_in[..., -1, :]))
+    state, o = lax.scan(one, state, by_chunk)  # o [n, b, h, c, dv]
+    return state, jnp.moveaxis(o, (0, 3), (1, 2)).reshape(b, L, h, dv)
+
+
+def kda_chunked(q, k, v, g, beta, state=None, chunk: int = CHUNK, span: int = SPAN):
+    """`kda_recurrent`'s numbers, a chunk at a time, for any g <= 0. The
+    same arguments; t is padded up to whole chunks with identity positions.
+    Prompts longer than `span` go a span at a time (a scan that carries the
+    state), so that what is formed beside the inputs and the outputs does not
+    grow with the prompt."""
+    b, t, h, dk = q.shape
+    chunk = min(chunk, -(-t // SUB) * SUB)
+    if chunk % SUB:
+        raise ValueError(f"chunk {chunk} is not a multiple of {SUB}")
+    chunks = -(-t // chunk)
+    spans = -(-chunks // max(span // chunk, 1))
+    L = -(-chunks // spans) * chunk  # spans of equal length, the fewest chunks of padding
+    pad = spans * L - t
+    q, k, v, g, beta = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2)) for x in (q, k, v, g, beta))
+    if state is None:
+        state = jnp.zeros((b, h, dk, v.shape[-1]), jnp.float32)
+    state = state.astype(jnp.float32)
+    with jax.named_scope("kda_chunked"):
+        if spans == 1:
+            state, o = _span_chunked(state, q, k, v, g, beta, chunk)
+            return o[:, :t], state
+        by_span = tuple(jnp.moveaxis(x.reshape(b, spans, L, *x.shape[2:]), 1, 0) for x in (q, k, v, g, beta))
+        # a gradient keeps a span's state and recomputes the span: its residuals are the inputs'
+        body = jax.checkpoint(lambda s, x: _span_chunked(s, *x, chunk))
+        state, o = lax.scan(body, state, by_span)  # o [spans, b, L, h, dv]
+    return jnp.moveaxis(o, 0, 1).reshape(b, spans * L, h, -1)[:, :t], state
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ tests skip.
 """
 
 import importlib.machinery
+import os
 import sys
 import types
 
@@ -72,6 +73,10 @@ def load_reference():
 
 
 def reference_available() -> bool:
+    if not os.path.isdir(os.path.join(REFERENCE_PATH, "trlx")):
+        # not mounted: said before torch and peft are imported to find it out,
+        # 11 s that every xdist worker paid while it collected test_ops.py
+        return False
     try:
         load_reference()
         return True

@@ -75,8 +75,8 @@ def test_flash_gradients_match_naive():
         out = naive_attention(q, k, v, mask, causal=True)
         return jnp.sum(jnp.where(mask[:, :, None, None] > 0, out, 0.0) ** 2)
 
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_naive = jax.grad(loss_naive, argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    g_naive = jax.jit(jax.grad(loss_naive, argnums=(0, 1, 2)))(q, k, v)
     for gf, gn in zip(g_flash, g_naive):
         np.testing.assert_allclose(gf, gn, atol=1e-4, rtol=1e-4)
 
@@ -129,7 +129,7 @@ def test_ring_attention_gradable():
         return jnp.sum(naive_attention(q, k, v, causal=True) ** 2)
 
     g = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g, g_ref):
         np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
 
@@ -178,9 +178,9 @@ def test_model_ring_matches_xla():
     cfg_x = TransformerConfig(**base, attn_impl="xla")
     cfg_r = TransformerConfig(**base, attn_impl="ring")
     model_x, model_r = TransformerLM(cfg_x), TransformerLM(cfg_r)
-    params = model_x.init(jax.random.PRNGKey(0), jnp.asarray(tokens), jnp.asarray(mask))
+    params = jax.jit(model_x.init)(jax.random.PRNGKey(0), jnp.asarray(tokens), jnp.asarray(mask))
 
-    lx, _, _ = model_x.apply(params, jnp.asarray(tokens), jnp.asarray(mask))
+    lx, _, _ = jax.jit(model_x.apply)(params, jnp.asarray(tokens), jnp.asarray(mask))
 
     ring_fwd = shard_map(
         lambda p, tok, m: model_r.apply(p, tok, m)[0],
@@ -210,10 +210,10 @@ def test_model_flash_matches_xla():
     cfg_x = TransformerConfig(**base, attn_impl="xla")
     cfg_f = TransformerConfig(**base, attn_impl="flash")
     model_x, model_f = TransformerLM(cfg_x), TransformerLM(cfg_f)
-    params = model_x.init(jax.random.PRNGKey(0), jnp.asarray(tokens), jnp.asarray(mask))
+    params = jax.jit(model_x.init)(jax.random.PRNGKey(0), jnp.asarray(tokens), jnp.asarray(mask))
 
-    lx, _, _ = model_x.apply(params, jnp.asarray(tokens), jnp.asarray(mask))
-    lf, _, _ = model_f.apply(params, jnp.asarray(tokens), jnp.asarray(mask))
+    lx, _, _ = jax.jit(model_x.apply)(params, jnp.asarray(tokens), jnp.asarray(mask))
+    lf, _, _ = jax.jit(model_f.apply)(params, jnp.asarray(tokens), jnp.asarray(mask))
     valid = mask[:, :, None].astype(bool)
     np.testing.assert_allclose(
         np.where(valid, lx, 0), np.where(valid, lf, 0), atol=2e-4, rtol=2e-4
@@ -236,10 +236,10 @@ def test_model_blockwise_matches_xla():
     cfg_x = TransformerConfig(**base, attn_impl="xla")
     cfg_b = TransformerConfig(**base, attn_impl="blockwise")
     model_x, model_b = TransformerLM(cfg_x), TransformerLM(cfg_b)
-    params = model_x.init(jax.random.PRNGKey(0), jnp.asarray(tokens), jnp.asarray(mask))
+    params = jax.jit(model_x.init)(jax.random.PRNGKey(0), jnp.asarray(tokens), jnp.asarray(mask))
 
-    lx, _, _ = model_x.apply(params, jnp.asarray(tokens), jnp.asarray(mask))
-    lb, _, _ = model_b.apply(params, jnp.asarray(tokens), jnp.asarray(mask))
+    lx, _, _ = jax.jit(model_x.apply)(params, jnp.asarray(tokens), jnp.asarray(mask))
+    lb, _, _ = jax.jit(model_b.apply)(params, jnp.asarray(tokens), jnp.asarray(mask))
     valid = mask[:, :, None].astype(bool)
     np.testing.assert_allclose(
         np.where(valid, lx, 0), np.where(valid, lb, 0), atol=2e-4, rtol=2e-4
@@ -251,8 +251,8 @@ def test_model_blockwise_matches_xla():
             return (lg * mask[:, :, None]).sum()
         return f
 
-    gx = jax.grad(loss(model_x))(params)
-    gb = jax.grad(loss(model_b))(params)
+    gx = jax.jit(jax.grad(loss(model_x)))(params)
+    gb = jax.jit(jax.grad(loss(model_b)))(params)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-4, rtol=5e-4
@@ -276,15 +276,15 @@ def test_fully_masked_query_rows_have_finite_grads():
     mask = jnp.asarray(mask)
 
     # deliberately do NOT mask the output: pad-row upstream grads flow
-    g = jax.grad(lambda q: jnp.sum(blockwise_attention(q, q, q, mask, True, 8) ** 2))(q)
+    g = jax.jit(jax.grad(lambda q: jnp.sum(blockwise_attention(q, q, q, mask, True, 8) ** 2)))(q)
     assert np.isfinite(np.asarray(g)).all()
 
     runtime = MeshRuntime.from_config(
         type("P", (), {"data": 2, "fsdp": 1, "tensor": 1, "sequence": 4})()
     )
-    g2 = jax.grad(
+    g2 = jax.jit(jax.grad(
         lambda q: jnp.sum(context_parallel_attention(runtime.mesh, q, q, q, mask) ** 2)
-    )(q)
+    ))(q)
     assert np.isfinite(np.asarray(g2)).all()
 
 
@@ -339,8 +339,8 @@ def test_flash_backward_xla_matches_dense_autodiff(nkv):
     def loss_dense(q, k, v):
         return (_dense_attention(q, k, v, mask, causal=True) ** 2).sum()
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
     for a, b_ in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=2e-4, rtol=2e-4)
@@ -513,14 +513,14 @@ def test_dense_forward_gradients_match_repeated_kv(nh, nkv, variant):
     cfg, ref_cfg = _attn_cfg(nh, nkv, **extra), _attn_cfg(nh, nh, **extra)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 9), 0, cfg.vocab_size)
     mask = jnp.ones_like(tokens).at[0, :3].set(0)
-    params = TransformerLM(cfg).init(jax.random.PRNGKey(1), tokens, mask)["params"]
+    params = jax.jit(TransformerLM(cfg).init)(jax.random.PRNGKey(1), tokens, mask)["params"]
     weight = jax.random.normal(jax.random.PRNGKey(2), (2, 9, cfg.vocab_size), jnp.float32)
 
     def loss(model_cfg):
         return lambda p: (TransformerLM(model_cfg).apply({"params": p}, tokens, mask)[0] * weight).sum()
 
-    value, grads = jax.value_and_grad(loss(cfg))(params)
-    ref_value, ref_grads = jax.value_and_grad(loss(ref_cfg))(_repeat_kv_heads(params, nkv, g))
+    value, grads = jax.jit(jax.value_and_grad(loss(cfg)))(params)
+    ref_value, ref_grads = jax.jit(jax.value_and_grad(loss(ref_cfg)))(_repeat_kv_heads(params, nkv, g))
     np.testing.assert_allclose(value, ref_value, rtol=1e-5)
 
     def fold(a, like):

@@ -90,5 +90,5 @@ def test_f32_composed_still_runs(tmp_path):
                      dict(data=2, pipeline=2, tensor=2), "f32", dtype="float32")
     trainer = PipelinedSFTTrainer(config)
     loss_fn, flat, batch = _loss_and_batch(trainer)
-    loss, _ = loss_fn(flat, {}, batch)
+    loss, _ = jax.jit(loss_fn)(flat, {}, batch)
     assert np.isfinite(float(jax.device_get(loss)))

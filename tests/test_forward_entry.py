@@ -44,7 +44,7 @@ def lms():
         model = TransformerLM(cfg)
         tokens = jax.random.randint(jax.random.PRNGKey(i), (B, T), 1, VOCAB)
         mask = (jnp.arange(T)[None, :] >= jnp.asarray([0, 2, 5])[:, None]).astype(jnp.int32)
-        params = model.init(jax.random.PRNGKey(10 + i), tokens, mask)["params"]
+        params = jax.jit(model.init)(jax.random.PRNGKey(10 + i), tokens, mask)["params"]
         out[kind] = (cfg, model, params, tokens, mask)
     return out
 
@@ -221,7 +221,7 @@ def test_conv_state_goes_through_the_per_row_branch_but_for_speculative_decode(l
 def test_policy_rules_sit_once_each(lms):
     cfg, _, _, tokens, mask = lms["dense"]
     branch = CausalLMWithValueHead(cfg, num_value_layers=1)
-    params = branch.init(jax.random.PRNGKey(0), tokens, mask)["params"]
+    params = jax.jit(branch.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
     with pytest.raises(NotImplementedError, match="value branch"):
         _forward(branch, params, tokens, mask, window=(2, 3))
     cache = init_kv_cache(cfg, B, T)
@@ -237,7 +237,7 @@ def test_policy_rules_sit_once_each(lms):
     _same(again[1], values)
 
     critic_free = CausalLMPolicy(cfg)
-    params = critic_free.init(jax.random.PRNGKey(0), tokens, mask)["params"]
+    params = jax.jit(critic_free.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
     assert "v_head" not in params
     assert _forward(critic_free, params, tokens, mask, window=(2, 3))[1] is None
     assert _step(critic_free, params, tokens, cache, mask, True)[1] is None
@@ -250,7 +250,7 @@ def test_prompt_tuning_refuses_what_the_soft_prompt_would_shift(kwargs):
     cfg = _cfg("dense", prompt_tokens=2)
     model = TransformerLM(cfg)
     tokens, mask = jnp.ones((B, T), jnp.int32), jnp.ones((B, T), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
     with pytest.raises(NotImplementedError, match="prompt tuning"):
         _forward(model, params, tokens, mask, **kwargs)
     assert _forward(model, params, tokens, mask)[0].shape == (B, T, VOCAB)

@@ -72,13 +72,13 @@ def test_a_chunk_edge_and_a_carried_state():
     """Two calls of the chunked form, the second from the first's state, cut
     inside a sub-chunk: the whole sequence's numbers."""
     x = inputs(1, slow=True)
-    o_whole, s_whole = la.kda_recurrent(*x)
-    o_a, s_a = la.kda_chunked(*(a[:, :37] for a in x))
-    o_b, s_b = la.kda_chunked(*(a[:, 37:] for a in x), state=s_a)
+    o_whole, s_whole = jax.jit(la.kda_recurrent)(*x)
+    o_a, s_a = jax.jit(la.kda_chunked)(*(a[:, :37] for a in x))
+    o_b, s_b = jax.jit(la.kda_chunked)(*(a[:, 37:] for a in x), state=s_a)
     assert np.abs(jnp.concatenate([o_a, o_b], 1) - o_whole).max() < 1e-5
     assert np.abs(s_b - s_whole).max() < 1e-5
     for chunk in (16, 32):  # other chunk sizes, the same numbers
-        assert np.abs(la.kda_chunked(*x, chunk=chunk)[0] - o_whole).max() < 1e-5
+        assert np.abs(jax.jit(lambda *a: la.kda_chunked(*a, chunk=chunk))(*x)[0] - o_whole).max() < 1e-5
     with pytest.raises(ValueError, match="multiple of 16"):
         la.kda_chunked(*x, chunk=24)
 
@@ -94,9 +94,9 @@ def test_a_padded_position_is_the_identity(side):
     # garbage where the padding is, but for what makes it the identity
     noise = jax.random.normal(jax.random.PRNGKey(9), q.shape)
     q, k = (jnp.where(jnp.zeros_like(a).at[:, real].set(1) > 0, a, noise) for a in (q, k))
-    o_want, s_want = la.kda_recurrent(*x)
+    o_want, s_want = jax.jit(la.kda_recurrent)(*x)
     for form in (la.kda_recurrent, la.kda_chunked):
-        o, s = form(q, k, v, g, beta)
+        o, s = jax.jit(form)(q, k, v, g, beta)
         assert np.abs(o[:, real] - o_want).max() < 1e-5 and np.abs(s - s_want).max() < 1e-5
 
 
@@ -105,11 +105,11 @@ def test_the_published_lower_bound_on_every_channel_does_not_overflow():
     would need e^320; no factor the chunked form takes exceeds 1."""
     q, k, v, g, beta = inputs(3, t=64, b=1)
     g = jnp.full_like(g, -5.0)
-    o_scan, s_scan = la.kda_recurrent(q, k, v, g, beta)
-    o, s = la.kda_chunked(q, k, v, g, beta)
+    o_scan, s_scan = jax.jit(la.kda_recurrent)(q, k, v, g, beta)
+    o, s = jax.jit(la.kda_chunked)(q, k, v, g, beta)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
     assert np.abs(o - o_scan).max() < 1e-5 and np.abs(s - s_scan).max() < 1e-5
-    grads = jax.grad(lambda g: la.kda_chunked(q, k, v, g, beta)[0].sum())(g)
+    grads = jax.jit(jax.grad(lambda g: la.kda_chunked(q, k, v, g, beta)[0].sum()))(g)
     assert bool(jnp.isfinite(grads).all())
 
 
@@ -121,10 +121,10 @@ def test_the_chunked_form_differentiates_like_the_scan(decay):
     from the state it kept."""
     x = inputs(4, t=40, **decay)
     loss = lambda form: lambda *a: (form(*a)[0] ** 2).sum() + form(*a)[1].sum()
-    want = jax.grad(loss(la.kda_recurrent), argnums=(0, 1, 2, 3, 4))(*x)
+    want = jax.jit(jax.grad(loss(la.kda_recurrent), argnums=(0, 1, 2, 3, 4)))(*x)
     by_spans = lambda *a: la.kda_chunked(*a, chunk=16, span=16)
     for form in (la.kda_chunked, by_spans) if decay.get("slow") else (la.kda_chunked,):
-        got = jax.grad(loss(form), argnums=(0, 1, 2, 3, 4))(*x)
+        got = jax.jit(jax.grad(loss(form), argnums=(0, 1, 2, 3, 4)))(*x)
         for a, b, name in zip(want, got, "q k v g beta".split()):
             if not (name == "g" and decay.get("g_step") == -30.0):  # nothing survives a step: no gradient to the decay
                 assert float(jnp.abs(a).max()) > 1e-3, name

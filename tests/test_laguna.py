@@ -28,6 +28,7 @@ sys.path[:0] = [p for p in (BENCH,) if p not in sys.path]
 
 from benchlib.files import load_module  # noqa: E402
 
+from parity import jitted_forward, jitted_init  # noqa: E402
 from trlx_tpu.models import CausalLMPolicy, CausalLMWithValueHead, config_from_preset  # noqa: E402
 from trlx_tpu.models import hf_interop  # noqa: E402
 from trlx_tpu.models.transformer import (  # noqa: E402
@@ -53,7 +54,7 @@ def sizes_of(cfg):
 def seeded_params(model, seed, *init_args):
     """Every leaf drawn from the seed, the selection bias too (a fresh init
     leaves it at zero, and then it would steer nothing)."""
-    params = model.init(jax.random.PRNGKey(seed), *init_args)["params"]
+    params = jitted_init(model)(jax.random.PRNGKey(seed), *init_args)["params"]
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
     rng = np.random.default_rng(seed)
     out = []
@@ -89,7 +90,7 @@ def reference_logprobs(lm_params, cfg, tokens, mask):
 
 def forward_logprobs(cfg, params, tokens, mask):
     with jax.default_matmul_precision("highest"):
-        logits = TransformerLM(cfg).apply({"params": params}, jnp.asarray(tokens), jnp.asarray(mask))[0]
+        logits = jitted_forward(cfg)(params, tokens, mask)
     return np.asarray(plain.logprobs_of_next(logits, jnp.asarray(tokens)))
 
 

@@ -19,6 +19,7 @@ sys.path[:0] = [p for p in (BENCH,) if p not in sys.path]
 
 from benchlib.files import load_module  # noqa: E402
 
+from parity import jitted_forward, jitted_init  # noqa: E402
 from trlx_tpu.models import CausalLMWithValueHead, config_from_preset, init_kv_cache  # noqa: E402
 from trlx_tpu.models import hf_interop  # noqa: E402
 from trlx_tpu.models.transformer import SparseMoE, TransformerLM, init_paged_kv_arena  # noqa: E402
@@ -47,7 +48,7 @@ def sizes_of(cfg):
 def seeded_params(model, seed, *init_args):
     """Every leaf drawn from the seed, the selection bias too (a fresh init
     leaves it at zero, and then it would steer nothing)."""
-    params = model.init(jax.random.PRNGKey(seed), *init_args)["params"]
+    params = jitted_init(model)(jax.random.PRNGKey(seed), *init_args)["params"]
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
     rng = np.random.default_rng(seed)
     out = []
@@ -92,7 +93,7 @@ def test_forward_matches_the_reference(seed):
     tokens, mask = left_padded(np.random.default_rng(seed), [24, 9, 2, 17], 24)
     params = seeded_params(model, seed, jnp.asarray(tokens), jnp.asarray(mask))
     with jax.default_matmul_precision("highest"):
-        logits = model.apply({"params": params}, jnp.asarray(tokens), jnp.asarray(mask))[0]
+        logits = jitted_forward(cfg)(params, tokens, mask)
     got = np.asarray(plain.logprobs_of_next(logits, jnp.asarray(tokens)))
     want = np.asarray(ref.logprobs(params, tokens, mask, sizes_of(cfg)))
     valid = (mask[:, :-1] * mask[:, 1:]).astype(bool)
@@ -153,8 +154,8 @@ def test_expert_layer_gradients_match_the_reference(mode, monkeypatch):
     layer, x, params = _layer_inputs(cfg, 11)
     probe = jnp.asarray(np.random.default_rng(1).normal(size=x.shape), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        got = jax.value_and_grad(lambda p, h: (layer.apply({"params": p}, h) * probe).sum(), (0, 1))(params, x)
-        want = jax.value_and_grad(lambda p, h: (_reference_layer(h, p, cfg) * probe).sum(), (0, 1))(params, x)
+        got = jax.jit(jax.value_and_grad(lambda p, h: (layer.apply({"params": p}, h) * probe).sum(), (0, 1)))(params, x)
+        want = jax.jit(jax.value_and_grad(lambda p, h: (_reference_layer(h, p, cfg) * probe).sum(), (0, 1)))(params, x)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
     flat_got, flat_want = (jax.tree_util.tree_leaves_with_path(g[1]) for g in (got, want))
     for (path, a), (_, b) in zip(flat_got, flat_want):

@@ -35,6 +35,7 @@ sys.path[:0] = [p for p in (BENCH,) if p not in sys.path]
 from benchlib import weights  # noqa: E402
 from benchlib.files import load_module  # noqa: E402
 
+from parity import jitted_forward, jitted_init  # noqa: E402
 from trlx_tpu.inference import InferenceEngine, Scheduler  # noqa: E402
 from trlx_tpu.models import CausalLMPolicy, CausalLMWithValueHead, config_from_preset  # noqa: E402
 from trlx_tpu.models import hf_interop  # noqa: E402
@@ -139,7 +140,7 @@ def test_forward_matches_the_reference_and_each_assumed_item_flipped_does_not(po
     cfg, params = policy
     tokens, mask = left_padded(np.random.default_rng(7), [70, 33, 5], 70)
     with jax.default_matmul_precision("highest"):
-        logits = TransformerLM(cfg).apply({"params": params["lm"]}, jnp.asarray(tokens), jnp.asarray(mask))[0]
+        logits = jitted_forward(cfg)(params["lm"], tokens, mask)
     got = np.asarray(plain.logprobs_of_next(logits, jnp.asarray(tokens)))
     want = reference_logprobs(params["lm"], cfg, tokens, mask, *([departure] if departure else []))
     valid = (mask[:, 1:] * mask[:, :-1]).astype(bool)
@@ -191,7 +192,7 @@ def test_sampler_through_the_scalar_index_cache_matches_the_reference(policy):
     cfg, params = policy
     model = CausalLMWithValueHead(cfg)
     tokens, mask = left_padded(np.random.default_rng(5), [20, 5, 1], 20)
-    full = model.init(jax.random.PRNGKey(0), jnp.asarray(tokens), jnp.asarray(mask))["params"]
+    full = jitted_init(model)(jax.random.PRNGKey(0), jnp.asarray(tokens), jnp.asarray(mask))["params"]
     full = {**full, "lm": params["lm"]}
     gen_cfg = GenerationConfig(max_new_tokens=40, do_sample=True, eos_token_id=VOCAB + 1, pad_token_id=0)
     generate = jax.jit(make_generate_fn(model, cfg, gen_cfg, capture=True))

@@ -87,7 +87,7 @@ def _convert(tmp_path, family):
     cfg = hf_interop.config_from_hf(path, dtype=jnp.float32)
     model = CausalLMWithValueHead(cfg)
     tokens = jnp.zeros((1, 8), jnp.int32)
-    template = model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"]
+    template = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"]
     params = hf_interop.load_params_from_hf(path, cfg, template)
     return hf_model, cfg, model, params, path
 
@@ -114,7 +114,7 @@ def test_logits_parity(tmp_path, family, rng):
             input_ids=torch.tensor(tokens), attention_mask=torch.tensor(mask), **kwargs
         ).logits.numpy()
 
-    logits, _, _ = model.apply(
+    logits, _, _ = jax.jit(model.apply)(
         {"params": params}, jnp.asarray(tokens, jnp.int32), jnp.asarray(mask, jnp.int32)
     )
     ours = np.asarray(logits, np.float32)
@@ -163,7 +163,7 @@ def test_mistral_sliding_window_parity(tmp_path, rng):
     assert cfg.sliding_window == 6
     model = CausalLMWithValueHead(cfg)
     tokens8 = jnp.zeros((1, 8), jnp.int32)
-    template = model.init(jax.random.PRNGKey(0), tokens8, jnp.ones_like(tokens8))["params"]
+    template = jax.jit(model.init)(jax.random.PRNGKey(0), tokens8, jnp.ones_like(tokens8))["params"]
     params = hf_interop.load_params_from_hf(path, cfg, template)
 
     tokens = rng.integers(0, VOCAB, size=(2, SEQ))  # SEQ=16 > window=6
@@ -172,14 +172,14 @@ def test_mistral_sliding_window_parity(tmp_path, rng):
         ref = hf_model(
             input_ids=torch.tensor(tokens), attention_mask=torch.tensor(mask)
         ).logits.numpy()
-    ours, _, _ = model.apply(
+    ours, _, _ = jax.jit(model.apply)(
         {"params": params}, jnp.asarray(tokens, jnp.int32), jnp.asarray(mask, jnp.int32)
     )
     np.testing.assert_allclose(np.asarray(ours), ref, atol=2e-3, rtol=2e-3)
 
     # windowed != unwindowed beyond the band (the test actually bites)
     cfg_nw = hf_interop.config_from_hf(path, dtype=jnp.float32, sliding_window=None)
-    logits_nw, _, _ = CausalLMWithValueHead(cfg_nw).apply(
+    logits_nw, _, _ = jax.jit(CausalLMWithValueHead(cfg_nw).apply)(
         {"params": params}, jnp.asarray(tokens, jnp.int32), jnp.asarray(mask, jnp.int32)
     )
     assert not np.allclose(np.asarray(ours)[:, -1], np.asarray(logits_nw)[:, -1], atol=1e-4)
@@ -196,22 +196,19 @@ def test_sliding_window_decode_matches_forward():
     rng_np = np.random.default_rng(0)
     tokens = jnp.asarray(rng_np.integers(0, 64, (2, 12)), jnp.int32)
     mask = jnp.ones_like(tokens)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)["params"]
-    full_logits, _, _ = model.apply({"params": params}, tokens, mask)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
+    full_logits, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
 
+    step = jax.jit(lambda tok, cache, m, prefill: model.apply(
+        {"params": params}, tok, cache, m, prefill, method=TransformerLM.decode_step),
+        static_argnums=3)
     cache = init_kv_cache(cfg, 2, 12, dtype=jnp.float32)
-    logits, _, cache = model.apply(
-        {"params": params}, tokens[:, :6], cache, mask[:, :6], True,
-        method=TransformerLM.decode_step,
-    )
+    logits, _, cache = step(tokens[:, :6], cache, mask[:, :6], True)
     np.testing.assert_allclose(
         np.asarray(logits), np.asarray(full_logits[:, :6]), atol=1e-4
     )
     for i in range(6, 12):
-        logits, _, cache = model.apply(
-            {"params": params}, tokens[:, i:i + 1], cache, mask[:, i:i + 1], False,
-            method=TransformerLM.decode_step,
-        )
+        logits, _, cache = step(tokens[:, i:i + 1], cache, mask[:, i:i + 1], False)
         np.testing.assert_allclose(
             np.asarray(logits[:, 0]), np.asarray(full_logits[:, i]), atol=1e-4,
             err_msg=f"step {i}",
@@ -262,7 +259,7 @@ def _convert_t5(tmp_path, variant):
     assert cfg.is_seq2seq and cfg.hf_family == "t5"
     model = Seq2SeqLMWithValueHead(cfg)
     tok = jnp.zeros((1, 8), jnp.int32)
-    template = model.init(
+    template = jax.jit(model.init)(
         jax.random.PRNGKey(0), tok, jnp.ones_like(tok), tok, jnp.ones_like(tok)
     )["params"]
     params = hf_interop.load_params_from_hf(path, cfg, template)
@@ -270,7 +267,7 @@ def _convert_t5(tmp_path, variant):
 
 
 def _t5_logits(model, params, enc, enc_mask, dec, dec_mask):
-    logits, _, _, _ = model.apply(
+    logits, _, _, _ = jax.jit(model.apply, static_argnums=5)(
         {"params": params},
         jnp.asarray(enc, jnp.int32), jnp.asarray(enc_mask, jnp.int32),
         jnp.asarray(dec, jnp.int32), jnp.asarray(dec_mask, jnp.int32), 0,
@@ -403,8 +400,8 @@ def test_preset_coverage():
         cfg = config_from_preset(name, vocab_size=64, dtype=jnp.float32)
         model = CausalLMWithValueHead(cfg)
         tokens = jnp.zeros((1, 8), jnp.int32)
-        params = model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"]
-        logits, values, _ = model.apply({"params": params}, tokens, jnp.ones_like(tokens))
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"]
+        logits, values, _ = jax.jit(model.apply)({"params": params}, tokens, jnp.ones_like(tokens))
         assert logits.shape == (1, 8, 64)
         assert np.all(np.isfinite(np.asarray(logits)))
 

@@ -38,7 +38,7 @@ def test_forward_shapes(preset):
     _, model, cfg, params = tiny_model(preset=preset)
     tokens = jnp.zeros((2, 8), dtype=jnp.int32)
     mask = jnp.ones_like(tokens)
-    logits, values, h = model.apply({"params": params}, tokens, mask)
+    logits, values, h = jax.jit(model.apply)({"params": params}, tokens, mask)
     assert logits.shape == (2, 8, 64)
     assert values.shape == (2, 8)
 
@@ -52,16 +52,16 @@ def test_decode_matches_forward(preset):
     mask = jnp.asarray([[0, 0, 1, 1, 1, 1, 1, 1, 1, 1], [0, 0, 0, 0, 1, 1, 1, 1, 1, 1]], jnp.int32)
 
     cache = init_kv_cache(cfg, 2, 12)
-    step = lambda t, c, m, pre: model.apply(
+    step = jax.jit(lambda t, c, m, pre: model.apply(
         {"params": params}, t, c, m, is_prefill=pre, method=type(model).decode_step
-    )
+    ), static_argnums=3)
     lg, _, cache = step(tokens[:, :6], cache, mask[:, :6], True)
     outs = [lg[:, -1]]
     for i in range(6, 10):
         lg, _, cache = step(tokens[:, i : i + 1], cache, mask[:, i : i + 1], False)
         outs.append(lg[:, 0])
     stepwise = jnp.stack(outs, 1)
-    full, _, _ = model.apply({"params": params}, tokens, mask)
+    full, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     np.testing.assert_allclose(np.asarray(stepwise), np.asarray(full[:, 5:10]), atol=2e-4)
 
 
@@ -167,7 +167,7 @@ def test_sharded_forward_on_mesh():
     sharded = jax.tree_util.tree_map(jax.device_put, params, shardings)
     tokens = jnp.asarray(np.random.RandomState(1).randint(0, 64, (8, 8)), dtype=jnp.int32)
     mask = jnp.ones_like(tokens)
-    logits_single, _, _ = model.apply({"params": params}, tokens, mask)
+    logits_single, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     f = jax.jit(lambda p, t, m: model.apply({"params": p}, t, m)[0])
     logits_sharded = f(sharded, runtime.shard_batch(tokens), runtime.shard_batch(mask))
     np.testing.assert_allclose(np.asarray(logits_sharded), np.asarray(logits_single), atol=2e-4)
@@ -188,16 +188,16 @@ def test_value_branch_model():
 
     tokens = jnp.asarray(np.arange(32).reshape(2, 16) % 64, jnp.int32)
     mask = jnp.ones_like(tokens)
-    logits, values, _ = model.apply({"params": params}, tokens, mask)
+    logits, values, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     assert values.shape == tokens.shape
 
     # logits identical to the plain value-head model on the same lm params
     _, m0, _, p0 = tiny_model()
-    logits0, _, _ = m0.apply({"params": {**p0, "lm": params["lm"]}}, tokens, mask)
+    logits0, _, _ = jax.jit(m0.apply)({"params": {**p0, "lm": params["lm"]}}, tokens, mask)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(logits0), atol=1e-5)
 
     # value gradients reach the branch
-    g = jax.grad(lambda p: jnp.sum(model.apply({"params": p}, tokens, mask)[1] ** 2))(params)
+    g = jax.jit(jax.grad(lambda p: jnp.sum(model.apply({"params": p}, tokens, mask)[1] ** 2)))(params)
     gn = sum(float(np.abs(np.asarray(x)).sum())
              for x in jax.tree_util.tree_leaves(g["value_branch"]))
     assert gn > 0

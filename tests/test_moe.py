@@ -22,12 +22,12 @@ def test_moe_forward_finite_and_param_shapes():
     model = TransformerLM(cfg)
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 8)), jnp.int32)
     mask = jnp.ones_like(tokens)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
     mlp = params["block_0"]["mlp"]
     assert mlp["up_proj"].shape == (4, cfg.d_model, cfg.d_ff)
     assert mlp["down_proj"].shape == (4, cfg.d_ff, cfg.d_model)
     assert mlp["router"]["kernel"].shape == (cfg.d_model, 4)
-    logits, _, _ = model.apply({"params": params}, tokens, mask)
+    logits, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     assert np.all(np.isfinite(np.asarray(logits)))
 
 
@@ -63,20 +63,17 @@ def test_moe_decode_matches_forward():
     rng_np = np.random.default_rng(0)
     tokens = jnp.asarray(rng_np.integers(0, 64, (2, 10)), jnp.int32)
     mask = jnp.ones_like(tokens)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)["params"]
-    full_logits, _, _ = model.apply({"params": params}, tokens, mask)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
+    full_logits, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
 
+    step = jax.jit(lambda tok, cache, m, prefill: model.apply(
+        {"params": params}, tok, cache, m, prefill, method=TransformerLM.decode_step),
+        static_argnums=3)
     cache = init_kv_cache(cfg, 2, 10, dtype=jnp.float32)
-    logits, _, cache = model.apply(
-        {"params": params}, tokens[:, :5], cache, mask[:, :5], True,
-        method=TransformerLM.decode_step,
-    )
+    logits, _, cache = step(tokens[:, :5], cache, mask[:, :5], True)
     np.testing.assert_allclose(np.asarray(logits), np.asarray(full_logits[:, :5]), atol=1e-4)
     for i in range(5, 10):
-        logits, _, cache = model.apply(
-            {"params": params}, tokens[:, i:i + 1], cache, mask[:, i:i + 1], False,
-            method=TransformerLM.decode_step,
-        )
+        logits, _, cache = step(tokens[:, i:i + 1], cache, mask[:, i:i + 1], False)
         np.testing.assert_allclose(
             np.asarray(logits[:, 0]), np.asarray(full_logits[:, i]), atol=1e-4,
             err_msg=f"step {i}",
@@ -117,7 +114,7 @@ def test_moe_aux_loss_sown_and_consumed():
     model = TransformerLM(cfg)
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 8)), jnp.int32)
     mask = jnp.ones_like(tokens)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
     from trlx_tpu.models.transformer import moe_aux_from_intermediates
 
     (_, _, _), inter = model.apply(
@@ -168,9 +165,8 @@ def test_moe_pipeline_parallel_training(tmp_path):
     loader = trainer.store.create_loader(8, shuffle=False)
     batch = next(iter(loader))
 
-    loss_fn = trainer.make_loss_fn()
-    loss, stats = loss_fn(trainer.train_params, trainer.frozen_params,
-                          trainer.batch_to_device(batch))
+    loss, stats = jax.jit(trainer.make_loss_fn())(
+        trainer.train_params, trainer.frozen_params, trainer.batch_to_device(batch))
     loss = float(np.asarray(loss))
     aux_pipe = float(np.asarray(stats["moe_aux_loss"]))
     assert np.isfinite(loss)
@@ -187,13 +183,10 @@ def test_moe_pipeline_parallel_training(tmp_path):
     ids = np.asarray(batch["input_ids"])
     mask = np.asarray(batch["attention_mask"])
     coef = cfg.moe_aux_coef
-    auxes = []
-    for lo in range(0, 8):  # microbatch size 1, in scan order per slice
-        _, inter = model.apply(
-            {"params": lm}, jnp.asarray(ids[lo:lo + 1]), jnp.asarray(mask[lo:lo + 1]),
-            position_ids(jnp.asarray(mask[lo:lo + 1])), mutable=["intermediates"],
-        )
-        auxes.append(float(moe_aux_from_intermediates(inter)))
+    one_row_aux = jax.jit(lambda ids, mask: moe_aux_from_intermediates(model.apply(
+        {"params": lm}, ids, mask, position_ids(mask), mutable=["intermediates"])[1]))
+    # microbatch size 1, in scan order per slice
+    auxes = [float(one_row_aux(ids[lo:lo + 1], mask[lo:lo + 1])) for lo in range(0, 8)]
     expected = coef * float(np.mean(auxes))
     np.testing.assert_allclose(aux_pipe, expected, rtol=2e-4)
 
@@ -286,7 +279,7 @@ def test_moe_aux_consumed_by_every_trainer_loss(tmp_path):
     t.make_experience(["good text", "bad text"] * 4, [1.0, -1.0] * 4, 32)
     batch = jax.tree_util.tree_map(jnp.asarray,
                                    next(iter(t.store.create_loader(8))))
-    loss, stats = t.make_loss_fn()(t.train_params, t.frozen_params, batch)
+    loss, stats = jax.jit(t.make_loss_fn())(t.train_params, t.frozen_params, batch)
     assert float(np.asarray(stats["moe_aux_loss"])) > 0
     assert np.isfinite(float(np.asarray(loss)))
 
@@ -310,7 +303,7 @@ def test_moe_aux_consumed_by_every_trainer_loss(tmp_path):
     t = RFTTrainer(rft_cfg, reward_fn=lambda samples, **kw: [0.0] * len(samples))
     fake = {"input_ids": jnp.ones((4, 8), jnp.int32),
             "attention_mask": jnp.ones((4, 8), jnp.int32)}
-    loss, stats = t.make_loss_fn()(t.train_params, t.frozen_params, fake)
+    loss, stats = jax.jit(t.make_loss_fn())(t.train_params, t.frozen_params, fake)
     assert float(np.asarray(stats["moe_aux_loss"])) > 0
 
     # pipelined ILQL + RFT (the in-pipe carry)
@@ -323,7 +316,7 @@ def test_moe_aux_consumed_by_every_trainer_loss(tmp_path):
     t.make_experience(["good text", "bad text"] * 4, [1.0, -1.0] * 4, 32)
     batch = jax.tree_util.tree_map(jnp.asarray,
                                    next(iter(t.store.create_loader(8))))
-    loss, stats = t.make_loss_fn()(t.train_params, t.frozen_params, batch)
+    loss, stats = jax.jit(t.make_loss_fn())(t.train_params, t.frozen_params, batch)
     assert float(np.asarray(stats["moe_aux_loss"])) > 0
 
     pr_cfg = rft_cfg.evolve(
@@ -334,5 +327,5 @@ def test_moe_aux_consumed_by_every_trainer_loss(tmp_path):
     t = PipelinedRFTTrainer(pr_cfg, reward_fn=lambda samples, **kw: [0.0] * len(samples))
     fake = {"input_ids": jnp.ones((8, 8), jnp.int32),
             "attention_mask": jnp.ones((8, 8), jnp.int32)}
-    loss, stats = t.make_loss_fn()(t.train_params, t.frozen_params, fake)
+    loss, stats = jax.jit(t.make_loss_fn())(t.train_params, t.frozen_params, fake)
     assert float(np.asarray(stats["moe_aux_loss"])) > 0

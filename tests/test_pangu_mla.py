@@ -32,6 +32,7 @@ sys.path[:0] = [p for p in (BENCH,) if p not in sys.path]
 
 from benchlib.files import load_module  # noqa: E402
 
+from parity import jitted_forward, jitted_init  # noqa: E402
 from trlx_tpu.models import CausalLMPolicy, CausalLMWithValueHead, config_from_preset  # noqa: E402
 from trlx_tpu.models import hf_interop  # noqa: E402
 from trlx_tpu.models.transformer import (  # noqa: E402
@@ -57,7 +58,7 @@ def sizes_of(cfg):
 def seeded_params(model, seed, *init_args):
     """Every leaf drawn from the seed, the norms' scales and the selection
     bias too (a fresh init leaves them at one and at zero)."""
-    params = model.init(jax.random.PRNGKey(seed), *init_args)["params"]
+    params = jitted_init(model)(jax.random.PRNGKey(seed), *init_args)["params"]
     leaves, tree = jax.tree_util.tree_flatten_with_path(params)
     rng = np.random.default_rng(seed)
     out = []
@@ -91,9 +92,9 @@ def reference_logprobs(lm_params, cfg, tokens, mask):
     return np.asarray(out)[:rows, : width - 1]
 
 
-def forward_logprobs(cfg, params, tokens, mask):
+def forward_logprobs(cfg, params, tokens, mask, program=jitted_forward):
     with jax.default_matmul_precision("highest"):
-        logits = TransformerLM(cfg).apply({"params": params}, jnp.asarray(tokens), jnp.asarray(mask))[0]
+        logits = program(cfg)(params, tokens, mask)
     return np.asarray(plain.logprobs_of_next(logits, jnp.asarray(tokens)))
 
 
@@ -145,7 +146,9 @@ def test_the_reference_tells_each_departure(monkeypatch):
     params = seeded_params(TransformerLM(cfg), 2, jnp.asarray(tokens), jnp.asarray(mask))
     want = reference_logprobs(params, cfg, tokens, mask)
     valid = (mask[:, 1:] * mask[:, :-1]).astype(bool)
-    err = lambda c: np.abs(forward_logprobs(c, params, tokens, mask) - want)[valid].max()
+    # a program traced anew every call: the patches below are read when the forward is traced
+    traced_anew = jitted_forward.__wrapped__
+    err = lambda c: np.abs(forward_logprobs(c, params, tokens, mask, traced_anew) - want)[valid].max()
     assert err(cfg) < TOL
     assert err(dataclasses.replace(cfg, sandwich_norm=False)) > 1e-2
     # 1/sqrt(qk_nope) in place of 1/sqrt(qk_nope + qk_rope): a wider nope head moves the scale alone
@@ -182,10 +185,10 @@ def test_multi_token_block_matches_the_reference():
     params = seeded_params(lm, 6, jnp.asarray(tokens), jnp.asarray(mask))
     assert sorted(params["mtp_0"]) == ["block", "eh_proj", "enorm", "hnorm"]
     assert params["mtp_0"]["eh_proj"]["kernel"].shape == (2 * cfg.d_model, cfg.d_model)
+    with_mtp = jax.jit(lambda p, t, m: lm.apply({"params": p}, t, m, mtp=True, method=TransformerLM.forward))
     with jax.default_matmul_precision("highest"):
-        logits, _, caps = lm.apply({"params": params}, jnp.asarray(tokens), jnp.asarray(mask), mtp=True,
-                                   method=TransformerLM.forward)
-        plain_logits = lm.apply({"params": params}, jnp.asarray(tokens), jnp.asarray(mask))[0]
+        logits, _, caps = with_mtp(params, tokens, mask)
+        plain_logits = jitted_forward(cfg)(params, tokens, mask)
     want, (want_mtp,) = ref.logits(params, tokens, mask, sizes_of(cfg), mtp=True)
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(plain_logits))
     valid = mask.astype(bool)
@@ -196,8 +199,7 @@ def test_multi_token_block_matches_the_reference():
     other = tokens.copy()
     other[:, -1] = (other[:, -1] % (VOCAB - 1)) + 1
     with jax.default_matmul_precision("highest"):
-        _, _, caps2 = lm.apply({"params": params}, jnp.asarray(other), jnp.asarray(mask), mtp=True,
-                               method=TransformerLM.forward)
+        _, _, caps2 = with_mtp(params, other, mask)
     assert np.abs(np.asarray(caps2["mtp"][0]) - np.asarray(caps["mtp"][0]))[:, -2].max() > 1e-3
     with pytest.raises(NotImplementedError, match="mtp=True takes a whole forward"):
         lm.apply({"params": params}, jnp.asarray(tokens), jnp.asarray(mask), mtp=True, window=(0, 4),
@@ -432,7 +434,7 @@ def test_hf_config_keys_and_tensor_names_round_trip(tmp_path):
 
     tiny = tiny_cfg(mtp_layers=1, moe_local_experts=4, moe_local_offset=2, hf_family="pangu_ultra_moe")
     tokens = jnp.zeros((1, 8), jnp.int32)
-    template = CausalLMPolicy(tiny).init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"]
+    template = jitted_init(CausalLMPolicy(tiny))(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"]
     # a random state dict with the names and shapes the exporter gives
     rng = np.random.default_rng(0)
     names = hf_interop.params_to_hf_state_dict(template, tiny)
@@ -530,14 +532,15 @@ def test_latent_attention_module_absorbed_equals_decompressed_at_a_prefill_behin
     mask = jnp.ones((2, 12), jnp.int32)
     pos = jnp.broadcast_to(jnp.arange(12), (2, 12))
     mod = LatentAttention(cfg, kind="latent_attention")
-    params = mod.init(jax.random.PRNGKey(0), h, train_bias(cfg, mask, "latent_attention"), pos)["params"]
+    params = jax.jit(mod.init)(jax.random.PRNGKey(0), h, train_bias(cfg, mask, "latent_attention"), pos)["params"]
+    cached = jax.jit(mod.apply, static_argnums=5)  # the cache index is the program's, as in `decode_step`
     with jax.default_matmul_precision("highest"):
-        want, _ = mod.apply({"params": params}, h, train_bias(cfg, mask, "latent_attention"), pos)
+        want, _ = jax.jit(mod.apply)({"params": params}, h, train_bias(cfg, mask, "latent_attention"), pos)
         cache = {"latent": jnp.zeros((2, 16, cfg.latent_width), jnp.float32)}
         seen = jnp.zeros((2, 16), jnp.int32).at[:, :5].set(1)
-        _, cache = mod.apply({"params": params}, h[:, :5], cached_bias(cfg, seen, pos[:, :5], 0, "latent_attention"),
-                             pos[:, :5], cache, 0)
+        _, cache = cached({"params": params}, h[:, :5], cached_bias(cfg, seen, pos[:, :5], 0, "latent_attention"),
+                          pos[:, :5], cache, 0)
         seen = seen.at[:, 5:12].set(1)
-        got, _ = mod.apply({"params": params}, h[:, 5:], cached_bias(cfg, seen, pos[:, 5:], 5, "latent_attention"),
-                           pos[:, 5:], cache, 5)
+        got, _ = cached({"params": params}, h[:, 5:], cached_bias(cfg, seen, pos[:, 5:], 5, "latent_attention"),
+                        pos[:, 5:], cache, 5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, 5:]), atol=2e-6)

@@ -38,7 +38,7 @@ def _build(lora=True):
     model = CausalLMWithValueHead(cfg)
     tokens = jnp.asarray(np.random.default_rng(0).integers(0, 64, (2, 12)), jnp.int32)
     mask = jnp.ones_like(tokens)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
     return cfg, model, params, tokens, mask
 
 
@@ -99,14 +99,14 @@ def test_adapter_params_exist_and_only_adapters_train():
 def test_init_is_identity_and_zero_lora_equivalence():
     """B=0 at init => lora model == base model; zero_lora == disabling."""
     cfg, model, params, tokens, mask = _build()
-    logits, values, _ = model.apply({"params": params}, tokens, mask)
+    logits, values, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
 
     perturbed = _perturb_lora(params)
-    logits_pert, *_ = model.apply({"params": perturbed}, tokens, mask)
+    logits_pert, *_ = jax.jit(model.apply)({"params": perturbed}, tokens, mask)
     assert not np.allclose(np.asarray(logits), np.asarray(logits_pert), atol=1e-5)
 
     disabled = zero_lora(perturbed)
-    logits_dis, *_ = model.apply({"params": disabled}, tokens, mask)
+    logits_dis, *_ = jax.jit(model.apply)({"params": disabled}, tokens, mask)
     np.testing.assert_allclose(np.asarray(logits_dis), np.asarray(logits), atol=1e-6)
 
 
@@ -224,7 +224,7 @@ def test_hf_load_with_lora_template(tmp_path):
     cfg = hf_interop.config_from_hf(path, dtype=jnp.float32, lora_rank=4)
     model = CausalLMWithValueHead(cfg)
     tokens = jnp.zeros((1, 8), jnp.int32)
-    template = model.init(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"]
+    template = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, jnp.ones_like(tokens))["params"]
     params = hf_interop.load_params_from_hf(path, cfg, template)
 
     lora_leaves, _ = split_lora(params)
@@ -303,7 +303,7 @@ def _build_prompt():
     mask = np.ones((2, 12), np.int32)
     mask[0, :3] = 0  # left padding
     mask = jnp.asarray(mask)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
     return cfg, model, params, tokens, mask
 
 
@@ -311,7 +311,7 @@ def test_prompt_tuning_translation_and_param():
     assert lora_overrides_from_peft_config(PROMPT_CONFIG) == {"prompt_tokens": 4}
     cfg, model, params, tokens, mask = _build_prompt()
     assert params["lm"]["soft_prompt"].shape == (4, cfg.d_model)
-    logits, values, _ = model.apply({"params": params}, tokens, mask)
+    logits, values, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     assert logits.shape == (2, 12, 64)  # caller-visible length unchanged
     assert values.shape == (2, 12)
 
@@ -331,7 +331,7 @@ def test_prompt_tuning_ref_is_prompt_free():
     """The full reference forward skips the soft prompt: equals a prompt-free model
     on the same base weights, and differs from the prompted forward."""
     cfg, model, params, tokens, mask = _build_prompt()
-    logits, _, _ = model.apply({"params": params}, tokens, mask)
+    logits, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     ref = ref_param_subtree(params, cfg, resolve_split(cfg, 2))
     assert resolve_split(cfg, 2) == 0  # prompt forces full-ref mode
     ref_logits, _, _ = model.apply(
@@ -342,9 +342,9 @@ def test_prompt_tuning_ref_is_prompt_free():
 
     cfg0 = config_from_preset("gpt2-tiny", vocab_size=64, dtype=jnp.float32)
     m0 = CausalLMWithValueHead(cfg0)
-    p0 = m0.init(jax.random.PRNGKey(1), tokens, mask)["params"]
+    p0 = jax.jit(m0.init)(jax.random.PRNGKey(1), tokens, mask)["params"]
     lm0 = {k: v for k, v in params["lm"].items() if k != "soft_prompt"}
-    l0, _, _ = m0.apply({"params": {**p0, "lm": lm0}}, tokens, mask)
+    l0, _, _ = jax.jit(m0.apply)({"params": {**p0, "lm": lm0}}, tokens, mask)
     np.testing.assert_allclose(np.asarray(ref_logits), np.asarray(l0), atol=1e-5)
 
 
@@ -352,7 +352,7 @@ def test_prompt_tuning_decode_matches_forward():
     from trlx_tpu.models import init_kv_cache
 
     cfg, model, params, tokens, mask = _build_prompt()
-    logits, _, _ = model.apply({"params": params}, tokens, mask)
+    logits, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     cache = init_kv_cache(cfg, 2, 12)  # prompt slots reserved internally
     dl, _, _ = model.apply(
         {"params": params}, tokens, cache, mask, True,
@@ -463,7 +463,7 @@ def _build_prefix():
     mask = np.ones((2, 12), np.int32)
     mask[0, :3] = 0
     mask = jnp.asarray(mask)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)["params"]
     return cfg, model, params, tokens, mask
 
 
@@ -482,7 +482,7 @@ def test_prefix_tuning_params_and_masking():
 
 def test_prefix_tuning_ref_is_prefix_free():
     cfg, model, params, tokens, mask = _build_prefix()
-    logits, _, _ = model.apply({"params": params}, tokens, mask)
+    logits, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     assert resolve_split(cfg, 2) == 0
     ref = ref_param_subtree(params, cfg, 0)
     ref_logits, _, _ = model.apply(
@@ -499,8 +499,8 @@ def test_prefix_tuning_ref_is_prefix_free():
 
     cfg0 = config_from_preset("gpt2-tiny", vocab_size=64, dtype=jnp.float32)
     m0 = CausalLMWithValueHead(cfg0)
-    p0 = m0.init(jax.random.PRNGKey(1), tokens, mask)["params"]
-    l0, _, _ = m0.apply({"params": {**p0, "lm": strip(params["lm"])}}, tokens, mask)
+    p0 = jax.jit(m0.init)(jax.random.PRNGKey(1), tokens, mask)["params"]
+    l0, _, _ = jax.jit(m0.apply)({"params": {**p0, "lm": strip(params["lm"])}}, tokens, mask)
     np.testing.assert_allclose(np.asarray(ref_logits), np.asarray(l0), atol=1e-5)
 
 
@@ -508,7 +508,7 @@ def test_prefix_tuning_decode_matches_forward():
     from trlx_tpu.models import init_kv_cache
 
     cfg, model, params, tokens, mask = _build_prefix()
-    logits, _, _ = model.apply({"params": params}, tokens, mask)
+    logits, _, _ = jax.jit(model.apply)({"params": params}, tokens, mask)
     cache = init_kv_cache(cfg, 2, 16)
     dl, _, cache = model.apply(
         {"params": params}, tokens, cache, mask, True,
@@ -524,7 +524,7 @@ def test_prefix_tuning_decode_matches_forward():
     )
     full = jnp.concatenate([tokens, nxt], axis=1)
     fmask = jnp.concatenate([mask, jnp.ones((2, 1), jnp.int32)], axis=1)
-    fl, _, _ = model.apply({"params": params}, full, fmask)
+    fl, _, _ = jax.jit(model.apply)({"params": params}, full, fmask)
     np.testing.assert_allclose(np.asarray(dl2[:, -1]), np.asarray(fl[:, -1]), atol=1e-4)
 
 

@@ -16,6 +16,8 @@ from flax import traverse_util
 from trlx_tpu.data.default_configs import default_ppo_config, default_sft_config
 from trlx_tpu.pipeline import MiniBatchIterator
 
+from parity import assert_loss_parity
+
 SAMPLES = ["hello world this is text", "another training sample here"] * 8
 PEFT = dict(peft_type="LORA", r=4, lora_alpha=8,
             target_modules=["q_proj", "v_proj"])
@@ -98,12 +100,10 @@ def test_pipelined_sft_freeze_cuts_through_stage(tmp_path):
     flat = traverse_util.flatten_dict(dict(trainer.params))
     train = {k: v for k, v in flat.items() if k in trainer.train_params}
     frozen = {k: v for k, v in flat.items() if k not in trainer.train_params}
-    pp_loss, _ = trainer.make_loss_fn()(train, frozen, trainer.batch_to_device(batch))
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(trainer.standard_params()), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)), rtol=1e-4
+    assert_loss_parity(
+        trainer.make_loss_fn(), (train, frozen, trainer.batch_to_device(batch)),
+        plain.make_loss_fn(),
+        (traverse_util.flatten_dict(trainer.standard_params()), {}, batch),
     )
 
 
@@ -120,9 +120,9 @@ def test_pipelined_freeze_grads_zero_below_split(tmp_path):
         next(iter(trainer.store.create_loader(8, shuffle=False)))
     )
     loss_fn = trainer.make_loss_fn()
-    grads = jax.grad(
+    grads = jax.jit(jax.grad(
         lambda tp: loss_fn(tp, trainer.frozen_params, batch)[0]
-    )(trainer.train_params)
+    ))(trainer.train_params)
     checked = 0
     for k, g in grads.items():
         if k[0] != "lm_stacked" or k[-1] != "kernel":
@@ -235,7 +235,6 @@ def test_pipelined_ppo_default_freeze_config(tmp_path):
     flat = traverse_util.flatten_dict(dict(trainer.params))
     train = {k: v for k, v in flat.items() if k in trainer.train_params}
     frozen = {k: v for k, v in flat.items() if k not in trainer.train_params}
-    pp_loss, _ = trainer.make_loss_fn()(train, frozen, trainer.batch_to_device(batch))
     # the plain trainer's ref/hydra split must see the SAME params
     plain_flat = traverse_util.flatten_dict(trainer.standard_params())
     plain_mask = traverse_util.flatten_dict(
@@ -243,9 +242,9 @@ def test_pipelined_ppo_default_freeze_config(tmp_path):
     )
     p_train = {k: v for k, v in plain_flat.items() if plain_mask[k]}
     p_frozen = {k: v for k, v in plain_flat.items() if not plain_mask[k]}
-    plain_loss, _ = plain.make_loss_fn()(p_train, p_frozen, batch)
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)), rtol=1e-4
+    assert_loss_parity(
+        trainer.make_loss_fn(), (train, frozen, trainer.batch_to_device(batch)),
+        plain.make_loss_fn(), (p_train, p_frozen, batch),
     )
 
 
@@ -290,10 +289,8 @@ def test_pipelined_sft_lora(tmp_path):
     batch = next(iter(trainer.store.create_loader(8, shuffle=False)))
     train = {k: v for k, v in flat.items() if k in trainer.train_params}
     frozen = {k: v for k, v in flat.items() if k not in trainer.train_params}
-    pp_loss, _ = trainer.make_loss_fn()(train, frozen, trainer.batch_to_device(batch))
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(trainer.standard_params()), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)), rtol=1e-4
+    assert_loss_parity(
+        trainer.make_loss_fn(), (train, frozen, trainer.batch_to_device(batch)),
+        plain.make_loss_fn(),
+        (traverse_util.flatten_dict(trainer.standard_params()), {}, batch),
     )

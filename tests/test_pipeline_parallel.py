@@ -17,6 +17,8 @@ from trlx_tpu.parallel.pipeline import (
     stack_block_params,
 )
 
+from parity import assert_pipelined_loss_parity
+
 
 @pytest.fixture(scope="module")
 def setup():
@@ -29,7 +31,7 @@ def setup():
     mask = np.ones((8, 16), np.int32)
     mask[3, -5:] = 0  # right padding on one row
     mask = jnp.asarray(mask)
-    params = model.init(jax.random.PRNGKey(0), tokens, mask)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), tokens, mask)
     return cfg, model, params, tokens, mask
 
 
@@ -49,7 +51,7 @@ def test_gpipe_matches_sequential(setup, n_stages, n_mb):
     mesh = make_pipe_mesh(n_stages)
     fwd = jax.jit(make_gpipe_forward(model, cfg, mesh, n_stages, n_mb))
     logits_pp = fwd(params, tokens, mask)
-    logits_seq, _, _ = model.apply(params, tokens, mask)
+    logits_seq, _, _ = jax.jit(model.apply)(params, tokens, mask)
     valid = np.asarray(mask)[:, :, None].astype(bool)
     np.testing.assert_allclose(
         np.where(valid, np.asarray(logits_pp), 0),
@@ -69,7 +71,7 @@ def test_gpipe_fused_attention_matches_sequential(setup):
     mesh = make_pipe_mesh(4)
     fwd = jax.jit(make_gpipe_forward(fmodel, fcfg, mesh, 4, 4))
     logits_pp = fwd(params, tokens, mask)
-    logits_seq, _, _ = model.apply(params, tokens, mask)
+    logits_seq, _, _ = jax.jit(model.apply)(params, tokens, mask)
     valid = np.asarray(mask)[:, :, None].astype(bool)
     np.testing.assert_allclose(
         np.where(valid, np.asarray(logits_pp), 0),
@@ -92,7 +94,7 @@ def test_gpipe_gradients_match_sequential(setup):
         return jnp.mean(model.apply(p, tokens, mask)[0] ** 2)
 
     g_pp = jax.jit(jax.grad(loss_pp))(params)
-    g_seq = jax.grad(loss_seq)(params)
+    g_seq = jax.jit(jax.grad(loss_seq))(params)
     flat_pp = jax.tree_util.tree_leaves_with_path(g_pp)
     flat_seq = dict(jax.tree_util.tree_leaves_with_path(g_seq))
     assert len(flat_pp) == len(flat_seq)
@@ -108,7 +110,6 @@ def test_pipelined_sft_trainer(tmp_path):
     trainer family on a (data=2, pipe=2) mesh — runs end-to-end via the
     public train() API, matches the plain SFT trainer's loss on identical
     params/batch, and exports the standard HF layout."""
-    import numpy as np
 
     import trlx_tpu as trlx
     from trlx_tpu.data.default_configs import default_sft_config
@@ -139,28 +140,13 @@ def test_pipelined_sft_trainer(tmp_path):
     assert trainer.iter_count >= 2
 
     # loss parity on identical params/batch: pipelined loss == plain loss
-    import jax
-
-    std = trainer.standard_params()
     plain_cfg = make_config("SFTTrainer", 1, "plain")
     plain_cfg.parallel.data = 1
     from trlx_tpu.trainer.sft_trainer import SFTTrainer
 
     plain = SFTTrainer(plain_cfg, devices=jax.devices()[:1])
     batch = next(iter(trainer.store.create_loader(8, shuffle=False)))
-    pp_loss_fn = trainer.make_loss_fn()
-    plain_loss_fn = plain.make_loss_fn()
-    from flax import traverse_util
-
-    pp_loss, _ = pp_loss_fn(traverse_util.flatten_dict({
-        k: v for k, v in trainer.params.items()
-    }), {}, trainer.batch_to_device(batch))
-    plain_loss, _ = plain_loss_fn(
-        traverse_util.flatten_dict(std), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)), rtol=1e-4
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)
 
     # HF export goes through the standard layout
     trainer.save_pretrained(str(tmp_path / "hf"))
@@ -213,22 +199,12 @@ def test_pipelined_ilql_trainer(tmp_path):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
     # loss parity vs the plain trainer on identical params/batch
-    from flax import traverse_util
     from trlx_tpu.trainer.ilql_trainer import ILQLTrainer
 
     plain = ILQLTrainer(make_config("ILQLTrainer", 1, "plain"),
                         devices=jax.devices()[:1])
     batch = next(iter(trainer.store.create_loader(8, shuffle=False, drop_last=True)))
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(trainer.standard_params()), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)), rtol=1e-4
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)
 
 
 def test_pipelined_ppo_trainer(tmp_path):
@@ -272,16 +248,7 @@ def test_pipelined_ppo_trainer(tmp_path):
                        reward_fn=lambda samples, **kw: [0.0] * len(samples),
                        devices=jax.devices()[:1])
     batch = next(iter(trainer.store.create_loader(8, shuffle=False)))
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(trainer.standard_params()), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)), rtol=1e-4
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)
 
     # score-fn parity incl. the KL stat ORDER (regression: a swapped
     # (mean_kl, mean_kl_per_token) pair feeds the adaptive KL controller
@@ -344,8 +311,6 @@ def test_pipelined_rft_trainer(tmp_path):
     assert trainer.iter_count >= 1
 
     # loss parity vs the plain RFT trainer on identical params/batch
-    import numpy as np
-    from flax import traverse_util
     from trlx_tpu.trainer.rft_trainer import RFTTrainer
 
     plain_cfg = config.evolve(train=dict(trainer="RFTTrainer"),
@@ -354,16 +319,7 @@ def test_pipelined_rft_trainer(tmp_path):
                        devices=jax.devices()[:1])
     batch = next(iter(trainer.store.create_loader(
         min(trainer.config.train.batch_size, len(trainer.store)), shuffle=False)))
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(trainer.standard_params()), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)), rtol=2e-3
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch, rtol=2e-3)
 
 
 # ---------------------------------------------------------------------------
@@ -400,7 +356,7 @@ def test_interleaved_matches_sequential(setup, n_stages, n_mb, n_virtual):
     mesh = make_pipe_mesh(n_stages)
     fwd = jax.jit(make_gpipe_forward(model, cfg, mesh, n_stages, n_mb, n_virtual=n_virtual))
     logits_pp = fwd(params, tokens, mask)
-    logits_seq, _, _ = model.apply(params, tokens, mask)
+    logits_seq, _, _ = jax.jit(model.apply)(params, tokens, mask)
     valid = np.asarray(mask)[:, :, None].astype(bool)
     np.testing.assert_allclose(
         np.where(valid, np.asarray(logits_pp), 0),
@@ -421,7 +377,7 @@ def test_interleaved_gradients_match_sequential(setup):
         return jnp.mean(model.apply(p, tokens, mask)[0] ** 2)
 
     g_pp = jax.jit(jax.grad(loss_pp))(params)
-    g_seq = jax.grad(loss_seq)(params)
+    g_seq = jax.jit(jax.grad(loss_seq))(params)
     flat_pp = jax.tree_util.tree_leaves_with_path(g_pp)
     flat_seq = dict(jax.tree_util.tree_leaves_with_path(g_seq))
     assert len(flat_pp) == len(flat_seq)
@@ -437,7 +393,6 @@ def test_pipelined_sft_trainer_interleaved(tmp_path):
     through the public API and its loss matches the plain SFT trainer on
     the unstacked param view."""
     import trlx_tpu as trlx
-    from flax import traverse_util
     from trlx_tpu.data.default_configs import default_sft_config
     from trlx_tpu.trainer.sft_trainer import SFTTrainer
 
@@ -462,13 +417,4 @@ def test_pipelined_sft_trainer_interleaved(tmp_path):
                               parallel=dict(data=1, pipeline=1, pipeline_interleave=1))
     plain = SFTTrainer(plain_cfg, devices=jax.devices()[:1])
     batch = next(iter(trainer.store.create_loader(8, shuffle=False)))
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(trainer.standard_params()), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)), rtol=1e-4
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)

@@ -20,6 +20,8 @@ from flax import traverse_util
 import trlx_tpu as trlx
 from trlx_tpu.data.default_configs import default_ppo_config, default_sft_config
 
+from parity import assert_pipelined_loss_parity
+
 
 def _sft_config(tmp_path, trainer, parallel, sub, padding_side="right"):
     return default_sft_config().evolve(
@@ -81,18 +83,7 @@ def test_pipelined_sft_sp_parity(tmp_path):
     )
     batch = next(iter(trainer.store.create_loader(8, shuffle=False)))
     assert np.asarray(batch["input_ids"]).shape[1] % 2 == 1
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    std_host = jax.tree_util.tree_map(np.asarray, trainer.standard_params())
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(std_host), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)),
-        rtol=1e-4,
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)
 
 
 def test_decode_view_under_tp_sp(tmp_path):
@@ -152,19 +143,8 @@ def test_pipelined_ppo_sp_parity(tmp_path):
         reward_fn=lambda samples, **kw: [0.0] * len(samples),
         devices=jax.devices()[:1],
     )
-    std_host = jax.tree_util.tree_map(np.asarray, trainer.standard_params())
     batch = next(iter(trainer.store.create_loader(8, shuffle=False)))
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(std_host), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)),
-        rtol=1e-4,
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)
 
     trainer._build_score_fn()
     all_tokens = jnp.concatenate(
@@ -180,6 +160,7 @@ def test_pipelined_ppo_sp_parity(tmp_path):
         trainer.ref_params["lm_stacked"], trainer.ref_params["lm_rest"],
         trainer.model_cfg.n_layers,
     )
+    std_host = jax.tree_util.tree_map(np.asarray, trainer.standard_params())
     lp_pl, _, _, kl_pl, _ = jax.device_get(plain._score_fn(
         traverse_util.flatten_dict(std_host), {}, ref_std, all_tokens,
     ))
@@ -228,16 +209,5 @@ def test_pipelined_ilql_sp_parity(tmp_path):
         make_config("ILQLTrainer", dict(data=1, pipeline=1), "plain"),
         devices=jax.devices()[:1],
     )
-    std_host = jax.tree_util.tree_map(np.asarray, trainer.standard_params())
     batch = next(iter(trainer.store.create_loader(8, shuffle=False, drop_last=True)))
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(std_host), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)),
-        rtol=1e-4,
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)

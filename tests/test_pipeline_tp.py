@@ -23,6 +23,8 @@ from trlx_tpu.parallel.pipeline import (
     stacked_param_shardings,
 )
 
+from parity import assert_pipelined_loss_parity
+
 
 def test_pipe_mesh_axes():
     mesh = make_pipe_mesh(2, tensor=2)
@@ -98,17 +100,7 @@ def test_pipelined_sft_trainer_tp_fsdp(tmp_path, axis):
     )
     plain = SFTTrainer(plain_cfg, devices=jax.devices()[:1])
     batch = next(iter(trainer.store.create_loader(8, shuffle=False)))
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(trainer.standard_params()), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)),
-        rtol=1e-4,
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)
 
 
 def test_pipelined_ppo_trainer_tp(tmp_path):
@@ -147,17 +139,7 @@ def test_pipelined_ppo_trainer_tp(tmp_path):
         devices=jax.devices()[:1],
     )
     batch = next(iter(trainer.store.create_loader(8, shuffle=False)))
-    pp_loss, _ = trainer.make_loss_fn()(
-        traverse_util.flatten_dict(dict(trainer.params)), {},
-        trainer.batch_to_device(batch),
-    )
-    plain_loss, _ = plain.make_loss_fn()(
-        traverse_util.flatten_dict(trainer.standard_params()), {}, batch
-    )
-    np.testing.assert_allclose(
-        float(jax.device_get(pp_loss)), float(jax.device_get(plain_loss)),
-        rtol=1e-4,
-    )
+    assert_pipelined_loss_parity(trainer, plain, batch)
 
     # double score pass (policy + stacked frozen ref) parity under TP x PP
     from trlx_tpu.parallel.pipeline import unstack_block_params

@@ -1,0 +1,110 @@
+"""The dense PPO cells' trainer programs, compiled for one v5e chip with no
+chip: `pythia-1.4b.ppo-hh`'s trunk-cache fill and the train step resumed
+from it, and `gpt2-xl.ppo-sentiments`' score program that hands out the
+trunk state, traced from where `PPOTrainer` makes them at the cells' widths
+(`aot_tpu.ppo_cell_trainer`). `lfm2-8b-a1b.ppo-hh`'s are in
+`test_lfm2_compile_tpu.py`.
+"""
+
+import pytest
+
+import jax
+from jax.sharding import SingleDeviceSharding
+
+pytest.importorskip("libtpu", reason="AOT compilation for the TPU needs libtpu")
+
+from aot_tpu import (  # noqa: E402, F401  (v5e and pallas_mode are fixtures)
+    abstract, BF16, donated_outputs, F32, I32, kernel_names, pallas_mode, ppo_cell_params,
+    ppo_cell_trainer, S, traced_score, v5e,
+)
+
+
+# pythia-1.4b.ppo-hh (bench/workloads): chunks of 16 x (896 + 128) tokens,
+# batches of 8, 64 rollouts a cycle, 2 blocks trained. Every width is the
+# cell's; the depth is cut to 2 frozen blocks under the 2 trained ones (the
+# 22 of the cell are one block's program 22 times, and a minute to compile)
+PPO_HH = dict(vocab_size=50304, attn_impl="flash", n_layers=4)
+
+
+@pytest.fixture(scope="module")
+def ppo_hh_trainer(tmp_path_factory):
+    return ppo_cell_trainer(tmp_path_factory.mktemp("ppo_hh"), "pythia-1.4b", PPO_HH,
+                         batch_size=8, num_rollouts=64, chunk_size=16, max_new=128)
+
+
+def test_dense_ppo_cell_trunk_cache_fill_compiles_under_its_name(v5e, pallas_mode, ppo_hh_trainer):
+    """`jit_trunk_cache_fill` over one chunk of the cell, 16 x 1,024 tokens:
+    the frozen blocks' flash forwards and no head, the state in bfloat16,
+    the forward's own dtype."""
+    trainer = ppo_hh_trainer
+    one = SingleDeviceSharding(v5e[0])
+    train, frozen = ppo_cell_params(trainer)
+    fill = trainer._build_trunk_cache_fn()
+    traced = fill.trace(*abstract((train, frozen, S((16, 1024), I32)), one))
+    assert traced.out_info.shape == (16, 1024, 2048) and traced.out_info.dtype == BF16
+    lowered = traced.lower(lowering_platforms=("tpu",))
+    assert "module @jit_trunk_cache_fill" in lowered.as_text()[:200]
+    compiled = lowered.compile()
+    assert kernel_names(compiled) == ["flash_fwd"] * trainer.split
+
+
+def test_dense_ppo_cell_train_step_resumes_from_the_trunk_cache(v5e, pallas_mode, ppo_hh_trainer):
+    """The cell's train step in outline (the trainer's own loss, gradients
+    of the top two blocks, AdamW) over a batch of 8 that names its rows of
+    the cycle's cache `[64, 1024, 2048]`: the gather, the two trained
+    blocks forward and backward and the windowed head lower and compile
+    for one v5e chip, and no frozen block runs: two flash forwards, where
+    the whole forward of the same step runs one a block."""
+    import optax
+
+    from trlx_tpu.data import PPORLBatch
+
+    trainer = ppo_hh_trainer
+    one = SingleDeviceSharding(v5e[0])
+    train, frozen = ppo_cell_params(trainer)
+    loss_fn = trainer.make_loss_fn()
+    opt = optax.adamw(6e-6)
+    opt_state = jax.eval_shape(opt.init, train)
+    b, q, new = 8, 896, 128
+    batch = PPORLBatch(
+        query_tensors=S((b, q), I32), response_tensors=S((b, new), I32),
+        logprobs=S((b, new), F32), values=S((b, new), F32), rewards=S((b, new), F32),
+        trunk_rows=S((b,), I32), trunk_cache=S((64, q + new, 2048), BF16))
+
+    def train_step(train, frozen, opt_state, batch):
+        grads = jax.grad(lambda p: loss_fn(p, frozen, batch)[0])(train)
+        updates, opt_state = opt.update(grads, opt_state, train)
+        return optax.apply_updates(train, updates), opt_state
+
+    def flash_forwards(batch):
+        compiled = jax.jit(train_step, donate_argnums=(0, 2)).trace(
+            *abstract((train, frozen, opt_state, batch), one)).lower(
+            lowering_platforms=("tpu",)).compile()
+        return sum(name.startswith("flash_fwd") for name in kernel_names(compiled)), compiled
+
+    resumed, compiled = flash_forwards(batch)
+    whole, _ = flash_forwards(batch.replace(trunk_rows=None, trunk_cache=None))
+    assert (resumed, whole) == (2, trainer.model_cfg.n_layers)
+    # the cache is an argument the step reads and hands back to nobody
+    assert donated_outputs(compiled) == len(jax.tree_util.tree_leaves((train, opt_state)))
+
+
+def test_gpt2_xl_score_keeps_its_weight_prefetches_with_the_trunk_state(v5e, pallas_mode, tmp_path):
+    """`gpt2-xl.ppo-sentiments` scores one chunk of 128 x 104, whose whole
+    residual stream (43 MB) fits a v5e's fast memory. Handed out as a plain
+    sixth output the state made the compiler keep that stream there and
+    stop prefetching the frozen blocks' MLP weights (sliced copies joined by
+    `ConcatBitcast`): 0.676 against 0.566 s a chunk on the chip (PERF.md
+    section 6, PR 40). `score` hands it out behind a barrier for that; this
+    holds the plan, at the cell's widths and a depth of 4 frozen blocks:
+    the six-output program prefetches what the five-output program does."""
+    trainer = ppo_cell_trainer(tmp_path, "gpt2-xl", dict(vocab_size=50257, attn_impl="flash", n_layers=6),
+                            batch_size=32, num_rollouts=128, chunk_size=128, max_new=40)
+    assert trainer._score_hands_out_trunk_state()
+
+    def prefetched_weights(hands_out):
+        traced = traced_score(trainer, v5e[0], 128, 104, hands_out)
+        return traced.lower(lowering_platforms=("tpu",)).compile().as_text().count("ConcatBitcast")
+
+    five, six = prefetched_weights(False), prefetched_weights(True)
+    assert five >= 4 * 6 and six >= five, (five, six)

@@ -107,7 +107,7 @@ def test_captured_stats_match_batched_forward(trainer_nb, mode):
 
     params = merge_params(trainer.train_params, trainer.frozen_params)
     amask = (samples != pad).astype(np.int32)
-    logits, values, _ = trainer.model.apply(
+    logits, values, _ = jax.jit(trainer.model.apply)(
         {"params": params}, jnp.asarray(samples), jnp.asarray(amask),
         position_ids(jnp.asarray(amask)),
     )
@@ -245,7 +245,7 @@ def test_engine_logprobs_match_batched_forward():
             assert r.wait(120), "request timed out"
             assert len(r.token_logprobs) == len(r.token_ids)
             full = np.asarray([p + r.token_ids], np.int32)
-            res = trainer.model.apply(
+            res = jax.jit(trainer.model.apply)(
                 {"params": trainer.params}, jnp.asarray(full),
                 jnp.ones_like(jnp.asarray(full)),
             )

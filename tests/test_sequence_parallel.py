@@ -9,10 +9,11 @@ import pytest
 import jax
 
 import trlx_tpu as trlx
-from flax import traverse_util
 from trlx_tpu.data.default_configs import default_sft_config
 from trlx_tpu.trainer.base_trainer import merge_params
 from trlx_tpu.trainer.sft_trainer import SFTTrainer
+
+from parity import assert_loss_parity
 
 
 SP_SAMPLES = ["long context sequence parallel training sample " * 2,
@@ -20,20 +21,20 @@ SP_SAMPLES = ["long context sequence parallel training sample " * 2,
               "another long context training sample with more words " * 2] * 2
 
 
+def assert_sp_loss_parity(trainer, plain, batch):
+    """SP-vs-plain loss parity on identical params/batch: the plain trainer
+    splits trainable from frozen as the sequence-parallel one does."""
+    assert_loss_parity(
+        trainer.make_loss_fn(),
+        (trainer.train_params, trainer.frozen_params, trainer.batch_to_device(batch)),
+        plain.make_loss_fn(), (trainer.train_params, trainer.frozen_params, batch),
+    )
+
+
 def assert_sft_loss_parity(trainer, plain_cfg):
-    """Pipelined/SP-vs-plain SFT loss parity on identical params/batch."""
     plain = SFTTrainer(plain_cfg, devices=jax.devices()[:1])
     batch = next(iter(trainer.store.create_loader(4, shuffle=False)))
-    sp_loss, _ = trainer.make_loss_fn()(
-        trainer.train_params, trainer.frozen_params, trainer.batch_to_device(batch)
-    )
-    flat = traverse_util.flatten_dict(
-        merge_params(trainer.train_params, trainer.frozen_params)
-    )
-    pl_loss, _ = plain.make_loss_fn()(flat, {}, batch)
-    np.testing.assert_allclose(
-        float(np.asarray(sp_loss)), float(np.asarray(pl_loss)), rtol=1e-4
-    )
+    assert_sp_loss_parity(trainer, plain, batch)
 
 
 def sp_config(tmp_path):
@@ -129,8 +130,6 @@ def test_sequence_parallel_ppo_end_to_end_and_loss_parity(tmp_path):
     """Context-parallel PPO: full train loop through trlx.train, then
     exact loss parity against the plain PPOTrainer on identical params
     and rollout batch (left-padded ragged queries included)."""
-    import jax.numpy as jnp
-
     from trlx_tpu.data.default_configs import default_ppo_config
     from trlx_tpu.trainer.ppo_trainer import PPOTrainer
 
@@ -154,23 +153,13 @@ def test_sequence_parallel_ppo_end_to_end_and_loss_parity(tmp_path):
     assert trainer.model_cfg.attn_impl == "ring"
 
     batch = next(iter(trainer.store.create_loader(4, shuffle=False)))
-    sp_loss, _ = trainer.make_loss_fn()(
-        trainer.train_params, trainer.frozen_params, trainer.batch_to_device(batch)
-    )
-    host_train = {k: np.asarray(v) for k, v in trainer.train_params.items()}
-    host_frozen = {k: np.asarray(v) for k, v in trainer.frozen_params.items()}
     plain_cfg = config.evolve(
         train=dict(trainer="PPOTrainer"),
         parallel=dict(data=1, sequence=1),
         model=dict(model_extra_configs=dict(dtype="float32", attn_impl="xla")),
     )
     plain = PPOTrainer(plain_cfg, reward_fn=reward_fn, devices=jax.devices()[:1])
-    pl_loss, _ = jax.jit(plain.make_loss_fn())(
-        host_train, host_frozen, jax.tree_util.tree_map(jnp.asarray, batch)
-    )
-    np.testing.assert_allclose(
-        float(np.asarray(sp_loss)), float(np.asarray(pl_loss)), rtol=1e-4
-    )
+    assert_sp_loss_parity(trainer, plain, batch)
 
 
 def test_sequence_parallel_ppo_composes_with_tp(tmp_path):
@@ -178,8 +167,6 @@ def test_sequence_parallel_ppo_composes_with_tp(tmp_path):
     tensor-sharded params, the double-duty score shard_map incl. the
     hydra ref branch, the SP train loss) on data=2 x sequence=2 x
     tensor=2, with loss parity vs the plain PPOTrainer."""
-    import jax.numpy as jnp
-
     from trlx_tpu.data.default_configs import default_ppo_config
     from trlx_tpu.trainer.ppo_trainer import PPOTrainer
 
@@ -202,23 +189,13 @@ def test_sequence_parallel_ppo_composes_with_tp(tmp_path):
     assert trainer.iter_count >= 2
 
     batch = next(iter(trainer.store.create_loader(4, shuffle=False)))
-    sp_loss, _ = trainer.make_loss_fn()(
-        trainer.train_params, trainer.frozen_params, trainer.batch_to_device(batch)
-    )
-    host_train = {k: np.asarray(v) for k, v in trainer.train_params.items()}
-    host_frozen = {k: np.asarray(v) for k, v in trainer.frozen_params.items()}
     plain_cfg = config.evolve(
         train=dict(trainer="PPOTrainer"),
         parallel=dict(data=1, sequence=1, tensor=1),
         model=dict(model_extra_configs=dict(dtype="float32", attn_impl="xla")),
     )
     plain = PPOTrainer(plain_cfg, reward_fn=reward_fn, devices=jax.devices()[:1])
-    pl_loss, _ = jax.jit(plain.make_loss_fn())(
-        host_train, host_frozen, jax.tree_util.tree_map(jnp.asarray, batch)
-    )
-    np.testing.assert_allclose(
-        float(np.asarray(sp_loss)), float(np.asarray(pl_loss)), rtol=1e-4
-    )
+    assert_sp_loss_parity(trainer, plain, batch)
 
 
 def test_sequence_parallel_ilql_end_to_end_and_loss_parity(tmp_path):
@@ -227,8 +204,6 @@ def test_sequence_parallel_ilql_end_to_end_and_loss_parity(tmp_path):
     trlx.train on a data x sequence mesh, target-Q Polyak sync on the
     sharded layout, and exact loss parity vs the plain ILQLTrainer on
     identical params/batch."""
-    import jax.numpy as jnp
-
     from trlx_tpu.data.default_configs import default_ilql_config
     from trlx_tpu.trainer.ilql_trainer import ILQLTrainer
 
@@ -262,23 +237,13 @@ def test_sequence_parallel_ilql_end_to_end_and_loss_parity(tmp_path):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
     batch = next(iter(trainer.store.create_loader(4, shuffle=False, drop_last=True)))
-    sp_loss, _ = trainer.make_loss_fn()(
-        trainer.train_params, trainer.frozen_params, trainer.batch_to_device(batch)
-    )
-    host_train = {k: np.asarray(v) for k, v in trainer.train_params.items()}
-    host_frozen = {k: np.asarray(v) for k, v in trainer.frozen_params.items()}
     plain_cfg = config.evolve(
         train=dict(trainer="ILQLTrainer"),
         parallel=dict(data=1, sequence=1),
         model=dict(model_extra_configs=dict(dtype="float32", attn_impl="xla")),
     )
     plain = ILQLTrainer(plain_cfg, devices=jax.devices()[:1])
-    pl_loss, _ = jax.jit(plain.make_loss_fn())(
-        host_train, host_frozen, jax.tree_util.tree_map(jnp.asarray, batch)
-    )
-    np.testing.assert_allclose(
-        float(np.asarray(sp_loss)), float(np.asarray(pl_loss)), rtol=1e-4
-    )
+    assert_sp_loss_parity(trainer, plain, batch)
 
 
 def test_sequence_parallel_ppo_validation(tmp_path):

@@ -377,3 +377,15 @@ def test_tpe_sweep_writes_report(tmp_path):
     report = (sweep_dir / "sweep_report.md").read_text()
     assert "Best trial" in report and "Parameter analysis" in report
     assert "method.x" in report
+
+
+def test_every_file_tier_1_starts_first_is_there():
+    """`conftest.LONGEST_FIRST` is the order tier-1's workers start the long
+    files in: every name on it is a file under `tests/`, once, so a rename or
+    a split cannot leave the list pointing at nothing."""
+    import conftest
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    names = conftest.LONGEST_FIRST
+    assert len(names) == len(set(names)) > 0
+    assert [name for name in names if not os.path.isfile(os.path.join(here, name))] == []

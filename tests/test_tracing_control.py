@@ -433,6 +433,13 @@ def test_no_session_formats_no_counter_span(paged_engine, monkeypatch):
 
 
 def test_a_session_changes_no_token_and_no_logprob(paged_engine, tmp_path):
+    """A tracing session changes no token and no arithmetic. The tokens are
+    compared exactly. The float32 logprobs are held to 4 units in the last
+    place, not to the bit: the CPU backend splits a reduction over its thread
+    pool by what is free at the time, so two `serve` calls of ONE program
+    beside five busy test workers can differ by one such unit (PR 42 saw it
+    with the long files collected first; alone they are bit-equal). A session
+    that changed the arithmetic would move a token or many units."""
     idle(paged_engine)
     plain = serve(paged_engine, range(4))
     idle(paged_engine)
@@ -443,7 +450,8 @@ def test_a_session_changes_no_token_and_no_logprob(paged_engine, tmp_path):
         tracing.stop()
     for (tokens, logprobs), (tokens_s, logprobs_s) in zip(plain, under_session):
         assert tokens == tokens_s
-        np.testing.assert_array_equal(logprobs, logprobs_s)
+        # (the scheduler hands the engine's float32 values out as Python floats)
+        np.testing.assert_array_max_ulp(np.float32(logprobs), np.float32(logprobs_s), maxulp=4)
     assert counter_spans(engine_spans(str(tmp_path)), "engine.queued")  # and the session listened
 
 

@@ -375,8 +375,8 @@ def test_ppo_windowed_loss_equals_full_forward(tmp_path):
 
     loader = trainer.store.create_loader(8, shuffle=False)
     chunk = jax.tree_util.tree_map(jnp.asarray, next(iter(loader)))
-    lw, sw = loss_windowed(trainer.train_params, trainer.frozen_params, chunk)
-    lf, sf = loss_full(trainer.train_params, trainer.frozen_params, chunk)
+    lw, sw = jax.jit(loss_windowed)(trainer.train_params, trainer.frozen_params, chunk)
+    lf, sf = jax.jit(loss_full)(trainer.train_params, trainer.frozen_params, chunk)
     np.testing.assert_allclose(np.asarray(lw), np.asarray(lf), rtol=1e-6)
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(

@@ -622,13 +622,13 @@ def test_whiten_with_mask_both_behaviors(trainer, chunk):
     assert not np.allclose(np.asarray(adv_u), np.asarray(adv_m))
 
     full = _uncached(chunk)
-    loss_off, _ = trainer.make_loss_fn()(
+    loss_off, _ = jax.jit(trainer.make_loss_fn())(
         trainer.train_params, trainer.frozen_params, full
     )
     on_config = trainer.config
     try:
         trainer.config = on_config.evolve(method=dict(whiten_with_mask=True))
-        loss_on, _ = trainer.make_loss_fn()(
+        loss_on, _ = jax.jit(trainer.make_loss_fn())(
             trainer.train_params, trainer.frozen_params, full
         )
     finally:

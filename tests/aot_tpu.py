@@ -106,6 +106,42 @@ def instructions_of_at_least(compiled, elements: int) -> list:
             if n >= elements and op not in names_or_updates]
 
 
+def loop_body_instructions(compiled, having: str = ""):
+    """(elements, opcode, text) of every array-valued instruction that a
+    `while` of a compiled program (one whose body's text holds `having`)
+    runs as a step of its own: those of its
+    body and condition and of the loops and branches nested in them. A
+    fusion counts as what its root is (a fusion that is one `copy` moves
+    its operand through HBM; a `copy` nested inside a product's fusion is
+    how that product reads its operand, and is not listed)."""
+    text = compiled.as_text()
+    blocks = {m.group(1): m.group(2) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.-]+) \([^\n]*\{\n(.*?)^\}", text, re.S | re.M)}
+    flow = r"(?:body|condition|branch_computations|true_computation|false_computation|to_apply)=\{?([^}\n]*)"
+    whiles = [re.findall(r"%([\w.-]+)", " ".join(re.findall(flow, line)))
+              for body in blocks.values() for line in body.splitlines() if " while(" in line]
+    todo = [name for names in whiles if any(having in blocks.get(n, "") for n in names) for name in names]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in blocks:
+            continue
+        seen.add(name)
+        todo += [n for line in blocks[name].splitlines() if " fusion(" not in line and " reduce(" not in line
+                 for names in re.findall(flow, line) for n in re.findall(r"%([\w.-]+)", names)]
+    result = r"= \w+\[([\d,]+)\]\S* ([\w-]+)\("
+    for name in sorted(seen):
+        for line in blocks[name].splitlines():
+            m = re.search(result, line)
+            if not m:
+                continue
+            op, fused = m.group(2), re.search(r"calls=%([\w.-]+)", line)
+            if op == "fusion" and fused and fused.group(1) in blocks:
+                root = re.search(r"ROOT [^\n]*?" + result, blocks[fused.group(1)])
+                op = root.group(2) if root else op
+            yield int(np.prod([int(d) for d in m.group(1).split(",")])), op, line.strip()
+
+
 def donated_outputs(compiled) -> int:
     """How many outputs of the program live in a donated input's buffer."""
     alias = re.search(r"input_output_alias=\{(.*?)\}, entry_computation_layout",
@@ -221,6 +257,20 @@ def ppo_cell_params(trainer, with_ref=False):
         return train, frozen
     return train, frozen, jax.eval_shape(
         lambda p: ref_param_subtree(p, trainer.model_cfg, trainer.split), params)
+
+
+def traced_generate(trainer, device, rows, width, **generate_kwargs):
+    """The trainer's `generate` program for a batch of that shape, traced
+    over shapes (float32 leaves, as the PPO cells hold them) for one
+    described chip."""
+    one = SingleDeviceSharding(device)
+    probe = jnp.zeros((1, 8), I32)
+    params = jax.eval_shape(
+        lambda: trainer.model.init(jax.random.PRNGKey(0), probe, jnp.ones_like(probe))["params"])
+    tokens = S((rows, width), I32, sharding=one)
+    fn = trainer.get_generate_fn(rows, width, trainer.generate_kwargs, **generate_kwargs)
+    return fn.trace(abstract(params, one), tokens, tokens,
+                    S(trainer.rng.shape, trainer.rng.dtype, sharding=one))
 
 
 def traced_score(trainer, device, rows, width, hands_out):

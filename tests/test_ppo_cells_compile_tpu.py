@@ -1,7 +1,9 @@
 """The dense PPO cells' trainer programs, compiled for one v5e chip with no
 chip: `pythia-1.4b.ppo-hh`'s trunk-cache fill and the train step resumed
-from it, and `gpt2-xl.ppo-sentiments`' score program that hands out the
-trunk state, traced from where `PPOTrainer` makes them at the cells' widths
+from it, its one `generate` program that follows a chunk's longest prompt
+(and `gpt2-xl.ppo-sentiments`' `generate`, which is too narrow to), and
+`gpt2-xl.ppo-sentiments`' score program that hands out the trunk state,
+traced from where `PPOTrainer` makes them at the cells' widths
 (`aot_tpu.ppo_cell_trainer`). `lfm2-8b-a1b.ppo-hh`'s are in
 `test_lfm2_compile_tpu.py`.
 """
@@ -9,13 +11,14 @@ trunk state, traced from where `PPOTrainer` makes them at the cells' widths
 import pytest
 
 import jax
+from jax.extend.core import Literal
 from jax.sharding import SingleDeviceSharding
 
 pytest.importorskip("libtpu", reason="AOT compilation for the TPU needs libtpu")
 
 from aot_tpu import (  # noqa: E402, F401  (v5e and pallas_mode are fixtures)
-    abstract, BF16, donated_outputs, F32, I32, kernel_names, pallas_mode, ppo_cell_params,
-    ppo_cell_trainer, S, traced_score, v5e,
+    abstract, BF16, donated_outputs, F32, held_bytes, I32, kernel_names, loop_body_instructions,
+    pallas_mode, ppo_cell_params, ppo_cell_trainer, S, traced_generate, traced_score, v5e,
 )
 
 
@@ -87,6 +90,74 @@ def test_dense_ppo_cell_train_step_resumes_from_the_trunk_cache(v5e, pallas_mode
     assert (resumed, whole) == (2, trainer.model_cfg.n_layers)
     # the cache is an argument the step reads and hands back to nobody
     assert donated_outputs(compiled) == len(jax.tree_util.tree_leaves((train, opt_state)))
+
+
+def _loops_and_branches(jaxpr):
+    """(the `while` equations, the `cond` equations) of a jaxpr's top level."""
+    return ([e for e in jaxpr.eqns if e.primitive.name == "while"],
+            [e for e in jaxpr.eqns if e.primitive.name == "cond"])
+
+
+def test_dense_ppo_cell_generate_follows_the_longest_prompt_in_one_program(v5e, pallas_mode, ppo_hh_trainer,
+                                                                           monkeypatch):
+    """`generate` for one chunk of the cell, 16 x 896 + 128: ONE prefill
+    loop over blocks of 128 columns whose first trip is data (the chunk's
+    first live block), then the one 128-step loop, in which every attention
+    layer chooses among five suffixes of the cache's 1,024 columns (256 /
+    384 / 512 / 768 / 1,024). It compiles for one v5e chip and holds less
+    than the one-shot program at the same depth (whose prefill scores
+    `[16, 16, 896, 1024]` in float32 a layer), and no step of the decode
+    loop copies a whole cache plane as an operation of its own (3.2 GB a
+    step at the cell's depth). The planes do change their layout once
+    between the two loops, as half of them do after the one-shot prefill;
+    the hoisted switch (one loop a width under one conditional) copied them
+    in every branch and its program did not load beside the trainer's
+    state: PERF.md section 6, PR 46."""
+    from trlx_tpu.models.transformer import live_widths
+    from trlx_tpu.ops import sampling
+
+    trainer = ppo_hh_trainer
+    plan = trainer._rollout_plan(896, trainer.generate_kwargs)
+    assert (plan.block, plan.pad, plan.blocks, plan.columns) == (128, 0, 7, 1024)
+    assert live_widths(plan.columns) == (256, 384, 512, 768, 1024)
+    traced = traced_generate(trainer, v5e[0], 16, 896)
+    loops, branches = _loops_and_branches(traced.jaxpr.jaxpr)
+    assert len(loops) == 2 and not branches, (len(loops), len(branches))
+    prefill, decode = loops
+    start = prefill.invars[prefill.params["cond_nconsts"] + prefill.params["body_nconsts"]]
+    assert not isinstance(start, Literal), "the prefill loop starts at a constant block"
+    # the prefill's body attends over the whole cache; a decode step's layers choose
+    assert not _loops_and_branches(prefill.params["body_jaxpr"].jaxpr)[1]
+    choices = _loops_and_branches(decode.params["body_jaxpr"].jaxpr)[1]
+    assert [len(c.params["branches"]) for c in choices] == [5] * trainer.model_cfg.n_layers
+    compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 2 and text.count(" conditional(") == trainer.model_cfg.n_layers
+    plane = 16 * 1024 * 16 * 128
+    steps = list(loop_body_instructions(compiled, having=" conditional("))
+    # (the loop in hand is the one that writes a token's keys and values into every plane)
+    assert sum(op == "dynamic-update-slice" and n == plane for n, op, _ in steps) == 2 * trainer.model_cfg.n_layers
+    moved = [line[:140] for n, op, line in steps
+             if n >= plane and op in ("copy", "copy-start", "copy-done", "transpose")]
+    assert not moved, moved
+    monkeypatch.setattr(sampling, "PREFILL_BLOCK", 0)
+    one_shot = traced_generate(trainer, v5e[0], 16, 896).lower(lowering_platforms=("tpu",)).compile()
+    assert one_shot.as_text().count(" while(") == 1 and one_shot.as_text().count(" conditional(") == 0
+    assert held_bytes(compiled) < held_bytes(one_shot) < 16e9, (held_bytes(compiled), held_bytes(one_shot))
+
+
+def test_gpt2_xl_generate_is_too_narrow_to_follow_and_keeps_its_program(v5e, pallas_mode, tmp_path):
+    """`gpt2-xl.ppo-sentiments` generates 128 x 64 + 40: a prompt block under
+    two blocks of 128 and a cache of 104 columns with one width to read. Its
+    program holds the one-shot prefill, one loop and no conditional."""
+    trainer = ppo_cell_trainer(tmp_path, "gpt2-xl", dict(vocab_size=50257, attn_impl="flash", n_layers=4),
+                               batch_size=32, num_rollouts=128, chunk_size=128, max_new=40)
+    assert trainer._rollout_plan(64, trainer.generate_kwargs) is None
+    traced = traced_generate(trainer, v5e[0], 128, 64)
+    loops, branches = _loops_and_branches(traced.jaxpr.jaxpr)
+    assert len(loops) == 1 and not branches
+    text = traced.lower(lowering_platforms=("tpu",)).compile().as_text()
+    assert text.count(" while(") == 1 and text.count(" conditional(") == 0
 
 
 def test_gpt2_xl_score_keeps_its_weight_prefetches_with_the_trunk_state(v5e, pallas_mode, tmp_path):

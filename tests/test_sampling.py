@@ -197,3 +197,95 @@ def test_repetition_penalty_discourages_repeats():
         assert not (set(valid.tolist()) & prompt_toks), (valid, prompt_toks)
     # sanity: the un-penalized greedy run differs (penalty actually engaged)
     assert not np.array_equal(toks_plain, toks_pen)
+
+
+# ---------------------------------------------------------------------------
+# One program whose work follows the chunk's longest prompt (BlockPlan)
+# ---------------------------------------------------------------------------
+
+BLOCK = 8
+
+
+def left_padded(lengths, width, seed=0):
+    rng = np.random.default_rng(seed)
+    ids = np.full((len(lengths), width), PAD, np.int32)
+    mask = np.zeros((len(lengths), width), np.int32)
+    for i, n in enumerate(lengths):
+        ids[i, width - n:] = rng.integers(0, 60, size=n)
+        mask[i, width - n:] = 1
+    return jnp.asarray(ids), jnp.asarray(mask)
+
+
+@pytest.mark.parametrize("preset, width, lengths, blocks_run, read, kw", [
+    ("gpt2-tiny", 32, [3, 7, 5, 8], 1, 16, {}),               # the longest prompt fills one block of four
+    ("gpt2-tiny", 32, [12, 16, 9, 2], 2, 24, {}),             # two
+    ("gpt2-tiny", 32, [32, 1, 20, 5], 4, 40, {}),             # all: the whole cache is read
+    ("gpt2-tiny", 32, [0, 14, 3, 9], 2, 24, {}),              # a row that is all padding
+    ("gpt2-tiny", 32, [10, 4, 6, 2], 2, 24, dict(mode="ilql")),
+    ("gpt2-tiny", 32, [17, 4, 6, 2], 3, 32, dict(capture=True)),
+    # a width that is no whole number of blocks is left-padded to one inside
+    # the program (30 -> 32): outputs keep the caller's columns
+    ("gpt2-tiny", 30, [5, 2, 6, 4], 1, 16, dict(capture=True)),
+    ("gpt2-tiny", 30, [30, 2, 11, 4], 4, 40, {}),
+    ("gpt2-tiny", 32, [9, 4, 6, 2], 2, 24, dict(repetition_penalty=1.3)),
+    ("llama-tiny", 32, [13, 4, 16, 2], 2, 24, dict(capture=True)),   # rope, 2 K/V heads under 4
+    ("bloom-tiny", 32, [3, 4, 6, 2], 1, 16, {}),              # ALiBi: the bias is cut with the columns
+    ("neox-tiny", 32, [20, 4, 6, 2], 3, 32, dict(capture=True)),     # the cells' family
+])
+def test_block_form_generate_matches_the_one_shot_program(preset, width, lengths, blocks_run, read, kw):
+    """`generate` with the prompt prefilled by blocks of 8 from the first
+    live one and the loop reading the cache's live suffix, against the
+    one-shot program on the same params, prompts and key, in float32 at
+    `highest`: the same tokens and masks, and the captured logprobs, values
+    and split activations within 1e-5 on every live row."""
+    from trlx_tpu.ops.sampling import block_plan, first_live_column
+
+    mc = ModelConfig(model_path=f"random:{preset}", model_extra_configs={"dtype": "float32"})
+    mode = kw.pop("mode", "lm")
+    capture = kw.pop("capture", False)
+    model, cfg, params = build_model(mc, vocab_size=64, with_ilql_heads=mode == "ilql")
+    ids, mask = left_padded(lengths, width)
+    g = gen_cfg(do_sample=True, temperature=0.9, **kw)
+    plan = block_plan(cfg, g, width, BLOCK)
+    first = int(first_live_column(np.asarray(mask)))
+    assert (plan.blocks - plan.first_block(first), plan.read_columns(first)) == (blocks_run, read)
+    assert plan.columns == plan.pad + width + 8 and plan.columns - read <= first + plan.pad
+
+    def run(block):
+        fn = jax.jit(make_generate_fn(model, cfg, g, mode=mode, capture=capture, capture_split=1,
+                                      prefill_block=block))
+        with jax.default_matmul_precision("highest"):
+            return jax.device_get(fn(params, ids, mask, jax.random.PRNGKey(4)))
+
+    one, blk = run(0), run(BLOCK)
+    live = np.asarray(lengths) > 0  # a row of padding attends to whatever the cache holds
+    assert set(one) == set(blk)
+    for name in ("samples", "samples_mask", "response_tokens", "response_mask"):
+        assert one[name].shape == blk[name].shape
+        np.testing.assert_array_equal(one[name][live], blk[name][live], err_msg=name)
+    if capture:
+        assert one["h_split"].shape == blk["h_split"].shape == (len(lengths), width + 8, cfg.d_model)
+        np.testing.assert_allclose(blk["logprobs"][live], one["logprobs"][live], atol=1e-5)
+        np.testing.assert_allclose(blk["values"][live], one["values"][live], atol=1e-5)
+        held = one["samples_mask"].astype(bool)[:, :-1]  # the last sampled token's row is never written
+        np.testing.assert_allclose(blk["h_split"][:, :-1][held], one["h_split"][:, :-1][held], atol=1e-5)
+        # the columns of the blocks not run keep their zeros
+        skipped = (plan.blocks - blocks_run) * BLOCK - plan.pad
+        assert not blk["h_split"][:, :max(skipped, 0)].any()
+
+
+@pytest.mark.parametrize("preset, width, takes", [
+    ("gpt2-tiny", 16, True),        # two blocks
+    ("gpt2-tiny", 15, False),       # under two blocks: today's program
+    ("lfm2-tiny", 32, False),       # convolution state a row, and the fused prefill
+    ("ling-flash-tiny", 32, False),  # a recurrent state a row
+])
+def test_who_takes_the_block_form(preset, width, takes):
+    from trlx_tpu.models import config_from_preset
+    from trlx_tpu.ops.sampling import block_plan
+
+    cfg = config_from_preset(preset, 64)
+    assert (block_plan(cfg, gen_cfg(), width, BLOCK) is not None) is takes
+    assert block_plan(cfg, gen_cfg(), width, 0) is None
+    assert block_plan(cfg, gen_cfg(num_beams=2), width, BLOCK) is None
+    assert block_plan(cfg, gen_cfg(), width, BLOCK, spec_k=2) is None

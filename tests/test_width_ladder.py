@@ -27,6 +27,7 @@ import numpy as np
 import pytest
 
 from trlx_tpu.data.configs import TokenizerConfig
+from trlx_tpu.models.transformer import live_widths
 from trlx_tpu.data.default_configs import default_grpo_config, default_ppo_config
 from trlx_tpu.pipeline import LoaderStream, offline_pipeline
 from trlx_tpu.pipeline.offline_pipeline import PromptPipeline, prompt_width_ladder
@@ -341,3 +342,101 @@ def test_grpo_groups_stay_together_in_sorted_chunks():
     assert len(window) == 16 and window[0::2] == window[1::2]
     assert [len(p) for p in window] == sorted((len(p) for p in window), reverse=True)
     assert grpo._prompt_ladder is not None and grpo._prompt_ladder[-1] == 100
+
+
+# ---------------------------------------------------------------------------
+# a sampler whose one program follows the chunk's longest prompt (BlockPlan)
+# ---------------------------------------------------------------------------
+
+BLOCK = 32  # the pool's width, 100, runs in `generate`'s bucket of 128: four blocks
+
+
+@pytest.fixture
+def block_form(trainer, monkeypatch):
+    from trlx_tpu.ops import sampling
+
+    monkeypatch.setattr(sampling, "PREFILL_BLOCK", BLOCK)
+    return _with_pool(trainer, POOL)
+
+
+def test_block_form_model_gets_no_ladder(block_form, monkeypatch):
+    trainer = block_form
+    assert trainer._prompt_ladder is None and trainer._ladder_countdown == 0
+    plan = trainer._rollout_plan(100, trainer.generate_kwargs)
+    assert (plan.block, plan.pad, plan.blocks, plan.columns) == (BLOCK, 0, 4, 128 + MAX_NEW)
+    # the loader still sorts a collection's prompts: that is what makes a chunk's longest short
+    window = [p for chunk in _take(trainer.prompt_iterator, CHUNKS) for p in chunk]
+    assert [len(p) for p in window] == sorted((len(p) for p in window), reverse=True)
+    # who keeps the ladder: right padding (no block in front is empty), speculative
+    # rounds, a pipelined trainer's own `generate`
+    monkeypatch.setattr(trainer.config.tokenizer, "padding_side", "right")
+    assert _with_pool(trainer, POOL)._prompt_ladder == (32, 100)
+    monkeypatch.setattr(trainer.config.tokenizer, "padding_side", "left")
+    assert _with_pool(trainer, POOL)._prompt_ladder is None
+    assert trainer._rollout_plan(100, trainer.generate_kwargs, spec_k=2) is None
+    monkeypatch.setattr(trainer, "_narrows_rollout_chunks", False)
+    assert trainer._rollout_plan(100, trainer.generate_kwargs) is None
+
+
+def test_block_form_runs_four_chunks_through_one_program_and_counts_its_blocks(block_form, monkeypatch):
+    trainer = block_form
+    spans = []
+    monkeypatch.setattr(ppo_trainer.tracing, "active", lambda: True)
+    monkeypatch.setattr(ppo_trainer.tracing, "counters",
+                        lambda name, **values: spans.append((name, values)))
+    # (a token budget of its own: the block is not in a program's name)
+    new_tokens = MAX_NEW + 4
+    gen_kwargs = dict(trainer.generate_experience_kwargs or trainer.generate_kwargs, max_new_tokens=new_tokens)
+    before, tokens, longest = set(_generate_programs(trainer)), 0, []
+    trainer._prefill_tally[:] = 0  # as a collection's start does
+    for _ in range(CHUNKS):
+        batch = next(trainer.prompt_iterator)
+        tokens += int(batch["attention_mask"].sum())
+        longest.append(int(batch["attention_mask"].sum(axis=1).max()))
+        out = trainer._rollout_generate(batch, gen_kwargs)
+        assert out["samples"].shape == (ROWS, 100 + new_tokens)
+        np.testing.assert_array_equal(np.asarray(out["samples"])[:, :100], batch["input_ids"])
+    new = set(_generate_programs(trainer)) - before
+    assert len(new) == 1 and all(n.startswith("generate[b8,p128,lm,kw") for n in new), new
+    assert len(set(longest)) == CHUNKS, longest  # four chunks, four longest prompts
+    assert [name for name, _ in spans] == ["ppo.prefill"] * CHUNKS
+    for (_, v), need in zip(spans, longest):
+        assert list(v) == ["calls", "rows", "width", "prompt_tokens", "padded_tokens", "pad_tokens",
+                           "blocks", "blocks_run", "read_columns", "cache_columns"]
+        assert v["rows"] == 8 and v["blocks"] == 4 and v["cache_columns"] == 128 + new_tokens
+        assert v["blocks_run"] == -(-need // BLOCK)  # the blocks that hold the longest prompt
+        assert v["width"] == v["blocks_run"] * BLOCK
+        assert v["padded_tokens"] == v["rows"] * v["blocks_run"] * BLOCK
+        assert v["pad_tokens"] == v["padded_tokens"] - v["prompt_tokens"]
+        # the narrowest of the cache's suffix widths that holds the prompt and the response
+        widths = live_widths(128 + new_tokens)
+        assert widths == (40, 56, 72, 104, 136)
+        assert v["read_columns"] == next(w for w in widths if w >= need + new_tokens)
+    assert sum(v["prompt_tokens"] for _, v in spans) == tokens
+    calls, width_sum, padded, pad = (int(x) for x in trainer._prefill_tally)
+    assert calls == CHUNKS and width_sum == sum(v["width"] for _, v in spans)
+    assert padded == sum(v["padded_tokens"] for _, v in spans) < CHUNKS * 8 * 128
+
+
+def test_block_form_chunk_matches_the_one_shot_program(block_form, monkeypatch):
+    """Through the trainer's own door, with the captured stats: the chunk
+    generated by blocks against the same chunk through the one-shot program."""
+    from trlx_tpu.ops import sampling
+
+    trainer = block_form
+    ids, mask = _left_padded([5, 30, 17, 23, 8, 2, 29, 11], 100, trainer.tokenizer.pad_token_id)
+    # greedy, so one more token of budget changes none of the tokens before
+    # it (a budget a program: the block is not in a program's name)
+    n = MAX_NEW + 2
+    blocks = jax.device_get(trainer.generate(ids, mask, dict(max_new_tokens=n, do_sample=False), capture=True))
+    monkeypatch.setattr(sampling, "PREFILL_BLOCK", 0)
+    whole = jax.device_get(trainer.generate(ids, mask, dict(max_new_tokens=n + 1, do_sample=False),
+                                            capture=True))
+    assert blocks["samples"].shape == (8, 100 + n) and blocks["h_split"].shape[:2] == (8, 100 + n)
+    for name in ("samples", "samples_mask"):
+        np.testing.assert_array_equal(blocks[name], whole[name][:, :100 + n])
+    np.testing.assert_allclose(blocks["logprobs"], whole["logprobs"][:, :n], atol=2e-5)
+    np.testing.assert_allclose(blocks["values"], whole["values"][:, :n], atol=2e-5)
+    live = blocks["samples_mask"].astype(bool)[:, :-1]
+    np.testing.assert_allclose(blocks["h_split"][:, :-1][live], whole["h_split"][:, :100 + n - 1][live],
+                               atol=2e-5)

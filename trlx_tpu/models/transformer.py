@@ -849,26 +849,26 @@ class Attention(nn.Module):
                 out = flash_attention(q, k, v, mask=attn_mask, causal=True, **band)
             out = out.astype(cfg.dtype)
         else:
-            qk, pv = "bthd,bshd->bhts", "bhts,bshd->bthd"
-            if nkv != nh:
-                # GQA/MQA: K/V stay at n_kv_heads — query head h reads kv
-                # head h // g, so the g query heads of a kv head are one more
-                # axis of the same two products and the cache is read once
-                # for all of them, never repeated to n_heads.
-                g = nh // nkv
-                q = q.reshape(b, t, nkv, g, hd)
-                qk, pv = "btkgd,bskd->bkgts", "bkgts,bskd->btkgd"
-                # [b, 1, t, S] broadcasts over both head axes; a per-head
-                # bias (ALiBi's [b, nh, 1, S]) splits its heads as q did
-                attn_bias = (
-                    attn_bias[:, :, None] if attn_bias.shape[1] == 1
-                    else attn_bias.reshape(attn_bias.shape[0], nkv, g, *attn_bias.shape[2:]))
-            scale = 1.0 / np.sqrt(hd)
-            # [b, h, t, S] ([b, nkv, g, t, S]) — accumulate scores in f32 for stability.
-            scores = jnp.einsum(qk, q, k, preferred_element_type=jnp.float32) * scale
-            scores = scores + attn_bias  # bias is f32, -inf on masked
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            out = jnp.einsum(pv, probs, v)
+            # `dense_attention` (at the end of this file, below every caller of a
+            # kernel: a Pallas program is keyed by its callers' lines) over the
+            # block's or the cache's keys and values. A decode step over a
+            # `first` cache (`decode_step`): no row has a token in front of the
+            # suffix chosen there, so the two products and the bias take those
+            # columns and the rest of the cache is not read. One branch a width
+            # of `live_widths`; the planes go in whole and each branch slices
+            # them, which the compiler reads in place. (One conditional
+            # hoisted round the whole decode loop re-laid every plane in every branch,
+            # and its program did not load beside a trainer's state:
+            # PERF.md section 6, PR 46.) The products' lines are `Attention`'s
+            # of before, moved.
+            if layer_cache is not None and "live" in layer_cache:
+                S = k.shape[1]
+                out = jax.lax.switch(layer_cache["live"], [
+                    (lambda q, k, v, bias, lo=S - w: dense_attention(
+                        q, k[:, lo:], v[:, lo:], bias[..., lo:], nkv, cfg.dtype))
+                    for w in live_widths(S)], q, k, v, attn_bias)
+            else:
+                out = dense_attention(q, k, v, attn_bias, nkv, cfg.dtype)
         return project_out(out), new_cache
 
 
@@ -1768,36 +1768,30 @@ class TransformerLM(nn.Module):
           its own mask, causal within the block); under prompt tuning the
           prefill prepends the soft prompt into the cache (init_kv_cache
           reserves the extra slots) and logits keep the caller's sequence
-          length.
-        - **`row_index`** ([b]): every row carries its OWN write offset —
-          the continuous-batching slot pool and the paged arena
-          (trlx_tpu/inference/engine.py), speculative decode. Rows sit at
-          different depths, which the shared scalar cannot express; for a
-          live row the computation is bit-identical to the scalar one on an
-          aligned batch, because masked cache columns contribute exactly
-          0.0 to every softmax sum wherever they sit (exp(-1e9) == 0.0 in
-          f32). t == 1 is a decode step: a row whose token_mask is 0 writes
-          a 0 into the mask at its current column — a value-level no-op —
-          and does not advance. t > 1 is a RIGHT-padded prefill: row r's
-          valid tokens occupy columns [row_index_r, row_index_r + len_r),
-          a nonzero row_index resumes behind a shared prefix already
-          resident in the cache (prefix-cache hit) whose mask bits the
-          caller seeds, and the pad positions write nothing the model can
-          see (mask bit 0; paged arena writes are dropped via the mask).
+          length. With a scalar **`first`** beside it (the sampler's
+          prefill by blocks): `live_widths`.
+        - **`row_index`** ([b]): every row carries its OWN write offset — the continuous-batching
+          slot pool and the paged arena (trlx_tpu/inference/engine.py), speculative decode. Rows
+          sit at different depths, which the shared scalar cannot express; for a live row the
+          computation is bit-identical to the scalar one on an aligned batch, because masked
+          cache columns contribute exactly 0.0 to every softmax sum wherever they sit (exp(-1e9)
+          == 0.0 in f32). t == 1 is a decode step: a row whose token_mask is 0 writes a 0 into
+          the mask at its current column — a value-level no-op — and does not advance. t > 1 is a
+          RIGHT-padded prefill: row r's valid tokens occupy columns [row_index_r, row_index_r +
+          len_r), a nonzero row_index resumes behind a shared prefix already resident in the
+          cache (prefix-cache hit) whose mask bits the caller seeds, and the pad positions write
+          nothing the model can see (mask bit 0; paged arena writes are dropped via the mask).
 
-        Speculative decode is this step three ways. A draft step is a
-        per-row t == 1 step with `stop=split`: it writes trunk K/V and sets
-        each position's mask bit as it goes — a drafted position becomes a
-        visible key only once its K/V is in the cache, so later-rejected
-        drafts roll back by clearing bits, and stale K/V beyond the
-        frontier contributes exactly zero — and passes the suffix layers'
-        caches through. The verify pass resumes `start=split` from the
-        trunk's own rows (x = the drafts' captured states), writing suffix
-        K/V for all t candidates in ONE pass; the draft steps have already
-        set its mask bits and moved row_index on, so it names the block's
-        first column (`block_start`) and its `positions` itself and moves
-        nothing (`token_mask` then only gates paged-arena writes; dense
-        caches ignore it)."""
+        Speculative decode is this step three ways. A draft step is a per-row t == 1 step with
+        `stop=split`: it writes trunk K/V and sets each position's mask bit as it goes — a
+        drafted position becomes a visible key only once its K/V is in the cache, so
+        later-rejected drafts roll back by clearing bits, and stale K/V beyond the frontier contributes
+        exactly zero — and passes the suffix layers' caches through. The verify pass resumes
+        `start=split` from the trunk's own rows (x = the drafts' captured states), writing suffix
+        K/V for all t candidates in ONE pass; the draft steps have already set its mask bits and
+        moved row_index on, so it names the block's first column (`block_start`) and its
+        `positions` itself and moves nothing (`token_mask` then only gates paged-arena writes;
+        dense caches ignore it)."""
         cfg = self.cfg
         to_head, stop = stop is None, cfg.n_layers if stop is None else stop
         b, t = x.shape[:2]
@@ -1832,6 +1826,8 @@ class TransformerLM(nn.Module):
             if is_prefill:
                 positions = position_ids(token_mask)
                 next_pos = token_mask.sum(-1).astype(jnp.int32)
+                if "first" in cache:  # a block behind others: as the per-row prefill below
+                    positions, next_pos = cache["pos"][:, None] + positions, cache["pos"] + next_pos
                 block_start = offset
             else:
                 positions = cache["pos"][:, None]
@@ -1862,6 +1858,10 @@ class TransformerLM(nn.Module):
                 jnp.arange(b)[:, None], jnp.clip(cols, 0, S - 1)
             ].set(token_mask.astype(mask_dtype))
         bias = cached_bias(cfg, new_mask, positions, block_start)
+        layers = cache["layers"]
+        if "first" in cache and not is_prefill and len(live_widths(new_mask.shape[-1])) > 1:
+            live = live_width_index(cache["first"], new_mask.shape[-1])
+            layers = [{**layer, "live": live} for layer in layers]
 
         if start > 0:
             h = x
@@ -1892,7 +1892,7 @@ class TransformerLM(nn.Module):
             if s == capture_split:
                 h_cap = h
             h, segment = self.run_blocks(
-                h, bias, positions, s, e, cache=cache["layers"],
+                h, bias, positions, s, e, cache=layers,
                 cache_index=offset, attn_mask=step_mask, attn_kernel=attn_kernel,
             )
             new_layers += segment
@@ -1905,6 +1905,8 @@ class TransformerLM(nn.Module):
             logits, h = None, self.ln_f(h)
         if not per_row:
             counters = {"index": offset + t, "pos": next_pos}
+            if "first" in cache:
+                counters["first"] = cache["first"]
         elif advance is None:
             counters = {"row_index": cache["row_index"], "pos": cache["pos"]}
         else:
@@ -1920,6 +1922,71 @@ def position_ids(attn_mask: jnp.ndarray) -> jnp.ndarray:
     (mirrors the reference's position_ids computation,
     accelerate_ppo_trainer.py:176-180)."""
     return jnp.clip(jnp.cumsum(attn_mask.astype(jnp.int32), axis=-1) - 1, 0, None)
+
+
+def dense_attention(q, k, v, attn_bias, nkv: int, dtype):
+    """Softmax attention as two plain products: q [b, t, nh, hd] over k, v
+    [b, S, nkv, hd] under an additive bias [b, 1 | nh, t, S] -> [b, t, nh, hd]
+    (`Attention`'s path wherever no fused kernel runs)."""
+    b, t, nh, hd = q.shape
+    qk, pv = "bthd,bshd->bhts", "bhts,bshd->bthd"
+    if nkv != nh:
+        # GQA/MQA: K/V stay at n_kv_heads — query head h reads kv
+        # head h // g, so the g query heads of a kv head are one more
+        # axis of the same two products and the cache is read once
+        # for all of them, never repeated to n_heads.
+        g = nh // nkv
+        q = q.reshape(b, t, nkv, g, hd)
+        qk, pv = "btkgd,bskd->bkgts", "bkgts,bskd->btkgd"
+        # [b, 1, t, S] broadcasts over both head axes; a per-head
+        # bias (ALiBi's [b, nh, 1, S]) splits its heads as q did
+        attn_bias = (
+            attn_bias[:, :, None] if attn_bias.shape[1] == 1
+            else attn_bias.reshape(attn_bias.shape[0], nkv, g, *attn_bias.shape[2:]))
+    scale = 1.0 / np.sqrt(hd)
+    # [b, h, t, S] ([b, nkv, g, t, S]) — accumulate scores in f32 for stability.
+    scores = jnp.einsum(qk, q, k, preferred_element_type=jnp.float32) * scale
+    scores = scores + attn_bias  # bias is f32, -inf on masked
+    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+    return jnp.einsum(pv, probs, v)
+
+
+def prefill_by_blocks(cfg: TransformerConfig, t: int, block: int) -> bool:
+    """Whether the sampler prefills a left-padded prompt of t columns a block
+    of `block` columns at a time, from the first block that holds a token
+    (`ops/sampling.py`): the prompt is at least two blocks, a block's step
+    scores against the cache (the fused prefill attends within the block
+    into an EMPTY cache), and every layer keeps columns of keys and values
+    and nothing a row (a convolution's or a recurrence's state is carried
+    through padding by its own rule), with no soft prompt or prefix in front."""
+    return (t >= 2 * block and not prefill_fuses(cfg, t) and not prefill_fuses(cfg, block)
+            and not cfg.has_slot_state and not cfg.has_latent_layers
+            and cfg.prompt_tokens == 0 and cfg.prefix_tokens == 0)
+
+
+def live_widths(columns: int) -> Tuple[int, ...]:
+    """The suffix widths a decode step over a `first` cache of that many
+    columns chooses from, ascending: 2, 3, 4, 6 and 8 eighths of it in whole
+    groups of 8 columns (256 / 384 / 512 / 768 / 1,024 of 1,024). Each is a
+    branch of every attention layer's conditional, so they are few, and no
+    step reads more than 1.5 times what it needs.
+
+    A `first` cache is a scalar-`index` cache (`decode_step`) that carries
+    `first`, a scalar: no row has a token in a column before it (the
+    sampler's prefill by blocks, `prefill_by_blocks`). It may start at any
+    column up to `first`; a prefill block appended behind others continues
+    each row's positions from `pos`; and a decode step attends over the
+    narrowest of these widths that starts at or before `first` and no other
+    column (none chosen where there is one width): the columns in front hold
+    no token, so the result is the same and the cache is read that much less."""
+    return tuple(sorted({min(-(-columns * n // 64) * 8, columns) for n in (2, 3, 4, 6, 8)}))
+
+
+def live_width_index(first, columns: int):
+    """Index into `live_widths(columns)` of the narrowest suffix that starts
+    at or before column `first` (a host integer, or a traced scalar): the
+    count of the widths that start behind it, which are the narrower ones."""
+    return sum((columns - w > first) * 1 for w in live_widths(columns)[:-1])
 
 
 def init_kv_cache(cfg: TransformerConfig, batch_size: int, max_len: int, dtype=None):

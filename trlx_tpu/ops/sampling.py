@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from trlx_tpu.models.transformer import TransformerConfig, init_kv_cache
+from trlx_tpu.models.transformer import (
+    TransformerConfig, init_kv_cache, live_width_index, live_widths, prefill_by_blocks)
 from trlx_tpu.ops.ilql import topk_mask
 from trlx_tpu.ops.quant import dequantize_tree
 
@@ -142,6 +143,60 @@ def topp_mask(logits: jnp.ndarray, p: float) -> jnp.ndarray:
     return jnp.where(logits < threshold, -jnp.inf, logits)
 
 
+#: columns of a prefill block (`BlockPlan`), at the widths the cells run
+PREFILL_BLOCK = 128
+
+
+@dataclass(frozen=True)
+class BlockPlan:
+    """How `generate` follows a chunk's longest prompt inside ONE program of
+    static shape `[rows, plen]` + `max_new`: the shapes, which are static,
+    and the two things read from the chunk's first live column, which is
+    data. The prompt block, left-padded to whole blocks (`pad`), is
+    prefilled `block` columns at a time from the block that holds the first
+    column any row has a token in; a decode step attends over the narrowest
+    of a few suffixes of the cache that still holds that column
+    (`transformer.live_widths`, chosen in `decode_step` from the cache's
+    `first`). `first` is a host integer (the trainer's counters) or a traced
+    scalar (the program), in the caller's columns."""
+
+    block: int
+    pad: int
+    blocks: int
+    columns: int  # the cache's: pad + plen + max_new
+
+    @classmethod
+    def of(cls, plen: int, max_new: int, block: int) -> "BlockPlan":
+        pad = -plen % block
+        return cls(block, pad, (plen + pad) // block, pad + plen + max_new)
+
+    def first_block(self, first):
+        return (first + self.pad) // self.block
+
+    def read_columns(self, first: int) -> int:
+        """The cache columns a decode step reads, of `columns`."""
+        return live_widths(self.columns)[live_width_index(first + self.pad, self.columns)]
+
+
+def block_plan(model_cfg: TransformerConfig, gen_cfg: GenerationConfig, plen: int,
+               block: int = PREFILL_BLOCK, spec_k: int = 0) -> Optional[BlockPlan]:
+    """The plan `make_generate_fn`'s program runs a prompt block of `plen`
+    columns by, or None where it keeps the one-shot prefill: the sampler is
+    the token-at-a-time causal loop (one beam, no speculative rounds), and
+    the model's layers and the width allow it (`prefill_by_blocks`)."""
+    if (block <= 0 or spec_k > 0 or gen_cfg.num_beams > 1 or getattr(model_cfg, "is_seq2seq", False)
+            or not prefill_by_blocks(model_cfg, plen, block)):
+        return None
+    return BlockPlan.of(plen, gen_cfg.max_new_tokens, block)
+
+
+def first_live_column(attn_mask):
+    """The first column any row has a token in (0 where none has): numpy in,
+    a host integer out; a traced mask in, a traced scalar out."""
+    xp = np if isinstance(attn_mask, np.ndarray) else jnp
+    return xp.argmax(attn_mask.astype(bool).any(axis=0)).astype(xp.int32)
+
+
 def make_generate_fn(
     model,
     model_cfg: TransformerConfig,
@@ -154,10 +209,18 @@ def make_generate_fn(
     spec_k: int = 0,  # > 0: self-speculative decode, k drafts per round
     spec_split: int = 0,  # hydra split = draft trunk depth (required when spec_k > 0)
     spec_draft_head: Optional[Tuple] = None,  # (A [d, r], B [r, V]) low-rank readout
+    prefill_block: int = PREFILL_BLOCK,  # 0: always the one-shot prefill
 ) -> Callable:
     """Build a jittable generate(params, input_ids, attn_mask, rng) ->
     dict(samples, response_tokens, response_mask). Shapes are static per
     (batch, prompt_len); jit-cache the returned fn per shape bucket.
+
+    Where `block_plan` gives one (a left-padded prompt of two blocks of
+    `prefill_block` columns or more, layers that all keep columns), the
+    program's WORK follows the chunk's longest prompt while its shapes stay:
+    the blocks in front of the first live column are not prefilled and the
+    decode loop does not read them (`BlockPlan`). Tokens, masks and captured
+    stats are those of the one-shot program.
 
     Covers both architectures: causal (prefill the prompt into the KV
     cache, continue) and seq2seq (encode the prompt once, decode from
@@ -391,6 +454,41 @@ def make_generate_fn(
         final = jax.lax.while_loop(cond, body, state)
         return final[6], final[7], final[9]
 
+    def prefill_blocks(params, input_ids, attn_mask, plan):
+        """The prompt block into a fresh cache, `plan.block` columns a step
+        from the block that holds the chunk's first live column: one traced
+        body, a trip count that is data. -> (what `step_model` gives for the
+        last block, the captured rows over all the cache's columns (zeros
+        where no block ran: only padding queries ever read them)). The cache
+        carries the first live column (`first`), from which every decode step
+        chooses the suffix it reads."""
+        b, block = input_ids.shape[0], plan.block
+        first = first_live_column(attn_mask)
+        start = plan.first_block(first)
+        if plan.pad:
+            input_ids = jnp.pad(input_ids, ((0, 0), (plan.pad, 0)), constant_values=gen_cfg.pad_token_id)
+            attn_mask = jnp.pad(attn_mask, ((0, 0), (plan.pad, 0)))
+        cache = {**init_kv_cache(model_cfg, b, plan.columns), "index": start * block,
+                 "first": first + plan.pad}
+        vocab = jnp.zeros((b, model_cfg.vocab_size), jnp.float32)
+        carry = (
+            cache, vocab, vocab if mode == "ilql" else None,
+            jnp.zeros((b,), jnp.float32) if capture else None,
+            jnp.zeros((b, plan.columns, model_cfg.d_model), model_cfg.dtype) if capture else None,
+        )
+
+        def body(j, carry):
+            cache, hs = carry[0], carry[4]
+            tokens = jax.lax.dynamic_slice_in_dim(input_ids, j * block, block, axis=1)
+            token_mask = jax.lax.dynamic_slice_in_dim(attn_mask, j * block, block, axis=1)
+            logits, adv, value, h_cap, cache = step_model(params, tokens, cache, token_mask, True)
+            if capture:
+                hs = jax.lax.dynamic_update_slice(hs, h_cap.astype(hs.dtype), (0, j * block, 0))
+            return cache, logits, adv, value, hs
+
+        cache, logits, adv, value, hs = jax.lax.fori_loop(start, plan.blocks, body, carry)
+        return (logits, adv, value, cache), hs
+
     def generate(params, input_ids, attn_mask, rng):
         # no-op for dense trees; reconstructs any int8 {q, scale} leaves of
         # the frozen-trunk decode view (method.quantize_frozen_trunk)
@@ -398,10 +496,16 @@ def make_generate_fn(
         params = dequantize_tree(params)
         b, plen = input_ids.shape
         total = plen + max_new
-        cache = init_kv_cache(model_cfg, b, total)
-        last_logits, last_adv, last_value, h_cap, cache = step_model(
-            params, input_ids, cache, attn_mask, True
-        )
+        plan = block_plan(model_cfg, gen_cfg, plen, prefill_block)
+        hs0 = None
+        if plan is not None:
+            (last_logits, last_adv, last_value, cache), hs0 = prefill_blocks(
+                params, input_ids, attn_mask, plan)
+        else:
+            cache = init_kv_cache(model_cfg, b, total)
+            last_logits, last_adv, last_value, h_cap, cache = step_model(
+                params, input_ids, cache, attn_mask, True
+            )
         seen0 = None
         if gen_cfg.repetition_penalty != 1.0:
             # HF semantics: the penalty covers prompt tokens too
@@ -410,14 +514,14 @@ def make_generate_fn(
                 attn_mask.astype(jnp.int32)
             )
             seen0 = counts > 0
-        hs0 = None
-        if capture:
+        if capture and plan is None:
             # split activations over the full [prompt + response] width:
             # prefill fills the prompt rows, the loop writes one row per
             # model step (the final sampled token's row is never written
             # — it is only ever a masked key / padding query downstream)
             hs0 = jnp.zeros((b, total, h_cap.shape[-1]), h_cap.dtype)
             hs0 = jax.lax.dynamic_update_slice(hs0, h_cap, (0, 0, 0))
+
         out_tokens, out_mask, cap = decode_loop(
             rng, cache, last_logits, last_adv, last_value, input_ids[:, -1], params, b,
             input_ids.dtype, seen0, hs0,
@@ -431,7 +535,8 @@ def make_generate_fn(
             "response_mask": out_mask,
         }
         if capture:
-            out["logprobs"], out["values"], out["h_split"] = cap
+            out["logprobs"], out["values"], hs = cap
+            out["h_split"] = hs[:, plan.pad:] if plan is not None and plan.pad else hs
         return out
 
     def generate_seq2seq(params, input_ids, attn_mask, rng):
@@ -796,10 +901,11 @@ def generate(
     spec_k: int = 0,
     spec_split: int = 0,
     spec_draft_head: Optional[Tuple] = None,
+    prefill_block: int = PREFILL_BLOCK,
 ):
     """One-shot convenience wrapper (not cached across shapes)."""
     fn = make_generate_fn(model, model_cfg, gen_cfg, mode, logit_mask, two_qs,
                           capture=capture, capture_split=capture_split,
                           spec_k=spec_k, spec_split=spec_split,
-                          spec_draft_head=spec_draft_head)
+                          spec_draft_head=spec_draft_head, prefill_block=prefill_block)
     return fn(params, jnp.asarray(input_ids), jnp.asarray(attn_mask), rng)

@@ -379,17 +379,16 @@ class TPUTrainer(BaseRLTrainer):
         `prompt_len` (a rollout chunk narrowed to a rung of
         `_prompt_ladder`) gives the outputs back with a prompt block that
         wide, inside the same program."""
-        from trlx_tpu.ops.sampling import GenerationConfig, make_generate_fn
+        from trlx_tpu.ops.sampling import make_generate_fn
 
         # repr-normalize values: gen_kwargs may carry unhashable HF-style
         # knobs (lists/dicts) from configs written against the reference
         widen_to = int(widen_to) if widen_to > prompt_len else 0
+        block = self._prefill_block()
         key = (batch_size, prompt_len, repr(sorted(gen_kwargs.items())), mode, bool(capture),
-               int(spec_k), widen_to)
+               int(spec_k), widen_to, block)
         if key not in self._generate_cache:
-            gen_cfg = GenerationConfig.from_gen_kwargs(
-                gen_kwargs, self.tokenizer.eos_token_id, self.tokenizer.pad_token_id
-            )
+            gen_cfg = self._generation_config(gen_kwargs)
             two_qs = bool(getattr(self.config.method, "two_qs", True))
             fn = make_generate_fn(
                 self.model, self.model_cfg, gen_cfg, mode=mode,
@@ -397,6 +396,7 @@ class TPUTrainer(BaseRLTrainer):
                 capture=capture, capture_split=self.split if capture else 0,
                 spec_k=spec_k, spec_split=self.split if spec_k > 0 else 0,
                 spec_draft_head=self._spec_draft_head() if spec_k > 0 else None,
+                prefill_block=block,
             )
             # each (shape, kwargs) bucket is its own compiled program by
             # design — name it as such so each gets a budget of 1 and a
@@ -1961,6 +1961,29 @@ class TPUTrainer(BaseRLTrainer):
                 f.write(serialization.to_bytes(self.params))
         with open(os.path.join(directory, "trlx_tpu_config.json"), "w") as f:
             json.dump(self.config.to_dict(), f, indent=2, default=str)
+
+    def _generation_config(self, gen_kwargs: Dict):
+        from trlx_tpu.ops.sampling import GenerationConfig
+
+        return GenerationConfig.from_gen_kwargs(
+            gen_kwargs, self.tokenizer.eos_token_id, self.tokenizer.pad_token_id)
+
+    def _prefill_block(self) -> int:
+        """Columns of the sampler's prefill block (`ops.sampling.BlockPlan`);
+        0, the one-shot prefill, where padding is on the right: a batch's
+        padding then lies behind its prompts and no block in front is empty."""
+        from trlx_tpu.ops import sampling
+
+        return sampling.PREFILL_BLOCK if self.config.tokenizer.padding_side == "left" else 0
+
+    def _block_plan(self, prompt_len: int, gen_kwargs: Dict, spec_k: int = 0, **_):
+        """The plan by which `get_generate_fn`'s program of that prompt width
+        follows a batch's longest prompt, or None where it keeps the one-shot
+        prefill: the sampler's own rule (`ops.sampling.block_plan`)."""
+        from trlx_tpu.ops.sampling import block_plan
+
+        return block_plan(self.model_cfg, self._generation_config(gen_kwargs), prompt_len,
+                          self._prefill_block(), spec_k)
 
 
 def _batch_shapes(batch) -> Tuple:

@@ -1482,18 +1482,29 @@ class PPOTrainer(TPUTrainer):
     def add_prompt_pipeline(self, pipeline):
         self.prompt_iterator = self._rollout_stream(pipeline, self.config.method.chunk_size)
 
-    #: whether `generate` narrows a rollout chunk to a rung of
-    #: `_prompt_ladder` (the pipelined trainers' own `generate` does not)
+    #: whether a rollout chunk goes through the base trainer's `generate`,
+    #: which follows its longest prompt (a `BlockPlan`, or a rung of
+    #: `_prompt_ladder`); the pipelined trainers' own `generate` does not
     _narrows_rollout_chunks = True
+
+    def _rollout_plan(self, width: int, gen_kwargs, **generate_kwargs):
+        """The `BlockPlan` a rollout chunk of that prompt width is generated
+        by, or None: the sampler keeps the one-shot prefill."""
+        if not self._narrows_rollout_chunks:
+            return None
+        return self._block_plan(self._bucket_shape(1, width)[1], gen_kwargs, **generate_kwargs)
 
     def _rollout_stream(self, pipeline, rows: int, **loader_kwargs) -> LoaderStream:
         """The rollout loader's chunks of `rows` prompts, forever. A pipeline
         that knows its prompts' lengths has every collection's prompts (a
         window of the shuffled order) sorted by length before they are cut
-        into chunks, and gives the trainer the few prompt widths such
-        chunks are generated at (`prompt_width_ladder`; one width, and the
-        loader of before, where a collection is one chunk). The stream's
-        place is part of the resume state."""
+        into chunks: that is what makes a chunk's longest prompt short.
+        Where the sampler's one program follows the chunk's longest prompt
+        itself (`_rollout_plan`) every chunk is generated at the pool's
+        width; elsewhere the trainer gets the few prompt widths such chunks
+        are generated at (`prompt_width_ladder`; one width, and the loader
+        of before, where a collection is one chunk). The stream's place is
+        part of the resume state."""
         method = self.config.method
         window = rows * -(-int(method.num_rollouts) // max(int(method.chunk_size), 1))
         lengths = getattr(pipeline, "prompt_lengths", None)
@@ -1504,6 +1515,9 @@ class PPOTrainer(TPUTrainer):
         # (seq2seq prompts are the encoder's: its samples hold no prompt block)
         narrows = self._narrows_rollout_chunks and not self.seq2seq and getattr(
             self.config.train, "bucket_generation", True)
+        if narrows and len(ladder) > 1:
+            narrows = self._rollout_plan(ladder[-1], self.generate_experience_kwargs or self.generate_kwargs,
+                                         spec_k=self._spec_k_effective()) is None
         self._prompt_ladder = ladder if len(ladder) > 1 and narrows else None
         # dispatches until the other rungs' programs are made (`_rollout_generate`)
         self._ladder_countdown = 2 if self._prompt_ladder else 0
@@ -1512,23 +1526,40 @@ class PPOTrainer(TPUTrainer):
 
     def _rollout_generate(self, batch, gen_kwargs, **generate_kwargs):
         """`generate` for one rollout chunk. Its prefill is counted first:
-        the rows and width the program runs at, the prompt tokens among
-        those positions, and the rest, padding. A rung's program compiles
+        the rows and width the program runs, the prompt tokens among those
+        positions, and the rest, padding; under a `BlockPlan` the width is
+        that of the blocks run, and the span says how far the mechanism
+        engaged (blocks run of the program's, cache columns a decode step
+        reads of the cache's), from the chunk's mask by the program's own
+        rule. A rung's program compiles
         when a chunk first runs at it, as any program does; once the first
         two chunks are dispatched (generation is double-buffered: the host
         would now wait for the first, and the device has two chunks' work,
         the longest's, to hide a start-up's tracing behind) the other rungs'
         are compiled too, so that no width is first met cycles later."""
+        from trlx_tpu.ops.sampling import first_live_column
+
         input_ids = np.asarray(batch["input_ids"])
         attention_mask = np.asarray(batch["attention_mask"])
-        rows, width = self._bucket_shape(
-            len(input_ids), self._ladder_width(attention_mask) or attention_mask.shape[1])
+        run_width = self._ladder_width(attention_mask) or attention_mask.shape[1]
+        rows, width = self._bucket_shape(len(input_ids), run_width)
+        plan = self._rollout_plan(run_width, gen_kwargs, **generate_kwargs)
+        engaged = {}
+        if plan is not None:
+            # the columns the program gets: a rung's are the last of the
+            # chunk's, and `_bucket_prompts` pads on the left, as the prompts are
+            first = int(first_live_column(attention_mask[:, -run_width:])) + width - run_width
+            blocks_run = plan.blocks - int(plan.first_block(first))
+            width = blocks_run * plan.block
+            engaged = dict(blocks=plan.blocks, blocks_run=blocks_run,
+                           read_columns=plan.read_columns(first),
+                           cache_columns=plan.columns)
         padded, tokens = rows * width, int(attention_mask.sum())
         self._prefill_tally += (1, width, padded, padded - tokens)
         if tracing.active():
             tracing.counters("ppo.prefill", calls=1, rows=rows, width=width,
                              prompt_tokens=tokens, padded_tokens=padded,
-                             pad_tokens=padded - tokens)
+                             pad_tokens=padded - tokens, **engaged)
         out = self.generate(input_ids, attention_mask, gen_kwargs, **generate_kwargs)
         if self._ladder_countdown:
             self._ladder_countdown -= 1

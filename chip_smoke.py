@@ -115,7 +115,7 @@ def build_config(args, workdir: str):
     from trlx_tpu.data.default_configs import default_ppo_config
 
     config = default_ppo_config().evolve(
-        # full GPT-2 vocab + the flash kernels, as bench.py's cell sets them
+        # full GPT-2 vocab + the flash kernels
         model=dict(model_extra_configs=dict(vocab_size=50257, attn_impl="flash")),
         train=dict(total_steps=32, checkpoint_dir=f"{workdir}/ckpts",
                    logging_dir=f"{workdir}/logs"),
@@ -293,9 +293,44 @@ def server_phase(args, trainer):
         server.shutdown()
 
 
-def flash_backward_parity():
+def flash_and_ce_parity(interpret=False):
+    """The Pallas flash forward and fused-CE kernels against their XLA paths,
+    at GPT-2 small's heads over 1,024 tokens and its vocabulary (a few rows
+    and heads of the same kernels where `interpret` runs them off the chip)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from trlx_tpu.ops.attention import _flash_fwd_pallas, blockwise_attention
+    from trlx_tpu.ops.fused_ce import _logprobs_pallas, _logprobs_xla
+
+    key = jax.random.PRNGKey(0)
+    shape = (2, 256, 2, 64) if interpret else (4, 1024, 12, 64)
+    q, k, v = (jax.random.normal(jax.random.fold_in(key, i), shape, jnp.bfloat16)
+               for i in range(3))
+    mask = jnp.ones(shape[:2], jnp.int32).at[:, -100:].set(0)
+    pallas = jax.jit(lambda q, k, v, m: _flash_fwd_pallas(
+        q, k, v, m, True, 128, 128, interpret=interpret))(q, k, v, mask)
+    xla = jax.jit(lambda q, k, v, m: blockwise_attention(q, k, v, m))(q, k, v, mask)
+    flash_dev = float(np.abs(np.asarray(pallas, np.float32) - np.asarray(xla, np.float32)).max())
+
+    n, vocab = (256, 5000) if interpret else (2048, 50257)
+    logits = jax.random.normal(jax.random.fold_in(key, 3), (n, vocab), jnp.bfloat16) * 3
+    labels = jax.random.randint(jax.random.fold_in(key, 4), (n,), 0, vocab)
+    pallas = jax.jit(lambda l, y: _logprobs_pallas(l, y, interpret=interpret)[0])(logits, labels)
+    xla = jax.jit(lambda l, y: _logprobs_xla(l.astype(jnp.float32), y)[0])(logits, labels)
+    ce_dev = float(np.abs(np.asarray(pallas) - np.asarray(xla)).max())
+
+    log(f"kernels: flash max|dev| {flash_dev:.2e} (bound 5e-2), "
+        f"fused CE max|dev| {ce_dev:.2e} (bound 1e-3)")
+    check(flash_dev < 5e-2, f"flash-attention parity {flash_dev} >= 5e-2")
+    check(ce_dev < 1e-3, f"fused-CE parity {ce_dev} >= 1e-3")
+
+
+def flash_backward_parity(interpret=False):
     """The Pallas dq / dk,dv kernels against the XLA scan backward, at one
-    PPO minibatch's shape (32 rows of 64 + 40 tokens, 12 heads x 64)."""
+    PPO minibatch's shape (32 rows of 64 + 40 tokens, 12 heads x 64; two rows
+    of two heads where `interpret` runs them off the chip)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -303,14 +338,14 @@ def flash_backward_parity():
     from trlx_tpu.ops import attention
 
     key = jax.random.PRNGKey(0)
-    shape = (32, 104, 12, 64)
+    shape = (2, 104, 2, 64) if interpret else (32, 104, 12, 64)
     q, k, v, g = (jax.random.normal(jax.random.fold_in(key, i), shape, jnp.bfloat16)
                   for i in range(4))
     mask = jnp.ones(shape[:2], jnp.int32).at[:, -9:].set(0)
     out, lse = jax.jit(lambda q, k, v, m: attention._flash_fwd_pallas_lse(
-        q, k, v, m, True, None, None))(q, k, v, mask)
-    pallas = jax.jit(lambda *a: attention._flash_bwd_pallas(*a, True, None, None))(
-        q, k, v, mask, out, lse, g)
+        q, k, v, m, True, None, None, interpret=interpret))(q, k, v, mask)
+    pallas = jax.jit(lambda *a: attention._flash_bwd_pallas(
+        *a, True, None, None, interpret=interpret))(q, k, v, mask, out, lse, g)
     xla = jax.jit(lambda *a: attention._flash_bwd_xla(*a, True, None))(
         q, k, v, mask, out, lse, g)
     for name, a, b in zip(("dq", "dk", "dv"), pallas, xla):
@@ -383,16 +418,11 @@ def kernel_phase(args, trainer):
 
     check_kernel_paths(args, trainer)
 
-    # bench.py's on-chip bounds: 5e-2 for bf16 flash (the paged kernel's
+    # on-chip bounds: 5e-2 for bf16 flash (the paged kernel's
     # bf16 output shares it: an ulp at |x| ~ 2 is 1.6e-2) and 1e-3 for CE
     paged_tol = 5e-2
-    if not args.rehearse_cpu:
-        from bench import pallas_parity_check
-
-        parity = pallas_parity_check()
-        log(f"kernels: flash max|dev| {parity['flash_max_dev']:.2e} (bound 5e-2), "
-            f"fused CE max|dev| {parity['fused_ce_max_dev']:.2e} (bound 1e-3)")
-        flash_backward_parity()
+    flash_and_ce_parity(interpret=args.rehearse_cpu)
+    flash_backward_parity(interpret=args.rehearse_cpu)
 
     # paged decode vs the gather reference: GPT-2 small's shape (group 1)
     # and the one causal preset family with group > 1, bf16 and int8 arenas.

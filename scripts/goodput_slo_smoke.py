@@ -8,7 +8,7 @@ Two independent checks, both CPU-only and dependency-free:
    wall time within 5%, with jit compile split out, the injected rewind
    attributed to `waste/rewind`, `goodput/*` stats flushed through the
    tracker on every stats step, and a ledger FLOP total that agrees with
-   bench.py's offline per-cycle FLOP model within 10% (i.e. the live MFU
+   the offline per-cycle FLOP model within 10% (i.e. the live MFU
    and the offline MFU agree over the same window).
 
 2. **Fleet SLO engine** — a supervised 2-replica fleet where one replica
@@ -139,7 +139,7 @@ def check_goodput(artifact_dir: str) -> str:
     )
     assert snap["wasted_s"] > 0.0 and snap["goodput_fraction"] < 1.0
 
-    # live FLOP accounting agrees with bench.py's offline per-cycle
+    # live FLOP accounting agrees with the offline per-cycle
     # model: the ledger priced every noted sample/row with
     # flops_per_sample; the offline model prices whole cycles. Same
     # config => totals must agree (within 10%, covering the partial

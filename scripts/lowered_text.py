@@ -21,7 +21,7 @@ a door in front of it; where the schedule trains from the trunk cache, the
 resumed train step is taken beside the whole-forward step, and the fill
 where the schedule runs one: not where the score program hands the state
 out);
-the serve cells' engines as `tests/test_kernels_compile_tpu.py` builds them.
+the serve cells' engines as `tests/test_serve_cells_compile_tpu.py` builds them.
 """
 
 import argparse

@@ -15,11 +15,40 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
 
-from bench import build_trainer  # noqa: E402
+VOCAB, EOS, N_PROMPT = 1024, 258, 16
+
+
+def build_trainer():
+    from trlx_tpu.data.default_configs import default_ppo_config
+    from trlx_tpu.pipeline.offline_pipeline import PromptPipeline
+    from trlx_tpu.trainer.ppo_trainer import PPOTrainer
+
+    # Random weights emit arbitrary ids; the speculative rollout scorer
+    # needs the decode->encode round trip to be the identity, as it is for
+    # a trained model's text, so sampling is held to printable ASCII + eos.
+    allowed = set(range(32, 127)) | {EOS}
+    config = default_ppo_config().evolve(
+        # num_layers_unfrozen 1: gpt2-tiny has two blocks, and a 2-of-2
+        # split leaves no frozen trunk to draft from
+        model=dict(model_path="random:gpt2-tiny", num_layers_unfrozen=1,
+                   model_extra_configs=dict(vocab_size=VOCAB, attn_impl="flash")),
+        train=dict(seq_length=128, batch_size=8, tracker=None,
+                   fuse_inner_epoch=True, fuse_all_inner_epochs=True),
+        method=dict(num_rollouts=16, chunk_size=16, speculative_decode=True, quantize_frozen_trunk=True,
+                    gen_kwargs=dict(max_new_tokens=8, top_k=0, top_p=1.0, do_sample=True,
+                                    suppress_tokens=[i for i in range(VOCAB) if i not in allowed])),
+    )
+    trainer = PPOTrainer(
+        config, reward_fn=lambda samples, prompts, outputs, **kw: [float(o.count("e") - o.count("z"))
+                                                                   for o in outputs])
+    rng = np.random.default_rng(0)
+    prompts = ["".join(chr(c) for c in rng.integers(97, 123, size=N_PROMPT)) for _ in range(256)]
+    trainer.add_prompt_pipeline(PromptPipeline(prompts, max_prompt_length=N_PROMPT, tokenizer=trainer.tokenizer))
+    return trainer
 
 
 def main():
-    trainer, config = build_trainer(smoke=True, spec_decode=True, int8=True)
+    trainer = build_trainer()
     _, pending = trainer.pipelined_cycle()
     _, pending = trainer.pipelined_cycle(pending)
     loss = float(np.asarray(pending[2][0]))
@@ -27,7 +56,7 @@ def main():
     rounds = int(getattr(trainer, "spec_decode_rounds", 0))
     accepted = int(getattr(trainer, "spec_decode_accepted", 0))
     fallbacks = int(getattr(trainer, "spec_decode_fallbacks", 0))
-    k = int(config.method.spec_k)
+    k = int(trainer.config.method.spec_k)
 
     assert np.isfinite(loss), f"non-finite loss after 2 spec-decode cycles: {loss}"
     assert fallbacks == 0, (

@@ -77,11 +77,12 @@ def test_next_step_is_dispatched_before_the_fetch(trainer, monkeypatch):
     p, q = prompt(0, 9), prompt(1, 40)
     want = direct_generate(trainer, p.tolist(), 4)[0]
     want_q = direct_generate(trainer, q.tolist(), 4)[0]
-    events, step_of = [], {}
+    events, step_of, kept = [], {}, []
     decode, get = engine._decode_fn, jax.device_get
 
     def logged_decode(*args):
         out = decode(*args)
+        kept.append(out[1])  # alive to the end: a freed array's id may name the next one
         step_of[id(out[1])] = len(step_of) + 1
         events.append(("dispatch", step_of[id(out[1])]))
         return out

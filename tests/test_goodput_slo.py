@@ -12,14 +12,11 @@ scripts/goodput_slo_smoke.py, gated in tier-1):
   and budget exhaustion firing EXACTLY one postmortem bundle;
 - metrics.py satellites: label-value escaping, +Inf/_sum/_count on
   labeled histograms, OpenMetrics exemplar rendering, and HELP/TYPE
-  dedup at registry-concatenation points;
-- scripts/bench_gate.py compare()/extract_metrics() logic (no
-  subprocess — the CI behavior is the smoke gate's job).
+  dedup at registry-concatenation points.
 
 Everything here is host-side and jax-free.
 """
 
-import importlib.util
 import json
 import os
 import time
@@ -35,16 +32,6 @@ from trlx_tpu.observability import FlightRecorder, postmortem
 from trlx_tpu.observability.flops import flops_per_sample
 from trlx_tpu.observability.goodput import WASTE_CAUSES, GoodputLedger
 from trlx_tpu.observability.slo import SLO, SLOEngine, default_slos
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_bench_gate():
-    spec = importlib.util.spec_from_file_location(
-        "bench_gate", os.path.join(REPO, "scripts", "bench_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 class _TinyCfg:
@@ -428,60 +415,3 @@ def test_dedupe_metadata_on_concatenated_registries():
     assert text.count(f"{NAMESPACE}_requests_total 1.0") == 2
     assert f"{NAMESPACE}_slots_total 1.0" in text
     assert f"{NAMESPACE}_slots_total 2.0" in text
-
-
-# ----------------------------------------------------------------------
-# bench_gate compare()/extract_metrics()
-# ----------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def bench_gate():
-    return _load_bench_gate()
-
-
-def test_extract_metrics_scans_backwards_past_noise(bench_gate):
-    stdout = "\n".join([
-        "some warmup chatter",
-        '{"metric": "stale", "value": 1.0}',
-        json.dumps({"metric": "ppo_samples_per_sec_per_chip",
-                    "value": 200.0, "tokens_per_sec_per_chip": 5000.0,
-                    "mfu_estimate": 0.25}),
-        "",
-    ])
-    out = bench_gate.extract_metrics(stdout)
-    assert out == {"ppo_samples_per_sec_per_chip": 200.0,
-                   "tokens_per_sec_per_chip": 5000.0,
-                   "mfu_estimate": 0.25}
-    with pytest.raises(ValueError):
-        bench_gate.extract_metrics("no json here\nat all")
-    with pytest.raises(ValueError):
-        bench_gate.extract_metrics('{"unrelated": 1}')
-
-
-def test_compare_flags_regressions_and_skips_noise_floor(bench_gate):
-    baseline = {"metrics": {
-        "ppo_samples_per_sec_per_chip": {"value": 200.0,
-                                         "max_regression": 0.5},
-        "tokens_per_sec_per_chip": {"value": 5000.0, "max_regression": 0.5},
-        # below MIN_MEANINGFUL_BASELINE: never gated (rounding noise)
-        "mfu_estimate": {"value": 0.0001, "max_regression": 0.5},
-    }}
-    current = {"ppo_samples_per_sec_per_chip": 80.0,  # 40% < allowed 50%
-               "tokens_per_sec_per_chip": 4000.0,  # 80%: fine
-               "mfu_estimate": 0.0}  # would be ratio 0 but skipped
-    failures = bench_gate.compare(baseline, current)
-    assert [f["metric"] for f in failures] == ["ppo_samples_per_sec_per_chip"]
-    f = failures[0]
-    assert f["ratio"] == pytest.approx(0.4)
-    assert f["allowed_min_ratio"] == pytest.approx(0.5)
-    # healthy run passes clean
-    assert bench_gate.compare(baseline, {
-        "ppo_samples_per_sec_per_chip": 210.0,
-        "tokens_per_sec_per_chip": 5100.0,
-        "mfu_estimate": 0.0001,
-    }) == []
-    # a metric missing from either side is skipped, not failed
-    assert bench_gate.compare(baseline,
-                              {"tokens_per_sec_per_chip": 4900.0}) == []
-    assert bench_gate.compare({"metrics": {}}, current) == []

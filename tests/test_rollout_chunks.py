@@ -1,22 +1,25 @@
-"""Rollout chunks grouped by prompt length, generated at a rung of a width
-ladder (PromptPipeline.create_loader(group_window=), prompt_width_ladder,
-TPUTrainer._ladder_width, PPOTrainer._rollout_generate).
+"""Rollout chunks: a collection's prompts sorted by length by the loader
+(PromptPipeline.create_loader(group_window=)), every chunk generated at the
+pool's width (PPOTrainer._rollout_generate, TPUTrainer.generate), and the
+sampler's own rule for whether its one program then follows the chunk's
+longest prompt (ops.sampling.block_plan).
 
 Pinned here:
 - the grouped loader hands out, window by window, the prompts the
   ungrouped loader would have, longest first, the same on two loaders of
   one seed, across an epoch's end, and after a save and a restore of the
   stream's place;
-- the ladder's rule: few rungs, powers of two of 32 under the pool's
-  longest prompt, one rung where a window is one chunk or the pool has one
-  length (those recipes keep the parent's batches and its one program);
-- a chunk generated at its rung gives the tokens and logprobs of the
-  full-width call, and comes back at the caller's width, on either padding
-  side and with the captured activations;
-- every rung is compiled once the first two chunks are dispatched, whether
-  a chunk ran at it or not: collections over a heavy-tailed pool add no
-  `generate` compile afterwards;
-- the counter in front of every rollout dispatch adds up.
+- a model without a block plan (right padding, a pool under two blocks,
+  beams) runs every chunk through ONE program at the pool's width, and
+  `_rollout_generate` adds a counter to `generate` and nothing else;
+- `generate` runs a batch at the width it was given: nothing recognises a
+  rollout chunk by its shape;
+- a model with a plan runs four chunks through one program, counts the
+  blocks it runs, and gives the tokens, logprobs and activations of the
+  one-shot program whichever block the chunk's longest prompt starts in;
+- collections add no `generate` compile after the first, and the counter
+  in front of every rollout dispatch adds up;
+- GRPO's groups stay together through the sorted loader and the block form.
 """
 
 import math
@@ -29,8 +32,8 @@ import pytest
 from trlx_tpu.data.configs import TokenizerConfig
 from trlx_tpu.models.transformer import live_widths
 from trlx_tpu.data.default_configs import default_grpo_config, default_ppo_config
-from trlx_tpu.pipeline import LoaderStream, offline_pipeline
-from trlx_tpu.pipeline.offline_pipeline import PromptPipeline, prompt_width_ladder
+from trlx_tpu.pipeline import LoaderStream
+from trlx_tpu.pipeline.offline_pipeline import PromptPipeline
 from trlx_tpu.tokenizers import get_tokenizer
 from trlx_tpu.trainer import ppo_trainer
 from trlx_tpu.trainer.ppo_trainer import PPOTrainer
@@ -38,6 +41,9 @@ from trlx_tpu.trainer.ppo_trainer import PPOTrainer
 ROWS, CHUNKS = 4, 4
 WINDOW = ROWS * CHUNKS
 MAX_NEW = 4
+# the block tests' `PREFILL_BLOCK`: the pool's width, 100, runs in `generate`'s
+# bucket of 128, four blocks of 32 (one block, and so no plan, at the sampler's 128)
+BLOCK = 32
 
 
 def _lognormal_lengths(n, median, sigma, lo, hi):
@@ -115,42 +121,6 @@ def test_stream_gives_the_same_chunks_after_a_restore(pipeline):
 
 
 # ---------------------------------------------------------------------------
-# the ladder's rule
-# ---------------------------------------------------------------------------
-
-HH = _lognormal_lengths(256, 192, 0.8, 16, 896)  # bench/traffic/ppo-hh.json
-
-
-@pytest.mark.parametrize("lengths, window, rows, want", [
-    (HH, 64, 16, (256, 896)),            # pythia-1.4b.ppo-hh
-    (HH, 64, 64, (896,)),                # lfm2-8b-a1b.ppo-hh: one chunk a window
-    ([64] * 128, 128, 128, (64,)),       # gpt2-xl.ppo-sentiments: one chunk, one length
-    ([64] * 128, 128, 16, (64,)),        # a pool of one length has one width
-    (HH, 64, 32, (256, 896)),            # never more rungs than chunks
-    (LENGTHS, WINDOW, ROWS, (32, 100)),
-    ([], 8, 4, ()),
-])
-def test_ladder_rule(lengths, window, rows, want):
-    ladder = prompt_width_ladder(lengths, window, rows)
-    assert ladder == want
-    assert len(ladder) <= max(-(-window // rows), 1) and len(ladder) <= 2
-    assert all(w % 32 == 0 for w in ladder[:-1])
-    assert not lengths or ladder[-1] == max(lengths)
-
-
-@pytest.mark.parametrize("max_widths, miss, rows, want", [
-    (2, 0.01, 16, (256, 896)),             # 512 goes: its one chunk loses 384 columns, 256's two 512
-    (3, 0.01, 16, (256, 512, 896)),        # the chunks' own rungs: 256, 256, 512, 896
-    (8, 0.01, 8, (128, 256, 512, 896)),
-    (4, 0.1, 16, (128, 256, 512, 896)),    # 128 holds the first chunk in 91% of windows
-])
-def test_ladder_keeps_the_rungs_that_save_the_most_columns(monkeypatch, max_widths, miss, rows, want):
-    monkeypatch.setattr(offline_pipeline, "LADDER_MAX_WIDTHS", max_widths)
-    monkeypatch.setattr(offline_pipeline, "LADDER_MISS", miss)
-    assert prompt_width_ladder(HH, 64, rows) == want
-
-
-# ---------------------------------------------------------------------------
 # the trainer
 # ---------------------------------------------------------------------------
 
@@ -192,41 +162,71 @@ def _left_padded(lengths, width, pad_id, seed=1):
     return ids, mask
 
 
-@pytest.mark.parametrize("side", ["left", "right"])
-def test_narrowed_generate_matches_the_full_width_call(trainer, monkeypatch, side):
-    _with_pool(trainer, POOL)
-    assert trainer._prompt_ladder == (32, 100)
-    ids, mask = _left_padded([5, 30, 17, 23, 8, 2, 29, 11], 100, trainer.tokenizer.pad_token_id)
-    if side == "right":
-        ids, mask = ids[:, ::-1].copy(), mask[:, ::-1].copy()
+def _spans_of(monkeypatch):
+    """The `trlx:` counters the trainer writes while a session is active."""
+    spans = []
+    monkeypatch.setattr(ppo_trainer.tracing, "active", lambda: True)
+    monkeypatch.setattr(ppo_trainer.tracing, "counters",
+                        lambda name, **values: spans.append((name, values)))
+    return spans
+
+
+@pytest.mark.parametrize("reason, new_tokens", [
+    ("right_padding", MAX_NEW + 5), ("pool_under_two_blocks", MAX_NEW + 6), ("two_beams", MAX_NEW + 7)])
+def test_a_model_without_a_block_plan_runs_every_chunk_at_the_pools_width(
+        trainer, monkeypatch, reason, new_tokens):
+    from trlx_tpu.ops import sampling
+
+    # (greedy, so a second call gives the same tokens; a token budget a
+    # case: neither the side nor the block is in a program's name)
+    gen_kwargs = dict(max_new_tokens=new_tokens, do_sample=False)
+    if reason == "right_padding":  # no block in front of the prompts is empty
+        monkeypatch.setattr(sampling, "PREFILL_BLOCK", BLOCK)
         monkeypatch.setattr(trainer.config.tokenizer, "padding_side", "right")
-    assert trainer._ladder_width(mask) == 32
-    # (another token budget a side: the side is not in a program's key)
-    greedy = dict(max_new_tokens=MAX_NEW + (side == "right"), do_sample=False)
-    narrow = jax.device_get(trainer.generate(ids, mask, greedy, capture=True))
-    monkeypatch.setattr(trainer, "_prompt_ladder", None)
-    full = jax.device_get(trainer.generate(ids, mask, greedy, capture=True))
-    new = greedy["max_new_tokens"]
-    if side == "right":
-        # the full-width call keeps `_bucket_prompts`' 28 columns between a
-        # right-padded prompt block and the response (it trims left padding only)
-        full = {k: np.delete(v, np.s_[100:128], axis=1) if k in ("samples", "samples_mask", "h_split")
-                else v for k, v in full.items()}
-    assert narrow["samples"].shape == full["samples"].shape == (8, 100 + new)
-    assert narrow["h_split"].shape == full["h_split"].shape
-    np.testing.assert_array_equal(narrow["samples"][:, :100], ids)
-    np.testing.assert_array_equal(narrow["samples_mask"], full["samples_mask"])
-    np.testing.assert_array_equal(narrow["samples"], full["samples"])
-    np.testing.assert_allclose(narrow["logprobs"], full["logprobs"], atol=2e-5)
-    np.testing.assert_allclose(narrow["values"], full["values"], atol=2e-5)
-    # activations of the columns that hold a token (the full-width call
-    # computes something at padding columns, the narrowed one holds zeros)
-    live = full["samples_mask"].astype(bool)[:, :-1]
-    np.testing.assert_allclose(narrow["h_split"][:, :-1][live], full["h_split"][:, :-1][live],
-                               atol=2e-5)
-    names = [n for n in _generate_programs(trainer) if ",cap" in n]
-    assert any(n.startswith("generate[b8,p32,out100,lm,cap") for n in names), names
-    assert any(n.startswith("generate[b8,p128,lm,cap") for n in names), names
+        monkeypatch.setattr(trainer.tokenizer, "padding_side", "right")
+    elif reason == "two_beams":  # not the token-at-a-time loop
+        monkeypatch.setattr(sampling, "PREFILL_BLOCK", BLOCK)
+        gen_kwargs["num_beams"] = 2
+    else:  # the pool's 100 columns run in a bucket of 128: one block of PREFILL_BLOCK
+        assert sampling.PREFILL_BLOCK == 128
+    _with_pool(trainer, POOL)
+    assert trainer._rollout_plan(100, gen_kwargs) is None
+    spans = _spans_of(monkeypatch)
+    before, longest = dict(_generate_programs(trainer)), []
+    for _ in range(CHUNKS):
+        batch = next(trainer.prompt_iterator)
+        longest.append(int(batch["attention_mask"].sum(axis=1).max()))
+        out = jax.device_get(trainer._rollout_generate(batch, gen_kwargs))
+        again = jax.device_get(trainer.generate(batch["input_ids"], batch["attention_mask"], gen_kwargs))
+        # (`_unbucket_output` trims the bucket's 28 columns of padding on the left only)
+        assert out["samples"].shape == (ROWS, (128 if reason == "right_padding" else 100) + new_tokens)
+        assert sorted(out) == sorted(again)
+        for name in out:
+            np.testing.assert_array_equal(out[name], again[name])
+    assert len(set(longest)) == CHUNKS and longest[-1] <= 32, longest  # short chunks too
+    after = _generate_programs(trainer)
+    new = {name: n for name, n in after.items() if name not in before}
+    assert list(new.values()) == [1] and all(n.startswith("generate[b8,p128,lm,kw") for n in new), new
+    assert {name: after[name] for name in before} == before
+    assert [name for name, _ in spans] == ["ppo.prefill"] * CHUNKS
+    for _, v in spans:
+        assert list(v) == ["calls", "rows", "width", "prompt_tokens", "padded_tokens", "pad_tokens"]
+        assert (v["rows"], v["width"], v["padded_tokens"]) == (8, 128, 8 * 128)
+
+
+@pytest.mark.parametrize("width, bucket", [(100, 128), (40, 64)])
+def test_generate_runs_a_batch_at_the_width_it_was_given(trainer, width, bucket):
+    """An evaluation batch as wide as the rollout pool, and a narrower one,
+    of prompts far shorter than either: nothing narrows them (ROADMAP D17)."""
+    _with_pool(trainer, POOL)
+    ids, mask = _left_padded([5, 30, 17, 23, 8, 2, 29, 11], width, trainer.tokenizer.pad_token_id)
+    gen_kwargs = dict(max_new_tokens=MAX_NEW + 8, do_sample=False)  # a budget no other test's program has
+    before = set(_generate_programs(trainer))
+    out = jax.device_get(trainer.generate(ids, mask, gen_kwargs))
+    assert out["samples"].shape == (8, width + MAX_NEW + 8)
+    np.testing.assert_array_equal(out["samples"][:, :width], ids)
+    new = set(_generate_programs(trainer)) - before
+    assert len(new) == 1 and all(n.startswith(f"generate[b8,p{bucket},lm,kw") for n in new), new
 
 
 def test_collections_add_no_generate_compile_after_the_first(trainer, monkeypatch):
@@ -237,33 +237,23 @@ def test_collections_add_no_generate_compile_after_the_first(trainer, monkeypatc
         if event.endswith("backend_compile_duration") else None)
     logged = []
     monkeypatch.setattr(trainer.tracker, "log", lambda stats, step=None: logged.append(stats))
-    # first chunks that all fit the narrowest rung: the other rung's program
-    # is there once two of them are dispatched
-    gen_kwargs = trainer.generate_experience_kwargs or trainer.generate_kwargs
-    short = dict(zip(("input_ids", "attention_mask"),
-                     _left_padded([5, 9, 17, 30], 100, trainer.tokenizer.pad_token_id)))
-    for left in (1, 0, 0):
-        trainer._rollout_generate(short, gen_kwargs)
-        assert trainer._ladder_countdown == left
-    prepared = _generate_programs(trainer)
-    rungs = [n for n in prepared if n.split(",kw")[0] in (
-        "generate[b8,p32,out100,lm", "generate[b8,p128,lm")]
-    assert len(rungs) == 2 == len(trainer._prompt_ladder) <= CHUNKS, prepared
-    del backend[:]
-    widths = set()
     for cycle in range(3):
         trainer.store.clear_history()
         trainer.make_experience(WINDOW, cycle)
         assert len(trainer.store) == WINDOW
-        widths.add(logged[-1]["rollout/prefill_width"])
+        if cycle == 0:
+            # the one program every chunk runs through, whatever its longest prompt
+            programs = _generate_programs(trainer)
+            rollouts = [n for n in programs if n.startswith("generate[b8,p128,lm,kw")]
+            assert rollouts and all(programs[n] == 1 for n in rollouts), programs
+            del backend[:]
         calls, width_sum, padded, pad = (int(x) for x in trainer._prefill_tally)
-        assert calls == CHUNKS and logged[-1]["rollout/prefill_width"] == width_sum / CHUNKS
+        # four chunks at the pool's width in its 32-column bucket (no plan: one block of 128)
+        assert (calls, width_sum, padded) == (CHUNKS, CHUNKS * 128, CHUNKS * 8 * 128)
+        assert logged[-1]["rollout/prefill_width"] == 128
         assert logged[-1]["rollout/prefill_padding_share"] == pad / padded
-        assert padded < CHUNKS * 8 * 128  # fewer positions than four chunks at the pool's width
-    assert _generate_programs(trainer) == prepared, "a collection compiled a generate program"
+    assert _generate_programs(trainer) == programs, "a collection compiled a generate program"
     assert not [name for name in backend if "generate" in str(name)], backend
-    # the chunks did run at several widths (a mean of 128 would be the pool's width)
-    assert max(widths) < 128
 
 
 @pytest.mark.parametrize("prompts, num_rollouts", [
@@ -273,7 +263,6 @@ def test_collections_add_no_generate_compile_after_the_first(trainer, monkeypatc
 def test_one_chunk_recipe_and_fixed_pool_keep_the_parents_batches_and_program(
         trainer, prompts, num_rollouts):
     _with_pool(trainer, prompts, num_rollouts)
-    assert trainer._prompt_ladder is None
     parent = LoaderStream(PromptPipeline(prompts, max_prompt_length=100, tokenizer=trainer.tokenizer)
                           .create_loader(ROWS, shuffle=True))
     gen_kwargs = trainer.generate_kwargs
@@ -291,26 +280,25 @@ def test_one_chunk_recipe_and_fixed_pool_keep_the_parents_batches_and_program(
 
 def test_prefill_counter_adds_up(trainer, monkeypatch):
     _with_pool(trainer, POOL)
-    spans = []
-    monkeypatch.setattr(ppo_trainer.tracing, "active", lambda: True)
-    monkeypatch.setattr(ppo_trainer.tracing, "counters",
-                        lambda name, **values: spans.append((name, values)))
+    spans = _spans_of(monkeypatch)
     gen_kwargs = trainer.generate_experience_kwargs or trainer.generate_kwargs
-    tokens = 0
+    tokens, longest = 0, []
     for _ in range(CHUNKS):
         batch = next(trainer.prompt_iterator)
         tokens += int(batch["attention_mask"].sum())
+        longest.append(int(batch["attention_mask"].sum(axis=1).max()))
         out = trainer._rollout_generate(batch, gen_kwargs)
         assert out["samples"].shape == (ROWS, 100 + MAX_NEW)
     assert [name for name, _ in spans] == ["ppo.prefill"] * CHUNKS
     for _, v in spans:
         assert list(v) == ["calls", "rows", "width", "prompt_tokens", "padded_tokens", "pad_tokens"]
         assert v["calls"] == 1 and v["rows"] == 8  # 4 prompts in `generate`'s row bucket of 8
-        assert v["width"] in (32, 128)  # the rungs, the last in its 32-column bucket
+        assert v["width"] == 128  # the pool's width in its 32-column bucket, whatever the chunk holds
         assert v["padded_tokens"] == v["rows"] * v["width"]
         assert v["pad_tokens"] == v["padded_tokens"] - v["prompt_tokens"]
     assert sum(v["prompt_tokens"] for _, v in spans) == tokens
-    assert [v["width"] for _, v in spans] == sorted((v["width"] for _, v in spans), reverse=True)
+    # the chunks themselves still come longest first
+    assert longest == sorted(longest, reverse=True) and len(set(longest)) == CHUNKS
 
 
 def test_trainer_resumes_the_stream_where_it_was(trainer):
@@ -324,7 +312,8 @@ def test_trainer_resumes_the_stream_where_it_was(trainer):
     assert _take(trainer.prompt_iterator, 9) == want
 
 
-def test_grpo_groups_stay_together_in_sorted_chunks():
+@pytest.fixture(scope="module")
+def grpo():
     from trlx_tpu.trainer.grpo_trainer import GRPOTrainer
 
     config = default_grpo_config().evolve(
@@ -334,22 +323,45 @@ def test_grpo_groups_stay_together_in_sorted_chunks():
         method=dict(num_rollouts=16, chunk_size=4, ppo_epochs=1, group_size=2,
                     gen_kwargs=dict(max_new_tokens=MAX_NEW, do_sample=True)),
     )
-    grpo = GRPOTrainer(config, reward_fn=lambda samples, **kw: [0.0] * len(samples),
+    return GRPOTrainer(config, reward_fn=lambda samples, **kw: [0.0] * len(samples),
                        devices=jax.devices()[:1])
+
+
+def test_grpo_groups_stay_together_in_sorted_chunks(grpo):
     grpo.add_prompt_pipeline(PromptPipeline(POOL, max_prompt_length=100, tokenizer=grpo.tokenizer))
     # a collection is 8 prompts x 2 completions in 4 chunks of 2 prompts
     window = [p for chunk in _take(grpo.prompt_iterator, 4) for p in chunk]
     assert len(window) == 16 and window[0::2] == window[1::2]
     assert [len(p) for p in window] == sorted((len(p) for p in window), reverse=True)
-    assert grpo._prompt_ladder is not None and grpo._prompt_ladder[-1] == 100
+    assert grpo._rollout_plan(100, grpo.generate_kwargs) is None  # one block of 128
+
+
+def test_grpo_chunk_goes_through_the_block_form(grpo, monkeypatch):
+    from trlx_tpu.ops import sampling
+
+    monkeypatch.setattr(sampling, "PREFILL_BLOCK", BLOCK)
+    grpo.add_prompt_pipeline(PromptPipeline(POOL, max_prompt_length=100, tokenizer=grpo.tokenizer))
+    spans = _spans_of(monkeypatch)
+    gen_kwargs = dict(max_new_tokens=MAX_NEW + 1, do_sample=True)  # a budget a block: see below
+    for _ in range(4):
+        batch = next(grpo.prompt_iterator)
+        lengths = batch["attention_mask"].sum(axis=1)
+        out = jax.device_get(grpo._rollout_generate(batch, gen_kwargs))
+        # 2 prompts x 2 completions, a prompt's side by side, each from its own draw
+        np.testing.assert_array_equal(batch["input_ids"][0::2], batch["input_ids"][1::2])
+        np.testing.assert_array_equal(out["samples"][:, :100], batch["input_ids"])
+        assert out["samples"].shape == (4, 100 + MAX_NEW + 1)
+        _, v = spans[-1]
+        assert (v["rows"], v["blocks"]) == (8, 4)
+        assert v["blocks_run"] == -(-int(lengths.max()) // BLOCK)  # from the group's longest prompt
+        assert v["width"] == v["blocks_run"] * BLOCK and v["prompt_tokens"] == int(lengths.sum())
+    assert [v["blocks_run"] for _, v in spans] == sorted((v["blocks_run"] for _, v in spans), reverse=True)
+    assert len({v["blocks_run"] for _, v in spans}) > 1
 
 
 # ---------------------------------------------------------------------------
 # a sampler whose one program follows the chunk's longest prompt (BlockPlan)
 # ---------------------------------------------------------------------------
-
-BLOCK = 32  # the pool's width, 100, runs in `generate`'s bucket of 128: four blocks
-
 
 @pytest.fixture
 def block_form(trainer, monkeypatch):
@@ -359,20 +371,15 @@ def block_form(trainer, monkeypatch):
     return _with_pool(trainer, POOL)
 
 
-def test_block_form_model_gets_no_ladder(block_form, monkeypatch):
+def test_block_form_plan_is_the_samplers_own_rule(block_form, monkeypatch):
     trainer = block_form
-    assert trainer._prompt_ladder is None and trainer._ladder_countdown == 0
     plan = trainer._rollout_plan(100, trainer.generate_kwargs)
     assert (plan.block, plan.pad, plan.blocks, plan.columns) == (BLOCK, 0, 4, 128 + MAX_NEW)
     # the loader still sorts a collection's prompts: that is what makes a chunk's longest short
     window = [p for chunk in _take(trainer.prompt_iterator, CHUNKS) for p in chunk]
     assert [len(p) for p in window] == sorted((len(p) for p in window), reverse=True)
-    # who keeps the ladder: right padding (no block in front is empty), speculative
-    # rounds, a pipelined trainer's own `generate`
-    monkeypatch.setattr(trainer.config.tokenizer, "padding_side", "right")
-    assert _with_pool(trainer, POOL)._prompt_ladder == (32, 100)
-    monkeypatch.setattr(trainer.config.tokenizer, "padding_side", "left")
-    assert _with_pool(trainer, POOL)._prompt_ladder is None
+    # who has no plan: speculative rounds, a pipelined trainer's own `generate`
+    # (right padding, one block and beams: the test of the pool's width above)
     assert trainer._rollout_plan(100, trainer.generate_kwargs, spec_k=2) is None
     monkeypatch.setattr(trainer, "_narrows_rollout_chunks", False)
     assert trainer._rollout_plan(100, trainer.generate_kwargs) is None
@@ -380,10 +387,7 @@ def test_block_form_model_gets_no_ladder(block_form, monkeypatch):
 
 def test_block_form_runs_four_chunks_through_one_program_and_counts_its_blocks(block_form, monkeypatch):
     trainer = block_form
-    spans = []
-    monkeypatch.setattr(ppo_trainer.tracing, "active", lambda: True)
-    monkeypatch.setattr(ppo_trainer.tracing, "counters",
-                        lambda name, **values: spans.append((name, values)))
+    spans = _spans_of(monkeypatch)
     # (a token budget of its own: the block is not in a program's name)
     new_tokens = MAX_NEW + 4
     gen_kwargs = dict(trainer.generate_experience_kwargs or trainer.generate_kwargs, max_new_tokens=new_tokens)
@@ -418,25 +422,53 @@ def test_block_form_runs_four_chunks_through_one_program_and_counts_its_blocks(b
     assert padded == sum(v["padded_tokens"] for _, v in spans) < CHUNKS * 8 * 128
 
 
-def test_block_form_chunk_matches_the_one_shot_program(block_form, monkeypatch):
-    """Through the trainer's own door, with the captured stats: the chunk
-    generated by blocks against the same chunk through the one-shot program."""
+# the chunk's longest prompt by the block of 4 it starts in (the bucket's 128 columns
+# hold the pool's 100 behind 28 of `_bucket_prompts`' padding)
+LONGEST_BY_FIRST_BLOCK = {0: 100, 1: 90, 2: 50, 3: 30}
+
+
+@pytest.mark.parametrize("capture", [True, False], ids=["capture", "plain"])
+@pytest.mark.parametrize("first_block", sorted(LONGEST_BY_FIRST_BLOCK))
+def test_block_form_chunk_matches_the_one_shot_program(block_form, monkeypatch, first_block, capture):
+    """Through the trainer's own door, with and without the captured stats:
+    the chunk generated by blocks against the same chunk through the
+    one-shot program, whichever block its longest prompt starts in. (The two
+    programs of a `capture` value are made by the first of its cases to run,
+    whichever that is, and read from the trainer's cache by the others.)"""
     from trlx_tpu.ops import sampling
 
     trainer = block_form
-    ids, mask = _left_padded([5, 30, 17, 23, 8, 2, 29, 11], 100, trainer.tokenizer.pad_token_id)
+    longest = LONGEST_BY_FIRST_BLOCK[first_block]
+    ids, mask = _left_padded([5, longest, 17, 23, 8, 2, 29, 11], 100, trainer.tokenizer.pad_token_id,
+                             seed=first_block)
     # greedy, so one more token of budget changes none of the tokens before
     # it (a budget a program: the block is not in a program's name)
     n = MAX_NEW + 2
-    blocks = jax.device_get(trainer.generate(ids, mask, dict(max_new_tokens=n, do_sample=False), capture=True))
+    plan = trainer._rollout_plan(100, dict(max_new_tokens=n, do_sample=False))
+    assert int(plan.first_block(int(sampling.first_live_column(mask)) + 28)) == first_block
+    before = set(_generate_programs(trainer))
+    blocks = jax.device_get(trainer.generate(ids, mask, dict(max_new_tokens=n, do_sample=False),
+                                             capture=capture))
     monkeypatch.setattr(sampling, "PREFILL_BLOCK", 0)
     whole = jax.device_get(trainer.generate(ids, mask, dict(max_new_tokens=n + 1, do_sample=False),
-                                            capture=True))
-    assert blocks["samples"].shape == (8, 100 + n) and blocks["h_split"].shape[:2] == (8, 100 + n)
+                                            capture=capture))
+    # the pair is made by whichever case of this `capture` value runs first
+    # and read from the trainer's cache by the rest: none is made twice
+    programs = _generate_programs(trainer)
+    new = set(programs) - before
+    stem = "generate[b8,p128,lm," + ("cap," if capture else "") + "kw"
+    assert len(new) <= 2 and all(name.startswith(stem) for name in new), new
+    ours = {name: made for name, made in programs.items() if name.startswith(stem)}
+    assert len(ours) >= 2 and set(ours.values()) == {1}, ours
+    assert ("h_split" in blocks) == ("logprobs" in blocks) == capture and sorted(blocks) == sorted(whole)
+    assert blocks["samples"].shape == (8, 100 + n)
     for name in ("samples", "samples_mask"):
         np.testing.assert_array_equal(blocks[name], whole[name][:, :100 + n])
-    np.testing.assert_allclose(blocks["logprobs"], whole["logprobs"][:, :n], atol=2e-5)
-    np.testing.assert_allclose(blocks["values"], whole["values"][:, :n], atol=2e-5)
-    live = blocks["samples_mask"].astype(bool)[:, :-1]
-    np.testing.assert_allclose(blocks["h_split"][:, :-1][live], whole["h_split"][:, :100 + n - 1][live],
-                               atol=2e-5)
+    np.testing.assert_array_equal(blocks["samples"][:, :100], ids)
+    if capture:
+        assert blocks["h_split"].shape[:2] == (8, 100 + n)
+        np.testing.assert_allclose(blocks["logprobs"], whole["logprobs"][:, :n], atol=2e-5)
+        np.testing.assert_allclose(blocks["values"], whole["values"][:, :n], atol=2e-5)
+        live = blocks["samples_mask"].astype(bool)[:, :-1]
+        np.testing.assert_allclose(blocks["h_split"][:, :-1][live], whole["h_split"][:, :100 + n - 1][live],
+                                   atol=2e-5)

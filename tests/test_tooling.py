@@ -3,6 +3,7 @@ trlx/reference.py + scripts/benchmark.sh equivalents)."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -389,3 +390,64 @@ def test_every_file_tier_1_starts_first_is_there():
     names = conftest.LONGEST_FIRST
     assert len(names) == len(set(names)) > 0
     assert [name for name in names if not os.path.isfile(os.path.join(here, name))] == []
+
+
+def _code_of(markdown: str):
+    """The text inside a document's fences and code spans."""
+    parts = markdown.split("```")
+    yield from parts[1::2]
+    for prose in parts[0::2]:
+        yield from re.findall(r"`([^`]+)`", prose)
+
+
+@pytest.mark.parametrize("document", [
+    "README.md", "docs/benchmark.md", "docs/observability.md", ".claude/skills/verify/SKILL.md"])
+def test_every_command_a_document_names_is_there(document):
+    """Every `python <path>.py`, `python3 <path>.py` or `bash <path>.sh` in a
+    code span or a fence of the document names a file of the tree (from its
+    root): a deleted or renamed script cannot stay behind as an instruction."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, document)) as f:
+        code = list(_code_of(f.read()))
+    named = {path for text in code
+             for path in re.findall(r"\b(?:python3?|bash)\s+((?:[\w.-]+/)*[\w.-]+\.(?:py|sh))\b", text)}
+    assert named, f"{document} names no command: the pattern no longer reads it"
+    assert sorted(p for p in named if not os.path.isfile(os.path.join(root, p))) == []
+
+
+def _chip_smoke():
+    import importlib.util
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location("chip_smoke", os.path.join(root, "chip_smoke.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def test_every_name_chip_smoke_imports_is_there():
+    """`chip_smoke.py` imports inside its phases, and the on-chip branches run
+    nowhere but on a chip: every `from <module> import <name>` of the file, at
+    whatever depth, resolves here, so a deleted helper fails tier-1 and not
+    the chip call."""
+    import ast
+    import importlib.util
+
+    with open(_chip_smoke().__file__) as f:
+        tree = ast.parse(f.read())
+    froms = [node for node in ast.walk(tree) if isinstance(node, ast.ImportFrom)]
+    assert len(froms) > 10
+    def there(module, name):
+        if hasattr(importlib.import_module(module), name):
+            return True
+        return importlib.util.find_spec(f"{module}.{name}") is not None
+
+    assert [f"{node.module}.{alias.name}" for node in froms for alias in node.names
+            if not there(node.module, alias.name)] == []
+
+
+@pytest.mark.parametrize("parity", ["flash_and_ce_parity", "flash_backward_parity"])
+def test_chip_smoke_kernel_parity_runs_interpreted(parity):
+    """The kernel-parity checks `chip_smoke.py` makes on the chip, through the
+    Pallas interpreter at a few rows and heads (what `--rehearse-cpu` runs)."""
+    getattr(_chip_smoke(), parity)(interpret=True)

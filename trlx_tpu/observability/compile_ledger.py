@@ -278,37 +278,20 @@ class CompileLedger:
                         rec.calls += 1
             return out
 
-        def _prepare(*args, **kwargs):
-            """Trace, lower and compile for these arguments (arrays or
-            `jax.ShapeDtypeStruct`s) without running: a later call with
-            arguments of that signature finds the program ready. Recorded
-            as the compile it is."""
-            prev = getattr(tls, "compiled", False)
-            tls.compiled = False
-            t0 = time.monotonic()
-            try:
-                jitted.lower(*args, **kwargs).compile()
-            finally:
-                compiled, tls.compiled = tls.compiled, prev
-            if compiled:
-                self._note_compile(fn_name, arg_signature(args, kwargs),
-                                   time.monotonic() - t0, calls=0)
-
         _call.__name__ = fn_name
         _call._ledgered = True  # introspection hook for tests
         _call._jitted = jitted  # escape hatch (.lower etc.)
-        _call.prepare = _prepare
         return _call
 
     def _note_compile(self, name: str,
                       sig: Tuple[Tuple[str, str], ...],
-                      wall_s: float, calls: int = 1) -> None:
+                      wall_s: float) -> None:
         with self._lock:
             rec = self.fns.get(name)
             if rec is None:
                 rec = self.fns[name] = _FnRecord(name, 1)
             rec.compiles += 1
-            rec.calls += calls
+            rec.calls += 1
             rec.compile_wall_s += wall_s
             prev_sig, rec.last_signature = rec.last_signature, sig
             over = rec.compiles > rec.budget
@@ -443,16 +426,6 @@ class CompileLedger:
             f"{ns}_compile_cache_misses_total {snap['persistent_cache']['misses']}",
         ]
         return "\n".join(lines) + "\n"
-
-
-def prepare_jit(fn: Callable, *args, **kwargs) -> None:
-    """Compile what `ledgered_jit` returned for these arguments ahead of
-    its first call, without running it (ledger on or off)."""
-    prepare = getattr(fn, "prepare", None)
-    if prepare is not None:
-        prepare(*args, **kwargs)
-    else:
-        fn.lower(*args, **kwargs).compile()
 
 
 def ledgered_jit(fn: Callable, name: Optional[str] = None, budget: int = 1,

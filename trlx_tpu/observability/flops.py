@@ -1,10 +1,10 @@
-"""FLOP model for PPO cycles — the single source of truth shared by the
-offline bench harness (`bench.py`) and the live goodput ledger
-(`trlx_tpu/observability/goodput.py`).
+"""FLOP model for PPO cycles — the itemized estimate the live goodput
+ledger (`trlx_tpu/observability/goodput.py`) prices a running trainer's
+samples with, and the one a whole cycle is priced with offline.
 
-Moved verbatim out of bench.py so a running trainer can compute live MFU
-with EXACTLY the same itemized estimate the offline benchmark prints;
-any model change made here moves both numbers together.
+One model for both, so a running trainer's live MFU and an offline
+per-cycle estimate (`scripts/goodput_slo_smoke.py` holds the two within
+10%) move together with any change made here.
 
 Dependency-free at import time: `chip_peak_flops()` imports jax lazily,
 so this module can be imported by host-only tooling.

@@ -18,12 +18,12 @@ charges each span only for the part of [t0, t1] not yet covered. The
 per-cause seconds therefore sum to the measured wall time exactly (the
 remainder is `other_host`), never double-counting nested spans.
 
-Live MFU reuses the SAME FLOP model as bench.py (observability/flops.py,
-moved there from bench): the trainer notes per-chunk rollout shapes and
-per-minibatch train rows, the ledger prices them with
+Live MFU uses the one FLOP model there is (observability/flops.py, which
+also prices whole cycles offline): the trainer notes per-chunk rollout
+shapes and per-minibatch train rows, the ledger prices them with
 `flops_per_sample`, and the steady-state rate divides by wall time since
-the last first-call span ended — the live analogue of bench.py's
-post-warmup timing window, so the two MFUs agree by construction for the
+the last first-call span ended — a timing window that opens after the
+warm-up, so a live and an offline MFU agree by construction for the
 same config.
 
 Everything is host-side bookkeeping on phase boundaries (a few dict ops
@@ -106,7 +106,7 @@ class GoodputLedger:
                 self.causes[cause] = self.causes.get(cause, 0.0) + exclusive
             if first:
                 # the live-MFU window opens when the LAST compile ends —
-                # the analogue of bench.py timing only post-warmup cycles
+                # so that only post-warmup work is timed
                 if self._steady_t0 is None or t1 > self._steady_t0:
                     self._steady_t0 = t1
 

@@ -171,7 +171,7 @@ def trunk_cache_bytes(rows: int, seq_len: int, d_model: int,
     """Frozen-trunk activation cache: the `[rows, seq_len, d_model]` state
     entering the first trainable block, kept for a PPO cycle
     (`PPOTrainer._trunk_cache_available` holds it to a share of the
-    device; bench.py's `trunk_cache_hbm_bytes`)."""
+    device)."""
     return int(rows) * int(seq_len) * int(d_model) * _itemsize(dtype)
 
 

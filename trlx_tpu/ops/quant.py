@@ -1,6 +1,6 @@
 """Int8 weight-only quantization for the frozen-trunk DECODE path.
 
-The r05 roofline (docs/benchmark.md) puts generation bandwidth-bound:
+Generation is bandwidth-bound (docs/trainers.md, the decode levers):
 every decode step streams the full bf16 param set to emit one token per
 row. Under the hydra split most of those bytes never see a gradient —
 blocks [0, split), the (tied) token embedding, and the learned position
@@ -149,8 +149,8 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
 
 
 def quantized_bytes(params: Any) -> int:
-    """HBM bytes of the decode view (int8 q + f32 scales + dense rest) —
-    reported by bench.py's roofline accounting."""
+    """HBM bytes of the decode view (int8 q + f32 scales + dense rest):
+    what a decode step streams, for roofline accounting."""
     total = 0
     for leaf in jax.tree_util.tree_leaves(params):
         total += leaf.size * leaf.dtype.itemsize

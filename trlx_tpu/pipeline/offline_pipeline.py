@@ -12,10 +12,8 @@ TPU-motivated:
   rather than string-level, so it also works with non-HF tokenizers.
 """
 
-import bisect
-import math
 from dataclasses import dataclass
-from typing import Any, Dict, Iterable, List, Sequence, Tuple, Union
+from typing import Any, Dict, Iterable, List, Tuple, Union
 
 import numpy as np
 
@@ -182,10 +180,9 @@ class PromptPipeline(BasePipeline):
         """`group_window` (a rollout loader's: the prompts of one
         collection) sorts every window of that many prompts of the epoch's
         order by token length, longest first, before it is cut into
-        batches; the batches keep the pool's width. (Longest first: the
-        widest `generate` program is the one every pool needs, and while
-        the device runs the long chunks a narrower rung's program compiles
-        behind them: PERF.md section 6, PR 42.)"""
+        batches; the batches keep the pool's width. (Longest first is the
+        order the cells have been measured in since PR 42; no program
+        depends on it.)"""
         pad_id = self.tokenizer.pad_token_id
         left = self.tokenizer.padding_side == "left"
         max_len = self.max_prompt_length
@@ -213,69 +210,6 @@ class PromptPipeline(BasePipeline):
             drop_last=drop_last, seed=seed,
             group_window=group_window, group_key=lambda ix: -self.prompt_lengths[ix],
         )
-
-
-def _log_choose(n: int, k: int) -> float:
-    return math.lgamma(n + 1) - math.lgamma(k + 1) - math.lgamma(n - k + 1)
-
-
-def _short_of(pool: int, good: int, draws: int, want: int) -> float:
-    """P(fewer than `want` of `draws` items taken from `pool` without
-    replacement are among its `good` ones): the hypergeometric lower tail."""
-    lo, hi = max(0, draws - (pool - good)), min(want - 1, good, draws)
-    total = _log_choose(pool, draws)
-    return sum(math.exp(_log_choose(good, x) + _log_choose(pool - good, draws - x) - total)
-               for x in range(lo, hi + 1))
-
-
-#: `prompt_width_ladder`: the narrowest rung (`generate`'s column bucket),
-#: the most rungs a trainer compiles, and the share of windows in which a
-#: chunk may outgrow its rung. Two rungs: a third costs a start-up what a
-#: second does (about 5 s for a 1.4 B model: 2.8 s traced, 1.0 s lowered,
-#: 1.1 s read from the compile cache; PERF.md section 6, PR 42) and saves
-#: less, and the benchmark holds start-up to its parent's plus a tenth
-LADDER_STEP, LADDER_MAX_WIDTHS, LADDER_MISS = 32, 2, 0.01
-
-
-def prompt_width_ladder(lengths: Sequence[int], window: int, rows: int) -> Tuple[int, ...]:
-    """The prompt widths a rollout chunk is generated at, narrowest first,
-    for a loader that sorts windows of `window` prompts of this pool by
-    length and cuts them into chunks of `rows`.
-
-    Chunk k of a sorted window ends at the window's (k+1) x rows-th
-    shortest prompt. Its rung is the narrowest of 32, 64, 128, ... that
-    holds that prompt in all but `LADDER_MISS` of the windows drawn from
-    this pool (the exact hypergeometric tail over the pool's own lengths);
-    the last chunk's is the pool's longest prompt, as it is collated. A
-    chunk that outgrows its rung runs at the next one, so a rung is only
-    ever a width that some chunk usually needs. Every rung is one more
-    compiled `generate` program (a model's blocks unrolled twice), so at
-    most `LADDER_MAX_WIDTHS` are kept (and never more than a window has
-    chunks): the rung given up is the one whose chunks lose the fewest
-    columns at the next rung up. A window of one chunk, or a pool of one
-    length, has the pool's longest prompt alone, and nothing changes."""
-    lengths = sorted(int(n) for n in lengths)
-    if not lengths:
-        return ()
-    top, pool = lengths[-1], len(lengths)
-    chunks = -(-window // max(rows, 1))
-    draws = min(window, pool)
-    rungs: Dict[int, int] = {}  # width -> chunks of a window that usually run at it
-    for k in range(chunks - 1):
-        want = min((k + 1) * rows, draws)
-        width = LADDER_STEP
-        while width < top and _short_of(
-                pool, bisect.bisect_right(lengths, width), draws, want) > LADDER_MISS:
-            width *= 2
-        width = min(width, top)
-        rungs[width] = rungs.get(width, 0) + 1
-    rungs[top] = rungs.get(top, 0) + 1
-    while len(rungs) > max(min(chunks, LADDER_MAX_WIDTHS), 1):
-        ladder = sorted(rungs)
-        # the rung whose chunks lose the fewest columns at the next one up
-        _, width, above = min((rungs[w] * (up - w), w, up) for w, up in zip(ladder, ladder[1:]))
-        rungs[above] += rungs.pop(width)
-    return tuple(sorted(rungs))
 
 
 def _pad_stack(seqs: List[np.ndarray], pad_value, max_len: int, dtype) -> np.ndarray:

@@ -17,7 +17,6 @@ GSPMD mesh from config.parallel and jit-compiling one train step:
   (accelerate_base_trainer.py:502-516).
 """
 
-import functools
 import json
 import os
 import pickle
@@ -60,27 +59,6 @@ def partition_params(params: Dict, mask_tree: Dict) -> Tuple[Dict, Dict]:
 def merge_params(train: Dict, frozen: Dict) -> Dict:
     """Inverse of partition_params -> nested param tree."""
     return traverse_util.unflatten_dict({**train, **frozen})
-
-
-def _widen_prompt_block(generate, prompt_len: int, extra: int, pad_id: int, left: bool):
-    """`generate` (ops/sampling.py, at a prompt width of `prompt_len`) with
-    the outputs that span [prompt + response] given `extra` more prompt
-    columns on the padding side: pad tokens, zero mask, zero activations.
-    The caller that narrowed a chunk gets its own width back from the one
-    program."""
-    at = 0 if left else prompt_len
-
-    @functools.wraps(generate)  # the program keeps its name, `jit_generate*`
-    def widened(params, input_ids, attn_mask, rng):
-        out = dict(generate(params, input_ids, attn_mask, rng))
-        for key, fill in (("samples", pad_id), ("samples_mask", 0), ("h_split", 0)):
-            if key in out:
-                v = out[key]
-                pad = jnp.full(v.shape[:1] + (extra,) + v.shape[2:], fill, v.dtype)
-                out[key] = jnp.concatenate([v[:, :at], pad, v[:, at:]], axis=1)
-        return out
-
-    return widened
 
 
 @register_trainer
@@ -229,7 +207,7 @@ class TPUTrainer(BaseRLTrainer):
         self._timeline = PhaseTimeline() if config.train.tracing else None
         # Goodput ledger (rides the timeline's phase hooks): attributes
         # every wall second of learn() to a cause and computes live MFU
-        # with bench.py's FLOP model. Only exists when tracing is on.
+        # with observability/flops.py. Only exists when tracing is on.
         self._goodput = None
         # Compile ledger + HBM ledger (ISSUE 18): per-function recompile
         # accounting with retrace-storm postmortems, and device-memory
@@ -370,23 +348,19 @@ class TPUTrainer(BaseRLTrainer):
                             ledger=self._compile_ledger, **jit_kwargs)
 
     def get_generate_fn(self, batch_size: int, prompt_len: int, gen_kwargs: Dict, mode: str = "lm",
-                        capture: bool = False, spec_k: int = 0, widen_to: int = 0):
+                        capture: bool = False, spec_k: int = 0):
         """Jit-cached generate fn per (shape, kwargs) bucket. `capture`
         builds the rollout fast-path sampler, which additionally emits
         per-token logprobs/values and the hydra-split activations; spec_k
         > 0 builds the self-speculative draft/verify sampler instead of
-        the token-at-a-time loop (see ops/sampling.py). `widen_to` >
-        `prompt_len` (a rollout chunk narrowed to a rung of
-        `_prompt_ladder`) gives the outputs back with a prompt block that
-        wide, inside the same program."""
+        the token-at-a-time loop (see ops/sampling.py)."""
         from trlx_tpu.ops.sampling import make_generate_fn
 
         # repr-normalize values: gen_kwargs may carry unhashable HF-style
         # knobs (lists/dicts) from configs written against the reference
-        widen_to = int(widen_to) if widen_to > prompt_len else 0
         block = self._prefill_block()
         key = (batch_size, prompt_len, repr(sorted(gen_kwargs.items())), mode, bool(capture),
-               int(spec_k), widen_to, block)
+               int(spec_k), block)
         if key not in self._generate_cache:
             gen_cfg = self._generation_config(gen_kwargs)
             two_qs = bool(getattr(self.config.method, "two_qs", True))
@@ -404,13 +378,8 @@ class TPUTrainer(BaseRLTrainer):
             import hashlib
 
             kw_tag = hashlib.md5(key[2].encode()).hexdigest()[:6]
-            if widen_to:
-                fn = _widen_prompt_block(
-                    fn, prompt_len, widen_to - prompt_len, self.tokenizer.pad_token_id,
-                    left=self.config.tokenizer.padding_side == "left")
             fn_name = (
-                f"generate[b{batch_size},p{prompt_len},"
-                + (f"out{widen_to}," if widen_to else "") + mode
+                f"generate[b{batch_size},p{prompt_len},{mode}"
                 + (",cap" if capture else "")
                 + (f",spec{spec_k}" if spec_k else "")
                 + f",kw{kw_tag}]"
@@ -433,30 +402,6 @@ class TPUTrainer(BaseRLTrainer):
         frozen-trunk view (ppo_trainer). Train/score paths never call
         this."""
         return self.params
-
-    #: prompt widths, narrowest first, that a batch as wide as the last of
-    #: them (a rollout chunk, collated at its pool's width) is generated
-    #: at; None: every batch keeps its width. Set by the trainer that owns
-    #: a rollout loader (PPOTrainer.add_prompt_pipeline)
-    _prompt_ladder: Optional[Tuple[int, ...]] = None
-
-    def _ladder_width(self, attention_mask) -> int:
-        """The rung of `_prompt_ladder` a rollout chunk is generated at: the
-        narrowest that holds its longest prompt once the columns that are
-        padding in every row are dropped on the tokenizer's padding side;
-        0 where the batch keeps its width (no ladder, another width than
-        the pool's, or the last rung)."""
-        ladder = self._prompt_ladder
-        width = attention_mask.shape[1]
-        if not ladder or width != ladder[-1]:
-            return 0
-        live = np.flatnonzero(np.asarray(attention_mask).any(axis=0))
-        if not live.size:
-            return 0
-        left = self.config.tokenizer.padding_side == "left"
-        need = width - live[0] if left else live[-1] + 1
-        rung = next(w for w in ladder if w >= need)
-        return rung if rung < width else 0
 
     def _bucket_shape(self, rows: int, width: int) -> Tuple[int, int]:
         """The (rows, prompt width) `generate` runs a batch of that shape at."""
@@ -507,13 +452,7 @@ class TPUTrainer(BaseRLTrainer):
         gen_kwargs = gen_kwargs if gen_kwargs is not None else self.generate_kwargs
         input_ids = np.asarray(input_ids)
         attention_mask = np.asarray(attention_mask)
-        widen_to = 0
         if getattr(self.config.train, "bucket_generation", True):
-            rung = self._ladder_width(attention_mask)
-            if rung:
-                widen_to = input_ids.shape[1]
-                keep = slice(-rung, None) if self.config.tokenizer.padding_side == "left" else slice(0, rung)
-                input_ids, attention_mask = input_ids[:, keep], attention_mask[:, keep]
             input_ids, attention_mask, orig = self._bucket_prompts(input_ids, attention_mask)
             if self.config.model.model_arch_type == "seq2seq":
                 # seq2seq samples are decoder-side only — never trim the
@@ -522,7 +461,7 @@ class TPUTrainer(BaseRLTrainer):
         else:
             orig = (input_ids.shape[0], 0)
         fn = self.get_generate_fn(input_ids.shape[0], input_ids.shape[1], gen_kwargs, mode,
-                                  capture=capture, spec_k=spec_k, widen_to=widen_to)
+                                  capture=capture, spec_k=spec_k)
         out = fn(self._decode_params(), jnp.asarray(input_ids), jnp.asarray(attention_mask),
                  self.next_rng())
         return self._unbucket_output(out, orig)

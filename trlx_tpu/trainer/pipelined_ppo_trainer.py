@@ -58,7 +58,7 @@ logger = logging.get_logger(__name__)
 @register_trainer
 class PipelinedPPOTrainer(PipelinedCausalMixin, PPOTrainer):
     _supports_moe_pp = True  # in-pipe aux-loss carry consumed in make_loss_fn
-    _narrows_rollout_chunks = False  # PipelinedCausalMixin.generate keeps a chunk's width
+    _narrows_rollout_chunks = False  # PipelinedCausalMixin.generate runs no `BlockPlan`
     # r4: the 1F1B loss is expressed in full token width (prepare() scatters
     # the response windows to their predicting positions, CE-preshift
     # style), so it composes with sequence parallelism — the deep-model

@@ -38,9 +38,7 @@ from trlx_tpu.ops.ppo import (
     ppo_loss,
 )
 from trlx_tpu.parallel import infer_param_shardings
-from trlx_tpu.observability.compile_ledger import prepare_jit
 from trlx_tpu.pipeline import LoaderStream
-from trlx_tpu.pipeline.offline_pipeline import prompt_width_ladder
 from trlx_tpu.pipeline.ppo_pipeline import PPORolloutStorage
 from trlx_tpu.trainer import register_trainer
 from trlx_tpu.trainer.base_trainer import TPUTrainer, merge_params
@@ -186,11 +184,9 @@ class PPOTrainer(TPUTrainer):
         if self.log_rollouts:
             self.setup_rollout_logging(config)
 
-        # the rollout loader's stream (`_rollout_stream`), its countdown to
-        # the ladder's programs all being made, and the collection's count of
-        # generate dispatches: [calls, widths, positions, padding positions]
+        # the rollout loader's stream (`_rollout_stream`) and the collection's
+        # count of generate dispatches: [calls, widths, positions, padding positions]
         self._prompt_stream = None
-        self._ladder_countdown = 0
         self._prefill_tally = np.zeros(4, np.int64)
         self._score_fn = None
         # whether `_score_fn` hands out a sixth result on request, the
@@ -261,9 +257,9 @@ class PPOTrainer(TPUTrainer):
         )
 
     def _goodput_configure(self, n_prompt: int, n_new: int) -> None:
-        """Price the goodput ledger's per-sample FLOPs with the same
-        knobs bench.py passes to flops_per_cycle — live MFU and the
-        offline bench MFU share one model by construction. Re-done every
+        """Price the goodput ledger's per-sample FLOPs with the knobs
+        an offline estimate passes to flops_per_cycle — live MFU and an
+        offline MFU share one model by construction. Re-done every
         chunk (pure arithmetic): the speculative accept rate is measured,
         so it converges as rounds accumulate."""
         spec_k = self._spec_k_effective()
@@ -518,7 +514,7 @@ class PPOTrainer(TPUTrainer):
                 # chunk (0.676 against 0.566 s), and with the barrier it
                 # plans as it does for five outputs (0.570 s). lfm2's chunk
                 # (268 MB) compiles to one plan either way. PERF.md section
-                # 6, PR 40; tests/test_kernels_compile_tpu.py holds the plan.
+                # 6, PR 40; tests/test_ppo_cells_compile_tpu.py holds the plan.
                 h_split, _ = jax.lax.optimization_barrier((h_split, ref_logits))
             scored = (logprobs, values[:, :-1], log_ratio, mean_kl, mean_kl_per_token)
             return (*scored, self._place_trunk_cache(h_split)) if with_trunk_state else scored
@@ -1483,8 +1479,8 @@ class PPOTrainer(TPUTrainer):
         self.prompt_iterator = self._rollout_stream(pipeline, self.config.method.chunk_size)
 
     #: whether a rollout chunk goes through the base trainer's `generate`,
-    #: which follows its longest prompt (a `BlockPlan`, or a rung of
-    #: `_prompt_ladder`); the pipelined trainers' own `generate` does not
+    #: whose program may follow its longest prompt (a `BlockPlan`); the
+    #: pipelined trainers' own `generate` does not
     _narrows_rollout_chunks = True
 
     def _rollout_plan(self, width: int, gen_kwargs, **generate_kwargs):
@@ -1499,28 +1495,13 @@ class PPOTrainer(TPUTrainer):
         that knows its prompts' lengths has every collection's prompts (a
         window of the shuffled order) sorted by length before they are cut
         into chunks: that is what makes a chunk's longest prompt short.
-        Where the sampler's one program follows the chunk's longest prompt
-        itself (`_rollout_plan`) every chunk is generated at the pool's
-        width; elsewhere the trainer gets the few prompt widths such chunks
-        are generated at (`prompt_width_ladder`; one width, and the loader
-        of before, where a collection is one chunk). The stream's place is
-        part of the resume state."""
+        Every chunk is generated at the pool's width; whether the program
+        then follows the chunk's longest prompt is the sampler's own rule
+        (`_rollout_plan`). The stream's place is part of the resume state."""
         method = self.config.method
         window = rows * -(-int(method.num_rollouts) // max(int(method.chunk_size), 1))
-        lengths = getattr(pipeline, "prompt_lengths", None)
-        ladder = ()
-        if lengths is not None:
+        if getattr(pipeline, "prompt_lengths", None) is not None:
             loader_kwargs["group_window"] = window
-            ladder = prompt_width_ladder(lengths, window, rows)
-        # (seq2seq prompts are the encoder's: its samples hold no prompt block)
-        narrows = self._narrows_rollout_chunks and not self.seq2seq and getattr(
-            self.config.train, "bucket_generation", True)
-        if narrows and len(ladder) > 1:
-            narrows = self._rollout_plan(ladder[-1], self.generate_experience_kwargs or self.generate_kwargs,
-                                         spec_k=self._spec_k_effective()) is None
-        self._prompt_ladder = ladder if len(ladder) > 1 and narrows else None
-        # dispatches until the other rungs' programs are made (`_rollout_generate`)
-        self._ladder_countdown = 2 if self._prompt_ladder else 0
         self._prompt_stream = LoaderStream(pipeline.create_loader(rows, shuffle=True, **loader_kwargs))
         return self._prompt_stream
 
@@ -1531,24 +1512,17 @@ class PPOTrainer(TPUTrainer):
         that of the blocks run, and the span says how far the mechanism
         engaged (blocks run of the program's, cache columns a decode step
         reads of the cache's), from the chunk's mask by the program's own
-        rule. A rung's program compiles
-        when a chunk first runs at it, as any program does; once the first
-        two chunks are dispatched (generation is double-buffered: the host
-        would now wait for the first, and the device has two chunks' work,
-        the longest's, to hide a start-up's tracing behind) the other rungs'
-        are compiled too, so that no width is first met cycles later."""
+        rule."""
         from trlx_tpu.ops.sampling import first_live_column
 
         input_ids = np.asarray(batch["input_ids"])
         attention_mask = np.asarray(batch["attention_mask"])
-        run_width = self._ladder_width(attention_mask) or attention_mask.shape[1]
-        rows, width = self._bucket_shape(len(input_ids), run_width)
-        plan = self._rollout_plan(run_width, gen_kwargs, **generate_kwargs)
+        rows, width = self._bucket_shape(*attention_mask.shape)
+        plan = self._rollout_plan(attention_mask.shape[1], gen_kwargs, **generate_kwargs)
         engaged = {}
         if plan is not None:
-            # the columns the program gets: a rung's are the last of the
-            # chunk's, and `_bucket_prompts` pads on the left, as the prompts are
-            first = int(first_live_column(attention_mask[:, -run_width:])) + width - run_width
+            # `_bucket_prompts` pads on the left, as the prompts are
+            first = int(first_live_column(attention_mask)) + width - attention_mask.shape[1]
             blocks_run = plan.blocks - int(plan.first_block(first))
             width = blocks_run * plan.block
             engaged = dict(blocks=plan.blocks, blocks_run=blocks_run,
@@ -1560,23 +1534,7 @@ class PPOTrainer(TPUTrainer):
             tracing.counters("ppo.prefill", calls=1, rows=rows, width=width,
                              prompt_tokens=tokens, padded_tokens=padded,
                              pad_tokens=padded - tokens, **engaged)
-        out = self.generate(input_ids, attention_mask, gen_kwargs, **generate_kwargs)
-        if self._ladder_countdown:
-            self._ladder_countdown -= 1
-            if not self._ladder_countdown:
-                self._prepare_ladder_programs(len(input_ids), gen_kwargs, **generate_kwargs)
-        return out
-
-    def _prepare_ladder_programs(self, rows: int, gen_kwargs, **generate_kwargs):
-        """Trace, lower and compile (or read back) the `generate` program
-        of every rung for chunks of `rows`, without running any; next to
-        nothing for a rung that a chunk has run at."""
-        ladder, params = self._prompt_ladder, self._decode_params()
-        for width in ladder:
-            rows, cols = self._bucket_shape(rows, width)
-            fn = self.get_generate_fn(rows, cols, gen_kwargs, widen_to=ladder[-1], **generate_kwargs)
-            tokens = jax.ShapeDtypeStruct((rows, cols), jnp.int32)
-            prepare_jit(fn, params, tokens, tokens, jax.ShapeDtypeStruct(self.rng.shape, self.rng.dtype))
+        return self.generate(input_ids, attention_mask, gen_kwargs, **generate_kwargs)
 
     def post_epoch_callback(self):
         if self.log_rollouts:

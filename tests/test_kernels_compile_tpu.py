@@ -309,6 +309,35 @@ def test_the_chunked_form_s_temporaries_do_not_grow_with_the_prompt(v5e):
     assert long < 1.05 * short and long < 512e6, (short, long)
 
 
+def test_ssd_decode_compiles_at_the_cell_s_slot_pool_and_the_chunked_form_holds_a_chunk(v5e):
+    """`ssd_decode` at `falcon-h1-34b.rollout-chat`'s pool, [128 slots, 32 heads,
+    256, 128] float32: Mosaic takes the [128, 256] transpose in the kernel, the
+    donated state is the result's (537 MB aliased, no temporary of that size)
+    and the call carries its name; `ssd_chunked` at 1,024 positions compiles to
+    ONE loop over its 8 chunks whose carried tuple holds the row's state as
+    `f32[1,2,16,256,128]`, which is what `bench/metrics/readers/ssm_kernels.py`
+    tells the form by in a trace (XLA keeps no scope name on an event)."""
+    from trlx_tpu.ops import ssd
+
+    rows, heads, d_state, d_head, groups = 128, 32, 256, 128, 2
+    one = SingleDeviceSharding(v5e[0])
+    vec = lambda *shape, dtype=F32: S(shape, dtype, sharding=one)
+    args = (vec(rows, heads, d_state, d_head), vec(rows, heads, d_head), vec(rows, heads), vec(heads),
+            vec(rows, groups, d_state), vec(rows, groups, d_state), vec(rows, dtype=I32))
+    compiled = jax.jit(ssd.ssd_decode, donate_argnums=(0,)).trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    assert kernel_names(compiled) == ["ssd_decode"]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == rows * heads * d_state * d_head * 4
+    assert memory.temp_size_in_bytes < 32 << 20, memory.temp_size_in_bytes  # the stacked vectors, 16.8 MB
+
+    t = 1024
+    args = (vec(1, t, heads, d_head), vec(1, t, heads), vec(heads), vec(1, t, groups, d_state),
+            vec(1, t, groups, d_state))
+    chunked = jax.jit(ssd.ssd_chunked).trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    loops = [line for line in chunked.as_text().splitlines() if " while(" in line]
+    assert len(loops) == 1 and "f32[1,2,16,256,128]" in loops[0].partition(" while(")[0], loops
+
+
 LAYOUTS = [(4, 1, 1), (2, 1, 2), (1, 2, 2)]  # (data, fsdp, tensor)
 
 

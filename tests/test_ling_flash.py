@@ -381,7 +381,7 @@ def test_sessions_submit_n_and_the_speculative_steps_refuse_slot_state_by_name(p
     with pytest.raises(NotImplementedError, match="floating cache type"):
         init_paged_kv_arena(cfg, 4, 8, jnp.int8, num_slots=2)
     gen_cfg = GenerationConfig(max_new_tokens=4, eos_token_id=VOCAB + 1)
-    with pytest.raises(NotImplementedError, match="convolution state or a recurrent matrix"):
+    with pytest.raises(NotImplementedError, match="speculative decode over slot state .linear_attention layers keep state, tails"):
         make_generate_fn(CausalLMWithValueHead(cfg), cfg, gen_cfg, spec_k=2, spec_split=1,
                          spec_draft_head=(jnp.zeros((64, 4)), jnp.zeros((4, VOCAB))))
     # a verify pass of the model's own cached step: nothing rolls a recurrence back

@@ -2,7 +2,8 @@
 compiled for one v5e chip with no chip.
 
 `ling-3.0-flash-vl.rollout-reason` and `solar-open2-250b.rollout-longctx`:
-Kimi-delta linear layers beside a paged cache. As in
+Kimi-delta linear layers beside a paged cache; `falcon-h1-34b.rollout-chat`: a
+Mamba-2 state a slot AND K/V a token in every layer. As in
 `test_serve_cells_compile_tpu.py`: the kernels by name, neither the arena nor
 a recurrent matrix copied, and what the program holds inside the chip.
 """
@@ -100,3 +101,34 @@ def test_kv_hybrid_cell_programs_compile_for_the_chip_and_fit_it(v5e, kv_hybrid_
     held = held_bytes(compiled)
     print(f"{program}: held {held}")
     assert held < 15.5e9, held
+
+
+CHAT_CELL = dict(n_tbl=(1024 + 512) // 32)
+
+
+def test_chat_cell_programs_compile_for_the_chip_and_fit_it(v5e, pallas_mode):
+    """`falcon-h1-34b.rollout-chat`'s decode step and its widest prefill (one
+    row of 1,024, the fresh-prompt program) at the published widths: in EVERY
+    layer one `ssd_decode` over the slot pool and one `paged_decode` at 5 query
+    heads a K/V head, the prompt's recurrence in chunks of 128 under XLA beside
+    the flash forward, neither the arena nor a recurrent matrix copied (the
+    state pools [128, 32, 256, 128] are told by their shape), and arguments plus
+    temporaries under 15.0 GB: 8.79 GB of weights, 2.16 GB of slot state, 1.61
+    GB of arena and the program's own (the prefill's logits over the whole
+    vocabulary at every prompt position among them). One test, so that the
+    engine's 3.8 GB pool on the host lives no longer than its two compiles."""
+    engine, params = serve_cell_engine(v5e, "falcon-h1-34b", "rollout-chat", 512, CHAT_CELL["n_tbl"])
+    pools = [a for layer in engine._pool["layers"] for name, a in layer.items() if name != "tails"]
+    assert len(pools) == 4 * 3 and engine._kernel_unsupported is None
+    for program, prefill, want in (("decode", None, {"ssd_decode": 4, "paged_decode": 4}),
+                                   ("paged_insert", (1, 1024, True), {"flash_fwd": 4})):
+        compiled = compile_engine_program(engine, params, v5e[0], prefill)
+        names = kernel_names(compiled)
+        assert {n: names.count(n) for n in set(names)} == want, program
+        assert arena_rewrites(compiled, engine._pool["layers"][0]["k"]) == [], program
+        assert [i for i in instructions_of_at_least(compiled, 128 * 32 * 256 * 128)
+                if "= f32[128,32,256,128]" in i and (" copy(" in i or " transpose(" in i)] == [], program
+        assert donated_outputs(compiled) >= len(pools), program
+        held = held_bytes(compiled)
+        print(f"{program}: held {held}")
+        assert held < 15.0e9, (program, held)

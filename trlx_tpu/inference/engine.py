@@ -54,9 +54,9 @@ from trlx_tpu.models.transformer import (
     init_paged_kv_arena,
     moe_stats_from_state,
     prefill_fuses,
+    slot_state_of,
 )
 from trlx_tpu.observability import tracing
-from trlx_tpu.ops.linear_attention import CHUNK
 from trlx_tpu.ops.quant import dequantize_tree
 from trlx_tpu.ops.sampling import (
     GenerationConfig,
@@ -91,11 +91,12 @@ def _refuse_over_latent_cache(model_cfg, what: str) -> None:
 def _refuse_over_slot_state(model_cfg, what: str) -> None:
     """A layer that keeps a state a slot (`LayerKeeps.slot`: a convolution's
     last inputs, a recurrent matrix) has nothing a block table can share and
-    nothing a mask bit can roll back: what would need either is refused by name."""
+    nothing a mask bit can roll back, whatever planes a token it keeps beside
+    it: what would need either is refused by name."""
     if getattr(model_cfg, "has_slot_state", False):
         raise NotImplementedError(
-            f"{what} over slot state (conv / linear_attention layers keep a state a slot, "
-            "not planes a token) is not supported")
+            f"{what} over slot state ({slot_state_of(model_cfg)}, which no block table shares and no mask "
+            "bit rolls back) is not supported")
 
 
 def _refuse_over_attention_kinds(model_cfg, what: str) -> None:
@@ -324,8 +325,9 @@ class InferenceEngine:
         keeps = getattr(model_cfg, "layer_keeps", None)
         self._layer_keeps = [keeps(i) for i in range(model_cfg.n_layers)] if keeps else []
         self._slot_state_layers = sum(1 for k in self._layer_keeps if k.slot)
-        self._linear_layers = sum(1 for i in range(len(self._layer_keeps))
-                                  if model_cfg.layer_op(i) == "linear_attention")
+        # the layers whose prefill runs a chunked recurrence from the slot's `state` (a KDA
+        # layer's, an SSM mixer's), whatever planes a token they keep beside it
+        self._recurrent_layers = sum(1 for k in self._layer_keeps if "state" in k.slot_names)
         # bytes of slot state a row holds over all layers (0 for K/V and latent layers)
         self._slot_state_bytes_per_slot = (
             model_cfg.slot_state_bytes_per_slot(self.kv_cache_dtype) if self._slot_state_layers else 0)
@@ -491,13 +493,19 @@ class InferenceEngine:
             return "kv_paging_off"
         if getattr(cfg, "alibi", False):
             return "alibi"
-        if getattr(cfg, "has_linear_layers", False) and not self._interprets_kernels():
+        if self._interprets_kernels():  # the interpreter takes any shape
+            return None
+        # the compiled `kda_decode` and `ssd_decode` tile whole groups of heads
+        if getattr(cfg, "has_linear_layers", False):
             from trlx_tpu.ops.linear_attention import decode_kernel_takes
 
-            # the compiled `kda_decode` tiles whole groups of heads; the
-            # interpreter takes any shape
             if not decode_kernel_takes(cfg.n_heads, cfg.head_dim, cfg.head_dim):
                 return "kda_decode_tiling"
+        if getattr(cfg, "has_ssm_layers", False):
+            from trlx_tpu.ops.ssd import decode_kernel_takes
+
+            if not decode_kernel_takes(cfg.ssm_heads, cfg.ssm_state, cfg.ssm_head_dim):
+                return "ssd_decode_tiling"
         return None
 
     def _interprets_kernels(self) -> bool:
@@ -911,11 +919,12 @@ class InferenceEngine:
         if tracing.active():
             tracing.counters("sched.insert", calls=1, rows=rows, prompt_tokens=tokens,
                              padded_tokens=padded, pad_tokens=padded - tokens)
-            if self._linear_layers:
+            if self._recurrent_layers:
                 # what the chunked recurrence is about to run, a layer: every padded position, in chunks
+                chunk = self.model_cfg.state_chunk
                 tracing.counters("engine.prefill_state", tokens=tokens, padded_tokens=padded,
-                                 linear_layers=self._linear_layers,
-                                 chunks=sum(pb * -(-plen // CHUNK) for plen, _, pb in programs))
+                                 linear_layers=self._recurrent_layers,
+                                 chunks=sum(pb * -(-plen // chunk) for plen, _, pb in programs))
         return rows, tokens, padded
 
     @contextlib.contextmanager

@@ -345,9 +345,12 @@ class Scheduler:
         the prompt's KV blocks. All-or-nothing against queue depth."""
         if n < 1:
             raise ValueError(f"n must be >= 1, got {n}")
-        if n > 1 and getattr(getattr(self.engine, "model_cfg", None), "has_slot_state", False):
+        model_cfg = getattr(self.engine, "model_cfg", None)
+        if n > 1 and getattr(model_cfg, "has_slot_state", False):
+            from trlx_tpu.models.transformer import slot_state_of
+
             raise NotImplementedError(
-                "submit_n's shared prompt over slot state (conv / linear_attention layers) is not supported: "
+                f"submit_n's shared prompt over slot state ({slot_state_of(model_cfg)}) is not supported: "
                 "a state a slot cannot be shared through block tables; submit the prompt n times")
         ids, max_new = self._validate(
             prompt_ids, max_new_tokens, adapter_id, stop_sequences

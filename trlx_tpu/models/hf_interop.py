@@ -26,7 +26,7 @@ from typing import Callable, Dict, Tuple
 
 import numpy as np
 
-from trlx_tpu.models.transformer import RopeSpec, TransformerConfig, cut_to_depth
+from trlx_tpu.models.transformer import Multipliers, RopeSpec, TransformerConfig, cut_to_depth
 from trlx_tpu.utils import logging
 
 logger = logging.get_logger(__name__)
@@ -63,6 +63,8 @@ def _family_of(hf: Dict) -> str:
         return "ling_flash"
     if mt == "solar_open2":
         return "solar_open2"
+    if mt == "falcon_h1":
+        return "falcon_h1"
     for fam, keys in (
         ("gpt_bigcode", ("bigcode",)),
         ("gpt_neox", ("neox",)),
@@ -195,6 +197,8 @@ def config_from_hf(path: str, **overrides):
         kwargs = _ling_kwargs(hf)
     elif fam == "solar_open2":
         kwargs = _solar_kwargs(hf)
+    elif fam == "falcon_h1":
+        kwargs = _falcon_h1_kwargs(hf)
     kwargs["hf_family"] = fam
     kwargs.update(overrides)
     return TransformerConfig(**cut_to_depth(kwargs, overrides))
@@ -299,6 +303,44 @@ def _solar_kwargs(hf: Dict) -> Dict:
         moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=0, moe_router="sigmoid",
         moe_shared_d_ff=hf["moe_intermediate_size"] * hf.get("n_shared_experts", 1),
         moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+    )
+
+
+def _falcon_h1_kwargs(hf: Dict) -> Dict:
+    """tiiuae's `falcon_h1` config keys -> TransformerConfig fields: a Mamba-2
+    mixer and GQA attention side by side in every block (`ssm_attention`), the
+    twelve forward multipliers as one `Multipliers`. What the keys leave open
+    (where each multiplier is applied, the grouped norm behind the gate, D, no
+    clamp on dt) is the family's public modelling code's and is listed in
+    bench/reference/falcon_h1.py. A key that would change a layer's equations
+    from what is written there is refused by name."""
+    for key, want in (("mamba_rms_norm", True), ("mamba_norm_before_gate", False), ("attn_layer_indices", None),
+                      ("attention_bias", False), ("mamba_proj_bias", False), ("mlp_bias", False),
+                      ("projectors_bias", False), ("mamba_conv_bias", True), ("mamba_use_mlp", True),
+                      ("hidden_act", "silu"), ("rope_scaling", None)):
+        if hf.get(key, want) != want:
+            raise NotImplementedError(f"falcon_h1 with {key}={hf[key]!r} is not supported")
+    d_ssm = hf.get("mamba_d_ssm") or int(hf.get("mamba_expand", 2) * hf["hidden_size"])
+    if d_ssm != hf["mamba_n_heads"] * hf["mamba_d_head"]:
+        raise NotImplementedError(
+            f"falcon_h1 with mamba_d_ssm={d_ssm} unlike mamba_n_heads x mamba_d_head is not supported")
+    n = hf["num_hidden_layers"]
+    return dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=n,
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"],
+        head_width=hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"],
+        d_ff=hf["intermediate_size"], max_seq_len=hf["max_position_embeddings"], pos_embed="rope",
+        rope_theta=float(hf["rope_theta"]), norm="rmsnorm", layer_norm_epsilon=hf.get("rms_norm_eps", 1e-5),
+        activation="silu", glu=True, tie_embeddings=bool(hf.get("tie_word_embeddings", False)), use_bias=False,
+        flash_prefill=True, layer_types=("ssm_attention",) * n,
+        ssm_heads=hf["mamba_n_heads"], ssm_head_dim=hf["mamba_d_head"], ssm_state=hf["mamba_d_state"],
+        ssm_groups=hf["mamba_n_groups"], ssm_conv_kernel=hf["mamba_d_conv"], ssm_chunk=hf.get("mamba_chunk_size", 128),
+        multipliers=Multipliers(
+            embedding=hf.get("embedding_multiplier", 1.0), lm_head=hf.get("lm_head_multiplier", 1.0),
+            attention_in=hf.get("attention_in_multiplier", 1.0), attention_out=hf.get("attention_out_multiplier", 1.0),
+            key=hf.get("key_multiplier", 1.0), ssm_in=hf.get("ssm_in_multiplier", 1.0),
+            ssm_out=hf.get("ssm_out_multiplier", 1.0), ssm=tuple(hf.get("ssm_multipliers") or ()),
+            mlp=tuple(hf.get("mlp_multipliers") or ())),
     )
 
 
@@ -655,6 +697,45 @@ def _load_pangu_ultra_moe(sd: Dict, cfg: TransformerConfig) -> Dict:
     return lm
 
 
+_FALCON_NORMS = (("ln_attn", "input_layernorm"), ("ln_mlp", "pre_ff_layernorm"))
+_FALCON_ATTN = ("q_proj", "k_proj", "v_proj", "o_proj")
+# (leaf, its name under `mamba.`): the heads' three vectors are bare parameters there
+_FALCON_HEAD = ((("a_log", "bias"), "A_log"), (("d", "scale"), "D"), (("dt_bias", "bias"), "dt_bias"))
+
+
+def _load_falcon_h1(sd: Dict, cfg: TransformerConfig) -> Dict:
+    """`falcon_h1` (tiiuae Falcon-H1), in the family's checkpoint names: a
+    block's `mamba.*`, `self_attn.*`, `feed_forward.*`, `input_layernorm`,
+    `pre_ff_layernorm`; `final_layernorm` and an untied `lm_head`. The
+    convolution's weight [channels, 1, taps] becomes [taps, channels] (tap j
+    meets the input taps - 1 - j back on both sides); `A_log`, `D` and
+    `dt_bias` are copied as they are (A = -exp(A_log) in both). UNCHECKED
+    against the published weights: no checkpoint of the family was at hand, the
+    round trip in tests/test_falcon_h1.py is over a random state dict."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lm: Dict = {
+        "embed_tokens": {"embedding": sd[f"{pre}embed_tokens.weight"]},
+        "ln_f": _ln(sd, f"{pre}final_layernorm", bias=False),
+    }
+    if not cfg.tie_embeddings:
+        lm["lm_head"] = _dense(sd["lm_head.weight"].T)
+    for i in range(cfg.n_layers):
+        p = f"{pre}layers.{i}."
+        lm[f"block_{i}"] = {
+            **{n: _ln(sd, p + hf_n, bias=False) for n, hf_n in _FALCON_NORMS},
+            "attn": {n: _dense(sd[p + f"self_attn.{n}.weight"].T) for n in _FALCON_ATTN},
+            "mlp": {n: _dense(sd[p + f"feed_forward.{n}.weight"].T) for n in _GLU},
+            "ssm": {
+                "in_proj": _dense(sd[p + "mamba.in_proj.weight"].T),
+                "out_proj": _dense(sd[p + "mamba.out_proj.weight"].T),
+                "conv1d": _dense(sd[p + "mamba.conv1d.weight"][:, 0, :].T, sd[p + "mamba.conv1d.bias"]),
+                "norm": _ln(sd, p + "mamba.norm", bias=False),
+                **{leaf[0]: {leaf[1]: sd[p + f"mamba.{hf_n}"]} for leaf, hf_n in _FALCON_HEAD},
+            },
+        }
+    return lm
+
+
 def _load_gpt_neox(sd: Dict, cfg: TransformerConfig) -> Dict:
     sd = _strip_prefix(sd, "gpt_neox.")
     lm: Dict = {
@@ -872,6 +953,7 @@ _LOADERS: Dict[str, Callable] = {
     "gpt_bigcode": _load_gpt_bigcode,
     "lfm2_moe": _load_lfm2_moe,
     "pangu_ultra_moe": _load_pangu_ultra_moe,
+    "falcon_h1": _load_falcon_h1,
 }
 
 
@@ -1050,6 +1132,33 @@ def _export_pangu_ultra_moe(lm: Dict, cfg: TransformerConfig) -> Dict:
         sd[p + "enorm.weight"], sd[p + "hnorm.weight"] = _f32(m["enorm"]["scale"]), _f32(m["hnorm"]["scale"])
         sd[p + "eh_proj.weight"] = _f32(m["eh_proj"]["kernel"]).T
         _export_pangu_block(m["block"], p, cfg, sd)
+    return sd
+
+
+def _export_falcon_h1(lm: Dict, cfg: TransformerConfig) -> Dict:
+    """Inverse of `_load_falcon_h1` (as unchecked against the published weights)."""
+    sd = {
+        "model.embed_tokens.weight": _f32(lm["embed_tokens"]["embedding"]),
+        "model.final_layernorm.weight": _f32(lm["ln_f"]["scale"]),
+    }
+    if not cfg.tie_embeddings:
+        sd["lm_head.weight"] = _f32(lm["lm_head"]["kernel"]).T
+    for i in range(cfg.n_layers):
+        b, p = lm[f"block_{i}"], f"model.layers.{i}."
+        for n, hf_n in _FALCON_NORMS:
+            sd[p + f"{hf_n}.weight"] = _f32(b[n]["scale"])
+        for n in _FALCON_ATTN:
+            sd[p + f"self_attn.{n}.weight"] = _f32(b["attn"][n]["kernel"]).T
+        for n in _GLU:
+            sd[p + f"feed_forward.{n}.weight"] = _f32(b["mlp"][n]["kernel"]).T
+        ssm = b["ssm"]
+        sd[p + "mamba.in_proj.weight"] = _f32(ssm["in_proj"]["kernel"]).T
+        sd[p + "mamba.out_proj.weight"] = _f32(ssm["out_proj"]["kernel"]).T
+        sd[p + "mamba.conv1d.weight"] = _f32(ssm["conv1d"]["kernel"]).T[:, None, :]
+        sd[p + "mamba.conv1d.bias"] = _f32(ssm["conv1d"]["bias"])
+        sd[p + "mamba.norm.weight"] = _f32(ssm["norm"]["scale"])
+        for leaf, hf_n in _FALCON_HEAD:
+            sd[p + f"mamba.{hf_n}"] = _f32(ssm[leaf[0]][leaf[1]])
     return sd
 
 
@@ -1277,6 +1386,7 @@ _EXPORTERS: Dict[str, Callable] = {
     "gpt_bigcode": _export_gpt_bigcode,
     "lfm2_moe": _export_lfm2_moe,
     "pangu_ultra_moe": _export_pangu_ultra_moe,
+    "falcon_h1": _export_falcon_h1,
 }
 
 
@@ -1287,6 +1397,8 @@ def infer_family(cfg) -> str:
         return "t5"
     if getattr(cfg, "has_conv_layers", False):
         return "lfm2_moe"
+    if getattr(cfg, "has_ssm_layers", False):
+        return "falcon_h1"
     if getattr(cfg, "has_linear_layers", False):
         return "ling_flash" if cfg.has_latent_layers else "solar_open2"
     if getattr(cfg, "has_latent_layers", False):
@@ -1416,6 +1528,25 @@ def config_to_hf(cfg: TransformerConfig, family: str = None) -> Dict:
             first_k_dense_replace=0, n_routed_experts=cfg.moe_experts, n_shared_experts=1,
             num_experts_per_tok=cfg.moe_top_k, moe_intermediate_size=cfg.expert_d_ff, norm_topk_prob=True,
             routed_scaling_factor=cfg.moe_routed_scale, tie_word_embeddings=cfg.tie_embeddings,
+        )
+    if family == "falcon_h1":
+        m = cfg.multipliers
+        return dict(
+            model_type="falcon_h1", architectures=["FalconH1ForCausalLM"], vocab_size=cfg.vocab_size,
+            hidden_size=cfg.d_model, intermediate_size=cfg.d_ff, num_hidden_layers=cfg.n_layers,
+            num_attention_heads=cfg.n_heads, num_key_value_heads=cfg.kv_heads, head_dim=cfg.head_dim,
+            max_position_embeddings=cfg.max_seq_len, rms_norm_eps=cfg.layer_norm_epsilon,
+            rope_theta=cfg.rope_theta, rope_scaling=None, hidden_act="silu", attention_bias=False,
+            mlp_bias=False, projectors_bias=False, attn_layer_indices=None,
+            tie_word_embeddings=cfg.tie_embeddings,
+            mamba_n_heads=cfg.ssm_heads, mamba_d_head=cfg.ssm_head_dim, mamba_d_ssm=cfg.ssm_heads * cfg.ssm_head_dim,
+            mamba_d_state=cfg.ssm_state, mamba_n_groups=cfg.ssm_groups, mamba_d_conv=cfg.ssm_conv_kernel,
+            mamba_chunk_size=cfg.ssm_chunk, mamba_conv_bias=True, mamba_proj_bias=False, mamba_rms_norm=True,
+            mamba_norm_before_gate=False, mamba_use_mlp=True,
+            embedding_multiplier=m.embedding, lm_head_multiplier=m.lm_head,
+            attention_in_multiplier=m.attention_in, attention_out_multiplier=m.attention_out,
+            key_multiplier=m.key, ssm_in_multiplier=m.ssm_in, ssm_out_multiplier=m.ssm_out,
+            ssm_multipliers=list(m.ssm), mlp_multipliers=list(m.mlp),
         )
     if family == "pangu_ultra_moe":
         return dict(

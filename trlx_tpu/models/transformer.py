@@ -40,9 +40,35 @@ class RopeSpec:
     attention_factor: Optional[float] = None  # on cos and sin; None: 0.1 ln(factor) + 1 under YaRN
 
 
-ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention", "latent_attention")
-# operators that keep a state a ROW (a slot), not planes a token
-SLOT_STATE_KINDS = ("conv", "linear_attention")
+@dataclass(frozen=True)
+class Multipliers:
+    """Forward multipliers (Falcon-H1's muP form), applied in the forward
+    where the published model applies them and never folded into a weight
+    (equal in exact arithmetic, not in bfloat16). All 1 and empty for every
+    other family, which then multiplies nothing."""
+
+    embedding: float = 1.0  # on the token embedding
+    lm_head: float = 1.0  # on the logits
+    attention_in: float = 1.0  # on the normed input of q, k and v
+    attention_out: float = 1.0  # on the attention branch's output
+    key: float = 1.0  # on k, before it is rotated
+    ssm_in: float = 1.0  # on the normed input of the SSM's `in_proj`
+    ssm_out: float = 1.0  # on the SSM branch's output
+    ssm: Tuple[float, ...] = ()  # on `in_proj`'s z, x, B, C and dt columns
+    mlp: Tuple[float, ...] = ()  # on the gate's pre-activation, on the down projection's output
+
+    def __post_init__(self):
+        object.__setattr__(self, "ssm", tuple(float(m) for m in self.ssm))
+        object.__setattr__(self, "mlp", tuple(float(m) for m in self.mlp))
+        if len(self.ssm) not in (0, 5) or len(self.mlp) not in (0, 2):
+            raise ValueError(f"multipliers: ssm names {len(self.ssm)} of 5 column groups, mlp {len(self.mlp)} of 2")
+
+
+# A layer's operator. What a kind keeps between steps (planes a token, arrays a
+# slot, or both) is `TransformerConfig.kind_keeps`'s to say, and every predicate
+# about caches asks that: a kind is in no list of "attention" or "state" kinds.
+LAYER_KINDS = ("attention", "full_attention", "sliding_attention", "latent_attention", "conv", "linear_attention",
+               "ssm_attention")
 
 
 @dataclass(frozen=True)
@@ -212,17 +238,36 @@ class TransformerConfig:
     kda_decay: str = "bounded"
     kda_gate_rank: int = 0
     kda_beta_max: float = 1.0
+    # A Mamba-2 mixer beside attention in ONE block (`layer_types` kind
+    # "ssm_attention", Falcon-H1: `Mamba2Mixer` and `Attention` read the same
+    # normed input and their outputs are summed into one residual add).
+    # `ssm_heads` heads of `ssm_head_dim` (together d_ssm), a state of
+    # `ssm_state` a head channel, B and C in `ssm_groups` groups, a causal
+    # depthwise convolution of `ssm_conv_kernel` taps with a bias over x, B and
+    # C, chunks of `ssm_chunk` positions in the chunked form. Such a layer
+    # keeps K and V by head a TOKEN and a matrix a head (`ssm_state_dtype`)
+    # and the convolution's last inputs a SLOT (`kind_keeps`).
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
+    ssm_state_dtype: Any = jnp.float32
+    multipliers: Multipliers = Multipliers()
 
     def __post_init__(self):
         if self.q_lora_rank is None:
             object.__setattr__(self, "q_lora_rank", 0)
+        if not isinstance(self.multipliers, Multipliers):
+            object.__setattr__(self, "multipliers", Multipliers(**dict(self.multipliers or {})))
         if self.layer_types:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if len(self.layer_types) != self.n_layers:
                 raise ValueError(
                     f"layer_types names {len(self.layer_types)} layers, n_layers is {self.n_layers}"
                 )
-            unknown = set(self.layer_types) - {*SLOT_STATE_KINDS, *ATTENTION_KINDS}
+            unknown = set(self.layer_types) - set(LAYER_KINDS)
             if unknown:
                 raise ValueError(f"unknown layer_types {sorted(unknown)}")
         if self.layer_heads:
@@ -267,6 +312,19 @@ class TransformerConfig:
             ) if on]
             if unsupported:
                 raise NotImplementedError(f"linear_attention layers with {', '.join(unsupported)} are not supported")
+        if self.has_ssm_layers:
+            if min(self.ssm_heads, self.ssm_head_dim, self.ssm_state, self.ssm_groups, self.ssm_chunk) <= 0 \
+                    or self.ssm_heads % self.ssm_groups or self.ssm_conv_kernel < 2:
+                raise ValueError(
+                    f"ssm_attention layers need ssm_heads {self.ssm_heads}, ssm_head_dim {self.ssm_head_dim}, "
+                    f"ssm_state {self.ssm_state} and ssm_chunk {self.ssm_chunk} > 0, heads in whole groups of "
+                    f"ssm_groups {self.ssm_groups}, and ssm_conv_kernel {self.ssm_conv_kernel} >= 2")
+            unsupported = [what for on, what in (
+                (self.lora_rank > 0, "lora_rank"), (self.prefix_tokens > 0, "prefix_tokens"),
+                (self.prompt_tokens > 0, "prompt_tokens"), (self.attn_impl == "ring", "attn_impl='ring'"),
+            ) if on]
+            if unsupported:
+                raise NotImplementedError(f"ssm_attention layers with {', '.join(unsupported)} are not supported")
         if bool(self.moe_n_group) != bool(self.moe_topk_group) or self.moe_n_group < 0:
             raise ValueError(f"moe_n_group {self.moe_n_group} and moe_topk_group {self.moe_topk_group} go together")
         if self.moe_n_group:
@@ -374,11 +432,21 @@ class TransformerConfig:
         return "linear_attention" in self.layer_types
 
     @property
+    def has_ssm_layers(self) -> bool:
+        return "ssm_attention" in self.layer_types
+
+    @property
+    def slot_state_kinds(self) -> Tuple[str, ...]:
+        """The kinds of layer that keep arrays a slot (`LayerKeeps.slot`), in
+        order of first use."""
+        return tuple(k for k in dict.fromkeys(self.layer_types) if self.kind_keeps(k).slot)
+
+    @property
     def has_slot_state(self) -> bool:
         """Whether some layer keeps a state a row (`LayerKeeps.slot`): what a
         block table cannot share, a mask bit cannot roll back and a pool must
         hold beside its arena."""
-        return any(k in SLOT_STATE_KINDS for k in self.layer_types)
+        return bool(self.slot_state_kinds)
 
     @property
     def kda_width(self) -> int:
@@ -386,18 +454,33 @@ class TransformerConfig:
         return 3 * self.n_heads * self.head_dim
 
     @property
+    def ssm_width(self) -> int:
+        """Channels of an SSM mixer's one short convolution: x, B and C side by side."""
+        return self.ssm_heads * self.ssm_head_dim + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def state_chunk(self) -> int:
+        """Positions a step of the chunked recurrence of a prompt's prefill takes
+        (a KDA layer's, an SSM mixer's), 0 where no layer runs one."""
+        from trlx_tpu.ops.linear_attention import CHUNK
+
+        return self.ssm_chunk if self.has_ssm_layers else CHUNK if self.has_linear_layers else 0
+
+    @property
     def latent_width(self) -> int:
         """Values a latent layer caches a token: the normed latent and the
         rotated key all heads share."""
         return self.kv_lora_rank + self.qk_rope_head_dim
 
-    def layer_keeps(self, i: int) -> LayerKeeps:
-        """What layer i keeps: K and V by head a token for an attention
-        layer, the one latent plane a token for a latent one; the last
-        `conv_kernel - 1` inputs a row for a `conv` layer; a matrix a head
+    def kind_keeps(self, op: str) -> LayerKeeps:
+        """What a layer of kind `op` keeps: K and V by head a token for an
+        attention layer, the one latent plane a token for a latent one; the
+        last `conv_kernel - 1` inputs a row for a `conv` layer; a matrix a head
         (`kda_state_dtype`) and the three convolutions' last inputs a row for
-        a `linear_attention` one."""
-        op = self.layer_op(i)
+        a `linear_attention` one; for an `ssm_attention` one BOTH: K and V by
+        head a token, and a matrix a head (`ssm_state_dtype`, state width
+        before head width: `ops/ssd.py`'s layout) and the convolution's last
+        inputs a row."""
         if op == "conv":
             return LayerKeeps(slot=(("conv", (self.conv_kernel - 1, self.d_model), None),))
         if op == "linear_attention":
@@ -406,7 +489,15 @@ class TransformerConfig:
                 ("tails", (self.conv_kernel - 1, self.kda_width), None)))
         if op == "latent_attention":
             return LayerKeeps(token=(("latent", (self.latent_width,)),))
-        return LayerKeeps(token=(("k", (self.kv_heads, self.head_dim)), ("v", (self.kv_heads, self.head_dim))))
+        kv = (("k", (self.kv_heads, self.head_dim)), ("v", (self.kv_heads, self.head_dim)))
+        if op == "ssm_attention":
+            return LayerKeeps(token=kv, slot=(
+                ("state", (self.ssm_heads, self.ssm_state, self.ssm_head_dim), self.ssm_state_dtype),
+                ("tails", (self.ssm_conv_kernel - 1, self.ssm_width), None)))
+        return LayerKeeps(token=kv)
+
+    def layer_keeps(self, i: int) -> LayerKeeps:
+        return self.kind_keeps(self.layer_op(i))
 
     def cache_planes(self, i: int) -> Tuple[int, ...]:
         """What a token caches in layer i, one width a plane (`layer_keeps`)."""
@@ -443,10 +534,11 @@ class TransformerConfig:
 
     @property
     def attention_kinds(self) -> Tuple[str, ...]:
-        """The kinds of attention layer the model names beside plain
-        "attention", in order of first use; () for every family whose
-        attention layers are all alike, which then builds one bias."""
-        kinds = tuple(dict.fromkeys(k for k in self.layer_types if k in ATTENTION_KINDS))
+        """The kinds of layer that keep planes a token (`LayerKeeps.token`: what
+        an attention reads, so what needs a bias) where the model names one
+        beside plain "attention", in order of first use; () for every family
+        whose attention layers are all alike, which then builds one bias."""
+        kinds = tuple(k for k in dict.fromkeys(self.layer_types) if self.kind_keeps(k).token)
         return kinds if set(kinds) - {"attention"} else ()
 
     def window_of(self, kind: Optional[str]) -> Optional[int]:
@@ -679,6 +771,8 @@ class Attention(nn.Module):
         q = dense(nh * hd, "q_proj")(h).reshape(b, t, nh, hd)
         k = dense(nkv * hd, "k_proj")(h).reshape(b, t, nkv, hd)
         v = dense(nkv * hd, "v_proj")(h).reshape(b, t, nkv, hd)
+        if cfg.multipliers.key != 1.0:
+            k = k * cfg.multipliers.key
 
         if cfg.qk_norm:
             head_norm = lambda name: nn.RMSNorm(
@@ -1025,9 +1119,14 @@ class MLP(nn.Module):
         cfg = self.cfg
         dense = lambda feats, name: lora_dense(self, cfg, feats, name, cfg.use_bias)
         act = activation_fn(cfg)
+        on_gate, on_out = cfg.multipliers.mlp or (1.0, 1.0)  # on the gate's pre-activation, on the output
+        if cfg.multipliers.mlp and not cfg.glu:
+            raise NotImplementedError("multipliers.mlp are a gated MLP's (glu=True)")
         if cfg.glu:
-            gated = act(dense(cfg.d_ff, "gate_proj")(h)) * dense(cfg.d_ff, "up_proj")(h)
-            return dense(cfg.d_model, "down_proj")(gated)
+            gate = dense(cfg.d_ff, "gate_proj")(h)
+            gated = act(gate if on_gate == 1.0 else gate * on_gate) * dense(cfg.d_ff, "up_proj")(h)
+            out = dense(cfg.d_model, "down_proj")(gated)
+            return out if on_out == 1.0 else out * on_out
         return dense(cfg.d_model, "down_proj")(act(dense(cfg.d_ff, "up_proj")(h)))
 
 
@@ -1242,6 +1341,107 @@ class KimiDeltaAttention(nn.Module):
         return dense(d, "o_proj")(out), new_cache
 
 
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 (SSD, `ops/ssd.py`) mixer of an `ssm_attention` block, as
+    Falcon-H1 publishes it. With u the block's normed input, H = `ssm_heads`
+    heads of P = `ssm_head_dim` (d_ssm = H P), a state of N = `ssm_state`, g =
+    `ssm_groups` groups, m = `cfg.multipliers` and no bias on a dense product:
+
+        [z ; x ; B ; C ; dt] = ((u * m.ssm_in) W_in) * m.ssm       W_in: d -> d_ssm + d_ssm + g N + g N + H;
+                                                                   m.ssm's five factors spread over the five column groups
+        [x ; B ; C] = SiLU(conv([x ; B ; C]) + b_conv)             depthwise, causal, `ssm_conv_kernel` taps
+        dt = softplus(dt + dt_bias),  A = -exp(a_log)              a head, float32, no clamp
+        h_t = exp(dt_t A) h_{t-1} + B_t (dt_t x_t)^T               h in R^{N x P} a head; head i reads group i // (H / g)
+        y_t = h_t^T C_t + D x_t                                    D a head
+        y = RMSNorm_grouped(y * SiLU(z))                           the gate FIRST, then a norm over each of g groups
+                                                                   of d_ssm / g channels, one scale d_ssm wide
+        out = (y W_out) * m.ssm_out
+
+    A position whose mask bit is 0 is the identity on what the layer keeps a
+    slot: its input is zeroed before `W_in` (so its convolution input is 0),
+    its dt is 0 (decay 1, write 0), and the tails end at the row's last real
+    position (`tail_inputs`). The mixer keeps `state` [b, H, N, P] and `tails`
+    [b, taps - 1, d_ssm + 2 g N] (the convolution's last inputs) a ROW; the
+    block's K and V are `Attention`'s. A block of positions goes through the
+    chunked form from the row's state; one position a row with a kernel asked
+    for (`attn_kernel` "pallas" | "interpret") through `ssd_decode`, in place."""
+
+    cfg: TransformerConfig
+
+    @nn.compact
+    def __call__(self, u, layer_cache=None, token_mask=None, attn_kernel=None):
+        from trlx_tpu.ops import ssd
+
+        cfg, m = self.cfg, self.cfg.multipliers
+        b, t, d = u.shape
+        nh, hd, N, g, taps = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.ssm_conv_kernel
+        d_ssm, width = nh * hd, cfg.ssm_width
+        dense = lambda feats, name: nn.Dense(
+            feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
+        head = lambda cls, name: cls((nh,), cfg.param_dtype, name=name)().astype(jnp.float32)
+        valid = None if token_mask is None else (token_mask > 0)
+        if valid is not None:
+            u = u * valid[..., None].astype(u.dtype)
+        if m.ssm_in != 1.0:
+            u = u * m.ssm_in
+        p = dense(2 * d_ssm + 2 * g * N + nh, "in_proj")(u)
+        if m.ssm:
+            p = p * jnp.asarray(np.repeat(np.asarray(m.ssm, np.float32), (d_ssm, d_ssm, g * N, g * N, nh)), p.dtype)
+        z, xbc, dt = p[..., :d_ssm], p[..., d_ssm:d_ssm + width], p[..., d_ssm + width:]
+        # [taps, channels], the fan-in leading like every `kernel`. From here to the gate
+        # everything is float32; the tails keep the product's own type
+        conv = _ConvLeaves((taps, width), cfg.param_dtype, name="conv1d")
+        w, b_conv = (leaf.astype(jnp.float32) for leaf in conv())
+        history = (jnp.zeros((b, taps - 1, width), xbc.dtype) if layer_cache is None
+                   else layer_cache["tails"].astype(xbc.dtype))
+        padded = jnp.concatenate([history, xbc], axis=1)
+        mixed = jax.nn.silu(b_conv + sum(
+            w[j] * jax.lax.dynamic_slice_in_dim(padded, j, t, axis=1).astype(jnp.float32) for j in range(taps)))
+        x = mixed[..., :d_ssm].reshape(b, t, nh, hd)
+        B, C = (mixed[..., lo:lo + g * N].reshape(b, t, g, N) for lo in (d_ssm, d_ssm + g * N))
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + head(_Bias, "dt_bias"))
+        if valid is not None:
+            dt = dt * valid[..., None]
+        A = -jnp.exp(head(_Bias, "a_log"))
+
+        state = None if layer_cache is None else layer_cache["state"]
+        if state is not None and t == 1:
+            live = jnp.ones((b,), jnp.int32) if valid is None else valid[:, 0].astype(jnp.int32)
+            y, new_state = ssd.ssd_decode_step(
+                state.astype(jnp.float32), x[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], live,
+                attn_kernel if attn_kernel in ("pallas", "interpret") else None)
+            y = y[:, None]
+        else:
+            y, new_state = ssd.ssd_chunked(x, dt, A, B, C, state, chunk=cfg.ssm_chunk)
+        new_cache = None
+        if layer_cache is not None:
+            new_cache = {"state": new_state.astype(state.dtype),
+                         "tails": tail_inputs(padded, valid, taps).astype(layer_cache["tails"].dtype)}
+        y = (y + head(_Scale, "d")[:, None] * x).reshape(b, t, d_ssm) * jax.nn.silu(z.astype(jnp.float32))
+        y = y.reshape(b, t, g, d_ssm // g)
+        y = (y * jax.lax.rsqrt((y * y).mean(-1, keepdims=True) + cfg.layer_norm_epsilon)).reshape(b, t, d_ssm)
+        y = y * _Scale((d_ssm,), cfg.param_dtype, name="norm")().astype(jnp.float32)
+        out = dense(d, "out_proj")(y.astype(cfg.dtype))
+        return (out if m.ssm_out == 1.0 else out * m.ssm_out), new_cache
+
+
+class _ConvLeaves(nn.Module):
+    """A depthwise convolution's `kernel` [taps, channels] and `bias` [channels]."""
+
+    shape: Tuple[int, ...]
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self):
+        return (self.param("kernel", _fan_in_normal, self.shape, self.param_dtype),
+                self.param("bias", nn.initializers.zeros, self.shape[1:], self.param_dtype))
+
+
+def _fan_in_normal(key, shape, dtype):
+    """Normal, 1 / sqrt(fan-in), the fan-in the leading dimension."""
+    return (jax.random.normal(key, shape, jnp.float32) / np.sqrt(shape[0])).astype(dtype)
+
+
 class _Kernel(nn.Module):
     """One `kernel` leaf under its own name: the expert stacks keep the
     leaf name (and the fan-in-first shape) that every weight rule knows."""
@@ -1251,9 +1451,7 @@ class _Kernel(nn.Module):
 
     @nn.compact
     def __call__(self):
-        init = lambda key, shape, dtype: (
-            jax.random.normal(key, shape, jnp.float32) / np.sqrt(shape[0])).astype(dtype)
-        return self.param("kernel", init, self.shape, self.param_dtype)
+        return self.param("kernel", _fan_in_normal, self.shape, self.param_dtype)
 
 
 class _Bias(nn.Module):
@@ -1370,10 +1568,22 @@ class Block(nn.Module):
                 attn_bias = attn_bias.get(self.op_kind)
             attn_cls = {"latent_attention": LatentAttention,
                         "linear_attention": KimiDeltaAttention}.get(self.op_kind, Attention)
+            m = cfg.multipliers
             attn_out, new_cache = attn_cls(cfg, kind=self.op_kind, n_heads=self.n_heads, name="attn")(
-                h_ln, attn_bias, positions, layer_cache, cache_index, attn_mask, use_prefix,
+                h_ln if m.attention_in == 1.0 else h_ln * m.attention_in,
+                attn_bias, positions, layer_cache, cache_index, attn_mask, use_prefix,
                 attn_kernel,
             )
+            if m.attention_out != 1.0:
+                attn_out = attn_out * m.attention_out
+            if self.op_kind == "ssm_attention":
+                # both mixers read `ln_attn`'s output; their sum is the one residual add. The
+                # layer's cache holds K/V planes (and a table) AND the slot's arrays: each
+                # mixer moves its own
+                ssm_out, slot = Mamba2Mixer(cfg, name="ssm")(h_ln, layer_cache, attn_mask, attn_kernel)
+                attn_out = attn_out + ssm_out
+                if layer_cache is not None:
+                    new_cache = {**new_cache, **slot}
         # the sandwich: a norm on what each operator gives, before its residual add
         post = (lambda name, x: make_norm(cfg, name)(x)) if cfg.sandwich_norm else (lambda name, x: x)
         if self.ffn_kind == "sparse_moe":
@@ -1550,6 +1760,8 @@ class TransformerLM(nn.Module):
 
     def embed(self, tokens, positions):
         h = self.embed_tokens(tokens)
+        if self.cfg.multipliers.embedding != 1.0:
+            h = h * self.cfg.multipliers.embedding
         if self.cfg.pos_embed == "learned":
             h = h + self.embed_pos(positions + self.cfg.pos_offset)
         if self.cfg.embed_ln:
@@ -1564,6 +1776,8 @@ class TransformerLM(nn.Module):
             logits = self.embed_tokens.attend(h_final)
         else:
             logits = self.lm_head(h_final)
+        if self.cfg.multipliers.lm_head != 1.0:
+            logits = logits * self.cfg.multipliers.lm_head
         return logits, h_final
 
     def _default_positions(self, tokens_or_h, attn_mask):
@@ -1989,6 +2203,14 @@ def live_width_index(first, columns: int):
     return sum((columns - w > first) * 1 for w in live_widths(columns)[:-1])
 
 
+def slot_state_of(cfg) -> str:
+    """What a refusal over slot state names, from what the layers keep
+    (`LayerKeeps.slot`): "conv / linear_attention layers keep conv, state, tails a slot"."""
+    kinds = getattr(cfg, "slot_state_kinds", ())
+    names = dict.fromkeys(name for kind in kinds for name in cfg.kind_keeps(kind).slot_names)
+    return f"{' / '.join(kinds)} layers keep {', '.join(names)} a slot"
+
+
 def init_kv_cache(cfg: TransformerConfig, batch_size: int, max_len: int, dtype=None):
     """Allocate an empty functional cache from what each layer keeps
     (`cfg.layer_keeps`): its planes a token, `[batch, max_len, *shape]`, and
@@ -2030,7 +2252,7 @@ def init_paged_kv_arena(
         )
     if cfg.has_slot_state and (num_slots <= 0 or jnp.dtype(dtype) == jnp.int8):
         raise NotImplementedError(
-            "a paged pool over slot state (conv / linear_attention layers) needs its number of slots "
+            f"a paged pool over slot state ({slot_state_of(cfg)}) needs its number of slots "
             "and a floating cache type (an int8 arena would hold the rows' state in int8 too)")
     from trlx_tpu.ops.paged_attention import init_paged_latent_layer, init_paged_layer
 
@@ -2051,6 +2273,13 @@ def init_paged_kv_arena(
 # ---------------------------------------------------------------------------
 # Model family presets
 # ---------------------------------------------------------------------------
+
+# Falcon-H1-34B-Instruct's published forward multipliers (its test preset keeps them)
+_FALCON_H1_34B_MULTIPLIERS = Multipliers(
+    embedding=5.656854249492381, lm_head=0.0078125, attention_in=1.0, attention_out=0.0375,
+    key=0.011048543456039804, ssm_in=0.25, ssm_out=0.08838834764831845,
+    ssm=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5, 0.3535533905932738),
+    mlp=(0.1767766952966369, 0.011160714285714284))
 
 PRESETS: Dict[str, Dict[str, Any]] = {
     # tiny from-scratch models for tests/benchmarks ("random:" prefix)
@@ -2276,6 +2505,35 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         attn_gate="elementwise", conv_kernel=4, kda_decay="softplus", kda_gate_rank=16, kda_beta_max=2.0,
         moe_experts=16, moe_top_k=2, moe_d_ff=32, moe_dense_layers=0, moe_router="sigmoid",
         moe_shared_d_ff=32, moe_routed_scale=1.0,
+    ),
+    # Falcon-H1-34B-Instruct (tiiuae, `falcon_h1`; 33.6B parameters, dense): in
+    # EVERY block a Mamba-2 mixer (32 heads of 128, a state of 256, B and C in 2
+    # groups, a convolution of 4 taps with a bias) beside GQA attention (20 query
+    # heads over 4 K/V heads of 128: a query width of 2,560 against d_model 5,120,
+    # a group of 5; rotate-half RoPE, theta 1e11), their outputs summed into one
+    # residual add; a SwiGLU of 21,504; muP forward multipliers on the embedding,
+    # both branches' inputs and outputs, the keys, the SSM projection's five
+    # column groups, the MLP's gate and output, and the logits. The published
+    # sizes; a cut in depth arrives as model_extra_configs.
+    "falcon-h1-34b": dict(
+        d_model=5120, n_layers=72, n_heads=20, n_kv_heads=4, head_width=128, d_ff=21504, max_seq_len=262144,
+        pos_embed="rope", rope_theta=1e11, norm="rmsnorm", layer_norm_epsilon=1e-5,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=("ssm_attention",) * 72,
+        ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2, ssm_conv_kernel=4, ssm_chunk=128,
+        multipliers=_FALCON_H1_34B_MULTIPLIERS,
+    ),
+    # the same stack at test size: two blocks, 10 query heads over 2 K/V heads of 8
+    # (a group of 5, a query width of 80 against d_model 64), 4 SSM heads of 8 (d_ssm
+    # 32) with a state of 16 in 2 groups, chunks of 16 so that a test's prompt crosses
+    # chunk edges, the published multipliers
+    "falcon-h1-tiny": dict(
+        d_model=64, n_layers=2, n_heads=10, n_kv_heads=2, head_width=8, d_ff=128, max_seq_len=256,
+        pos_embed="rope", rope_theta=1e11, norm="rmsnorm", layer_norm_epsilon=1e-5,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=("ssm_attention",) * 2,
+        ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_conv_kernel=4, ssm_chunk=16,
+        multipliers=_FALCON_H1_34B_MULTIPLIERS,
     ),
     # Mixture-of-experts (beyond the reference): experts shard over `tensor`
     "moe-tiny": dict(

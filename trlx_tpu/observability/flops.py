@@ -75,6 +75,13 @@ def layer_matmul_flops(model_cfg, i: int) -> float:
         op = 4 * d * heads * model_cfg.head_dim + 4 * d * model_cfg.kv_heads * model_cfg.head_dim
         if getattr(model_cfg, "attn_gate", "none") == "per_head":
             op += 2 * d * heads
+        if model_cfg.layer_op(i) == "ssm_attention":
+            # beside the attention: the SSM's in and out projections, its convolution's
+            # taps, and the recurrence itself: 5 N P a head a token (decay, write, read:
+            # `ops/ssd.ssd_step`), whatever the context
+            c, d_ssm = model_cfg, model_cfg.ssm_heads * model_cfg.ssm_head_dim
+            op += 2 * d * (d_ssm + c.ssm_width + c.ssm_heads) + 2 * d_ssm * d \
+                + 2 * c.ssm_conv_kernel * c.ssm_width + 5 * c.ssm_heads * c.ssm_state * c.ssm_head_dim
     mats = 3 if model_cfg.glu else 2
     if model_cfg.layer_ffn(i) == "dense":
         return op + 2 * mats * d * model_cfg.d_ff

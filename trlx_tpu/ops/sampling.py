@@ -276,10 +276,11 @@ def make_generate_fn(
                 "rollback)"
             )
         if getattr(model_cfg, "has_slot_state", False):
+            from trlx_tpu.models.transformer import slot_state_of
+
             raise NotImplementedError(
-                "speculative decode with a convolution state or a recurrent matrix "
-                "(layer_types 'conv', 'linear_attention') is not supported: rejected "
-                "drafts roll back by clearing mask bits, which does not undo a state a row"
+                f"speculative decode over slot state ({slot_state_of(model_cfg)}) is not supported: "
+                "rejected drafts roll back by clearing mask bits, which does not undo a state a row"
             )
         if getattr(model_cfg, "moe_experts", 0) > 0:
             raise NotImplementedError(

@@ -278,9 +278,10 @@ def traced_score(trainer, device, rows, width, hands_out):
     traced over a chunk of the cell for one described chip."""
     programs, ljit = {}, type(trainer)._ljit
     trainer._ljit = lambda fn, name, **kw: programs.setdefault(name, ljit(trainer, fn, name, **kw))
-    trainer._score_hands_out_trunk_state = lambda: hands_out
+    trainer._score_hands_out_trunk_state = lambda program=None: hands_out
     trainer._build_score_fn()
     assert trainer._score_with_trunk_state is hands_out and list(programs) == ["score"]
     assert (trainer._score_fn is programs["score"]) is not hands_out
     return programs["score"].trace(*abstract(
-        (*ppo_cell_params(trainer, with_ref=True), S((rows, width), I32)), SingleDeviceSharding(device)))
+        (*ppo_cell_params(trainer, with_ref=True), S((rows, width), I32)), SingleDeviceSharding(device)),
+        with_trunk_state=hands_out)

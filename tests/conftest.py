@@ -26,7 +26,7 @@ jax.config.update("jax_platforms", "cpu")
 # is not listed keeps its alphabetical place behind these; one that takes
 # over a minute belongs here, and one that passes 250 s is split (ROADMAP D10).
 LONGEST_FIRST = [
-    "test_trunk_cache.py",                 # 211 s
+    "test_trunk_cache.py",                 # 211 s (206 s alone with PR 49's 69 cases)
     "test_lfm2_compile_tpu.py",            # 192 s
     "test_ling_flash.py",                  # 169 s
     "test_pangu_mla.py",                   # 165 s
@@ -36,6 +36,9 @@ LONGEST_FIRST = [
     "test_trainers.py",                    # 133 s
     "test_engine_step_ahead.py",           # 129 s
     "test_state_cells_compile_tpu.py",     # 139 s (98 s before PR 48's chat cell)
+    # (PR 49: pythia's two `score` programs at [16, 1024] compile for 4-7 min
+    # each at any depth and took this file to 1,128 s of a 1,245 s run: that
+    # case is marked slow and its lowering stays)
     "test_ppo_cells_compile_tpu.py",       # 126 s
     "test_onef1b_trainers.py",             # 120 s
     "test_peft.py",                        # 103 s

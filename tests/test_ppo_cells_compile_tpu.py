@@ -1,8 +1,8 @@
 """The dense PPO cells' trainer programs, compiled for one v5e chip with no
-chip: `pythia-1.4b.ppo-hh`'s trunk-cache fill and the train step resumed
-from it, its one `generate` program that follows a chunk's longest prompt
-(and `gpt2-xl.ppo-sentiments`' `generate`, which is too narrow to), and
-`gpt2-xl.ppo-sentiments`' score program that hands out the trunk state,
+chip: `pythia-1.4b.ppo-hh`'s trunk-cache fill (the fallback since PR 49) and
+the train step resumed from it, its one `generate` program that follows a
+chunk's longest prompt (and `gpt2-xl.ppo-sentiments`' `generate`, which is
+too narrow to), and both cells' score programs that hand out the trunk state,
 traced from where `PPOTrainer` makes them at the cells' widths
 (`aot_tpu.ppo_cell_trainer`). `lfm2-8b-a1b.ppo-hh`'s are in
 `test_lfm2_compile_tpu.py`.
@@ -179,3 +179,49 @@ def test_gpt2_xl_score_keeps_its_weight_prefetches_with_the_trunk_state(v5e, pal
 
     five, six = prefetched_weights(False), prefetched_weights(True)
     assert five >= 4 * 6 and six >= five, (five, six)
+
+
+def _pythia_score(tmp_path, device, hands_out):
+    """(what it returns, its text lowered for the TPU) of `pythia-1.4b.ppo-hh`'s
+    score program over one chunk of 16 x 1,024, five outputs or six."""
+    trainer = ppo_cell_trainer(tmp_path / str(hands_out), "pythia-1.4b", PPO_HH,
+                               batch_size=8, num_rollouts=64, chunk_size=16, max_new=128)
+    traced = traced_score(trainer, device, 16, 1024, hands_out)
+    lowered = traced.lower(lowering_platforms=("tpu",))
+    assert "module @jit_score" in lowered.as_text()[:200]
+    return traced.out_info, lowered
+
+
+def test_pythia_score_hands_out_a_chunks_trunk_state_in_the_one_program_named_score(v5e, pallas_mode, tmp_path):
+    """`pythia-1.4b.ppo-hh` scores a chunk of 16 x 1,024; since PR 49 the
+    rule finds room for the collection's four states beside a generation in
+    flight and the cell's `jit_score` has a sixth output, the chunk's state
+    (67 MB, bfloat16, the fill's). One program named `score` either way, the
+    same five results in front and the same kernel calls in it, and the state
+    of a several-chunk collection leaves plainly: no barrier, which is what
+    cost this program its weight prefetches on the chip (PERF.md section 6,
+    PR 49). Lowered for the TPU, not compiled: the compile is the test below."""
+    (five_out, five), (six_out, six) = (_pythia_score(tmp_path, v5e[0], h) for h in (False, True))
+    assert len(five_out) == 5 and len(six_out) == 6
+    assert (six_out[5].shape, six_out[5].dtype) == ((16, 1024, 2048), BF16)
+    assert [(o.shape, o.dtype) for o in six_out[:5]] == [(o.shape, o.dtype) for o in five_out]
+    calls = [text.as_text().count("tpu_custom_call") for text in (five, six)]
+    assert calls[0] == calls[1] == 8, calls  # six flash forwards, two fused CEs
+    assert "optimization_barrier" not in six.as_text()
+
+
+@pytest.mark.slow  # 4-7 min a program at [16, 1024], whatever the depth (ROADMAP S5): 15 min of tier-1's 24
+def test_pythia_score_keeps_its_weight_prefetches_with_the_trunk_state(v5e, pallas_mode, tmp_path):
+    """Both programs compiled for a v5e: the six-output program prefetches the
+    frozen blocks' weights in slices where the five-output program does
+    (`ConcatBitcast` joins, `slice-start` copies: it was gpt2-xl's plan that
+    one more output moved, above), at the cell's widths and the file's depth.
+    (At this depth the six-output program has MORE of both, 29 | 22 joins;
+    the chip's reading, 97 | 97 plainly and 19 behind the barrier, needs all
+    24 layers: my AOT and chip runs, PR 49.)"""
+    five, six = (_pythia_score(tmp_path, v5e[0], h)[1].compile() for h in (False, True))
+    # (the two schedules order the policy's and the reference's calls differently)
+    assert sorted(kernel_names(six)) == sorted(kernel_names(five)) == ["flash_fwd"] * 6 + ["fused_ce_fwd"] * 2
+    for prefetch in ("ConcatBitcast", "slice-start"):
+        n5, n6 = five.as_text().count(prefetch), six.as_text().count(prefetch)
+        assert n6 >= n5 > 0, (prefetch, n5, n6)

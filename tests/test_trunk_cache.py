@@ -12,8 +12,10 @@ Pinned here:
   trainable leaves BITWISE equal to the same cycle run from the whole
   forward: the fill lays a chunk's tokens out as the train batches will,
   so a cached row is column for column what the whole forward computes;
-- where a collection is one chunk the score program hands the state out
-  and nothing is filled (`_score_hands_out_trunk_state`): bitwise the
+- where a collection is one chunk, or the device says that the
+  collection's states fit beside a generation in flight (and where it says
+  nothing: the CPU), the score program hands the state out and nothing is
+  filled (`_score_hands_out_trunk_state`): bitwise the
   whole forward's where the scorer's layout is the train batches' (the
   benchmark's cells), and to the last bits where the loader pads queries
   wider than the scorer saw them (attention over 14 columns and over 32,
@@ -88,6 +90,16 @@ def _whole_forward(trainer):
     """The same trainer with the arbiter saying no: every step runs the
     whole forward."""
     trainer._trunk_cache_available = lambda: False
+    return trainer
+
+
+def _no_room(trainer, free=0):
+    """The same trainer on a device that reports a capacity and `free` bytes
+    of it free: the rule reckons (it sizes the trainer's own `generate`
+    unless a test answers for it) and finds no room for a collection's
+    states beside a generation in flight."""
+    trainer._trunk_cache_budget = V5E // 8
+    trainer._device_free_bytes = lambda: free
     return trainer
 
 
@@ -203,28 +215,36 @@ def _uncached(batch):
 @pytest.mark.parametrize("recipe", [
     dict(chunk_size=8, devices=1),                                  # one chunk a cycle
     dict(chunk_size=8),                                             # rows over 8 devices
-    dict(chunk_size=4, train=dict(batch_size=4), devices=1),        # two chunks: one array from both
+    # two chunks on a device with no room beside a generation: one array from both fills
+    dict(chunk_size=4, train=dict(batch_size=4), devices=1, fills=True),
     dict(chunk_size=8, train=dict(minibatch_size=4), devices=1),    # the accumulation step
     dict(SCORER_LAYOUT, chunk_size=8, devices=1),
     dict(SCORER_LAYOUT, chunk_size=8),
     dict(SCORER_LAYOUT, chunk_size=8, train=dict(minibatch_size=4), devices=1),
+    # several chunks, every one's state handed out: one array from the score program's
+    dict(chunk_size=4, train=dict(batch_size=4), devices=1),
+    dict(SCORER_LAYOUT, chunk_size=2, train=dict(batch_size=4), devices=1),
 ], ids=["one_chunk", "one_chunk_8_devices", "two_chunks", "accumulation",
         "one_chunk_scorer_layout", "one_chunk_8_devices_scorer_layout",
-        "accumulation_scorer_layout"])
+        "accumulation_scorer_layout", "two_chunks_handed_out",
+        "four_chunks_handed_out_scorer_layout"])
 def test_classic_cycle_bitwise_equals_the_whole_forward(tmp_path, monkeypatch, recipe):
     """Two cycles with the cache against two cycles from the whole forward:
     every step's loss and the final trainable leaves bitwise equal; the
     cycle's cache is a device array that nothing copies to the host, and
-    the collator builds row numbers, never a [b, T, d] array. One chunk a
-    collection: the rows come from the score program and no fill is ever
+    the collator builds row numbers, never a [b, T, d] array. Where the rule
+    says yes (one chunk a collection, or several with room for their states)
+    the rows come from the score program and no fill is ever
     built; on one device in the scorer's layout they are the fill's to the
     bit, otherwise to the last bits."""
     recipe = dict(recipe)
-    train = recipe.pop("train", None)
+    train, scored = recipe.pop("train", None), not recipe.pop("fills", False)
     cached = _make_trainer(tmp_path / "cached", train=train, **recipe)
     whole = _whole_forward(_make_trainer(tmp_path / "whole", train=train, **recipe))
     assert cached._trunk_cache_available() and not whole._trunk_cache_available()
-    scored = recipe["chunk_size"] == 8
+    if not scored:
+        _no_room(cached)
+        monkeypatch.setattr(cached, "_generate_held_bytes", lambda: (0, 0))
     assert cached._score_hands_out_trunk_state() is scored
     assert not whole._score_hands_out_trunk_state()
     # the score program's state is the fill's to the bit on one device in
@@ -281,44 +301,88 @@ def _score_args(tr, rows=8, width=8 + MAX_NEW):
             jax.ShapeDtypeStruct((rows, width), jnp.int32))
 
 
-def test_two_chunks_score_with_five_outputs_and_fill_both_at_the_end(tmp_path):
+@pytest.mark.parametrize("room", [False, True], ids=["no_room", "both_handed_out"])
+def test_two_chunks_score_with_five_outputs_and_fill_both_at_the_end(tmp_path, room):
     """A recipe that collects in two chunks dispatches the second chunk's
-    generation before the first is scored: the arbiter says no, the score
-    program is the one a trainer without a trunk cache builds, and both
-    chunks are filled when the collection has ended."""
+    generation before the first is scored. On a device with no room for the
+    collection's states beside it (the rule sizes this trainer's own
+    `generate` and stops there) the arbiter says no, the score program is the one
+    a trainer without a trunk cache builds, and both chunks are filled when
+    the collection has ended; where there is room (here: no capacity known)
+    both chunks' states come from the score program and nothing is filled."""
     tr = _make_trainer(tmp_path / "two", chunk_size=4, train=dict(batch_size=4), devices=1)
     plain = _make_trainer(tmp_path / "plain", chunk_size=4, train=dict(batch_size=4), devices=1,
                           ppo_epochs=1)
-    assert tr._trunk_cache_available() and not tr._score_hands_out_trunk_state()
-    assert not plain._trunk_cache_available()
+    if not room:
+        _no_room(tr)
+    assert tr._trunk_cache_available() and not plain._trunk_cache_available()
     for t in (tr, plain):
         t._build_score_fn()
-        assert not t._score_with_trunk_state
+    assert tr._score_with_trunk_state is room and not plain._score_with_trunk_state
     fills = _count_fills(tr)
-    texts = [t._score_fn.lower(*_score_args(t, rows=4)).as_text() for t in (tr, plain)]
-    assert texts[0] == texts[1] and "jit_score" in texts[0][:200]
+    if room:
+        six = jax.eval_shape(lambda *args: tr._score_fn(*args, trunk_state=True), *_score_args(tr, rows=4))
+        assert len(six) == 6 and six[5].shape == (4, 8 + MAX_NEW, tr.model_cfg.d_model)
+    else:
+        texts = [t._score_fn.lower(*_score_args(t, rows=4)).as_text() for t in (tr, plain)]
+        assert texts[0] == texts[1] and "jit_score" in texts[0][:200]
     assert len(jax.eval_shape(tr._score_fn, *_score_args(tr, rows=4))) == 5
     tr.make_experience(8)
-    assert fills == [(4, 32), (4, 32)] and tr._trunk_scored_rows == 0
+    assert fills == ([] if room else [(4, 32), (4, 32)])
+    assert tr._trunk_scored_rows == (8 if room else 0) and (tr._trunk_cache_fn is None) is room
     assert tr._trunk_cache.shape == (8, 32, tr.model_cfg.d_model)
+    _assert_rows_hold_their_tokens_state(tr)
 
 
-@pytest.mark.parametrize("chunk_size", [8, 4], ids=["handing_out", "five_outputs"])
-def test_score_fn_is_a_five_output_door_under_both_answers(tmp_path, chunk_size):
+def test_the_reckoning_compiles_the_programs_the_collection_runs(tmp_path, caplog):
+    """What the rule sizes are the trainer's own `generate` and six-output
+    `score` at the collection's shapes, by the compiler's analysis of each;
+    the collection then runs those executables and compiles neither again."""
+    import logging
+
+    tr = _make_trainer(tmp_path, chunk_size=4, train=dict(batch_size=4), devices=1)
+    tr._trunk_cache_budget = V5E // 8
+    tr._device_free_bytes = lambda: V5E
+
+    def compiled():
+        names = [r.getMessage().split("jit(")[1].split(")")[0] for r in caplog.records
+                 if "Finished XLA compilation of jit(" in r.getMessage()]
+        caplog.clear()
+        return [n for n in names if n in ("generate", "score")]
+
+    with jax.log_compiles(), caplog.at_level(logging.WARNING, logger="jax"):
+        tr._build_score_fn()
+        assert compiled() == ["generate", "score"] and tr._score_with_trunk_state
+        assert min(tr._generate_held_bytes()) > 0 and compiled() == []
+        tr.make_experience(8)
+        assert compiled() == [] and tr._trunk_scored_rows == 8
+    # a program that cannot be lowered here (`scripts/lowered_text.py` stands one in) is not sized
+    assert tr._program_held_bytes(lambda *args: None) is None
+
+
+@pytest.mark.parametrize("chunk_size, room", [(8, False), (4, False), (4, True)],
+                         ids=["handing_out", "five_outputs", "two_chunks_handing_out"])
+def test_score_fn_is_a_five_output_door_under_both_answers(tmp_path, chunk_size, room):
     """What bench/jobs/ppo.py `compare_outputs`, scripts/lowered_text.py and
     the multi-turn collection unpack. Behind the door stands ONE program
-    named `score`; the sixth result goes to the caller that asks for it."""
+    named `score`; the sixth result goes to the caller that asks for it.
+    (One chunk reads no memory; two chunks hand out where there is room.)"""
     tr = _make_trainer(tmp_path, chunk_size=chunk_size, devices=1)
+    if not room:
+        _no_room(tr)
     names = []
     ljit = tr._ljit
     tr._ljit = lambda fn, name, **kw: (names.append(name), ljit(fn, name, **kw))[1]
     tr._build_score_fn()
-    assert names == ["score"] and tr._score_with_trunk_state is (chunk_size == 8)
+    hands_out = chunk_size == 8 or room
+    # (the rule, where it reckons, sizes the collection's `generate` too)
+    assert [n for n in names if not n.startswith("generate[")] == ["score"]
+    assert tr._score_with_trunk_state is hands_out
     tokens = np.full((8, 8 + MAX_NEW), 65, np.int32)
     out = tr._score_fn(tr.train_params, tr.frozen_params, tr.ref_params, jnp.asarray(tokens))
     logprobs, values, log_ratio, mean_kl, mean_kl_per_token = out
     assert logprobs.shape == values.shape == log_ratio.shape == (8, 8 + MAX_NEW - 1)
-    if chunk_size == 8:
+    if hands_out:
         six = tr._score_fn(tr.train_params, tr.frozen_params, tr.ref_params,
                            jnp.asarray(tokens), trunk_state=True)
         assert len(six) == 6 and six[5].shape == (8, 8 + MAX_NEW, tr.model_cfg.d_model)
@@ -350,6 +414,22 @@ def test_the_next_collection_over_an_empty_store_drops_the_cache(tmp_path):
     tr.store.clear_history()
     tr._open_trunk_cache()
     assert tr._trunk_cache is None and tr._trunk_chunks == []
+
+
+def test_a_several_chunk_collection_that_adds_to_a_live_cache_fills_its_chunks(tmp_path):
+    """The rule counted one collection's states. A second collection over the
+    same store finds the first's cache standing, which was not in the
+    reckoning: its chunks wait for the collection's end, when nothing is in
+    flight, and the fills' rows join the cache behind the scored ones."""
+    tr = _make_trainer(tmp_path, chunk_size=4, train=dict(batch_size=4), devices=1)
+    fills = _count_fills(tr)
+    tr.make_experience(8)
+    assert tr._score_with_trunk_state and tr._trunk_scored_rows == 8 and fills == []
+    tr.make_experience(8)
+    assert fills == [(4, 32), (4, 32)] and tr._trunk_scored_rows == 8
+    assert tr._trunk_cache.shape[0] == 16
+    assert [e.trunk_row for e in tr.store.history] == list(range(16))
+    _assert_rows_hold_their_tokens_state(tr)
 
 
 def test_one_epoch_fills_nothing_and_trains_from_the_whole_forward(tmp_path):
@@ -412,6 +492,29 @@ class _OwnScore(PPOTrainer):
 
 
 CELL = dict(budget=int(ppo_trainer.TRUNK_CACHE_HBM_SHARE * V5E))
+# What `pythia-1.4b.ppo-hh` reads on its v5e chip when its scorer is built (my
+# chip runs, PR 49): `bytes_limit` 16,909,336,064 less 8,205,274,112 in use (the
+# weights, the optimizer, the reference) and 524,288 reserved; and the compiler's
+# analysis of the two programs at the cell's shapes, (temporaries, code + results)
+FREE = 16_909_336_064 - 8_205_274_112 - 524_288
+GENERATE = (6_767_312_896, 9_828_864 + 147_968)    # `generate[b16,p896]` since PR 46 (`bytes_in_use` rose by their sum at its first dispatch)
+GENERATE_PR42 = (8_371_238_912, GENERATE[1])       # PR 42's sampler at the same shapes (PERF.md section 5, PR 46)
+SCORE = (3_749_879_296, 304_285_696 + 67_307_008)  # the six-output `score` over `[16, 1024]`
+STATES = 4 * 16 * 1024 * 2048 * 2
+MARGIN = PPOTrainer.COLLECTION_HBM_MARGIN
+# what the rule asks of the device in cell 1: `generate`'s temporaries are the larger
+ASKED = GENERATE[0] + GENERATE[1] + SCORE[1] + STATES + MARGIN
+
+
+def _device(free=FREE, generate=GENERATE, score=SCORE):
+    """The parts of the rule's reckoning, as a device and the compiler would
+    report them."""
+    return dict(CELL, _device_free_bytes=lambda: free, _generate_held_bytes=lambda: generate,
+                _score_held_bytes=lambda program=None: score)
+
+
+PYTHIA = dict(method=dict(num_rollouts=64, chunk_size=16, ppo_epochs=4), train=dict(seq_length=1024),
+              model_cfg=dict(d_model=2048, n_layers=24, dtype=jnp.bfloat16), split=22)
 ARBITER = {
     # what the schedule observes -> (patch, the cycle trains from the trunk
     # cache, bytes a device holds of it, the score program hands the state out)
@@ -428,23 +531,50 @@ ARBITER = {
     "just_inside_hbm_budget": (dict(budget=8 * 32 * 64 * 4), True, None, True),
     # a backend that reports no capacity (the CPU) bounds nothing
     "no_capacity_known": (dict(budget=0), True, None, True),
-    # the scorer's own reasons: a second chunk's generation is in flight
-    # while the first is scored; a trainer that builds its own scorer
-    "two_chunks": (dict(method=dict(chunk_size=4)), True, None, False),
-    "one_row_over_a_chunk": (dict(method=dict(num_rollouts=9)), True, None, False),
-    "own_score": (dict(__class__=_OwnScore), True, None, False),
-    # the benchmark's three PPO cells on a v5e's 16 GiB
-    "pythia-1.4b.ppo-hh": (dict(
-        CELL, method=dict(num_rollouts=64, chunk_size=16, ppo_epochs=4), train=dict(seq_length=1024),
-        model_cfg=dict(d_model=2048, n_layers=24, dtype=jnp.bfloat16), split=22),
-        True, 268435456, False),
+    # the scorer's own reasons. A second chunk's generation is in flight while
+    # the first is scored: yes since PR 49 where the device has room for both
+    # chunks' states beside it (a no until then, whatever the device held),
+    # and where it reports nothing (the CPU: the rehearsal's two-chunk recipes)
+    "two_chunks": (dict(_device(), method=dict(chunk_size=4)), True, None, True),
+    "two_chunks_no_capacity_known": (dict(method=dict(chunk_size=4)), True, None, True),
+    "two_chunks_no_room": (dict(_device(free=ASKED - STATES), method=dict(chunk_size=4)), True, None, False),
+    # two chunks, the second of one row: both counted, both handed out
+    "one_row_over_a_chunk": (dict(_device(), method=dict(num_rollouts=9)), True, None, True),
+    # a trainer that builds its own scorer; one pass over a chunk's rows (above)
+    "own_score": (dict(_device(), __class__=_OwnScore), True, None, False),
+    "own_score_one_chunk": (dict(__class__=_OwnScore), True, None, False),
+    # the states just inside what is free, and one byte over
+    "states_just_inside": (dict(_device(free=ASKED), **PYTHIA), True, STATES, True),
+    "states_one_byte_over": (dict(_device(free=ASKED - 1), **PYTHIA), True, STATES, False),
+    # the device keeps one region for temporaries: a scorer whose own are the
+    # larger is counted in `generate`'s place, not on top of it
+    "score_temporaries_the_larger": (dict(_device(
+        free=ASKED + 1, score=(GENERATE[0] + 1, SCORE[1])), **PYTHIA), True, STATES, True),
+    "score_temporaries_the_larger_one_byte_over": (dict(_device(
+        free=ASKED, score=(GENERATE[0] + 1, SCORE[1])), **PYTHIA), True, STATES, False),
+    # a plain no never sizes (and so never compiles) the six-output program
+    "no_room_beside_generate_alone": (dict(_device(free=ASKED - SCORE[1] - 1), **PYTHIA,
+                                           _score_held_bytes=None), True, STATES, False),
+    # a generation that is not on this device (fleet rollouts) says so, and
+    # the rule answers from the rest; a program nobody can size declines
+    "fleet_rollouts": (dict(CELL, **{**PYTHIA, "train": dict(seq_length=1024, rollout_backend="fleet")},
+                            _device_free_bytes=lambda: sum(SCORE) + STATES + MARGIN,
+                            _score_held_bytes=lambda program=None: SCORE), True, STATES, True),
+    "a_program_without_a_size": (dict(_device(generate=None), **PYTHIA), True, STATES, False),
+    # the benchmark's three PPO cells on a v5e's 16 GiB. Cell 1 collects in
+    # four chunks: PR 46's sampler leaves room for their states beside a
+    # generation in flight (a no until PR 49: the rule read the chunk count
+    # alone), PR 42's sampler at the same shapes did not
+    "pythia-1.4b.ppo-hh": (dict(_device(), **PYTHIA), True, STATES, True),
+    "pythia-1.4b.ppo-hh_pr42_sampler": (dict(_device(generate=GENERATE_PR42), **PYTHIA), True, STATES, False),
+    # one chunk a collection: no memory is read (a reckoning would raise here)
     "gpt2-xl.ppo-sentiments": (dict(
         CELL, method=dict(num_rollouts=128, chunk_size=128, ppo_epochs=4), train=dict(seq_length=104),
-        model_cfg=dict(d_model=1600, n_layers=48, dtype=jnp.bfloat16), split=46),
+        model_cfg=dict(d_model=1600, n_layers=48, dtype=jnp.bfloat16), split=46, _device_free_bytes=None),
         True, 42598400, True),
     "lfm2-8b-a1b.ppo-hh": (dict(
         CELL, method=dict(num_rollouts=64, chunk_size=64, ppo_epochs=4), train=dict(seq_length=1024),
-        model_cfg=dict(d_model=2048, n_layers=10, dtype=jnp.bfloat16), split=8),
+        model_cfg=dict(d_model=2048, n_layers=10, dtype=jnp.bfloat16), split=8, _device_free_bytes=None),
         True, 268435456, True),
     # the same recipe at a width and depth of rollouts one chip cannot hold
     "too_many_rollouts": (dict(
@@ -656,7 +786,8 @@ def test_the_counter_span_says_what_every_dispatch_resumed_from(trainer, monkeyp
     ]
 
 
-@pytest.mark.parametrize("chunk_size, scored", [(8, 8), (4, 0)], ids=["one_chunk", "two_chunks"])
+@pytest.mark.parametrize("chunk_size, scored", [(8, 8), (4, 0), (4, 8)],
+                         ids=["one_chunk", "two_chunks", "two_chunks_handed_out"])
 def test_the_counter_span_says_where_the_caches_rows_came_from(
         tmp_path, monkeypatch, chunk_size, scored):
     """`trlx:ppo.trunk_rows rows=.. scored=..` once a collection while a
@@ -665,6 +796,8 @@ def test_the_counter_span_says_where_the_caches_rows_came_from(
     from trlx_tpu.observability import tracing
 
     tr = _make_trainer(tmp_path, chunk_size=chunk_size, devices=1)
+    if not scored:
+        _no_room(tr)
 
     def no_counters(name, **kv):
         raise AssertionError(f"{name} formatted off a session")
@@ -817,11 +950,15 @@ def test_a_quarantined_row_leaves_the_other_rows_right(tmp_path):
     every other element still names the state of ITS tokens."""
     tr = _make_trainer(tmp_path, chunk_size=4, train=dict(batch_size=4))
     tr._sentinel = _DropOneRow()
+    fills = _count_fills(tr)
     tr.make_experience(8)
     rows = [e.trunk_row for e in tr.store.history]
     # chunk 0 lost row 1, so a third chunk made up the count
     assert rows == [0, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11]
     assert tr._trunk_cache.shape[0] == 12
+    # the rule counted the collection's two planned chunks: their states came
+    # from the score program, and the third is filled beside them at the end
+    assert tr._score_with_trunk_state and tr._trunk_scored_rows == 8 and fills == [(4, 32)]
     _assert_rows_hold_their_tokens_state(tr)
     # and the batches train from them
     host = next(iter(tr.create_train_dataloader()))

@@ -14,7 +14,7 @@ import itertools
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -488,7 +488,7 @@ class PPOTrainer(TPUTrainer):
             self._score_fn = self._ljit(score_seq2seq, "score_seq2seq", budget=2)
             return
 
-        def score(train_params, frozen_params, ref_params, all_tokens):
+        def score(train_params, frozen_params, ref_params, all_tokens, with_trunk_state=False):
             params = merge_params(train_params, frozen_params)
             attention_mask = (all_tokens != pad_id).astype(jnp.int32)
             positions = position_ids(attention_mask)
@@ -502,30 +502,30 @@ class PPOTrainer(TPUTrainer):
             kl = jnp.exp(log_ratio) - 1 - log_ratio
             mean_kl_per_token = kl.mean()
             mean_kl = kl.sum(1).mean()
-            if with_trunk_state:
+            if with_trunk_state and self._planned_chunks() == 1:
                 # The state (what `trunk_cache_fill` returns for these
                 # tokens) leaves behind a barrier it shares with the
                 # reference logits, and nobody reads the barrier's logits: a
-                # nudge, and a measured one. gpt2-xl's chunk (128 x 104 x
-                # 1600 bfloat16, 43 MB) fits the chip's fast memory; with the
-                # state as a plain sixth output the TPU compiler keeps the
-                # residual stream there and no longer prefetches the 46
-                # frozen blocks' MLP weights, 2.2 ms a block and 0.109 s a
-                # chunk (0.676 against 0.566 s), and with the barrier it
-                # plans as it does for five outputs (0.570 s). lfm2's chunk
-                # (268 MB) compiles to one plan either way. PERF.md section
-                # 6, PR 40; tests/test_ppo_cells_compile_tpu.py holds the plan.
+                # nudge, measured and not derived. gpt2-xl's one chunk (128 x
+                # 104 x 1600 bfloat16, 43 MB) as a plain sixth output lost the
+                # 46 frozen blocks' weight prefetches (109 for 245 joins; 2.2
+                # ms a block, 0.676 against 0.566 s a chunk) and behind the
+                # barrier plans as for five outputs (0.570 s; PR 40); lfm2's
+                # (268 MB) has one plan either way. pythia's chunks (16 x 1024
+                # x 2048, 67 MB, four a collection) are the other way round,
+                # 19 for 97 joins behind the barrier and 2.033 against 1.920
+                # s, and leave plainly (PR 49). PERF.md section 6.
                 h_split, _ = jax.lax.optimization_barrier((h_split, ref_logits))
             scored = (logprobs, values[:, :-1], log_ratio, mean_kl, mean_kl_per_token)
             return (*scored, self._place_trunk_cache(h_split)) if with_trunk_state else scored
 
-        # the function's name is the program's in a device trace
-        # (`jit_score`: bench/metrics/ppo.score_s.json), with either width
-        with_trunk_state = self._score_with_trunk_state = self._score_hands_out_trunk_state()
-        program = self._ljit(score, "score", budget=2)
-        if not with_trunk_state:
+        # the function's name is the program's in a device trace (`jit_score`), with either width
+        program = self._ljit(score, "score", budget=2, static_argnames="with_trunk_state")
+        self._score_with_trunk_state = self._score_hands_out_trunk_state(program)
+        if not self._score_with_trunk_state:
             self._score_fn = program
             return
+        program = jax.tree_util.Partial(program, with_trunk_state=True)  # the door's call stays as its kernels' cache key has it
 
         def score_door(*args, trunk_state=False):
             # the state dropped here is freed at once: it never stands
@@ -950,13 +950,12 @@ class PPOTrainer(TPUTrainer):
                 )
             else:
                 all_tokens = np.concatenate([prompt_tensors, sample_outputs], axis=1)
-                # the collection's first chunk, which a one-chunk recipe
-                # expects to be its last (`_score_hands_out_trunk_state`):
+                # a chunk the rule counted (`_score_hands_out_trunk_state`):
                 # its trunk state stays on the device as the score program
-                # leaves it. A chunk that a quarantine made necessary after
-                # it is filled at the collection's end like any other.
-                take_state = (self._score_with_trunk_state and chunk == 0
-                              and self._trunk_chunks is not None)
+                # leaves it. A chunk that a quarantine made necessary beyond
+                # the collection's planned number was not in the reckoning
+                # and is filled at the collection's end like any other.
+                take_state = self._takes_trunk_state(chunk)
                 scored = self._score_fn(
                     self.train_params, self.frozen_params, self.ref_params,
                     jnp.asarray(all_tokens), **({"trunk_state": True} if take_state else {}),
@@ -1482,6 +1481,9 @@ class PPOTrainer(TPUTrainer):
     #: whose program may follow its longest prompt (a `BlockPlan`); the
     #: pipelined trainers' own `generate` does not
     _narrows_rollout_chunks = True
+    #: the width the rollout loader pads every chunk's prompts to, where its
+    #: pipeline says (`_rollout_stream`)
+    _rollout_prompt_width: Optional[int] = None
 
     def _rollout_plan(self, width: int, gen_kwargs, **generate_kwargs):
         """The `BlockPlan` a rollout chunk of that prompt width is generated
@@ -1498,11 +1500,11 @@ class PPOTrainer(TPUTrainer):
         Every chunk is generated at the pool's width; whether the program
         then follows the chunk's longest prompt is the sampler's own rule
         (`_rollout_plan`). The stream's place is part of the resume state."""
-        method = self.config.method
-        window = rows * -(-int(method.num_rollouts) // max(int(method.chunk_size), 1))
+        window = rows * self._planned_chunks()
         if getattr(pipeline, "prompt_lengths", None) is not None:
             loader_kwargs["group_window"] = window
         self._prompt_stream = LoaderStream(pipeline.create_loader(rows, shuffle=True, **loader_kwargs))
+        self._rollout_prompt_width = getattr(pipeline, "max_prompt_length", None)
         return self._prompt_stream
 
     def _rollout_generate(self, batch, gen_kwargs, **generate_kwargs):
@@ -1926,31 +1928,158 @@ class PPOTrainer(TPUTrainer):
         return not self._trunk_cache_budget or (
             self._trunk_cache_device_bytes() <= self._trunk_cache_budget)
 
-    def _score_hands_out_trunk_state(self) -> bool:
+    #: What `_score_hands_out_trunk_state` leaves free beyond the parts it
+    #: adds up, for what it does not size: the code of the cycle's other
+    #: programs (the train steps, the cache's concat), the prompts and results
+    #: of two chunks in flight, the allocator's rounding and the holes
+    #: between standing arrays.
+    #: In `pythia-1.4b.ppo-hh` those came to about 100 MB (`bytes_in_use` at a
+    #: collection's first dispatch 415 MB over what it was when the scorer was
+    #: built, 314 MB of it the two sized programs' code; my chip runs, PR 49).
+    COLLECTION_HBM_MARGIN = 256 * 2**20
+
+    def _score_hands_out_trunk_state(self, score_program=None) -> bool:
         """Whether the score program returns the state entering block
         `split` as one more output, which `_process_chunk` puts into the
         cycle's trunk cache in the tokens' place: the scorer runs blocks
         [0, split) over exactly the tokens a fill would, so the collection's
-        end then has nothing to fill. Read once, when the scorer is built
-        (one compiled program a trainer). Of the schedule: the cycle trains
-        from the trunk cache (`_trunk_cache_available`). Of the recipe: a
-        collection is one chunk (`num_rollouts <= chunk_size`). Generation
+        end then has nothing to fill. Read once, when the scorer is built and
+        before it is compiled (one compiled program a trainer);
+        `score_program` is that scorer, jitted. Of the trainer: this class's
+        own `score` (GRPO, the pipelined and the sequence-parallel trainers
+        build theirs). Of the schedule: the cycle trains from the trunk cache
+        (`_trunk_cache_available`). Of the recipe and the chip, one of two.
+        A collection is one chunk (`num_rollouts <= chunk_size`): generation
         is double-buffered (`_collect_rollouts` dispatches the next chunk's
-        before it fetches this one's), so with one chunk, and only then,
-        nothing is dispatched after a chunk has been scored; a dispatch
-        takes its buffers at once, and a recipe sized to the chip has no
-        room for a chunk's state beside a generation in flight (the
-        benchmark's `pythia-1.4b.ppo-hh`, four chunks: 17-53 MB free, a
-        chunk's state 67 MB; PERF.md section 6, PR 38). Of the trainer: this
-        class's own `score` (GRPO, the pipelined and the sequence-parallel
-        trainers build theirs). Where this says no, the score program has
-        its five outputs and `_close_trunk_cache` fills every chunk."""
+        before it fetches this one's), so with one chunk nothing is
+        dispatched after a chunk has been scored, and no memory is read. Or
+        the collection's states fit beside a generation in flight and the
+        scorer, by the device's own account. A dispatch takes its buffers at
+        once and does not wait for memory, so what the device has free now
+        (`_device_free_bytes`: the weights, the optimizer and the reference
+        stand, and nothing else yet) has to hold four things. The programs'
+        temporaries: the device keeps ONE region for them, as large as the
+        largest program it has run needs and never given back (cell 1:
+        `bytes_reserved` 3.39 GB after the first `score`, 6.42 GB from the
+        first `generate` on, the same with a score dispatched beside a
+        generation in flight; my chip runs, PR 49), so the larger of the two
+        programs' counts, not their sum. What each program holds beside that
+        region: its code, which lives on the device from its first call on
+        (the six-output `score` of cell 1: 304 MB), and its results. Every
+        chunk's state (`_trunk_cache_device_bytes`). And
+        `COLLECTION_HBM_MARGIN`. Both programs are sized by the compiler's
+        analysis of the executables the collection then runs
+        (`_generate_held_bytes`, `_score_held_bytes`): `generate` first,
+        which any answer runs, and `score` only where the states fit beside
+        `generate` alone, so that a plain no never compiles the six-output
+        program. A backend that reports no capacity (the CPU) bounds
+        nothing, as in `_trunk_cache_available`, and nothing is lowered for
+        it; a program that cannot be sized declines. Where this says no, the
+        score program has its five outputs and `_close_trunk_cache` fills
+        every chunk when the collection has ended."""
+        if not (type(self)._build_score_fn is PPOTrainer._build_score_fn
+                and self._trunk_cache_available()):
+            return False
+        if self._planned_chunks() == 1 or not self._trunk_cache_budget:
+            return True
+        t0 = time.monotonic()
+        free = self._device_free_bytes()
+        asked = self._trunk_cache_device_bytes() + self.COLLECTION_HBM_MARGIN
+        generate, score = self._generate_held_bytes(), None
+        if generate is not None and sum(generate) + asked <= free:
+            score = self._score_held_bytes(score_program)
+        fits = score is not None and (
+            max(generate[0], score[0]) + generate[1] + score[1] + asked <= free)
+        logger.info(
+            f"a collection's trunk states beside a generation in flight: {free} B free; (temporaries, "
+            f"code and results) of generate {generate}, of score {score or 'not sized'}; "
+            f"states and margin {asked} B: the score program "
+            f"{'hands them out' if fits else 'does not hand them out'} "
+            f"(reckoned in {time.monotonic() - t0:.1f} s)")
+        return fits
+
+    def _takes_trunk_state(self, chunk: int) -> bool:
+        """Whether chunk number `chunk` of the collection under way keeps the
+        state the score program hands out: a chunk the rule counted
+        (`_score_hands_out_trunk_state`), one of the collection's planned
+        number with the states of the chunks before it the only ones
+        standing. A chunk that a quarantine makes necessary beyond those, and
+        the chunks of a several-chunk collection that adds to a live cache
+        (`_open_trunk_cache`), were not in the reckoning: they are filled at
+        the collection's end, when nothing is in flight."""
+        planned = self._planned_chunks()
+        return (self._score_with_trunk_state and self._trunk_chunks is not None
+                and chunk < planned and (planned == 1 or len(self._trunk_chunks) == chunk))
+
+    def _planned_chunks(self) -> int:
+        """Chunks a collection of the recipe takes when no row is quarantined."""
         method = self.config.method
-        return (
-            type(self)._build_score_fn is PPOTrainer._build_score_fn
-            and int(method.num_rollouts) <= max(int(method.chunk_size), 1)
-            and self._trunk_cache_available()
-        )
+        return -(-int(method.num_rollouts) // max(int(method.chunk_size), 1))
+
+    def _device_free_bytes(self) -> int:
+        """What the fullest of this process's devices of the mesh has free
+        for programs and new arrays, by its own account: its limit less the
+        arrays in use and the region reserved for programs' temporaries.
+        `bytes_in_use` counts arrays and loaded code and not that region
+        (PERF.md section 6, PR 38), `bytes_reserved` counts it."""
+        free = []
+        for device in self.runtime.mesh.local_devices:
+            stats = device.memory_stats() or {}
+            free.append(int(stats.get("bytes_limit", 0)) - int(stats.get("bytes_in_use", 0))
+                        - int(stats.get("bytes_reserved", 0)))
+        return min(free)
+
+    @staticmethod
+    def _program_held_bytes(program, *args, **kwargs) -> Optional[Tuple[int, int]]:
+        """What a jitted program takes on a device, beside its arguments, by
+        the compiler's analysis of the executable compiled for those
+        arguments (shapes will do): (its temporaries, which stand in the
+        device's one region for them while it runs; its code and its
+        results, which stand beside). The executable is the one a call with
+        such arguments then runs. None where the program or the backend
+        cannot say."""
+        jitted = getattr(program, "_jitted", program)  # behind the compile ledger's wrapper
+        if not hasattr(jitted, "lower"):
+            return None
+        stats = jitted.lower(*args, **kwargs).compile().memory_analysis()
+        if stats is None:
+            return None
+        return (int(stats.temp_size_in_bytes),
+                int(stats.generated_code_size_in_bytes) + int(stats.output_size_in_bytes))
+
+    def _chunk_prompt_width(self) -> int:
+        """Prompt columns of a collection's chunk: the width the rollout
+        loader pads to where its pipeline says, else what the recipe leaves
+        room for."""
+        return (self._rollout_prompt_width
+                or self.config.train.seq_length - self._trunk_response_width())
+
+    def _generate_held_bytes(self) -> Optional[Tuple[int, int]]:
+        """`_program_held_bytes` of the `generate` program a collection's
+        chunks run: `chunk_size` prompts of `_chunk_prompt_width`, through
+        the buckets `generate` rounds to, under the collection's
+        `gen_kwargs`. Nothing where the generation is not on this device
+        (fleet rollouts)."""
+        if self._fleet_rollouts_enabled():
+            return 0, 0
+        gen_kwargs = self.generate_experience_kwargs or self.generate_kwargs
+        rows, width = self._bucket_shape(int(self.config.method.chunk_size), self._chunk_prompt_width())
+        prompts = jax.ShapeDtypeStruct((rows, width), jnp.int32)
+        return self._program_held_bytes(
+            self.get_generate_fn(rows, width, gen_kwargs, spec_k=self._spec_k_effective()),
+            self._decode_params(), prompts, prompts,
+            jax.ShapeDtypeStruct(self.rng.shape, self.rng.dtype))
+
+    def _score_held_bytes(self, score_program) -> Optional[Tuple[int, int]]:
+        """`_program_held_bytes` of the six-output score program over one
+        chunk of the collection, its state (one of those
+        `_trunk_cache_device_bytes` counts) included."""
+        tokens = jax.ShapeDtypeStruct(
+            (int(self.config.method.chunk_size),
+             self._chunk_prompt_width() + self._trunk_response_width()), jnp.int32)
+        return self._program_held_bytes(
+            score_program, self.train_params, self.frozen_params, self.ref_params, tokens,
+            with_trunk_state=True)
 
     def _trunk_cache_device_bytes(self) -> int:
         """What one device holds of a cycle's cache at its widest: whole
@@ -1959,9 +2088,7 @@ class PPOTrainer(TPUTrainer):
         rows and columns on."""
         from trlx_tpu.observability.hbm import trunk_cache_bytes
 
-        method = self.config.method
-        chunk = max(int(method.chunk_size), 1)
-        shape = (-(-int(method.num_rollouts) // chunk) * chunk,
+        shape = (self._planned_chunks() * max(int(self.config.method.chunk_size), 1),
                  self.config.train.seq_length, self.model_cfg.d_model)
         sharding = self._trunk_cache_sharding(shape)
         if sharding is not None:
@@ -2081,8 +2208,9 @@ class PPOTrainer(TPUTrainer):
         every train step of the cycle takes beside its batch and which never
         leaves the device. The fills wait for the end because only then do
         the sampler and the scorer hold nothing: while a generation is in
-        flight its buffers stand, and the benchmark's `pythia-1.4b.ppo-hh`
-        has 17 MB free beside them (my chip runs, PR 38). The device is as
+        flight its buffers stand, and a fill's own come on top of them; the
+        chunks that wait here are those the rule found no room for, or did
+        not count (`_score_hands_out_trunk_state`). The device is as
         busy either way; the train steps queue behind the fills. Chunks of
         different query widths (a prompt pipeline that pads batch by batch),
         and a scored state whose queries the loader will pad wider, move to

@@ -297,11 +297,14 @@ def test_engine_gather_path_and_int8_arena_follow_the_window():
 # no source locations) of three neox-tiny programs, recorded on the parent
 # commit b8ba58b under jax 0.9.0 by this very code: a family that names no
 # layer kinds lowers to the program it lowered to before the layers could
-# differ, the paged kernel's body (interpreted) included.
+# differ, the paged kernel's body (interpreted) included. The insert's was
+# recorded again at PR 50, whose point is that program's text: it unembeds each
+# row's last live position (`decode_step`'s `head_at`) where it unembedded all
+# (19f396a3...385 until then); the forward and the decode step stand as recorded.
 LOWERED_BEFORE = {
     "forward": "803f839d0b7e6abadd156f8b2cb8bd16c9d3034a7f14eecc5b4922349f28de6d",
     "decode": "5ac5d745641b218d2498b9ec139d2af255b2e4670b95c59e5dc818d2cdf47ffa",
-    "insert": "19f396a3375b7835d5dea24c7d567def1b4b7ff6fd114584b91d5cb8ccaa4385",
+    "insert": "6503e6edc5c8f4b5bae8b96cd693b1d431f0b7431c99f5d28a572f5bb6974d3f",
 }
 
 

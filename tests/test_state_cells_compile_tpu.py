@@ -115,7 +115,7 @@ def test_chat_cell_programs_compile_for_the_chip_and_fit_it(v5e, pallas_mode):
     state pools [128, 32, 256, 128] are told by their shape), and arguments plus
     temporaries under 15.0 GB: 8.79 GB of weights, 2.16 GB of slot state, 1.61
     GB of arena and the program's own (the prefill's logits over the whole
-    vocabulary at every prompt position among them). One test, so that the
+    vocabulary at its one row's last position among them). One test, so that the
     engine's 3.8 GB pool on the host lives no longer than its two compiles."""
     engine, params = serve_cell_engine(v5e, "falcon-h1-34b", "rollout-chat", 512, CHAT_CELL["n_tbl"])
     pools = [a for layer in engine._pool["layers"] for name, a in layer.items() if name != "tails"]

@@ -344,12 +344,12 @@ def test_insert_counter_spans_add_up_to_the_counters(paged_engine, tmp_path):
     idle(paged_engine)
     scheduler = Scheduler(paged_engine, max_wait_s=0.0)
     names = ("prefill_batches_total", "prefill_rows_total", "prefill_tokens_total",
-             "prefill_padded_tokens_total")
+             "prefill_padded_tokens_total", "prefill_head_positions_total")
     scheduler.start()
     try:
         assert scheduler.submit(prompt_of(5)).wait(120)
         before = [scheduler.metrics.get(n) for n in names]
-        assert before[:3] == [1, 1, 5] and before[3] == paged_engine.prompt_bucket
+        assert before[:3] == [1, 1, 5] and before[3:] == [paged_engine.prompt_bucket, 1]
         tracing.start(str(tmp_path))
         reqs = [scheduler.submit(prompt_of(3 + 2 * i, i)) for i in range(5)]
         assert all(r.wait(120) for r in reqs)
@@ -358,7 +358,10 @@ def test_insert_counter_spans_add_up_to_the_counters(paged_engine, tmp_path):
         scheduler.stop()
     delta = [scheduler.metrics.get(n) - b for n, b in zip(names, before)]
     counts = counter_spans(engine_spans(str(tmp_path)), "sched.insert")
-    assert [sum(c[k] for c in counts) for k in ("calls", "rows", "prompt_tokens", "padded_tokens")] == delta
+    assert [sum(c[k] for c in counts)
+            for k in ("calls", "rows", "prompt_tokens", "padded_tokens", "head_positions")] == delta
+    # a padded row's one position goes through the head, whatever the width it is dispatched at
+    assert len(reqs) <= delta[4] < delta[3]
     assert delta[1] == len(reqs) and delta[2] == sum(len(r.prompt_ids) for r in reqs)
     assert all(c["calls"] == 1 and c["pad_tokens"] == c["padded_tokens"] - c["prompt_tokens"] for c in counts)
 
@@ -367,7 +370,7 @@ def test_insert_counter_spans_add_up_to_the_counters(paged_engine, tmp_path):
 def test_padding_of_a_batch_over_two_buckets(trainer, tmp_path, kv_paging):
     """Lengths 3, 5, 7 share the bucket of 8 (three rows are dispatched as
     four) and 12 takes the bucket of 16 alone: 4 x 8 + 1 x 16 positions for
-    27 prompt tokens."""
+    27 prompt tokens, and the head over the 4 + 1 rows' last positions."""
     from trlx_tpu.models import CausalLMPolicy
 
     gen_cfg = GenerationConfig(max_new_tokens=MAX_NEW, do_sample=False, eos_token_id=10_000,
@@ -381,10 +384,11 @@ def test_padding_of_a_batch_over_two_buckets(trainer, tmp_path, kv_paging):
         counts = engine.insert_requests(rows, [0, 1, 2, 3])
     finally:
         tracing.stop()
-    assert counts == (4, 27, 48)
+    assert counts == (4, 27, 48, 5)
     spans = [s for v in read_spans(str(tmp_path)).values() for s in v]
     assert counter_spans(spans, "sched.insert") == [
-        {"calls": 1, "rows": 4, "prompt_tokens": 27, "padded_tokens": 48, "pad_tokens": 21}]
+        {"calls": 1, "rows": 4, "prompt_tokens": 27, "padded_tokens": 48, "pad_tokens": 21,
+         "head_positions": 5}]
     # in front of the first of the admission's two programs
     programs = sorted(named(spans, "engine.insert"), key=lambda s: s[1])
     assert len(programs) == 2 and spans_named(spans, "sched.insert")[0][2] <= programs[0][1]
@@ -403,9 +407,9 @@ def test_a_shared_prefix_is_not_counted_as_prefilled(trainer):
         num_slots=2, max_prompt_len=24, prompt_bucket=8, kv_paging=True, kv_block_size=8,
         prefix_cache=True)
     prompt = prompt_of(20)
-    assert engine.insert_requests([(prompt, MAX_NEW)], [0]) == (1, 20, 24)
+    assert engine.insert_requests([(prompt, MAX_NEW)], [0]) == (1, 20, 24, 1)
     # blocks [0, 16) are resident under their keys: 4 tokens left, one bucket of 8
-    assert engine.insert_requests([(prompt, MAX_NEW)], [1]) == (1, 4, 8)
+    assert engine.insert_requests([(prompt, MAX_NEW)], [1]) == (1, 4, 8, 1)
 
 
 def serve(engine, seeds):

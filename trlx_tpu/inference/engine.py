@@ -617,12 +617,13 @@ class InferenceEngine:
                     # the prompt's K/V must carry each row's own adapter
                     variables["lora_rows"] = _gather_rows(stack, aidx)
                 cache = init_kv_cache(cfg, ids.shape[0], S)
+                # left padding: every row reads the last column, so the head runs over that one
                 out = model.apply(
                     variables, ids, cache, mask, True,
-                    method=type(model).decode_step,
+                    method=type(model).decode_step, head_at=jnp.full((pb,), plen - 1, jnp.int32),
                 )
                 logits, new_cache = out[0], out[-1]
-                return logits[:, -1].astype(jnp.float32), new_cache
+                return logits[:, 0].astype(jnp.float32), new_cache
 
             self._prefill_fns[key] = self._ljit(
                 prefill, f"engine.prefill[b{pb},p{plen}]")
@@ -719,17 +720,16 @@ class InferenceEngine:
                     "pos": shared_len,
                     "row_index": shared_len,
                 }
+                # the head runs over each row's LAST valid position (right padding), the one read
+                lens = tmask.sum(-1).astype(jnp.int32)
                 out = model.apply(
                     variables, ids, cache, tmask,
                     method=type(model).decode_step,
                     attn_kernel="prefill" if fresh else None,
+                    head_at=jnp.clip(lens - 1, 0, plen - 1),
                 )
                 logits, new_cache = out[0], out[-1]
-                # per-row LAST-valid-position logits (right padding)
-                lens = tmask.sum(-1).astype(jnp.int32)
-                last = jnp.take_along_axis(
-                    logits, jnp.clip(lens - 1, 0, plen - 1)[:, None, None], axis=1
-                )[:, 0].astype(jnp.float32)
+                last = logits[:, 0].astype(jnp.float32)
                 rng, key_ = jax.random.split(pool["rng"])
                 token, lp = sample_fused(last, key_, 0)
                 # the arena is the pool's; a row's final slot state goes into
@@ -781,7 +781,7 @@ class InferenceEngine:
         rows: Sequence[Tuple],  # (unpadded prompt ids, max_new[, adapter_id])
         slot_ids: Sequence[int],
         sessions: Optional[Sequence] = None,  # per-row Session or None
-    ) -> Tuple[int, int, int]:
+    ) -> Tuple[int, int, int, int]:
         """Prefill `rows` (length-bucketed, left-padded) and scatter them
         into the given free slots. Requests are grouped by prompt-width
         bucket; each group prefills as one jitted call. Paged mode routes
@@ -844,7 +844,7 @@ class InferenceEngine:
             if int(slot) in self._slot_adapter:
                 self.adapter_store.release(self._slot_adapter.pop(int(slot)))
 
-    def _insert_dense(self, norm, slot_ids, aslots: Optional[List[int]]) -> Tuple[int, int, int]:
+    def _insert_dense(self, norm, slot_ids, aslots: Optional[List[int]]) -> Tuple[int, int, int, int]:
         pad_id = self.gen_cfg.pad_token_id
         mt = self.multi_tenant
         programs = self._prefill_programs([
@@ -904,28 +904,28 @@ class InferenceEngine:
         return [(plen, rows[i : i + step], _pow2_bucket(len(rows[i : i + step]), step))
                 for plen, rows in groups.items() for i in range(0, len(rows), step)]
 
-    def _count_admission(self, programs: Sequence[Tuple[int, List[Tuple], int]]) -> Tuple[int, int, int]:
-        """What one call of `insert_requests` is about to prefill, from the
-        programs it will dispatch: the rows, the prompt tokens they compute
-        (a prompt's, less what it shares from the prefix store or its
-        session) and the positions the programs are dispatched at (rows
-        padded to their bucket x the width bucket, summed over the
-        programs). The scheduler adds them to its counters; while a tracing
-        session listens they stand on the trace too, one counter span an
-        admission, in front of its first program's dispatch."""
+    def _count_admission(self, programs: Sequence[Tuple[int, List[Tuple], int]]) -> Tuple[int, int, int, int]:
+        """What one call of `insert_requests` is about to prefill, from the programs it will
+        dispatch: the rows, the prompt tokens they compute (a prompt's, less what it shares from
+        the prefix store or its session), the positions the programs are dispatched at (rows
+        padded to their bucket x the width bucket, summed over the programs) and the positions
+        they run the head over (a padded row's one: `decode_step`'s `head_at`). The scheduler adds
+        them to its counters; while a tracing session listens they stand on the trace too, one
+        counter span an admission, in front of its first program's dispatch."""
         rows = sum(len(chunk) for _, chunk, _ in programs)
         tokens = sum(len(member[0]) for _, chunk, _ in programs for member in chunk)
         padded = sum(pb * plen for plen, _, pb in programs)
+        head_positions = sum(pb for _, _, pb in programs)
         if tracing.active():
-            tracing.counters("sched.insert", calls=1, rows=rows, prompt_tokens=tokens,
-                             padded_tokens=padded, pad_tokens=padded - tokens)
+            tracing.counters("sched.insert", calls=1, rows=rows, prompt_tokens=tokens, padded_tokens=padded,
+                             pad_tokens=padded - tokens, head_positions=head_positions)
             if self._recurrent_layers:
                 # what the chunked recurrence is about to run, a layer: every padded position, in chunks
                 chunk = self.model_cfg.state_chunk
                 tracing.counters("engine.prefill_state", tokens=tokens, padded_tokens=padded,
                                  linear_layers=self._recurrent_layers,
                                  chunks=sum(pb * -(-plen // chunk) for plen, _, pb in programs))
-        return rows, tokens, padded
+        return rows, tokens, padded, head_positions
 
     @contextlib.contextmanager
     def _insert_span(self, rows: int, width: int):
@@ -967,7 +967,7 @@ class InferenceEngine:
     def _insert_paged(
         self, rows, slot_ids, aslots: Optional[List[int]] = None,
         sessions: Optional[Sequence] = None,
-    ) -> Tuple[int, int, int]:
+    ) -> Tuple[int, int, int, int]:
         """Paged insert: allocate each request's blocks up front
         (prompt + max_new + spec_k — no mid-decode OOM, no preemption),
         probing the prefix store for resident leading blocks first. In
@@ -1405,11 +1405,11 @@ class InferenceEngine:
             },
         )
 
-    def insert_requests(self, rows, slot_ids, **kwargs) -> Tuple[int, int, int]:
+    def insert_requests(self, rows, slot_ids, **kwargs) -> Tuple[int, int, int, int]:
         """OOM-guarded wrapper over `_insert_requests_impl` (see there for
         the contract); samples the HBM ledger at the prefill boundary.
         Returns the admission's (rows, prompt tokens prefilled, positions
-        dispatched with the padding): `_count_admission`."""
+        dispatched with the padding, positions unembedded): `_count_admission`."""
         # the step in flight was dispatched before these rows: whatever it
         # holds for their slots is not theirs
         self._disown(slot_ids)

@@ -791,11 +791,11 @@ class Scheduler:
         )
         # what the admission cost, as the engine built it (rows, the prompt
         # tokens its programs compute, the positions they are dispatched at
-        # with the padding); the same numbers stand on a tracing session's
-        # trace as `trlx:sched.insert`
+        # with the padding, the positions they run the head over); the same
+        # numbers stand on a tracing session's trace as `trlx:sched.insert`
         self.metrics.inc("prefill_batches_total")
-        for name, n in zip(("prefill_rows_total", "prefill_tokens_total",
-                            "prefill_padded_tokens_total"), prefilled):
+        for name, n in zip(("prefill_rows_total", "prefill_tokens_total", "prefill_padded_tokens_total",
+                            "prefill_head_positions_total"), prefilled):
             self.metrics.inc(name, n)
         if traced:
             ts1 = time.monotonic()

@@ -1963,27 +1963,27 @@ class TransformerLM(nn.Module):
         attn_kernel: Optional[str] = None,  # paged read path: None | "pallas" | "interpret"
         block_start: Optional[jnp.ndarray] = None,  # [b]
         positions: Optional[jnp.ndarray] = None,  # [b, t]
+        head_at: Optional[jnp.ndarray] = None,  # [b] the one of the t new columns a row reads
     ):
-        """The one cached step: blocks [start, stop) over t new positions
-        against the cache, which is moved on. Returns (logits, h_final,
-        new_cache), and the activation ENTERING block `capture_split` (the
-        same hydra split point as __call__'s h_split) as a fourth when that
-        is given. A step with `stop` given (n_layers too) is an early exit:
-        no head runs, logits is None, and h_final is `ln_f`'s reading of the
-        state entering block `stop`, which a low-rank draft head projects.
+        """The one cached step: blocks [start, stop) over t new positions against the cache, which
+        is moved on. Returns (logits, h_final, new_cache), and the activation ENTERING block
+        `capture_split` (the same hydra split point as __call__'s h_split) as a fourth when that is
+        given. A step with `stop` given (n_layers too) is an early exit: no head runs, logits is
+        None, and h_final is `ln_f`'s reading of the state entering block `stop`, which a low-rank
+        draft head projects. A caller that reads one position a row (an admission: the prompt's
+        last token) says which with `head_at`: the final norm and the head then run over that
+        position alone, and logits and h_final come back one position wide.
 
-        The cache pytree says which family it is. Both carry mask [b, S],
-        pos [b] (next position id per row) and layers (K/V tables for an
-        attention layer, the convolution's last inputs for a `conv` one):
+        The cache pytree says which family it is. Both carry mask [b, S], pos [b] (next position id
+        per row) and layers (K/V tables for an attention layer, the convolution's last inputs for a
+        `conv` one):
 
-        - **`index`** (a scalar write offset; `init_kv_cache`): every row
-          writes at the same column, the fused sampler's cache. With
-          `is_prefill` the block is a left-padded prompt (positions from
-          its own mask, causal within the block); under prompt tuning the
-          prefill prepends the soft prompt into the cache (init_kv_cache
-          reserves the extra slots) and logits keep the caller's sequence
-          length. With a scalar **`first`** beside it (the sampler's
-          prefill by blocks): `live_widths`.
+        - **`index`** (a scalar write offset; `init_kv_cache`): every row writes at the same
+          column, the fused sampler's cache. With `is_prefill` the block is a left-padded prompt
+          (positions from its own mask, causal within the block); under prompt tuning the prefill
+          prepends the soft prompt into the cache (init_kv_cache reserves the extra slots) and
+          logits keep the caller's sequence length. With a scalar **`first`** beside it (the
+          sampler's prefill by blocks): `live_widths`.
         - **`row_index`** ([b]): every row carries its OWN write offset — the continuous-batching
           slot pool and the paged arena (trlx_tpu/inference/engine.py), speculative decode. Rows
           sit at different depths, which the shared scalar cannot express; for a live row the
@@ -2114,7 +2114,10 @@ class TransformerLM(nn.Module):
             h_cap = h
         new_layers += cache["layers"][stop:]
         if to_head:
-            logits, h = self.unembed(h[:, P:] if P > 0 else h)
+            h = h[:, P:] if P > 0 else h
+            if head_at is not None:
+                h = jnp.take_along_axis(h, head_at[:, None, None], axis=1)
+            logits, h = self.unembed(h)
         else:
             logits, h = None, self.ln_f(h)
         if not per_row:

@@ -48,7 +48,8 @@ SERVE_CELLS = {"pythia-1.4b.rollout-batch": ((1, 256), (8, 512)),
                # a tree from before PR 41 has no such preset: name the other cells with --cells there
                "ling-3.0-flash-vl.rollout-reason": ((1, 1024),),
                "solar-open2-250b.rollout-longctx": ((1, 8192),),  # likewise from before PR 43
-               "falcon-h1-34b.rollout-chat": ((1, 1024),)}  # and from before PR 48
+               "falcon-h1-34b.rollout-chat": ((1, 1024),),  # and from before PR 48
+               "dots3-note-prev.rollout-longdoc": ((1, 8192),)}  # and from before PR 51
 
 
 class Lowered(Exception):

@@ -29,6 +29,7 @@ LONGEST_FIRST = [
     "test_trunk_cache.py",                 # 211 s (206 s alone with PR 49's 69 cases)
     "test_lfm2_compile_tpu.py",            # 192 s
     "test_ling_flash.py",                  # 169 s
+    "test_dots3_note.py",                  # 196 s (my CPU run, PR 51)
     "test_pangu_mla.py",                   # 165 s
     "test_laguna.py",                      # 160 s
     "test_serve_cells_compile_tpu.py",     # 145 s
@@ -36,6 +37,7 @@ LONGEST_FIRST = [
     "test_trainers.py",                    # 133 s
     "test_engine_step_ahead.py",           # 129 s
     "test_state_cells_compile_tpu.py",     # 139 s (98 s before PR 48's chat cell)
+    "test_sparse_cell_compile_tpu.py",     # 120 s (my CPU run, PR 51: the decode step and one row of 24,576)
     # (PR 49: pythia's two `score` programs at [16, 1024] compile for 4-7 min
     # each at any depth and took this file to 1,128 s of a 1,245 s run: that
     # case is marked slow and its lowering stays)

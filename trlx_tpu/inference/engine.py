@@ -82,10 +82,12 @@ def _pow2_bucket(n: int, cap: int) -> int:
 
 
 def _refuse_over_latent_cache(model_cfg, what: str) -> None:
-    """What no test holds over a latent cache (`latent_attention` layers:
-    one plane a token a layer, read absorbed) is refused by name."""
+    """What no test holds over a latent cache (the layers of a kind
+    `cfg.latent_of` knows: planes a token a layer, read absorbed, banded or
+    by an index's choice) is refused by name."""
     if getattr(model_cfg, "has_latent_layers", False):
-        raise NotImplementedError(f"{what} over a latent cache (latent_attention layers) is not supported")
+        kinds = " / ".join(k for k in dict.fromkeys(model_cfg.layer_types) if model_cfg.latent_of(k) is not None)
+        raise NotImplementedError(f"{what} over a latent cache ({kinds} layers) is not supported")
 
 
 def _refuse_over_slot_state(model_cfg, what: str) -> None:
@@ -1671,40 +1673,61 @@ class InferenceEngine:
 
     def _kv_walk(self) -> Dict[str, int]:
         """Key positions the next decode step reads against the positions
-        resident, over the slots with a request, summed over the attention
-        layers: `resident` (every layer could read all of a row's columns),
-        `walked_full` and `walked_window` (what the K/V layers without and
-        with a window do read: the paged kernel walks whole table entries,
-        from the one that holds a row's first column inside the window to
-        the one that holds its last; the gather path reads a row's whole
-        table), `walked_latent` (the same for the latent layers, which have
-        no window), `bytes` (what those walks read from the arena: positions
-        x what a position holds in each layer's planes) and `layers`. From
-        the host's own count of each slot's columns; the step adds its one."""
+        resident, over the slots with a request, summed over the layers that
+        keep planes a token: `resident` (every layer could read all of a
+        row's columns), `walked_full` and `walked_window` (what the K/V
+        layers without and with a window do read: the paged kernel walks
+        whole table entries, from the one that holds a row's first column
+        inside the window to the one that holds its last; the gather path
+        reads a row's whole table), `walked_latent` (the same for the latent
+        layers that read by a walk, banded or not), and for the latent layers
+        whose index chooses (`LatentSpec.index_topk`): `index_scored` (index
+        keys walked: every live entry), `index_attendable` (positions the
+        index chose among) and `index_chosen` (latents the step reads:
+        index_topk a row at most; on the gather path every column is read and
+        `walked_latent` counts it). `bytes` is what all of that reads from
+        the arena, positions x what a position holds in the plane read,
+        `bytes_full` the part of it in the layers that choose (their index
+        keys and chosen latents) and `bytes_dense_full` what those layers
+        would read were every attendable latent read; `layers`. From the
+        host's own count of each slot's columns; the step adds its one."""
         cfg, blk = self.model_cfg, self.kv_block_size
         cols = self._next_columns()
-        ops = [cfg.layer_op(i) for i in range(cfg.n_layers) if self._layer_keeps[i].token]
-        n_latent = ops.count("latent_attention")
-        windows = [cfg.window_of(op) for op in ops if op != "latent_attention"]
+        kinds = [cfg.layer_op(i) for i in range(cfg.n_layers) if self._layer_keeps[i].token]
         last = -(-cols // blk)
+        kernels = self.decode_path != "xla"
 
         def walked(window) -> int:  # by one layer
-            if self.decode_path == "xla":
+            if not kernels:
                 return len(cols) * self._n_tbl * blk
             first = 0 if window is None else np.maximum(cols - window, 0) // blk
             return int(((last - first) * blk).sum())
 
-        walk = {
-            "resident": int(cols.sum()) * len(ops),
-            "walked_full": windows.count(None) * walked(None),
-            "walked_window": sum(walked(w) for w in windows if w is not None),
-            "walked_latent": n_latent * walked(None),
-            "layers": len(ops),
-        }
+        walk = dict.fromkeys(("walked_full", "walked_window", "walked_latent", "index_scored", "index_attendable",
+                              "index_chosen", "bytes", "bytes_full", "bytes_dense_full"), 0)
+        walk.update(resident=int(cols.sum()) * len(kinds), layers=len(kinds))
         itemsize = jnp.dtype(self.kv_cache_dtype).itemsize
-        walk["bytes"] = itemsize * (
-            (walk["walked_full"] + walk["walked_window"]) * 2 * cfg.kv_heads * cfg.head_dim
-            + walk["walked_latent"] * cfg.latent_width)
+        for kind in kinds:
+            latent, n = cfg.latent_of(kind), walked(cfg.window_of(kind))
+            if latent is None:
+                walk["walked_full" if cfg.window_of(kind) is None else "walked_window"] += n
+                walk["bytes"] += itemsize * n * 2 * cfg.kv_heads * cfg.head_dim
+            elif not latent.index_topk:
+                walk["walked_latent"] += n
+                walk["bytes"] += itemsize * n * latent.width
+            else:
+                chosen = int(np.minimum(cols, latent.index_topk).sum())
+                walk["index_scored"] += n
+                walk["index_attendable"] += int(cols.sum())
+                walk["index_chosen"] += chosen
+                if not kernels:
+                    walk["walked_latent"] += n
+                read = itemsize * (n * latent.index_head_dim + (chosen if kernels else n) * latent.width)
+                walk["bytes"] += read
+                walk["bytes_full"] += read
+                walk["bytes_dense_full"] += itemsize * n * latent.width
+        if not getattr(cfg, "has_index_layers", False):  # no layer chooses: the counters of a choice say nothing
+            walk = {k: v for k, v in walk.items() if not k.startswith(("index_", "bytes_"))}
         return walk
 
     def kv_stats(self) -> Dict[str, Any]:

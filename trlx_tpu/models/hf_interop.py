@@ -26,7 +26,7 @@ from typing import Callable, Dict, Tuple
 
 import numpy as np
 
-from trlx_tpu.models.transformer import Multipliers, RopeSpec, TransformerConfig, cut_to_depth
+from trlx_tpu.models.transformer import LatentSpec, Multipliers, RopeSpec, TransformerConfig, cut_to_depth
 from trlx_tpu.utils import logging
 
 logger = logging.get_logger(__name__)
@@ -59,6 +59,8 @@ def _family_of(hf: Dict) -> str:
         return "laguna"
     if mt == "pangu_ultra_moe":
         return "pangu_ultra_moe"
+    if mt == "dots3_note":
+        return "dots3_note"
     if "layer_group_size" in hf and "kda_lower_bound" in hf:  # the published config names no model_type here
         return "ling_flash"
     if mt == "solar_open2":
@@ -193,6 +195,8 @@ def config_from_hf(path: str, **overrides):
         kwargs = _laguna_kwargs(hf)
     elif fam == "pangu_ultra_moe":
         kwargs = _pangu_kwargs(hf)
+    elif fam == "dots3_note":
+        kwargs = _dots3_kwargs(hf)
     elif fam == "ling_flash":
         kwargs = _ling_kwargs(hf)
     elif fam == "solar_open2":
@@ -269,6 +273,57 @@ def _pangu_kwargs(hf: Dict) -> Dict:
         moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=hf["first_k_dense_replace"],
         moe_router="sigmoid", moe_shared_d_ff=hf["moe_intermediate_size"] * hf.get("n_shared_experts", 1),
         moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)),
+    )
+
+
+# dots3-note's two kinds of attention layer, as its `layer_types` name them and as this repo does
+_DOTS3_KINDS = {"full_attention": "sparse_latent_attention", "sliding_attention": "sliding_latent_attention"}
+
+
+def _dots3_kwargs(hf: Dict) -> Dict:
+    """`dots3_note` config keys (dots-studio's dots3-note-prev, the language
+    model's) -> TransformerConfig fields: latent attention of two shapes by
+    `layer_types`, the full layers' under a learned index (`index_*`), the
+    sliding ones' (`swa_*`) banded, both latents rescaled, a gate a head;
+    DeepSeek-V3's sigmoid router without groups. What the keys leave open is
+    listed in bench/reference/dots3_note.py; a key that would change a layer's
+    equations from what is written there is refused by name."""
+    for key, want in (("attention_bias", False), ("norm_topk_prob", True), ("n_shared_experts", 1),
+                      ("hidden_act", "silu"), ("rope_scaling", None), ("attention_gate_type", "headwise"),
+                      ("swa_attention_gate_type", "headwise"), ("scoring_func", "sigmoid"),
+                      ("topk_method", "noaux_tc"), ("moe_layer_freq", 1)):
+        if hf.get(key, want) != want:
+            raise NotImplementedError(f"dots3_note with {key}={hf[key]!r} is not supported")
+    if (hf.get("n_group") or 1) > 1:
+        raise NotImplementedError(f"dots3_note with n_group={hf['n_group']!r} (group-limited routing) is not supported")
+    unknown = set(hf["layer_types"]) - set(_DOTS3_KINDS)
+    if unknown:
+        raise NotImplementedError(f"dots3_note with layer_types {sorted(unknown)} is not supported")
+    rescale = bool(hf.get("apply_mla_qkv_lora_rescale", False))
+    shape = lambda pre: dict(
+        n_heads=hf[pre + "num_attention_heads"], q_lora_rank=hf[pre + "q_lora_rank"],
+        kv_lora_rank=hf[pre + "kv_lora_rank"], qk_nope_head_dim=hf[pre + "qk_nope_head_dim"],
+        qk_rope_head_dim=hf[pre + "qk_rope_head_dim"], v_head_dim=hf[pre + "v_head_dim"], rescale=rescale)
+    full = shape("")
+    return dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=hf["num_hidden_layers"],
+        n_heads=hf["num_attention_heads"], d_ff=hf["intermediate_size"],
+        max_seq_len=hf["max_position_embeddings"], pos_embed="rope", rope_theta=float(hf["rope_theta"]),
+        norm="rmsnorm", layer_norm_epsilon=hf.get("rms_norm_eps", 1e-5), activation="silu", glu=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)), use_bias=False, flash_prefill=True,
+        layer_types=tuple(_DOTS3_KINDS[kind] for kind in hf["layer_types"]), attn_gate="per_head",
+        latent_kinds=(
+            ("sparse_latent_attention", LatentSpec(**full, index_heads=hf["index_n_heads"],
+                                                   index_head_dim=hf["index_head_dim"], index_topk=hf["index_topk"])),
+            ("sliding_latent_attention", LatentSpec(**shape("swa_"), window=hf["sliding_window_size"])),
+        ),
+        rope_kinds=(("sparse_latent_attention", RopeSpec(theta=float(hf["rope_theta"]))),
+                    ("sliding_latent_attention", RopeSpec(theta=float(hf["swa_rope_theta"])))),
+        **{k: full[k] for k in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")},
+        moe_experts=hf["n_routed_experts"], moe_top_k=hf["num_experts_per_tok"],
+        moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=hf["first_k_dense_replace"],
+        moe_router="sigmoid", moe_shared_d_ff=hf["moe_intermediate_size"] * hf.get("n_shared_experts", 1),
+        moe_routed_scale=float(hf.get("routed_scaling_factor", 1.0)), moe_token_block=4096,
     )
 
 
@@ -697,6 +752,53 @@ def _load_pangu_ultra_moe(sd: Dict, cfg: TransformerConfig) -> Dict:
     return lm
 
 
+_DOTS3_NORMS = (("ln_attn", "input_layernorm"), ("ln_mlp", "post_attention_layernorm"))
+_DOTS3_ATTN = _PANGU_ATTN + (("gate_proj", "gate_proj"),)
+_DOTS3_INDEX = ("wq_b", "wk", "weights_proj")
+
+
+def _load_dots3_note(sd: Dict, cfg: TransformerConfig) -> Dict:
+    """`dots3_note` (dots3-note-prev's language model), in DeepSeek-V3's
+    tensor names: `self_attn.{q_a_proj, q_a_layernorm, q_b_proj,
+    kv_a_proj_with_mqa, kv_a_layernorm, kv_b_proj, o_proj}`, the full layers'
+    `self_attn.indexer.{wq_b, wk, k_norm, weights_proj}` (DeepSeek-V3.2's),
+    `mlp.gate` with its `e_score_correction_bias`, `mlp.experts.N.*`,
+    `mlp.shared_experts.*`. The gate a head is taken as `self_attn.gate_proj`
+    and the rotary dimensions as they lie (half-split): both ASSUMED, no
+    published file settles them. The tree holds experts [moe_local_offset,
+    + experts_held) side by side. UNCHECKED against the published weights: no
+    checkpoint of the family was at hand, the round trip in
+    tests/test_dots3_note.py is over a random state dict."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lm: Dict = {
+        "embed_tokens": {"embedding": sd[f"{pre}embed_tokens.weight"]},
+        "ln_f": _ln(sd, f"{pre}norm", bias=False),
+        "lm_head": _dense(sd["lm_head.weight"].T),
+    }
+    held = range(cfg.moe_local_offset, cfg.moe_local_offset + cfg.experts_held)
+    for i in range(cfg.n_layers):
+        p = f"{pre}layers.{i}."
+        block = {n: _ln(sd, p + hf_n, bias=False) for n, hf_n in _DOTS3_NORMS}
+        attn = {n: _dense(sd[p + f"self_attn.{hf_n}.weight"].T) for n, hf_n in _DOTS3_ATTN}
+        attn.update({n: _ln(sd, p + f"self_attn.{hf_n}", bias=False) for n, hf_n in _PANGU_ATTN_NORMS})
+        if cfg.latent_of(cfg.layer_op(i)).index_topk:
+            attn["indexer"] = {n: _dense(sd[p + f"self_attn.indexer.{n}.weight"].T) for n in _DOTS3_INDEX}
+            attn["indexer"]["k_norm"] = _ln(sd, p + "self_attn.indexer.k_norm")
+        block["attn"] = attn
+        if cfg.layer_ffn(i) == "dense":
+            block["mlp"] = {n: _dense(sd[p + f"mlp.{n}.weight"].T) for n in _GLU}
+        else:
+            block["mlp"] = {
+                "router": _dense(sd[p + "mlp.gate.weight"].T),
+                "expert_bias": {"bias": sd[p + "mlp.gate.e_score_correction_bias"]},
+                **{f"expert_{n.split('_')[0]}": _dense(np.concatenate(
+                    [sd[p + f"mlp.experts.{e}.{n}.weight"].T for e in held], axis=1)) for n in _GLU},
+                **{f"shared_{n.split('_')[0]}": _dense(sd[p + f"mlp.shared_experts.{n}.weight"].T) for n in _GLU},
+            }
+        lm[f"block_{i}"] = block
+    return lm
+
+
 _FALCON_NORMS = (("ln_attn", "input_layernorm"), ("ln_mlp", "pre_ff_layernorm"))
 _FALCON_ATTN = ("q_proj", "k_proj", "v_proj", "o_proj")
 # (leaf, its name under `mamba.`): the heads' three vectors are bare parameters there
@@ -953,6 +1055,7 @@ _LOADERS: Dict[str, Callable] = {
     "gpt_bigcode": _load_gpt_bigcode,
     "lfm2_moe": _load_lfm2_moe,
     "pangu_ultra_moe": _load_pangu_ultra_moe,
+    "dots3_note": _load_dots3_note,
     "falcon_h1": _load_falcon_h1,
 }
 
@@ -1132,6 +1235,42 @@ def _export_pangu_ultra_moe(lm: Dict, cfg: TransformerConfig) -> Dict:
         sd[p + "enorm.weight"], sd[p + "hnorm.weight"] = _f32(m["enorm"]["scale"]), _f32(m["hnorm"]["scale"])
         sd[p + "eh_proj.weight"] = _f32(m["eh_proj"]["kernel"]).T
         _export_pangu_block(m["block"], p, cfg, sd)
+    return sd
+
+
+def _export_dots3_note(lm: Dict, cfg: TransformerConfig) -> Dict:
+    """Inverse of `_load_dots3_note` (as unchecked against the published
+    weights): the experts held go out under their indices in the whole model."""
+    sd = {
+        "model.embed_tokens.weight": _f32(lm["embed_tokens"]["embedding"]),
+        "model.norm.weight": _f32(lm["ln_f"]["scale"]),
+        "lm_head.weight": _f32(lm["lm_head"]["kernel"]).T,
+    }
+    for i in range(cfg.n_layers):
+        b, p = lm[f"block_{i}"], f"model.layers.{i}."
+        for n, hf_n in _DOTS3_NORMS:
+            sd[p + f"{hf_n}.weight"] = _f32(b[n]["scale"])
+        for n, hf_n in _DOTS3_ATTN:
+            sd[p + f"self_attn.{hf_n}.weight"] = _f32(b["attn"][n]["kernel"]).T
+        for n, hf_n in _PANGU_ATTN_NORMS:
+            sd[p + f"self_attn.{hf_n}.weight"] = _f32(b["attn"][n]["scale"])
+        if "indexer" in b["attn"]:
+            index = b["attn"]["indexer"]
+            for n in _DOTS3_INDEX:
+                sd[p + f"self_attn.indexer.{n}.weight"] = _f32(index[n]["kernel"]).T
+            sd[p + "self_attn.indexer.k_norm.weight"] = _f32(index["k_norm"]["scale"])
+            sd[p + "self_attn.indexer.k_norm.bias"] = _f32(index["k_norm"]["bias"])
+        if "router" not in b["mlp"]:
+            for n in _GLU:
+                sd[p + f"mlp.{n}.weight"] = _f32(b["mlp"][n]["kernel"]).T
+            continue
+        sd[p + "mlp.gate.weight"] = _f32(b["mlp"]["router"]["kernel"]).T
+        sd[p + "mlp.gate.e_score_correction_bias"] = _f32(b["mlp"]["expert_bias"]["bias"])
+        for n in _GLU:
+            stack = _f32(b["mlp"][f"expert_{n.split('_')[0]}"]["kernel"])
+            for g, mat in enumerate(np.split(stack, cfg.experts_held, axis=1)):
+                sd[p + f"mlp.experts.{cfg.moe_local_offset + g}.{n}.weight"] = mat.T
+            sd[p + f"mlp.shared_experts.{n}.weight"] = _f32(b["mlp"][f"shared_{n.split('_')[0]}"]["kernel"]).T
     return sd
 
 
@@ -1386,6 +1525,7 @@ _EXPORTERS: Dict[str, Callable] = {
     "gpt_bigcode": _export_gpt_bigcode,
     "lfm2_moe": _export_lfm2_moe,
     "pangu_ultra_moe": _export_pangu_ultra_moe,
+    "dots3_note": _export_dots3_note,
     "falcon_h1": _export_falcon_h1,
 }
 
@@ -1401,6 +1541,8 @@ def infer_family(cfg) -> str:
         return "falcon_h1"
     if getattr(cfg, "has_linear_layers", False):
         return "ling_flash" if cfg.has_latent_layers else "solar_open2"
+    if getattr(cfg, "latent_kinds", ()):
+        return "dots3_note"
     if getattr(cfg, "has_latent_layers", False):
         return "pangu_ultra_moe"
     if getattr(cfg, "attention_kinds", ()):
@@ -1547,6 +1689,29 @@ def config_to_hf(cfg: TransformerConfig, family: str = None) -> Dict:
             attention_in_multiplier=m.attention_in, attention_out_multiplier=m.attention_out,
             key_multiplier=m.key, ssm_in_multiplier=m.ssm_in, ssm_out_multiplier=m.ssm_out,
             ssm_multipliers=list(m.ssm), mlp_multipliers=list(m.mlp),
+        )
+    if family == "dots3_note":
+        kinds = {ours: theirs for theirs, ours in _DOTS3_KINDS.items()}
+        full, swa = cfg.latent_of("sparse_latent_attention"), cfg.latent_of("sliding_latent_attention")
+        shape = lambda pre, spec: {
+            pre + "num_attention_heads": spec.n_heads, pre + "num_key_value_heads": spec.n_heads,
+            pre + "q_lora_rank": spec.q_lora_rank, pre + "kv_lora_rank": spec.kv_lora_rank,
+            pre + "qk_nope_head_dim": spec.qk_nope_head_dim, pre + "qk_rope_head_dim": spec.qk_rope_head_dim,
+            pre + "v_head_dim": spec.v_head_dim}
+        return dict(
+            model_type="dots3_note", vocab_size=cfg.vocab_size, hidden_size=cfg.d_model,
+            intermediate_size=cfg.d_ff, num_hidden_layers=cfg.n_layers, max_position_embeddings=cfg.max_seq_len,
+            attention_bias=False, hidden_act="silu", rms_norm_eps=cfg.layer_norm_epsilon, rope_scaling=None,
+            layer_types=[kinds[kind] for kind in cfg.layer_types], **shape("", full), **shape("swa_", swa),
+            rope_theta=cfg.rope_of("sparse_latent_attention").theta,
+            swa_rope_theta=cfg.rope_of("sliding_latent_attention").theta, sliding_window_size=swa.window,
+            index_n_heads=full.index_heads, index_head_dim=full.index_head_dim, index_topk=full.index_topk,
+            apply_mla_qkv_lora_rescale=full.rescale, attention_gate_type="headwise",
+            swa_attention_gate_type="headwise", first_k_dense_replace=cfg.moe_dense_layers, moe_layer_freq=1,
+            n_routed_experts=cfg.moe_experts, n_shared_experts=1, num_experts_per_tok=cfg.moe_top_k,
+            moe_intermediate_size=cfg.expert_d_ff, norm_topk_prob=True, scoring_func="sigmoid",
+            topk_method="noaux_tc", routed_scaling_factor=cfg.moe_routed_scale,
+            tie_word_embeddings=cfg.tie_embeddings,
         )
     if family == "pangu_ultra_moe":
         return dict(

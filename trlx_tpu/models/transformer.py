@@ -16,6 +16,7 @@ Decode uses a functional KV cache (static max length, dynamic_update_slice
 writes) so the sampling loop is a single compiled lax.while_loop.
 """
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
 
@@ -64,11 +65,40 @@ class Multipliers:
             raise ValueError(f"multipliers: ssm names {len(self.ssm)} of 5 column groups, mlp {len(self.mlp)} of 2")
 
 
+@dataclass(frozen=True)
+class LatentSpec:
+    """The shape of one kind of latent-attention layer, where a model's kinds
+    differ (`TransformerConfig.latent_kinds`; `LatentAttention` has the
+    equations). `rescale`: each normed latent times sqrt(d_model / its rank).
+    `window`: query t attends keys j with 0 <= t - j < window. `index_topk` > 0:
+    a learned index (`index_heads` heads of `index_head_dim`, ONE key a token)
+    scores the attendable positions and the layer attends to the `index_topk`
+    largest (DeepSeek-V3.2's sparse attention); such a layer keeps the index's
+    key a token beside the latent."""
+
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rescale: bool = False
+    window: Optional[int] = None
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+
+    @property
+    def width(self) -> int:
+        """Values a token caches in the latent plane: the normed latent and the rotated shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
 # A layer's operator. What a kind keeps between steps (planes a token, arrays a
 # slot, or both) is `TransformerConfig.kind_keeps`'s to say, and every predicate
 # about caches asks that: a kind is in no list of "attention" or "state" kinds.
 LAYER_KINDS = ("attention", "full_attention", "sliding_attention", "latent_attention", "conv", "linear_attention",
-               "ssm_attention")
+               "ssm_attention", "sliding_latent_attention", "sparse_latent_attention")
 
 
 @dataclass(frozen=True)
@@ -188,6 +218,11 @@ class TransformerConfig:
     # every token meets, computed whole on every chip.
     moe_shared_d_ff: int = 0
     moe_routed_scale: float = 1.0
+    # `SparseMoE` only: the tokens dispatched at once (0: all of a call's). The
+    # dispatch holds every token once for each of its `moe_top_k` experts, held
+    # here or not: 24,576 positions of 5,120 x 8 are 1.9 GB a copy, so a model
+    # that prefills prompts that long routes them a block at a time.
+    moe_token_block: int = 0
     # Latent attention (MLA, DeepSeek-V2; `layer_types` kind
     # "latent_attention", `LatentAttention` below): queries through a
     # low-rank pair of `q_lora_rank`, keys and values through one shared
@@ -203,6 +238,13 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Latent layers of more than one shape in one stack (dots3-note's): a kind
+    # of `layer_types` ("sliding_latent_attention": banded; "sparse_latent_attention":
+    # a learned index chooses the positions attended to) mapped to its own
+    # `LatentSpec`, as `rope_kinds` maps a kind to its rotary settings. Empty
+    # for every other family, whose "latent_attention" layers read the five
+    # fields above.
+    latent_kinds: Tuple[Tuple[str, LatentSpec], ...] = ()
     # a = x + N(Attn(N(x))); y = a + N(FFN(N(a))): a norm after the operator
     # and after the feed-forward too (`ln_post_attn`, `ln_post_mlp`), before
     # each residual add (openPangu's `sandwich_norm`)
@@ -288,14 +330,30 @@ class TransformerConfig:
         if self.kda_gate_rank < 0 or not 0.0 < self.kda_beta_max <= 2.0:
             raise ValueError(f"kda_gate_rank {self.kda_gate_rank} must be >= 0 and kda_beta_max "
                              f"{self.kda_beta_max} in (0, 2]")
-        if self.has_latent_layers:
+        if self.latent_kinds:
+            object.__setattr__(self, "latent_kinds", tuple(
+                (k, s if isinstance(s, LatentSpec) else LatentSpec(**s)) for k, s in self.latent_kinds))
+        for kind, spec in ((k, self.latent_of(k)) for k in dict.fromkeys(self.layer_types)):
+            if spec is None:
+                if kind in ("sliding_latent_attention", "sparse_latent_attention"):
+                    raise ValueError(f"{kind} layers need their LatentSpec in latent_kinds")
+                continue
             sizes = ("kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim")
-            missing = [n for n in sizes if getattr(self, n) <= 0]
-            if missing or self.q_lora_rank < 0:
-                raise ValueError(f"latent_attention layers need {missing or ['q_lora_rank']} "
+            missing = [n for n in sizes if getattr(spec, n) <= 0]
+            if missing or spec.q_lora_rank < 0:
+                raise ValueError(f"{kind} layers need {missing or ['q_lora_rank']} "
                                  "(q_lora_rank 0 or None is a full-rank query, never a negative rank)")
-            if self.qk_rope_head_dim % 2:
-                raise ValueError(f"qk_rope_head_dim {self.qk_rope_head_dim} must be even")
+            if spec.qk_rope_head_dim % 2:
+                raise ValueError(f"qk_rope_head_dim {spec.qk_rope_head_dim} must be even")
+            if (kind == "sliding_latent_attention") != (spec.window is not None) \
+                    or (kind == "sparse_latent_attention") != (spec.index_topk > 0):
+                raise ValueError(f"{kind}: a sliding latent layer names a window and no index, a sparse one an "
+                                 f"index (index_topk > 0) and no window; got {spec}")
+            if spec.index_topk and (min(spec.index_heads, spec.index_head_dim) <= 0 or spec.index_head_dim % 4
+                                    or not spec.q_lora_rank):
+                raise ValueError(f"{kind}: the index reads the query's latent (q_lora_rank > 0) through "
+                                 f"index_heads > 0 heads of index_head_dim (a multiple of 4: half of it rotates)")
+        if self.has_latent_layers:
             unsupported = [what for on, what in (
                 (self.pos_embed != "rope", f"pos_embed={self.pos_embed!r}"), (self.alibi, "alibi"),
                 (self.lora_rank > 0, "lora_rank"), (self.prefix_tokens > 0, "prefix_tokens"),
@@ -304,7 +362,8 @@ class TransformerConfig:
                 (self.attn_gate == "elementwise", "attn_gate='elementwise'"),
             ) if on]
             if unsupported:
-                raise NotImplementedError(f"latent_attention layers with {', '.join(unsupported)} are not supported")
+                kinds = " / ".join(k for k in dict.fromkeys(self.layer_types) if self.latent_of(k) is not None)
+                raise NotImplementedError(f"{kinds} layers with {', '.join(unsupported)} are not supported")
         if self.has_linear_layers:
             unsupported = [what for on, what in (
                 (self.lora_rank > 0, "lora_rank"), (self.prefix_tokens > 0, "prefix_tokens"),
@@ -423,9 +482,24 @@ class TransformerConfig:
     def has_conv_layers(self) -> bool:
         return "conv" in self.layer_types
 
+    def latent_of(self, kind: Optional[str]) -> Optional[LatentSpec]:
+        """The shape of a latent-attention layer of `kind`: its own
+        (`latent_kinds`), the model's five fields for "latent_attention", None
+        for a kind that is no latent attention."""
+        spec = dict(self.latent_kinds).get(kind)
+        if spec is None and kind == "latent_attention":
+            spec = LatentSpec(self.n_heads, self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                              self.qk_rope_head_dim, self.v_head_dim)
+        return spec
+
     @property
     def has_latent_layers(self) -> bool:
-        return "latent_attention" in self.layer_types
+        return any(self.latent_of(kind) is not None for kind in set(self.layer_types))
+
+    @property
+    def has_index_layers(self) -> bool:
+        """Whether some layer chooses the positions it attends to (`LatentSpec.index_topk`)."""
+        return any(spec.index_topk > 0 for kind, spec in self.latent_kinds if kind in self.layer_types)
 
     @property
     def has_linear_layers(self) -> bool:
@@ -468,13 +542,15 @@ class TransformerConfig:
 
     @property
     def latent_width(self) -> int:
-        """Values a latent layer caches a token: the normed latent and the
-        rotated key all heads share."""
+        """Values a "latent_attention" layer caches a token (the model's own
+        five fields; a kind of `latent_kinds` has its `LatentSpec.width`)."""
         return self.kv_lora_rank + self.qk_rope_head_dim
 
     def kind_keeps(self, op: str) -> LayerKeeps:
         """What a layer of kind `op` keeps: K and V by head a token for an
-        attention layer, the one latent plane a token for a latent one; the
+        attention layer, the one latent plane a token for a latent one (of
+        its own kind's width) and, where an index chooses its positions, the
+        index's key a token beside it; the
         last `conv_kernel - 1` inputs a row for a `conv` layer; a matrix a head
         (`kda_state_dtype`) and the three convolutions' last inputs a row for
         a `linear_attention` one; for an `ssm_attention` one BOTH: K and V by
@@ -487,8 +563,10 @@ class TransformerConfig:
             return LayerKeeps(slot=(
                 ("state", (self.n_heads, self.head_dim, self.head_dim), self.kda_state_dtype),
                 ("tails", (self.conv_kernel - 1, self.kda_width), None)))
-        if op == "latent_attention":
-            return LayerKeeps(token=(("latent", (self.latent_width,)),))
+        latent = self.latent_of(op)
+        if latent is not None:
+            index = (("index_k", (latent.index_head_dim,)),) if latent.index_topk else ()
+            return LayerKeeps(token=(("latent", (latent.width,)),) + index)
         kv = (("k", (self.kv_heads, self.head_dim)), ("v", (self.kv_heads, self.head_dim)))
         if op == "ssm_attention":
             return LayerKeeps(token=kv, slot=(
@@ -542,9 +620,16 @@ class TransformerConfig:
         return kinds if set(kinds) - {"attention"} else ()
 
     def window_of(self, kind: Optional[str]) -> Optional[int]:
-        """The band of a layer of `kind`: `sliding_window` on a sliding
-        layer and on every layer of a model that names no kinds."""
-        return None if kind == "full_attention" else self.sliding_window
+        """The band of a layer of `kind`: a latent kind's own
+        (`LatentSpec.window`); none on a "full_attention" layer and on a kind
+        that keeps nothing a token; `sliding_window` on every other layer
+        that keeps K and V by head (a model that names no kinds: all)."""
+        latent = self.latent_of(kind)
+        if latent is not None:
+            return latent.window
+        if kind == "full_attention" or (kind is not None and not self.kind_keeps(kind).token):
+            return None
+        return self.sliding_window
 
     def rope_of(self, kind: Optional[str]) -> Optional[RopeSpec]:
         return dict(self.rope_kinds).get(kind)
@@ -681,7 +766,7 @@ def fused_attention_ok(cfg: TransformerConfig, seq_len: Optional[int] = None,
     window = cfg.window_of(kind)
     if cfg.attn_impl not in ("flash", "ring", "blockwise"):
         return False
-    if kind == "latent_attention":
+    if cfg.latent_of(kind) is not None:
         return forward_only and cfg.attn_impl == "flash" and seq_len is not None
     if window is not None and cfg.attn_impl == "ring":
         raise NotImplementedError(
@@ -968,31 +1053,52 @@ class Attention(nn.Module):
 
 class LatentAttention(nn.Module):
     """Multi-head latent attention (MLA: DeepSeek-V2, as `pangu_ultra_moe`
-    configures it). With N an RMSNorm and no bias anywhere:
+    configures it), of the shape `cfg.latent_of(kind)` gives. With N an
+    RMSNorm and no bias anywhere:
 
         c_q = N(x W_qa);  [q_nope_h ; q_rope_h] = c_q W_qb      (heads of dn + dr)
         [c_kv ; k_r] = x W_kva;  c = N(c_kv)                     (dc + dr)
         [k_nope_h ; v_h] = c W_kvb                               (dn + dv a head)
         q_rope_h, k_r rotated at the token's position; k_r is ONE vector for all heads
-        s_h,t = (q_nope_h . k_nope_h,t + q_rope_h . k_r,t) / sqrt(dn + dr), causal softmax
-        o_h = sum_t p_h,t v_h,t;  y = [o_1 .. o_H] W_o
+        s_h,t,j = (q_nope_h,t . k_nope_h,j + q_rope_h,t . k_r,j) / sqrt(dn + dr)
+        p_h,t = softmax over j in A_t;  o_h,t = sum_j p_h,t,j v_h,j;  y = [o_1 .. o_H] W_o
 
-    A token caches `[c ; rotated k_r]`, `cfg.latent_width` values, and nothing
-    else (`layer_cache["latent"]`: `[b, S, width]` in the dense families,
-    two tokens a row of one plane in the paged arena, whose layout
-    ops/paged_attention.py owns). The two bodies meet it two
-    ways. Without a cache, and in a prefill into an empty one
-    (`attn_kernel="prefill"`), keys and values are DECOMPRESSED for the block
-    at hand and go through the fused forward (query/key width dn + dr, value
-    width dv) or the dense products. Every other cached step runs ABSORBED
-    over the latents: with W_kvb split by head into W_uk,h and W_uv,h,
+    `LatentSpec.rescale` (dots3-note's `apply_mla_qkv_lora_rescale`): c_q and
+    c each times sqrt(d_model / its rank), behind its norm; the cached c is
+    the rescaled one. A_t is every j <= t ("latent_attention"); the band
+    t - j < window ("sliding_latent_attention"); or ("sparse_latent_attention",
+    DeepSeek-V3.2's sparse attention) the `index_topk` positions j <= t with
+    the largest index score, all of them while t < index_topk, ties toward the
+    later position (`LatentIndex`):
+
+        I_t,j = sum_g w_t,g relu(qI_t,g . kI_j)      float32; not differentiated
+
+    A token caches `[c ; rotated k_r]`, `LatentSpec.width` values, and in a
+    sparse layer kI_j beside it (`layer_cache["latent"]`, `["index_k"]`:
+    `[b, S, width]` in the dense families; in the paged arena two tokens a row
+    of one plane and a plane of its own, whose layouts ops/paged_attention.py
+    owns). The bodies meet the cache three ways. Without a cache, and in a
+    prefill into an empty one (`attn_kernel="prefill"`), keys and values are
+    DECOMPRESSED for the block at hand and go through the fused forward
+    (query/key width dn + dr, value width dv) or the dense products. Every
+    other cached step runs ABSORBED over the latents: with W_kvb split by
+    head into W_uk,h and W_uv,h,
 
         q_lat_h = W_uk,h^T q_nope_h;  s_h,t = (q_lat_h . c_t + q_rope_h . k_r,t) / sqrt(dn + dr)
         o_h = W_uv,h (sum_t p_h,t c_t)
 
     the same numbers, never a per-head key or value in memory: all heads read
     the same latent rows (one K/V head of width dc + dr whose values are its
-    first dc columns), which is what `paged_attention_latent` is built on."""
+    first dc columns), which is what `paged_attention_latent` is built on. A
+    banded or a sparse layer's prefill into an empty cache goes
+    `PREFILL_QUERY_BLOCK` queries at a time (`prefill_by_query_blocks`): a
+    band's block through the banded fused forward over the keys its band
+    reaches, a sparse block absorbed over the positions its index chose,
+    so that neither per-head queries nor index scores of the whole prompt
+    exist at once. A sparse layer's paged decode step scores a row's cached
+    index keys through its block table (`paged_index_scores`), takes the
+    `index_topk` largest and reads those latents and no others
+    (`paged_latent_rows`)."""
 
     cfg: TransformerConfig
     kind: Optional[str] = None
@@ -1002,9 +1108,10 @@ class LatentAttention(nn.Module):
     def __call__(self, h, attn_bias, positions, layer_cache=None, cache_index=None, attn_mask=None,
                  use_prefix=True, attn_kernel=None):
         cfg = self.cfg
+        spec = cfg.latent_of(self.kind)
         b, t, d = h.shape
-        nh = self.n_heads or cfg.n_heads
-        dc, dn, dr, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        nh = self.n_heads or spec.n_heads
+        dc, dn, dr, dv = spec.kv_lora_rank, spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.v_head_dim
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
         norm = lambda name: nn.RMSNorm(
@@ -1012,19 +1119,27 @@ class LatentAttention(nn.Module):
         # the float64 frequency table (`rope_tables`), whatever the kind names
         rope = cfg.rope_of(self.kind) or RopeSpec(theta=cfg.rope_theta)
         scale = 1.0 / np.sqrt(dn + dr)
+        rescaled = (lambda x, rank: x * float(np.sqrt(d / rank))) if spec.rescale else (lambda x, rank: x)
+        # a banded or a sparse layer's prefill into an empty cache: a block of queries at a time
+        by_blocks = attn_kernel == "prefill" and (spec.window is not None or spec.index_topk > 0)
 
-        if cfg.q_lora_rank:
-            q = dense(nh * (dn + dr), "q_b_proj")(norm("q_a_norm")(dense(cfg.q_lora_rank, "q_a_proj")(h)))
+        if spec.q_lora_rank:
+            c_q = rescaled(norm("q_a_norm")(dense(spec.q_lora_rank, "q_a_proj")(h)), spec.q_lora_rank)
+            q_up = dense(nh * (dn + dr), "q_b_proj")
         else:
-            q = dense(nh * (dn + dr), "q_proj")(h)
-        q = q.reshape(b, t, nh, dn + dr)
+            c_q, q_up = h, dense(nh * (dn + dr), "q_proj")
         # `qk_norm`: over each head's whole query and over the shared rotary key, before
         # rotation: the cached latent holds the normed key, the absorbed form stays exact
-        if cfg.qk_norm:
-            q = norm("q_norm")(q)
-        q_nope, q_rope = q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta, spec=rope)
+        q_norm = norm("q_norm") if cfg.qk_norm else (lambda q: q)
+
+        def queries(c_q, positions):  # rows of the query's latent -> each head's query, rotary part rotated
+            q = q_norm(q_up(c_q).reshape(*c_q.shape[:2], nh, dn + dr))
+            return q[..., :dn], apply_rope(q[..., dn:], positions, cfg.rope_theta, spec=rope)
+
+        if not by_blocks:
+            q_nope, q_rope = queries(c_q, positions)
         kv_a = dense(dc + dr, "kv_a_proj")(h)
-        c = norm("kv_a_norm")(kv_a[..., :dc])
+        c = rescaled(norm("kv_a_norm")(kv_a[..., :dc]), dc)
         k_rope = kv_a[:, :, None, dc:]  # [b, t, 1, dr]
         if cfg.qk_norm:
             k_rope = norm("k_norm")(k_rope)
@@ -1035,29 +1150,46 @@ class LatentAttention(nn.Module):
         gate = None
         if cfg.attn_gate == "per_head":  # as `Attention`'s: sigmoid(x W_g)_h on head h's output
             gate = jax.nn.sigmoid(dense(nh, "gate_proj")(h).astype(jnp.float32)).astype(cfg.dtype)
+        o_proj = dense(d, "o_proj")
 
-        def project_out(out):  # [b, t, nh, dv]
+        def project_out(out, gate=gate):  # [b, n, nh, dv]
             if gate is not None:
                 out = out * gate[..., None]
-            return dense(d, "o_proj")(out.reshape(b, t, nh * dv))
+            return o_proj(out.reshape(*out.shape[:2], nh * dv))
 
         latent = jnp.concatenate([c, k_rope[:, :, 0]], axis=-1)  # [b, t, dc + dr]: what is cached
+        index, index_k = None, None
+        if spec.index_topk:
+            index = LatentIndex(cfg, spec, rope, name="indexer")
+            index_k = index.keys(h, positions)  # [b, t, index_head_dim]: cached beside the latent
 
-        def absorbed_query():
+        def absorbed_query(q_nope, q_rope):
             q_lat = jnp.einsum("bthn,chn->bthc", q_nope, w_kvb[..., :dn])
             return jnp.concatenate([q_lat, q_rope], axis=-1)  # [b, t, nh, dc + dr]
 
         def values_up(o_lat):  # [b, t, nh, dc] -> [b, t, nh, dv]
             return jnp.einsum("bthc,chv->bthv", o_lat, w_kvb[..., dn:])
 
+        def among_chosen(bias, keys):
+            """`bias` [b, 1, t, S] without the attendable positions the index
+            did not choose (`keys` [b, S, index_head_dim]: the block's or the
+            cache's index keys)."""
+            from trlx_tpu.ops import sparse_attention as sparse
+
+            scores = sparse.index_scores(*index.queries(c_q, h, positions), keys)  # [b, t, S] float32
+            chosen = sparse.topk_mask(jnp.where(bias[:, 0] == 0.0, scores, -jnp.inf), spec.index_topk)
+            return bias + jnp.where(chosen, 0.0, -1e9)[:, None].astype(bias.dtype)
+
         new_cache, cached = None, None
         if layer_cache is not None and "table" in layer_cache:
-            # the paged latent arena: as Attention's paged branch, one plane
+            # the paged latent arena: as Attention's paged branch, one plane (and the index's)
             from trlx_tpu.ops import paged_attention as paged
 
             table = layer_cache["table"]
             idx = cache_index if jnp.ndim(cache_index) == 1 else jnp.full((b,), cache_index, jnp.int32)
             new_cache = paged.paged_latent_write(layer_cache, latent, table, idx, attn_mask, values=dc)
+            if index is not None:
+                new_cache.update(paged.paged_plane_write(layer_cache, "index_k", index_k, table, idx, attn_mask))
             new_cache["table"] = table
             if attn_kernel is not None and attn_kernel != "prefill":
                 if t != 1:
@@ -1068,32 +1200,61 @@ class LatentAttention(nn.Module):
                 key_mask = attn_bias[:, 0, 0, :] == 0.0  # as Attention reads it
                 if attn_mask is not None:
                     key_mask &= attn_mask > 0
-                o_lat = paged.paged_attention_latent(
-                    absorbed_query()[:, 0], new_cache["latent"], table, key_mask,
-                    values=dc, scale=scale, out_dtype=cfg.dtype,
-                    interpret=(attn_kernel == "interpret"),
-                )
+                interpret = attn_kernel == "interpret"
+                q_abs = absorbed_query(q_nope, q_rope)[:, 0]
+                if index is not None:
+                    from trlx_tpu.ops import sparse_attention as sparse
+
+                    q_index, w_index = index.queries(c_q, h, positions)
+                    scores = paged.paged_index_scores(
+                        q_index[:, 0], w_index[:, 0], new_cache["index_k"], table, key_mask, interpret=interpret)
+                    columns, chosen = sparse.topk_columns(scores, spec.index_topk)
+                    rows = paged.paged_latent_rows(new_cache["latent"], table, columns, values=dc)
+                    o_lat = sparse.attend_chosen(q_abs, rows, chosen, values=dc, scale=scale, out_dtype=cfg.dtype)
+                else:
+                    o_lat = paged.paged_attention_latent(
+                        q_abs, new_cache["latent"], table, key_mask,
+                        values=dc, scale=scale, out_dtype=cfg.dtype,
+                        interpret=interpret, window=spec.window,
+                    )
                 return project_out(values_up(o_lat[:, None])), new_cache
             if attn_kernel != "prefill":
                 cached = paged.paged_latent_gather(new_cache, table, values=dc)
+                if index is not None:
+                    index_k = paged.paged_plane_gather(new_cache["index_k"], table)
         elif layer_cache is not None:
-            lc = latent.astype(layer_cache["latent"].dtype)
-            if jnp.ndim(cache_index) == 1:
-                cached = jax.vmap(lambda row, x, i: jax.lax.dynamic_update_slice(row, x, (i, 0)))(
-                    layer_cache["latent"], lc, cache_index)
-            else:
-                cached = jax.lax.dynamic_update_slice(layer_cache["latent"], lc, (0, cache_index, 0))
+            def put(plane, x):
+                x = x.astype(plane.dtype)
+                if jnp.ndim(cache_index) == 1:
+                    return jax.vmap(lambda row, x, i: jax.lax.dynamic_update_slice(row, x, (i, 0)))(
+                        plane, x, cache_index)
+                return jax.lax.dynamic_update_slice(plane, x, (0, cache_index, 0))
+
+            cached = put(layer_cache["latent"], latent)
             new_cache = {"latent": cached}
+            if index is not None:
+                new_cache["index_k"] = put(layer_cache["index_k"], index_k)
             if attn_kernel == "prefill":
                 cached = None  # the cache was empty: the block is all there is to attend to
+            elif index is not None:
+                index_k = new_cache["index_k"]
 
         if cached is not None:
             # absorbed, over every cached latent: [b, nh, t, S] scores in f32
-            scores = jnp.einsum("bthc,bsc->bhts", absorbed_query(), cached,
+            if index is not None:
+                attn_bias = among_chosen(attn_bias, index_k)
+            scores = jnp.einsum("bthc,bsc->bhts", absorbed_query(q_nope, q_rope), cached,
                                 preferred_element_type=jnp.float32) * scale
             probs = jax.nn.softmax(scores + attn_bias, axis=-1).astype(cfg.dtype)
             out = values_up(jnp.einsum("bhts,bsc->bthc", probs, cached[..., :dc]))
             return project_out(out), new_cache
+
+        if by_blocks:
+            out = prefill_by_query_blocks(
+                spec, c_q=c_q, c=c, k_rope=k_rope, latent=latent, index_k=index_k, h=h, positions=positions,
+                mask=attn_mask, gate=gate, w_kvb=w_kvb, queries=queries, absorbed_query=absorbed_query,
+                values_up=values_up, project_out=project_out, index=index, scale=scale)
+            return out, new_cache
 
         kv = jnp.einsum("btc,chm->bthm", c, w_kvb)
         k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(k_rope, (b, t, nh, dr))], axis=-1)
@@ -1105,10 +1266,54 @@ class LatentAttention(nn.Module):
 
             out = flash_attention(q, k, v, mask=attn_mask, causal=True).astype(cfg.dtype)
         else:
+            if index is not None:
+                attn_bias = among_chosen(attn_bias, index_k)
             scores = jnp.einsum("bthd,bshd->bhts", q, k, preferred_element_type=jnp.float32) * scale
             probs = jax.nn.softmax(scores + attn_bias, axis=-1).astype(cfg.dtype)
             out = jnp.einsum("bhts,bshd->bthd", probs, v)
         return project_out(out), new_cache
+
+
+class LatentIndex(nn.Module):
+    """The learned index of a sparse latent layer (DeepSeek-V3.2's "lightning
+    indexer", without its Hadamard rotation and FP8 cast of qI and kI). With x
+    the layer's normed input, c_q the query's (rescaled) latent, G =
+    `index_heads` heads of D = `index_head_dim`:
+
+        qI_t,g = c_q,t W_iq                      (`wq_b`, G heads of D)
+        kI_j   = LayerNorm(x_j W_ik)             (`wk`, `k_norm` with a bias, eps 1e-6: ONE key of D a token)
+        the first D / 2 dimensions rotated at the position (rotate-half, the layer's base), on both
+        w_t,g  = (x_t W_w)_g * G^-1/2 * D^-1/2   (`weights_proj`, float32)
+        I_t,j  = sum_g w_t,g relu(qI_t,g . kI_j)    float32 (`ops/sparse_attention.index_scores`)
+    """
+
+    cfg: TransformerConfig
+    spec: LatentSpec
+    rope: RopeSpec
+
+    def setup(self):
+        cfg, spec = self.cfg, self.spec
+        dense = lambda feats: nn.Dense(feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
+        self.wq_b = dense(spec.index_heads * spec.index_head_dim)
+        self.wk = dense(spec.index_head_dim)
+        self.k_norm = nn.LayerNorm(epsilon=1e-6, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
+        self.weights_proj = dense(spec.index_heads)
+
+    def _rotated(self, x, positions):  # [b, n, heads, D]: the first half of D rotates
+        half = self.spec.index_head_dim // 2
+        return jnp.concatenate(
+            [apply_rope(x[..., :half], positions, self.cfg.rope_theta, spec=self.rope), x[..., half:]], axis=-1)
+
+    def keys(self, x, positions):
+        """[b, n, D]: what a token caches for the index."""
+        return self._rotated(self.k_norm(self.wk(x))[:, :, None], positions)[:, :, 0]
+
+    def queries(self, c_q, x, positions):
+        """(qI [b, n, G, D], w [b, n, G] float32) of the query positions."""
+        spec = self.spec
+        q = self.wq_b(c_q).reshape(*c_q.shape[:2], spec.index_heads, spec.index_head_dim)
+        w = self.weights_proj(x).astype(jnp.float32) * float(spec.index_heads ** -0.5 * spec.index_head_dim ** -0.5)
+        return self._rotated(q, positions), w
 
 
 class MLP(nn.Module):
@@ -1472,6 +1677,15 @@ class _Scale(nn.Module):
         return self.param("scale", nn.initializers.ones, self.shape, self.param_dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("top_k", "offset", "act", "n_group", "topk_group"))
+def _routed_block(x, token_mask, router, bias, w_gate, w_up, w_down, *, top_k, offset, act, n_group, topk_group):
+    """`ops.moe.sparse_moe` on one block of a long call's tokens (`SparseMoE`, `moe_token_block`)."""
+    from trlx_tpu.ops import moe
+
+    return moe.sparse_moe(x, router, bias, w_gate, w_up, w_down, top_k=top_k, offset=offset, act=act,
+                          token_mask=token_mask, n_group=n_group, topk_group=topk_group)
+
+
 class SparseMoE(nn.Module):
     """Sigmoid-routed experts with grouped dispatch (LFM2's expert ffn):
 
@@ -1501,13 +1715,22 @@ class SparseMoE(nn.Module):
         router = _Kernel((d, E), cfg.param_dtype, name="router")()
         bias = _Bias((E,), cfg.param_dtype, name="expert_bias")()
         stack = lambda name, shape: _Kernel(shape, cfg.param_dtype, name=name)().astype(cfg.dtype)
-        out, stats = moe.sparse_moe(
-            h.reshape(b * t, d).astype(cfg.dtype), router, bias,
-            stack("expert_gate", (d, G * f)), stack("expert_up", (d, G * f)), stack("expert_down", (f, G * d)),
-            top_k=cfg.moe_top_k, offset=cfg.moe_local_offset, act=activation_fn(cfg),
-            token_mask=None if token_mask is None else token_mask.reshape(b * t),
-            n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
-        )
+        flat = h.reshape(b * t, d).astype(cfg.dtype)
+        stacks = stack("expert_gate", (d, G * f)), stack("expert_up", (d, G * f)), stack("expert_down", (f, G * d))
+        flat_mask = None if token_mask is None else token_mask.reshape(b * t)
+        how = dict(top_k=cfg.moe_top_k, offset=cfg.moe_local_offset, act=activation_fn(cfg),
+                   n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group)
+        block = cfg.moe_token_block
+        if block and b * t > block:  # `moe_token_block` tokens at a time; the counters are the blocks' mean
+            # one jitted function: blocks of one shape (all but a ragged last, in every layer and
+            # every program of the process) are traced once and lowered once a program
+            parts = [_routed_block(flat[i:i + block], None if flat_mask is None else flat_mask[i:i + block],
+                                   router, bias, *stacks, **how)
+                     for i in range(0, b * t, block)]
+            out = jnp.concatenate([y for y, _ in parts])
+            stats = jax.tree_util.tree_map(lambda *xs: sum(xs) / len(xs), *(st for _, st in parts))
+        else:
+            out, stats = moe.sparse_moe(flat, router, bias, *stacks, token_mask=flat_mask, **how)
         self.sow("moe_stats", "stats", stats)
         out = out.reshape(b, t, d)
         if cfg.moe_routed_scale != 1.0:
@@ -1566,8 +1789,8 @@ class Block(nn.Module):
         else:
             if isinstance(attn_bias, dict):  # a bias for each kind of attention layer
                 attn_bias = attn_bias.get(self.op_kind)
-            attn_cls = {"latent_attention": LatentAttention,
-                        "linear_attention": KimiDeltaAttention}.get(self.op_kind, Attention)
+            attn_cls = (LatentAttention if cfg.latent_of(self.op_kind) is not None
+                        else KimiDeltaAttention if self.op_kind == "linear_attention" else Attention)
             m = cfg.multipliers
             attn_out, new_cache = attn_cls(cfg, kind=self.op_kind, n_heads=self.n_heads, name="attn")(
                 h_ln if m.attention_in == 1.0 else h_ln * m.attention_in,
@@ -2206,6 +2429,77 @@ def live_width_index(first, columns: int):
     return sum((columns - w > first) * 1 for w in live_widths(columns)[:-1])
 
 
+# Queries a banded or a sparse latent layer's prefill into an empty cache takes
+# at once (`prefill_by_query_blocks`): a block's per-head queries (128 heads of
+# 576 absorbed: 300 MB) and its index scores against a prompt of 24,576 (200 MB)
+# are what exists at once, where the whole prompt's would be 3.6 and 2.4 GB.
+PREFILL_QUERY_BLOCK = 2048
+
+
+def prefill_by_query_blocks(spec: LatentSpec, *, c_q, c, k_rope, latent, index_k, h, positions, mask, gate,
+                            w_kvb, queries, absorbed_query, values_up, project_out, index, scale):
+    """`LatentAttention`'s prefill into an empty cache for a banded or a
+    sparse layer, `PREFILL_QUERY_BLOCK` queries at a time over the prompt's
+    latents (`c`, `k_rope`, `latent`, `index_k`: whole, they are small). A
+    banded block decompresses keys and values for the columns its band
+    reaches (the block's own and `window - 1` in front, from a tile's edge)
+    and runs the banded fused forward over them; a sparse block scores the
+    prompt with the index, takes the chosen (`chosen_in_block`) and attends
+    absorbed under their mask (`masked_latent_attention`). Causal structure
+    goes by column, which is position in a prompt without holes.
+
+    Every block has the same shapes (a band's reach is cut from a prompt
+    padded by that reach in front; a sparse block is handed the whole
+    prompt's index keys and latents and where it stands, and its kernels
+    pass over the tiles behind it), so the blocks are ONE traced body run in
+    turn (`jax.lax.map`): a prompt of 12 blocks traces, lowers and compiles
+    what a prompt of one does."""
+    from trlx_tpu.ops import sparse_attention as sparse
+    from trlx_tpu.ops.attention import flash_attention
+
+    t = c.shape[1]
+    dc, dn = spec.kv_lora_rank, spec.qk_nope_head_dim
+    block = min(PREFILL_QUERY_BLOCK, t)
+    n_blocks = -(-t // block)
+    # columns in front of a band's block: its reach, from a tile's edge where the block is made of tiles
+    reach = 0 if spec.window is None else spec.window - 1
+    front = -(-reach // 128) * 128 if block % 128 == 0 else reach
+    ends = (front, n_blocks * block - t)
+    padded = lambda x: x if x is None or ends == (0, 0) else jnp.pad(x, ((0, 0), ends) + ((0, 0),) * (x.ndim - 2))
+    cut = lambda x, s, n: jax.lax.dynamic_slice_in_dim(x, s, n, axis=1)
+    c_q, positions, mask, gate = (padded(x) for x in (c_q, positions, mask, gate))
+
+    if spec.window is not None:
+        c, k_rope = padded(c), padded(k_rope)
+
+        def attend(s):  # the block's columns s .. s + block of the prompt lie `front` further in the padded arrays
+            span = lambda x: cut(x, s, front + block)
+            q_nope, q_rope = queries(span(c_q), span(positions))
+            kv = jnp.einsum("btc,chm->bthm", span(c), w_kvb)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(span(k_rope), (*q_rope.shape[:3], k_rope.shape[-1]))], axis=-1)
+            return flash_attention(jnp.concatenate([q_nope, q_rope], axis=-1), k, kv[..., dn:], mask=span(mask),
+                                   causal=True, window=spec.window)[:, front:]
+    else:
+        h, latent, index_k = padded(h), padded(latent), padded(index_k)
+
+        def attend(s):
+            rows = lambda x: cut(x, s, block)
+            allow = sparse.chosen_in_block(*index.queries(rows(c_q), rows(h), rows(positions)), index_k, mask,
+                                           first=s, topk=spec.index_topk)
+            q_abs = absorbed_query(*queries(rows(c_q), rows(positions)))
+            return values_up(sparse.masked_latent_attention(q_abs, latent, allow, values=dc, scale=scale, first=s))
+
+    def one(j):
+        s = j * block
+        return project_out(attend(s).astype(c.dtype), None if gate is None else cut(gate, front + s, block))
+
+    if n_blocks == 1:
+        return one(jnp.int32(0))[:, :t]
+    outs = jax.lax.map(one, jnp.arange(n_blocks, dtype=jnp.int32))  # [blocks, b, block, d]
+    return jnp.moveaxis(outs, 0, 1).reshape(outs.shape[1], n_blocks * block, -1)[:, :t]
+
+
 def slot_state_of(cfg) -> str:
     """What a refusal over slot state names, from what the layers keep
     (`LayerKeeps.slot`): "conv / linear_attention layers keep conv, state, tails a slot"."""
@@ -2242,8 +2536,9 @@ def init_paged_kv_arena(
     """Allocate what each layer of a paged pool keeps (`cfg.layer_keeps`):
     for its planes a token, an arena of `num_blocks` blocks of `block_size`
     token columns shared by every slot through per-row block tables
-    (Attention's paged branch; a latent layer's is one plane of
-    `cfg.latent_width` values a token, in a floating type only); for its
+    (Attention's paged branch; a latent layer's is one plane of its kind's
+    `LatentSpec.width` values a token, and a sparse one's index keys in a
+    plane beside it, in a floating type only); for its
     arrays a row, `[num_slots, *shape]`, a slot's own. Block 0 is reserved by the
     engine as a permanent zero block backing padding table entries, so it
     is never allocated to a request. int8 arenas carry f32 scale planes
@@ -2257,14 +2552,16 @@ def init_paged_kv_arena(
         raise NotImplementedError(
             f"a paged pool over slot state ({slot_state_of(cfg)}) needs its number of slots "
             "and a floating cache type (an int8 arena would hold the rows' state in int8 too)")
-    from trlx_tpu.ops.paged_attention import init_paged_latent_layer, init_paged_layer
+    from trlx_tpu.ops.paged_attention import init_paged_latent_layer, init_paged_layer, init_paged_plane
 
     def layer(i):
-        keeps = cfg.layer_keeps(i)
-        names = tuple(name for name, _ in keeps.token)
-        if names == ("latent",):
-            arena = init_paged_latent_layer(num_blocks, block_size, cfg.latent_width, dtype)
-        elif names:
+        keeps, latent = cfg.layer_keeps(i), cfg.latent_of(cfg.layer_op(i))
+        if latent is not None:
+            # the latent plane (two tokens a row), and any other plane the kind keeps a token, plain
+            arena = init_paged_latent_layer(num_blocks, block_size, latent.width, dtype)
+            arena.update({name: init_paged_plane(num_blocks, block_size, *shape, dtype)
+                          for name, shape in keeps.token if name != "latent"})
+        elif keeps.token:
             arena = init_paged_layer(num_blocks, block_size, cfg.kv_heads, cfg.head_dim, dtype)
         else:
             arena = {}
@@ -2537,6 +2834,55 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         layer_types=("ssm_attention",) * 2,
         ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_conv_kernel=4, ssm_chunk=16,
         multipliers=_FALCON_H1_34B_MULTIPLIERS,
+    ),
+    # dots3-note-prev's language model (dots-studio, `dots3_note`; 288B parameters,
+    # ~17B active): latent attention of TWO shapes in one stack, 13 full layers
+    # (128 heads of 128 + 64 over a latent of 512, theta 8e7) on which a learned
+    # index (64 heads of 128, one key a token) chooses the 2,048 positions
+    # attended to, and 33 banded ones (64 heads of 192 + 64 over a latent of
+    # 1,024, window 513, theta 5e4), both latents rescaled, a sigmoid gate a
+    # head on both; one dense SwiGLU layer, then 256 sigmoid-routed experts (8 a
+    # token, no groups, normalised, scaled 1) beside one shared expert. The
+    # published sizes; a cut (depth and its kinds, experts held here, vocabulary)
+    # arrives as model_extra_configs. The towers and the multi-token block are
+    # not part of this preset.
+    "dots3-note-prev": dict(
+        d_model=5120, n_layers=46, n_heads=128, d_ff=13824, max_seq_len=524288,
+        pos_embed="rope", rope_theta=80000000.0, norm="rmsnorm", layer_norm_epsilon=1e-5,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=tuple("sparse_latent_attention" if i < 2 or i % 4 == 1 else "sliding_latent_attention"
+                          for i in range(46)),
+        latent_kinds=(
+            ("sparse_latent_attention", LatentSpec(128, 1024, 512, 128, 64, 128, rescale=True,
+                                                   index_heads=64, index_head_dim=128, index_topk=2048)),
+            ("sliding_latent_attention", LatentSpec(64, 1024, 1024, 192, 64, 128, rescale=True, window=513)),
+        ),
+        rope_kinds=(("sparse_latent_attention", RopeSpec(theta=80000000.0)),
+                    ("sliding_latent_attention", RopeSpec(theta=50000.0))),
+        q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        attn_gate="per_head",
+        moe_experts=256, moe_top_k=8, moe_d_ff=1536, moe_dense_layers=1, moe_router="sigmoid",
+        moe_shared_d_ff=1536, moe_routed_scale=1.0, moe_token_block=4096,
+    ),
+    # the same stack at test size: the leading dense layer and one period behind
+    # it, a window of 5 and an index that keeps 6, both shorter than a test's
+    # prompt; 8 experts (2 a token) beside a shared one
+    "dots3-note-tiny": dict(
+        d_model=64, n_layers=5, n_heads=4, d_ff=128, max_seq_len=256,
+        pos_embed="rope", rope_theta=80000000.0, norm="rmsnorm", layer_norm_epsilon=1e-5,
+        activation="silu", glu=True, tie_embeddings=False, use_bias=False, flash_prefill=True,
+        layer_types=("sparse_latent_attention",) + ("sliding_latent_attention",) * 3 + ("sparse_latent_attention",),
+        latent_kinds=(
+            ("sparse_latent_attention", LatentSpec(4, 24, 32, 16, 8, 12, rescale=True,
+                                                   index_heads=4, index_head_dim=16, index_topk=6)),
+            ("sliding_latent_attention", LatentSpec(2, 24, 48, 24, 8, 12, rescale=True, window=5)),
+        ),
+        rope_kinds=(("sparse_latent_attention", RopeSpec(theta=80000000.0)),
+                    ("sliding_latent_attention", RopeSpec(theta=50000.0))),
+        q_lora_rank=24, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=12,
+        attn_gate="per_head",
+        moe_experts=8, moe_top_k=2, moe_d_ff=32, moe_dense_layers=1, moe_router="sigmoid",
+        moe_shared_d_ff=32, moe_routed_scale=1.0,
     ),
     # Mixture-of-experts (beyond the reference): experts shard over `tensor`
     "moe-tiny": dict(

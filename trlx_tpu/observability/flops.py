@@ -58,16 +58,19 @@ def layer_matmul_flops(model_cfg, i: int) -> float:
         c, hw = model_cfg, model_cfg.n_heads * model_cfg.head_dim
         op = 2 * 5 * d * hw + 2 * 2 * d * c.n_heads + 2 * c.conv_kernel * c.kda_width \
             + 7 * c.n_heads * c.head_dim * c.head_dim
-    elif model_cfg.layer_op(i) == "latent_attention":
+    elif _latent_of(model_cfg, i) is not None:
         # decompressed, as a forward or a prefill runs it: the query's
         # low-rank pair (or its one full-rank product), the latent and shared
         # rotary key, the per-head keys and values out of the latent, the
-        # output projection
-        c, heads = model_cfg, _layer_heads(model_cfg, i)
+        # output projection; where an index chooses the positions, its
+        # queries, its one key and its heads' weights
+        c, heads = _latent_of(model_cfg, i), _layer_heads(model_cfg, i)
         qk = c.qk_nope_head_dim + c.qk_rope_head_dim
         query = d * c.q_lora_rank + c.q_lora_rank * heads * qk if c.q_lora_rank else d * heads * qk
-        op = 2 * (query + d * c.latent_width
+        op = 2 * (query + d * c.width
                   + c.kv_lora_rank * heads * (c.qk_nope_head_dim + c.v_head_dim) + heads * c.v_head_dim * d)
+        if c.index_topk:
+            op += 2 * (c.q_lora_rank * c.index_heads * c.index_head_dim + d * (c.index_head_dim + c.index_heads))
         if getattr(model_cfg, "attn_gate", "none") == "per_head":
             op += 2 * d * heads
     else:
@@ -90,9 +93,15 @@ def layer_matmul_flops(model_cfg, i: int) -> float:
     return op + 2 * d * model_cfg.moe_experts + active * 2 * mats * d * model_cfg.expert_d_ff + shared
 
 
+def _latent_of(model_cfg, i: int):
+    """Layer i's `LatentSpec`, None for a layer that is no latent attention."""
+    return model_cfg.latent_of(model_cfg.layer_op(i)) if hasattr(model_cfg, "latent_of") else None
+
+
 def _layer_heads(model_cfg, i: int) -> int:
     heads = getattr(model_cfg, "layer_heads", ())
-    return heads[i] if heads else model_cfg.n_heads
+    latent = _latent_of(model_cfg, i)
+    return heads[i] if heads else latent.n_heads if latent is not None else model_cfg.n_heads
 
 
 def layer_attention_flops(model_cfg, i: int, ctx: float) -> float:
@@ -105,9 +114,14 @@ def layer_attention_flops(model_cfg, i: int, ctx: float) -> float:
     kind = model_cfg.layer_op(i)
     if kind in ("conv", "linear_attention"):  # nothing grows with the context
         return 0.0
-    if kind == "latent_attention":  # scores at the query/key width, values at their own
-        return 2 * ctx * _layer_heads(model_cfg, i) * (
-            model_cfg.qk_nope_head_dim + model_cfg.qk_rope_head_dim + model_cfg.v_head_dim)
+    latent = _latent_of(model_cfg, i)
+    if latent is not None:
+        # scores at the query/key width, values at their own, over the keys a band or an index's
+        # choice leaves; the index itself scores every key with each of its heads
+        keys = min(ctx, latent.window or ctx, latent.index_topk or ctx)
+        index = 2 * ctx * latent.index_heads * latent.index_head_dim if latent.index_topk else 0
+        return index + 2 * keys * _layer_heads(model_cfg, i) * (
+            latent.qk_nope_head_dim + latent.qk_rope_head_dim + latent.v_head_dim)
     window = model_cfg.window_of(kind)
     keys = ctx if window is None else min(ctx, window)
     return 4 * keys * _layer_heads(model_cfg, i) * model_cfg.head_dim

@@ -120,22 +120,24 @@ def kv_arena_bytes(n_layers: int, kv_heads: int, head_dim: int,
 
 def latent_arena_bytes(n_layers: int, latent_width: int, n_blocks: int,
                        block_size: int, dtype="bfloat16") -> int:
-    """Paged latent arena: ONE plane of `n_blocks x block_size x
-    latent_width` elements a latent-attention layer (layout:
-    ops/paged_attention.py, "Latent"); no int8 form, so no scale planes."""
+    """Paged latent arena: `n_blocks x block_size x latent_width` elements a
+    latent-attention layer, `latent_width` what a token caches there (the
+    latent plane, and a sparse layer's index keys beside it; layouts:
+    ops/paged_attention.py, "Latent" and "Index"); no int8 form, so no scale planes."""
     return int(n_layers * n_blocks * block_size * latent_width * _itemsize(dtype))
 
 
 def paged_arena_bytes(cfg, n_blocks: int, block_size: int, dtype="float32") -> int:
     """The paged arena `init_paged_kv_arena` allocates for `cfg`, from what
     each layer keeps a token (`cfg.layer_keeps`): K and V by head for its
-    attention layers, one latent plane for its latent ones, nothing for a
-    layer whose state is a slot's (`slot_state_bytes` counts that)."""
-    planes = [tuple(name for name, _ in cfg.layer_keeps(i).token) for i in range(cfg.n_layers)]
-    n_latent = planes.count(("latent",))
-    return (kv_arena_bytes(len(planes) - n_latent - planes.count(()), cfg.kv_heads, cfg.head_dim,
-                           n_blocks, block_size, dtype)
-            + latent_arena_bytes(n_latent, getattr(cfg, "latent_width", 0), n_blocks, block_size, dtype))
+    attention layers; for a latent layer its latent plane, of its own kind's
+    width, and the index's keys where it keeps them; nothing for a layer
+    whose state is a slot's (`slot_state_bytes` counts that)."""
+    latent_of = getattr(cfg, "latent_of", lambda kind: None)
+    latent = [i for i in range(cfg.n_layers) if latent_of(cfg.layer_op(i)) is not None]
+    by_head = sum(1 for i in range(cfg.n_layers) if cfg.layer_keeps(i).token) - len(latent)
+    return (kv_arena_bytes(by_head, cfg.kv_heads, cfg.head_dim, n_blocks, block_size, dtype)
+            + sum(latent_arena_bytes(1, sum(cfg.cache_planes(i)), n_blocks, block_size, dtype) for i in latent))
 
 
 def slot_state_bytes(cfg, num_slots: int, dtype="float32") -> int:

@@ -285,9 +285,9 @@ def _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k, interpret=False, 
     The kv blocks outside a q block's band are neither computed (the
     kernel's `run`) nor fetched: their index is held on the band's nearest
     block, and a repeated block index moves no data. Such a call is named
-    `flash_fwd_window` in the device trace; its blocks are `WINDOW_BLOCK`
-    wide at most, so that a band narrower than a forward block is not
-    rounded up to one."""
+    `flash_fwd_window` in the device trace (`flash_fwd_latent_window` with
+    narrower value heads); its blocks are `WINDOW_BLOCK` wide at most, so
+    that a band narrower than a forward block is not rounded up to one."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -310,10 +310,8 @@ def _flash_fwd_pallas(q, k, v, mask, causal, block_q, block_k, interpret=False, 
         mask = jnp.ones((b, tk), jnp.int32)
     maskh = mask.astype(jnp.int32)[:, None, :]  # [b, 1, tk]
     name = "flash_fwd" if window is None else "flash_fwd_window"
-    if hv != hd:
-        if window is not None:
-            raise NotImplementedError("a windowed flash forward with narrower value heads is not written")
-        name = "flash_fwd_latent"
+    if hv != hd:  # a banded latent layer's decompressed prefill is both
+        name = "flash_fwd_latent" if window is None else "flash_fwd_latent_window"
 
     def kv_index(i, j, kk):
         if window is not None:
@@ -1023,7 +1021,9 @@ def _flash_window_forward(q, k, v, mask, window, block_q, block_k):
                                  interpret=(mode == "interpret"), window=window)
     # a multi-device mesh has no shard_map wrapper for the banded kernel
     note_kernel_path("flash_fwd_window", "xla", q.shape)
-    return blockwise_attention(q, k, v, mask, True, block_k, window=window)
+    hv = v.shape[-1]  # narrower values ride padded, as `_flash_latent_forward`'s
+    v = jnp.pad(v, ((0, 0),) * 3 + ((0, q.shape[-1] - hv),))
+    return blockwise_attention(q, k, v, mask, True, block_k, window=window)[..., :hv]
 
 
 def _flash_window_fwd_rule(q, k, v, mask, window, block_q, block_k):

@@ -115,8 +115,25 @@ the SAME latent rows, and the values are the rows' leading columns, so
 and feeds the MXU [heads, width] x [width, T] and [heads, T] x [T, values]: 2 x heads x (width + values) operations for
 `width` x itemsize bytes a cached position, on the v5e's ridge at 128 heads
 in bfloat16. Schedule, masking and the online softmax are the K/V kernel's
-(`_live_schedule` as it is); there is no window and no int8 form. The call
-is named `paged_decode_latent` in the device trace.
+(`_live_schedule` as it is); there is no int8 form. The call is named
+`paged_decode_latent` in the device trace. A banded latent layer's call names
+its `window` as a sliding K/V layer's does ("Window" above: `band_mask`, the
+windowed schedule, a walk that starts at `first`), over a plane of its own
+width (1,024 + 64 = 1,088 at dots3-note's sizes: rows of 2,176 = 17 lane
+tiles); such a call is named `paged_decode_latent_window`, and a call without
+a window is the program it was before the window existed.
+
+Index. A sparse latent layer (`LatentSpec.index_topk`) keeps the index's key
+a token in a plane of its own beside the latent, [n_blocks, block, width]
+(`init_paged_plane`; 128 wide at the published sizes: one lane tile, row-major
+as declared). `paged_index_scores` walks a row's live blocks of it through the
+table (scalar prefetch and `_live_schedule` as they are) and writes the
+index's score of every cached column for the row's one query position:
+[heads, width] x [width, T] on the MXU, relu, the weighted sum over heads, in
+float32; 2 x heads x width operations for width x itemsize bytes a position,
+under the ridge. It is named `paged_index_scores` in the device trace. The
+step then reads the latents it chose by token address (`paged_latent_rows`:
+a gather of arena rows, two tokens each) and no others.
 
 `q` is [b, nh, hd] (ONE query position per row — the decode shape);
 `table` is [b, n_tbl] int32; `key_mask` is [b, n_tbl*block] key validity
@@ -229,6 +246,50 @@ def paged_latent_gather(layer, table, *, values: int) -> jnp.ndarray:
     one dense `[b, n_tbl*block, width]` view, a token a row."""
     rows = _unpack_latent(layer["latent"][table], values)  # [b, n_tbl, block, width]
     return rows.reshape(rows.shape[0], -1, rows.shape[-1])
+
+
+def init_paged_plane(num_blocks: int, block_size: int, width: int, dtype) -> jnp.ndarray:
+    """A zeroed arena of one plain plane, a token a row: `[blocks, block,
+    width]` (a sparse latent layer's index keys: module docstring, "Index")."""
+    return jnp.zeros((num_blocks, block_size, width), dtype)
+
+
+def paged_plane_write(layer, name: str, x, table, start, valid=None) -> Dict[str, jnp.ndarray]:
+    """`paged_kv_write` for the plain plane `layer[name]`: `x` [b, t, width],
+    whole blocks patched over the arena's major dimension."""
+    arena = layer[name]
+    n_blocks, blk, width = arena.shape
+    b, t = x.shape[:2]
+    valid = jnp.ones((b, t), bool) if valid is None else valid.astype(bool)
+    rows = jnp.arange(b)[:, None]
+    phys, src, live = _touched_blocks(table, start, valid, rows, t, n_blocks, blk)
+    new = x[rows, src].reshape(b, -1, blk, width).astype(arena.dtype)
+    patched = jnp.where(live[..., None], new, arena[phys])
+    return {name: arena.at[phys.reshape(-1)].set(patched.reshape(-1, blk, width), mode="drop")}
+
+
+def paged_plane_gather(arena, table) -> jnp.ndarray:
+    """The gather read path of a plain plane: `[b, n_tbl*block, width]`."""
+    rows = arena[table]  # [b, n_tbl, block, width]
+    return rows.reshape(rows.shape[0], -1, rows.shape[-1])
+
+
+def paged_latent_rows(arena, table, columns, *, values: int) -> jnp.ndarray:
+    """The latents at a row's chosen logical `columns` [b, k] -> [b, k,
+    width], by token address: the arena row that holds the token (two tokens
+    a row, `_pack_latent`) is gathered and the token's half of each part
+    taken. Columns past the table read the table's last entry: the caller
+    masks what it did not choose."""
+    n_blocks, half, w2 = arena.shape
+    blk, width = 2 * half, w2 // 2
+    entry = jnp.clip(columns // blk, 0, table.shape[1] - 1)
+    phys = jnp.take_along_axis(table, entry, axis=1)
+    offset = columns % blk
+    pairs = arena.reshape(n_blocks * half, w2)[phys * half + offset // 2]  # [b, k, 2 * width]
+    odd = (offset % 2 == 1)[..., None]
+    head = jnp.where(odd, pairs[..., values:2 * values], pairs[..., :values])
+    tail = jnp.where(odd, pairs[..., width + values:], pairs[..., 2 * values:width + values])
+    return jnp.concatenate([head, tail], axis=-1)
 
 
 def paged_kv_write(
@@ -606,15 +667,16 @@ _LATENT_TILE_TOKENS = 512
 _MAX_LATENT_TILE_ENTRIES = 16
 
 
-def _paged_latent_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, q_ref, qr_ref, *rest,
-                         entries: int, scale: float, values: int, p_dtype):
+def _paged_latent_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest,
+                         entries: int, scale: float, values: int, p_dtype, windowed: bool = False):
     """One grid step of `paged_attention_latent`: tile `tile_ref[w]` of row
     `row_ref[w]`, its `entries` latent blocks in VMEM one under another as
     [T / 2, 2 * width] rows of two tokens (`_pack_latent`). Every head's
     absorbed query multiplies the tile, and the value product reads the
     same tile's value columns: one fetch serves both. The tile's T columns
     are its even tokens, then its odd ones (the mask arrives in that order;
-    a softmax does not mind). Scalar prefetch as `_paged_decode_kernel`.
+    a softmax does not mind). Scalar prefetch as `_paged_decode_kernel`,
+    `first_ref` of a windowed call too.
 
     q_ref      [1, nh, values]: the queries against the value columns
     qr_ref     [1, 2 * nh, 2 * rest]: against the other columns of a row's two
@@ -627,13 +689,17 @@ def _paged_latent_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, q_ref, qr_re
     import jax.experimental.pallas as pl
 
     E = entries
+    first_ref = None
+    if windowed:
+        first_ref, rest = rest[0], rest[1:]
+    q_ref, qr_ref, rest = rest[0], rest[1], rest[2:]
     c_refs, (mask_ref, o_ref, m_scr, l_scr, acc_scr) = rest[:E], rest[E:]
     w = pl.program_id(0)
     first_entry = tile_ref[w] * E
     n_live = n_live_ref[row_ref[w]]
     nh = q_ref.shape[1]
 
-    @pl.when(first_entry == 0)
+    @pl.when(first_entry == (0 if first_ref is None else first_ref[row_ref[w]]))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -682,13 +748,14 @@ def paged_attention_latent(
     scale: float,            # on the scores: 1 / sqrt(the decompressed query/key width)
     out_dtype=None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Fused paged decode attention over a latent arena (module docstring,
     "Latent"). Returns [b, nh, values] in `out_dtype` (defaults to q's):
     softmax(q . latent * scale) over a row's attendable latents, times their
     first `values` columns. Grid, schedule and masking as
-    `paged_attention_decode`; named `paged_decode_latent` in the device
-    trace."""
+    `paged_attention_decode`, its `window` too; named `paged_decode_latent`
+    in the device trace, `paged_decode_latent_window` with a window."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -700,7 +767,10 @@ def paged_attention_latent(
     p_dtype = jnp.float32 if arena.dtype == jnp.float32 else jnp.promote_types(out_dtype, arena.dtype)
 
     E = max(1, min(_LATENT_TILE_TOKENS // blk, _MAX_LATENT_TILE_ENTRIES, n_tbl))
-    blocks, row, tile, n_live, n_work, n_tiles = _live_schedule(table, key_mask, blk, E)
+    windowed = window is not None
+    if windowed:
+        key_mask = band_mask(key_mask, window)
+    blocks, row, tile, n_live, n_work, n_tiles, *first = _live_schedule(table, key_mask, blk, E, windowed)
     T = E * blk
     maskh = jnp.pad(key_mask.astype(jnp.int32), ((0, 0), (0, n_tiles * T - n_tbl * blk)))
     # a tile's even tokens, then its odd ones: the order the kernel's scores come in
@@ -717,7 +787,7 @@ def paged_attention_latent(
         return lambda w, blocks_ref, *_: (blocks_ref[w * E + e], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=4 + len(first),
         grid=(n_work,),  # the steps that hold work, read at run time
         in_specs=[pl.BlockSpec((1, nh, values), slot_index),
                   pl.BlockSpec((1, 2 * nh, 2 * (width - values)), slot_index)]
@@ -732,23 +802,107 @@ def paged_attention_latent(
         ],
     )
     return pl.pallas_call(
-        functools.partial(_paged_latent_kernel, entries=E, scale=scale, values=values, p_dtype=p_dtype),
+        functools.partial(_paged_latent_kernel, entries=E, scale=scale, values=values, p_dtype=p_dtype,
+                          windowed=windowed),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, values), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT_BYTES
         ),
         interpret=interpret,
-        name="paged_decode_latent",
-    )(blocks, row, tile, n_live, q[..., :values], qr, *([arena] * E), maskh)
+        name="paged_decode_latent_window" if windowed else "paged_decode_latent",
+    )(blocks, row, tile, n_live, *first, q[..., :values], qr, *([arena] * E), maskh)
 
 
-def paged_latent_reference(q, arena, table, key_mask, *, values: int, scale: float, out_dtype=None):
+def _paged_index_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, q_ref, w_ref, *rest, entries: int):
+    """One grid step of `paged_index_scores`: the index's score of the
+    `entries * blk` columns of tile `tile_ref[w]` of row `row_ref[w]`.
+
+    q_ref  [1, G, D] the row's index queries, w_ref [1, G, 1] float32 their weights
+    k_refs `entries` x [1, blk, D]: the tile's blocks of index keys
+    o_ref  [1, 1, 1, T] float32
+    """
+    import jax.experimental.pallas as pl
+
+    k_refs, o_ref = rest[:entries], rest[entries]
+    w = pl.program_id(0)
+
+    @pl.when(tile_ref[w] * entries < n_live_ref[row_ref[w]])
+    def _tile():
+        q = q_ref[0]
+        keys = jnp.concatenate([r[0].astype(q.dtype) for r in k_refs], axis=0)  # [T, D]
+        s = jax.lax.dot_general(q, keys, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        o_ref[0, 0] = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0, keepdims=True)
+
+
+def paged_index_scores(
+    q: jnp.ndarray,         # [b, G, D] the index's queries, one position a row
+    w: jnp.ndarray,         # [b, G] float32 the heads' weights
+    arena: jnp.ndarray,     # [n_blocks, blk, D] the index keys (`init_paged_plane`)
+    table: jnp.ndarray,     # [b, n_tbl] int32 physical block ids
+    key_mask: jnp.ndarray,  # [b, n_tbl*blk] key validity (1 = may be chosen)
+    *,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """The index's scores of a row's cached columns (module docstring,
+    "Index"): [b, n_tbl*blk] float32, sum_g w_g relu(q_g . k_j) at every
+    column the mask allows and -inf elsewhere. One pass over each row's live
+    table entries, as `paged_attention_decode` walks them; named
+    `paged_index_scores` in the device trace."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, G, D = q.shape
+    n_blocks, blk, _ = arena.shape
+    n_tbl = table.shape[1]
+    E = max(1, min(_LATENT_TILE_TOKENS // blk, _MAX_LATENT_TILE_ENTRIES, n_tbl))
+    blocks, row, tile, n_live, n_work, n_tiles = _live_schedule(table, key_mask, blk, E)
+    T = E * blk
+
+    def slot_index(w_, blocks_ref, row_ref, *_):
+        return (row_ref[w_], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_work,),  # the steps that hold work, read at run time
+        in_specs=[pl.BlockSpec((1, G, D), slot_index), pl.BlockSpec((1, G, 1), slot_index)]
+        + [pl.BlockSpec((1, blk, D), (lambda e: lambda w_, blocks_ref, *_: (blocks_ref[w_ * E + e], 0, 0))(e))
+           for e in range(E)],
+        out_specs=pl.BlockSpec(
+            (1, 1, 1, T), lambda w_, blocks_ref, row_ref, tile_ref, *_: (row_ref[w_], tile_ref[w_], 0, 0)),
+    )
+    scores = pl.pallas_call(
+        functools.partial(_paged_index_kernel, entries=E),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n_tiles, 1, T), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT_BYTES
+        ),
+        interpret=interpret,
+        name="paged_index_scores",
+    )(blocks, row, tile, n_live, q.astype(jnp.promote_types(q.dtype, arena.dtype)),
+      w.astype(jnp.float32)[..., None], *([arena] * E))
+    # a tile the walk left out was never written: the mask covers it
+    return jnp.where(key_mask.astype(bool), scores.reshape(b, n_tiles * T)[:, :n_tbl * blk], -jnp.inf)
+
+
+def paged_index_reference(q, w, arena, table, key_mask):
+    """XLA shadow of `paged_index_scores`: the plane gathered to a dense view."""
+    keys = paged_plane_gather(arena, table).astype(q.dtype)  # [b, S, D]
+    s = jnp.einsum("bgd,bsd->bgs", q, keys, preferred_element_type=jnp.float32)
+    scores = jnp.einsum("bgs,bg->bs", jnp.maximum(s, 0.0), w.astype(jnp.float32))
+    return jnp.where(key_mask.astype(bool), scores, -jnp.inf)
+
+
+def paged_latent_reference(q, arena, table, key_mask, *, values: int, scale: float, out_dtype=None,
+                           window: Optional[int] = None):
     """XLA shadow of a latent layer's gather read path (`LatentAttention`'s
     absorbed branch at t == 1): gather the table back to a dense view, dense
     softmax with the -1e9 additive bias, the value product on the leading
     `values` columns."""
     out_dtype = out_dtype or q.dtype
+    if window is not None:
+        key_mask = band_mask(key_mask, window)
     cached = paged_latent_gather({"latent": arena}, table, values=values)  # [b, S, width]
     bias = jnp.where(key_mask.astype(bool), 0.0, -1e9)[:, None, :]
     scores = jnp.einsum("bhc,bsc->bhs", q, cached, preferred_element_type=jnp.float32) * scale

@@ -284,6 +284,36 @@ def test_sampler_through_the_dense_cache_matches_the_reference():
     assert np.abs(np.asarray(out["logprobs"]) - want).max() < TOL
 
 
+def test_a_prefill_per_head_and_the_absorbed_step_behind_it_match_the_reference(monkeypatch):
+    """The two forms of a full layer held to each other through the model's
+    own doors: a prefill into an empty cache of prompts longer than the index
+    keeps (21 and 17 against 6), three query blocks of 8, two groups of two
+    heads (`prefill_by_query_blocks`: per head over keys and values
+    decompressed a group at a time), every position's logprob against the
+    reference; then ONE decode step, absorbed over the latents and the index
+    keys that prefill cached, against the reference at the next position."""
+    from trlx_tpu.ops import attention
+
+    monkeypatch.setattr(tr, "PREFILL_QUERY_BLOCK", 8)
+    monkeypatch.setattr(tr, "PREFILL_HEAD_GROUP", 2)
+    cfg = tiny_cfg(attn_impl="flash")
+    model = TransformerLM(cfg)
+    tokens, mask = left_padded(np.random.default_rng(13), [23, 19], 23)  # a prompt of 21 (17) and two tokens behind it
+    params = seeded_params(model, 13, jnp.asarray(tokens), jnp.asarray(mask))
+    step = lambda is_prefill: jax.jit(lambda p, x, cache, m: model.apply(
+        {"params": p}, x, cache, m, is_prefill, method=TransformerLM.decode_step))
+    with jax.default_matmul_precision("highest"):
+        logits, _, cache = step(True)(params, tokens[:, :21], init_kv_cache(cfg, 2, 24), mask[:, :21])
+        after, _, _ = step(False)(params, tokens[:, 21:22], cache, mask[:, 21:22])
+    assert any(shape[1:3] == (8, 2) for shape in attention.KERNEL_PATHS["sparse_latent_fwd"]["xla"])
+    # (the last column's logits are read by nobody: `logprobs_of_next` drops them)
+    got = np.asarray(plain.logprobs_of_next(jnp.concatenate([logits, after, after], axis=1), jnp.asarray(tokens)))
+    want = reference_logprobs(params, cfg, tokens, mask)
+    live = mask[:, :-1] * mask[:, 1:] > 0
+    assert np.abs(got - want)[live].max() < TOL
+    assert live[:, 20:].all()  # the prompt's last position (the prefill's) and the one behind it (the step's)
+
+
 def run_engine(cfg, params, prompts, max_new, engine=None, slots=None, **engine_kw):
     """Every prompt through a paged `InferenceEngine` to `max_new` tokens:
     per request its tokens and the logprobs the engine reports for them."""

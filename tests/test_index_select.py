@@ -77,10 +77,32 @@ def test_chosen_in_block_is_causal_valid_and_skips_the_index_while_everything_fi
     assert not everything[1][:, 9:].any()
 
 
+def _head_inputs(rng, n, S, heads, dc=64, dn=128, dr=64, dv=128):
+    """A sparse layer's prefill operands in bfloat16: each head's query in its
+    two parts, the prompt's latents `c` and shared rotary key, and W_kvb."""
+    bf16 = lambda x: jnp.asarray(x, jnp.bfloat16)
+    return (bf16(rng.normal(size=(1, n, heads, dn)) * 0.3), bf16(rng.normal(size=(1, n, heads, dr)) * 0.3),
+            bf16(rng.normal(size=(1, S, dc))), bf16(rng.normal(size=(1, S, dr))),
+            bf16(rng.normal(size=(dc, heads, dn + dv)) * dc ** -0.5))
+
+
+def _absorbed(q_nope, q_rope, c, k_rope, w_kvb, allow, dn=128):
+    """The same attention as a decode step runs it: the query through W_uk
+    against the latents, the latents' sum through W_uv (`masked_latent_reference`)."""
+    q_abs = jnp.concatenate([jnp.einsum("bnhd,chd->bnhc", q_nope, w_kvb[..., :dn]), q_rope], axis=-1)
+    o_lat = sparse.masked_latent_reference(q_abs, jnp.concatenate([c, k_rope], axis=-1), allow,
+                                           values=c.shape[-1], scale=0.25)
+    return jnp.einsum("bnhc,chv->bnhv", o_lat, w_kvb[..., dn:])
+
+
 def test_the_prefill_kernels_through_the_interpreter_match_their_plain_forms(monkeypatch):
     """`sparse_index_scores` and `sparse_latent_fwd` at shapes their tiles
     divide (two tiles of queries, three of keys), bfloat16 operands as the
-    cell's, a block of queries that stands behind 128 columns."""
+    cell's, a block of queries that stands behind 128 columns. The attention
+    is per head (a key of 128 + 64 of which the 64 are the rotary part all
+    heads share, values of 128; two groups of two heads, the two heads of a
+    group one grid step) and is held to the ABSORBED form over the latents;
+    a query that is allowed nothing gives zeros."""
     from trlx_tpu.ops import attention
 
     monkeypatch.setattr(attention, "kernel_mode", lambda: "interpret")
@@ -96,19 +118,25 @@ def test_the_prefill_kernels_through_the_interpreter_match_their_plain_forms(mon
     np.testing.assert_allclose(np.asarray(got), np.asarray(sparse.index_scores_reference(q, w, k)),
                                rtol=2e-2, atol=2e-2)
 
-    heads, values, width = 2, 128, 136
-    qa = jnp.asarray(rng.normal(size=(1, n, heads, width)) * 0.3, jnp.bfloat16)
-    latent = jnp.asarray(rng.normal(size=(1, S, width)), jnp.bfloat16)
+    heads, values = 4, 128
+    operands = _head_inputs(rng, n, S, heads)
     key_mask = jnp.ones((1, S), jnp.int32).at[0, 300:].set(0)
     allow = sparse.chosen_in_block(q, w, k, key_mask, first=first, topk=40)
     assert int(np.asarray(allow).sum(-1).max()) == 40
-    got = sparse.masked_latent_attention(qa, latent, allow, values=values, scale=0.25, first=first)
-    want = sparse.masked_latent_reference(qa, latent, allow, values=values, scale=0.25)
+    allow = allow.at[0, 37].set(False)
+    got = sparse.masked_latent_attention_by_groups(*operands, allow, group=2, scale=0.25, first=first)
+    assert attention.KERNEL_PATHS["sparse_latent_fwd"].get("interpret")
+    want = _absorbed(*operands, allow)
     assert got.shape == (1, n, heads, values)
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), atol=2e-2)
+    some = np.arange(n) != 37
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:, some], np.asarray(want, np.float32)[:, some], atol=2e-2)
+    assert not np.asarray(got, np.float32)[:, 37].any()
     # shapes the tiles do not divide take the plain form
-    assert sparse.masked_latent_attention(qa[:, :5], latent[:, :133], allow[:, :5, :133], values=values,
-                                          scale=0.25).shape == (1, 5, heads, values)
+    q_nope, q_rope, c, k_rope, w_kvb = operands
+    assert sparse.masked_latent_attention_by_groups(
+        q_nope[:, :5], q_rope[:, :5], c[:, :133], k_rope[:, :133], w_kvb, allow[:, :5, :133], group=2, scale=0.25,
+        first=first).shape == (1, 5, heads, values)
+    assert attention.KERNEL_PATHS["sparse_latent_fwd"].get("xla") == [(1, 5, 2, 128)]
 
 
 @pytest.mark.parametrize("block", [0, 1, 2])
@@ -116,26 +144,27 @@ def test_a_block_of_a_traced_loop_is_the_block_at_a_host_integer(block, monkeypa
     """A prefill runs its blocks as one body under `jax.lax.map`, where a
     block stands is a traced scalar and every block is handed the WHOLE
     prompt: both kernels, through the interpreter, give each block what the
-    plain forms give it, the index's scores 0 on the tiles of columns behind
+    plain forms give it (the attention per head, a head a grid step, against
+    the absorbed form), the index's scores 0 on the tiles of columns behind
     the block's last query."""
     from trlx_tpu.ops import attention
 
     monkeypatch.setattr(attention, "kernel_mode", lambda: "interpret")
-    for name, size in (("INDEX_BLOCK_Q", 32), ("INDEX_BLOCK_K", 128), ("ATTEND_BLOCK_Q", 32), ("ATTEND_BLOCK_K", 128)):
+    for name, size in (("INDEX_BLOCK_Q", 32), ("INDEX_BLOCK_K", 128), ("ATTEND_BLOCK_Q", 32), ("ATTEND_BLOCK_K", 128),
+                       ("ATTEND_HEADS", 1)):
         monkeypatch.setattr(sparse, name, size)
     rng = np.random.default_rng(11)
-    n, S, heads, values, width = 128, 384, 2, 128, 136
+    n, S, heads = 128, 384, 4
     q, w, k = (x.astype(jnp.bfloat16) for x in _index_inputs(rng, 1, S, S, G=4, D=128))
-    qa = jnp.asarray(rng.normal(size=(1, S, heads, width)) * 0.3, jnp.bfloat16)
-    latent = jnp.asarray(rng.normal(size=(1, S, width)), jnp.bfloat16)
+    q_nope, q_rope, c, k_rope, w_kvb = _head_inputs(rng, S, S, heads)
     key_mask = jnp.ones((1, S), jnp.int32).at[0, 340:].set(0)
 
     def one(j):
         rows = lambda x: jax.lax.dynamic_slice_in_dim(x, j * n, n, axis=1)
         scores = sparse.index_scores(rows(q), rows(w), k, first=j * n)
         allow = sparse.chosen_in_block(rows(q), rows(w), k, key_mask, first=j * n, topk=40)
-        return scores, allow, sparse.masked_latent_attention(rows(qa), latent, allow, values=values, scale=0.25,
-                                                             first=j * n)
+        return scores, allow, sparse.masked_latent_attention_by_groups(
+            rows(q_nope), rows(q_rope), c, k_rope, w_kvb, allow, group=2, scale=0.25, first=j * n)
 
     scores, allow, out = (np.asarray(x[block], np.float32) for x in jax.jit(
         lambda: jax.lax.map(one, jnp.arange(S // n, dtype=jnp.int32)))())
@@ -149,7 +178,7 @@ def test_a_block_of_a_traced_loop_is_the_block_at_a_host_integer(block, monkeypa
     assert int(np.asarray(want).sum(-1).max()) == min(40, seen)
     # the kernel's scores and the plain form's differ in the last bits: a choice may differ at a near-tie
     assert (allow.astype(bool) != np.asarray(want)).mean() < 2e-3
-    want = sparse.masked_latent_reference(qa[:, at], latent, jnp.asarray(allow.astype(bool)), values=values, scale=0.25)
+    want = _absorbed(q_nope[:, at], q_rope[:, at], c, k_rope, w_kvb, jnp.asarray(allow.astype(bool)))
     np.testing.assert_allclose(out, np.asarray(want, np.float32), atol=2e-2)
 
 

@@ -34,14 +34,16 @@ def test_longdoc_cell_programs_compile_for_the_chip_and_fit_it(v5e, longdoc_cell
     latents a gather reads: no third kernel), three grouped products an expert
     layer. The widest prefill, 12 query blocks of 2,048 that are one body of
     a loop a layer: ONE banded forward a sliding layer, ONE call of the
-    index's scores and ONE of the absorbed forward under the mask of the
-    chosen a full layer, whatever the prompt's width (the first block's
-    scores are computed and change nothing: everything attendable is
+    index's scores and ONE of the per-head forward under the mask of the
+    chosen a full layer (a block's eight groups of 16 heads, each over keys
+    and values decompressed for it, 2 x 101 MB at this width, are one body of
+    a loop inside the blocks'), whatever the prompt's width (the first
+    block's scores are computed and change nothing: everything attendable is
     chosen); the experts 4,096 tokens at a time, six calls a product. In
     both no arena is copied; in the prefill nothing has the elements of a
-    [width, width] score matrix, let alone of one a head; and arguments plus
-    temporaries stay under 15.75 GiB: the 11.93 GB resident (weights 8.17,
-    the pool 3.75) and the program's own."""
+    [width, width] score matrix, let alone of one a head, nor of all heads'
+    keys; and arguments plus temporaries stay under 15.75 GiB: the 11.93 GB
+    resident (weights 8.17, the pool 3.75) and the program's own."""
     engine, params = longdoc_cell_engine
     if program == "decode":
         compiled = compile_engine_program(engine, params, v5e[0])

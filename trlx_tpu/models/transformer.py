@@ -1093,12 +1093,12 @@ class LatentAttention(nn.Module):
     banded or a sparse layer's prefill into an empty cache goes
     `PREFILL_QUERY_BLOCK` queries at a time (`prefill_by_query_blocks`): a
     band's block through the banded fused forward over the keys its band
-    reaches, a sparse block absorbed over the positions its index chose,
-    so that neither per-head queries nor index scores of the whole prompt
-    exist at once. A sparse layer's paged decode step scores a row's cached
-    index keys through its block table (`paged_index_scores`), takes the
-    `index_topk` largest and reads those latents and no others
-    (`paged_latent_rows`)."""
+    reaches, a sparse block per head under the mask of what its index chose,
+    a group of heads' decompressed keys at a time: neither all heads' keys nor
+    per-head queries or index scores of the whole prompt exist at once. A
+    sparse layer's paged decode step scores a row's cached index keys through
+    its block table (`paged_index_scores`), takes the `index_topk` largest
+    and reads those latents and no others (`paged_latent_rows`)."""
 
     cfg: TransformerConfig
     kind: Optional[str] = None
@@ -1251,9 +1251,9 @@ class LatentAttention(nn.Module):
 
         if by_blocks:
             out = prefill_by_query_blocks(
-                spec, c_q=c_q, c=c, k_rope=k_rope, latent=latent, index_k=index_k, h=h, positions=positions,
-                mask=attn_mask, gate=gate, w_kvb=w_kvb, queries=queries, absorbed_query=absorbed_query,
-                values_up=values_up, project_out=project_out, index=index, scale=scale)
+                spec, c_q=c_q, c=c, k_rope=k_rope, index_k=index_k, h=h, positions=positions,
+                mask=attn_mask, gate=gate, w_kvb=w_kvb, queries=queries,
+                project_out=project_out, index=index, scale=scale)
             return out, new_cache
 
         kv = jnp.einsum("btc,chm->bthm", c, w_kvb)
@@ -2431,34 +2431,42 @@ def live_width_index(first, columns: int):
 
 # Queries a banded or a sparse latent layer's prefill into an empty cache takes
 # at once (`prefill_by_query_blocks`): a block's per-head queries (128 heads of
-# 576 absorbed: 300 MB) and its index scores against a prompt of 24,576 (200 MB)
-# are what exists at once, where the whole prompt's would be 3.6 and 2.4 GB.
+# 192: 100 MB) and its index scores against a prompt of 24,576 (200 MB) are
+# what exists at once, where the whole prompt's would be 1.2 and 2.4 GB. A
+# sparse block attends `PREFILL_HEAD_GROUP` heads at a time over their keys and
+# values of the whole prompt (16 heads of 128 + 128 over 24,576: 201 MB).
 PREFILL_QUERY_BLOCK = 2048
+PREFILL_HEAD_GROUP = 16
 
 
-def prefill_by_query_blocks(spec: LatentSpec, *, c_q, c, k_rope, latent, index_k, h, positions, mask, gate,
-                            w_kvb, queries, absorbed_query, values_up, project_out, index, scale):
+def prefill_by_query_blocks(spec: LatentSpec, *, c_q, c, k_rope, index_k, h, positions, mask, gate,
+                            w_kvb, queries, project_out, index, scale):
     """`LatentAttention`'s prefill into an empty cache for a banded or a
     sparse layer, `PREFILL_QUERY_BLOCK` queries at a time over the prompt's
-    latents (`c`, `k_rope`, `latent`, `index_k`: whole, they are small). A
-    banded block decompresses keys and values for the columns its band
-    reaches (the block's own and `window - 1` in front, from a tile's edge)
-    and runs the banded fused forward over them; a sparse block scores the
-    prompt with the index, takes the chosen (`chosen_in_block`) and attends
-    absorbed under their mask (`masked_latent_attention`). Causal structure
-    goes by column, which is position in a prompt without holes.
+    latents (`c`, `k_rope`, `index_k`: whole, they are small). A banded block
+    decompresses keys and values for the columns its band reaches (the
+    block's own and `window - 1` in front, from a tile's edge) and runs the
+    banded fused forward over them; a sparse block scores the prompt with the
+    index, takes the chosen (`chosen_in_block`) and attends PER HEAD under
+    their mask (`masked_latent_attention_by_groups`), `PREFILL_HEAD_GROUP`
+    heads at a time over keys and values decompressed from the prompt's
+    latents for that group: a (query, key, head) pair costs 2 x (dn + dr + dv)
+    operations and not the absorbed form's 2 x (2 dc + dr), and no key or
+    value of all heads exists at once. Causal structure goes by column, which
+    is position in a prompt without holes.
 
     Every block has the same shapes (a band's reach is cut from a prompt
     padded by that reach in front; a sparse block is handed the whole
     prompt's index keys and latents and where it stands, and its kernels
     pass over the tiles behind it), so the blocks are ONE traced body run in
-    turn (`jax.lax.map`): a prompt of 12 blocks traces, lowers and compiles
-    what a prompt of one does."""
+    turn (`jax.lax.map`), and so are a sparse block's groups of heads: a
+    prompt of 12 blocks traces, lowers and compiles what a prompt of one
+    does."""
     from trlx_tpu.ops import sparse_attention as sparse
     from trlx_tpu.ops.attention import flash_attention
 
     t = c.shape[1]
-    dc, dn = spec.kv_lora_rank, spec.qk_nope_head_dim
+    dn = spec.qk_nope_head_dim
     block = min(PREFILL_QUERY_BLOCK, t)
     n_blocks = -(-t // block)
     # columns in front of a band's block: its reach, from a tile's edge where the block is made of tiles
@@ -2467,11 +2475,9 @@ def prefill_by_query_blocks(spec: LatentSpec, *, c_q, c, k_rope, latent, index_k
     ends = (front, n_blocks * block - t)
     padded = lambda x: x if x is None or ends == (0, 0) else jnp.pad(x, ((0, 0), ends) + ((0, 0),) * (x.ndim - 2))
     cut = lambda x, s, n: jax.lax.dynamic_slice_in_dim(x, s, n, axis=1)
-    c_q, positions, mask, gate = (padded(x) for x in (c_q, positions, mask, gate))
+    c_q, positions, mask, gate, c, k_rope = (padded(x) for x in (c_q, positions, mask, gate, c, k_rope))
 
     if spec.window is not None:
-        c, k_rope = padded(c), padded(k_rope)
-
         def attend(s):  # the block's columns s .. s + block of the prompt lie `front` further in the padded arrays
             span = lambda x: cut(x, s, front + block)
             q_nope, q_rope = queries(span(c_q), span(positions))
@@ -2481,14 +2487,17 @@ def prefill_by_query_blocks(spec: LatentSpec, *, c_q, c, k_rope, latent, index_k
             return flash_attention(jnp.concatenate([q_nope, q_rope], axis=-1), k, kv[..., dn:], mask=span(mask),
                                    causal=True, window=spec.window)[:, front:]
     else:
-        h, latent, index_k = padded(h), padded(latent), padded(index_k)
+        h, index_k = padded(h), padded(index_k)
+        nh = w_kvb.shape[1]
+        group = PREFILL_HEAD_GROUP if nh % PREFILL_HEAD_GROUP == 0 else nh
 
         def attend(s):
             rows = lambda x: cut(x, s, block)
             allow = sparse.chosen_in_block(*index.queries(rows(c_q), rows(h), rows(positions)), index_k, mask,
                                            first=s, topk=spec.index_topk)
-            q_abs = absorbed_query(*queries(rows(c_q), rows(positions)))
-            return values_up(sparse.masked_latent_attention(q_abs, latent, allow, values=dc, scale=scale, first=s))
+            return sparse.masked_latent_attention_by_groups(
+                *queries(rows(c_q), rows(positions)), c, k_rope[:, :, 0], w_kvb, allow, group=group, scale=scale,
+                first=s)
 
     def one(j):
         s = j * block

@@ -1,7 +1,7 @@
 """Attention over positions a learned index chose (DeepSeek-V3.2's sparse
 attention, as `models/transformer.LatentAttention` runs a
 "sparse_latent_attention" layer): the index's scores, the choice of the
-`topk` largest, and the absorbed latent attention over the chosen.
+`topk` largest, and latent attention over the chosen.
 
 With G index heads of D, qI [.., n, G, D] and w [.., n, G] the queries'
 (`LatentIndex.queries`) and kI [.., S, D] one key a position:
@@ -18,16 +18,16 @@ the columns themselves are wanted) name the same set.
 Three places run it. The dense paths (no cache, the dense families' cache,
 the paged gather path) call `index_scores` and `topk_mask` and add the mask
 to their bias. A prefill into an empty cache goes a block of queries at a
-time (`chosen_in_block`, then `masked_latent_attention`: the absorbed scores
-of a block's queries over the prompt, under the mask of the chosen, through a
-fused forward in which every head reads the same latent rows; where the block
-stands, `first`, may be a traced scalar, so that the blocks of a prompt are
-one body of `jax.lax.map`, and both kernels pass over the tiles of columns
-behind the block's last query). A
+time (`chosen_in_block`, then `masked_latent_attention_by_groups`: each head's
+queries over that head's keys and values, decompressed from the prompt's
+latents a group of heads at a time, under the mask of the chosen, through a
+fused forward, `masked_latent_attention`; where the block stands, `first`, may be a traced scalar, so
+that the blocks of a prompt are one body of `jax.lax.map`, and both kernels
+pass over the tiles of columns behind the block's last query). A
 paged decode step scores a row's cached index keys through its block table
 (`ops/paged_attention.paged_index_scores`), takes `topk_columns`, gathers
-those latents (`paged_latent_rows`) and attends over them with
-`attend_chosen`.
+those latents (`paged_latent_rows`) and attends over them ABSORBED
+(`attend_chosen`: the cache holds latents, and 2,048 of them a row).
 
 On one TPU chip `index_scores` and `masked_latent_attention` are Pallas
 kernels, named `sparse_index_scores` and `sparse_latent_fwd` in the device
@@ -45,9 +45,15 @@ from trlx_tpu.ops import attention
 from trlx_tpu.ops.attention import NEG_INF, note_kernel_path
 
 # Tiles of the two kernels: queries x keys of `sparse_index_scores`, and of
-# `sparse_latent_fwd` (one head's queries against a tile of latents).
+# `sparse_latent_fwd` (`ATTEND_HEADS` heads' queries against a tile of their
+# keys and values: the heads of a grid step share the mask's tile). A key tile
+# of 1,024 and not 512: every tile rescales a head's [queries, 128] accumulator
+# and rewrites its running maximum and sum, a cost beside the tile's softmax
+# that 512 keys do not carry (9.7 ps a (query, key, head) pair at 512 x 512,
+# 6.0 at 1,024 x 1,024; 2,048 keys gain 0.2 more and lose it twice over on the
+# tiles that straddle a block's diagonal: PERF.md section 6, PR 52).
 INDEX_BLOCK_Q, INDEX_BLOCK_K = 256, 512
-ATTEND_BLOCK_Q, ATTEND_BLOCK_K = 512, 512
+ATTEND_BLOCK_Q, ATTEND_BLOCK_K, ATTEND_HEADS = 1024, 1024, 4
 _VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 
@@ -205,29 +211,45 @@ def chosen_in_block(q, w, k, key_mask, *, first, topk: int):
 
 
 def masked_latent_reference(q, latent, allow, *, values: int, scale: float):
-    """softmax(q . latent * scale) under `allow`, times the latents' leading
-    `values` columns: q [b, n, nh, width], latent [b, S, width], allow
-    [b, n, S] -> [b, n, nh, values]. [b, nh, n, S] scores exist whole."""
+    """The ABSORBED form of `masked_latent_attention`, what a decode step runs
+    over the cache (`attend_chosen`) and the tests' yardstick for the per-head
+    kernel: softmax(q . latent * scale) under `allow`, times the latents'
+    leading `values` columns: q [b, n, nh, width] (each head's query through
+    W_uk, then its rotary part), latent [b, S, width], allow [b, n, S] ->
+    [b, n, nh, values] (W_uv still to come). [b, nh, n, S] scores exist whole."""
     scores = jnp.einsum("bnhc,bsc->bhns", q, latent, preferred_element_type=jnp.float32) * scale
     probs = jax.nn.softmax(jnp.where(allow[:, None], scores, -1e9), axis=-1).astype(q.dtype)
     return jnp.einsum("bhns,bsc->bnhc", probs, latent[..., :values])
 
 
-def _masked_latent_kernel(first_ref, qv_ref, qr_ref, kv_ref, kr_ref, allow_ref, o_ref, m_scr, l_scr, acc_scr,
-                          *, scale, block_q, block_k):
-    """One head's `block_q` queries against `block_k` latents: the online
-    softmax of `ops/attention._flash_fwd_kernel` with the operands in their
-    own type, the mask an operand, and keys and values one array.
+def masked_heads_reference(q_nope, q_rope, k_nope, k_rope, v, allow, *, scale: float):
+    """`masked_latent_attention` in plain products (its shapes): [b, h, n, S]
+    scores exist whole."""
+    scores = jnp.einsum("bnhd,bshd->bhns", q_nope, k_nope, preferred_element_type=jnp.float32)
+    scores = (scores + jnp.einsum("bnhr,bsr->bhns", q_rope, k_rope, preferred_element_type=jnp.float32)) * scale
+    probs = jax.nn.softmax(jnp.where(allow[:, None], scores, -1e9), axis=-1).astype(q_nope.dtype)
+    return jnp.einsum("bhns,bshd->bnhd", probs, v)
 
-    qv_ref [1, bq, values], qr_ref [1, bq, 128]: the absorbed query against the
-        value columns and against the rotated key (padded to a lane tile)
-    kv_ref [1, bk, values], kr_ref [1, bk, 128]: the latents' two parts
+
+def _masked_heads_kernel(first_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, allow_ref, o_ref, m_scr, l_scr, acc_scr,
+                         *, scale, block_q, block_k, heads):
+    """`heads` heads' `block_q` queries against `block_k` of their keys and
+    values: the online softmax of `ops/attention._flash_fwd_kernel` with the
+    operands in their own type, the mask an operand the heads share (widened
+    once a tile), and a key in two parts, a head's own and the rotary part
+    that is one vector for all heads.
+
+    qn_ref [1, bq, heads * dn], qr_ref [1, bq, heads * 128]: each head's query
+        against its keys and against the rotary key (padded to a lane tile)
+    kn_ref [1, bk, heads * dn], v_ref [1, bk, heads * dv]: each head's keys and values
+    kr_ref [1, bk, 128]: the rotary key, padded likewise
     allow_ref [1, bq, bk] int8
     first_ref [1]: the column the call's first query stands at
     """
     import jax.experimental.pallas as pl
 
-    qb, kb = pl.program_id(1), pl.program_id(2)
+    qb, kb = pl.program_id(2), pl.program_id(3)
+    dn, lanes, dv = (ref.shape[-1] // heads for ref in (qn_ref, qr_ref, v_ref))
 
     @pl.when(kb == 0)
     def _init():
@@ -239,92 +261,128 @@ def _masked_latent_kernel(first_ref, qv_ref, qr_ref, kv_ref, kr_ref, allow_ref, 
     @pl.when(kb * block_k <= first_ref[0] + qb * block_q + block_q - 1)
     def _compute():
         nt = (((1,), (1,)), ((), ()))
-        kv = kv_ref[0]
-        s = jax.lax.dot_general(qv_ref[0], kv, nt, preferred_element_type=jnp.float32)
-        s = (s + jax.lax.dot_general(qr_ref[0], kr_ref[0], nt, preferred_element_type=jnp.float32)) * scale
-        s = jnp.where(allow_ref[0].astype(jnp.int32) > 0, s, NEG_INF)
-        m_prev, l_prev = m_scr[:, 0:1], l_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        shift = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - shift)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        corr = jnp.where(m_prev <= NEG_INF / 2, 0.0, corr)
-        l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(kv.dtype), kv, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+        allowed = allow_ref[0].astype(jnp.int32) > 0
+        kr = kr_ref[0]
+        for h in range(heads):  # unrolled: the heads of a step share the mask's tile and the rotary key's
+            s = jax.lax.dot_general(qn_ref[0, :, h * dn:(h + 1) * dn], kn_ref[0, :, h * dn:(h + 1) * dn], nt,
+                                    preferred_element_type=jnp.float32)
+            s = s + jax.lax.dot_general(qr_ref[0, :, h * lanes:(h + 1) * lanes], kr, nt,
+                                        preferred_element_type=jnp.float32)
+            s = jnp.where(allowed, s * scale, NEG_INF)
+            m_prev, l_prev = m_scr[h, :, 0:1], l_scr[h, :, 0:1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # NEG_INF is finite: what the mask took out is exp(-1e30 - shift) = 0 with no second select,
+            # and a row that has seen nothing yet keeps l = acc = 0 under corr = exp(0)
+            p = jnp.exp(s - jnp.where(m_new <= NEG_INF / 2, 0.0, m_new))
+            corr = jnp.exp(m_prev - m_new)
+            l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, :, h * dv:(h + 1) * dv]
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_scr[h] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+            l_scr[h] = jnp.broadcast_to(l_new, l_scr.shape[1:])
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    @pl.when(kb == pl.num_programs(3) - 1)
     def _finalize():
-        l = l_scr[:, 0:1]
-        o_ref[0] = (acc_scr[:] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+        for h in range(heads):
+            l = l_scr[h, :, 0:1]
+            o_ref[0, :, h * dv:(h + 1) * dv] = (acc_scr[h] / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
-def _masked_latent_pallas(q, latent, allow, values: int, scale: float, first, interpret: bool):
+def _masked_heads_pallas(q_nope, q_rope, k_nope, k_rope, v, allow, scale: float, first, interpret: bool):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, n, nh, width = q.shape
-    S = latent.shape[1]
+    b, n, nh, dn = q_nope.shape
+    S, dv = v.shape[1], v.shape[-1]
     bq, bk = min(ATTEND_BLOCK_Q, n), min(ATTEND_BLOCK_K, S)
-    lanes = -(-(width - values) // 128) * 128
-    pad = lambda x: jnp.pad(x[..., values:], ((0, 0),) * (x.ndim - 1) + ((0, lanes - (width - values)),))
-    qh = q.transpose(0, 2, 1, 3).reshape(b * nh, n, width)
+    heads = max(h for h in range(1, ATTEND_HEADS + 1) if nh % h == 0)
+    lanes = -(-q_rope.shape[-1] // 128) * 128
+    pad = lambda x: jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, lanes - x.shape[-1]),))
+    flat = lambda x: x.reshape(*x.shape[:2], -1)  # [.., heads, d] -> heads side by side: a head is a tile of lanes
 
     def last_seen(j, first_ref):  # the last tile of columns a query of tile j may see
         return (first_ref[0] + j * bq + bq - 1) // bk
 
-    def key_tile(i, j, kk, first_ref):  # a tile past the block's last query is not fetched again
-        return (i // nh, jnp.minimum(kk, last_seen(j, first_ref)), 0)
+    def query_tile(i, g, j, kk, first_ref):
+        return (i, j, g)
+
+    def key_tile(i, g, j, kk, first_ref):  # a tile past the block's last query is not fetched again
+        return (i, jnp.minimum(kk, last_seen(j, first_ref)), g)
 
     out = pl.pallas_call(
-        functools.partial(_masked_latent_kernel, scale=scale, block_q=bq, block_k=bk),
+        functools.partial(_masked_heads_kernel, scale=scale, block_q=bq, block_k=bk, heads=heads),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(b * nh, n // bq, S // bk),
+            grid=(b, nh // heads, n // bq, S // bk),
             in_specs=[
-                pl.BlockSpec((1, bq, values), lambda i, j, kk, first_ref: (i, j, 0)),
-                pl.BlockSpec((1, bq, lanes), lambda i, j, kk, first_ref: (i, j, 0)),
-                pl.BlockSpec((1, bk, values), key_tile),
-                pl.BlockSpec((1, bk, lanes), key_tile),
+                pl.BlockSpec((1, bq, heads * dn), query_tile),
+                pl.BlockSpec((1, bq, heads * lanes), query_tile),
+                pl.BlockSpec((1, bk, heads * dn), key_tile),
+                pl.BlockSpec((1, bk, lanes), lambda i, g, j, kk, first_ref: key_tile(i, 0, j, kk, first_ref)),
+                pl.BlockSpec((1, bk, heads * dv), key_tile),
                 pl.BlockSpec((1, bq, bk),
-                             lambda i, j, kk, first_ref: (i // nh, j, jnp.minimum(kk, last_seen(j, first_ref)))),
+                             lambda i, g, j, kk, first_ref: (i, j, jnp.minimum(kk, last_seen(j, first_ref)))),
             ],
-            out_specs=pl.BlockSpec((1, bq, values), lambda i, j, kk, first_ref: (i, j, 0)),
+            out_specs=pl.BlockSpec((1, bq, heads * dv), query_tile),
             scratch_shapes=[
-                pltpu.VMEM((bq, 128), jnp.float32),     # m (broadcast over lanes)
-                pltpu.VMEM((bq, 128), jnp.float32),     # l
-                pltpu.VMEM((bq, values), jnp.float32),  # acc
+                pltpu.VMEM((heads, bq, 128), jnp.float32),  # m (broadcast over lanes)
+                pltpu.VMEM((heads, bq, 128), jnp.float32),  # l
+                pltpu.VMEM((heads, bq, dv), jnp.float32),   # acc
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b * nh, n, values), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n, nh * dv), q_nope.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="sparse_latent_fwd",
-    )(jnp.asarray(first, jnp.int32).reshape(1), qh[..., :values], pad(qh), latent[..., :values], pad(latent),
+    )(jnp.asarray(first, jnp.int32).reshape(1), flat(q_nope), flat(pad(q_rope)), flat(k_nope), pad(k_rope), flat(v),
       allow.astype(jnp.int8))
-    return out.reshape(b, nh, n, values).transpose(0, 2, 1, 3)
+    return out.reshape(b, n, nh, dv)
 
 
-def masked_latent_attention(q, latent, allow, *, values: int, scale: float, first=0):
-    """Absorbed latent attention of a block of queries under a mask: q
-    [b, n, nh, width] (the block stands at columns `first` onward, a host
-    integer or a traced scalar), latent [b, S, width] the prompt's cached
-    rows, the block's end or further (the kernel passes over the tiles
-    behind the block), allow
-    [b, n, S] -> [b, n, nh, values]. `allow` holds the causal structure; a
-    query it allows nothing gives zeros."""
-    latent = latent.astype(q.dtype)
+def masked_latent_attention(q_nope, q_rope, k_nope, k_rope, v, allow, *, scale: float, first=0):
+    """Latent attention of a block of queries under a mask, PER HEAD over
+    keys and values decompressed from the latents (2 x (dn + dr + dv)
+    operations a (query, key, head) pair where the absorbed form pays
+    2 x (2 dc + dr)): q_nope [b, n, h, dn] and q_rope [b, n, h, dr] (the
+    block stands at columns `first` onward, a host integer or a traced
+    scalar), k_nope [b, S, h, dn] and v [b, S, h, dv] of the same heads,
+    k_rope [b, S, dr] the rotary key all heads share, of the prompt's columns,
+    the block's end or further (the kernel passes over the tiles behind the
+    block), allow [b, n, S] -> [b, n, h, dv]. `allow` holds the causal
+    structure; a query it allows nothing gives zeros."""
+    k_nope, k_rope, v = (x.astype(q_nope.dtype) for x in (k_nope, k_rope, v))
     mode = attention.kernel_mode()
-    if (mode in ("pallas", "interpret") and values % 128 == 0
-            and _tiles(q.shape[1], latent.shape[1], ATTEND_BLOCK_Q, ATTEND_BLOCK_K)):
-        note_kernel_path("sparse_latent_fwd", mode, q.shape)
-        return _masked_latent_pallas(q, latent, allow, values, scale, first, interpret=(mode == "interpret"))
-    note_kernel_path("sparse_latent_fwd", "xla", q.shape)
-    return masked_latent_reference(q, latent, allow, values=values, scale=scale)
+    if (mode in ("pallas", "interpret") and q_nope.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+            and _tiles(q_nope.shape[1], v.shape[1], ATTEND_BLOCK_Q, ATTEND_BLOCK_K)):
+        note_kernel_path("sparse_latent_fwd", mode, q_nope.shape)
+        return _masked_heads_pallas(q_nope, q_rope, k_nope, k_rope, v, allow, scale, first,
+                                    interpret=(mode == "interpret"))
+    note_kernel_path("sparse_latent_fwd", "xla", q_nope.shape)
+    return masked_heads_reference(q_nope, q_rope, k_nope, k_rope, v, allow, scale=scale)
+
+
+def masked_latent_attention_by_groups(q_nope, q_rope, c, k_rope, w_kvb, allow, *, group: int, scale: float, first=0):
+    """`masked_latent_attention` of every head, `group` heads at a time over
+    keys and values decompressed from the prompt's latents for that group: c
+    [b, S, dc], w_kvb [dc, h, dn + dv] (W_uk beside W_uv, by head), the rest as
+    there -> [b, n, h, dv]. The groups are one traced body run in turn
+    (`jax.lax.map`), and the keys and values of all heads never exist at once."""
+    nh, dn = q_nope.shape[2:]
+    # [groups, .., group, d]: a group's heads of the weights, and of the queries
+    by_group = lambda x, axis: jnp.moveaxis(
+        x.reshape(*x.shape[:axis], nh // group, group, *x.shape[axis + 1:]), axis, 0)
+
+    def heads(of):
+        w, q_nope, q_rope = of
+        return masked_latent_attention(
+            q_nope, q_rope, jnp.einsum("bsc,chn->bshn", c, w[..., :dn]), k_rope,
+            jnp.einsum("bsc,chv->bshv", c, w[..., dn:]), allow, scale=scale, first=first)
+
+    out = jax.lax.map(heads, (by_group(w_kvb, 1), by_group(q_nope, 2), by_group(q_rope, 2)))
+    return jnp.moveaxis(out, 0, 2).reshape(*out.shape[1:3], nh, -1)
 
 
 def attend_chosen(q, rows, chosen, *, values: int, scale: float, out_dtype=None):

@@ -163,11 +163,14 @@ def _left_padded(lengths, width, pad_id, seed=1):
 
 
 def _spans_of(monkeypatch):
-    """The `trlx:` counters the trainer writes while a session is active."""
+    """The `trlx:` counters the trainer writes while a session is active
+    (the build account's `build.event`, one a program built meanwhile, is
+    not the trainer's)."""
     spans = []
     monkeypatch.setattr(ppo_trainer.tracing, "active", lambda: True)
     monkeypatch.setattr(ppo_trainer.tracing, "counters",
-                        lambda name, **values: spans.append((name, values)))
+                        lambda name, /, **values: spans.append((name, values))
+                        if name.startswith("ppo.") else None)
     return spans
 
 

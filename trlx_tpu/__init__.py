@@ -5,7 +5,10 @@ trainer/pipeline/method plugins, running on one GSPMD device mesh."""
 
 __version__ = "0.1.0"
 
+from trlx_tpu.observability.compile_ledger import install_monitoring
 from trlx_tpu.utils import logging  # noqa: F401
+
+install_monitoring()  # the build account hears the process's first jit
 
 
 def train(*args, **kwargs):

@@ -483,6 +483,11 @@ class Scheduler:
             if self._running:
                 return self
             self._running = True
+        # imported here, not at the top: a line added up there moves every line
+        # number a Pallas program's cache key may hold (ROADMAP S5)
+        from trlx_tpu.observability.compile_ledger import account
+
+        account().mark("sched.start")  # every program warmed, traffic about to begin
         self._thread = threading.Thread(
             target=self._loop, name="trlx-tpu-inference-scheduler", daemon=True
         )

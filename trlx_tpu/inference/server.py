@@ -66,6 +66,7 @@ from trlx_tpu.inference.sessions import (
     SessionResetError,
 )
 from trlx_tpu.inference.metrics import dedupe_metadata
+from trlx_tpu.observability.compile_ledger import account as build_account
 from trlx_tpu.observability.slo import SLOEngine
 from trlx_tpu.observability.tracing import new_id
 from trlx_tpu.utils import logging
@@ -922,6 +923,7 @@ class InferenceServer:
                     text = dedupe_metadata(
                         server.metrics.render()
                         + server.slo.render_prometheus(ns="trlx_tpu_inference")
+                        + build_account().render_prometheus()
                         + (ledger.render_prometheus() if ledger is not None else "")
                         + (hbm.render_prometheus() if hbm is not None else "")
                     )

@@ -7,7 +7,10 @@ Chrome-trace/Perfetto exporter.
 
 Everything here is dependency-free and OFF by default — components hold
 `tracer = None` / `recorder = None` unless `train.tracing` /
-`inference.tracing` is set. See docs/observability.md.
+`inference.tracing` is set — but for the build account
+(`compile_ledger.account()`: four `jax.monitoring` listeners that fire
+when a program is built and at no other time), which `import trlx_tpu`
+installs. See docs/observability.md.
 """
 
 from trlx_tpu.observability.compile_ledger import (

@@ -28,11 +28,12 @@ recompile on a pod; this module makes every compile an *event*:
   flight-recorder ring, the ``compile/*`` tracker stat family,
   ``trlx_tpu_compiles_total{fn=...}`` Prometheus series, and a
   once-per-fn postmortem bundle via `maybe_dump`.
-- `jax.monitoring` listeners (installed once per process, forwarded to
-  every live ledger through a weak registry) supply true backend-compile
-  seconds and — when `train.compilation_cache_dir` wires the persistent
-  compilation cache — cache hit/miss counts, so a warm-start run shows
-  up as compiles with near-zero backend seconds.
+- the process's **build account** (`account()`, installed by `import
+  trlx_tpu`, always on) hears every trace to a jaxpr, every lowering and
+  every backend compile or cache read from `jax.monitoring`, by program;
+  a ledger's backend seconds and persistent-cache hits and misses are the
+  account's, so a warm-start run shows up as compiles with near-zero
+  backend seconds.
 
 Like the tracer and the flight recorders, ledgers are explicit context
 objects: components hold ``compile_ledger = None`` and every wrap site
@@ -40,72 +41,247 @@ routes through it — there is no ambient "current ledger" to leak across
 tests or replicas.
 """
 
+import re
 import threading
 import time
-import weakref
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from trlx_tpu.observability import tracing
 from trlx_tpu.observability.flight_recorder import FlightRecorder
 from trlx_tpu.observability.postmortem import maybe_dump
 from trlx_tpu.utils import logging
 
 logger = logging.get_logger(__name__)
 
-#: every live CompileLedger, so the process-wide jax.monitoring listeners
-#: (installed at most once; jax has no public unregister) can forward
-#: backend-compile durations and persistent-cache hit/miss events without
-#: pinning ledgers past their owner's lifetime
-_ledgers: "weakref.WeakSet" = weakref.WeakSet()
-_ledgers_lock = threading.Lock()
-_monitoring_installed = False
+# ----------------------------------------------------------------------
+# The build account
+# ----------------------------------------------------------------------
 
-# jax.monitoring event names (stable since jax 0.4.x)
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_TRACE_EVENT = "/jax/core/tracing_duration"  # jaxpr trace, when emitted
-_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
-_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+# jax.monitoring names (JAX 0.9.0). Each of the three spans comes with a
+# `fun_name`, and a scalar of the same name when it STARTS.
+_TRACE_SPAN = "/jax/core/compile/jaxpr_trace_duration"
+_KINDS = {
+    _TRACE_SPAN: "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # a compile or a read from the persistent cache: what the runtime waits for
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s",
+}
 
+#: `trlx:build.program` spans a `write()`, heaviest first; the rest is one
+#: `name=(others)`
+_PROGRAMS_WRITTEN = 64
 
-def _forward(method: str, *args) -> None:
-    with _ledgers_lock:
-        targets = list(_ledgers)
-    for led in targets:
-        try:
-            getattr(led, method)(*args)
-        except Exception:  # pragma: no cover - never raise into jax
-            pass
-
-
-def _on_duration(event: str, duration_secs: float, **kwargs) -> None:
-    if event == _COMPILE_EVENT:
-        _forward("_note_backend_compile", float(duration_secs))
-    elif event == _TRACE_EVENT:
-        _forward("_note_trace_duration", float(duration_secs))
+_WRAPPED = re.compile(r"^\w+\((.*)\)$")
 
 
-def _on_event(event: str, **kwargs) -> None:
-    if event == _CACHE_MISS_EVENT:
-        _forward("_note_cache", False)
-    elif event == _CACHE_HIT_EVENT:
-        _forward("_note_cache", True)
+def _program_name(fun_name: str) -> str:
+    """One key a program: a trace reports `decode`, its lowering and its
+    backend compile `jit(decode)`. Blanks go, since the name stands in a
+    counter span's name (`<unnamed wrapped function>`)."""
+    wrapped = _WRAPPED.match(fun_name)
+    return "_".join((wrapped.group(1) if wrapped else fun_name).split()) or "?"
 
 
-def install_monitoring() -> bool:
-    """Register the process-wide jax.monitoring forwarders (idempotent).
-    Returns True when the listeners are installed (now or earlier),
-    False when jax.monitoring is unavailable."""
-    global _monitoring_installed
-    if _monitoring_installed:
-        return True
-    try:
-        from jax import monitoring
+class _Program:
+    """What one `fun_name` cost: events and seconds by kind, and where on
+    the account's clock the first began and the last ended."""
 
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        monitoring.register_event_listener(_on_event)
-    except Exception:  # pragma: no cover - very old jax
-        return False
-    _monitoring_installed = True
-    return True
+    __slots__ = ("events", "seconds", "first_at", "last_at")
+
+    def __init__(self, at: float):
+        self.events = dict.fromkeys(_KINDS.values(), 0)
+        self.seconds = dict.fromkeys(_KINDS.values(), 0.0)
+        self.first_at = self.last_at = at
+
+    def add(self, other: "_Program") -> None:
+        for kind in self.events:
+            self.events[kind] += other.events[kind]
+            self.seconds[kind] += other.seconds[kind]
+        self.first_at = min(self.first_at, other.first_at)
+        self.last_at = max(self.last_at, other.last_at)
+
+    def counters(self) -> Dict[str, float]:
+        return {
+            "builds": self.events["backend"],
+            **{f"{kind}_s": round(s, 4) for kind, s in self.seconds.items()},
+            "first_at_s": round(self.first_at, 3), "last_at_s": round(self.last_at, 3),
+        }
+
+
+class BuildAccount:
+    """What the process has spent building programs, heard from
+    `jax.monitoring`: every trace to a jaxpr, every lowering, every backend
+    compile or read from the persistent cache, folded into one row a
+    program (memory follows the number of function names, not the uptime),
+    and the cache's hits, misses and seconds. Always on: a listener adds to
+    a row under a lock, nothing runs between builds, no `jit` is wrapped.
+
+    Traces nest (`jnp.matmul`, itself jitted, reports a trace of its own
+    inside the program's that calls it), so a thread's outermost trace is
+    the one kept and `trace_s` counts no second twice. JAX hands
+    `time.time()` pairs; durations are kept from them, and positions as
+    seconds since the account was made."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._made_at = time.time()
+        self._programs: Dict[str, _Program] = {}
+        self._cache = {"cache_hits": 0, "cache_misses": 0, "cache_read_s": 0.0, "saved_s": 0.0}
+        self._marks: Dict[str, Dict[str, float]] = {}
+        self._open = threading.local()  # .traces: jaxpr traces this thread is inside
+
+    # -- jax.monitoring intake (the thread that builds) -----------------
+
+    def _on_start(self, event: str, value: float, **kwargs) -> None:
+        if event == _TRACE_SPAN:
+            self._open.traces = getattr(self._open, "traces", 0) + 1
+
+    def _on_span(self, event: str, start: float, end: float,
+                 fun_name: str = "?", **kwargs) -> None:
+        kind = _KINDS.get(event)
+        if kind is None:
+            return
+        if kind == "trace":
+            # a release that sends no start hears every trace as outermost
+            inside = self._open.traces = max(getattr(self._open, "traces", 1) - 1, 0)
+            if inside:
+                return
+        name = _program_name(fun_name)
+        with self._lock:
+            row = self._programs.get(name)
+            if row is None:
+                row = self._programs[name] = _Program(start - self._made_at)
+            row.events[kind] += 1
+            row.seconds[kind] += end - start
+            row.last_at = end - self._made_at
+        if tracing.active():
+            # on the trace's clock, at the build's end: a program built inside
+            # a traced window stands next to the idle gap it made
+            tracing.counters("build.event", kind=kind, name=name,
+                             ms=round(1e3 * (end - start), 3))
+
+    def _on_seconds(self, event: str, seconds: float, **kwargs) -> None:
+        key = _CACHE_SECONDS.get(event)
+        if key is not None:
+            with self._lock:
+                self._cache[key] += seconds
+
+    def _on_count(self, event: str, **kwargs) -> None:
+        key = _CACHE_COUNTS.get(event)
+        if key is not None:
+            with self._lock:
+                self._cache[key] += 1
+
+    # -- readers --------------------------------------------------------
+
+    def _totals(self) -> Dict[str, float]:
+        rows = self._programs.values()
+        return {
+            "programs": len(rows),
+            "builds": sum(r.events["backend"] for r in rows),
+            **{f"{kind}_s": sum(r.seconds[kind] for r in rows) for kind in _KINDS.values()},
+            **self._cache,
+            "at_s": time.time() - self._made_at,
+        }
+
+    def totals(self) -> Dict[str, float]:
+        """From the process's start to this moment: `programs` (distinct
+        names), `builds` (backend events), `trace_s`, `lower_s`, `backend_s`
+        (compiles and cache reads together), the persistent cache's
+        `cache_hits`, `cache_misses`, `cache_read_s`, `saved_s`, and `at_s`,
+        this moment on the account's clock."""
+        with self._lock:
+            return self._totals()
+
+    def programs(self) -> Dict[str, Dict[str, Any]]:
+        """{name: {"events": {kind: n}, "seconds": {kind: s}, "first_at", "last_at"}}"""
+        with self._lock:
+            return {name: {"events": dict(r.events), "seconds": dict(r.seconds),
+                           "first_at": r.first_at, "last_at": r.last_at}
+                    for name, r in self._programs.items()}
+
+    def mark(self, name: str) -> None:
+        """Keep the totals of this moment under `name`, the FIRST time the
+        name is seen: where an operator would say "ready"."""
+        if name in self._marks:
+            return
+        with self._lock:
+            self._marks.setdefault(name, self._totals())
+
+    def marks(self) -> Dict[str, Dict[str, float]]:
+        with self._lock:
+            return {name: dict(totals) for name, totals in self._marks.items()}
+
+    def write(self) -> None:
+        """Into the active tracing session, as counter spans (the names
+        carry the numbers; `tracing.stop()` calls this before it closes the
+        session): one `trlx:build.total` a mark and one `mark=end`, and one
+        `trlx:build.program` a program, heaviest first."""
+        with self._lock:
+            totals = {**self._marks, "end": self._totals()}
+            rows = sorted(self._programs.items(), key=lambda kv: -sum(kv[1].seconds.values()))
+            if len(rows) > _PROGRAMS_WRITTEN:
+                others = _Program(rows[_PROGRAMS_WRITTEN][1].first_at)
+                for _, row in rows[_PROGRAMS_WRITTEN:]:
+                    others.add(row)
+                rows = rows[:_PROGRAMS_WRITTEN] + [("(others)", others)]
+            rows = [(name, row.counters()) for name, row in rows]
+        for mark, at_mark in totals.items():
+            tracing.counters("build.total", mark=mark, **{
+                k: round(v, 4) if isinstance(v, float) else v for k, v in at_mark.items()})
+        for name, counters in rows:
+            tracing.counters("build.program", name=name, **counters)
+
+    def render_prometheus(self, ns: str = "trlx_tpu") -> str:
+        """The process's build totals for /metrics (dedupe_metadata-compatible)."""
+        t = self.totals()
+        lines = []
+        for series, value, what in (
+            ("build_trace_seconds_total", t["trace_s"], "seconds tracing functions to jaxprs"),
+            ("build_lower_seconds_total", t["lower_s"], "seconds lowering jaxprs to modules"),
+            ("build_backend_seconds_total", t["backend_s"],
+             "seconds in backend compiles and persistent-cache reads"),
+            ("build_cache_misses_total", t["cache_misses"], "persistent compilation cache misses"),
+            ("build_programs_total", t["programs"], "distinct programs built"),
+        ):
+            lines += [f"# HELP {ns}_{series} {what}", f"# TYPE {ns}_{series} counter",
+                      f"{ns}_{series} {round(value, 6)}"]
+        return "\n".join(lines) + "\n"
+
+
+_account: Optional[BuildAccount] = None
+_install_lock = threading.Lock()
+
+
+def install_monitoring() -> BuildAccount:
+    """Make the process's build account and register its listeners with
+    `jax.monitoring` (idempotent; jax keeps them for the process's life).
+    `import trlx_tpu` calls this, so that the first `jit` is heard."""
+    global _account
+    with _install_lock:
+        if _account is None:
+            from jax import monitoring
+
+            made = BuildAccount()
+            monitoring.register_scalar_listener(made._on_start)
+            monitoring.register_event_time_span_listener(made._on_span)
+            monitoring.register_event_duration_secs_listener(made._on_seconds)
+            monitoring.register_event_listener(made._on_count)
+            _account = made
+    return _account
+
+
+def account() -> BuildAccount:
+    """The process's build account."""
+    return _account or install_monitoring()
 
 
 # ----------------------------------------------------------------------
@@ -191,8 +367,10 @@ class _FnRecord:
 
 class CompileLedger:
     """Per-function compile accounting for one trainer / engine / bench
-    run. Thread-safe: wrap sites run on the driver thread, the jax
-    monitoring forwarders on whichever thread compiles."""
+    run: compiles, calls, budgets and storms of the functions IT wraps.
+    Backend seconds, trace seconds and the persistent cache's hits and
+    misses are the process's, read from the build account (`account()`)
+    and the same in every ledger. Thread-safe."""
 
     def __init__(self, ring_capacity: int = 256,
                  postmortem_dir: str = "logs/postmortems",
@@ -203,31 +381,7 @@ class CompileLedger:
         self.storms: List[Dict[str, Any]] = []
         self.postmortem_dir = postmortem_dir
         self.config = config
-        self.backend_compile_s = 0.0  # XLA time, from jax.monitoring
-        self.trace_s = 0.0  # jaxpr tracing time, when jax emits it
-        self.cache_hits = 0  # persistent compilation cache (when wired)
-        self.cache_misses = 0
         self._tls = threading.local()
-        with _ledgers_lock:
-            _ledgers.add(self)
-        install_monitoring()
-
-    # -- jax.monitoring intake (any thread) ----------------------------
-
-    def _note_backend_compile(self, seconds: float) -> None:
-        with self._lock:
-            self.backend_compile_s += seconds
-
-    def _note_trace_duration(self, seconds: float) -> None:
-        with self._lock:
-            self.trace_s += seconds
-
-    def _note_cache(self, hit: bool) -> None:
-        with self._lock:
-            if hit:
-                self.cache_hits += 1
-            else:
-                self.cache_misses += 1
 
     # -- wrap sites ----------------------------------------------------
 
@@ -354,6 +508,7 @@ class CompileLedger:
             return len(self.storms)
 
     def snapshot(self) -> Dict[str, Any]:
+        built = account().totals()
         with self._lock:
             return {
                 "functions": {
@@ -372,25 +527,28 @@ class CompileLedger:
                 },
                 "total_compiles": sum(r.compiles for r in self.fns.values()),
                 "storms": list(self.storms),
-                "backend_compile_s": round(self.backend_compile_s, 6),
-                "trace_s": round(self.trace_s, 6),
+                "backend_compile_s": round(built["backend_s"], 6),
+                "trace_s": round(built["trace_s"], 6),
+                "lower_s": round(built["lower_s"], 6),
                 "persistent_cache": {
-                    "hits": self.cache_hits,
-                    "misses": self.cache_misses,
+                    "hits": built["cache_hits"],
+                    "misses": built["cache_misses"],
                 },
             }
 
     def drain_stats(self) -> Dict[str, float]:
-        """``compile/*`` floats for the tracker: totals plus one counter
-        per over-budget function (quiet functions stay out of the logs)."""
+        """``compile/*`` floats for the tracker: this ledger's totals, one
+        counter per over-budget function (quiet functions stay out of the
+        logs), and the process's build totals."""
+        built = account().totals()
         with self._lock:
             out: Dict[str, float] = {
                 "compile/total": float(
                     sum(r.compiles for r in self.fns.values())),
                 "compile/storms": float(len(self.storms)),
-                "compile/backend_s": self.backend_compile_s,
-                "compile/cache_hits": float(self.cache_hits),
-                "compile/cache_misses": float(self.cache_misses),
+                **{f"compile/{key}": float(built[key]) for key in (
+                    "trace_s", "lower_s", "backend_s", "cache_hits", "cache_misses",
+                    "programs")},
             }
             for n, r in self.fns.items():
                 if r.compiles > r.budget:
@@ -400,8 +558,9 @@ class CompileLedger:
         return out
 
     def render_prometheus(self, ns: str = "trlx_tpu") -> str:
-        """`trlx_tpu_compiles_total{fn=...}` counters + storm/cache
-        series for /metrics concatenation (dedupe_metadata-compatible)."""
+        """`trlx_tpu_compiles_total{fn=...}` counters + the storm series
+        for /metrics concatenation (dedupe_metadata-compatible); the
+        process's build totals are `BuildAccount.render_prometheus`."""
         snap = self.snapshot()
         esc = lambda s: s.replace("\\", "\\\\").replace('"', '\\"')
         lines = [
@@ -415,15 +574,6 @@ class CompileLedger:
             f"# HELP {ns}_retrace_storms_total over-budget recompiles",
             f"# TYPE {ns}_retrace_storms_total counter",
             f"{ns}_retrace_storms_total {len(snap['storms'])}",
-            f"# HELP {ns}_backend_compile_seconds_total XLA compile seconds",
-            f"# TYPE {ns}_backend_compile_seconds_total counter",
-            f"{ns}_backend_compile_seconds_total {snap['backend_compile_s']}",
-            f"# HELP {ns}_compile_cache_hits_total persistent compilation cache hits",
-            f"# TYPE {ns}_compile_cache_hits_total counter",
-            f"{ns}_compile_cache_hits_total {snap['persistent_cache']['hits']}",
-            f"# HELP {ns}_compile_cache_misses_total persistent compilation cache misses",
-            f"# TYPE {ns}_compile_cache_misses_total counter",
-            f"{ns}_compile_cache_misses_total {snap['persistent_cache']['misses']}",
         ]
         return "\n".join(lines) + "\n"
 

@@ -2725,7 +2725,12 @@ class PPOTrainer(TPUTrainer):
             return self.train_epochs_from_chunk(full, n_epochs)
 
     def post_backward_callback(self):
+        # imported here, not at the top: the lines above hold the numbers that
+        # the Pallas programs' cache keys keep (ROADMAP S5)
+        from trlx_tpu.observability.compile_ledger import account
+
         self.kl_ctl.update(self.mean_kl, n_steps=self.config.train.batch_size)
+        account().mark("train.first_epoch")  # every program of a cycle has run once
 
     def create_train_dataloader(self, seed_offset: int = 0, drop_last: bool = False):
         # seed moves with iter_count so each inner epoch reshuffles (the

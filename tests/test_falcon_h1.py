@@ -419,7 +419,7 @@ def test_the_prefill_state_counter_span_says_what_the_chunked_form_runs(policy, 
     monkeypatch.setattr(tracing, "counters", lambda name, **kw: seen.append((name, kw)))
     programs = engine._prefill_programs([(np.arange(1, 30, dtype=np.int32), 4), (np.arange(1, 6, dtype=np.int32), 4)])
     assert engine._count_admission(programs) == (2, 34, 64, 2)
-    assert ("engine.prefill_state", dict(tokens=34, padded_tokens=64, linear_layers=2, chunks=4)) in seen
+    assert ("engine.prefill_state", dict(tokens=34, padded_tokens=64, linear_layers=2, chunks=4, form="xla")) in seen
 
 
 KEPT = "ssm_attention layers keep state, tails a slot"

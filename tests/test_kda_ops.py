@@ -1,7 +1,8 @@
-"""Kimi delta attention three ways (`trlx_tpu/ops/linear_attention.py`): the
-scan over tokens that defines it, the chunked form a forward and a prefill
-run, and the decode kernel (through the Pallas interpreter) stepped over the
-same tokens, on seeded inputs in float32."""
+"""Kimi delta attention four ways (`trlx_tpu/ops/linear_attention.py`): the
+scan over tokens that defines it, the chunked form a forward runs, the span
+kernel a cached prefill runs and the decode kernel (both through the Pallas
+interpreter), the last stepped over the same tokens, on seeded inputs in
+float32."""
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +37,16 @@ DECAYS = {"published_decay": {}, "slow_decay": {"slow": True},
 by_decay = pytest.mark.parametrize("decay", list(DECAYS.values()), ids=list(DECAYS))
 
 
+def by_kernel(*x, state=None, **kw):
+    """What a cached prefill runs where kernels run: `kda_chunk_fwd` a span, interpreted."""
+    return la._chunked_by_kernel(*x, state, kw.get("span", la.SPAN), "interpret")
+
+
+# the chunked form in XLA and as the span kernel: the same cases hold both to the scan
+FORMS = {"xla": la.kda_chunked, "span_kernel": by_kernel}
+by_form = pytest.mark.parametrize("form", list(FORMS.values()), ids=list(FORMS))
+
+
 def step_through_the_kernel(x, live=None, state=None):
     b, t = x[0].shape[:2]
     state = jnp.zeros((b, H, DK, DV), jnp.float32) if state is None else state
@@ -61,6 +72,11 @@ def test_scan_chunks_and_kernel_agree(decay):
     # spans of two chunks, the state carried from one to the next: the same numbers
     o_span, s_span = jax.jit(lambda *a: la.kda_chunked(*a, chunk=16, span=32))(*x)
     assert np.abs(o_scan - o_span).max() < 1e-5 and np.abs(s_scan - s_span).max() < 1e-5
+    # the span kernel, a chunk a span (a span edge, the state carried; two chunks of one span are the next test's)
+    o_fwd, s_fwd = jax.jit(lambda *a: by_kernel(*a, span=la.CHUNK))(*x)
+    assert bool(jnp.isfinite(o_fwd).all()) and bool(jnp.isfinite(s_fwd).all())
+    assert np.abs(o_scan - o_fwd).max() < 1e-5 and np.abs(s_scan - s_fwd).max() < 1e-5
+    assert np.abs(o_chunk - o_fwd).max() < 1e-5 and np.abs(s_chunk - s_fwd).max() < 1e-5
     o_40, s_40 = la.kda_recurrent(*(a[:, :40] for a in x))
     assert np.abs(o_40 - o_step).max() < 1e-5 and np.abs(s_40 - s_step).max() < 1e-5
     if slow:  # the first tokens are still in the state forty tokens on
@@ -68,15 +84,18 @@ def test_scan_chunks_and_kernel_agree(decay):
         assert np.abs(s_40 - without).max() > 1e-2
 
 
-def test_a_chunk_edge_and_a_carried_state():
+@by_form
+def test_a_chunk_edge_and_a_carried_state(form):
     """Two calls of the chunked form, the second from the first's state, cut
     inside a sub-chunk: the whole sequence's numbers."""
     x = inputs(1, slow=True)
     o_whole, s_whole = jax.jit(la.kda_recurrent)(*x)
-    o_a, s_a = jax.jit(la.kda_chunked)(*(a[:, :37] for a in x))
-    o_b, s_b = jax.jit(la.kda_chunked)(*(a[:, 37:] for a in x), state=s_a)
+    o_a, s_a = jax.jit(form)(*(a[:, :37] for a in x))
+    o_b, s_b = jax.jit(form)(*(a[:, 37:] for a in x), state=s_a)
     assert np.abs(jnp.concatenate([o_a, o_b], 1) - o_whole).max() < 1e-5
     assert np.abs(s_b - s_whole).max() < 1e-5
+    if form is by_kernel:  # the kernel's chunk is `CHUNK`, whatever the caller's
+        return
     for chunk in (16, 32):  # other chunk sizes, the same numbers
         assert np.abs(jax.jit(lambda *a: la.kda_chunked(*a, chunk=chunk))(*x)[0] - o_whole).max() < 1e-5
     with pytest.raises(ValueError, match="multiple of 16"):
@@ -95,20 +114,25 @@ def test_a_padded_position_is_the_identity(side):
     noise = jax.random.normal(jax.random.PRNGKey(9), q.shape)
     q, k = (jnp.where(jnp.zeros_like(a).at[:, real].set(1) > 0, a, noise) for a in (q, k))
     o_want, s_want = jax.jit(la.kda_recurrent)(*x)
-    for form in (la.kda_recurrent, la.kda_chunked):
+    for form in (la.kda_recurrent, la.kda_chunked, by_kernel):
         o, s = jax.jit(form)(q, k, v, g, beta)
         assert np.abs(o[:, real] - o_want).max() < 1e-5 and np.abs(s - s_want).max() < 1e-5
 
 
-def test_the_published_lower_bound_on_every_channel_does_not_overflow():
-    """g = -5 on every channel for 64 positions: a `k / cumprod` over the chunk
-    would need e^320; no factor the chunked form takes exceeds 1."""
+@pytest.mark.parametrize("floor", [-5.0, -12.0])
+@by_form
+def test_the_published_lower_bound_on_every_channel_does_not_overflow(form, floor):
+    """g = -5 (and -12, the other published bound) on every channel for 64
+    positions: a `k / cumprod` over the chunk would need e^320 (e^768); no
+    factor the chunked form or the span kernel takes exceeds 1."""
     q, k, v, g, beta = inputs(3, t=64, b=1)
-    g = jnp.full_like(g, -5.0)
+    g = jnp.full_like(g, floor)
     o_scan, s_scan = jax.jit(la.kda_recurrent)(q, k, v, g, beta)
-    o, s = jax.jit(la.kda_chunked)(q, k, v, g, beta)
+    o, s = jax.jit(form)(q, k, v, g, beta)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
     assert np.abs(o - o_scan).max() < 1e-5 and np.abs(s - s_scan).max() < 1e-5
+    if form is by_kernel:  # forward only
+        return
     grads = jax.jit(jax.grad(lambda g: la.kda_chunked(q, k, v, g, beta)[0].sum()))(g)
     assert bool(jnp.isfinite(grads).all())
 
@@ -150,3 +174,22 @@ def test_the_kernel_leaves_a_masked_row_s_state_to_the_bit_and_the_plain_step_ag
     assert la.decode_kernel_takes(32, 128, 128) and not la.decode_kernel_takes(4, 16, 16)
     with pytest.raises(ValueError, match="groups of 32 heads"):
         la.kda_decode_step(state, *now, live, "pallas")
+
+
+def test_who_takes_the_span_kernel_is_the_mode_and_the_tiling(monkeypatch):
+    """`forward_only` (a cached prefill) hands the spans to `kda_chunk_fwd`
+    where `ops.attention.kernel_mode()` says "pallas" and the tiling fits, or
+    "interpret"; off the TPU, with kernels off, across a mesh, or without
+    `forward_only` (a forward that may be differentiated) the XLA form runs."""
+    from trlx_tpu.ops import attention
+
+    x, calls, kernel = inputs(6, t=20, b=1), [], la._chunked_by_kernel
+    monkeypatch.setattr(la, "_chunked_by_kernel", lambda *a: calls.append(a[-1]) or la.kda_chunked(*a[:6]))
+    for mode, takes in (("off", None), ("sharded", None), ("interpret", "interpret"), ("pallas", None)):
+        monkeypatch.setattr(attention, "kernel_mode", lambda mode=mode: mode)
+        assert la.chunk_kernel_mode(H, DK, DV) == takes  # 4 heads of 16: not the compiled kernel's tiling
+        assert la.chunk_kernel_mode(64, 128, 128) == (mode if mode in ("interpret", "pallas") else None)
+        jax.eval_shape(lambda: (la.kda_chunked(*x, forward_only=True), la.kda_chunked(*x)))
+    assert calls == ["interpret"]
+    with pytest.raises(ValueError, match="groups of 32 heads"):
+        kernel(*x, None, la.SPAN, "pallas")

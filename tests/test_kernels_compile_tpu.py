@@ -21,9 +21,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 pytest.importorskip("libtpu", reason="AOT compilation for the TPU needs libtpu")
 
-from aot_tpu import (  # noqa: E402, F401  (v5e is a fixture)
+from aot_tpu import (  # noqa: E402, F401  (v5e and pallas_mode are fixtures)
     BF16, CELL, CODE_CELL, F32, I8, I32, LONGCTX_CELL, S, abstract, arena_rewrites, compile_for,
-    donated_outputs, kernel_names, mosaic_calls, v5e,
+    donated_outputs, kernel_names, mosaic_calls, pallas_mode, v5e,
 )
 from trlx_tpu.ops import attention, fused_ce, paged_attention  # noqa: E402
 from trlx_tpu.ops.paged_attention import (  # noqa: E402
@@ -307,6 +307,39 @@ def test_the_chunked_form_s_temporaries_do_not_grow_with_the_prompt(v5e):
     short, long = temporaries(2048), temporaries(8192)
     print(f"temporaries at 2,048 positions {short}, at 8,192 {long}")
     assert long < 1.05 * short and long < 512e6, (short, long)
+
+
+@pytest.mark.parametrize("t,heads", [(8192, 64), (1024, 32)], ids=["longctx_widest_insert", "reason_widest_insert"])
+def test_kda_chunk_fwd_compiles_at_the_cells_widest_inserts_and_holds_nothing_of_a_span(v5e, pallas_mode, t, heads):
+    """`kda_chunked` as a cached prefill runs it where kernels run, at
+    `solar-open2-250b.rollout-longctx`'s widest insert ([1, 8192, 64, 128]) and
+    `ling-3.0-flash-vl.rollout-reason`'s ([1, 1024, 32, 128]), float32 as the
+    layer hands them: Mosaic takes the strided tile reads, the shifts and the
+    float32 products; the call carries `kda_chunk_fwd`; beside arguments and
+    results the program holds next to nothing (no pair tensor, no re-laid
+    copy of an input: the XLA form's span is 0.4 GB); and at 8,192 the text
+    still holds ONE `while` whose tuple carries the row's state as
+    `f32[1,64,128,128]`, which is what
+    `bench/metrics/readers/kv_hybrid_kernels.py` tells the recurrence by."""
+    import re
+
+    from trlx_tpu.ops import linear_attention
+
+    one = SingleDeviceSharding(v5e[0])
+    vec = lambda *shape: S(shape, F32, sharding=one)
+    args = (vec(1, t, heads, 128), vec(1, t, heads, 128), vec(1, t, heads, 128), vec(1, t, heads, 128),
+            vec(1, t, heads), vec(1, heads, 128, 128))
+    prefill = lambda *a: linear_attention.kda_chunked(*a, forward_only=True)
+    compiled = jax.jit(prefill).trace(*args).lower(lowering_platforms=("tpu",)).compile()
+    assert kernel_names(compiled) == ["kda_chunk_fwd"]
+    text = compiled.as_text()
+    assert not re.search(r"f32\[[0-9,]*,16,16,128\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20, compiled.memory_analysis().temp_size_in_bytes
+    loops = [line for line in text.splitlines() if " while(" in line]
+    if t > linear_attention.SPAN:
+        assert len(loops) == 1 and f"f32[1,{heads},128,128]" in loops[0].partition(" while(")[0], loops
+    else:
+        assert not loops, loops  # one span: one call
 
 
 def test_ssd_decode_compiles_at_the_cell_s_slot_pool_and_the_chunked_form_holds_a_chunk(v5e):

@@ -236,13 +236,22 @@ def engine_errors(cfg, params, prompts, out, got):
 
 
 @pytest.mark.parametrize("path", ["interpret", "xla"])
-def test_engine_end_to_end_matches_the_reference_with_no_fallback(policy, path):
+def test_engine_end_to_end_matches_the_reference_with_no_fallback(policy, path, monkeypatch):
     """(b) the fresh-prompt insert (right-padded rows through the chunked form
     from an empty state, the final state and tails into each row's slot), then
     48 decode steps (3 x the prompt bucket's chunk) with a step in flight:
     `kda_decode` through the interpreter or the plain step, absorbed paged
-    latent attention beside it, rows of unequal length."""
+    latent attention beside it, rows of unequal length. Where kernels are
+    interpreted (`TRLX_TPU_KERNELS`, the rule the insert's kernels ask) the
+    prompts' recurrence is `kda_chunk_fwd` a span; else the XLA form."""
+    from trlx_tpu.inference.engine import _prefill_state_form
+    from trlx_tpu.ops.attention import KERNEL_PATHS
+
     cfg, params = policy
+    if path == "interpret":
+        monkeypatch.setenv("TRLX_TPU_KERNELS", "interpret")
+    monkeypatch.setitem(KERNEL_PATHS, "kda_chunk_fwd", {})
+    assert _prefill_state_form(cfg) == ("kernel" if path == "interpret" else "xla")
     rng = np.random.default_rng(11)
     prompts = [rng.integers(1, VOCAB, size=n).astype(np.int32) for n in (29, 5, 16)]
     with jax.default_matmul_precision("highest"):
@@ -255,6 +264,9 @@ def test_engine_end_to_end_matches_the_reference_with_no_fallback(policy, path):
     stats = engine.kv_stats()
     assert stats["kv_kernel_fallbacks"] == {} and stats["decode_steps_ahead_total"] > 0
     assert sorted(engine._paged_insert_fns) == [(1, 32, True), (2, 16, True)]
+    # the two insert programs' KDA layers: one row of 32 positions and two of 16, 4 heads of 16, a span each
+    assert KERNEL_PATHS["kda_chunk_fwd"] == (
+        {"interpret": [(1, 32, 4, 16), (2, 16, 4, 16)]} if path == "interpret" else {})
     assert [len(got[s]) for s in range(3)] == [48] * 3
     assert max(engine_errors(cfg, params, prompts, [out[s] for s in range(3)], [got[s] for s in range(3)])) < TOL
     # what a slot holds beside the arena, and what a step does to it: float32 matrices, float32 tails here
@@ -285,6 +297,14 @@ def test_a_state_the_compiled_kda_kernel_cannot_tile_is_a_counted_fallback(polic
     assert stats["kv_kernel_dispatches"] == 0 and set(stats["kv_kernel_fallbacks"]) == {"kda_decode_tiling"}
     published = config_from_preset("ling-3.0-flash-vl", 157184)
     assert linear_attention.decode_kernel_takes(published.n_heads, published.head_dim, published.head_dim)
+    # the same predicate decides the span kernel of a prompt's prefill: where compiled kernels run, this
+    # model's 4 heads of 16 go the XLA form (and the counter above is the count), the published 32 of 128 the kernel
+    from trlx_tpu.inference.engine import _prefill_state_form
+    from trlx_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "kernel_mode", lambda: "pallas")
+    assert linear_attention.chunk_kernel_mode(cfg.n_heads, cfg.head_dim, cfg.head_dim) is None
+    assert _prefill_state_form(cfg) == "xla" and _prefill_state_form(published) == "kernel"
 
 
 def test_a_reused_slot_and_a_cancelled_step_in_flight_touch_nobody_s_state(policy):

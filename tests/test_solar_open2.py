@@ -252,13 +252,22 @@ def engine_errors(cfg, params, prompts, out, got):
 
 
 @pytest.mark.parametrize("path", ["interpret", "xla"])
-def test_engine_end_to_end_matches_the_reference_with_no_fallback(policy, engines, path):
+def test_engine_end_to_end_matches_the_reference_with_no_fallback(policy, engines, path, monkeypatch):
     """The fresh-prompt insert (right-padded rows: K and V into the GQA layer's
     blocks through the fused prefill, the chunked form's final state and the
     tails into each row's slot), then 24 decode steps with a step in flight:
     `paged_decode` over 2 query heads a K/V head and `kda_decode`, through the
-    interpreter or the plain paths, rows of unequal length."""
+    interpreter or the plain paths, rows of unequal length. Where kernels are
+    interpreted (`TRLX_TPU_KERNELS`, the rule the insert's kernels ask) the
+    prompt's recurrence is `kda_chunk_fwd` a span; else the XLA form."""
+    from trlx_tpu.inference.engine import _prefill_state_form
+    from trlx_tpu.ops.attention import KERNEL_PATHS
+
     (cfg, params), engine = policy, engines[path]
+    if path == "interpret":
+        monkeypatch.setenv("TRLX_TPU_KERNELS", "interpret")
+    monkeypatch.setitem(KERNEL_PATHS, "kda_chunk_fwd", {})
+    assert _prefill_state_form(cfg) == ("kernel" if path == "interpret" else "xla")
     rng = np.random.default_rng(11)
     prompts = [rng.integers(1, VOCAB, size=n).astype(np.int32) for n in (29, 5, 16)]
     with jax.default_matmul_precision("highest"):
@@ -272,6 +281,8 @@ def test_engine_end_to_end_matches_the_reference_with_no_fallback(policy, engine
     stats = engine.kv_stats()
     assert stats["kv_kernel_fallbacks"] == {} and stats["decode_steps_ahead_total"] > 0
     assert sorted(engine._paged_insert_fns) == [(1, 32, True)]
+    # the insert program's three KDA layers: one row of 32 positions, 4 heads of 16, a span each
+    assert KERNEL_PATHS["kda_chunk_fwd"] == ({"interpret": [(1, 32, 4, 16)]} if path == "interpret" else {})
     assert [len(got[s]) for s in range(3)] == [24] * 3
     assert max(engine_errors(cfg, params, prompts, [out[s] for s in range(3)], [got[s] for s in range(3)])) < TOL
     # what a slot holds beside the arena, and what a step does to it: 3 of the 4 layers, float32 tails here
@@ -321,7 +332,11 @@ def test_the_prefill_state_counter_span_says_what_the_chunked_form_runs(policy, 
     monkeypatch.setattr(tracing, "counters", lambda name, **kw: seen.append((name, kw)))
     programs = engine._prefill_programs([(np.arange(1, 30, dtype=np.int32), 4), (np.arange(1, 6, dtype=np.int32), 4)])
     assert engine._count_admission(programs) == (2, 34, 64, 2)
-    assert ("engine.prefill_state", dict(tokens=34, padded_tokens=64, linear_layers=3, chunks=2)) in seen
+    assert ("engine.prefill_state", dict(tokens=34, padded_tokens=64, linear_layers=3, chunks=2, form="xla")) in seen
+    monkeypatch.setenv("TRLX_TPU_KERNELS", "interpret")  # as where kernels run: the span kernel takes the recurrence
+    engine._count_admission(programs)
+    assert seen[-1] == ("engine.prefill_state", dict(tokens=34, padded_tokens=64, linear_layers=3, chunks=2,
+                                                     form="kernel"))
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer_with_the_shared_expert_counted_once():

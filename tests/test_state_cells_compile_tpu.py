@@ -33,8 +33,9 @@ def test_reason_cell_programs_compile_for_the_chip_and_fit_it(v5e, reason_cell_e
     """`ling-3.0-flash-vl.rollout-reason`'s decode step and its widest prefill
     (one row of 1,024, the fresh-prompt program) at the published widths: one
     `kda_decode` a linear layer and one absorbed paged call for the latent one,
-    the prompt's recurrence in chunks under XLA and its latent layer through
-    the flash forward, three grouped products an expert layer, neither the
+    the prompt's recurrence one `kda_chunk_fwd` a linear layer (a prompt of one
+    span) and its latent layer through the flash forward, three grouped
+    products an expert layer, neither the
     arena nor a slot-state array copied, and arguments plus temporaries under
     15.0 GB: 4.73 GB of weights, 1.39 GB of slot state, 0.45 GB of arena and
     the program's own."""
@@ -44,7 +45,7 @@ def test_reason_cell_programs_compile_for_the_chip_and_fit_it(v5e, reason_cell_e
         want = {"kda_decode": 5, "paged_decode_latent": 1, "moe_gmm": 15}
     else:
         compiled = compile_engine_program(engine, params, v5e[0], (1, 1024, True))
-        want = {"flash_fwd_latent": 1, "moe_gmm": 15}
+        want = {"flash_fwd_latent": 1, "kda_chunk_fwd": 5, "moe_gmm": 15}
     names = kernel_names(compiled)
     assert {n: names.count(n) for n in set(names)} == want
     # the convolutions' tails (9 MB a layer) are shifted, so written anew, every step by
@@ -74,9 +75,10 @@ def test_kv_hybrid_cell_programs_compile_for_the_chip_and_fit_it(v5e, kv_hybrid_
     (one row of 8,192, the fresh-prompt program) at the published widths: one
     `kda_decode` a linear layer over 64 heads and one `paged_decode` for the
     GQA layer (8 query heads a K/V head, no rotation in front), the prompt's
-    recurrence in chunks under XLA a span of 1,024 positions at a time (the
-    test below holds its temporaries to a span's) and its GQA layer through the
-    flash forward, three grouped products an expert layer, neither the arena
+    recurrence one `kda_chunk_fwd` a linear layer inside the scan over spans of
+    1,024 positions (one call in the text, eight spans at run time) and its GQA
+    layer through the flash forward, three grouped products an expert layer,
+    neither the arena
     nor a recurrent matrix copied, and arguments plus temporaries under 15.5
     GB: 6.62 GB of weights, 0.83 GB of slot state, 1.61 GB of arena and the
     program's own."""
@@ -86,7 +88,7 @@ def test_kv_hybrid_cell_programs_compile_for_the_chip_and_fit_it(v5e, kv_hybrid_
         want = {"kda_decode": 3, "paged_decode": 1, "moe_gmm": 12}
     else:
         compiled = compile_engine_program(engine, params, v5e[0], (1, 8192, True))
-        want = {"flash_fwd": 1, "moe_gmm": 12}
+        want = {"flash_fwd": 1, "kda_chunk_fwd": 3, "moe_gmm": 12}
         assert instructions_of_at_least(compiled, 64 * 8192 * 8192) == []  # no [heads, 8192, 8192] score tensor
     names = kernel_names(compiled)
     assert {n: names.count(n) for n in set(names)} == want

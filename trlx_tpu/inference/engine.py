@@ -922,11 +922,11 @@ class InferenceEngine:
             tracing.counters("sched.insert", calls=1, rows=rows, prompt_tokens=tokens, padded_tokens=padded,
                              pad_tokens=padded - tokens, head_positions=head_positions)
             if self._recurrent_layers:
-                # what the chunked recurrence is about to run, a layer: every padded position, in chunks
-                chunk = self.model_cfg.state_chunk
+                # what the chunked recurrence is about to run, a layer: every padded position, in chunks, in which form
+                chunks = sum(pb * -(-plen // self.model_cfg.state_chunk) for plen, _, pb in programs)
                 tracing.counters("engine.prefill_state", tokens=tokens, padded_tokens=padded,
-                                 linear_layers=self._recurrent_layers,
-                                 chunks=sum(pb * -(-plen // chunk) for plen, _, pb in programs))
+                                 linear_layers=self._recurrent_layers, chunks=chunks,
+                                 form=_prefill_state_form(self.model_cfg))
         return rows, tokens, padded, head_positions
 
     @contextlib.contextmanager
@@ -1855,3 +1855,13 @@ class InferenceEngine:
         step behind the device, and never a wait for it (any thread may
         ask while a step is in flight)."""
         return int(self._live.sum())
+
+
+def _prefill_state_form(cfg) -> str:
+    """Which form a prompt's chunked recurrence runs in an insert program:
+    "kernel" (`kda_chunk_fwd` a span; `kda_chunked` asks the same function
+    when the program is traced) or "xla" (an SSM mixer's always)."""
+    from trlx_tpu.ops.linear_attention import chunk_kernel_mode
+
+    by_kernel = cfg.has_linear_layers and chunk_kernel_mode(cfg.n_heads, cfg.head_dim, cfg.head_dim)
+    return "kernel" if by_kernel else "xla"

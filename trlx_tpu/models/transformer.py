@@ -1530,7 +1530,7 @@ class KimiDeltaAttention(nn.Module):
                 attn_kernel if attn_kernel in ("pallas", "interpret") else None)
             o = o[:, None]
         else:
-            o, new_state = kda.kda_chunked(q, k, v, g, beta, state)
+            o, new_state = kda.kda_chunked(q, k, v, g, beta, state, forward_only=state is not None)
         new_cache = None
         if layer_cache is not None:
             new_cache = {"state": new_state.astype(state.dtype),

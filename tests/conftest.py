@@ -54,6 +54,8 @@ LONGEST_FIRST = [
     "test_pipeline_sequence.py",           # 82 s
     "test_forward_entry.py",               # 80 s
     "test_sequence_parallel.py",           # 79 s
+    "test_transcript_cell_compile_tpu.py",  # 75 s (my CPU run, PR 55: the decode step and one row of 14,336)
+    "test_smallthinker.py",                # 76 s (my CPU run, PR 55)
     "test_tracing_control.py",             # 69 s
     "test_solar_open2.py",                 # 68 s
     "test_pipeline_tp.py",                 # 64 s

@@ -106,6 +106,7 @@ def test_the_prefill_kernels_through_the_interpreter_match_their_plain_forms(mon
     from trlx_tpu.ops import attention
 
     monkeypatch.setattr(attention, "kernel_mode", lambda: "interpret")
+    monkeypatch.setattr(attention, "KERNEL_PATHS", {})  # the process's record: a file run earlier by this worker is in it
     monkeypatch.setattr(sparse, "INDEX_BLOCK_Q", 32)
     monkeypatch.setattr(sparse, "INDEX_BLOCK_K", 128)
     monkeypatch.setattr(sparse, "ATTEND_BLOCK_Q", 32)

@@ -179,10 +179,10 @@ def test_grouped_dispatch_equals_the_dense_masked_layer_under_a_skewed_router(mo
     params = {**params, "expert_bias": {"bias": params["expert_bias"]["bias"].at[1].add(10.0)}}
     flat = x.reshape(-1, cfg.d_model)
     token_mask = jnp.asarray(np.random.default_rng(0).integers(0, 4, size=flat.shape[0]) > 0, jnp.int32)
-    args = (flat, router, params["expert_bias"]["bias"], params["expert_gate"]["kernel"],
-            params["expert_up"]["kernel"], params["expert_down"]["kernel"])
+    stacks = (params["expert_gate"]["kernel"], params["expert_up"]["kernel"], params["expert_down"]["kernel"])
     with jax.default_matmul_precision("highest"):
-        got, stats = moe.sparse_moe(*args, top_k=2, token_mask=token_mask, mode=mode)
+        routing = moe.route_sigmoid(flat, router, params["expert_bias"]["bias"], 2)
+        got, stats = moe.routed_experts(flat, *routing, *stacks, token_mask=token_mask, mode=mode)
         want = _reference_layer(flat, params, cfg) * token_mask[:, None]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     assert float(stats["dropped_tokens"]) == 0.0

@@ -57,6 +57,8 @@ def _family_of(hf: Dict) -> str:
         return "lfm2_moe"
     if mt == "laguna":
         return "laguna"
+    if mt == "smallthinker" or ("moe_num_primary_experts" in hf and "sliding_window_layout" in hf):
+        return "smallthinker"
     if mt == "pangu_ultra_moe":
         return "pangu_ultra_moe"
     if mt == "dots3_note":
@@ -193,6 +195,8 @@ def config_from_hf(path: str, **overrides):
                 raise NotImplementedError(f"lfm2_moe with {key}={hf[key]!r} is not supported")
     elif fam == "laguna":
         kwargs = _laguna_kwargs(hf)
+    elif fam == "smallthinker":
+        kwargs = _smallthinker_kwargs(hf)
     elif fam == "pangu_ultra_moe":
         kwargs = _pangu_kwargs(hf)
     elif fam == "dots3_note":
@@ -246,6 +250,35 @@ def _laguna_kwargs(hf: Dict) -> Dict:
         moe_d_ff=hf["moe_intermediate_size"], moe_dense_layers=dense, moe_router="sigmoid",
         moe_shared_d_ff=hf.get("shared_expert_intermediate_size", 0),
         moe_routed_scale=float(hf.get("moe_routed_scaling_factor", 1.0)),
+    )
+
+
+def _smallthinker_kwargs(hf: Dict) -> Dict:
+    """PowerInfer's `smallthinker` config keys -> TransformerConfig fields. A
+    layer is full and unrotated (`sliding_window_layout` 0, `rope_layout` 0) or
+    banded and rotated (1, 1): the two layouts name the same layers in every
+    published config, and one that did not would need a third kind. What the
+    keys do not settle (the router's input, rotate-half, ReGLU as relu * up) is
+    bench/configs/smallthinker-21b-a3b.json's `assumed`."""
+    window, rope = list(hf["sliding_window_layout"]), list(hf["rope_layout"])
+    if window != rope:
+        raise NotImplementedError("smallthinker with rope_layout != sliding_window_layout (a banded layer that "
+                                  "does not rotate, or a full one that does) is not supported")
+    for key, want in (("moe_primary_router_apply_softmax", True), ("norm_topk_prob", True), ("rope_scaling", None)):
+        if hf.get(key, want) != want:
+            raise NotImplementedError(f"smallthinker with {key}={hf[key]!r} is not supported")
+    return dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=hf["num_hidden_layers"],
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf["num_key_value_heads"], head_width=hf["head_dim"],
+        d_ff=hf["moe_ffn_hidden_size"], max_seq_len=hf["max_position_embeddings"], pos_embed="rope",
+        norm="rmsnorm", layer_norm_epsilon=hf.get("rms_norm_eps", 1e-6), activation="relu", glu=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)), use_bias=False, flash_prefill=True,
+        sliding_window=hf["sliding_window_size"],
+        layer_types=tuple("sliding_attention" if banded else "full_attention" for banded in window),
+        rope_kinds=(("full_attention", RopeSpec(pct=0.0)),
+                    ("sliding_attention", RopeSpec(theta=float(hf["rope_theta"])))),
+        moe_experts=hf["moe_num_primary_experts"], moe_top_k=hf["moe_num_active_primary_experts"],
+        moe_d_ff=hf["moe_ffn_hidden_size"], moe_router="topk_softmax", moe_route_on="block_input",
     )
 
 
@@ -695,6 +728,31 @@ def _load_lfm2_moe(sd: Dict, cfg: TransformerConfig) -> Dict:
     return lm
 
 
+def _load_smallthinker(sd: Dict, cfg: TransformerConfig) -> Dict:
+    """SmallThinkerForCausalLM, under the tensor names bench/configs/
+    smallthinker-21b-a3b.json `assumed` lists (the family's convention as
+    recalled: the published index is not in the repository). The tree holds the
+    matrices of experts [moe_local_offset, + experts_held) side by side."""
+    pre = "model." if any(k.startswith("model.") for k in sd) else ""
+    lm: Dict = {"embed_tokens": {"embedding": sd[f"{pre}embed_tokens.weight"]},
+                "ln_f": _ln(sd, f"{pre}norm", bias=False),
+                "lm_head": _dense(sd["lm_head.weight"].T)}
+    held = range(cfg.moe_local_offset, cfg.moe_local_offset + cfg.experts_held)
+    for i in range(cfg.n_layers):
+        p = f"{pre}layers.{i}."
+        moe = p + "block_sparse_moe."
+        lm[f"block_{i}"] = {
+            "ln_attn": _ln(sd, p + "input_layernorm", bias=False),
+            "ln_mlp": _ln(sd, p + "post_attention_layernorm", bias=False),
+            "attn": {n: _dense(sd[p + f"self_attn.{n}.weight"].T) for n in ("q_proj", "k_proj", "v_proj", "o_proj")},
+            "mlp": {"router": _dense(sd[moe + "primary_router.weight"].T),
+                    **{f"expert_{n}": _dense(np.concatenate(
+                        [sd[moe + f"experts.{e}.{n}.weight"].T for e in held], axis=1))
+                       for n in ("gate", "up", "down")}},
+        }
+    return lm
+
+
 _PANGU_ATTN = (("q_a_proj", "q_a_proj"), ("q_b_proj", "q_b_proj"), ("kv_a_proj", "kv_a_proj_with_mqa"),
                ("kv_b_proj", "kv_b_proj"), ("o_proj", "o_proj"))
 _PANGU_ATTN_NORMS = (("q_a_norm", "q_a_layernorm"), ("kv_a_norm", "kv_a_layernorm"))
@@ -1057,6 +1115,7 @@ _LOADERS: Dict[str, Callable] = {
     "pangu_ultra_moe": _load_pangu_ultra_moe,
     "dots3_note": _load_dots3_note,
     "falcon_h1": _load_falcon_h1,
+    "smallthinker": _load_smallthinker,
 }
 
 
@@ -1196,6 +1255,26 @@ def _export_lfm2_moe(lm: Dict, cfg: TransformerConfig) -> Dict:
             stack = _f32(b["mlp"][f"expert_{n.split('_')[0]}"]["kernel"])
             for g, mat in enumerate(np.split(stack, cfg.experts_held, axis=1)):
                 sd[p + f"feed_forward.experts.{cfg.moe_local_offset + g}.{w}.weight"] = mat.T
+    return sd
+
+
+def _export_smallthinker(lm: Dict, cfg: TransformerConfig) -> Dict:
+    """Inverse of `_load_smallthinker`: the experts held go out under their
+    indices in the whole model."""
+    sd = {"model.embed_tokens.weight": _f32(lm["embed_tokens"]["embedding"]),
+          "model.norm.weight": _f32(lm["ln_f"]["scale"]),
+          "lm_head.weight": _f32(lm["lm_head"]["kernel"]).T}
+    for i in range(cfg.n_layers):
+        b, p = lm[f"block_{i}"], f"model.layers.{i}."
+        sd[p + "input_layernorm.weight"] = _f32(b["ln_attn"]["scale"])
+        sd[p + "post_attention_layernorm.weight"] = _f32(b["ln_mlp"]["scale"])
+        for n in ("q_proj", "k_proj", "v_proj", "o_proj"):
+            sd[p + f"self_attn.{n}.weight"] = _f32(b["attn"][n]["kernel"]).T
+        moe = p + "block_sparse_moe."
+        sd[moe + "primary_router.weight"] = _f32(b["mlp"]["router"]["kernel"]).T
+        for n in ("gate", "up", "down"):
+            for g, mat in enumerate(np.split(_f32(b["mlp"][f"expert_{n}"]["kernel"]), cfg.experts_held, axis=1)):
+                sd[moe + f"experts.{cfg.moe_local_offset + g}.{n}.weight"] = mat.T
     return sd
 
 
@@ -1527,6 +1606,7 @@ _EXPORTERS: Dict[str, Callable] = {
     "pangu_ultra_moe": _export_pangu_ultra_moe,
     "dots3_note": _export_dots3_note,
     "falcon_h1": _export_falcon_h1,
+    "smallthinker": _export_smallthinker,
 }
 
 
@@ -1546,7 +1626,7 @@ def infer_family(cfg) -> str:
     if getattr(cfg, "has_latent_layers", False):
         return "pangu_ultra_moe"
     if getattr(cfg, "attention_kinds", ()):
-        return "laguna"
+        return "smallthinker" if cfg.moe_route_on == "block_input" else "laguna"
     if cfg.alibi:
         return "bloom"
     if cfg.pos_offset:
@@ -1610,6 +1690,18 @@ def config_to_hf(cfg: TransformerConfig, family: str = None) -> Dict:
             pad_token_id=(cfg.pad_token_id if cfg.pad_token_id is not None
                           else cfg.decoder_start_token_id),
             eos_token_id=cfg.eos_token_id if cfg.eos_token_id is not None else 1,
+        )
+    if family == "smallthinker":
+        banded = [int(kind == "sliding_attention") for kind in cfg.layer_types]
+        return dict(
+            model_type="smallthinker", architectures=["SmallThinkerForCausalLM"], vocab_size=cfg.vocab_size,
+            hidden_size=cfg.d_model, num_hidden_layers=cfg.n_layers, num_attention_heads=cfg.n_heads,
+            num_key_value_heads=cfg.kv_heads, head_dim=cfg.head_dim, max_position_embeddings=cfg.max_seq_len,
+            rms_norm_eps=cfg.layer_norm_epsilon, moe_ffn_hidden_size=cfg.expert_d_ff,
+            moe_num_primary_experts=cfg.moe_experts, moe_num_active_primary_experts=cfg.moe_top_k,
+            moe_primary_router_apply_softmax=True, norm_topk_prob=True, rope_layout=banded,
+            sliding_window_layout=banded, sliding_window_size=cfg.sliding_window, rope_scaling=None,
+            rope_theta=cfg.rope_of("sliding_attention").theta, tie_word_embeddings=cfg.tie_embeddings,
         )
     if family == "laguna":
         def rope(spec):

@@ -99,6 +99,8 @@ class LatentSpec:
 # about caches asks that: a kind is in no list of "attention" or "state" kinds.
 LAYER_KINDS = ("attention", "full_attention", "sliding_attention", "latent_attention", "conv", "linear_attention",
                "ssm_attention", "sliding_latent_attention", "sparse_latent_attention")
+# The routers of `SparseMoE` (`TransformerConfig.moe_router`; "softmax" is `MoEMLP`'s).
+SPARSE_ROUTERS = ("sigmoid", "topk_softmax")
 
 
 @dataclass(frozen=True)
@@ -203,10 +205,18 @@ class TransformerConfig:
     moe_dense_layers: int = 0
     moe_d_ff: Optional[int] = None
     # "softmax": MoEMLP below (renormalized softmax gates, auxiliary loss,
-    # every expert computes every token). "sigmoid": `SparseMoE` (float32
-    # sigmoid scores, top-k over scores + a selection bias that no gradient
-    # moves, grouped dispatch over the experts held here, no auxiliary loss).
+    # every expert computes every token). The two of `SPARSE_ROUTERS` are
+    # `SparseMoE`'s (grouped dispatch over the experts held here, no auxiliary
+    # loss): "sigmoid", float32 sigmoid scores, top-k over scores + a selection
+    # bias that no gradient moves; "topk_softmax", top-k over float32 logits and
+    # a softmax over the chosen, no bias (SmallThinker's).
     moe_router: str = "softmax"
+    # `SparseMoE` only, what the router reads: "ffn_input", the normed input
+    # of the feed-forward it routes (every family but one); "block_input", the
+    # block's own input, un-normed, before its attention (SmallThinker: the
+    # routing is known while the attention runs). `Block` is the one place
+    # that hands the router its input.
+    moe_route_on: str = "ffn_input"
     # Expert parallelism seen from one chip: of the model's `moe_experts`
     # this process holds `moe_local_experts` (0 = all), the contiguous range
     # starting at `moe_local_offset`. The router still scores all of them;
@@ -388,7 +398,8 @@ class TransformerConfig:
             raise ValueError(f"moe_n_group {self.moe_n_group} and moe_topk_group {self.moe_topk_group} go together")
         if self.moe_n_group:
             if self.moe_router != "sigmoid":
-                raise NotImplementedError("group-limited routing (moe_n_group) needs moe_router='sigmoid'")
+                raise NotImplementedError("group-limited routing (moe_n_group) scores groups by biased sigmoid "
+                                          "scores: it needs moe_router='sigmoid'")
             if self.moe_experts % self.moe_n_group or self.moe_topk_group > self.moe_n_group \
                     or self.moe_top_k > self.moe_topk_group * (self.moe_experts // self.moe_n_group):
                 raise ValueError(
@@ -396,15 +407,18 @@ class TransformerConfig:
                     f"{self.moe_top_k} experts a token do not fit")
         if self.sandwich_norm and self.parallel_residual:
             raise NotImplementedError("sandwich_norm under parallel_residual is not supported")
-        if (self.moe_shared_d_ff or self.moe_routed_scale != 1.0) and self.moe_router != "sigmoid":
-            raise NotImplementedError("a shared expert and a routed scale need moe_router='sigmoid'")
-        if self.moe_router not in ("softmax", "sigmoid"):
-            raise ValueError(f"moe_router must be 'softmax' or 'sigmoid', got {self.moe_router!r}")
-        if self.moe_local_experts and self.moe_router != "sigmoid":
-            raise NotImplementedError(
-                "moe_local_experts (one chip's share of the experts) needs the "
-                "grouped dispatch of moe_router='sigmoid'"
-            )
+        if self.moe_router != "softmax" and self.moe_router not in SPARSE_ROUTERS:
+            raise ValueError(f"moe_router must be 'softmax' or one of {SPARSE_ROUTERS}, got {self.moe_router!r}")
+        if self.moe_route_on not in ("ffn_input", "block_input"):
+            raise ValueError(f"moe_route_on must be 'ffn_input' or 'block_input', got {self.moe_route_on!r}")
+        for on, what in (
+                (self.moe_shared_d_ff or self.moe_routed_scale != 1.0, "a shared expert and a routed scale"),
+                (self.moe_local_experts, "moe_local_experts (one chip's share of the experts)"),
+                (self.moe_route_on != "ffn_input", f"moe_route_on={self.moe_route_on!r}")):
+            if on and self.moe_router not in SPARSE_ROUTERS:
+                raise NotImplementedError(
+                    f"{what}: only the grouped dispatch of `SparseMoE` has it (moe_router one of {SPARSE_ROUTERS}, "
+                    f"not {self.moe_router!r}: `MoEMLP` multiplies every token by every expert)")
         if self.moe_local_offset + self.experts_held > max(self.moe_experts, 0) and self.moe_experts:
             raise ValueError(
                 f"experts [{self.moe_local_offset}, {self.moe_local_offset + self.experts_held}) "
@@ -597,8 +611,9 @@ class TransformerConfig:
 
     @property
     def has_sparse_moe(self) -> bool:
-        """Whether some layer is a `SparseMoE` (which sows dispatch counters)."""
-        return self.moe_experts > 0 and self.moe_router == "sigmoid"
+        """Whether some layer is a `SparseMoE` (which sows dispatch counters),
+        under either of its routers."""
+        return self.moe_experts > 0 and self.moe_router in SPARSE_ROUTERS
 
     @property
     def blocks_read_token_mask(self) -> bool:
@@ -863,8 +878,8 @@ class Attention(nn.Module):
             head_norm = lambda name: nn.RMSNorm(
                 epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
             q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
-        if cfg.pos_embed == "rope":
-            rope = cfg.rope_of(self.kind)
+        rope = cfg.rope_of(self.kind)
+        if cfg.pos_embed == "rope" and (rope is None or rope.pct > 0):  # pct 0: a kind that does not rotate (NoPE)
             q = apply_rope(q, positions, cfg.rope_theta, cfg.rotary_dim, rope)
             k = apply_rope(k, positions, cfg.rope_theta, cfg.rotary_dim, rope)
         gate = None
@@ -1677,70 +1692,104 @@ class _Scale(nn.Module):
         return self.param("scale", nn.initializers.ones, self.shape, self.param_dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("top_k", "offset", "act", "n_group", "topk_group"))
-def _routed_block(x, token_mask, router, bias, w_gate, w_up, w_down, *, top_k, offset, act, n_group, topk_group):
-    """`ops.moe.sparse_moe` on one block of a long call's tokens (`SparseMoE`, `moe_token_block`)."""
+def _routed_block(x, token_mask, routing, router, bias, w_gate, w_up, w_down, *, router_kind, top_k, offset, act,
+                  n_group, topk_group, count_met):
+    """The experts held over one block of a call's tokens: routed here over `x` itself
+    (`routing` None: `ops.moe.route`), or by the `routing` the caller made elsewhere."""
     from trlx_tpu.ops import moe
 
-    return moe.sparse_moe(x, router, bias, w_gate, w_up, w_down, top_k=top_k, offset=offset, act=act,
-                          token_mask=token_mask, n_group=n_group, topk_group=topk_group)
+    if routing is None:
+        routing = moe.route(x, router, bias, top_k, router_kind, n_group, topk_group)
+    return moe.routed_experts(x, *routing, w_gate, w_up, w_down, offset=offset, act=act, token_mask=token_mask,
+                              count_met=count_met)
+
+
+# one jitted function for the blocks of a long call (`moe_token_block`): blocks of one shape (all
+# but a ragged last, in every layer and every program of the process) are traced once and lowered
+# once a program
+_routed_block_jit = jax.jit(_routed_block, static_argnames=(
+    "router_kind", "top_k", "offset", "act", "n_group", "topk_group", "count_met"))
 
 
 class SparseMoE(nn.Module):
-    """Sigmoid-routed experts with grouped dispatch (LFM2's expert ffn):
+    """Routed experts with grouped dispatch (LFM2's expert ffn; SmallThinker's):
 
-        s = sigmoid(x W_r) in float32;  sel = top_k(s + b);  w = s[sel]
-        w /= sum(w) + 1e-6;  y = sum_{e in sel, e held here} w_e W2_e(act(W1_e x) * W3_e x)
+        "sigmoid":       s = sigmoid(x W_r) in float32;  sel = top_k(s + b);  w = s[sel];  w /= sum(w) + 1e-6
+        "topk_softmax":  r = x W_r in float32;  sel = top_k(r);  w = softmax(r[sel])
+        y = sum_{e in sel, e held here} w_e W2_e(act(W1_e n) * W3_e n)
 
-    `b` (`expert_bias/bias`) only steers the selection: load balancing
-    moves it, the gradient never does (stop_gradient in `route_sigmoid`,
-    frozen by `policy.trainable_mask`). The layer holds `cfg.experts_held` of the
+    `b` (`expert_bias/bias`, the sigmoid router's only) steers the selection
+    and nothing else: load balancing moves it, the gradient never does
+    (stop_gradient in `route_sigmoid`, frozen by `policy.trainable_mask`). `n`
+    is the layer's input; `x`, what the router reads, is `n` too unless the
+    caller routed elsewhere (`route`, then `__call__(..., routing=)`: `Block`
+    under `cfg.moe_route_on`). The layer holds `cfg.experts_held` of the
     model's `cfg.moe_experts`, scores all of them, and adds up what its own
     experts give (ops/moe.py); nothing stands in for the absent ones.
     A stack holds its experts' matrices side by side, `[fan_in, experts
     held * fan_out]` (expert g is column block g): the kernels read a
     block of it and write a block of its gradient where they lie, so no
     step re-lays a stack. Positions whose mask bit is 0 are dispatched
-    nowhere and get 0."""
+    nowhere and get 0. A layer that holds every expert also counts the
+    experts a call met (`ops.moe.MET_STATS`)."""
 
     cfg: TransformerConfig
 
-    @nn.compact
-    def __call__(self, h, token_mask=None):
+    def setup(self):
+        cfg = self.cfg
+        E, G, d, f = cfg.moe_experts, cfg.experts_held, cfg.d_model, cfg.expert_d_ff
+        self.router = _Kernel((d, E), cfg.param_dtype)
+        if cfg.moe_router == "sigmoid":
+            self.expert_bias = _Bias((E,), cfg.param_dtype)
+        self.expert_gate = _Kernel((d, G * f), cfg.param_dtype)
+        self.expert_up = _Kernel((d, G * f), cfg.param_dtype)
+        self.expert_down = _Kernel((f, G * d), cfg.param_dtype)
+        if cfg.moe_shared_d_ff:
+            dense = lambda feats: nn.Dense(feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype)
+            self.shared_gate, self.shared_up = dense(cfg.moe_shared_d_ff), dense(cfg.moe_shared_d_ff)
+            self.shared_down = dense(d)
+
+    def _router_leaves(self):
+        return self.router(), self.expert_bias() if self.cfg.moe_router == "sigmoid" else None
+
+    def route(self, x):
+        """(top_i, top_w), each [b * t, k], for x [b, t, d]: the routing of a
+        later `__call__(h, token_mask, routing)` over an input of the caller's
+        choosing."""
         from trlx_tpu.ops import moe
 
         cfg = self.cfg
-        E, G, d, f = cfg.moe_experts, cfg.experts_held, cfg.d_model, cfg.expert_d_ff
+        return moe.route(x.reshape(-1, cfg.d_model).astype(cfg.dtype), *self._router_leaves(), cfg.moe_top_k,
+                         cfg.moe_router, cfg.moe_n_group, cfg.moe_topk_group)
+
+    def __call__(self, h, token_mask=None, routing=None):
+        cfg = self.cfg
+        d = cfg.d_model
         b, t, _ = h.shape
-        router = _Kernel((d, E), cfg.param_dtype, name="router")()
-        bias = _Bias((E,), cfg.param_dtype, name="expert_bias")()
-        stack = lambda name, shape: _Kernel(shape, cfg.param_dtype, name=name)().astype(cfg.dtype)
+        router, bias = self._router_leaves()
         flat = h.reshape(b * t, d).astype(cfg.dtype)
-        stacks = stack("expert_gate", (d, G * f)), stack("expert_up", (d, G * f)), stack("expert_down", (f, G * d))
+        stacks = tuple(stack().astype(cfg.dtype) for stack in (self.expert_gate, self.expert_up, self.expert_down))
         flat_mask = None if token_mask is None else token_mask.reshape(b * t)
-        how = dict(top_k=cfg.moe_top_k, offset=cfg.moe_local_offset, act=activation_fn(cfg),
-                   n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group)
+        how = dict(router_kind=cfg.moe_router, top_k=cfg.moe_top_k, offset=cfg.moe_local_offset,
+                   act=activation_fn(cfg), n_group=cfg.moe_n_group, topk_group=cfg.moe_topk_group,
+                   count_met=cfg.experts_held == cfg.moe_experts)
         block = cfg.moe_token_block
         if block and b * t > block:  # `moe_token_block` tokens at a time; the counters are the blocks' mean
-            # one jitted function: blocks of one shape (all but a ragged last, in every layer and
-            # every program of the process) are traced once and lowered once a program
-            parts = [_routed_block(flat[i:i + block], None if flat_mask is None else flat_mask[i:i + block],
-                                   router, bias, *stacks, **how)
+            rows = lambda x, i: None if x is None else x[i:i + block]
+            parts = [_routed_block_jit(flat[i:i + block], rows(flat_mask, i),
+                                       None if routing is None else tuple(rows(r, i) for r in routing),
+                                       router, bias, *stacks, **how)
                      for i in range(0, b * t, block)]
             out = jnp.concatenate([y for y, _ in parts])
             stats = jax.tree_util.tree_map(lambda *xs: sum(xs) / len(xs), *(st for _, st in parts))
         else:
-            out, stats = moe.sparse_moe(flat, router, bias, *stacks, token_mask=flat_mask, **how)
+            out, stats = _routed_block(flat, flat_mask, routing, router, bias, *stacks, **how)
         self.sow("moe_stats", "stats", stats)
         out = out.reshape(b, t, d)
         if cfg.moe_routed_scale != 1.0:
             out = out * cfg.moe_routed_scale
         if cfg.moe_shared_d_ff:
-            dense = lambda feats, name: nn.Dense(
-                feats, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
-            gated = activation_fn(cfg)(dense(cfg.moe_shared_d_ff, "shared_gate")(h)) \
-                * dense(cfg.moe_shared_d_ff, "shared_up")(h)
-            out = out + dense(d, "shared_down")(gated)
+            out = out + self.shared_down(activation_fn(cfg)(self.shared_gate(h)) * self.shared_up(h))
         return out
 
 
@@ -1756,11 +1805,14 @@ def moe_stats_from_state(state) -> Dict[str, jnp.ndarray]:
     if not per_layer:
         return {}
     stack = lambda name: jnp.stack([s[name] for s in per_layer])
-    return {
+    stats = {
         "local_assignment_share": stack("local_assignment_share").mean(),
         "tokens_per_expert_max_over_mean": stack("tokens_per_expert_max_over_mean").max(),
         "dropped_tokens": stack("dropped_tokens").sum(),
     }
+    if all("experts_met" in s for s in per_layer):  # layers that hold every expert: the mean layer's count
+        stats.update(experts_met=stack("experts_met").mean(), experts_held=stack("experts_held").mean())
+    return stats
 
 
 def moe_aux_from_intermediates(state) -> jnp.ndarray:
@@ -1783,6 +1835,12 @@ class Block(nn.Module):
     def __call__(self, h, attn_bias, positions, layer_cache=None, cache_index=None, attn_mask=None,
                  use_prefix=True, attn_kernel=None):
         cfg = self.cfg
+        moe, routing = (SparseMoE(cfg, name="mlp") if self.ffn_kind == "sparse_moe" else None), None
+        if moe is not None and cfg.moe_route_on == "block_input":
+            # the ONE place a router is handed another input than its feed-forward's: the block's
+            # own, un-normed, before the attention (under a name of its own in the device trace)
+            with jax.named_scope("moe_route_block_input"):
+                routing = moe.route(h)
         h_ln = make_norm(cfg, "ln_attn")(h)
         if self.op_kind == "conv":
             attn_out, new_cache = ShortConv(cfg, name="conv")(h_ln, layer_cache, attn_mask, cache_index)
@@ -1809,8 +1867,8 @@ class Block(nn.Module):
                     new_cache = {**new_cache, **slot}
         # the sandwich: a norm on what each operator gives, before its residual add
         post = (lambda name, x: make_norm(cfg, name)(x)) if cfg.sandwich_norm else (lambda name, x: x)
-        if self.ffn_kind == "sparse_moe":
-            mlp = lambda x: SparseMoE(cfg, name="mlp")(x, attn_mask)
+        if moe is not None:
+            mlp = lambda x: moe(x, attn_mask, routing)
         elif self.ffn_kind == "dense" or (self.ffn_kind is None and cfg.moe_experts <= 0):
             mlp = MLP(cfg, name="mlp")
         else:
@@ -2892,6 +2950,32 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         attn_gate="per_head",
         moe_experts=8, moe_top_k=2, moe_d_ff=32, moe_dense_layers=1, moe_router="sigmoid",
         moe_shared_d_ff=32, moe_routed_scale=1.0,
+    ),
+    # SmallThinker-21BA3B-Instruct (PowerInfer, `smallthinker`; 21.5B parameters,
+    # 3.3B active): period of one full-attention layer that rotates nothing (NoPE)
+    # and three sliding ones (window 4,096, rotary over the whole head) over 4
+    # K/V heads of 128 under 28 query heads; in every layer 64 experts of width
+    # 768 (6 a token), gated by ReLU, routed by a softmax over the 6 largest
+    # logits of the block's INPUT (`moe_route_on`), no bias, no shared expert, no
+    # dense layer. The published sizes; a cut in depth arrives as model_extra_configs.
+    "smallthinker-21b-a3b": dict(
+        d_model=2560, n_layers=52, n_heads=28, n_kv_heads=4, head_width=128, d_ff=768, max_seq_len=16384,
+        pos_embed="rope", norm="rmsnorm", layer_norm_epsilon=1e-6, activation="relu", glu=True,
+        tie_embeddings=False, use_bias=False, flash_prefill=True, sliding_window=4096,
+        layer_types=("full_attention", "sliding_attention", "sliding_attention", "sliding_attention") * 13,
+        rope_kinds=(("full_attention", RopeSpec(pct=0.0)), ("sliding_attention", RopeSpec(theta=1500000.0))),
+        moe_experts=64, moe_top_k=6, moe_d_ff=768, moe_router="topk_softmax", moe_route_on="block_input",
+        moe_token_block=4096,
+    ),
+    # the same stack at test size: one period, a group of 3 query heads a K/V
+    # head, a window shorter than the test sequences, 16 experts (3 a token)
+    "smallthinker-tiny": dict(
+        d_model=64, n_layers=4, n_heads=6, n_kv_heads=2, head_width=16, d_ff=32, max_seq_len=256,
+        pos_embed="rope", norm="rmsnorm", layer_norm_epsilon=1e-6, activation="relu", glu=True,
+        tie_embeddings=False, use_bias=False, flash_prefill=True, sliding_window=8,
+        layer_types=("full_attention", "sliding_attention", "sliding_attention", "sliding_attention"),
+        rope_kinds=(("full_attention", RopeSpec(pct=0.0)), ("sliding_attention", RopeSpec(theta=1500000.0))),
+        moe_experts=16, moe_top_k=3, moe_d_ff=32, moe_router="topk_softmax", moe_route_on="block_input",
     ),
     # Mixture-of-experts (beyond the reference): experts shard over `tensor`
     "moe-tiny": dict(

@@ -1,14 +1,20 @@
-"""Sparse experts: sigmoid routing and grouped dispatch over the experts
-held here.
+"""Sparse experts: routing (sigmoid scores with a selection bias, or a
+softmax over the chosen logits) and grouped dispatch over the experts held
+here.
 
 A layer of E experts of which this process holds G (a contiguous range
 from `offset`: one chip's share under expert parallelism, or all of them)
 scores every token against all E, keeps each token's top-k, and computes
 what its own experts add:
 
-    1. route      s = sigmoid(x W_r) in float32; sel = top_k(s + b); the
-                  combine weights are s[sel] renormalized over the k chosen
-                  (the selection bias b steers the choice and nothing else)
+    1. route      `route_sigmoid`: s = sigmoid(x W_r) in float32; sel =
+                  top_k(s + b); the combine weights are s[sel] renormalized
+                  over the k chosen (the selection bias b steers the choice
+                  and nothing else). `route_softmax`: r = x W_r in float32;
+                  sel = top_k(r); the weights are softmax(r[sel]). The input
+                  need not be the one the experts read (`routed_experts`
+                  takes the routing as it takes the tokens: SmallThinker
+                  routes on a block's input and dispatches behind attention)
     2. dispatch   the T x k assignments sorted by expert, those of experts
                   held elsewhere (and of masked tokens) behind the rest; the
                   rows of x gathered in that order. The row count is static,
@@ -39,6 +45,8 @@ import jax.numpy as jnp
 from jax import lax
 
 STATS = ("local_assignment_share", "tokens_per_expert_max_over_mean", "dropped_tokens")
+# beside them where `count_met`: a layer that holds every expert says how many of them a call met
+MET_STATS = ("experts_met", "experts_held")
 _VMEM_LIMIT = 64 * 1024 * 1024
 
 
@@ -47,16 +55,19 @@ _VMEM_LIMIT = 64 * 1024 * 1024
 # ---------------------------------------------------------------------------
 
 
+def _router_logits(x, router_kernel):
+    """x W_r in float32 at `highest` precision: a near-tie between two experts
+    decides which matrices a token meets, so the scores may not carry
+    bfloat16's error."""
+    return jnp.matmul(x.astype(jnp.float32), router_kernel.astype(jnp.float32), precision=lax.Precision.HIGHEST)
+
+
 def route_sigmoid(x, router_kernel, select_bias, top_k: int, n_group: int = 0, topk_group: int = 0):
-    """(top_i [T, k] int32, top_w [T, k] float32). Scores in float32 at
-    `highest` precision: a near-tie between two experts decides which
-    matrices a token meets, so the scores may not carry bfloat16's error.
+    """(top_i [T, k] int32, top_w [T, k] float32), scores in float32.
     With `n_group` groups (DeepSeek-V3's `noaux_tc`): the experts lie in
     equal contiguous groups, a group scores the sum of its two largest biased
     scores, and only the experts of the `topk_group` best groups can be chosen."""
-    logits = jnp.matmul(x.astype(jnp.float32), router_kernel.astype(jnp.float32),
-                        precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.sigmoid(_router_logits(x, router_kernel))
     biased = scores + lax.stop_gradient(select_bias.astype(jnp.float32))
     if n_group:
         tokens, experts = biased.shape
@@ -69,6 +80,21 @@ def route_sigmoid(x, router_kernel, select_bias, top_k: int, n_group: int = 0, t
     top_w = jnp.take_along_axis(scores, top_i, axis=-1)
     top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-6)
     return top_i.astype(jnp.int32), top_w
+
+
+def route_softmax(x, router_kernel, top_k: int):
+    """(top_i [T, k] int32, top_w [T, k] float32): the k largest logits of x
+    W_r and a softmax over those k: the full softmax renormalised over the
+    chosen (the other experts' terms cancel). No bias, no groups, no scale."""
+    top_l, top_i = lax.top_k(_router_logits(x, router_kernel), top_k)
+    return top_i.astype(jnp.int32), jax.nn.softmax(top_l, axis=-1)
+
+
+def route(x, router_kernel, select_bias, top_k: int, kind: str = "sigmoid", n_group: int = 0, topk_group: int = 0):
+    """(top_i, top_w) under the router `kind` (`TransformerConfig.moe_router`)."""
+    if kind == "topk_softmax":
+        return route_softmax(x, router_kernel, top_k)
+    return route_sigmoid(x, router_kernel, select_bias, top_k, n_group, topk_group)
 
 
 # ---------------------------------------------------------------------------
@@ -348,17 +374,16 @@ def dispatch(top_i, n_experts_held: int, offset: int, token_mask=None):
     return order, inverse, held, group_sizes
 
 
-def sparse_moe(x, router_kernel, select_bias, w_gate, w_up, w_down, *, top_k: int, offset: int = 0,
-               act: Callable = jax.nn.silu, token_mask=None, mode: Optional[str] = None,
-               n_group: int = 0, topk_group: int = 0):
-    """The expert layer on flat tokens: x [T, d]; router_kernel [d, E];
-    select_bias [E]; w_gate, w_up [d, G * f] and w_down [f, G * d], the
-    matrices of experts [offset, offset + G) side by side, in the compute
-    type. Returns
-    (y [T, d], stats): y is the part of sum_e w_e W2_e(act(W1_e x) * W3_e
-    x) that the experts held give; `stats` are scalars named in STATS."""
-    tokens, k, held_n = x.shape[0], top_k, w_down.shape[1] // x.shape[1]
-    top_i, top_w = route_sigmoid(x, router_kernel, select_bias, k, n_group, topk_group)
+def routed_experts(x, top_i, top_w, w_gate, w_up, w_down, *, offset: int = 0, act: Callable = jax.nn.silu,
+                   token_mask=None, mode: Optional[str] = None, count_met: bool = False):
+    """What the experts held add for tokens already routed: x [T, d]; top_i,
+    top_w [T, k] (a router's, `route_sigmoid` or `route_softmax`, over
+    whatever input the model routes on); w_gate, w_up [d, G * f] and w_down
+    [f, G * d], the matrices of experts [offset, offset + G) side by side, in
+    the compute type. Returns (y [T, d], stats): y is the part of sum_e w_e
+    W2_e(act(W1_e x) * W3_e x) that the experts held give; `stats` are scalars
+    named in STATS and, with `count_met`, MET_STATS."""
+    tokens, k, held_n = x.shape[0], top_i.shape[1], w_down.shape[1] // x.shape[1]
     order, inverse, held, group_sizes = dispatch(top_i, held_n, offset, token_mask)
 
     rows = _gather_sorted(x, order, inverse, k)  # [T * k, d]
@@ -377,4 +402,7 @@ def sparse_moe(x, router_kernel, select_bias, w_gate, w_up, w_down, *, top_k: in
         # the static row count is T x k, every assignment there is: none can be left out
         "dropped_tokens": jnp.maximum(held.sum() - order.shape[0], 0).astype(jnp.float32),
     }
+    if count_met:  # the held experts with at least one row: what a call reads of the stacks
+        stats["experts_met"] = (group_sizes > 0).sum().astype(jnp.float32)
+        stats["experts_held"] = jnp.float32(held_n)
     return y, stats

@@ -36,6 +36,7 @@ import argparse
 import functools
 import glob
 import importlib.metadata
+import itertools
 import json
 import math
 import shutil
@@ -412,6 +413,7 @@ def kernel_phase(args, trainer):
     from trlx_tpu.models import config_from_preset
     from trlx_tpu.ops import quant
     from trlx_tpu.ops.paged_attention import (
+        copies_blocks,
         paged_attention_decode,
         paged_attention_reference,
     )
@@ -424,15 +426,20 @@ def kernel_phase(args, trainer):
     flash_and_ce_parity(interpret=args.rehearse_cpu)
     flash_backward_parity(interpret=args.rehearse_cpu)
 
-    # paged decode vs the gather reference: GPT-2 small's shape (group 1)
-    # and the one causal preset family with group > 1, bf16 and int8 arenas.
-    # Ragged rows over a table of three tiles: a single token, block edges,
-    # the whole table, a mask with holes, an all-masked row. Table slack
-    # past a row's live entries names ids beyond the arena, and every block
-    # no live entry names (the zero block too) is poison in the kernel's
-    # copy of the arena: a dead entry that was fetched would show as NaN.
-    shapes = {name: config_from_preset(name, vocab_size=50257)
-              for name in ("gpt2-small", "llama-tiny")}
+    # paged decode vs the gather reference: GPT-2 small's shape (group 1),
+    # the one causal preset family with group > 1, and the transcript cell's
+    # 4 K/V heads of 128 under groups of 7 (whose blocks the kernel copies
+    # itself: `copies_blocks`), there with a band of 100 as well; bf16 and
+    # int8 arenas. Ragged rows over a table of three tiles (two of sixteen
+    # entries): a single token, block edges, the whole table, a mask with
+    # holes, an all-masked row. Table slack past a row's live entries names
+    # ids beyond the arena, and every block no live entry names (the zero
+    # block too, and under a band the blocks in front of it) is poison in
+    # the kernel's copy of the arena: a dead entry that was fetched, or its
+    # place in the kernel's scratch that no copy wrote, would show as NaN.
+    shapes = {name: (cfg.n_heads, cfg.kv_heads, cfg.head_dim, (None,)) for name, cfg in (
+        (name, config_from_preset(name, vocab_size=50257)) for name in ("gpt2-small", "llama-tiny"))}
+    shapes["4 x 7 x 128"] = (28, 4, 128, (None, 100))
     rng = np.random.default_rng(1)
     b, blk, n_tbl = 8, 32, 20
     n_blocks = b * n_tbl + 1
@@ -444,16 +451,14 @@ def kernel_phase(args, trainer):
     table = np.full((b, n_tbl), n_blocks + 3, np.int32)
     for r in range(b):
         table[r, :n_live[r]] = ids[r * n_tbl:r * n_tbl + n_live[r]]
-    dead = np.setdiff1d(np.arange(n_blocks), table[table < n_blocks])
     table_ref = jnp.asarray(np.where(table < n_blocks, table, 0))
-    table, mask = jnp.asarray(table), jnp.asarray(mask)
-    for name, cfg in shapes.items():
-        nh, nkv, hd = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    for (name, (nh, nkv, hd, windows)), dtype in itertools.product(shapes.items(), ("bf16", "int8")):
         q = jnp.asarray(rng.standard_normal((b, nh, hd)), jnp.bfloat16)
-        ka = jnp.asarray(rng.standard_normal((n_blocks, nkv, blk, hd)), jnp.bfloat16)
-        va = jnp.asarray(rng.standard_normal((n_blocks, nkv, blk, hd)), jnp.bfloat16)
-        ka, va = ka.at[0].set(0), va.at[0].set(0)
-        for dtype in ("bf16", "int8"):
+        ka = jnp.asarray(rng.standard_normal((n_blocks, nkv, blk, hd)), jnp.bfloat16).at[0].set(0)
+        va = jnp.asarray(rng.standard_normal((n_blocks, nkv, blk, hd)), jnp.bfloat16).at[0].set(0)
+        for window in windows:
+            front = np.maximum(lens - (window or n_tbl * blk), 0) // blk  # entries wholly in front of the band
+            dead = np.setdiff1d(np.arange(n_blocks), np.concatenate([table[r, front[r]:n_live[r]] for r in range(b)]))
             kw, kw_poison = {}, {}
             k_in, v_in = ka, va
             k_poison, v_poison = ka.at[dead].set(jnp.nan), va.at[dead].set(jnp.nan)
@@ -465,18 +470,19 @@ def kernel_phase(args, trainer):
                 k_poison, v_poison = k_in.at[dead].set(127), v_in.at[dead].set(127)
                 kw_poison = {key: plane.at[dead].set(jnp.nan) for key, plane in kw.items()}
             out = jax.jit(lambda *a, kw=kw_poison: paged_attention_decode(
-                *a, interpret=args.rehearse_cpu, **kw))(q, k_poison, v_poison, table, mask)
+                *a, interpret=args.rehearse_cpu, window=window, **kw))(q, k_poison, v_poison, jnp.asarray(table), mask)
             ref = jax.jit(lambda *a, kw=kw: paged_attention_reference(
-                *a, **kw))(q, k_in, v_in, table_ref, mask)
+                *a, window=window, **kw))(q, k_in, v_in, table_ref, mask)
             out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
-            check(bool(np.isfinite(out).all()),
-                  f"paged {name}/{dtype}: non-finite output (a dead table entry was read)")
-            check(bool((out[lens == 0] == 0.0).all()), f"paged {name}/{dtype}: all-masked row not 0.0")
+            what = f"paged {name}/{dtype}" + (f"/window {window}" if window else "")
+            check(bool(np.isfinite(out).all()), f"{what}: non-finite output (a dead table entry was read)")
+            check(bool((out[lens == 0] == 0.0).all()), f"{what}: all-masked row not 0.0")
             dev = float(np.abs(out - ref)[lens > 0].max())
-            log(f"kernels: paged decode {name} (heads {nh}/{nkv} x {hd}, group "
-                f"{nh // nkv}) {dtype}, {int(n_live.sum())} live of {b * n_tbl} table entries: "
-                f"max|dev| {dev:.2e} (bound {paged_tol:.0e})")
-            check(dev < paged_tol, f"paged {name}/{dtype} parity {dev} >= {paged_tol}")
+            copied = copies_blocks(nkv, blk, hd, k_in.dtype)
+            log(f"kernels: paged decode {name} (heads {nh}/{nkv} x {hd}, group {nh // nkv}"
+                f"{f', window {window}' if window else ''}) {dtype}, blocks {'copied' if copied else 'operands'}, "
+                f"{len(dead)} of {n_blocks} blocks poison: max|dev| {dev:.2e} (bound {paged_tol:.0e})")
+            check(dev < paged_tol, f"{what} parity {dev} >= {paged_tol}")
 
 
 def main():

@@ -136,36 +136,50 @@ def test_paged_decode_compiles_without_copying_the_arena(v5e, heads, blk, dtype)
         assert arena_rewrites(compiled, arena, *args[5:]) == []
 
 
-# the cell's own call, and the open chat cell's, 44 table entries a slot
-# at `max_prompt_len` 1024 (PERF.md section 7)
-@pytest.mark.parametrize("n_tbl", [CELL["n_tbl"], 44], ids=["cell", "chat"])
-def test_paged_decode_fits_its_vmem_budget_at_the_cell_and_chat_shapes(v5e, n_tbl, monkeypatch):
-    """64 rows over 1,280 blocks of 32, 16 heads of 128, bfloat16: one
-    Mosaic call named `paged_decode`, the arena read where it lies, tiles
-    of 8 entries, and VMEM inside the budget the module docstring states.
-    The call carries `vmem_limit_bytes` and Mosaic refuses a kernel that
-    needs more: the cell's 4 MiB of tile buffers and the rest fit half the
-    12 MiB it is compiled under, and a quarter is refused, which shows the
-    limit is enforced and not merely stated."""
-    n_blocks, nkv, blk, hd, b = (CELL[k] for k in ("n_blocks", "nkv", "blk", "hd", "slots"))
+# the cell's own call, the open chat cell's, 44 table entries a slot at
+# `max_prompt_len` 1024 (PERF.md section 7), and the transcript cell's: 32
+# rows of 28 query heads over 4 K/V heads, 480 table entries, full and banded
+TRANSCRIPT = dict(n_blocks=10240, nkv=4, group=7, slots=32, n_tbl=480)
+FETCH_SHAPES = {
+    "cell": (dict(CELL, group=1), None, 8),
+    "chat": (dict(CELL, group=1, n_tbl=44), None, 8),
+    "transcript": (TRANSCRIPT, None, 2),
+    "transcript-window": (TRANSCRIPT, 4096, 2),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(FETCH_SHAPES))
+def test_paged_decode_fits_its_vmem_budget_at_the_cells_shapes(v5e, shape, monkeypatch):
+    """Blocks of 32 x 128 bfloat16 a K/V head: one Mosaic call named
+    `paged_decode` (`paged_decode_window` with a band), the arenas read where
+    they lie (they enter in `pl.ANY` and the kernel copies a tile's blocks
+    itself: no copy of an arena in front of the call), tiles of 16 entries,
+    and VMEM inside the budget the module docstring states. The call carries
+    `vmem_limit_bytes` and Mosaic refuses a kernel that needs more: the
+    scratch (2 buffers x 2 sides x 16 blocks: 8 MiB at 16 K/V heads, 2 MiB
+    at 4) and the rest fit the 12 MiB it is compiled under, and a limit the
+    scratch alone does not fit is refused, which shows the limit is enforced
+    and not merely stated."""
+    c, window, scratch_mib = FETCH_SHAPES[shape]
+    n_blocks, nkv, n_tbl, b, blk, hd = *(c[k] for k in ("n_blocks", "nkv", "n_tbl", "slots")), 32, 128
     one = SingleDeviceSharding(v5e[0])
     arena = S((n_blocks, nkv, blk, hd), BF16)
-    args = (S((b, nkv, hd), BF16), arena, arena, S((b, n_tbl), I32), S((b, n_tbl * blk), I32))
-    assert paged_attention._tile_entries(n_tbl, nkv, blk, hd, BF16) == 8
+    args = (S((b, nkv * c["group"], hd), BF16), arena, arena, S((b, n_tbl), I32), S((b, n_tbl * blk), I32))
+    assert paged_attention.copies_blocks(nkv, blk, hd, BF16)
+    assert paged_attention._tile_entries(n_tbl, nkv, blk, hd, BF16) == 16
     assert paged_attention._VMEM_LIMIT_BYTES == 12 * 2 ** 20
-    assert 4 * 8 * paged_attention._vmem_block_bytes(nkv, blk, hd, BF16) == 4 * 2 ** 20
+    assert 4 * 16 * paged_attention._vmem_block_bytes(nkv, blk, hd, BF16) == scratch_mib * 2 ** 20
 
     def compile_under(limit):
         monkeypatch.setattr(paged_attention, "_VMEM_LIMIT_BYTES", limit)
         # a fresh function object: jit's trace cache would hand back the last limit's program
-        return compile_for(lambda *a: paged_attention_decode(*a), args, one)
+        return compile_for(lambda *a: paged_attention_decode(*a, window=window), args, one)
 
     compiled = compile_under(12 * 2 ** 20)
-    assert kernel_names(compiled) == ["paged_decode"]
+    assert kernel_names(compiled) == ["paged_decode" if window is None else "paged_decode_window"]
     assert arena_rewrites(compiled, arena) == []
-    assert mosaic_calls(compile_under(6 * 2 ** 20)) == 1
     with pytest.raises(Exception, match="vmem"):
-        compile_under(3 * 2 ** 20)
+        compile_under(scratch_mib * 2 ** 20)
 
 
 @pytest.mark.parametrize("dtype", [BF16, I8], ids=["bf16", "int8"])

@@ -300,10 +300,15 @@ def test_engine_gather_path_and_int8_arena_follow_the_window():
 # differ, the paged kernel's body (interpreted) included. The insert's was
 # recorded again at PR 50, whose point is that program's text: it unembeds each
 # row's last live position (`decode_step`'s `head_at`) where it unembedded all
-# (19f396a3...385 until then); the forward and the decode step stand as recorded.
+# (19f396a3...385 until then). The decode step's was recorded again at PR 56,
+# whose point is the paged kernel's body: a row's first tile is told by the
+# row's change and no longer by a fifth scalar operand, and an int8 arena's
+# value scales are selected by the mask (5ac5d745...ffa until then; neox-tiny's
+# heads of 16 keep every block an operand, `copies_blocks`); the forward stands
+# as recorded.
 LOWERED_BEFORE = {
     "forward": "803f839d0b7e6abadd156f8b2cb8bd16c9d3034a7f14eecc5b4922349f28de6d",
-    "decode": "5ac5d745641b218d2498b9ec139d2af255b2e4670b95c59e5dc818d2cdf47ffa",
+    "decode": "4c97953ac380e00eda34a80836d64ca20b7202876519aee57c3df1400f402f14",
     "insert": "6503e6edc5c8f4b5bae8b96cd693b1d431f0b7431c99f5d28a572f5bb6974d3f",
 }
 

@@ -15,6 +15,7 @@ from trlx_tpu.inference import InferenceEngine
 from trlx_tpu.ops import quant
 from trlx_tpu.ops.attention import kernel_mode
 from trlx_tpu.ops.paged_attention import (
+    copies_blocks,
     _live_schedule,
     _tile_entries,
     paged_attention_decode,
@@ -260,14 +261,14 @@ def test_kernel_requires_scales_for_int8():
 N_TBL_RAGGED = 11  # a tile is 8 entries: the second tile is partial
 
 
-def _ragged_case(rng, group, dtype, blk, nkv=2, hd=16, n_blocks=64):
+def _ragged_case(rng, group, dtype, blk, nkv=2, hd=16, n_blocks=64, n_tbl=N_TBL_RAGGED, more=()):
     """One batch with every kind of row: a single token, exactly one
     block, one past a block boundary, the whole table, all-masked, live
     entries that are no multiple of the tile, and a mask with a hole
-    inside a live entry. Every live entry names a block of its own; table
-    slack past a row's live entries names an id >= n_blocks."""
-    n_tbl = N_TBL_RAGGED
-    lens = np.array([1, blk, blk + 1, n_tbl * blk, 0, 9 * blk + 3, 3 * blk - 2])
+    inside a live entry (then rows of `more` positions). Every live entry
+    names a block of its own; table slack past a row's live entries names
+    an id >= n_blocks."""
+    lens = np.array([1, blk, blk + 1, n_tbl * blk, 0, 9 * blk + 3, 3 * blk - 2, *more])
     b, nh = len(lens), nkv * group
     qdtype = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     q = jnp.asarray(rng.randn(b, nh, hd), qdtype)
@@ -311,16 +312,59 @@ def test_kernel_matches_reference_on_ragged_rows(group, dtype, blk):
     assert (out_k[~active] == 0.0).all()
 
 
+# Where a block is whole (sublane, lane) tiles the kernel copies a tile's
+# blocks itself (`copies_blocks`): 4 K/V heads of 128 in blocks of 32, the
+# transcript cell's group of 7 and the chat cell's of 5, a table of 19 entries
+# over tiles of 16, and a row one position past a tile's edge.
+COPIED = dict(blk=32, nkv=4, hd=128, n_tbl=19, more=(16 * 32 + 1,), n_blocks=128)
+
+
+def _interpreter(name):
+    """`interpret=True`, or the TPU interpreter with every scratch a NaN until
+    something writes it: the place of a dead entry in the kernel's scratch is
+    written by no copy."""
+    if name == "interpret":
+        return True
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.InterpretParams(uninitialized_memory="nan")
+
+
+COPIED_CASES = [(7, "bfloat16", "interpret"), (7, "bfloat16", "nan"), (5, "bfloat16", "interpret"),
+                (7, "int8", "nan"), (5, "int8", "interpret"), (5, "float32", "nan"), (7, "float32", "interpret")]
+
+
+@pytest.mark.parametrize("group,dtype,interpreter", COPIED_CASES, ids=lambda v: str(v))
+def test_the_copying_kernel_matches_reference_on_ragged_rows(group, dtype, interpreter):
+    rng = np.random.RandomState(COPIED_CASES.index((group, dtype, interpreter)))
+    q, ka, va, table, mask, scales, n_live = _ragged_case(rng, group, dtype, **COPIED)
+    assert copies_blocks(4, 32, 128, ka.dtype) and _tile_entries(19, 4, 32, 128, ka.dtype) == 16
+    out_k = np.asarray(paged_attention_decode(
+        q, ka, va, table, mask, interpret=_interpreter(interpreter), **scales).astype(jnp.float32))
+    out_r = np.asarray(paged_attention_reference(
+        q, ka, va, jnp.where(table < ka.shape[0], table, 0), mask, **scales).astype(jnp.float32))
+    active = n_live > 0
+    tol = 2e-2 if dtype == "bfloat16" else 1e-5
+    np.testing.assert_allclose(out_k[active], out_r[active], rtol=tol, atol=tol)
+    assert (out_k[~active] == 0.0).all()
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int8"])
-def test_table_slack_is_never_dereferenced(dtype):
+@pytest.mark.parametrize("form", ["operands", "copied-interpret", "copied-nan"])
+def test_table_slack_is_never_dereferenced(dtype, form):
     """Everything a live entry does not name is poison: the zero block,
     the blocks no row holds, the scale planes' rows of both, and table
     slack names ids past the arena (the interpreter would clamp such an
     id onto the last block, poisoned too). The output must not change by
-    a bit: a fetched dead entry would put 0 x NaN into p.v."""
+    a bit: a fetched dead entry would put 0 x NaN into p.v, and so would
+    the place of a dead entry in the copying kernel's scratch."""
     rng = np.random.RandomState(7)
-    q, ka, va, table, mask, scales, n_live = _ragged_case(rng, 2, dtype, 16)
-    clean = paged_attention_decode(q, ka, va, table, mask, interpret=True, **scales)
+    shape, _, interpreter = form.partition("-")
+    q, ka, va, table, mask, scales, n_live = _ragged_case(
+        rng, 2, dtype, 16) if shape == "operands" else _ragged_case(rng, 7, dtype, **COPIED)
+    assert copies_blocks(*ka.shape[1:], ka.dtype) == (shape == "copied")
+    interpret = _interpreter(interpreter or "interpret")
+    clean = paged_attention_decode(q, ka, va, table, mask, interpret=interpret, **scales)
     live_ids = np.concatenate([np.asarray(table)[r, :n] for r, n in enumerate(n_live)])
     dead = np.setdiff1d(np.arange(ka.shape[0]), live_ids)
     assert 0 in dead and ka.shape[0] - 1 in dead
@@ -329,7 +373,7 @@ def test_table_slack_is_never_dereferenced(dtype):
         scales = {k: v.at[dead].set(jnp.nan) for k, v in scales.items()}
     else:
         poison = lambda a: a.at[dead].set(jnp.nan)  # noqa: E731
-    got = paged_attention_decode(q, poison(ka), poison(va), table, mask, interpret=True, **scales)
+    got = paged_attention_decode(q, poison(ka), poison(va), table, mask, interpret=interpret, **scales)
     assert bool(jnp.isfinite(got.astype(jnp.float32)).all())
     np.testing.assert_array_equal(_bits(got), _bits(clean))
 
@@ -363,16 +407,34 @@ def test_live_schedule_walks_live_entries_only(entries):
 
 
 def test_tile_follows_the_shapes():
-    """Tile entries adapt to block size, table length and VMEM: 256
-    tokens a step where the table and eight operands a side allow it."""
+    """Tile entries adapt to block size, table length and VMEM: 512 tokens
+    and at most sixteen entries a step where the kernel copies its blocks,
+    256 tokens and at most eight operands a side where they are operands."""
     bf16, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
-    assert _tile_entries(20, 16, 32, 128, bf16) == 8   # the benchmark's cell
-    assert _tile_entries(44, 16, 32, 128, bf16) == 8   # the chat shape
-    assert _tile_entries(20, 16, 16, 128, bf16) == 8   # eight operands a side at most
+    assert _tile_entries(20, 16, 32, 128, bf16) == 16  # the benchmark's cell: 8 MiB of tiles exactly
+    assert _tile_entries(44, 16, 32, 128, bf16) == 16  # the chat shape
+    assert _tile_entries(20, 16, 16, 128, bf16) == 16  # sixteen entries at most
     assert _tile_entries(3, 2, 16, 64, bf16) == 3      # never past the table
-    assert _tile_entries(20, 8, 32, 128, i8) == 8      # the 7B GQA shape, int8
-    assert _tile_entries(20, 16, 32, 128, f32) == 8    # 8 MiB of tiles exactly
+    assert _tile_entries(20, 8, 32, 128, i8) == 16     # the 7B GQA shape, int8
+    assert _tile_entries(20, 16, 32, 128, f32) == 8    # shrunk to 8 MiB of tiles
     assert _tile_entries(20, 32, 32, 128, f32) == 4    # shrunk to fit VMEM
+    assert _tile_entries(480, 4, 32, 128, bf16) == 16  # the transcript cell: 2 MiB of tiles
+    assert _tile_entries(20, 12, 32, 64, bf16) == 8    # heads of 64: operands, eight a side
+    assert _tile_entries(11, 2, 8, 16, bf16) == 8
+
+
+def test_the_kernelcopies_blocks_that_are_whole_tiles():
+    """Mosaic slices one block out of an arena by whole (sublane, lane) tiles
+    of its last two dims (the 128 lanes; 8 rows of float32, 16 of bfloat16, 32
+    of int8), and an int8 arena's scale planes by rows of whole lanes: there
+    the kernel copies, elsewhere each block is an operand."""
+    bf16, f32, i8 = jnp.bfloat16, jnp.float32, jnp.int8
+    assert copies_blocks(16, 32, 128, bf16) and copies_blocks(4, 32, 128, bf16) and copies_blocks(8, 32, 128, i8)
+    assert copies_blocks(4, 16, 128, bf16) and copies_blocks(4, 8, 256, f32)
+    assert not copies_blocks(12, 32, 64, bf16)   # heads of 64
+    assert not copies_blocks(4, 8, 128, bf16)    # half a bfloat16 tile's rows
+    assert not copies_blocks(4, 16, 128, i8)     # half an int8 tile's rows
+    assert not copies_blocks(2, 32, 128, i8)     # a scale plane's row of 64 lanes
 
 
 # ----------------------------------------------------------------------
@@ -473,6 +535,11 @@ def test_live_entry_share_counts_the_columns_held(trainers, monkeypatch):
     eng.step()
     assert seen.count(("engine.dispatch", {})) == 3
     assert [name for name, _ in counted] == ["engine.kv_walk", "engine.queued", "engine.fetched"]
+    # which of the kernel's two fetch forms the step's K/V calls take: heads of 16 are no whole lane tile
+    layers = eng.model_cfg.n_layers
+    assert (counted[0][1]["calls_copied"], counted[0][1]["calls_operands"]) == (0, layers)
+    monkeypatch.setattr("trlx_tpu.ops.paged_attention.copies_blocks", lambda *shape: True)
+    assert (eng._kv_walk()["calls_copied"], eng._kv_walk()["calls_operands"]) == (layers, 0)
     assert counted[1][1] == {"seq": 3, "ahead": 1, "rows": 1} and counted[2][1] == {"seq": 2}
     stats = eng.kv_stats()
     assert (stats["decode_steps_total"], stats["decode_steps_ahead_total"],

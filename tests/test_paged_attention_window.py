@@ -4,7 +4,9 @@
 columns and the kernel walks only the table entries that hold one of them.
 Windows smaller than, equal to and no multiple of the block; rows with fewer
 positions than the window, a single one, none; groups of 3, 4 and 6 query
-heads a K/V head; bfloat16 and int8 arenas."""
+heads a K/V head; bfloat16 and int8 arenas. Then the same where the kernel
+copies a tile's blocks itself (blocks of whole tiles: 4 K/V heads of 128,
+groups of 7 and 5), under both interpreters."""
 
 import numpy as np
 import pytest
@@ -13,7 +15,9 @@ import jax.numpy as jnp
 
 from trlx_tpu.ops import quant
 from trlx_tpu.ops.paged_attention import (
+    copies_blocks,
     _live_schedule,
+    _live_walk,
     _tile_entries,
     band_mask,
     paged_attention_decode,
@@ -24,16 +28,16 @@ BLK, N_TBL, NKV, HD, N_BLOCKS = 8, 11, 2, 16, 64  # a tile is 8 entries: the sec
 LENS = np.array([1, 5, BLK, BLK + 1, 40, N_TBL * BLK, 0, 63])
 
 
-def case(rng, group, dtype):
+def case(rng, group, dtype, lens=LENS, blk=BLK, n_tbl=N_TBL, nkv=NKV, hd=HD):
     """Rows of every length (above), every live entry a block of its own,
     table slack past a row's live entries an id >= n_blocks."""
-    b, nh = len(LENS), NKV * group
-    q = jnp.asarray(rng.randn(b, nh, HD), jnp.float32 if dtype == "float32" else jnp.bfloat16)
-    arenas = [jnp.asarray(rng.randn(N_BLOCKS, NKV, BLK, HD), jnp.float32) for _ in range(2)]
-    mask = (np.arange(N_TBL * BLK)[None, :] < LENS[:, None]).astype(np.int32)
-    n_live = -(-LENS // BLK)
+    b, nh = len(lens), nkv * group
+    q = jnp.asarray(rng.randn(b, nh, hd), jnp.float32 if dtype == "float32" else jnp.bfloat16)
+    arenas = [jnp.asarray(rng.randn(N_BLOCKS, nkv, blk, hd), jnp.float32) for _ in range(2)]
+    mask = (np.arange(n_tbl * blk)[None, :] < lens[:, None]).astype(np.int32)
+    n_live = -(-lens // blk)
     ids = 1 + rng.permutation(N_BLOCKS - 1)
-    table = np.full((b, N_TBL), N_BLOCKS, np.int32) + rng.randint(0, 5, (b, N_TBL))
+    table = np.full((b, n_tbl), N_BLOCKS, np.int32) + rng.randint(0, 5, (b, n_tbl))
     at = 0
     for r in range(b):
         table[r, :n_live[r]] = ids[at:at + n_live[r]]
@@ -118,3 +122,59 @@ def test_windowed_schedule_walks_the_band_and_nothing_else(window):
     got = paged_attention_decode(q, poison(ka), poison(va), table, mask, interpret=True, window=window)
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_array_equal(np.asarray(got).view(np.uint32), np.asarray(clean).view(np.uint32))
+
+
+# The copying kernel (`copies_blocks`): 4 K/V heads of 128 in blocks of 32, a
+# table of 19 entries over tiles of 16. Rows of 1 token, one block, one past a
+# block's and one past a tile's edge, the whole table, none; bands inside one
+# block, that start inside the first tile and inside the second.
+COPIED = dict(blk=32, n_tbl=19, nkv=4, hd=128)
+COPIED_LENS = np.array([1, 32, 33, 16 * 32 + 1, 19 * 32, 0, 17 * 32 + 5])
+COPIED_CASES = [(w, g, d, i) for (w, g, d), i in zip(
+    [(5, 7, "bfloat16"), (100, 7, "bfloat16"), (100, 5, "int8"), (32, 5, "bfloat16"), (530, 7, "int8"), (100, 5, "float32")],
+    ["nan", "interpret", "nan", "interpret", "interpret", "nan"])]
+
+
+@pytest.mark.parametrize("window,group,dtype,interpreter", COPIED_CASES, ids=lambda v: str(v))
+def test_the_copying_kernel_walks_the_band_and_nothing_else(window, group, dtype, interpreter):
+    """Against the reference, and then with poison in every block outside
+    the rows' bands (those in front of a band among them) and, under the TPU
+    interpreter, NaN in every place of the kernel's scratch no copy wrote:
+    not a bit of the output moves."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    interpret = True if interpreter == "interpret" else pltpu.InterpretParams(uninitialized_memory="nan")
+    rng = np.random.RandomState(COPIED_CASES.index((window, group, dtype, interpreter)))
+    q, ka, va, table, mask, scales = case(rng, group, dtype, COPIED_LENS, **COPIED)
+    assert copies_blocks(4, 32, 128, ka.dtype) and _tile_entries(19, 4, 32, 128, ka.dtype) == 16
+    clean = paged_attention_decode(q, ka, va, table, mask, interpret=interpret, window=window, **scales)
+    want = paged_attention_reference(
+        q, ka, va, jnp.where(table < N_BLOCKS, table, 0), mask, window=window, **scales)
+    active = COPIED_LENS > 0
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    got = np.asarray(clean.astype(jnp.float32))
+    np.testing.assert_allclose(got[active], np.asarray(want.astype(jnp.float32))[active], rtol=tol, atol=tol)
+    assert (got[~active] == 0.0).all()
+
+    last = -(-COPIED_LENS // 32)
+    front = np.maximum(COPIED_LENS - window, 0) // 32  # entries wholly in front of the band
+    walk = jax.jit(_live_walk, static_argnums=(2, 3, 4))(table, band_mask(mask, window), 32, 16, True)
+    np.testing.assert_array_equal(walk.n_live, last)
+    np.testing.assert_array_equal(walk.n_first, front)
+    steps = np.maximum(1, -(-last // 16) - front // 16)
+    n_work = int(walk.n_work)
+    assert n_work == steps.sum() and walk.n_tiles == 2
+    np.testing.assert_array_equal(np.asarray(walk.row)[:n_work], np.repeat(np.arange(len(last)), steps))
+    np.testing.assert_array_equal(
+        np.asarray(walk.tile)[:n_work], np.concatenate([f // 16 + np.arange(n) for f, n in zip(front, steps)]))
+
+    in_band = np.concatenate([np.asarray(table)[r, front[r]:last[r]] for r in range(len(last))])
+    dead = np.setdiff1d(np.arange(N_BLOCKS), in_band)
+    if dtype == "int8":
+        poison = lambda a: a.at[dead].set(127)  # noqa: E731
+        scales = {k: v.at[dead].set(jnp.nan) for k, v in scales.items()}
+    else:
+        poison = lambda a: a.at[dead].set(jnp.nan)  # noqa: E731
+    poisoned = paged_attention_decode(q, poison(ka), poison(va), table, mask, interpret=interpret, window=window, **scales)
+    assert bool(jnp.isfinite(poisoned.astype(jnp.float32)).all())
+    np.testing.assert_array_equal(np.asarray(poisoned.astype(jnp.float32)), got)

@@ -1689,8 +1689,14 @@ class InferenceEngine:
         the arena, positions x what a position holds in the plane read,
         `bytes_full` the part of it in the layers that choose (their index
         keys and chosen latents) and `bytes_dense_full` what those layers
-        would read were every attendable latent read; `layers`. From the
-        host's own count of each slot's columns; the step adds its one."""
+        would read were every attendable latent read; `layers`; and, of the
+        K/V layers' calls of the paged kernel, `calls_copied` (the kernel
+        copies a tile's blocks itself) and `calls_operands` (each block an
+        operand the pipeline fetches), which the arena's shapes decide
+        (`ops.paged_attention.copies_blocks`). From the host's own count of
+        each slot's columns; the step adds its one."""
+        from trlx_tpu.ops.paged_attention import copies_blocks
+
         cfg, blk = self.model_cfg, self.kv_block_size
         cols = self._next_columns()
         kinds = [cfg.layer_op(i) for i in range(cfg.n_layers) if self._layer_keeps[i].token]
@@ -1704,14 +1710,17 @@ class InferenceEngine:
             return int(((last - first) * blk).sum())
 
         walk = dict.fromkeys(("walked_full", "walked_window", "walked_latent", "index_scored", "index_attendable",
-                              "index_chosen", "bytes", "bytes_full", "bytes_dense_full"), 0)
+                              "index_chosen", "bytes", "bytes_full", "bytes_dense_full", "calls_copied",
+                              "calls_operands"), 0)
         walk.update(resident=int(cols.sum()) * len(kinds), layers=len(kinds))
         itemsize = jnp.dtype(self.kv_cache_dtype).itemsize
+        form = "calls_copied" if copies_blocks(cfg.kv_heads, blk, cfg.head_dim, self.kv_cache_dtype) else "calls_operands"
         for kind in kinds:
             latent, n = cfg.latent_of(kind), walked(cfg.window_of(kind))
             if latent is None:
                 walk["walked_full" if cfg.window_of(kind) is None else "walked_window"] += n
                 walk["bytes"] += itemsize * n * 2 * cfg.kv_heads * cfg.head_dim
+                walk[form] += int(kernels)
             elif not latent.index_topk:
                 walk["walked_latent"] += n
                 walk["bytes"] += itemsize * n * latent.width

@@ -8,10 +8,10 @@ before the dense contraction (which keeps K/V at n_kv_heads under GQA).
 The kernel collapses the read side into one Pallas call whose time
 follows the tokens resident, not the table's length:
 
-* the physical blocks a call fetches ride **scalar prefetch**: each K/V
-  operand's BlockSpec index map reads its block id there and the DMA
-  engine fetches the arena block directly (all kv heads of the block in
-  one contiguous transfer) — no gathered dense copy in HBM;
+* the physical blocks a call fetches ride **scalar prefetch**: the kernel
+  reads a block's id there and the DMA engine fetches the arena block
+  directly (all kv heads of the block in one contiguous transfer) — no
+  gathered dense copy in HBM;
 * int8 arenas are dequantized **in registers**: the per-token f32 scales
   multiply the [nkv, group, T] score / probability tiles, which is the
   `ops.quant.dequantize_kv` product re-associated — no dequantized copy;
@@ -22,10 +22,10 @@ follows the tokens resident, not the table's length:
   tile, so GQA divides KV bytes per step by the group factor.
 
 Grid and tile. A grid step is one **tile** of one row: `E` consecutive
-table entries (`_tile_entries`: 256 tokens' worth, at most 8, never past
-the table: 8 entries of 32 for the benchmark's cell), each entry's
-[nkv, blk, hd] block of K and of V an operand of its own, so a tile is E
-block DMAs a side and the fixed cost of a step is paid once per 256
+table entries (`_tile_entries`: 512 tokens' worth, at most 16, never past
+the table: 16 entries of 32 in every cell of the benchmark), each entry's
+[nkv, blk, hd] block of K and of V one transfer of its own, so a tile is E
+block DMAs a side and the fixed cost of a step is paid once per 512
 tokens. In the kernel the E blocks are laid side by side as one
 [nkv, T = E*blk, hd] tile (an aligned concatenation: no copy) and every
 kv head is contracted at once: scores are one `dot_general` batched over
@@ -38,29 +38,59 @@ a bfloat16 or int8 arena, as the gather path rounds them). The same form
 serves every group size: for group == 1 a multiply-and-reduce on the VPU
 was measured beside it and took 1.8-2.0 times as long (PERF.md section 6,
 PR 30). Running max and denominator are [nkv, group, 1] float32 state
-written once per tile. VMEM: the tile's buffers are 2 sides x E entries x
-2 pipeline buffers x one block (the cell: 32 x 128 KiB = 4 MiB; the tile
-shrinks until they fit `_TILE_VMEM_BYTES` = 8 MiB), beside them q, mask,
-output, the scale planes' rows and the [nkv, group, T] scores; the call
-is compiled under `vmem_limit_bytes` = 12 MiB (`_VMEM_LIMIT_BYTES`; the
-v5e's default scoped limit is 16), which Mosaic enforces and
-tests/test_kernels_compile_tpu.py holds for the cell's and the chat
-shape.
+written once per tile.
 
-Schedule (`_live_schedule`, a few small XLA operations on the table and
-the mask, the same for every layer of a step). `n_live[i]` is row i's
-live table entries: (its last attendable column + 1) rounded up to
-blocks, 0 for an all-masked row. The grid is ONE dimension over the
-tiles that hold a live entry, rows in order (an all-masked row gets one
-step, which writes its zeros), and its length `n_work` is read at run
-time: lengths are data, not shapes, so one decode program serves every
-mix. A table entry j >= n_live[i] is neither fetched nor multiplied: in
-a row's last tile, an operand whose entry is not live names the block it
-held the step before, and a repeated block index is not fetched again;
-so table slack (the zero block, or an id >= n_blocks on a padding row)
-is never dereferenced. The mask still rules INSIDE live entries: a mask
-with holes, a partly filled last block, and shared read-only prefix
-blocks behave as on the gather path.
+Fetch. How a tile's blocks reach VMEM is decided by the arena's shapes
+alone (`copies_blocks`). Where one block is whole (sublane, lane) tiles
+(heads of a multiple of 128, blocks of a multiple of 8 rows of float32, 16
+of bfloat16, 32 of int8: every cell of the benchmark), the arenas (and an
+int8 arena's scale planes) enter the call where they lie
+(`memory_space=pl.ANY`) and **the kernel copies**: a scratch
+[2 buffers, 2 sides, E, nkv, blk, hd] (and [2, 2, E, 1, nkv*blk] float32
+for the planes), one `make_async_copy` a live entry a side on one DMA
+semaphore a buffer, in a loop over the tile's LIVE entries that reads each
+block's id from the table in SMEM. Grid step w starts step w + 1's copies
+into the other buffer (across a row's end too: the next step's row, tile,
+`n_live` and `n_first` are all in SMEM) before it waits for its own; `q`,
+the mask's tile and the output are the call's three pipelined operands. An
+entry that is not live is not copied; its place in the scratch holds
+whatever it held: its scores are masked, and its VALUES are zeroed in the
+scratch before the tile is multiplied (a row's first and last tile only),
+because 0 x NaN is NaN. Elsewhere (heads of 64, the `-tiny` models: Mosaic
+slices a memref by whole tiles only) **each entry's block is a `BlockSpec`
+operand** of its own (2E of them, 4E with int8 planes; 256 tokens and at
+most 8 entries a tile) whose index map reads the block's id, and the
+pipeline fetches it. The body is one and the same. Measured at the cells'
+call shapes (PERF.md section 6, PR 56): the pipeline's bookkeeping for 17
+operands runs on the scalar core IN SERIES with the body, 0.33 us a step;
+with 4 K/V heads, where a step's bytes take 0.64 us and the body about as
+long, the copying form at 16 entries reads 90% of the roofline where the
+operand form read 63% at 8 entries and 73% at 16, and it is no slower at
+8 and 16 K/V heads. VMEM: the tile's buffers are 2 sides x E entries x 2
+buffers x one block either way (16 K/V heads: 64 x 128 KiB = 8 MiB, which
+is `_TILE_VMEM_BYTES`: the tile shrinks until they fit; 4 K/V heads:
+2 MiB), beside them q, mask, output, the scale planes' rows and the
+[nkv, group, T] scores; the call is compiled under `vmem_limit_bytes` =
+12 MiB (`_VMEM_LIMIT_BYTES`; the v5e's default scoped limit is 16), which
+Mosaic enforces and tests/test_kernels_compile_tpu.py holds for the
+cells' shapes.
+
+Schedule (`_live_walk`, a few small XLA operations on the table and the
+mask, the same for every layer of a step). `n_live[i]` is row i's live
+table entries: (its last attendable column + 1) rounded up to blocks, 0
+for an all-masked row. The grid is ONE dimension over the tiles that hold
+a live entry, rows in order (an all-masked row gets one step, which writes
+its zeros), and its length `n_work` is read at run time: lengths are
+data, not shapes, so one decode program serves every mix. A table entry
+j >= n_live[i] is neither fetched nor multiplied: the copying kernel's
+loop ends at `n_live`, and where blocks are operands (`_live_schedule`:
+the operand form of this kernel, the latent and the index kernel) an
+operand whose entry is not live names the block it held the step before,
+and a repeated block index is not fetched again; so table slack (the zero
+block, or an id >= n_blocks on a padding row) is never dereferenced. The
+mask still rules INSIDE live entries: a mask with holes, a partly filled
+last block, and shared read-only prefix blocks behave as on the gather
+path.
 
 Layout. The Pallas TPU lowering requires the last two dims of every block
 to be divisible by (8, 128) or equal to the array's, and the TPU's
@@ -90,10 +120,11 @@ Window. A sliding layer's call names its `window` (static): the mask loses
 the columns that trail a row's last one by `window` or more (`band_mask`),
 and the schedule leaves out the tiles in front of the band as it leaves out
 those past the row's end: a row's walk starts at the tile that holds its
-first attendable column (`first`, a fifth scalar-prefetch operand, says
-where the online softmax begins), and inside that tile the entries in front
-of the band are not fetched (they keep the block the operand held) and the
-mask rules the columns. Time then follows min(tokens resident, window). Such
+first attendable column, and inside that tile the entries in front of the
+band are not fetched (`n_first`, a fifth scalar-prefetch operand, is where
+the copying kernel's loop begins; an operand keeps the block it held, and
+the latent kernel is told the first entry of that tile, `first`, where its
+online softmax begins) and the mask rules the columns. Time then follows min(tokens resident, window). Such
 a call is named `paged_decode_window` in the device trace; a call without a
 window is the program it was before the window existed.
 
@@ -150,7 +181,7 @@ here so tests can pin both semantics side by side.
 """
 
 import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -387,92 +418,171 @@ def paged_kv_gather(
 # compiled under `_VMEM_LIMIT_BYTES`, which Mosaic enforces.
 _TILE_VMEM_BYTES = 8 * 1024 * 1024
 _VMEM_LIMIT_BYTES = 12 * 1024 * 1024
-_TILE_TOKENS = 256
-_MAX_TILE_ENTRIES = 8
+# (tokens, entries) a grid step takes at most, by whether the kernel copies
+# its blocks itself (module docstring, "Fetch": measured, PERF.md section 6, PR 56)
+_TILE = {True: (512, 16), False: (256, 8)}
+
+
+def _sublanes(dtype) -> int:
+    """Rows of the type's (sublane, 128-lane) tile: 8 of float32, 16 of bfloat16, 32 of int8."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
 
 
 def _vmem_block_bytes(nkv: int, blk: int, hd: int, dtype) -> int:
     """VMEM bytes of one [nkv, blk, hd] arena block: the last two dims
     padded to the type's (sublane, 128-lane) tile."""
-    itemsize = jnp.dtype(dtype).itemsize
-    sublanes = 8 * (4 // itemsize)
-    rows = -(-blk // sublanes) * sublanes
-    return nkv * rows * (-(-hd // 128) * 128) * itemsize
+    rows = -(-blk // _sublanes(dtype)) * _sublanes(dtype)
+    return nkv * rows * (-(-hd // 128) * 128) * jnp.dtype(dtype).itemsize
+
+
+def copies_blocks(nkv: int, blk: int, hd: int, dtype) -> bool:
+    """Whether the kernel copies a tile's blocks itself (module docstring,
+    "Fetch"): where Mosaic can slice one block out of the arena, which it
+    does by whole (sublane, lane) tiles of the last two dims."""
+    whole = hd % 128 == 0 and blk % _sublanes(dtype) == 0
+    return whole and (jnp.dtype(dtype) != jnp.int8 or (nkv * blk) % 128 == 0)  # its planes: [1, nkv*blk] rows
 
 
 def _tile_entries(n_tbl: int, nkv: int, blk: int, hd: int, dtype) -> int:
-    """Table entries a grid step takes: `_TILE_TOKENS` tokens' worth, at
-    most `_MAX_TILE_ENTRIES` operands a side and the whole table, shrunk
-    until K and V tiles, double-buffered, fit `_TILE_VMEM_BYTES`."""
-    entries = max(1, min(_TILE_TOKENS // blk, _MAX_TILE_ENTRIES, n_tbl))
+    """Table entries a grid step takes: `_TILE`'s tokens' worth, at most its
+    entries and the whole table, shrunk until K and V tiles, double-buffered,
+    fit `_TILE_VMEM_BYTES`."""
+    tokens, most = _TILE[copies_blocks(nkv, blk, hd, dtype)]
+    entries = max(1, min(tokens // blk, most, n_tbl))
     while entries > 1 and 4 * entries * _vmem_block_bytes(nkv, blk, hd, dtype) > _TILE_VMEM_BYTES:
         entries -= 1
     return entries
 
 
-def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest,
-                         entries: int, scale: float, quantized: bool, p_dtype, windowed: bool = False):
-    """One grid step: tile `tile_ref[w]` of row `row_ref[w]`, whose
-    `entries` blocks have landed in VMEM for every kv head; mask invalid
-    columns and fold the tile into the row's online softmax, all kv heads
-    in one batched product.
+def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest, entries: int, n_tbl: Optional[int],
+                         scale: float, quantized: bool, p_dtype, windowed: bool = False):
+    """One grid step: tile `tile_ref[w]` of row `row_ref[w]`; mask invalid
+    columns and fold the tile into the row's online softmax, all kv heads in
+    one batched product. `n_tbl` names how the tile's blocks reach VMEM
+    (module docstring, "Fetch"): the table's length, and the step starts the
+    copies of step w + 1's live blocks into the other half of a scratch and
+    waits for its own; or None, and each entry's block is an operand that
+    the pipeline fetched.
 
-    blocks_ref scalar prefetch [n_steps * entries] (drives index maps)
+    blocks_ref scalar prefetch: the table, [b * n_tbl], that the copies read;
+               or [n_steps * entries], what drives the operands' index maps
     row_ref / tile_ref scalar prefetch [n_steps]: the step's row and tile
     n_live_ref scalar prefetch [b]: each row's live table entries
-    first_ref  scalar prefetch [b], a windowed call only: the first entry of
-               each row's first tile (its walk starts there, not at 0)
+    n_first_ref scalar prefetch [b], a windowed call that copies: each row's
+               entries in front of its band (not copied, like those past `n_live`)
     q_ref      [1, nkv, group, hd]
-    k_refs/v_refs `entries` x [1, nkv, blk, hd]
-    ks_refs/vs_refs `entries` x [1, 1, nkv*blk] f32 (int8 arenas only)
+    K, V       the arenas where they lie, [n_blocks, nkv, blk, hd], and for
+               int8 their scale planes, [n_blocks, 1, nkv*blk] f32;
+               or `entries` x [1, nkv, blk, hd] each (and `entries` x [1, 1, nkv*blk])
     mask_ref   [1, 1, 1, entries*blk] int32 key validity of the tile
     o_ref      [1, nkv, group, hd]
+    kv_buf     VMEM [2 buffers, 2 sides, entries, nkv, blk, hd], sc_buf VMEM
+               [2, 2, entries, 1, nkv*blk] f32 (int8), sem: a DMA semaphore a
+               buffer; a call that copies only
     m_scr/l_scr VMEM [nkv, group, 1] f32 running max / denominator,
                acc_scr VMEM [nkv, group, hd] numerator
     """
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     E = entries
-    first_ref = None
-    if windowed:
-        first_ref, rest = rest[0], rest[1:]
+    copied = n_tbl is not None
+    n_first_ref = None
+    if windowed and copied:
+        n_first_ref, rest = rest[0], rest[1:]
     q_ref, rest = rest[0], rest[1:]
-    k_refs, v_refs, rest = rest[:E], rest[E:2 * E], rest[2 * E:]
-    if quantized:
-        ks_refs, vs_refs, rest = rest[:E], rest[E:2 * E], rest[2 * E:]
-    mask_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    per = 1 if copied else E  # refs a plane: the plane where it lies, or an operand an entry
+    n_planes = 4 if quantized else 2  # K, V, then an int8 arena's scale planes of K and of V
+    planes = [rest[i * per:(i + 1) * per] for i in range(n_planes)]
+    mask_ref, o_ref, *bufs, m_scr, l_scr, acc_scr = rest[n_planes * per:]
+    if copied:
+        *bufs, sem = bufs  # kv_buf, then an int8 arena's sc_buf
     w = pl.program_id(0)
+    r = row_ref[w]
     first_entry = tile_ref[w] * E
-    n_live = n_live_ref[row_ref[w]]
-    nkv, blk = k_refs[0].shape[1], k_refs[0].shape[2]
+    n_live = n_live_ref[r]
 
-    @pl.when(first_entry == (0 if first_ref is None else first_ref[row_ref[w]]))
+    if copied:
+        buf = w % 2
+
+        def span(step):
+            """Step `step`'s row, its tile's first entry and its live entries [lo, hi)."""
+            row = row_ref[step]
+            base = tile_ref[step] * E
+            lo = base if n_first_ref is None else jnp.maximum(base, n_first_ref[row])
+            return row, base, lo, jnp.minimum(base + E, n_live_ref[row])
+
+        def copies(step, into, start: bool):
+            """Start (or wait for) the copies of `step`'s live blocks into half
+            `into` of the scratch; a wait needs a copy's size, not its source."""
+            row, base, lo, hi = span(step)
+
+            def one(e, carry):
+                block = blocks_ref[row * n_tbl + e] if start else 0
+                for i, (plane,) in enumerate(planes):
+                    copy = pltpu.make_async_copy(
+                        plane.at[block], bufs[i // 2].at[into, i % 2, e - base], sem.at[into])
+                    copy.start() if start else copy.wait()
+                return carry
+
+            jax.lax.fori_loop(lo, hi, one, 0)
+
+        @pl.when(w == 0)
+        def _first():
+            copies(0, 0, True)
+
+        @pl.when(w + 1 < pl.num_programs(0))
+        def _next():  # across a row's end as well: the next step's walk is in SMEM
+            copies(w + 1, 1 - buf, True)
+
+        copies(w, buf, False)
+        _, _, lo, hi = span(w)
+
+    def block(i, e, *at):
+        """Entry e's block of plane i (K, V, K's scales, V's scales), or the part `at` of it."""
+        return bufs[i // 2][(buf, i % 2, e, *at)] if copied else planes[i][e][(0, *at)]
+
+    nkv, blk = (bufs[0] if copied else planes[0][0]).shape[-3:-1]
+
+    def tile(side, dtype):
+        """[nkv, entries*blk, hd]: the entries' blocks side by side."""
+        return jnp.concatenate([block(side, e).astype(dtype) for e in range(E)], axis=1)
+
+    def scale_tile(side):
+        """[nkv, 1, entries*blk] from the planes' head-major lane rows."""
+        return jnp.stack([
+            jnp.concatenate([block(2 + side, e, slice(None), slice(h * blk, (h + 1) * blk)) for e in range(E)], axis=1)
+            for h in range(nkv)
+        ])
+
+    @pl.when((w == 0) | (row_ref[jnp.maximum(w - 1, 0)] != r))  # the row's first tile
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def tile(refs, dtype):
-        """[nkv, entries*blk, hd]: the entries' blocks side by side."""
-        return jnp.concatenate([r[0].astype(dtype) for r in refs], axis=1)
-
-    def scale_tile(refs):
-        """[nkv, 1, entries*blk] from the planes' head-major lane rows."""
-        return jnp.stack([
-            jnp.concatenate([r[0, :, h * blk:(h + 1) * blk] for r in refs], axis=1)
-            for h in range(nkv)
-        ])
-
     @pl.when(first_entry < n_live)
     def _tile():
+        if copied:
+            @pl.when(hi - lo < E)
+            def _dead():
+                # no copy wrote a dead entry's place: its scores are masked, but
+                # its values meet probabilities of 0, and 0 x NaN is NaN
+                def zero(e, carry):
+                    bufs[0][buf, 1, e - first_entry] = jnp.zeros(bufs[0].shape[3:], bufs[0].dtype)
+                    return carry
+
+                jax.lax.fori_loop(first_entry, lo, zero, 0)
+                jax.lax.fori_loop(hi, first_entry + E, zero, 0)
+
         q = q_ref[0]  # [nkv, group, hd], already in the product's type
         valid = mask_ref[0, 0] > 0  # [1, T]
         s = jax.lax.dot_general(
-            q, tile(k_refs, q.dtype), (((2,), (2,)), ((0,), (0,))),
+            q, tile(0, q.dtype), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         ) * scale  # [nkv, group, T]
         if quantized:
-            s = s * scale_tile(ks_refs)
+            s = s * scale_tile(0)
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev, l_prev = m_scr[:], l_scr[:]  # [nkv, group, 1]
@@ -487,9 +597,9 @@ def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest,
         l_scr[:] = l_prev * corr + jnp.sum(p, axis=2, keepdims=True)
         m_scr[:] = m_new
         if quantized:
-            p = p * scale_tile(vs_refs)
+            p = jnp.where(valid, p * scale_tile(1), 0.0)  # a dead entry's scales are no copy's either
         acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p.astype(p_dtype), tile(v_refs, p_dtype), (((2,), (1,)), ((0,), (0,))),
+            p.astype(p_dtype), tile(1, p_dtype), (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
 
@@ -512,13 +622,22 @@ def band_mask(key_mask, window: int):
     return valid & (cols[None, :] >= (last_col - window)[:, None])
 
 
-def _live_schedule(table, key_mask, blk: int, entries: int, windowed: bool = False):
-    """The grid's walk, from the table and the mask (module docstring,
-    "Schedule"). Returns `blocks` [n_steps*entries], `row` and `tile`
-    [n_steps], `n_live` [b], the scalar `n_work`, the static n_tiles, and,
-    `windowed`, `first` [b]: the walk then leaves out the tiles in front of
-    a row's first attendable column too (a banded mask: `band_mask`), and
-    `first` is the first entry of the first tile it keeps."""
+class _Walk(NamedTuple):
+    """The grid's walk (`_live_walk`; module docstring, "Schedule")."""
+    row: jnp.ndarray     # [n_steps] the step's row
+    tile: jnp.ndarray    # [n_steps] and its tile of that row
+    n_live: jnp.ndarray  # [b] each row's live table entries
+    n_work: jnp.ndarray  # scalar: the steps that hold work, the grid's length
+    n_tiles: int         # a row's tiles at most (static)
+    n_first: Optional[jnp.ndarray]  # [b], `windowed`: a row's entries in front of its first attendable column
+    tile0: Optional[jnp.ndarray]    # [b], `windowed`: the first tile a row's walk keeps
+    step: jnp.ndarray    # [n_steps] arange
+
+
+def _live_walk(table, key_mask, blk: int, entries: int, windowed: bool = False) -> _Walk:
+    """The grid's walk, from the table and the mask. `windowed`, it leaves
+    out the tiles in front of a row's first attendable column too (a banded
+    mask: `band_mask`)."""
     b, n_tbl = table.shape
     n_tiles = -(-n_tbl // entries)
     n_steps = b * n_tiles
@@ -527,6 +646,7 @@ def _live_schedule(table, key_mask, blk: int, entries: int, windowed: bool = Fal
     n_live = ((last_col + blk - 1) // blk).astype(jnp.int32)  # [b]
     # a row's tiles, one even for an all-masked row: its step writes the zeros
     tiles = jnp.maximum(1, (n_live + entries - 1) // entries)
+    n_first = tile0 = None
     if windowed:
         first_col = jnp.min(jnp.where(key_mask.astype(bool), cols, n_tbl * blk), axis=1)
         n_first = jnp.minimum(first_col // blk, n_live).astype(jnp.int32)  # [b] entries in front
@@ -540,6 +660,18 @@ def _live_schedule(table, key_mask, blk: int, entries: int, windowed: bool = Fal
     tile = at - (ends - tiles)[row]
     if windowed:
         tile = tile + tile0[row]
+    return _Walk(row, tile, n_live, n_work, n_tiles, n_first, tile0, step)
+
+
+def _live_schedule(table, key_mask, blk: int, entries: int, windowed: bool = False):
+    """`_live_walk` for a kernel whose blocks are `BlockSpec` operands (the
+    latent and the index kernel, and `paged_attention_decode` where it does
+    not copy), with the block each operand names in each step: `blocks`
+    [n_steps*entries], then `row`, `tile`, `n_live`, `n_work`, n_tiles and,
+    `windowed`, `first` [b], the first entry of the first tile a row's walk
+    keeps."""
+    n_tbl = table.shape[1]
+    row, tile, n_live, n_work, n_tiles, n_first, tile0, step = _live_walk(table, key_mask, blk, entries, windowed)
     entry = tile[:, None] * entries + jnp.arange(entries, dtype=jnp.int32)[None, :]
     live = entry < n_live[row][:, None]  # [n_steps, entries]
     if windowed:
@@ -601,18 +733,16 @@ def paged_attention_decode(
     qk_dtype = jnp.promote_types(q.dtype, kv_dtype)
     p_dtype = jnp.float32 if kv_dtype == jnp.float32 else jnp.promote_types(out_dtype, kv_dtype)
 
+    copied = copies_blocks(nkv, blk, hd, k_arena.dtype)
     E = _tile_entries(n_tbl, nkv, blk, hd, k_arena.dtype)
     windowed = window is not None
     if windowed:
         key_mask = band_mask(key_mask, window)
-    blocks, row, tile, n_live, n_work, n_tiles, *first = _live_schedule(table, key_mask, blk, E, windowed)
     T = E * blk
     # Head order matches the dense path's (and jnp.repeat(k, group, axis=2)):
     # q head h attends kv head h // group, so [b, nh, hd] -> [b, nkv,
     # group, hd] keeps each kv head's q-group contiguous.
     qg = q.reshape(b, nkv, group, hd).astype(qk_dtype)
-    maskh = jnp.pad(key_mask.astype(jnp.int32), ((0, 0), (0, n_tiles * T - n_tbl * blk)))
-    maskh = maskh.reshape(b, n_tiles, 1, T)
 
     def slot_index(w, blocks_ref, row_ref, *_):
         return (row_ref[w], 0, 0, 0)
@@ -622,24 +752,36 @@ def paged_attention_decode(
             return (blocks_ref[w * E + e],) + (0,) * (ndim - 1)
         return index
 
-    kv_specs = [pl.BlockSpec((1, nkv, blk, hd), entry_index(e, 4)) for e in range(E)]
-    in_specs = [pl.BlockSpec((1, nkv, group, hd), slot_index)] + kv_specs + kv_specs
-    operands = [qg] + [k_arena] * E + [v_arena] * E
-    if quantized:
-        plane_specs = [pl.BlockSpec((1, 1, nkv * blk), entry_index(e, 3)) for e in range(E)]
-        in_specs += plane_specs + plane_specs
-        operands += [k_scale] * E + [v_scale] * E
-    in_specs.append(pl.BlockSpec(
-        (1, 1, 1, T), lambda w, blocks_ref, row_ref, tile_ref, *_: (row_ref[w], tile_ref[w], 0, 0)
-    ))
-    operands.append(maskh)
+    planes = [k_arena, v_arena] + ([k_scale, v_scale] if quantized else [])
+    scratch = []
+    if copied:
+        # the arenas (and an int8 arena's planes) enter where they lie and
+        # the kernel copies a tile's live blocks itself, by the table
+        walk = _live_walk(table, key_mask, blk, E, windowed)
+        row, tile, n_live, n_work, n_tiles = walk[:5]
+        scalars = [table.astype(jnp.int32).reshape(-1), row, tile, n_live] + ([walk.n_first] if windowed else [])
+        operands = planes
+        kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * len(planes)
+        scratch = [pltpu.VMEM((2, 2, E) + plane.shape[1:], plane.dtype) for plane in planes[::2]]
+        scratch.append(pltpu.SemaphoreType.DMA((2,)))
+    else:
+        # each entry's block of each plane is an operand of its own
+        blocks, row, tile, n_live, n_work, n_tiles, *_ = _live_schedule(table, key_mask, blk, E, windowed)
+        scalars = [blocks, row, tile, n_live]
+        operands = [plane for plane in planes for _ in range(E)]
+        kv_specs = [pl.BlockSpec((1,) + plane.shape[1:], entry_index(e, plane.ndim))
+                    for plane in planes for e in range(E)]
+    maskh = jnp.pad(key_mask.astype(jnp.int32), ((0, 0), (0, n_tiles * T - n_tbl * blk)))
+    maskh = maskh.reshape(b, n_tiles, 1, T)
+    mask_spec = pl.BlockSpec(
+        (1, 1, 1, T), lambda w, blocks_ref, row_ref, tile_ref, *_: (row_ref[w], tile_ref[w], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4 + len(first),
+        num_scalar_prefetch=len(scalars),
         grid=(n_work,),  # the steps that hold work, read at run time
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec((1, nkv, group, hd), slot_index)] + kv_specs + [mask_spec],
         out_specs=pl.BlockSpec((1, nkv, group, hd), slot_index),
-        scratch_shapes=[
+        scratch_shapes=scratch + [
             pltpu.VMEM((nkv, group, 1), jnp.float32),   # m
             pltpu.VMEM((nkv, group, 1), jnp.float32),   # l
             pltpu.VMEM((nkv, group, hd), jnp.float32),  # acc
@@ -647,7 +789,7 @@ def paged_attention_decode(
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_decode_kernel, entries=E, scale=1.0 / np.sqrt(hd),
+            _paged_decode_kernel, entries=E, n_tbl=n_tbl if copied else None, scale=1.0 / np.sqrt(hd),
             quantized=quantized, p_dtype=p_dtype, windowed=windowed,
         ),
         grid_spec=grid_spec,
@@ -657,12 +799,12 @@ def paged_attention_decode(
         ),
         interpret=interpret,
         name="paged_decode_window" if windowed else "paged_decode",
-    )(blocks, row, tile, n_live, *first, *operands)
+    )(*scalars, qg, *operands, maskh)
     return out.reshape(b, nh, hd)
 
 
-# A latent tile is one plane and its rows are 4.5 lane tiles wide: twice the
-# tokens of a K/V tile for the same count of block DMAs a grid step.
+# A latent tile is one plane and its rows are 4.5 lane tiles wide: 512 tokens
+# a grid step, its blocks `BlockSpec` operands (`_live_schedule`).
 _LATENT_TILE_TOKENS = 512
 _MAX_LATENT_TILE_ENTRIES = 16
 

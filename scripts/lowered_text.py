@@ -50,7 +50,8 @@ SERVE_CELLS = {"pythia-1.4b.rollout-batch": ((1, 256), (8, 512)),
                "solar-open2-250b.rollout-longctx": ((1, 8192),),  # likewise from before PR 43
                "falcon-h1-34b.rollout-chat": ((1, 1024),),  # and from before PR 48
                "dots3-note-prev.rollout-longdoc": ((1, 8192),),  # and from before PR 51
-               "smallthinker-21b-a3b.rollout-transcript": ((1, 8192),)}  # and from before PR 55
+               "smallthinker-21b-a3b.rollout-transcript": ((1, 8192),),  # and from before PR 55
+               "ouro-2.6b.rollout-math": ((1, 256),)}  # and from before PR 57
 
 
 class Lowered(Exception):

@@ -56,6 +56,7 @@ LONGEST_FIRST = [
     "test_sequence_parallel.py",           # 79 s
     "test_transcript_cell_compile_tpu.py",  # 75 s (my CPU run, PR 55: the decode step and one row of 14,336)
     "test_smallthinker.py",                # 76 s (my CPU run, PR 55)
+    "test_loop_cell_compile_tpu.py",       # 70 s (my CPU run, PR 57: the looped decode step and one row of 256)
     "test_tracing_control.py",             # 69 s
     "test_solar_open2.py",                 # 68 s
     "test_pipeline_tp.py",                 # 64 s

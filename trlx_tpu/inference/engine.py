@@ -49,7 +49,9 @@ import numpy as np
 
 from trlx_tpu.inference.adapters import adapter_salt
 from trlx_tpu.inference.paging import BlockPool, KVPoolExhaustedError, prefix_keys
+from trlx_tpu.models.policy import refuse_over_looped_stack
 from trlx_tpu.models.transformer import (
+    exit_early_from_state,
     init_kv_cache,
     init_paged_kv_arena,
     moe_stats_from_state,
@@ -235,6 +237,7 @@ class InferenceEngine:
                          (not kv_paging, "the dense slot pool (kv_paging=False)")):
             if on:
                 _refuse_over_attention_kinds(model_cfg, what)
+                refuse_over_looped_stack(model_cfg, what)
         if _KV_DTYPES.get(kv_cache_dtype) == jnp.int8:
             _refuse_over_slot_state(model_cfg, "an int8 arena (kv_cache_dtype='int8')")
             _refuse_over_latent_cache(model_cfg, "an int8 arena (kv_cache_dtype='int8')")
@@ -327,6 +330,9 @@ class InferenceEngine:
         keeps = getattr(model_cfg, "layer_keeps", None)
         self._layer_keeps = [keeps(i) for i in range(model_cfg.n_layers)] if keeps else []
         self._slot_state_layers = sum(1 for k in self._layer_keeps if k.slot)
+        # a looped stack runs its layers `_loop_passes` times a token, and a layer keeps planes a pass
+        self._loop_passes = int(getattr(model_cfg, "loop_steps", 1))
+        self._loop_exit_early = 0.0
         # the layers whose prefill runs a chunked recurrence from the slot's `state` (a KDA
         # layer's, an SSM mixer's), whatever planes a token they keep beside it
         self._recurrent_layers = sum(1 for k in self._layer_keeps if "state" in k.slot_names)
@@ -1134,6 +1140,7 @@ class InferenceEngine:
         # counted per dispatch in _step_impl)
         ak = self._attn_kernel if self._kernel_unsupported is None else None
         sown = getattr(self.model_cfg, "has_sparse_moe", False)
+        looped = self._loop_passes > 1  # its step sows the exit gate's one scalar (never both: no looped experts)
 
         def decode(params, pool, stack=None):
             params = dequantize_tree(params)
@@ -1166,9 +1173,9 @@ class InferenceEngine:
                 valid.astype(jnp.int32)[:, None],
                 method=type(model).decode_step,
                 attn_kernel=ak,
-                mutable=["moe_stats"] if sown else False,
+                mutable=["moe_stats"] if sown else ["loop_stats"] if looped else False,
             )
-            if sown:
+            if sown or looped:
                 out, state = out
             logits, new_cache = out[0], out[-1]
             if paged:
@@ -1196,6 +1203,8 @@ class InferenceEngine:
                 # the step's dispatch counters (a few scalars), fetched with
                 # its tokens: no further transfer
                 return new_pool, token, logprob, valid, finished, moe_stats_from_state(state)
+            if looped:  # the same way: one more scalar among the step's outputs
+                return new_pool, token, logprob, valid, finished, exit_early_from_state(state)
             return new_pool, token, logprob, valid, finished
 
         # distinct ledger site per read path (budget 1 either way): a
@@ -1482,12 +1491,18 @@ class InferenceEngine:
         # the span ends when the host has the step's outputs, and covers
         # nothing else: its end is what a reader sets against the device's
         with tracing.span("engine.fetch"):
-            token, logprob, valid, finished, *moe = jax.device_get(due.out)
+            token, logprob, valid, finished, *stats = jax.device_get(due.out)
         traced = tracing.active()
         if traced:  # which step that fetch waited for: the `seq` it was queued under
             tracing.counters("engine.fetched", seq=due.seq)
-        if moe:  # a model with `SparseMoE` layers: the step's dispatch counters
-            self._moe_stats = {k: float(v) for k, v in moe[0].items()}
+        if stats and self._loop_passes > 1:  # a looped stack: what the step fetched ran, and its exit gate's scalar
+            self._loop_exit_early = float(stats[0])
+            if traced:
+                tracing.counters("engine.loop", steps=1, passes=self._loop_passes,
+                                 layer_calls=self._loop_passes * self.model_cfg.n_layers,
+                                 exit_early=round(self._loop_exit_early, 6))
+        elif stats:  # a model with `SparseMoE` layers: the step's dispatch counters
+            self._moe_stats = {k: float(v) for k, v in stats[0].items()}
             if self.kv_paging and traced:
                 tracing.counters("engine.moe", **self._moe_stats)
         rows = due.rows if valid.ndim == 1 else due.rows[:, None]
@@ -1699,7 +1714,8 @@ class InferenceEngine:
 
         cfg, blk = self.model_cfg, self.kv_block_size
         cols = self._next_columns()
-        kinds = [cfg.layer_op(i) for i in range(cfg.n_layers) if self._layer_keeps[i].token]
+        # one entry a call of the read side: a layer of a looped stack is read once a pass, from that pass's planes
+        kinds = [cfg.layer_op(i) for i in range(cfg.n_layers) if self._layer_keeps[i].token] * self._loop_passes
         last = -(-cols // blk)
         kernels = self.decode_path != "xla"
 

@@ -61,6 +61,8 @@ def _family_of(hf: Dict) -> str:
         return "smallthinker"
     if mt == "pangu_ultra_moe":
         return "pangu_ultra_moe"
+    if mt == "ouro" or "total_ut_steps" in hf:
+        return "ouro"
     if mt == "dots3_note":
         return "dots3_note"
     if "layer_group_size" in hf and "kda_lower_bound" in hf:  # the published config names no model_type here
@@ -199,6 +201,8 @@ def config_from_hf(path: str, **overrides):
         kwargs = _smallthinker_kwargs(hf)
     elif fam == "pangu_ultra_moe":
         kwargs = _pangu_kwargs(hf)
+    elif fam == "ouro":
+        kwargs = _ouro_kwargs(hf)
     elif fam == "dots3_note":
         kwargs = _dots3_kwargs(hf)
     elif fam == "ling_flash":
@@ -279,6 +283,33 @@ def _smallthinker_kwargs(hf: Dict) -> Dict:
                     ("sliding_attention", RopeSpec(theta=float(hf["rope_theta"])))),
         moe_experts=hf["moe_num_primary_experts"], moe_top_k=hf["moe_num_active_primary_experts"],
         moe_d_ff=hf["moe_ffn_hidden_size"], moe_router="topk_softmax", moe_route_on="block_input",
+    )
+
+
+def _ouro_kwargs(hf: Dict) -> Dict:
+    """ByteDance's `ouro` (LoopLM) config keys -> TransformerConfig fields: a
+    llama-style stack under sandwich norms that runs `total_ut_steps` times a
+    token. `max_window_layers` is read past while no window is on; a window
+    that is on is refused by name. What the keys do not settle (the two
+    further norms, the norm between passes, the gate, a cache slot a (pass,
+    layer)) is bench/configs/ouro-2.6b.json's `assumed`."""
+    if hf.get("use_sliding_window") or hf.get("sliding_window") is not None:
+        raise NotImplementedError(
+            f"ouro with use_sliding_window={hf.get('use_sliding_window')!r} / sliding_window="
+            f"{hf.get('sliding_window')!r} is not supported: every published layer attends to every position")
+    kinds = set(hf.get("layer_types") or ["full_attention"])
+    if kinds != {"full_attention"} or hf.get("rope_scaling") is not None:
+        raise NotImplementedError(f"ouro with layer_types {sorted(kinds)} or rope_scaling={hf.get('rope_scaling')!r} "
+                                  "is not supported")
+    return dict(
+        vocab_size=hf["vocab_size"], d_model=hf["hidden_size"], n_layers=hf["num_hidden_layers"],
+        n_heads=hf["num_attention_heads"], n_kv_heads=hf.get("num_key_value_heads", hf["num_attention_heads"]),
+        head_width=hf.get("head_dim"), d_ff=hf["intermediate_size"], max_seq_len=hf["max_position_embeddings"],
+        pos_embed="rope", rope_theta=float(hf.get("rope_theta", 10000.0)), norm="rmsnorm",
+        layer_norm_epsilon=hf.get("rms_norm_eps", 1e-6), activation="silu", glu=True,
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)), use_bias=False, sandwich_norm=True,
+        loop_steps=int(hf["total_ut_steps"]), loop_gate=int(hf["total_ut_steps"]) > 1,
+        loop_exit_threshold=float(hf.get("early_exit_threshold", 1.0)),
     )
 
 
@@ -682,6 +713,23 @@ def _load_llama(sd: Dict, cfg: TransformerConfig) -> Dict:
         }
     if not cfg.tie_embeddings:
         lm["lm_head"] = _dense(sd["lm_head.weight"].T)
+    return lm
+
+
+# our leaf -> the `ouro` checkpoint's norm, a layer (the names bench/configs/ouro-2.6b.json `assumed`
+# lists: the family's convention as recalled, unchecked against the published safetensors index)
+_OURO_NORMS = (("ln_attn", "input_layernorm"), ("ln_post_attn", "input_layernorm_2"),
+               ("ln_mlp", "post_attention_layernorm"), ("ln_post_mlp", "post_attention_layernorm_2"))
+
+
+def _load_ouro(sd: Dict, cfg: TransformerConfig) -> Dict:
+    """OuroForCausalLM: llama's names, two further norms a layer and the exit gate."""
+    lm = _load_llama(sd, cfg)
+    for i in range(cfg.n_layers):
+        lm[f"block_{i}"].update({ours: _ln(sd, f"model.layers.{i}.{theirs}", bias=False)
+                                 for ours, theirs in _OURO_NORMS})
+    if cfg.loop_gate:
+        lm["exit_gate"] = _dense(sd["model.early_exit_gate.weight"].T, sd["model.early_exit_gate.bias"])
     return lm
 
 
@@ -1113,6 +1161,7 @@ _LOADERS: Dict[str, Callable] = {
     "gpt_bigcode": _load_gpt_bigcode,
     "lfm2_moe": _load_lfm2_moe,
     "pangu_ultra_moe": _load_pangu_ultra_moe,
+    "ouro": _load_ouro,
     "dots3_note": _load_dots3_note,
     "falcon_h1": _load_falcon_h1,
     "smallthinker": _load_smallthinker,
@@ -1220,6 +1269,18 @@ def _export_llama(lm: Dict, cfg: TransformerConfig) -> Dict:
         sd["lm_head.weight"] = _f32(lm["lm_head"]["kernel"]).T
     else:
         sd["lm_head.weight"] = sd["model.embed_tokens.weight"]
+    return sd
+
+
+def _export_ouro(lm: Dict, cfg: TransformerConfig) -> Dict:
+    """Inverse of `_load_ouro`."""
+    sd = _export_llama(lm, cfg)
+    for i in range(cfg.n_layers):
+        for ours, theirs in _OURO_NORMS:
+            sd[f"model.layers.{i}.{theirs}.weight"] = _f32(lm[f"block_{i}"][ours]["scale"])
+    if cfg.loop_gate:
+        sd["model.early_exit_gate.weight"] = _f32(lm["exit_gate"]["kernel"]).T
+        sd["model.early_exit_gate.bias"] = _f32(lm["exit_gate"]["bias"])
     return sd
 
 
@@ -1604,6 +1665,7 @@ _EXPORTERS: Dict[str, Callable] = {
     "gpt_bigcode": _export_gpt_bigcode,
     "lfm2_moe": _export_lfm2_moe,
     "pangu_ultra_moe": _export_pangu_ultra_moe,
+    "ouro": _export_ouro,
     "dots3_note": _export_dots3_note,
     "falcon_h1": _export_falcon_h1,
     "smallthinker": _export_smallthinker,
@@ -1615,6 +1677,8 @@ def infer_family(cfg) -> str:
     (used when exporting a model that wasn't loaded from an HF dir)."""
     if getattr(cfg, "is_seq2seq", False):
         return "t5"
+    if getattr(cfg, "loop_steps", 1) > 1:
+        return "ouro"
     if getattr(cfg, "has_conv_layers", False):
         return "lfm2_moe"
     if getattr(cfg, "has_ssm_layers", False):
@@ -1804,6 +1868,17 @@ def config_to_hf(cfg: TransformerConfig, family: str = None) -> Dict:
             moe_intermediate_size=cfg.expert_d_ff, norm_topk_prob=True, scoring_func="sigmoid",
             topk_method="noaux_tc", routed_scaling_factor=cfg.moe_routed_scale,
             tie_word_embeddings=cfg.tie_embeddings,
+        )
+    if family == "ouro":
+        return dict(
+            model_type="ouro", architectures=["OuroForCausalLM"], vocab_size=cfg.vocab_size,
+            hidden_size=cfg.d_model, intermediate_size=cfg.d_ff, num_hidden_layers=cfg.n_layers,
+            num_attention_heads=cfg.n_heads, num_key_value_heads=cfg.kv_heads, head_dim=cfg.head_dim,
+            hidden_act="silu", max_position_embeddings=cfg.max_seq_len, max_window_layers=cfg.n_layers,
+            layer_types=["full_attention"] * cfg.n_layers, rms_norm_eps=cfg.layer_norm_epsilon,
+            rope_scaling=None, rope_theta=cfg.rope_theta, sliding_window=None, use_sliding_window=False,
+            tie_word_embeddings=cfg.tie_embeddings, total_ut_steps=cfg.loop_steps,
+            early_exit_threshold=cfg.loop_exit_threshold,
         )
     if family == "pangu_ultra_moe":
         return dict(

@@ -207,6 +207,17 @@ class CausalLMWithILQLHeads(nn.Module):
 # ---------------------------------------------------------------------------
 
 
+def refuse_over_looped_stack(cfg, what: str) -> None:
+    """What assumes that a layer runs once a token is refused by name over a
+    looped stack (`TransformerConfig.loop_steps` > 1): every layer runs in
+    every pass, so the layers below a split are no prefix of the computation,
+    and a cached plane is a (pass, layer)'s."""
+    if getattr(cfg, "loop_steps", 1) > 1:
+        raise NotImplementedError(
+            f"{what} over a looped stack (loop_steps {cfg.loop_steps}: the layers below a split run again in every "
+            "later pass, and a cached plane is a (pass, layer)'s) is not supported")
+
+
 def resolve_split(cfg: TransformerConfig, num_layers_unfrozen: int) -> int:
     """Map the user-facing `num_layers_unfrozen` to the hydra split layer.
     Semantics match the reference's freeze_bottom_causal_layers
@@ -230,6 +241,8 @@ def resolve_split(cfg: TransformerConfig, num_layers_unfrozen: int) -> int:
         return 0
     if num_layers_unfrozen == -1:
         return 0
+    refuse_over_looped_stack(cfg, f"num_layers_unfrozen={num_layers_unfrozen} (the hydra split, the frozen-trunk "
+                                  "cache: only -1, every layer trainable, has a meaning)")
     if num_layers_unfrozen == 0:
         return cfg.n_layers
     return max(cfg.n_layers - num_layers_unfrozen, 0)

@@ -17,7 +17,7 @@ writes) so the sampling loop is a single compiled lax.while_loop.
 """
 
 import functools
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -109,10 +109,13 @@ class LayerKeeps:
     is built from (`init_kv_cache`, `init_paged_kv_arena`, the engine's pool,
     `observability.hbm`): `token` planes, (name, shape a token), in the
     cache's type; `slot` arrays, (name, shape a row, type or None for the
-    cache's), which a row owns whole and a step overwrites."""
+    cache's), which a row owns whole and a step overwrites. `passes`: how
+    often a token's planes are kept, once a pass of a looped stack
+    (`TransformerConfig.loop_steps`: the cache's unit is (pass, layer))."""
 
     token: Tuple[Tuple[str, Tuple[int, ...]], ...] = ()
     slot: Tuple[Tuple[str, Tuple[int, ...], Any], ...] = ()
+    passes: int = 1
 
     @property
     def slot_names(self) -> Tuple[str, ...]:
@@ -307,6 +310,18 @@ class TransformerConfig:
     ssm_chunk: int = 128
     ssm_state_dtype: Any = jnp.float32
     multipliers: Multipliers = Multipliers()
+    # A looped stack (Ouro's LoopLM, `total_ut_steps`): the SAME `n_layers`
+    # blocks run `loop_steps` times a token, `ln_f` after every pass and its
+    # output fed on as the next pass's input (`TransformerLM.run_passes`: one
+    # traced body). Every (pass, layer) keeps keys and values of its own
+    # (`LayerKeeps.passes`); a token's position is the same in every pass.
+    # `loop_gate`: an exit gate leaf (`exit_gate`, d_model -> 1 with a bias) read
+    # on every pass's normed output (`exit_distribution`). `loop_exit_threshold`
+    # is the published `early_exit_threshold`: at 1.0 every row runs every pass
+    # and the logits are the last pass's; anything lower is refused.
+    loop_steps: int = 1
+    loop_gate: bool = False
+    loop_exit_threshold: float = 1.0
 
     def __post_init__(self):
         if self.q_lora_rank is None:
@@ -407,6 +422,23 @@ class TransformerConfig:
                     f"{self.moe_top_k} experts a token do not fit")
         if self.sandwich_norm and self.parallel_residual:
             raise NotImplementedError("sandwich_norm under parallel_residual is not supported")
+        if self.loop_steps < 1 or (self.loop_gate and self.loop_steps == 1):
+            raise ValueError(f"loop_steps {self.loop_steps} must be >= 1, and loop_gate needs loop_steps > 1")
+        if self.loop_steps > 1:
+            if self.loop_exit_threshold < 1.0:
+                raise NotImplementedError(
+                    f"a looped stack with loop_exit_threshold {self.loop_exit_threshold} < 1 (early_exit_threshold: "
+                    "a row that leaves before the last pass still owes the later passes' keys and values to the "
+                    "tokens after it, and the published config does not say what they are) is not supported")
+            unsupported = [what for on, what in (
+                (self.slot_state_kinds or self.has_latent_layers, "layers that keep anything but K and V by head"),
+                (self.moe_experts > 0, "moe_experts"), (self.mtp_layers > 0, "mtp_layers"),
+                (self.prompt_tokens > 0, "prompt_tokens"), (self.prefix_tokens > 0, "prefix_tokens"),
+                (self.attn_impl == "ring", "attn_impl='ring'"),
+            ) if on]
+            if unsupported:
+                raise NotImplementedError(f"a looped stack (loop_steps > 1) with {', '.join(unsupported)} "
+                                          "is not supported")
         if self.moe_router != "softmax" and self.moe_router not in SPARSE_ROUTERS:
             raise ValueError(f"moe_router must be 'softmax' or one of {SPARSE_ROUTERS}, got {self.moe_router!r}")
         if self.moe_route_on not in ("ffn_input", "block_input"):
@@ -589,11 +621,13 @@ class TransformerConfig:
         return LayerKeeps(token=kv)
 
     def layer_keeps(self, i: int) -> LayerKeeps:
-        return self.kind_keeps(self.layer_op(i))
+        keeps = self.kind_keeps(self.layer_op(i))
+        return keeps if self.loop_steps == 1 else replace(keeps, passes=self.loop_steps)
 
     def cache_planes(self, i: int) -> Tuple[int, ...]:
-        """What a token caches in layer i, one width a plane (`layer_keeps`)."""
-        return tuple(int(np.prod(shape)) for _, shape in self.layer_keeps(i).token)
+        """What a token caches in layer i, one width a plane (`layer_keeps`), the planes of every pass."""
+        keeps = self.layer_keeps(i)
+        return tuple(int(np.prod(shape)) for _, shape in keeps.token) * keeps.passes
 
     def slot_state_bytes_per_slot(self, cache_dtype) -> int:
         """Bytes of slot state one row holds over all layers."""
@@ -670,10 +704,10 @@ def activation_fn(cfg: TransformerConfig):
     }.get(cfg.activation, jax.nn.gelu)
 
 
-def make_norm(cfg: TransformerConfig, name: str):
+def make_norm(cfg: TransformerConfig, name: str, **module_kw):
     if cfg.norm == "rmsnorm":
-        return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
-    return nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name)
+        return nn.RMSNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name, **module_kw)
+    return nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name=name, **module_kw)
 
 
 def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
@@ -1815,6 +1849,13 @@ def moe_stats_from_state(state) -> Dict[str, jnp.ndarray]:
     return stats
 
 
+def exit_early_from_state(state) -> jnp.ndarray:
+    """The one scalar a looped stack's cached step sowed during a
+    mutable=['loop_stats'] apply (`TransformerLM.decode_step`): the mean share
+    of a live position that the exit gate would have let leave before the last pass."""
+    return jax.tree_util.tree_leaves(state["loop_stats"])[0]
+
+
 def moe_aux_from_intermediates(state) -> jnp.ndarray:
     """Sum the moe_aux scalars sown by every MoEMLP during a
     mutable=['intermediates'] apply; 0 when nothing was sown."""
@@ -1997,6 +2038,34 @@ def cached_bias(cfg: TransformerConfig, new_mask: jnp.ndarray, positions: jnp.nd
     return bias
 
 
+@functools.lru_cache(maxsize=None)
+def _block_step(cfg: TransformerConfig, kwargs: Tuple, use_prefix: bool, attn_kernel: Optional[str]):
+    """One block of a looped stack as ONE jitted function of its leaves, `(variables, h, attn_bias,
+    positions, layer_cache, cache_index, attn_mask) -> (h, new layer cache)`: the layers of a kind
+    share it, so a program traces and lowers a block once and calls it a layer (`run_passes`)."""
+    block = Block(cfg, **dict(kwargs), parent=None)  # no module of the caller's: a pure function of its leaves
+
+    def step(variables, h, attn_bias, positions, layer_cache, cache_index, attn_mask):
+        return block.apply(variables, h, attn_bias, positions, layer_cache, cache_index, attn_mask, use_prefix,
+                           attn_kernel)
+
+    return jax.jit(jax.checkpoint(step) if cfg.remat_blocks else step)
+
+
+@functools.lru_cache(maxsize=None)
+def _pass_end(cfg: TransformerConfig):
+    """What ends a pass of a looped stack, `(leaves, h) -> (ln_f(h), the exit gate's logit on it
+    [b, t] float32)`: the final norm after EVERY pass, the gate read on the normed output."""
+    norm = make_norm(cfg, None, parent=None)
+    gate = nn.Dense(1, dtype=jnp.float32, param_dtype=cfg.param_dtype, parent=None)
+
+    def end(leaves, h):
+        h = norm.apply(leaves["ln_f"], h)
+        return h, gate.apply(leaves["gate"], h)[..., 0] if cfg.loop_gate else jnp.zeros(h.shape[:2], jnp.float32)
+
+    return jax.jit(end)
+
+
 class TransformerLM(nn.Module):
     """Decoder-only LM. Returns logits and (optionally) the hidden state at
     a static split layer for the hydra reference branch."""
@@ -2032,6 +2101,8 @@ class TransformerLM(nn.Module):
             block_cls(cfg, **cfg.block_kwargs(i), name=f"block_{i}") for i in range(cfg.n_layers)
         ]
         self.ln_f = make_norm(cfg, "ln_f")
+        if cfg.loop_gate:  # read in float32 on every pass's normed output (`run_passes`)
+            self.exit_gate = nn.Dense(1, dtype=jnp.float32, param_dtype=cfg.param_dtype, name="exit_gate")
         if not cfg.tie_embeddings:
             self.lm_head = nn.Dense(
                 cfg.vocab_size, use_bias=cfg.lm_head_bias,
@@ -2049,10 +2120,11 @@ class TransformerLM(nn.Module):
             h = self.ln_embed(h)
         return h
 
-    def unembed(self, h):
+    def unembed(self, h, normed: bool = False):
         """Final norm + output projection. Returns (logits, h_final) so the
-        value head can reuse the normed hidden state."""
-        h_final = self.ln_f(h)
+        value head can reuse the normed hidden state. `normed`: `h` is under
+        the final norm already (a looped stack's last pass: `run_passes`)."""
+        h_final = h if normed else self.ln_f(h)
         if self.cfg.tie_embeddings:
             logits = self.embed_tokens.attend(h_final)
         else:
@@ -2091,6 +2163,72 @@ class TransformerLM(nn.Module):
             if cache is not None:
                 new_layers.append(new_cache)
         return h, new_layers
+
+    def run_passes(self, h, attn_bias, positions, cache=None, cache_index=None, attn_mask=None,
+                   use_prefix: bool = True, attn_kernel: Optional[str] = None):
+        """A looped stack (`cfg.loop_steps` > 1): every block, then `ln_f`, `loop_steps` times over,
+        each pass's normed output the next one's input. Returns (the last pass's normed output, the
+        cache's new layers or None, the exit gate's logits `[passes, b, t]` float32: zeros without
+        a gate).
+
+        The passes are ONE traced body under `jax.lax.scan`, and a block is ONE jitted function of
+        its leaves (`_block_step`: the layers differ in their leaves, not in their shapes), so a
+        program costs one block to trace and one pass to compile, whatever the layers and the
+        passes (tracing is most of a warm set-up: PERF.md section 5). The leaves are read from the
+        bound modules here and handed to pure functions inside the loop.
+
+        The cache's unit is (pass, layer). A dense layer's planes lie `[passes, b, S, ...]`
+        (`init_kv_cache`) and pass t is handed plane t and gives it back; a paged layer's arena is
+        `passes` pools laid end to end (`init_paged_kv_arena`), carried through the passes and
+        patched where it lies, and pass t reads and writes through the row's ONE block table shifted
+        by t pools (`ops.paged_attention.pass_table`): one allocation and one table a row serve every
+        pass, and the kernels, the block pool and the write are what an un-looped model runs."""
+        from trlx_tpu.ops.paged_attention import pass_table
+
+        cfg = self.cfg
+        if self.is_initializing():
+            # `init`: one walk through the modules makes every leaf; what it returns beside them is not read
+            h, _ = self.run_blocks(h, attn_bias, positions, 0, cfg.n_layers, attn_mask=attn_mask, use_prefix=use_prefix)
+            h = self.ln_f(h)
+            gate = self.exit_gate(h)[..., 0] if cfg.loop_gate else jnp.zeros(h.shape[:2], jnp.float32)
+            return h, None, jnp.broadcast_to(gate, (cfg.loop_steps, *h.shape[:2]))
+        leaves = [block.variables for block in self.blocks]
+        steps = [_block_step(cfg, tuple(sorted(cfg.block_kwargs(i).items())), use_prefix, attn_kernel)
+                 for i in range(cfg.n_layers)]
+        end_leaves = {"ln_f": self.ln_f.variables, **({"gate": self.exit_gate.variables} if cfg.loop_gate else {})}
+        paged = cache is not None and "table" in cache[0]
+        bare = lambda layers: [{k: v for k, v in layer.items() if k != "table"} for layer in layers]  # the arena's own
+        kv = lambda layers: [{k: layer[k] for k in ("k", "v")} for layer in layers]  # a dense layer's planes
+        arena = planes = None
+        if paged:
+            table, arena = cache[0]["table"], bare(cache)
+        elif cache is not None:
+            planes = kv(cache)
+
+        def one_pass(carry, xs):
+            h, arena = carry
+            t, planes = xs
+            layers = [None] * cfg.n_layers
+            if paged:
+                shifted = pass_table(table, t, cfg.loop_steps, arena[0]["k"].shape[0])
+                layers = [{**layer, "table": shifted} for layer in arena]
+            elif planes is not None:
+                layers = [{**layer, **plane} for layer, plane in zip(cache, planes)]
+            new = []
+            for step, block, layer in zip(steps, leaves, layers):
+                h, kept = step(block, h, attn_bias, positions, layer, cache_index, attn_mask)
+                new.append(kept)
+            h, gate = _pass_end(cfg)(end_leaves, h)
+            if paged:
+                arena = bare(new)
+            elif planes is not None:
+                planes = kv(new)
+            return (h, arena), (planes, gate)
+
+        (h, arena), (planes, gates) = jax.lax.scan(one_pass, (h, arena), (jnp.arange(cfg.loop_steps), planes))
+        if paged:
+            return h, [{**layer, "table": table} for layer in arena], gates
+        return h, planes, gates
 
     def __call__(
         self,
@@ -2132,6 +2270,7 @@ class TransformerLM(nn.Module):
         window: Optional[Tuple[Any, int]] = None,
         use_prompt: bool = True,
         mtp: bool = False,
+        exit_pdf: bool = False,
     ):
         """The one forward without a cache: embed (start == 0) or take the
         hidden state entering block `start` (the hydra frozen branch,
@@ -2166,9 +2305,21 @@ class TransformerLM(nn.Module):
           runs the multi-token-prediction blocks: `caps["mtp"]` is a list, one
           `[b, t, vocab]` a block, in which block k's position i holds the
           logits for token i + k + 2 (its last k + 1 positions have no next
-          token to read and are not to be used)."""
+          token to read and are not to be used).
+        - `exit_pdf=True` (a looped stack, `cfg.loop_steps` > 1) also gives
+          `caps["exit_pdf"]`, `[b, t, passes]` float32: the share of a position
+          that the exit gate would let leave after each pass
+          (`exit_distribution`). The logits are the last pass's whatever it reads."""
         cfg = self.cfg
         to_head, stop = stop is None, cfg.n_layers if stop is None else stop
+        looped = cfg.loop_steps > 1
+        if looped and (start > 0 or not to_head or set(capture) - {0}):
+            raise NotImplementedError(
+                "a looped stack (loop_steps > 1) runs whole: a forward from, to or capturing a layer inside it (the "
+                "hydra split, the frozen-trunk cache, num_layers_unfrozen) is not supported: the top layers run in "
+                "every pass, so the layers below a split are no prefix of the computation")
+        if exit_pdf and not looped:
+            raise ValueError("exit_pdf=True needs a looped stack (loop_steps > 1)")
         if cfg.mtp_layers and self.is_initializing() and start == 0 and to_head and window is None:
             mtp = True  # `init` walks the multi-token blocks too, or they get no leaves
         if mtp and (start > 0 or not to_head or window is not None or cfg.prompt_tokens > 0
@@ -2207,11 +2358,15 @@ class TransformerLM(nn.Module):
         if bounds[0] < start or bounds[-1] > stop:
             raise ValueError(f"capture {capture} outside the blocks run, [{start}, {stop}]")
         caps = {}
-        for s, e in zip(bounds, bounds[1:]):
-            caps[s] = h
-            h, _ = self.run_blocks(h, bias, positions, s, e, attn_mask=attn_mask,
-                                   use_prefix=use_prompt)
-        caps[stop] = h
+        if looped:
+            caps[0] = h
+            h, _, gates = self.run_passes(h, bias, positions, attn_mask=attn_mask, use_prefix=use_prompt)
+        else:
+            for s, e in zip(bounds, bounds[1:]):
+                caps[s] = h
+                h, _ = self.run_blocks(h, bias, positions, s, e, attn_mask=attn_mask,
+                                       use_prefix=use_prompt)
+            caps[stop] = h
         caps = {i: caps[i] for i in capture}
         if not to_head:
             return None, h, caps
@@ -2219,7 +2374,10 @@ class TransformerLM(nn.Module):
             h = h[:, P:]
         if window is not None:
             h = jax.lax.dynamic_slice_in_dim(h, window[0], window[1], axis=1)
-        logits, h_final = self.unembed(h)
+        logits, h_final = self.unembed(h, normed=looped)
+        if exit_pdf:
+            pdf = exit_distribution(gates)
+            caps["exit_pdf"] = pdf if window is None else jax.lax.dynamic_slice_in_dim(pdf, window[0], window[1], axis=1)
         if mtp:
             caps["mtp"] = []
             tokens, mask, state = x, attn_mask, h
@@ -2291,6 +2449,11 @@ class TransformerLM(nn.Module):
         to_head, stop = stop is None, cfg.n_layers if stop is None else stop
         b, t = x.shape[:2]
         per_row = "row_index" in cache
+        looped = cfg.loop_steps > 1
+        if looped and (start > 0 or not to_head or capture_split is not None or positions is not None):
+            raise NotImplementedError(
+                "a looped stack (loop_steps > 1) runs whole: a cached step from, to or capturing a layer inside it "
+                "(speculative decode's draft step and verify pass, the hydra split) is not supported")
         if per_row:
             if cfg.prompt_tokens > 0 or cfg.prefix_tokens > 0:
                 raise NotImplementedError(
@@ -2381,8 +2544,15 @@ class TransformerLM(nn.Module):
                 step_mask, attn_kernel = token_mask, "prefill"
         # cache layer indices are absolute, so the segments' new layers
         # concatenate exactly, behind and before the layers passed through
-        bounds = sorted({start, stop, capture_split} - {None})
+        bounds = () if looped else sorted({start, stop, capture_split} - {None})  # a looped stack runs whole
         new_layers, h_cap = list(cache["layers"][:start]), None
+        if looped:
+            h, new_layers, gates = self.run_passes(h, bias, positions, cache=layers, cache_index=offset,
+                                                   attn_mask=step_mask, attn_kernel=attn_kernel)
+            # the mean share of a live position that the gate would have let leave before the last pass
+            live = jnp.ones((b, t), jnp.float32) if token_mask is None else token_mask.astype(jnp.float32)
+            early = 1.0 - exit_distribution(gates)[..., -1]
+            self.sow("loop_stats", "exit_early", (early * live).sum() / jnp.maximum(live.sum(), 1.0))
         for s, e in zip(bounds, bounds[1:]):
             if s == capture_split:
                 h_cap = h
@@ -2398,7 +2568,7 @@ class TransformerLM(nn.Module):
             h = h[:, P:] if P > 0 else h
             if head_at is not None:
                 h = jnp.take_along_axis(h, head_at[:, None, None], axis=1)
-            logits, h = self.unembed(h)
+            logits, h = self.unembed(h, normed=looped)
         else:
             logits, h = None, self.ln_f(h)
         if not per_row:
@@ -2413,6 +2583,17 @@ class TransformerLM(nn.Module):
         if capture_split is not None:
             return logits, h, new_cache, h_cap
         return logits, h, new_cache
+
+
+def exit_distribution(gate_logits: jnp.ndarray) -> jnp.ndarray:
+    """A looped stack's exit distribution, `[passes, ...]` gate logits -> `[..., passes]` float32:
+    with lam_t = sigmoid(logit_t), p_t = lam_t prod_{j<t}(1 - lam_j) before the last pass and
+    p_last = prod_{j<last}(1 - lam_j), what no earlier pass let go (the last pass's own gate
+    decides nothing). The shares sum to 1. The one place the rule is written."""
+    lam = jax.nn.sigmoid(gate_logits.astype(jnp.float32))[:-1]
+    stays = jnp.cumprod(1.0 - lam, axis=0)  # [passes - 1, ...]: still there after pass t
+    before = jnp.concatenate([jnp.ones_like(stays[:1]), stays[:-1]])
+    return jnp.moveaxis(jnp.concatenate([lam * before, stays[-1:]]), 0, -1)
 
 
 def position_ids(attn_mask: jnp.ndarray) -> jnp.ndarray:
@@ -2586,7 +2767,8 @@ def init_kv_cache(cfg: TransformerConfig, batch_size: int, max_len: int, dtype=N
 
     def layer(i):
         keeps = cfg.layer_keeps(i)
-        planes = {name: jnp.zeros((batch_size, max_len, *shape), dtype=dtype) for name, shape in keeps.token}
+        passes = () if keeps.passes == 1 else (keeps.passes,)  # a looped stack: a plane a pass, `[passes, batch, ...]`
+        planes = {name: jnp.zeros((*passes, batch_size, max_len, *shape), dtype=dtype) for name, shape in keeps.token}
         return {**planes, **keeps.slot_arrays(batch_size, dtype)}
 
     return {
@@ -2628,8 +2810,8 @@ def init_paged_kv_arena(
             arena = init_paged_latent_layer(num_blocks, block_size, latent.width, dtype)
             arena.update({name: init_paged_plane(num_blocks, block_size, *shape, dtype)
                           for name, shape in keeps.token if name != "latent"})
-        elif keeps.token:
-            arena = init_paged_layer(num_blocks, block_size, cfg.kv_heads, cfg.head_dim, dtype)
+        elif keeps.token:  # a looped stack's layer: a pool a pass, end to end, under ONE table (`pass_table`)
+            arena = init_paged_layer(num_blocks * keeps.passes, block_size, cfg.kv_heads, cfg.head_dim, dtype)
         else:
             arena = {}
         return {**arena, **keeps.slot_arrays(num_slots, dtype)}
@@ -2976,6 +3158,19 @@ PRESETS: Dict[str, Dict[str, Any]] = {
         layer_types=("full_attention", "sliding_attention", "sliding_attention", "sliding_attention"),
         rope_kinds=(("full_attention", RopeSpec(pct=0.0)), ("sliding_attention", RopeSpec(theta=1500000.0))),
         moe_experts=16, moe_top_k=3, moe_d_ff=32, moe_router="topk_softmax", moe_route_on="block_input",
+    ),
+    # Ouro LoopLM (ByteDance/Ouro-2.6B `config.json`, `model_type` "ouro"): ONE stack of 48 llama-style
+    # layers under sandwich norms, run `total_ut_steps` = 4 times a token over the same weights
+    # (`loop_steps`), the final norm after every pass, an exit gate on each pass's output
+    "ouro-2.6b": dict(
+        d_model=2048, n_layers=48, n_heads=16, n_kv_heads=16, head_width=128, d_ff=5632, max_seq_len=65536,
+        pos_embed="rope", rope_theta=1000000.0, norm="rmsnorm", layer_norm_epsilon=1e-6, activation="silu",
+        glu=True, tie_embeddings=False, use_bias=False, sandwich_norm=True, loop_steps=4, loop_gate=True,
+    ),
+    "ouro-tiny": dict(
+        d_model=64, n_layers=2, n_heads=4, n_kv_heads=4, head_width=16, d_ff=128, max_seq_len=256,
+        pos_embed="rope", rope_theta=1000000.0, norm="rmsnorm", layer_norm_epsilon=1e-6, activation="silu",
+        glu=True, tie_embeddings=False, use_bias=False, sandwich_norm=True, loop_steps=4, loop_gate=True,
     ),
     # Mixture-of-experts (beyond the reference): experts shard over `tensor`
     "moe-tiny": dict(

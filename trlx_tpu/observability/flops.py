@@ -160,7 +160,9 @@ def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
         with `top=False` its bottom ones (the frozen trunk)."""
         lo, hi = (L - layers, L) if top else (0, layers)
         attention = sum(layer_attention_flops(model_cfg, i, avg_ctx) for i in range(lo, hi))
-        return tokens * (sum(per_layer[lo:hi]) + attention + (head if with_head else 0))
+        # a looped stack runs every layer `loop_steps` times a token, the head once
+        passes = getattr(model_cfg, "loop_steps", 1)
+        return tokens * (passes * (sum(per_layer[lo:hi]) + attention) + (head if with_head else 0))
 
     # generation: prefill the prompt, then n_new cached decode steps
     if spec_k > 0:

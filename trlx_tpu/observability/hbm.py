@@ -135,7 +135,9 @@ def paged_arena_bytes(cfg, n_blocks: int, block_size: int, dtype="float32") -> i
     whose state is a slot's (`slot_state_bytes` counts that)."""
     latent_of = getattr(cfg, "latent_of", lambda kind: None)
     latent = [i for i in range(cfg.n_layers) if latent_of(cfg.layer_op(i)) is not None]
-    by_head = sum(1 for i in range(cfg.n_layers) if cfg.layer_keeps(i).token) - len(latent)
+    # K/V layers, a looped stack's once a pass (`LayerKeeps.passes`: a pool a pass, end to end)
+    by_head = sum(cfg.layer_keeps(i).passes for i in range(cfg.n_layers)
+                  if cfg.layer_keeps(i).token and i not in latent)
     return (kv_arena_bytes(by_head, cfg.kv_heads, cfg.head_dim, n_blocks, block_size, dtype)
             + sum(latent_arena_bytes(1, sum(cfg.cache_planes(i)), n_blocks, block_size, dtype) for i in latent))
 

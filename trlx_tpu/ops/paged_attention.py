@@ -1028,6 +1028,19 @@ def paged_index_scores(
     return jnp.where(key_mask.astype(bool), scores.reshape(b, n_tiles * T)[:, :n_tbl * blk], -jnp.inf)
 
 
+def pass_table(table: jnp.ndarray, t, passes: int, arena_blocks: int) -> jnp.ndarray:
+    """A row's block table as pass `t` of a looped stack reads and writes through it
+    (`TransformerLM.run_passes`): a looped layer's arena of `arena_blocks` blocks is `passes` pools
+    laid end to end (`init_paged_kv_arena`), and pass t's keys and values of the block a row was
+    handed lie at that block's id + t pools. An id past a pool's end (an insert's padding rows, a
+    stale table: the write drops them) goes past the ARENA's end, and block 0, the zero block
+    nobody is handed, becomes pass t's own first block, which nobody writes either. `t` may be
+    traced. The one place the layout of a looped arena is written."""
+    pool = arena_blocks // passes
+    table = table.astype(jnp.int32)
+    return jnp.where(table < pool, table + t * pool, arena_blocks)
+
+
 def paged_index_reference(q, w, arena, table, key_mask):
     """XLA shadow of `paged_index_scores`: the plane gathered to a dense view."""
     keys = paged_plane_gather(arena, table).astype(q.dtype)  # [b, S, D]

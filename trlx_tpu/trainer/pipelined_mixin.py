@@ -211,8 +211,11 @@ class PipelinedCausalMixin:
     # ------------------------------------------------------------------
 
     def place_params(self, params) -> Dict:
+        from trlx_tpu.models.policy import refuse_over_looped_stack
         from trlx_tpu.parallel import infer_param_shardings
         from trlx_tpu.parallel.pipeline import stacked_param_shardings
+
+        refuse_over_looped_stack(self.model_cfg, "pipeline stages (a stage's layers run once a microbatch)")
 
         runtime: PipeMeshRuntime = self.runtime
         assert isinstance(runtime, PipeMeshRuntime)

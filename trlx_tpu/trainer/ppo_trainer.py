@@ -1871,6 +1871,10 @@ class PPOTrainer(TPUTrainer):
         method.quantize_frozen_trunk is on (quantized ONCE — those leaves
         never train — and re-merged with the live trainable leaves every
         dispatch), else the dense merged tree."""
+        if getattr(self.config.method, "quantize_frozen_trunk", False):
+            from trlx_tpu.models.policy import refuse_over_looped_stack
+
+            refuse_over_looped_stack(self.model_cfg, "method.quantize_frozen_trunk (an int8 frozen trunk)")
         if not (
             getattr(self.config.method, "quantize_frozen_trunk", False)
             and self.split > 0

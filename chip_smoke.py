@@ -416,6 +416,8 @@ def kernel_phase(args, trainer):
         copies_blocks,
         paged_attention_decode,
         paged_attention_reference,
+        paged_kv_write,
+        writes_in_kernel,
     )
 
     check_kernel_paths(args, trainer)
@@ -483,6 +485,29 @@ def kernel_phase(args, trainer):
                 f"{f', window {window}' if window else ''}) {dtype}, blocks {'copied' if copied else 'operands'}, "
                 f"{len(dead)} of {n_blocks} blocks poison: max|dev| {dev:.2e} (bound {paged_tol:.0e})")
             check(dev < paged_tol, f"{what} parity {dev} >= {paged_tol}")
+            if writes_in_kernel(k_in):
+                # a decode step's write from the kernel itself against `paged_kv_write` in front of
+                # it: the same products over the same bits, so arenas AND outputs bit for bit
+                new_k, new_v = (jnp.asarray(rng.standard_normal((b, 1, nkv, hd)), jnp.bfloat16) for _ in range(2))
+                column, live = jnp.asarray(np.maximum(lens - 1, 0), jnp.int32), jnp.asarray(lens > 0)[:, None]
+
+                def xla_write(q, k, v, t, m):
+                    new = paged_kv_write({"k": k, "v": v}, new_k, new_v, t, column, live)
+                    return paged_attention_decode(
+                        q, new["k"], new["v"], t, m, interpret=args.rehearse_cpu, window=window), new["k"], new["v"]
+
+                def kernel_write(q, k, v, t, m):
+                    return paged_attention_decode(q, k, v, t, m, interpret=args.rehearse_cpu, window=window,
+                                                  new_kv=(new_k[:, 0], new_v[:, 0]), column=column)
+
+                want = jax.jit(xla_write)(q, k_in, v_in, jnp.asarray(table), mask)
+                got = jax.jit(kernel_write)(q, k_in, v_in, jnp.asarray(table), mask)
+                same = [bool((np.asarray(x).view(np.uint16) == np.asarray(y).view(np.uint16)).all())
+                        for x, y in zip(got, want)]
+                log(f"kernels: {what}: the kernel's own write against paged_kv_write's, "
+                    f"output / K arena / V arena bit for bit: {same}")
+                check(all(same), f"{what}: the kernel's write differs from paged_kv_write's {same}")
+                check(not bool((got[1] == k_in).all()), f"{what}: the kernel wrote nothing")
 
 
 def main():

@@ -163,42 +163,57 @@ def test_paged_decode_fits_its_vmem_budget_at_the_cells_shapes(v5e, shape, monke
     c, window, scratch_mib = FETCH_SHAPES[shape]
     n_blocks, nkv, n_tbl, b, blk, hd = *(c[k] for k in ("n_blocks", "nkv", "n_tbl", "slots")), 32, 128
     one = SingleDeviceSharding(v5e[0])
-    arena = S((n_blocks, nkv, blk, hd), BF16)
-    args = (S((b, nkv * c["group"], hd), BF16), arena, arena, S((b, n_tbl), I32), S((b, n_tbl * blk), I32))
-    assert paged_attention.copies_blocks(nkv, blk, hd, BF16)
+    arena, row = S((n_blocks, nkv, blk, hd), BF16), S((b, nkv, hd), BF16)
+    # the call as a decode step makes it: with the step's K and V rows and their column, which it writes
+    args = (S((b, nkv * c["group"], hd), BF16), arena, arena, S((b, n_tbl), I32), S((b, n_tbl * blk), I32),
+            row, row, S((b,), I32))
+    assert paged_attention.writes_in_kernel(arena)
     assert paged_attention._tile_entries(n_tbl, nkv, blk, hd, BF16) == 16
     assert paged_attention._VMEM_LIMIT_BYTES == 12 * 2 ** 20
     assert 4 * 16 * paged_attention._vmem_block_bytes(nkv, blk, hd, BF16) == scratch_mib * 2 ** 20
 
     def compile_under(limit):
         monkeypatch.setattr(paged_attention, "_VMEM_LIMIT_BYTES", limit)
+        paged_attention._paged_decode_call.clear_cache()  # or the call's own jit hands back the last limit's trace
         # a fresh function object: jit's trace cache would hand back the last limit's program
-        return compile_for(lambda *a: paged_attention_decode(*a, window=window), args, one)
+        return jax.jit(lambda *a: paged_attention_decode(*a[:5], window=window, new_kv=a[5:7], column=a[7]),
+                       donate_argnums=(1, 2)).trace(*abstract(args, one)).lower(lowering_platforms=("tpu",)).compile()
 
     compiled = compile_under(12 * 2 ** 20)
     assert kernel_names(compiled) == ["paged_decode" if window is None else "paged_decode_window"]
     assert arena_rewrites(compiled, arena) == []
     with pytest.raises(Exception, match="vmem"):
         compile_under(scratch_mib * 2 ** 20)
+    paged_attention._paged_decode_call.clear_cache()  # nobody else gets the small limit's trace
 
 
 @pytest.mark.parametrize("dtype", [BF16, I8], ids=["bf16", "int8"])
 @pytest.mark.parametrize("form", ["decode", "prefill"])
 def test_paged_write_and_read_leave_the_arena_where_it_lies(v5e, form, dtype):
     """The write and the read in ONE program, arenas donated, as a layer of
-    the engine's decode step (64 rows x 1 position, the kernel) and of a
-    prefill (1 row x 256 positions, the gather) run them: the step's keys
-    and values land in the donated arena in the layout the kernel and the
-    gather read, with no copy of an arena or a scale plane on the way.
+    the engine's decode step (64 rows x 1 position, the kernel: since PR 58
+    a bfloat16 arena's write is the kernel's own, an int8 arena keeps
+    `paged_kv_write` in front of it) and of a prefill (1 row x 256
+    positions, the gather) run them: the step's keys and values land in the
+    donated arena in the layout the kernel and the gather read, with no copy
+    of an arena or a scale plane on the way, in ONE Mosaic call a layer.
     `arena.at[phys, :, off].set(k)` put four arena copies a layer there:
     49 ms of a 120 ms decode step on the chip (PERF.md, PR 28)."""
     n_blocks, nkv, blk, hd = (CELL[k] for k in ("n_blocks", "nkv", "blk", "hd"))
     b, t = (CELL["slots"], 1) if form == "decode" else (1, 256)
     one = SingleDeviceSharding(v5e[0])
 
+    in_kernel = form == "decode" and dtype == BF16  # as `Attention` decides: `writes_in_kernel`
+
     def layer_step(layer, q, k, v, table, start, valid, key_mask):
+        if in_kernel:
+            assert paged_attention.writes_in_kernel(layer["k"])
+            out, *arenas = paged_attention_decode(
+                q[:, 0], layer["k"], layer["v"], table, key_mask, new_kv=(k[:, 0], v[:, 0]), column=start)
+            return dict(zip("kv", arenas)), out
         new = paged_kv_write(layer, k, v, table, start, valid)
         if form == "decode":
+            assert not paged_attention.writes_in_kernel(layer["k"])
             out = paged_attention_decode(
                 q[:, 0], new["k"], new["v"], table, key_mask,
                 k_scale=new.get("k_scale"), v_scale=new.get("v_scale"))
@@ -219,6 +234,35 @@ def test_paged_write_and_read_leave_the_arena_where_it_lies(v5e, form, dtype):
     assert mosaic_calls(compiled) == (1 if form == "decode" else 0)
     assert arena_rewrites(compiled, *layer.values()) == []
     assert donated_outputs(compiled) == len(layer)
+
+
+def test_the_kernel_s_write_under_a_scan_over_passes_copies_no_arena(v5e):
+    """The looped stack's form (`TransformerLM.run_passes`; ouro-2.6b's cut: 8
+    rows, 16 K/V heads, 4 pools of 160 blocks laid end to end): the arenas are
+    the carry of `jax.lax.scan` over the passes, pass t reads and writes through
+    `pass_table`, and the compiled program holds one Mosaic call in the loop's
+    body and no copy of an arena: nothing but the arenas themselves is as large."""
+    passes, pool, nkv, blk, hd, b, n_tbl = 4, 160, 16, 32, 128, 8, 19
+    one = SingleDeviceSharding(v5e[0])
+    arena = S((passes * pool, nkv, blk, hd), BF16)
+
+    def step(layer, q, k, v, table, start, key_mask):
+        def one_pass(carry, t):
+            layer, q = carry
+            out, *arenas = paged_attention_decode(
+                q, layer["k"], layer["v"], paged_attention.pass_table(table, t, passes, passes * pool), key_mask,
+                new_kv=(k, v), column=start)
+            return (dict(zip("kv", arenas)), out), None
+        return jax.lax.scan(one_pass, (layer, q), jnp.arange(passes))[0]
+
+    row = S((b, nkv, hd), BF16)
+    args = ({"k": arena, "v": arena}, row, row, row, S((b, n_tbl), I32), S((b,), I32), S((b, n_tbl * blk), I32))
+    compiled = jax.jit(step, donate_argnums=(0,)).trace(
+        *abstract(args, one)).lower(lowering_platforms=("tpu",)).compile()
+    assert mosaic_calls(compiled) == 1 and kernel_names(compiled) == ["paged_decode"]
+    assert arena_rewrites(compiled, arena) == []
+    assert donated_outputs(compiled) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20  # no second arena anywhere (one is 168 MB)
 
 
 @pytest.mark.parametrize("dtype", [BF16, I8], ids=["bf16", "int8"])

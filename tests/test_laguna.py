@@ -304,11 +304,15 @@ def test_engine_gather_path_and_int8_arena_follow_the_window():
 # whose point is the paged kernel's body: a row's first tile is told by the
 # row's change and no longer by a fifth scalar operand, and an int8 arena's
 # value scales are selected by the mask (5ac5d745...ffa until then; neox-tiny's
-# heads of 16 keep every block an operand, `copies_blocks`); the forward stands
-# as recorded.
+# heads of 16 keep every block an operand, `copies_blocks`), and again at PR
+# 58, which left the kernel's body for this arena as it was (heads of 16 keep
+# `paged_kv_write` too: the text held while only the write was added) and made
+# the call one jitted function, so the layers' calls are ONE private function
+# of the module, called a layer, where each layer had a copy of it
+# (4c97953a...f14 until then); the forward stands as recorded.
 LOWERED_BEFORE = {
     "forward": "803f839d0b7e6abadd156f8b2cb8bd16c9d3034a7f14eecc5b4922349f28de6d",
-    "decode": "4c97953ac380e00eda34a80836d64ca20b7202876519aee57c3df1400f402f14",
+    "decode": "ba4d54c3b3028db9bbbe690786224dd21600a5076b08fe048dfa040426bd7ade",
     "insert": "6503e6edc5c8f4b5bae8b96cd693b1d431f0b7431c99f5d28a572f5bb6974d3f",
 }
 

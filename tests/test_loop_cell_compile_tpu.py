@@ -38,6 +38,10 @@ def test_loop_cell_programs_compile_for_the_chip_and_fit_it(v5e, loop_cell_engin
     if program == "decode":
         compiled = compile_engine_program(engine, params, v5e[0])
         assert kernel_names(compiled).count("paged_decode") == 48  # one body, 4 trips
+        # the step's keys and values reach the arena from that call itself (PR 58): no other Mosaic call, and
+        # the program's temporaries no more than with `paged_kv_write` in front of it (1.324 GB at PR 57)
+        assert kernel_names(compiled) == ["paged_decode"] * 48 and engine._kv_write_form() == "kernel"
+        assert compiled.memory_analysis().temp_size_in_bytes <= 1.324e9  # 1,314,187,776 B (my AOT compile, PR 58)
     else:
         compiled = compile_engine_program(engine, params, v5e[0], (1, WIDEST, False))
         assert kernel_names(compiled) == []  # the dense insert: a prompt of 256 scores against its 640 columns

@@ -265,7 +265,7 @@ def run_engine(cfg, params, prompts, max_new, **engine_kw):
     gen_cfg = GenerationConfig(max_new_tokens=max_new, do_sample=True, eos_token_id=VOCAB + 1, pad_token_id=0)
     engine = InferenceEngine(CausalLMPolicy(cfg), cfg, params, gen_cfg, seed=3, kv_paging=True,
                              num_slots=len(prompts), max_prompt_len=32, max_prefill_batch=1, prompt_bucket=16,
-                             kv_block_size=4, **engine_kw)
+                             **{"kv_block_size": 4, **engine_kw})
     scheduler = Scheduler(engine, max_queue_depth=8).start()
     try:
         requests = [scheduler.submit(p, max_new_tokens=max_new) for p in prompts]
@@ -286,20 +286,29 @@ def engine_errors(cfg, params, prompts, out, got):
             for r, (p, lps) in enumerate(zip(prompts, got))]
 
 
-@pytest.mark.parametrize("kernel", ["xla", "auto"])
+@pytest.mark.parametrize("kernel", ["xla", "auto", "auto-heads128"])
 def test_the_engine_through_the_scheduler_matches_the_reference(kernel, lm_params, monkeypatch):
     """Two requests: four passes over each prompt into the arena's four pools, then paged decode
-    (the gather path; the kernel, interpreted, in 8 calls a step), against the plain reference."""
+    (the gather path; the kernel, interpreted, in 8 calls a step), against the plain reference.
+    With heads of 128 in blocks of 8 rows the kernel also WRITES the step's keys and values, pass
+    by pass through `pass_table` under the scan (`kv_kernel_writes`); the preset's heads of 16 keep
+    `paged_kv_write` in front of it."""
     monkeypatch.setenv("TRLX_TPU_KERNELS", "interpret")
-    cfg, params = tiny_cfg(), {"lm": lm_params}
+    kernel, _, wide = kernel.partition("-")
+    cfg, params, engine_kw = tiny_cfg(), {"lm": lm_params}, {}
+    if wide:
+        cfg, engine_kw = tiny_cfg(n_heads=2, n_kv_heads=2, head_width=128), dict(kv_block_size=8)
+        params = {"lm": seeded_params(TransformerLM(cfg), 5)}
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, VOCAB, size=n).astype(np.int32) for n in (21, 6)]
     with jax.default_matmul_precision("highest"):
-        engine, out, got = run_engine(cfg, params, prompts, 9, decode_kernel=kernel)
+        engine, out, got = run_engine(cfg, params, prompts, 9, decode_kernel=kernel, **engine_kw)
     assert engine.decode_path == ("xla" if kernel == "xla" else "interpret")
     assert [len(lps) for lps in got] == [9, 9] and max(engine_errors(cfg, params, prompts, out, got)) < TOL
     stats = engine.kv_stats()
     assert stats["kv_kernel_fallbacks"] == {} and 0.0 < engine._loop_exit_early < 1.0
+    assert stats["kv_kernel_writes"] == (stats["kv_kernel_dispatches"] if wide else 0)
+    assert (stats["kv_kernel_dispatches"] > 0) == (kernel != "xla")
 
 
 @pytest.mark.parametrize("passes", [1, 2, 4])

@@ -21,20 +21,21 @@ from trlx_tpu.ops.paged_attention import (
     paged_attention_decode,
     paged_attention_reference,
     paged_kv_write,
+    writes_in_kernel,
 )
 from trlx_tpu.ops.sampling import GenerationConfig
 
 EOS_FREE = 10_000  # an id the byte model never emits -> length-capped runs
 
 
-def _build_trainer(preset, dtype="float32"):
+def _build_trainer(preset, dtype="float32", extra=None):
     from trlx_tpu.data.default_configs import default_sft_config
     from trlx_tpu.trainer.sft_trainer import SFTTrainer
 
     config = default_sft_config().evolve(
         model=dict(
             model_path=f"random:{preset}",
-            model_extra_configs={"dtype": dtype},
+            model_extra_configs={"dtype": dtype, **(extra or {})},
         ),
         tokenizer=dict(tokenizer_path="byte"),
         train=dict(seq_length=64, total_steps=0, tracker=None, batch_size=2),
@@ -56,8 +57,8 @@ def make_engine(trainer, decode_kernel, max_new=8, **kw):
     )
     return InferenceEngine(
         trainer.model, trainer.model_cfg, trainer.params, gen_cfg,
-        num_slots=2, max_prompt_len=32, kv_paging=True, kv_block_size=8,
-        decode_kernel=decode_kernel, **kw,
+        num_slots=2, max_prompt_len=32, kv_paging=True, decode_kernel=decode_kernel,
+        **{"kv_block_size": 8, **kw},
     )
 
 
@@ -349,6 +350,62 @@ def test_the_copying_kernel_matches_reference_on_ragged_rows(group, dtype, inter
     assert (out_k[~active] == 0.0).all()
 
 
+# (group, arena, block rows, window, interpreter): heads of 128 in blocks of whole tiles, where
+# the kernel copies a tile's blocks and so can write the step's row back (`writes_in_kernel`)
+FUSED_WRITES = [(1, "bfloat16", 32, None, "interpret"), (7, "bfloat16", 16, None, "nan"),
+                (7, "float32", 16, None, "interpret"), (1, "float32", 32, 40, "nan"),
+                (7, "bfloat16", 32, 100, "interpret")]
+
+
+@pytest.mark.parametrize("group,dtype,blk,window,interpreter", FUSED_WRITES, ids=lambda v: str(v))
+def test_the_kernel_writes_the_step_s_kv_as_paged_kv_write_does(group, dtype, blk, window, interpreter):
+    """`paged_attention_decode(..., new_kv=, column=)` against `paged_kv_write`
+    in front of the reference: both arenas bit for bit in EVERY block, touched
+    or not, and the outputs within the file's tolerance. The rows: offset 0 of a
+    fresh block; offset blk - 1; a freed slot between live rows (no token this
+    step, all-masked, its stale table naming blocks that are now other rows');
+    a row of two tiles; a padding row whose table names ids past the arena; two
+    rows behind one shared read-only prefix block; a row of one position."""
+    rng = np.random.RandomState(FUSED_WRITES.index((group, dtype, blk, window, interpreter)))
+    nkv, hd, n_tbl, n_blocks = 2, 128, 19, 48
+    lens = np.array([3 * blk + 1, 2 * blk, 2 * blk + 5, 16 * blk + 5, 7, blk + 3, 2 * blk - 4, 1])  # with the new column
+    valid = np.array([1, 1, 0, 1, 1, 1, 1, 1])
+    b, n_live = len(lens), -(-lens // blk)
+    ids = list(1 + rng.permutation(n_blocks - 1))
+    table = np.zeros((b, n_tbl), np.int32)
+    for r in range(b):
+        table[r, :n_live[r]] = [ids.pop() for _ in range(n_live[r])]
+    table[2, :3] = [table[0, 3], table[1, 1], table[3, 16]]  # stale: the blocks the live rows write
+    table[4] = n_blocks + rng.randint(0, 5, n_tbl)           # a padding row
+    table[6, 0] = table[5, 0]                                # the shared prefix block, full and read-only
+    mask = (np.arange(n_tbl * blk)[None, :] < lens[:, None]) & (valid[:, None] > 0)
+    mask[4] = False
+    adtype = jnp.dtype(dtype)
+    q = jnp.asarray(rng.randn(b, nkv * group, hd), adtype)
+    ka, va = (jnp.asarray(rng.randn(n_blocks, nkv, blk, hd), adtype) for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(b, 1, nkv, hd), jnp.float32) for _ in range(2))
+    table, mask, column = jnp.asarray(table), jnp.asarray(mask.astype(np.int32)), jnp.asarray(lens - 1, jnp.int32)
+    assert writes_in_kernel(ka) and _tile_entries(n_tbl, nkv, blk, hd, adtype) == 16
+
+    want = paged_kv_write({"k": ka, "v": va}, k, v, table, column, jnp.asarray(valid)[:, None])
+    out_r = np.asarray(paged_attention_reference(
+        q, want["k"], want["v"], jnp.where(table < n_blocks, table, 0), mask, window=window).astype(jnp.float32))
+    out_k, k_arena, v_arena = paged_attention_decode(
+        q, ka, va, table, mask, interpret=_interpreter(interpreter), window=window,
+        new_kv=(k[:, 0], v[:, 0]), column=column)
+    np.testing.assert_array_equal(_bits(k_arena), _bits(want["k"]))
+    np.testing.assert_array_equal(_bits(v_arena), _bits(want["v"]))
+    assert not (_bits(k_arena) == _bits(ka)).all()  # and something was written
+    active = np.asarray(mask).any(-1)
+    tol = 2e-2 if dtype == "bfloat16" else 1e-5
+    out_k = np.asarray(out_k.astype(jnp.float32))
+    np.testing.assert_allclose(out_k[active], out_r[active], rtol=tol, atol=tol)
+    assert (out_k[~active] == 0.0).all()
+    with pytest.raises(ValueError, match="does not write"):  # heads of 16: the operand form keeps `paged_kv_write`
+        paged_attention_decode(q[..., :16], ka[..., :16], va[..., :16], table, mask, interpret=True,
+                               new_kv=(k[:, 0, :, :16], v[:, 0, :, :16]), column=column)
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32", "int8"])
 @pytest.mark.parametrize("form", ["operands", "copied-interpret", "copied-nan"])
 def test_table_slack_is_never_dereferenced(dtype, form):
@@ -462,6 +519,49 @@ def test_greedy_bitwise_bf16_kv(trainers):
     assert kernel == gather
 
 
+@pytest.fixture(scope="module")
+def wide_trainer():
+    """llama-tiny with heads of 128: the width at which a block is whole (sublane, lane) tiles."""
+    return _build_trainer("llama-tiny", extra=dict(head_width=128))
+
+
+# (arena, block rows, heads of 128, whether the kernel writes): heads of 128 in blocks of whole
+# tiles are what `writes_in_kernel` takes; an int8 arena and the `-tiny` presets' heads of 16 keep
+# `paged_kv_write` in front of the kernel
+WRITE_FORMS = {
+    "bf16-kernel": ("bf16", 16, True, True),
+    "int8-xla": ("int8", 16, True, False),
+    "tiny-xla": ("bf16", 16, False, False),
+}
+
+
+@pytest.mark.parametrize("form", sorted(WRITE_FORMS))
+def test_greedy_bitwise_where_the_kernel_writes_the_step_s_kv(form, trainers, wide_trainer):
+    """A model whose heads are 128 wide over blocks of whole tiles: the decode
+    step's K and V reach the arena from the paged kernel itself (no
+    `paged_kv_write` in front of it), and the greedy streams are the gather
+    path's; `kv_kernel_writes` counts those dispatches, and stays 0 where the
+    XLA write is kept (an int8 arena, the `-tiny` presets' heads of 16)."""
+    from trlx_tpu.ops.paged_attention import writes_in_kernel
+
+    kv_dtype, blk, wide, writes = WRITE_FORMS[form]
+    tr = wide_trainer if wide else trainers["llama-tiny"]
+    kw = dict(kv_cache_dtype=kv_dtype, kv_block_size=blk, max_new=6)
+    prompts = [list(range(60, 60 + n)) for n in (blk - 4, blk, blk + 1)]  # decode steps round a block's edge
+    gather = run_serial(make_engine(tr, "xla", **kw), prompts, max_new=6)
+    eng = make_engine(tr, "interpret", **kw)
+    assert writes_in_kernel(eng._pool["layers"][0]["k"]) == writes
+    assert eng._kv_write_form() == ("kernel" if writes else "xla")
+    kernel = run_serial(eng, prompts, max_new=6)
+    if kv_dtype == "int8":  # as `test_greedy_int8_within_dequant_tolerance`
+        assert sum(a == b for a, b in zip(gather, kernel)) >= len(prompts) - 1, (gather, kernel)
+    else:
+        assert kernel == gather
+    stats = eng.kv_stats()
+    assert stats["kv_kernel_dispatches"] > 0 and stats["kv_kernel_fallbacks"] == {}
+    assert stats["kv_kernel_writes"] == (stats["kv_kernel_dispatches"] if writes else 0)
+
+
 def test_greedy_int8_within_dequant_tolerance(trainers):
     """int8 KV quantizes identically on both read paths; the tiny random
     model's greedy streams may rarely diverge at near-tie logits, the
@@ -538,8 +638,10 @@ def test_live_entry_share_counts_the_columns_held(trainers, monkeypatch):
     # which of the kernel's two fetch forms the step's K/V calls take: heads of 16 are no whole lane tile
     layers = eng.model_cfg.n_layers
     assert (counted[0][1]["calls_copied"], counted[0][1]["calls_operands"]) == (0, layers)
+    assert counted[0][1]["kv_write"] == "xla" == eng._kv_write_form()  # and so `paged_kv_write` writes the step's K/V
     monkeypatch.setattr("trlx_tpu.ops.paged_attention.copies_blocks", lambda *shape: True)
     assert (eng._kv_walk()["calls_copied"], eng._kv_walk()["calls_operands"]) == (layers, 0)
+    assert eng._kv_write_form() == "kernel"
     assert counted[1][1] == {"seq": 3, "ahead": 1, "rows": 1} and counted[2][1] == {"seq": 2}
     stats = eng.kv_stats()
     assert (stats["decode_steps_total"], stats["decode_steps_ahead_total"],

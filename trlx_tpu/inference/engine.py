@@ -1548,7 +1548,7 @@ class InferenceEngine:
         # dispatch, the device's program and the fetch that waits for it
         if tracing.active():
             if self.kv_paging:
-                tracing.counters("engine.kv_walk", **self._kv_walk())
+                tracing.counters("engine.kv_walk", **self._kv_walk(), kv_write=self._kv_write_form())
             if self._slot_state_layers:
                 tracing.counters("engine.slot_state", **self._slot_state_step())
             tracing.counters("engine.queued", seq=seq, ahead=int(ahead), rows=int(self._live.sum()))
@@ -1755,6 +1755,18 @@ class InferenceEngine:
             walk = {k: v for k, v in walk.items() if not k.startswith(("index_", "bytes_"))}
         return walk
 
+    def _kv_write_form(self) -> str:
+        """How a decode step's K and V reach the arena: `kernel`, from the paged
+        kernel itself (`ops.paged_attention.writes_in_kernel`: the engine runs
+        the kernel and every K/V layer's arena is one it copies blocks of, no
+        int8 arena), or `xla`, `paged_kv_write` in front of the read (and what a
+        model with no K/V layer says)."""
+        from trlx_tpu.ops.paged_attention import writes_in_kernel
+
+        arenas = [layer["k"] for layer in self._pool["layers"] if "k" in layer]
+        runs_kernel = self._attn_kernel is not None and self._kernel_unsupported is None
+        return "kernel" if runs_kernel and arenas and all(map(writes_in_kernel, arenas)) else "xla"
+
     def kv_stats(self) -> Dict[str, Any]:
         """Host-side paged-pool counters for metrics/healthz; {} when
         paging is off. `kv_kernel_fallbacks` is a {reason: count} dict,
@@ -1794,6 +1806,9 @@ class InferenceEngine:
                 "prefix_cache_evictions": pool.evictions,
                 "prefix_cache_idle_blocks": pool.cached_idle(),
                 "kv_kernel_dispatches": self._kv_kernel_dispatches,
+                # of those, the dispatches whose K/V layers wrote the step's keys and values
+                # from the kernel itself (all of them or none: the arena's shapes decide)
+                "kv_kernel_writes": self._kv_kernel_dispatches * (self._kv_write_form() == "kernel"),
                 "kv_kernel_fallbacks": dict(self._kv_kernel_fallbacks),
                 "kv_live_entry_share": self._live_entries() / (self.num_slots * self._n_tbl),
                 # decode steps dispatched while their predecessor's outputs

@@ -1023,6 +1023,7 @@ class Scheduler:
             self.metrics.set_counter(
                 "kv_kernel_dispatches", stats["kv_kernel_dispatches"]
             )
+            self.metrics.set_counter("kv_kernel_writes", stats["kv_kernel_writes"])
             for reason, n in sorted(stats.get("kv_kernel_fallbacks", {}).items()):
                 self.metrics.set_counter(
                     "kv_kernel_fallbacks", n, labels={"reason": reason}

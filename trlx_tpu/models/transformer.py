@@ -948,18 +948,20 @@ class Attention(nn.Module):
             idx = cache_index if jnp.ndim(cache_index) == 1 else jnp.full(
                 (b,), cache_index, jnp.int32
             )
-            new_cache = paged.paged_kv_write(layer_cache, k, v, table, idx, attn_mask)
-            new_cache["table"] = table
-            if attn_kernel is not None and attn_kernel != "prefill":
-                # Fused Pallas read side: one pass over each row's live
-                # table entries, several a grid step, fetches the blocks
-                # directly — no gathered dense copy, no materialized
-                # dequant, no kv-head repeat. The engine
-                # guarantees the shape is expressible (t == 1, no
-                # alibi/prefix bias terms) and counts a fallback to
-                # the gather path otherwise. A sliding layer hands the kernel
-                # its window: it walks the table entries that hold the last
-                # `window` columns and no others.
+            kernel = attn_kernel is not None and attn_kernel != "prefill"
+            # a decode step's one position a row reaches the arena from the kernel itself where the
+            # arena's shapes let it (`writes_in_kernel`); everything else is `paged_kv_write`'s
+            in_kernel = kernel and t == 1 and paged.writes_in_kernel(layer_cache["k"])
+            if not in_kernel:
+                new_cache = paged.paged_kv_write(layer_cache, k, v, table, idx, attn_mask)
+                new_cache["table"] = table
+            if kernel:
+                # Fused Pallas read side: one pass over each row's live table entries, several a
+                # grid step, fetches the blocks directly: no gathered dense copy, no materialized
+                # dequant, no kv-head repeat. The engine guarantees the shape is expressible
+                # (t == 1, no alibi/prefix bias terms) and counts a fallback to the gather path
+                # otherwise. A sliding layer hands the kernel its window: it walks the table
+                # entries that hold the last `window` columns and no others.
                 if t != 1:
                     raise ValueError(
                         "paged decode kernel takes single-position queries; "
@@ -970,27 +972,25 @@ class Attention(nn.Module):
                         "paged decode kernel cannot express alibi/"
                         "prefix bias terms (engine should have fallen back)"
                     )
-                # decode_bias writes exactly 0.0 on attendable columns and
-                # -1e9 elsewhere, so key validity is recoverable from the
-                # bias row without widening the call signature. A row with
-                # no token this step (a freed slot keeps its mask until the
-                # next insert) has nothing to attend with: all-masked, so
-                # the kernel walks none of its stale table.
+                # decode_bias writes exactly 0.0 on attendable columns and -1e9 elsewhere, so key
+                # validity is recoverable from the bias row without widening the call signature.
+                # A row with no token this step (a freed slot keeps its mask until the next
+                # insert) has nothing to attend with: all-masked, so the kernel walks none of its
+                # stale table, and writes nothing through it.
                 key_mask = attn_bias[:, 0, 0, :] == 0.0
                 if attn_mask is not None:
                     key_mask &= attn_mask > 0
+                arenas = layer_cache if in_kernel else new_cache
                 kernel_out = paged.paged_attention_decode(
-                    q[:, 0],
-                    new_cache["k"],
-                    new_cache["v"],
-                    table,
-                    key_mask,
-                    k_scale=new_cache.get("k_scale"),
-                    v_scale=new_cache.get("v_scale"),
-                    out_dtype=cfg.dtype,
-                    interpret=(attn_kernel == "interpret"),
-                    window=window,
+                    q[:, 0], arenas["k"], arenas["v"], table, key_mask,
+                    k_scale=arenas.get("k_scale"), v_scale=arenas.get("v_scale"),
+                    out_dtype=cfg.dtype, interpret=(attn_kernel == "interpret"), window=window,
+                    new_kv=(k[:, 0], v[:, 0]) if in_kernel else None,  # the step's K/V: the call writes them too,
+                    column=idx if in_kernel else None,  # at this column a row
                 )
+                if in_kernel:
+                    kernel_out, k_arena, v_arena = kernel_out
+                    new_cache = {"k": k_arena, "v": v_arena, "table": table}
                 return project_out(kernel_out.reshape(b, 1, nh * hd)), new_cache
             if attn_kernel != "prefill":
                 # "prefill" (cfg.flash_prefill, rows that meet no cached

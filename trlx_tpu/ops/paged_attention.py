@@ -103,18 +103,57 @@ Hence:
   head is its last two dims. With hd >= 64 (every preset but the `-tiny`
   test models) the kernel's operand is the arena as it lies; below that
   XLA inserts the layout copy, which costs time but not correctness.
-  That holds for the PROGRAM only if the write in front of the kernel
-  leaves the arena in that layout too: `paged_kv_write` scatters whole
-  blocks over the arena's major dimension for that reason (a scatter
-  into dims 0 and 2, `arena.at[phys, :, off]`, made XLA re-lay the whole
-  arena in front of the write and back in front of the kernel or the
-  gather, four arena copies a layer, in every decode step and every
-  prefill: tests/test_kernels_compile_tpu.py holds write and read in one
-  program).
+  That holds for the PROGRAM only if a write in front of the read leaves
+  the arena in that layout too: `paged_kv_write` (every write of more
+  than one position a row, and a decode step's where the kernel does not
+  write: "Write" below) scatters whole blocks over the arena's major
+  dimension for that reason (a scatter into dims 0 and 2,
+  `arena.at[phys, :, off]`, made XLA re-lay the whole arena in front of
+  the write and back in front of the kernel or the gather, four arena
+  copies a layer, in every decode step and every prefill:
+  tests/test_kernels_compile_tpu.py holds write and read in one program).
 * int8 scale planes are [n_blocks, 1, nkv*block] f32, head-major along
   the lane axis (column h*block + offset), so a block's scales are one
   lane-dense row and each head's slice is a static lane window.
 * the key mask enters as [b, n_tiles, 1, T]: one tile per lane row.
+
+Write. A decode step writes ONE position a row, and `paged_kv_write` pays
+for it by the block: a gather, a select and a scatter of the whole block
+that holds the position, a side a layer, one block after another (3.2 ms
+of pythia-1.4b's 11.9 ms decode program at 64 rows x 24 layers, 8.6 ms of
+ouro-2.6b's 37.2 at 8 rows x 192 layer calls: PERF.md section 6, PR 58).
+Where the kernel copies a tile's blocks itself (`writes_in_kernel`: "Fetch"
+above, and no int8 arena, whose scale planes take their elements one by
+one) the block that holds a row's new column is already in the scratch
+when its tile is multiplied, so the call takes the step's K and V rows
+(`new_kv`, one more pipelined operand beside `q`, [1, 2, nkv, 1, hd]) and
+their column (`column`, a scalar-prefetch operand: -1 where a row writes
+nothing) and does the write itself: in the grid step whose tile holds the
+column's entry, after the step has waited for its own copies and before the
+tile is read, it selects the row into the one (sublane, lane) tile of K and
+of V that holds it (16 rows of bfloat16, 8 of float32: a compare against an
+iota, no one-row store) and starts one copy a side of those rows, all K/V
+heads, from the scratch to the arena, which is the call's OUTPUT aliased to
+its operand (`input_output_aliases`: the program donates the arena, the
+engine's pool and a looped stack's scan carry do). The products read the
+patched scratch, so what is attended to is what `paged_kv_write` in front
+of the call would have put there, bit for bit; the copy back is waited for
+at the end of the same grid step, behind the products, before the next
+step starts the copies that overwrite that half of the scratch. The call is
+still ONE Mosaic call a layer under the same name. Hazards, and why they do
+not arise: (1) the block a row writes is its own: the block pool hands a
+block that is being written to one row only (inference/paging.py: shared
+prefix blocks are full and read-only), so the copies the step has started
+for the NEXT grid step (another row's blocks, or a later tile of the same
+row) never read rows in flight; (2) a fresh block written at offset 0 keeps
+whatever the arena held behind the new row, as it does under
+`paged_kv_write`: the mask rules; (3) a row with no live entry (a freed
+slot, `attn_mask == 0`: the call site masks it) writes nothing and its
+stale table is never read, and a row writes only if its walk holds the
+column's entry (the query's own column is the row's last attendable one in
+the engine's mask; with a window it lies in the band). tests/
+test_paged_attention.py holds all of that against `paged_kv_write` in
+front of the reference, every block of both arenas bit for bit.
 
 Window. A sliding layer's call names its `window` (static): the mask loses
 the columns that trail a row's last one by `window` or more (`band_mask`),
@@ -348,7 +387,15 @@ def paged_kv_write(
     block pool handing a block that is being written to one row only
     (inference/paging.py: shared prefix blocks are full and read-only).
     A scale plane takes its t x nkv elements one by one through the flat
-    view: patching its [1, nkv*block] rows makes XLA re-lay the plane."""
+    view: patching its [1, nkv*block] rows makes XLA re-lay the plane.
+
+    Who calls it (`models/transformer.py:Attention`): every prefill and
+    insert and speculative verify (t > 1: whole blocks are written and the
+    scatter is the right tool), every step of the gather read path, and a
+    decode step (t == 1) in front of the kernel where the kernel does not
+    write: int8 arenas and their planes, and arenas whose blocks are
+    operands (heads of 64, the `-tiny` models). Elsewhere a decode step's
+    write is `paged_attention_decode`'s own (module docstring, "Write")."""
     n_blocks, nkv, blk, _ = layer["k"].shape
     b, t = k.shape[:2]
     n_tbl = table.shape[1]
@@ -443,6 +490,14 @@ def copies_blocks(nkv: int, blk: int, hd: int, dtype) -> bool:
     return whole and (jnp.dtype(dtype) != jnp.int8 or (nkv * blk) % 128 == 0)  # its planes: [1, nkv*blk] rows
 
 
+def writes_in_kernel(arena) -> bool:
+    """Whether a decode step's K and V reach this arena from `paged_attention_decode` itself
+    (module docstring, "Write"): where the kernel copies a tile's blocks, and not into an
+    int8 arena, whose scale planes take their elements one by one (`paged_kv_write`)."""
+    _, nkv, blk, hd = arena.shape
+    return arena.dtype != jnp.int8 and copies_blocks(nkv, blk, hd, arena.dtype)
+
+
 def _tile_entries(n_tbl: int, nkv: int, blk: int, hd: int, dtype) -> int:
     """Table entries a grid step takes: `_TILE`'s tokens' worth, at most its
     entries and the whole table, shrunk until K and V tiles, double-buffered,
@@ -455,14 +510,18 @@ def _tile_entries(n_tbl: int, nkv: int, blk: int, hd: int, dtype) -> int:
 
 
 def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest, entries: int, n_tbl: Optional[int],
-                         scale: float, quantized: bool, p_dtype, windowed: bool = False):
+                         scale: float, quantized: bool, p_dtype, windowed: bool = False, writes: bool = False):
     """One grid step: tile `tile_ref[w]` of row `row_ref[w]`; mask invalid
     columns and fold the tile into the row's online softmax, all kv heads in
     one batched product. `n_tbl` names how the tile's blocks reach VMEM
     (module docstring, "Fetch"): the table's length, and the step starts the
     copies of step w + 1's live blocks into the other half of a scratch and
     waits for its own; or None, and each entry's block is an operand that
-    the pipeline fetched.
+    the pipeline fetched. `writes` (a call that copies, no int8 arena): the
+    step whose tile holds the row's new column selects the step's K and V
+    row into that entry's blocks in the scratch before the tile is
+    multiplied, and copies the patched rows back to the arena (module
+    docstring, "Write").
 
     blocks_ref scalar prefetch: the table, [b * n_tbl], that the copies read;
                or [n_steps * entries], what drives the operands' index maps
@@ -470,15 +529,18 @@ def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest, entri
     n_live_ref scalar prefetch [b]: each row's live table entries
     n_first_ref scalar prefetch [b], a windowed call that copies: each row's
                entries in front of its band (not copied, like those past `n_live`)
+    col_ref    scalar prefetch [b], `writes`: the column a row's new K/V lands at, -1 for none
     q_ref      [1, nkv, group, hd]
+    new_ref    [1, 2, nkv, 1, hd], `writes`: the row's new K and V in the arena's type
     K, V       the arenas where they lie, [n_blocks, nkv, blk, hd], and for
                int8 their scale planes, [n_blocks, 1, nkv*blk] f32;
                or `entries` x [1, nkv, blk, hd] each (and `entries` x [1, 1, nkv*blk])
     mask_ref   [1, 1, 1, entries*blk] int32 key validity of the tile
     o_ref      [1, nkv, group, hd]
+    k_out, v_out  `writes`: the arenas again, the call's aliased outputs, which the write goes to
     kv_buf     VMEM [2 buffers, 2 sides, entries, nkv, blk, hd], sc_buf VMEM
                [2, 2, entries, 1, nkv*blk] f32 (int8), sem: a DMA semaphore a
-               buffer; a call that copies only
+               buffer; a call that copies only. wsem, `writes`: the write's semaphore
     m_scr/l_scr VMEM [nkv, group, 1] f32 running max / denominator,
                acc_scr VMEM [nkv, group, hd] numerator
     """
@@ -487,15 +549,22 @@ def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest, entri
 
     E = entries
     copied = n_tbl is not None
-    n_first_ref = None
+    n_first_ref = col_ref = new_ref = None
     if windowed and copied:
         n_first_ref, rest = rest[0], rest[1:]
+    if writes:
+        col_ref, rest = rest[0], rest[1:]
     q_ref, rest = rest[0], rest[1:]
+    if writes:
+        new_ref, rest = rest[0], rest[1:]
     per = 1 if copied else E  # refs a plane: the plane where it lies, or an operand an entry
     n_planes = 4 if quantized else 2  # K, V, then an int8 arena's scale planes of K and of V
     planes = [rest[i * per:(i + 1) * per] for i in range(n_planes)]
     mask_ref, o_ref, *bufs, m_scr, l_scr, acc_scr = rest[n_planes * per:]
-    if copied:
+    if writes:
+        *arenas_out, kv_buf, sem, wsem = bufs
+        bufs = [kv_buf]
+    elif copied:
         *bufs, sem = bufs  # kv_buf, then an int8 arena's sc_buf
     w = pl.program_id(0)
     r = row_ref[w]
@@ -555,6 +624,32 @@ def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest, entri
             for h in range(nkv)
         ])
 
+    if writes:
+        # the row's new column, if it lands in this tile (its last one, where the engine calls:
+        # the query's own column is the row's last attendable one): entry `at` of the tile,
+        # row `off` of that block, inside the slab of `slab` rows (one sublane tile of the type)
+        col = col_ref[r]
+        at = jnp.maximum(col, 0) // blk - first_entry
+        lands = (col >= 0) & (at >= 0) & (at < E)
+        slab = _sublanes(kv_buf.dtype)
+
+        def write_back(start: bool):
+            """Select the step's K and V row into its slab of the scratch and start the slabs' copy
+            to the arena, or wait for that copy."""
+            off = jnp.maximum(col, 0) % blk
+            rows = pl.ds(pl.multiple_of(off // slab * slab, slab), slab)
+            place = jnp.clip(at, 0, E - 1)
+            block = blocks_ref[r * n_tbl + first_entry + place] if start else 0
+            for side, arena in enumerate(arenas_out):
+                held = kv_buf.at[buf, side, place, :, rows, :]  # [nkv, slab, hd]
+                if start:
+                    here = jax.lax.broadcasted_iota(jnp.int32, held.shape, 1) == off % slab
+                    held[...] = jnp.where(here, new_ref[0, side], held[...])
+                copy = pltpu.make_async_copy(held, arena.at[block, :, rows, :], wsem)
+                copy.start() if start else copy.wait()
+
+        pl.when(lands)(lambda: write_back(True))
+
     @pl.when((w == 0) | (row_ref[jnp.maximum(w - 1, 0)] != r))  # the row's first tile
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
@@ -608,6 +703,10 @@ def _paged_decode_kernel(blocks_ref, row_ref, tile_ref, n_live_ref, *rest, entri
         l = l_scr[:]
         denom = jnp.where(l > 0, l, 1.0)
         o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
+
+    if writes:
+        # behind the products, which hid it: the next step starts step w + 2's copies into this half
+        pl.when(lands)(lambda: write_back(False))
 
 
 def band_mask(key_mask, window: int):
@@ -703,12 +802,41 @@ def paged_attention_decode(
     out_dtype=None,
     interpret: bool = False,
     window: Optional[int] = None,
-) -> jnp.ndarray:
+    new_kv: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,  # the step's K and V, [b, nkv, hd] each
+    column: Optional[jnp.ndarray] = None,  # [b] the logical column they land at
+):
     """Fused paged decode attention. Returns [b, nh, hd] in `out_dtype`
     (defaults to q's dtype). Grid, tile and schedule: module docstring.
     `window` (static, a layer's own) keeps a row's last `window` columns
     (`band_mask`) and walks only the table entries that hold one of them;
-    such a call is named `paged_decode_window` in the device trace."""
+    such a call is named `paged_decode_window` in the device trace.
+
+    With `new_kv` and `column` the call WRITES the step's keys and values
+    too, in place of a `paged_kv_write` in front of it (module docstring,
+    "Write"; `writes_in_kernel` says for which arenas): row r's `new_kv`
+    lands at `column[r]` and is attended to in the same call, and the
+    return is (output, k_arena, v_arena), the arenas aliased to the
+    operands. A row writes if its walk holds the table entry of `column[r]`:
+    if `key_mask` attends to a column of that entry or of a later one (the
+    engine's mask does: the query's own column is the row's last, unless the
+    row has no token this step and is all-masked, and then it writes nothing,
+    as `paged_kv_write` drops a position that is not valid).
+
+    The call itself is one jitted function (`_paged_decode_call`): the layers
+    of a program share its shapes, so a program traces and lowers the walk
+    and the kernel's body once, not once a layer (tracing is most of a warm
+    set-up: PERF.md section 5)."""
+    # (not under the TPU interpreter, `interpret=pltpu.InterpretParams(...)`: its callbacks run JAX
+    # operations of their own, which deadlock with the caller's next one behind a jit's asynchronous dispatch)
+    call = _paged_decode_call if isinstance(interpret, bool) else _paged_decode_call.__wrapped__
+    return call(q, k_arena, v_arena, table, key_mask, k_scale, v_scale, new_kv, column,
+                out_dtype=jnp.dtype(out_dtype or q.dtype), interpret=interpret, window=window)
+
+
+@functools.partial(jax.jit, static_argnames=("out_dtype", "interpret", "window"))
+def _paged_decode_call(q, k_arena, v_arena, table, key_mask, k_scale, v_scale, new_kv, column, *,
+                       out_dtype, interpret, window):
+    """`paged_attention_decode`, its options static."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -721,7 +849,6 @@ def paged_attention_decode(
     quantized = k_arena.dtype == jnp.int8
     if quantized and (k_scale is None or v_scale is None):
         raise ValueError("int8 arenas require k_scale/v_scale planes")
-    out_dtype = jnp.dtype(out_dtype or q.dtype)
 
     # The products' operand types. K and V enter in the arena's own type
     # (int8 converts exactly) unless q or the output is wider; a bfloat16
@@ -734,6 +861,10 @@ def paged_attention_decode(
     p_dtype = jnp.float32 if kv_dtype == jnp.float32 else jnp.promote_types(out_dtype, kv_dtype)
 
     copied = copies_blocks(nkv, blk, hd, k_arena.dtype)
+    writes = new_kv is not None
+    if writes and not writes_in_kernel(k_arena):
+        raise ValueError(f"the paged kernel does not write into a {k_arena.dtype} arena of {k_arena.shape[1:]} "
+                         "blocks: `paged_kv_write` in front of the call does")
     E = _tile_entries(n_tbl, nkv, blk, hd, k_arena.dtype)
     windowed = window is not None
     if windowed:
@@ -760,6 +891,12 @@ def paged_attention_decode(
         walk = _live_walk(table, key_mask, blk, E, windowed)
         row, tile, n_live, n_work, n_tiles = walk[:5]
         scalars = [table.astype(jnp.int32).reshape(-1), row, tile, n_live] + ([walk.n_first] if windowed else [])
+        if writes:
+            # a row writes where its walk copies the entry that holds the column: a live entry names a block
+            column = column.astype(jnp.int32)
+            entry = column // blk
+            walked = (column >= 0) & (entry >= (walk.n_first if windowed else 0)) & (entry < n_live)
+            scalars.append(jnp.where(walked, column, -1))
         operands = planes
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * len(planes)
         scratch = [pltpu.VMEM((2, 2, E) + plane.shape[1:], plane.dtype) for plane in planes[::2]]
@@ -776,11 +913,22 @@ def paged_attention_decode(
     mask_spec = pl.BlockSpec(
         (1, 1, 1, T), lambda w, blocks_ref, row_ref, tile_ref, *_: (row_ref[w], tile_ref[w], 0, 0))
 
+    q_specs, out_specs = [pl.BlockSpec((1, nkv, group, hd), slot_index)], pl.BlockSpec((1, nkv, group, hd), slot_index)
+    out_shape, aliases, rows = jax.ShapeDtypeStruct((b, nkv, group, hd), out_dtype), {}, [qg]
+    if writes:
+        # the step's rows ride beside q, one operand for K and V; the arenas come back as
+        # outputs aliased to their operands (counted with the scalars: q, the rows, K, V)
+        rows.append(jnp.stack(new_kv, axis=1).astype(k_arena.dtype).reshape(b, 2, nkv, 1, hd))
+        q_specs.append(pl.BlockSpec((1, 2, nkv, 1, hd), lambda w, blocks_ref, row_ref, *_: (row_ref[w], 0, 0, 0, 0)))
+        out_specs = [out_specs] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        out_shape = [out_shape] + [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (k_arena, v_arena)]
+        aliases = {len(scalars) + 2: 1, len(scalars) + 3: 2}
+        scratch.append(pltpu.SemaphoreType.DMA(()))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(n_work,),  # the steps that hold work, read at run time
-        in_specs=[pl.BlockSpec((1, nkv, group, hd), slot_index)] + kv_specs + [mask_spec],
-        out_specs=pl.BlockSpec((1, nkv, group, hd), slot_index),
+        in_specs=q_specs + kv_specs + [mask_spec],
+        out_specs=out_specs,
         scratch_shapes=scratch + [
             pltpu.VMEM((nkv, group, 1), jnp.float32),   # m
             pltpu.VMEM((nkv, group, 1), jnp.float32),   # l
@@ -790,16 +938,19 @@ def paged_attention_decode(
     out = pl.pallas_call(
         functools.partial(
             _paged_decode_kernel, entries=E, n_tbl=n_tbl if copied else None, scale=1.0 / np.sqrt(hd),
-            quantized=quantized, p_dtype=p_dtype, windowed=windowed,
+            quantized=quantized, p_dtype=p_dtype, windowed=windowed, writes=writes,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nkv, group, hd), out_dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT_BYTES
         ),
         interpret=interpret,
         name="paged_decode_window" if windowed else "paged_decode",
-    )(*scalars, qg, *operands, maskh)
+    )(*scalars, *rows, *operands, maskh)
+    if writes:
+        return out[0].reshape(b, nh, hd), out[1], out[2]
     return out.reshape(b, nh, hd)
 
 

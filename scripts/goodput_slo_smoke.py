@@ -148,10 +148,6 @@ def check_goodput(artifact_dir: str) -> str:
     cycles = snap["samples_total"] / n_rollouts
     tokens_per_sample = snap["tokens_total"] / max(snap["samples_total"], 1)
     n_prompt = int(round(tokens_per_sample)) - MAX_NEW
-    spec_k = trainer._spec_k_effective()
-    rounds = int(getattr(trainer, "spec_decode_rounds", 0))
-    accepted = int(getattr(trainer, "spec_decode_accepted", 0))
-    accept = accepted / (spec_k * rounds) if rounds and spec_k else 0.0
     fc = flops_per_cycle(
         trainer.model_cfg, n_prompt, MAX_NEW, n_rollouts,
         config.method.ppo_epochs,
@@ -159,8 +155,6 @@ def check_goodput(artifact_dir: str) -> str:
         window_ok=(trainer._window_loss_ok()
                    and getattr(trainer.model_cfg, "moe_experts", 0) == 0),
         fast_path=False,
-        spec_k=spec_k, spec_accept=accept,
-        spec_rank=int(getattr(trainer.config.method, "spec_draft_rank", 64)),
     )
     offline_flops = fc["total"] * cycles
     live_flops = snap["flops_total"]

@@ -341,7 +341,6 @@ class _FakeEngine:
     max_prefill_batch = 4
     kv_paging = False
     multi_tenant = True
-    spec_k = 0
 
     def blocks_available(self):
         return 0

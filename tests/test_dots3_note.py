@@ -544,7 +544,6 @@ REFUSED = [
     ("prefix_cache over a latent cache", dict(kv_paging=True, prefix_cache=True)),
     ("dense slot pool .* over a latent cache", dict()),
     ("int8 arena .* over a latent cache", dict(kv_paging=True, kv_cache_dtype="int8")),
-    ("MoE|speculative decode over a latent cache", dict(kv_paging=True, spec_k=2, spec_split=2)),
 ]
 
 
@@ -557,7 +556,7 @@ def test_paths_that_cannot_follow_refuse_by_name(match, engine_kw):
     gen_cfg = GenerationConfig(max_new_tokens=4, eos_token_id=VOCAB + 1)
     with pytest.raises(NotImplementedError, match=match) as refusal:
         InferenceEngine(CausalLMPolicy(cfg), cfg, None, gen_cfg, num_slots=2, max_prompt_len=8, **engine_kw)
-    assert f"{FULL} / {SLIDING} layers" in str(refusal.value) or "MoE" in str(refusal.value)
+    assert f"{FULL} / {SLIDING} layers" in str(refusal.value)
 
 
 def test_sessions_a_shared_prompt_and_an_int8_plane_refuse_by_name():
@@ -570,9 +569,6 @@ def test_sessions_a_shared_prompt_and_an_int8_plane_refuse_by_name():
     with pytest.raises(NotImplementedError, match="sessions .* over a latent cache"):
         engine.enable_sessions()
     dense_ffn = dataclasses.replace(cfg, moe_experts=0, moe_router="softmax", moe_shared_d_ff=0, moe_local_experts=0)
-    with pytest.raises(NotImplementedError, match="speculative decode over a latent cache"):
-        InferenceEngine(CausalLMPolicy(dense_ffn), dense_ffn, None, gen_cfg, num_slots=2, max_prompt_len=8,
-                        kv_paging=True, spec_k=2, spec_split=2)
     with pytest.raises(NotImplementedError, match="int8 latent arena"):
         init_paged_kv_arena(cfg, 4, 4, jnp.int8)
     with pytest.raises(NotImplementedError, match=f"{FULL} / {SLIDING} layers with lora_rank"):

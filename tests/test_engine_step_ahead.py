@@ -147,8 +147,6 @@ MODES = {
     "paged": dict(kv_paging=True, kv_block_size=8),
     "paged-kernel": dict(kv_paging=True, kv_block_size=8, decode_kernel="interpret"),
     "paged-prefix": dict(kv_paging=True, kv_block_size=8, prefix_cache=True),
-    "spec-dense": dict(spec_k=2, spec_split=1),
-    "spec-paged": dict(spec_k=2, spec_split=1, kv_paging=True, kv_block_size=8),
 }
 
 
@@ -172,6 +170,41 @@ def test_scheduler_outputs_are_fresh_batch_generate(trainer, mode):
     if engine.kv_paging:
         assert sched.metrics.get("decode_steps_ahead_total") == engine._steps_ahead
         assert engine.kv_stats()["kv_blocks_used"] == 0
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+def test_scheduler_hands_a_request_exactly_the_engines_step(trainer, mode):
+    """What a request is handed is the step's `[P]` output at its slot: one
+    token and its log-probability a step whose `valid` bit is set there, none
+    a step whose bit is 0 (a row admitted behind the step in flight)."""
+    engine = make_engine(trainer, num_slots=2, max_new=6, **MODES[mode])
+    sched = Scheduler(engine, max_wait_s=0.0)
+    sched._running = True  # the loop's turns are taken by hand below
+    step, steps = engine.step, []
+
+    def logged_step():
+        steps.append((step(), dict(sched._slot_req)))
+        return steps[-1][0]
+
+    engine.step = logged_step
+    reqs = [sched.submit(prompt(60 + i, n), m) for i, (n, m) in enumerate(((5, 6), (37, 3), (12, 5), (29, 2)))]
+    handed = {id(r): ([], []) for r in reqs}
+    silent = 0
+    while any(r.finish_reason is None for r in reqs):
+        before = {id(r): len(r.token_ids) for r in reqs}
+        turn(sched)
+        (tokens, logprobs, valid, _), held = steps.pop()
+        assert not steps and tokens.shape == logprobs.shape == valid.shape == (2,)
+        for slot, req in held.items():
+            assert len(req.token_ids) - before[id(req)] == int(valid[slot])
+            if valid[slot]:
+                handed[id(req)][0].append(int(tokens[slot]))
+                handed[id(req)][1].append(float(logprobs[slot]))
+            silent += not valid[slot]
+    sched.stop()
+    assert silent > 0  # two of the four went into a slot another freed
+    for r in reqs:
+        assert (r.token_ids, r.token_logprobs) == handed[id(r)] and len(r.token_ids) == r.max_new_tokens
 
 
 def test_multi_tenant_outputs_are_fresh_batch_generate(lora_trainer, tmp_path, monkeypatch):

@@ -425,7 +425,6 @@ def test_the_prefill_state_counter_span_says_what_the_chunked_form_runs(policy, 
 KEPT = "ssm_attention layers keep state, tails a slot"
 REFUSALS = [
     ("prefix_cache", dict(prefix_cache=True), f"prefix_cache over slot state .{KEPT}"),
-    ("speculative_decode", dict(spec_k=2, spec_split=1), f"speculative decode over slot state .{KEPT}"),
     ("dense_slot_pool", dict(kv_paging=False), f"dense slot pool .* over slot state .{KEPT}"),
     ("int8_arena", dict(kv_cache_dtype="int8"), f"int8 arena .* over slot state .{KEPT}"),
 ]
@@ -440,16 +439,13 @@ def test_what_cannot_follow_slot_state_refuses_a_layer_that_also_keeps_planes_by
                         **{"kv_paging": True, **kw})
 
 
-def test_sessions_submit_n_the_sampler_s_drafts_and_a_slotless_pool_refuse_by_name(engines):
+def test_sessions_submit_n_and_a_slotless_pool_refuse_by_name(engines):
     engine = engines["xla"]
     with pytest.raises(NotImplementedError, match=f"sessions .* over slot state .{KEPT}"):
         engine.enable_sessions()
     with pytest.raises(NotImplementedError, match=f"submit_n's shared prompt over slot state .{KEPT}"):
         Scheduler(engine).submit_n(np.arange(1, 5, dtype=np.int32), 2, max_new_tokens=4)
     cfg = engine.model_cfg
-    gen_cfg = GenerationConfig(max_new_tokens=4, eos_token_id=VOCAB + 1)
-    with pytest.raises(NotImplementedError, match=f"speculative decode over slot state .{KEPT}"):
-        make_generate_fn(CausalLMWithValueHead(cfg), cfg, gen_cfg, spec_k=2, spec_split=1)
     from trlx_tpu.models.transformer import init_paged_kv_arena
     with pytest.raises(NotImplementedError, match=f"a paged pool over slot state .{KEPT}. needs its number of slots"):
         init_paged_kv_arena(cfg, 8, 4)

@@ -121,13 +121,13 @@ def _row_cache(cache):
             **{k: cache[k] for k in ("mask", "pos", "layers")}}
 
 
-def _same_cache(rows, scalar, same=_same):
+def _same_cache(rows, scalar):
     _same(rows["mask"], scalar["mask"])
     _same(rows["pos"], scalar["pos"])
     _same(rows["row_index"], jnp.full_like(rows["row_index"], scalar["index"]))
     for got, want in zip(rows["layers"], scalar["layers"]):
         for name in want:
-            same(got[name], want[name])
+            _same(got[name], want[name])
 
 
 @pytest.mark.parametrize("t", [1, 4], ids=["t1", "t4"])
@@ -150,72 +150,19 @@ def test_per_row_step_is_the_scalar_step_on_an_aligned_batch(lms, kind, t):
     _same_cache(got_cache, new_cache)
 
 
-@pytest.mark.parametrize("split", [1, 2, 3])
-@pytest.mark.parametrize("kind", ["dense"])
-def test_draft_steps_then_one_verify_pass_are_the_full_steps(lms, kind, split):
-    """Speculative decode's use of the seam: k per-row steps of blocks
-    [0, split), then blocks [split, n) over all k positions at once from the
-    captured states, give the logits of k full per-row steps."""
-    cfg, model, params, tokens, _ = lms[kind]
-    k = 3
-    ones = jnp.ones((B, T), jnp.int32)
-    _, _, cache = _step(model, params, tokens, init_kv_cache(cfg, B, T + k), ones, True)
-    start = _row_cache(cache)
-    fed = tokens[:, :k]
-    full, want = start, []
-    for j in range(k):
-        logits, _, full = _step(model, params, fed[:, j:j + 1], full, ones[:, :1])
-        want.append(logits)
-    draft, rows = start, []
-    for j in range(k):
-        none, h_norm, draft, h = _step(model, params, fed[:, j:j + 1], draft, ones[:, :1],
-                                       stop=split, capture_split=split)
-        assert none is None and h_norm.shape == h.shape
-        rows.append(h)
-    for kept, moved in zip(draft["layers"][split:], start["layers"][split:]):
-        _same(kept["k"], moved["k"])  # the suffix layers' caches pass through a draft step
-    positions = start["pos"][:, None] + jnp.arange(k)[None, :]
-    got, _, verified = _step(model, params, jnp.concatenate(rows, axis=1), draft, None,
-                             start=split, block_start=start["row_index"], positions=positions)
-    # one pass over k positions multiplies in another order than k passes over one
-    close = lambda a, b: np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)  # noqa: E731
-    close(got, jnp.concatenate(want, axis=1))
-    _same_cache(verified, {**full, "index": full["row_index"][0]}, same=close)
-
-
-PER_ROW_USES = {
-    "decode": dict(t=1),
-    "prefill": dict(t=4),
-    "draft": dict(t=1, stop=2, capture_split=2),
-    "verify": dict(t=2, start=2, verify=True),
-}
-
-
-@pytest.mark.parametrize("use", PER_ROW_USES)
-def test_conv_state_goes_through_the_per_row_branch_but_for_speculative_decode(lms, use):
+@pytest.mark.parametrize("t", [1, 4], ids=["decode", "prefill"])
+def test_conv_state_goes_through_the_per_row_branch(lms, t):
     """A per-row cache carries a `conv` layer's state as the scalar one does
-    (the engine's pool holds it a slot); a draft step and a verify pass are
-    refused by name, because a mask bit does not roll a state back."""
+    (the engine's pool holds it a slot)."""
     cfg, model, params, tokens, _ = lms["lfm2"]
-    spec = dict(PER_ROW_USES[use])
-    t, verify = spec.pop("t"), spec.pop("verify", False)
     ones = jnp.ones((B, t), jnp.int32)
     scalar = init_kv_cache(cfg, B, T)
-    x = jnp.zeros((B, t, cfg.d_model), jnp.float32) if spec.get("start") else tokens[:, :t]
-    if verify:
-        spec.update(block_start=jnp.zeros((B,), jnp.int32), positions=jnp.zeros((B, t), jnp.int32))
-    if use in ("draft", "verify"):
-        with pytest.raises(NotImplementedError, match="speculative decode .* over slot state"):
-            _step(model, params, x, _row_cache(scalar), ones, **spec)
-    else:
-        want = _step(model, params, x, scalar, ones, t > 1)
-        got = _step(model, params, x, _row_cache(scalar), ones)
-        _same(got[0], want[0])
-        _same_cache(got[2], want[2])
-    # the scalar cache carries the convolution state through the same ranges of blocks
-    if not verify:
-        out = _step(model, params, x, scalar, ones, t > 1, **spec)
-        assert sorted(out[2]["layers"][0]) == ["conv"]
+    x = tokens[:, :t]
+    want = _step(model, params, x, scalar, ones, t > 1)
+    got = _step(model, params, x, _row_cache(scalar), ones)
+    _same(got[0], want[0])
+    _same_cache(got[2], want[2])
+    assert sorted(want[2]["layers"][0]) == ["conv"]
 
 
 def test_policy_rules_sit_once_each(lms):

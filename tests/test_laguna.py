@@ -442,8 +442,6 @@ def test_paths_that_cannot_follow_refuse_by_name():
         build(kv_paging=True, prefix_cache=True)
     with pytest.raises(NotImplementedError, match="dense slot pool"):
         build()
-    with pytest.raises(NotImplementedError, match="MoE"):
-        build(kv_paging=True, spec_k=2, spec_split=2)
     with pytest.raises(NotImplementedError, match="sessions .* over attention layers of several kinds"):
         build(kv_paging=True).enable_sessions()
     from trlx_tpu.ops.attention import flash_attention

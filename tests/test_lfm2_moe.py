@@ -284,7 +284,7 @@ def test_the_per_row_paths_carry_the_convolution_state_and_refuse_what_cannot_fo
     refusals over slot state: tests/test_ling_flash.py, both presets)."""
     from trlx_tpu.inference import InferenceEngine
     from trlx_tpu.models import CausalLMPolicy
-    from trlx_tpu.ops.sampling import GenerationConfig, make_generate_fn
+    from trlx_tpu.ops.sampling import GenerationConfig
 
     cfg = tiny_cfg()
     gen_cfg = GenerationConfig(max_new_tokens=4, eos_token_id=VOCAB + 1)
@@ -296,14 +296,6 @@ def test_the_per_row_paths_carry_the_convolution_state_and_refuse_what_cannot_fo
         InferenceEngine(CausalLMPolicy(cfg), cfg, None, gen_cfg, num_slots=2, max_prompt_len=8)
     with pytest.raises(NotImplementedError, match="needs its number of slots"):
         init_paged_kv_arena(cfg, 4, 8)
-    with pytest.raises(NotImplementedError, match="speculative decode over slot state .conv layers keep conv a slot"):
-        make_generate_fn(CausalLMWithValueHead(cfg), cfg, gen_cfg, spec_k=2, spec_split=4,
-                         spec_draft_head=(jnp.zeros((64, 4)), jnp.zeros((4, VOCAB))))
-    # experts without a convolution state are still refused for what they are
-    moe_only = tiny_cfg(layer_types=("attention",) * 6)
-    with pytest.raises(NotImplementedError, match="MoE"):
-        make_generate_fn(CausalLMWithValueHead(moe_only), moe_only, gen_cfg, spec_k=2, spec_split=4,
-                         spec_draft_head=(jnp.zeros((64, 4)), jnp.zeros((4, VOCAB))))
 
 
 @pytest.mark.parametrize("path", ["interpret", "xla"])

@@ -40,7 +40,7 @@ from trlx_tpu.inference import InferenceEngine, Scheduler  # noqa: E402
 from trlx_tpu.models import CausalLMPolicy, CausalLMWithValueHead, config_from_preset  # noqa: E402
 from trlx_tpu.models import hf_interop  # noqa: E402
 from trlx_tpu.models.transformer import (  # noqa: E402
-    PRESETS, LayerKeeps, SparseMoE, TransformerLM, init_kv_cache, init_paged_kv_arena)
+    PRESETS, LayerKeeps, SparseMoE, init_kv_cache, init_paged_kv_arena)
 from trlx_tpu.observability import flops, hbm  # noqa: E402
 from trlx_tpu.ops.sampling import GenerationConfig, make_generate_fn  # noqa: E402
 from trlx_tpu.ops import linear_attention  # noqa: E402
@@ -370,7 +370,6 @@ def test_the_eight_shares_add_up_to_the_uncut_layer_with_the_shared_expert_count
 
 REFUSALS = [
     ("prefix_cache", dict(prefix_cache=True), "prefix_cache over slot state"),
-    ("speculative_decode", dict(spec_k=2, spec_split=1), "MoE|speculative decode over slot state"),
     ("dense_slot_pool", dict(kv_paging=False), "dense slot pool .* over slot state"),
     ("int8_arena", dict(kv_cache_dtype="int8"), "int8 arena .* over slot state"),
 ]
@@ -388,7 +387,7 @@ def test_what_cannot_follow_slot_state_refuses_by_name(preset, name, kw, match):
                         **{"kv_paging": True, **kw})
 
 
-def test_sessions_submit_n_and_the_speculative_steps_refuse_slot_state_by_name(policy):
+def test_sessions_and_submit_n_refuse_slot_state_by_name(policy):
     cfg, params = policy
     engine = make_engine(cfg, params, 2, 4)
     with pytest.raises(NotImplementedError, match="sessions .* over slot state"):
@@ -400,17 +399,6 @@ def test_sessions_submit_n_and_the_speculative_steps_refuse_slot_state_by_name(p
         init_paged_kv_arena(cfg, 4, 8)
     with pytest.raises(NotImplementedError, match="floating cache type"):
         init_paged_kv_arena(cfg, 4, 8, jnp.int8, num_slots=2)
-    gen_cfg = GenerationConfig(max_new_tokens=4, eos_token_id=VOCAB + 1)
-    with pytest.raises(NotImplementedError, match="speculative decode over slot state .linear_attention layers keep state, tails"):
-        make_generate_fn(CausalLMWithValueHead(cfg), cfg, gen_cfg, spec_k=2, spec_split=1,
-                         spec_draft_head=(jnp.zeros((64, 4)), jnp.zeros((4, VOCAB))))
-    # a verify pass of the model's own cached step: nothing rolls a recurrence back
-    cache = {"layers": init_paged_kv_arena(cfg, 4, 8, num_slots=2), "mask": jnp.zeros((2, 16), jnp.int32),
-             "pos": jnp.zeros((2,), jnp.int32), "row_index": jnp.zeros((2,), jnp.int32)}
-    with pytest.raises(NotImplementedError, match="speculative decode .* over slot state"):
-        TransformerLM(cfg).apply({"params": params["lm"]}, jnp.zeros((2, 2), jnp.int32), cache,
-                                 jnp.ones((2, 2), jnp.int32), method=TransformerLM.decode_step,
-                                 positions=jnp.zeros((2, 2), jnp.int32), block_start=jnp.zeros((2,), jnp.int32))
     linear_only = dict(layer_types=("linear_attention",) * 3)
     for bad, match in ((dict(lora_rank=4, moe_experts=0, moe_router="softmax", moe_shared_d_ff=0, moe_routed_scale=1.0,
                              moe_local_experts=0, moe_n_group=0, moe_topk_group=0, **linear_only),

@@ -423,10 +423,9 @@ def test_what_assumes_one_pass_is_refused_by_name(lm_params):
             jax.eval_shape(lambda p: lm.apply({"params": p}, tokens, mask, method=TransformerLM.forward, **kw),
                            lm_params)
     cache = {**init_kv_cache(cfg, 2, 16), "row_index": jnp.zeros((2,), jnp.int32)}
-    for kw in (dict(stop=1), dict(capture_split=1), dict(positions=jnp.zeros((2, 8), jnp.int32))):
-        with pytest.raises(NotImplementedError, match="a looped stack .* runs whole: a cached step .*speculative"):
-            jax.eval_shape(lambda p: lm.apply({"params": p}, tokens, cache, mask,
-                                              method=TransformerLM.decode_step, **kw), lm_params)
+    with pytest.raises(NotImplementedError, match="a looped stack .* runs whole: a cached step capturing"):
+        jax.eval_shape(lambda p: lm.apply({"params": p}, tokens, cache, mask,
+                                          method=TransformerLM.decode_step, capture_split=1), lm_params)
     with pytest.raises(ValueError, match="exit_pdf=True needs a looped stack"):
         once = TransformerLM(tiny_cfg(loop_steps=1, loop_gate=False))
         jax.eval_shape(lambda p: once.apply({"params": p}, tokens, mask, method=TransformerLM.forward,
@@ -434,8 +433,6 @@ def test_what_assumes_one_pass_is_refused_by_name(lm_params):
     gen_cfg = GenerationConfig(max_new_tokens=4, do_sample=True, eos_token_id=VOCAB + 1, pad_token_id=0)
     engine = lambda **kw: InferenceEngine(CausalLMPolicy(cfg), cfg, {"lm": lm_params}, gen_cfg, num_slots=2,
                                           max_prompt_len=16, **kw)
-    with pytest.raises(NotImplementedError, match="speculative decode over a looped stack"):
-        engine(kv_paging=True, spec_k=2, spec_split=1)
     with pytest.raises(NotImplementedError, match=r"the dense slot pool \(kv_paging=False\) over a looped stack"):
         engine()
     with pytest.raises(NotImplementedError, match="prefix_cache over a looped stack"):

@@ -446,28 +446,8 @@ def test_submit_rejects_request_that_can_never_fit(trainer):
 
 
 # ----------------------------------------------------------------------
-# Composition: spec decode, hot swap, engine validation
+# Composition: hot swap, engine validation
 # ----------------------------------------------------------------------
-
-def test_paged_spec_decode_matches_fixed_spec(trainer):
-    """Speculative decode rides the paged block tables: outputs must be
-    identical to the fixed-slot spec engine on the same requests."""
-    rng = np.random.RandomState(9)
-    prompts = [rng.randint(0, 255, size=n).tolist() for n in (5, 21, 34)]
-    max_news = [6, 5, 6]
-    outs = {}
-    for label, kw in (
-        ("fixed", {}),
-        ("paged", dict(kv_paging=True, kv_block_size=16)),
-    ):
-        engine = make_engine(trainer, num_slots=2, max_new=6,
-                             spec_k=2, spec_split=1, **kw)
-        reqs, _ = run_requests(engine, prompts, max_news)
-        outs[label] = [r.token_ids for r in reqs]
-        for r in reqs:
-            assert r.finish_reason in ("eos", "length")
-    assert outs["paged"] == outs["fixed"], "spec decode diverged under paging"
-
 
 def test_hot_swap_flushes_prefix_store(trainer):
     """set_params invalidates every cached prefix (stale-weights K/V must

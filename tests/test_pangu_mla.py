@@ -474,13 +474,6 @@ def test_paths_that_cannot_follow_refuse_by_name():
         build()
     with pytest.raises(NotImplementedError, match="int8 arena .* over a latent cache"):
         build(kv_paging=True, kv_cache_dtype="int8")
-    with pytest.raises(NotImplementedError, match="MoE|speculative decode over a latent cache"):
-        build(kv_paging=True, spec_k=2, spec_split=2)
-    dense_ffn = dataclasses.replace(cfg, moe_experts=0, moe_router="softmax", moe_shared_d_ff=0,
-                                    moe_routed_scale=1.0, moe_local_experts=0)
-    with pytest.raises(NotImplementedError, match="speculative decode over a latent cache"):
-        InferenceEngine(CausalLMPolicy(dense_ffn), dense_ffn, None, gen_cfg, num_slots=2, max_prompt_len=8,
-                        kv_paging=True, spec_k=2, spec_split=2)
     with pytest.raises(NotImplementedError, match="sessions .* over a latent cache"):
         build(kv_paging=True).enable_sessions()
     with pytest.raises(NotImplementedError, match="int8 latent arena"):

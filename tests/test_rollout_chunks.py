@@ -374,18 +374,18 @@ def block_form(trainer, monkeypatch):
     return _with_pool(trainer, POOL)
 
 
-def test_block_form_plan_is_the_samplers_own_rule(block_form, monkeypatch):
+def test_block_form_plan_is_the_samplers_own_rule(block_form):
     trainer = block_form
     plan = trainer._rollout_plan(100, trainer.generate_kwargs)
     assert (plan.block, plan.pad, plan.blocks, plan.columns) == (BLOCK, 0, 4, 128 + MAX_NEW)
     # the loader still sorts a collection's prompts: that is what makes a chunk's longest short
     window = [p for chunk in _take(trainer.prompt_iterator, CHUNKS) for p in chunk]
     assert [len(p) for p in window] == sorted((len(p) for p in window), reverse=True)
-    # who has no plan: speculative rounds, a pipelined trainer's own `generate`
+    # who has no plan: a pipelined trainer's own `generate`
     # (right padding, one block and beams: the test of the pool's width above)
-    assert trainer._rollout_plan(100, trainer.generate_kwargs, spec_k=2) is None
-    monkeypatch.setattr(trainer, "_narrows_rollout_chunks", False)
-    assert trainer._rollout_plan(100, trainer.generate_kwargs) is None
+    from trlx_tpu.trainer.pipelined_ppo_trainer import PipelinedPPOTrainer
+
+    assert PipelinedPPOTrainer._rollout_plan(trainer, 100, trainer.generate_kwargs) is None
 
 
 def test_block_form_runs_four_chunks_through_one_program_and_counts_its_blocks(block_form, monkeypatch):

@@ -288,4 +288,3 @@ def test_who_takes_the_block_form(preset, width, takes):
     assert (block_plan(cfg, gen_cfg(), width, BLOCK) is not None) is takes
     assert block_plan(cfg, gen_cfg(), width, 0) is None
     assert block_plan(cfg, gen_cfg(num_beams=2), width, BLOCK) is None
-    assert block_plan(cfg, gen_cfg(), width, BLOCK, spec_k=2) is None

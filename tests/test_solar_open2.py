@@ -364,7 +364,6 @@ def test_the_eight_shares_add_up_to_the_uncut_layer_with_the_shared_expert_count
 
 REFUSALS = [
     ("prefix_cache", dict(prefix_cache=True), "prefix_cache over slot state"),
-    ("speculative_decode", dict(spec_k=2, spec_split=1), "MoE|speculative decode over slot state"),
     ("dense_slot_pool", dict(kv_paging=False), "dense slot pool .* over slot state"),
     ("int8_arena", dict(kv_cache_dtype="int8"), "int8 arena .* over slot state"),
 ]

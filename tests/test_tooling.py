@@ -415,6 +415,82 @@ def test_every_command_a_document_names_is_there(document):
     assert sorted(p for p in named if not os.path.isfile(os.path.join(root, p))) == []
 
 
+def _section_of(markdown: str, name: str) -> str:
+    """The `## ` section of a document whose heading names `name` in a code
+    span, down to the next `## ` heading (its `### ` subsections included)."""
+    sections = re.split(r"^## ", markdown, flags=re.M)[1:]
+    found = [body for body in sections if f"`{name}`" in body.split("\n", 1)[0]]
+    assert len(found) == 1, f"{len(found)} sections are headed `{name}`"
+    return found[0].split("\n", 1)[1]
+
+
+def _listed_options(section: str) -> set:
+    """The options a section lists: the code spans that open a top-level list
+    item (one, or several joined by " / " or ", ") or stand in a table row's
+    first cell."""
+    listed = set()
+    for line in section.splitlines():
+        opener = re.match(r"- ((?:`\w+`(?:\s*/\s*|,\s*)?)+)", line)
+        cell = re.match(r"\|([^|]*)\|", line)
+        for text in ((opener.group(1),) if opener else ()) + ((cell.group(1),) if cell else ()):
+            listed.update(re.findall(r"`(\w+)`", text))
+    return listed
+
+
+CONFIG_CLASSES = ["PPOConfig", "GRPOConfig", "BONConfig", "ILQLConfig", "SFTConfig", "RFTConfig", "ModelConfig",
+                  "TokenizerConfig", "OptimizerConfig", "SchedulerConfig", "TrainConfig", "ParallelConfig",
+                  "InferenceConfig"]
+
+
+@pytest.mark.parametrize("name", CONFIG_CLASSES)
+def test_configs_md_lists_the_fields_a_config_class_has_and_no_other(name):
+    """`docs/configs.md` has one section a config class: every option the
+    section lists is a field of the class, and every field is listed, so an
+    option that went cannot stay behind and a new one cannot go unsaid."""
+    import dataclasses
+
+    import trlx_tpu.utils.loading  # noqa: F401  (registers the method configs)
+    from trlx_tpu.data import configs
+    from trlx_tpu.data.method_configs import get_method
+
+    cls = getattr(configs, name, None) or get_method(name)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "docs", "configs.md")) as f:
+        listed = _listed_options(_section_of(f.read(), name))
+    fields = {field.name for field in dataclasses.fields(cls)}
+    assert sorted(listed - fields) == [], "listed, and no field of the class"
+    assert sorted(fields - listed) == [], "fields the section does not list"
+
+
+@pytest.mark.parametrize("document", [
+    "README.md", "docs/configs.md", "docs/robustness.md", "docs/serving.md", "docs/trainers.md"])
+def test_every_option_a_document_names_by_its_section_is_a_field(document):
+    """Every `<section>.<name>` in a code span or a fence of the document
+    (`method.gen_kwargs`, `train.sentinel`, `inference.kv_paging`, ...) names a
+    field of that section's config class (of a registered method config for
+    `method.`): an option that went cannot stay behind as an instruction."""
+    import dataclasses
+
+    import trlx_tpu.utils.loading  # noqa: F401  (registers the method configs)
+    from trlx_tpu.data import configs
+    from trlx_tpu.data.method_configs import _METHODS
+
+    def names(*classes):
+        return {field.name for cls in classes for field in dataclasses.fields(cls)}
+
+    fields = {"method": names(*_METHODS.values())}
+    for section in ("model", "tokenizer", "optimizer", "scheduler", "train", "parallel", "inference"):
+        fields[section] = names(getattr(configs, section.capitalize() + "Config"))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, document)) as f:
+        code = list(_code_of(f.read()))
+    # a path (`inference.py`), a call (`model.apply(`) and a prefix (`train.rollout_*`) are not options
+    named = {(section, name) for text in code for section, name in re.findall(
+        r"(?<![\w./])(" + "|".join(fields) + r")\.([a-z_][a-z0-9_]*)(?![\w(/*])", text) if name != "py"}
+    assert named, f"{document} names no option: the pattern no longer reads it"
+    assert sorted(f"{section}.{name}" for section, name in named if name not in fields[section]) == []
+
+
 def _chip_smoke():
     import importlib.util
 

@@ -65,7 +65,6 @@ from trlx_tpu.ops.sampling import (
     process_logits,
     sampled_token_logprob,
     select_token,
-    spec_draft_head_from_params,
 )
 from trlx_tpu.utils import logging
 
@@ -166,9 +165,6 @@ class InferenceEngine:
         max_prefill_batch: int = 8,
         prompt_bucket: int = 32,
         seed: int = 0,
-        spec_k: int = 0,
-        spec_split: int = 0,
-        spec_draft_rank: int = 64,
         kv_paging: bool = False,
         kv_block_size: int = 32,
         kv_pool_blocks: int = 0,
@@ -206,34 +202,18 @@ class InferenceEngine:
         if multi_tenant:
             if adapter_store is None:
                 raise ValueError("multi_tenant serving needs an AdapterStore")
-            if spec_k > 0:
-                raise NotImplementedError(
-                    "speculative decode under multi-tenant adapters is "
-                    "unsupported (the draft head is per-policy)"
-                )
             if getattr(model_cfg, "lora_rank", 0) <= 0:
                 raise ValueError(
                     "multi_tenant serving needs a LoRA-enabled policy "
                     "(cfg.lora_rank > 0)"
-                )
-        if spec_k > 0:
-            if spec_split <= 0:
-                raise ValueError(
-                    "speculative decode needs a hydra split > 0 (the frozen "
-                    "trunk is the draft model)"
-                )
-            if getattr(model_cfg, "moe_experts", 0) > 0:
-                raise NotImplementedError(
-                    "speculative decode under MoE routing is unsupported"
                 )
         if getattr(model_cfg, "prompt_tokens", 0) > 0 or getattr(model_cfg, "prefix_tokens", 0) > 0:
             raise NotImplementedError(
                 "slot-pool decode under prompt/prefix tuning is unsupported"
             )
         # untested over sliding layers, so refused by name: a cached or
-        # retained prefix resumes a prefill behind blocks it did not write,
-        # and a draft's rollback clears mask bits a band reads
-        for on, what in ((prefix_cache, "prefix_cache"), (spec_k > 0, "speculative decode"),
+        # retained prefix resumes a prefill behind blocks it did not write
+        for on, what in ((prefix_cache, "prefix_cache"),
                          (not kv_paging, "the dense slot pool (kv_paging=False)")):
             if on:
                 _refuse_over_attention_kinds(model_cfg, what)
@@ -256,9 +236,6 @@ class InferenceEngine:
         self.max_prompt_len = _round_up(int(max_prompt_len), self.prompt_bucket)
         self.max_prefill_batch = int(max_prefill_batch)
         self.max_len = self.max_prompt_len + gen_cfg.max_new_tokens
-        self.spec_k = int(spec_k)
-        self.spec_split = int(spec_split)
-        self.spec_draft_rank = int(spec_draft_rank)
         self.kv_paging = bool(kv_paging)
         self.kv_block_size = int(kv_block_size)
         self.prefix_cache = bool(prefix_cache) and self.kv_paging
@@ -282,9 +259,7 @@ class InferenceEngine:
             raise NotImplementedError("int8 KV cache requires kv_paging")
         if prefix_cache and not kv_paging:
             raise ValueError("prefix_cache requires kv_paging")
-        # a speculative round may write spec_k cache rows past a slot's
-        # budget before the rollback clears them — give the pool the slack
-        self._cache_len = self.max_len + self.spec_k
+        self._cache_len = self.max_len
         if self.kv_paging:
             if self.kv_block_size < 1:
                 raise ValueError("kv_block_size must be >= 1")
@@ -342,9 +317,6 @@ class InferenceEngine:
         self._params = params
         self._param_lock = threading.Lock()
         self._param_version = 0
-        self._spec_head = None
-        if self.spec_k > 0 and params is not None:
-            self._spec_head = self._build_spec_head(params)
 
         V = model_cfg.vocab_size
         P = self.num_slots
@@ -434,7 +406,7 @@ class InferenceEngine:
         self._prefill_fns: Dict[Tuple[int, int], Callable] = {}
         self._insert_fns: Dict[int, Callable] = {}
         self._paged_insert_fns: Dict[Tuple[int, int, bool], Callable] = {}
-        self._decode_fn = self._make_spec_decode() if self.spec_k > 0 else self._make_decode()
+        self._decode_fn = self._make_decode()
         if self.hbm is not None and self.kv_paging:
             stats = self.kv_stats()
             self.hbm.set_component(
@@ -493,9 +465,7 @@ class InferenceEngine:
 
     def _kernel_unsupported_reason(self) -> Optional[str]:
         """Engine-static reason the paged decode kernel cannot serve this
-        config (counted once per decode dispatch), or None. Per-dispatch
-        dynamic shapes (spec-decode verify rows) are counted at the
-        dispatch site instead."""
+        config (counted once per decode dispatch), or None."""
         cfg = self.model_cfg
         if not self.kv_paging:
             return "kv_paging_off"
@@ -538,12 +508,7 @@ class InferenceEngine:
         on the new weights from the next decode step to be dispatched: the
         step already in flight (`step`) ends on the old ones — the KV cache
         keeps the old prefix's keys/values, exactly like serving a live
-        policy mid-update. Under speculative decode the low-rank draft
-        head is recomputed from the fresh unembedding (host-side SVD) so
-        draft quality tracks the served policy; the swap of (params,
-        head) is atomic under the same lock. Returns the new param
-        version."""
-        head = self._build_spec_head(params) if self.spec_k > 0 else None
+        policy mid-update. Returns the new param version."""
         if self.prefix_cache:
             # cached prefixes hold K/V computed under the OLD weights:
             # in-flight requests may finish on their stale prefix (same
@@ -558,16 +523,8 @@ class InferenceEngine:
             self.session_store.invalidate_all("weights_updated")
         with self._param_lock:
             self._params = params
-            self._spec_head = head
             self._param_version += 1
             return self._param_version
-
-    def _build_spec_head(self, params):
-        a, b = spec_draft_head_from_params(
-            params, self.model_cfg, self.spec_draft_rank
-        )
-        dtype = getattr(self.model_cfg, "dtype", jnp.float32)
-        return jnp.asarray(a, dtype), jnp.asarray(b, dtype)
 
     @property
     def param_version(self) -> int:
@@ -583,10 +540,6 @@ class InferenceEngine:
     def _current_params(self):
         with self._param_lock:
             return self._params
-
-    def _current_params_and_head(self):
-        with self._param_lock:
-            return self._params, self._spec_head
 
     # ------------------------------------------------------------------
     # Fused sampling (traced inside the insert / decode programs)
@@ -977,7 +930,7 @@ class InferenceEngine:
         sessions: Optional[Sequence] = None,
     ) -> Tuple[int, int, int, int]:
         """Paged insert: allocate each request's blocks up front
-        (prompt + max_new + spec_k — no mid-decode OOM, no preemption),
+        (prompt + max_new — no mid-decode OOM, no preemption),
         probing the prefix store for resident leading blocks first. In
         multi-tenant mode prefix keys are salted with the row's adapter
         identity, so paged prefix blocks never cross tenants.
@@ -1044,7 +997,7 @@ class InferenceEngine:
                                     pool.hits += 1
                                 else:
                                     pool.misses += 1
-                        n_cap = -(-(ids.size + max_new + self.spec_k) // bs)
+                        n_cap = -(-(ids.size + max_new) // bs)
                         try:
                             owned = self._alloc_evicting_sessions(n_cap - len(shared))
                         except KVPoolExhaustedError:
@@ -1214,188 +1167,6 @@ class InferenceEngine:
         site = "engine.decode" if ak is None else f"engine.decode[{ak}]"
         return self._ljit(decode, site, donate_argnums=(1,))
 
-    def _make_spec_decode(self) -> Callable:
-        """Speculative slot decode: one call emits the slot's pending
-        token plus every draft the full model accepts (up to spec_k+1
-        tokens per slot per call). The frozen trunk runs spec_k+1 per-row
-        cached steps (draft tokens from the low-rank readout between
-        them), ONE batched suffix pass verifies all positions from the
-        trunk's own h_split, and the longest matching prefix is accepted
-        with exact rejection-sampling correction — the correction token
-        becomes the slot's new pending `next_token`, preserving the plain
-        path's sampled-but-unemitted invariant. Greedy emissions are
-        bitwise the plain decode program's; rejected KV rows are rolled
-        back by clearing mask bits."""
-        model, gen_cfg = self.model, self.gen_cfg
-        pad, eos = gen_cfg.pad_token_id, gen_cfg.eos_token_id
-        k, split = self.spec_k, self.spec_split
-        greedy = (not gen_cfg.do_sample) or (gen_cfg.temperature == 0.0)
-        suppress = self._suppress
-        paged = self.kv_paging
-        # trunk draft steps are decode-shaped (t == 1) and ride the fused
-        # kernel; the batched multi-position verify cannot (counted as a
-        # per-dispatch "spec_verify_rows" fallback in _step_impl)
-        ak = self._attn_kernel if self._kernel_unsupported is None else None
-
-        def warp(raw_logits, step):
-            scores = raw_logits
-            if suppress is not None:
-                scores = scores + suppress
-            return process_logits(scores, gen_cfg, step)
-
-        def decode(params, pool, a_fac, b_fac):
-            params = dequantize_tree(params)
-            P = pool["active"].shape[0]
-            active = pool["active"].astype(bool)
-            act_i = active.astype(jnp.int32)
-            step0 = pool["step"]
-            rng = pool["rng"]
-            cache = {key: pool[key] for key in ("layers", "mask", "pos", "row_index")}
-            if paged:
-                cache["layers"] = [
-                    dict(al, table=pool["table"]) for al in cache["layers"]
-                ]
-            row_start = pool["row_index"]
-            pos_start = pool["pos"]
-            f0 = jnp.where(active, pool["next_token"], pad)
-            f = f0
-            h_rows, q_scores, draft_toks = [], [], []
-            for j in range(k + 1):
-                # a trunk-only step: no head, the state entering block
-                # `split` raw (h_j) and through `ln_f` (hn_j)
-                _, hn_j, cache, h_j = model.apply(
-                    {"params": params}, f[:, None], cache, act_i[:, None],
-                    stop=split, capture_split=split, attn_kernel=ak,
-                    method=type(model).decode_step,
-                )
-                h_rows.append(h_j)
-                if j < k:
-                    rng, key = jax.random.split(rng)
-                    dl = ((hn_j[:, 0] @ a_fac) @ b_fac).astype(jnp.float32)
-                    sq = warp(dl, step0 + 1 + j)
-                    f = select_token(sq, key, gen_cfg).astype(jnp.int32)
-                    q_scores.append(sq)
-                    draft_toks.append(f)
-            h_block = jnp.concatenate(h_rows, axis=1)
-            positions = pos_start[:, None] + jnp.arange(k + 1)[None, :]
-            # gate the batched verify's arena writes on row liveness: a
-            # freed slot's stale block table may point at blocks now owned
-            # by other requests, so its writes must drop
-            out = model.apply(
-                {"params": params}, h_block, cache,
-                jnp.broadcast_to(act_i[:, None], (P, k + 1)) if paged else None,
-                start=split, block_start=row_start, positions=positions,
-                method=type(model).decode_step,
-            )
-            logits_v, cache = out[0].astype(jnp.float32), out[-1]
-            p_scores = [warp(logits_v[:, j], step0 + 1 + j) for j in range(k + 1)]
-            if greedy:
-                acc = [
-                    jnp.argmax(p_scores[j], -1).astype(jnp.int32) == draft_toks[j]
-                    for j in range(k)
-                ]
-            else:
-                acc = []
-                for j in range(k):
-                    rng, key = jax.random.split(rng)
-                    u = jax.random.uniform(key, (P,))
-                    tok = draft_toks[j][:, None]
-                    lr = (
-                        jnp.take_along_axis(jax.nn.log_softmax(p_scores[j], -1), tok, 1)
-                        - jnp.take_along_axis(jax.nn.log_softmax(q_scores[j], -1), tok, 1)
-                    )[:, 0]
-                    acc.append(u < jnp.exp(jnp.minimum(lr, 0.0)))
-            run = jnp.ones((P,), bool)
-            m = jnp.zeros((P,), jnp.int32)
-            for j in range(k):
-                run = run & acc[j]
-                m = m + run.astype(jnp.int32)
-            corr, corr_lp = [], []
-            lsm_v = jax.nn.log_softmax(logits_v, axis=-1)
-            for j in range(k + 1):
-                if greedy:
-                    c = jnp.argmax(p_scores[j], -1).astype(jnp.int32)
-                elif j < k:
-                    rng, key = jax.random.split(rng)
-                    p_w = jax.nn.softmax(p_scores[j], -1)
-                    q_w = jax.nn.softmax(q_scores[j], -1)
-                    res = jnp.clip(p_w - q_w, 0.0, None)
-                    tot = res.sum(-1, keepdims=True)
-                    res = jnp.where(tot > 0, res / tot, p_w)
-                    c = jax.random.categorical(
-                        key, jnp.where(res > 0, jnp.log(res), -jnp.inf), axis=-1
-                    ).astype(jnp.int32)
-                else:
-                    rng, key = jax.random.split(rng)
-                    c = select_token(p_scores[j], key, gen_cfg).astype(jnp.int32)
-                corr.append(c)
-                corr_lp.append(
-                    jnp.take_along_axis(lsm_v[:, j], c[:, None], axis=-1)[:, 0]
-                )
-            corr = jnp.stack(corr, axis=1)
-            corr_lp = jnp.stack(corr_lp, axis=1)
-            corr_at_m = jnp.take_along_axis(corr, m[:, None], axis=1)[:, 0]
-            corr_lp_at_m = jnp.take_along_axis(corr_lp, m[:, None], axis=1)[:, 0]
-            # emissions this call: [f0, accepted drafts]; the correction
-            # stays pending as the slot's new next_token
-            jidx = jnp.arange(k + 1)[None, :]
-            draft_mat = (
-                jnp.stack(draft_toks, axis=1)
-                if k > 0 else jnp.zeros((P, 0), jnp.int32)
-            )
-            emit_mat = jnp.concatenate([f0[:, None], draft_mat], axis=1)
-            draft_lp = jnp.stack(
-                [
-                    jnp.take_along_axis(
-                        lsm_v[:, j], draft_toks[j][:, None], axis=-1
-                    )[:, 0]
-                    for j in range(k)
-                ],
-                axis=1,
-            ) if k > 0 else jnp.zeros((P, 0), jnp.float32)
-            lp_mat = jnp.concatenate([pool["next_logprob"][:, None], draft_lp], axis=1)
-            alive = active
-            valids = []
-            for j in range(k + 1):
-                v_j = alive & (j - 1 < m) & (step0 + j < pool["max_new"])
-                valids.append(v_j)
-                alive = v_j & (emit_mat[:, j] != eos)
-            valid_mat = jnp.stack(valids, axis=1)
-            emit_mat = jnp.where(valid_mat, emit_mat, pad)
-            e = valid_mat.astype(jnp.int32).sum(1)
-            hit_eos = jnp.any(valid_mat & (emit_mat == eos), axis=1)
-            new_step = step0 + e
-            finished = active & (hit_eos | (new_step >= pool["max_new"]))
-            # roll back rejected KV rows; keep offsets for the e emitted
-            # (and fed) tokens f_0..f_{e-1}
-            rows_p = jnp.arange(P)[:, None]
-            offs = row_start[:, None] + jidx
-            new_mask = cache["mask"].at[rows_p, offs].set(
-                (jidx < e[:, None]).astype(cache["mask"].dtype)
-            )
-            layers_out = cache["layers"]
-            if paged:
-                layers_out = [
-                    {k2: v2 for k2, v2 in layer.items() if k2 != "table"}
-                    for layer in layers_out
-                ]
-            new_pool = {
-                **pool,
-                "layers": layers_out,
-                "mask": new_mask,
-                "pos": pos_start + e,
-                "row_index": row_start + e,
-                "next_token": corr_at_m,
-                "next_logprob": corr_lp_at_m,
-                "step": new_step,
-                "active": pool["active"] * (1 - finished.astype(jnp.int32)),
-                "rng": rng,
-            }
-            return new_pool, emit_mat, lp_mat, valid_mat, finished
-
-        site = "engine.spec_decode" if ak is None else f"engine.spec_decode[{ak}]"
-        return self._ljit(decode, site, donate_argnums=(1,))
-
     def _maybe_oom_postmortem(self, site: str, exc: BaseException) -> None:
         """OOM forensics at the engine-dispatch boundary: RESOURCE_EXHAUSTED
         escaping a prefill/insert/decode dispatch dumps a memory postmortem
@@ -1456,15 +1227,12 @@ class InferenceEngine:
         return out
 
     def _step_impl(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-        """Advance every active slot. Plain mode returns host arrays
-        (tokens [P], logprobs [P] f32, emitted [P] bool, finished [P]
-        bool); speculative mode returns (tokens [P, spec_k+1], logprobs
-        [P, spec_k+1], emitted [P, spec_k+1], finished [P]) — each slot
-        emits between 1 and spec_k+1 tokens per call, in order, flagged
-        by the emitted mask. Finished slots are already deactivated in
-        the pool. The logprob is the policy's raw-logit log-probability
-        of the emitted token (see `_sample_fused`), meaningful only where
-        `emitted`.
+        """Advance every active slot. Returns host arrays (tokens [P],
+        logprobs [P] f32, emitted [P] bool, finished [P] bool): a slot
+        emits one token a call where `emitted`. Finished slots are already
+        deactivated in the pool. The logprob is the policy's raw-logit
+        log-probability of the emitted token (see `_sample_fused`),
+        meaningful only where `emitted`.
 
         One decode program stays in flight: a call dispatches the NEXT step
         first and only then waits for the step dispatched a call earlier,
@@ -1505,33 +1273,25 @@ class InferenceEngine:
             self._moe_stats = {k: float(v) for k, v in stats[0].items()}
             if self.kv_paging and traced:
                 tracing.counters("engine.moe", **self._moe_stats)
-        rows = due.rows if valid.ndim == 1 else due.rows[:, None]
-        self._outputs_masked += int((valid & ~rows).sum())
-        valid = valid & rows
+        self._outputs_masked += int((valid & ~due.rows).sum())
+        valid = valid & due.rows
         finished = finished & due.rows
         # the device deactivated these rows itself: the step in flight
         # holds nothing for them
         self._live[finished] = False
         self._ahead.rows[finished] = False
         if self.kv_paging:
-            self._slot_cols += valid.reshape(self.num_slots, -1).sum(-1)
+            self._slot_cols += valid
         # kernel dispatch accounting (driver thread; read under _kv_lock
         # by kv_stats), after the step has run: a decode dispatch either
         # rode the fused kernel or fell back to the gather path for a
-        # counted reason. The spec path counts BOTH — its t=1 trunk draft
-        # steps use the kernel while the multi-position verify cannot, so
-        # every spec dispatch also logs a "spec_verify_rows" fallback
-        # explaining the non-kernel portion.
+        # counted reason.
         if self._attn_kernel is not None:
             if self._kernel_unsupported is not None:
                 r = self._kernel_unsupported
                 self._kv_kernel_fallbacks[r] = self._kv_kernel_fallbacks.get(r, 0) + 1
             else:
                 self._kv_kernel_dispatches += 1
-                if self.spec_k > 0:
-                    self._kv_kernel_fallbacks["spec_verify_rows"] = (
-                        self._kv_kernel_fallbacks.get("spec_verify_rows", 0) + 1
-                    )
         return np.asarray(token), np.asarray(logprob, np.float32), valid, finished
 
     def _dispatch_decode(self) -> _InFlight:
@@ -1553,14 +1313,10 @@ class InferenceEngine:
                 tracing.counters("engine.slot_state", **self._slot_state_step())
             tracing.counters("engine.queued", seq=seq, ahead=int(ahead), rows=int(self._live.sum()))
         with tracing.span("engine.dispatch"):
-            if self.spec_k > 0:
-                params, head = self._current_params_and_head()
-                self._pool, *out = self._decode_fn(params, self._pool, head[0], head[1])
-            elif self.multi_tenant:
-                params = self._current_params()
+            params = self._current_params()
+            if self.multi_tenant:
                 self._pool, *out = self._decode_fn(params, self._pool, self.adapter_store.stacked())
             else:
-                params = self._current_params()
                 self._pool, *out = self._decode_fn(params, self._pool)
             # or the copy starts only when the fetch asks for it, and the
             # device stands still meanwhile (PERF.md section 5)
@@ -1611,7 +1367,7 @@ class InferenceEngine:
         adapter_id: Optional[str] = None, session=None,
     ) -> int:
         """Blocks this request would claim if admitted now:
-        ceil((prompt + max_new + spec_k) / block_size) minus the leading
+        ceil((prompt + max_new) / block_size) minus the leading
         blocks a read-only prefix-store probe says are resident (probed
         in the request's own adapter key space), or minus the session's
         retained blocks when the request rides one. 0 when paging is
@@ -1619,7 +1375,7 @@ class InferenceEngine:
         if not self.kv_paging:
             return 0
         ids = np.asarray(prompt_ids, np.int32).reshape(-1)
-        n_cap = -(-(ids.size + int(max_new_tokens) + self.spec_k) // self.kv_block_size)
+        n_cap = -(-(ids.size + int(max_new_tokens)) // self.kv_block_size)
         if session is not None:
             # session rows never touch the prefix store; their only
             # reuse is the conversation's own retained prefix

@@ -254,9 +254,7 @@ class Scheduler:
                 max(req.max_new_tokens - len(req.token_ids), 1)
                 for req in self._slot_req.values()
             )
-            per_step = max(1, getattr(self.engine, "spec_k", 0) + 1)
-            steps = -(-remaining // per_step)
-            return max(0.05, self._decode_ewma * steps)
+            return max(0.05, self._decode_ewma * remaining)
         return float(max(1, len(self._queue) // max(self.engine.num_slots, 1)))
 
     def _enqueue(self, reqs: List[InferenceRequest]) -> None:
@@ -840,14 +838,6 @@ class Scheduler:
         self._decode_ewma = (
             dt if self._decode_ewma == 0.0 else 0.8 * self._decode_ewma + 0.2 * dt
         )
-        # normalize the plain program's [P] outputs to the speculative
-        # program's [P, K] layout — one loop body serves both; plain mode
-        # is just K == 1
-        if tokens.ndim == 1:
-            tokens = tokens[:, None]
-            logprobs = logprobs[:, None]
-            valid = valid[:, None]
-        spec = getattr(self.engine, "spec_k", 0) > 0
         multi_tenant = getattr(self.engine, "multi_tenant", False)
         tenant_emitted: Dict[str, int] = {}
         emitted = 0
@@ -855,29 +845,22 @@ class Scheduler:
         eos = self.engine.gen_cfg.eos_token_id
         with tracing.span("sched.emit"):
             for slot, req in list(self._slot_req.items()):
-                n_slot = 0
-                for j in range(tokens.shape[1]):
-                    if valid[slot, j]:
-                        req.token_ids.append(int(tokens[slot, j]))
-                        req.token_logprobs.append(float(logprobs[slot, j]))
-                        n_slot += 1
-                emitted += n_slot
-                if n_slot and req.first_token_time is None:
-                    req.first_token_time = now
-                    self.metrics.observe(
-                        "ttft_seconds", req.first_token_time - req.enqueue_time,
-                        trace_id=(req.trace.trace_id if req.trace is not None
-                                  else None),
-                    )
-                if multi_tenant and n_slot:
-                    t = self._tenant(req)
-                    tenant_emitted[t] = tenant_emitted.get(t, 0) + n_slot
-                if spec and n_slot:
-                    # accept-length per slot per speculative round (1 pending
-                    # + accepted drafts) — the serving-side mirror of the
-                    # trainer's rollout/spec_accept_rate
-                    self.metrics.observe("spec_accepted_tokens", n_slot)
-                stopped = bool(n_slot) and self._apply_stop(req)
+                got = bool(valid[slot])
+                if got:
+                    req.token_ids.append(int(tokens[slot]))
+                    req.token_logprobs.append(float(logprobs[slot]))
+                    emitted += 1
+                    if req.first_token_time is None:
+                        req.first_token_time = now
+                        self.metrics.observe(
+                            "ttft_seconds", req.first_token_time - req.enqueue_time,
+                            trace_id=(req.trace.trace_id if req.trace is not None
+                                      else None),
+                        )
+                    if multi_tenant:
+                        t = self._tenant(req)
+                        tenant_emitted[t] = tenant_emitted.get(t, 0) + 1
+                stopped = got and self._apply_stop(req)
                 if stopped:
                     # a stop sequence matched: truncated, session retained,
                     # slot cancelled (release_slots deactivates + reclaims)
@@ -899,7 +882,7 @@ class Scheduler:
                     self.engine.release_slots([slot])
                     self._release(slot)
                     self._finish_request(req, "deadline")
-                elif n_slot:
+                elif got:
                     self._stream_emit(req)
         self.metrics.add("tokens_generated_total", emitted)
         for t, n in tenant_emitted.items():

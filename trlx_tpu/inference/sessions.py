@@ -12,8 +12,7 @@ excluded when the history ends exactly on a boundary — at least one
 suffix token always prefills on the next turn (the engine never stores
 last-position logits), and the final sampled token of a turn (whose KV
 was never written — it was sampled but not fed back) can never sit
-inside a retained block. Speculative-decode slack writes land past the
-slot's live length, also outside the retained prefix.
+inside a retained block.
 
 Consistency: retained KV is only valid under the weights that wrote
 it. A checkpoint hot-swap (`engine.set_params`) or a per-adapter

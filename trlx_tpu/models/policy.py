@@ -142,12 +142,8 @@ class CausalLMWithValueHead(nn.Module):
     def decode_step(self, x, cache, token_mask, is_prefill: bool = False,
                     with_value: bool = False, **step):
         """`TransformerLM.decode_step` with the value of each new position
-        when `with_value`: (logits, values | None, new_cache[, h_cap]). A
-        step that stops short of the head runs none here either and returns
-        the LM's own tuple."""
+        when `with_value`: (logits, values | None, new_cache[, h_cap])."""
         out = self.lm.decode_step(x, cache, token_mask, is_prefill, **step)
-        if out[0] is None:
-            return out
         values = None
         if with_value:
             values = self._values(out[1])

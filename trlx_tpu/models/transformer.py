@@ -2391,25 +2391,19 @@ class TransformerLM(nn.Module):
 
     def decode_step(
         self,
-        x: jnp.ndarray,  # [b, t] token ids; [b, t, d] the state entering block `start` when start > 0
+        x: jnp.ndarray,  # [b, t] token ids
         cache: Dict[str, Any],
         token_mask: Optional[jnp.ndarray],  # [b, t] validity of these positions
         is_prefill: bool = False,
         *,
-        start: int = 0,
-        stop: Optional[int] = None,
         capture_split: Optional[int] = None,
         attn_kernel: Optional[str] = None,  # paged read path: None | "pallas" | "interpret"
-        block_start: Optional[jnp.ndarray] = None,  # [b]
-        positions: Optional[jnp.ndarray] = None,  # [b, t]
         head_at: Optional[jnp.ndarray] = None,  # [b] the one of the t new columns a row reads
     ):
-        """The one cached step: blocks [start, stop) over t new positions against the cache, which
+        """The one cached step: every block over t new positions against the cache, which
         is moved on. Returns (logits, h_final, new_cache), and the activation ENTERING block
         `capture_split` (the same hydra split point as __call__'s h_split) as a fourth when that is
-        given. A step with `stop` given (n_layers too) is an early exit: no head runs, logits is
-        None, and h_final is `ln_f`'s reading of the state entering block `stop`, which a low-rank
-        draft head projects. A caller that reads one position a row (an admission: the prompt's
+        given. A caller that reads one position a row (an admission: the prompt's
         last token) says which with `head_at`: the final norm and the head then run over that
         position alone, and logits and h_final come back one position wide.
 
@@ -2424,7 +2418,7 @@ class TransformerLM(nn.Module):
           logits keep the caller's sequence length. With a scalar **`first`** beside it (the
           sampler's prefill by blocks): `live_widths`.
         - **`row_index`** ([b]): every row carries its OWN write offset — the continuous-batching
-          slot pool and the paged arena (trlx_tpu/inference/engine.py), speculative decode. Rows
+          slot pool and the paged arena (trlx_tpu/inference/engine.py). Rows
           sit at different depths, which the shared scalar cannot express; for a live row the
           computation is bit-identical to the scalar one on an aligned batch, because masked
           cache columns contribute exactly 0.0 to every softmax sum wherever they sit (exp(-1e9)
@@ -2433,37 +2427,18 @@ class TransformerLM(nn.Module):
           RIGHT-padded prefill: row r's valid tokens occupy columns [row_index_r, row_index_r +
           len_r), a nonzero row_index resumes behind a shared prefix already resident in the
           cache (prefix-cache hit) whose mask bits the caller seeds, and the pad positions write
-          nothing the model can see (mask bit 0; paged arena writes are dropped via the mask).
-
-        Speculative decode is this step three ways. A draft step is a per-row t == 1 step with
-        `stop=split`: it writes trunk K/V and sets each position's mask bit as it goes — a
-        drafted position becomes a visible key only once its K/V is in the cache, so
-        later-rejected drafts roll back by clearing bits, and stale K/V beyond the frontier contributes
-        exactly zero — and passes the suffix layers' caches through. The verify pass resumes
-        `start=split` from the trunk's own rows (x = the drafts' captured states), writing suffix
-        K/V for all t candidates in ONE pass; the draft steps have already set its mask bits and
-        moved row_index on, so it names the block's first column (`block_start`) and its
-        `positions` itself and moves nothing (`token_mask` then only gates paged-arena writes;
-        dense caches ignore it)."""
+          nothing the model can see (mask bit 0; paged arena writes are dropped via the mask)."""
         cfg = self.cfg
-        to_head, stop = stop is None, cfg.n_layers if stop is None else stop
         b, t = x.shape[:2]
         per_row = "row_index" in cache
         looped = cfg.loop_steps > 1
-        if looped and (start > 0 or not to_head or capture_split is not None or positions is not None):
+        if looped and capture_split is not None:
             raise NotImplementedError(
-                "a looped stack (loop_steps > 1) runs whole: a cached step from, to or capturing a layer inside it "
-                "(speculative decode's draft step and verify pass, the hydra split) is not supported")
-        if per_row:
-            if cfg.prompt_tokens > 0 or cfg.prefix_tokens > 0:
-                raise NotImplementedError(
-                    "a per-row cache (slot pool, paged insert, speculative decode) "
-                    "under prompt/prefix tuning is unsupported"
-                )
-            if cfg.has_slot_state and (positions is not None or not to_head):
-                raise NotImplementedError(
-                    "speculative decode (a draft step, a verify pass) over slot state: rejected drafts "
-                    "roll back by clearing mask bits, which does not undo a convolution's or a recurrence's state")
+                "a looped stack (loop_steps > 1) runs whole: a cached step capturing a layer inside it "
+                "(the hydra split) is not supported")
+        if per_row and (cfg.prompt_tokens > 0 or cfg.prefix_tokens > 0):
+            raise NotImplementedError(
+                "a per-row cache (slot pool, paged insert) under prompt/prefix tuning is unsupported")
         if capture_split is not None and cfg.prompt_tokens > 0:
             raise NotImplementedError(
                 "split-activation capture under prompt tuning is unsupported "
@@ -2479,6 +2454,7 @@ class TransformerLM(nn.Module):
         # the one place that moves the cache on: the mask and where the new
         # positions are here (the bias reads both), the counters at the end
         mask_dtype = cache["mask"].dtype
+        block_start = None
         if not per_row:
             offset = cache["index"]
             if is_prefill:
@@ -2493,10 +2469,6 @@ class TransformerLM(nn.Module):
             new_mask = jax.lax.dynamic_update_slice(
                 cache["mask"], token_mask.astype(mask_dtype), (0, offset)
             )
-        elif positions is not None:
-            # a verify pass: the draft steps set its bits and moved the counters
-            offset, advance, new_mask = block_start, None, cache["mask"]
-            positions = positions.astype(jnp.int32)
         elif t == 1:
             offset = cache["row_index"]
             positions = cache["pos"][:, None]
@@ -2521,9 +2493,7 @@ class TransformerLM(nn.Module):
             live = live_width_index(cache["first"], new_mask.shape[-1])
             layers = [{**layer, "live": live} for layer in layers]
 
-        if start > 0:
-            h = x
-        elif P > 0:
+        if P > 0:
             h = jnp.concatenate(
                 [self._embed_soft_prompt(b, positions[:, :P]),
                  self.embed(x, positions[:, P:])],
@@ -2543,9 +2513,9 @@ class TransformerLM(nn.Module):
             if is_prefill and prefill_fuses(cfg, t):
                 step_mask, attn_kernel = token_mask, "prefill"
         # cache layer indices are absolute, so the segments' new layers
-        # concatenate exactly, behind and before the layers passed through
-        bounds = () if looped else sorted({start, stop, capture_split} - {None})  # a looped stack runs whole
-        new_layers, h_cap = list(cache["layers"][:start]), None
+        # concatenate exactly
+        bounds = () if looped else sorted({0, cfg.n_layers, capture_split} - {None})  # a looped stack runs whole
+        new_layers, h_cap = [], None
         if looped:
             h, new_layers, gates = self.run_passes(h, bias, positions, cache=layers, cache_index=offset,
                                                    attn_mask=step_mask, attn_kernel=attn_kernel)
@@ -2561,22 +2531,16 @@ class TransformerLM(nn.Module):
                 cache_index=offset, attn_mask=step_mask, attn_kernel=attn_kernel,
             )
             new_layers += segment
-        if stop == capture_split:
+        if cfg.n_layers == capture_split:
             h_cap = h
-        new_layers += cache["layers"][stop:]
-        if to_head:
-            h = h[:, P:] if P > 0 else h
-            if head_at is not None:
-                h = jnp.take_along_axis(h, head_at[:, None, None], axis=1)
-            logits, h = self.unembed(h, normed=looped)
-        else:
-            logits, h = None, self.ln_f(h)
+        h = h[:, P:] if P > 0 else h
+        if head_at is not None:
+            h = jnp.take_along_axis(h, head_at[:, None, None], axis=1)
+        logits, h = self.unembed(h, normed=looped)
         if not per_row:
             counters = {"index": offset + t, "pos": next_pos}
             if "first" in cache:
                 counters["first"] = cache["first"]
-        elif advance is None:
-            counters = {"row_index": cache["row_index"], "pos": cache["pos"]}
         else:
             counters = {"row_index": cache["row_index"] + advance, "pos": cache["pos"] + advance}
         new_cache = {**counters, "mask": new_mask, "layers": new_layers}

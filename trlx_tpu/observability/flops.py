@@ -129,9 +129,7 @@ def layer_attention_flops(model_cfg, i: int, ctx: float) -> float:
 
 def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
                     unfrozen, window_ok: bool = True,
-                    fast_path: bool = False,
-                    spec_k: int = 0, spec_accept: float = 0.0,
-                    spec_rank: int = 64) -> dict:
+                    fast_path: bool = False) -> dict:
     """Itemized FLOP estimate for one PPO cycle (documented approximations;
     used only for the MFU estimate, never for vs_baseline).
 
@@ -155,39 +153,16 @@ def flops_per_cycle(model_cfg, n_prompt, n_new, n_rollouts, ppo_epochs,
     head = 2 * d * V
     per_layer = [layer_matmul_flops(model_cfg, i) for i in range(L)]
 
-    def fwd(tokens, avg_ctx, layers=L, with_head=True, top=True):
-        """`layers` of the stack: its top ones (the unfrozen suffix), or
-        with `top=False` its bottom ones (the frozen trunk)."""
-        lo, hi = (L - layers, L) if top else (0, layers)
+    def fwd(tokens, avg_ctx, layers=L, with_head=True):
+        """`layers` of the stack: its top ones (the unfrozen suffix)."""
+        lo, hi = L - layers, L
         attention = sum(layer_attention_flops(model_cfg, i, avg_ctx) for i in range(lo, hi))
         # a looped stack runs every layer `loop_steps` times a token, the head once
         passes = getattr(model_cfg, "loop_steps", 1)
         return tokens * (passes * (sum(per_layer[lo:hi]) + attention) + (head if with_head else 0))
 
     # generation: prefill the prompt, then n_new cached decode steps
-    if spec_k > 0:
-        # HONEST speculative accounting: charge what the chip actually
-        # computes, including rejected-draft waste. Each round runs k+1
-        # per-row t=1 TRUNK steps (pending + k drafts), k low-rank draft
-        # readouts, and ONE batched suffix verify over k+1 positions (the
-        # suffix blocks plus the full lm_head at each verified position).
-        # Rounds needed = n_new / E[tokens emitted per round], with
-        # E[tokens/round] = 1 + accept_rate * k from the MEASURED accept
-        # rate — a wrong draft head inflates rounds and deflates MFU
-        # instead of silently flattering the denominator.
-        ctx = n_prompt + n_new / 2
-        split_L = max(L - unfrozen, 1)
-        trunk_step = fwd(1, ctx, layers=split_L, with_head=False, top=False)
-        suffix_pos = fwd(1, ctx, layers=unfrozen)
-        draft_head = 2 * d * spec_rank + 2 * spec_rank * V
-        per_round = ((spec_k + 1) * trunk_step + spec_k * draft_head
-                     + (spec_k + 1) * suffix_pos)
-        tokens_per_round = 1.0 + max(0.0, min(1.0, spec_accept)) * spec_k
-        rounds = max(n_new - 1, 0) / tokens_per_round  # token 0 is plain
-        gen = (fwd(n_prompt, n_prompt / 2)  # prefill (emits token 0)
-               + rounds * per_round)
-    else:
-        gen = fwd(n_prompt, n_prompt / 2) + fwd(n_new, n_prompt + n_new / 2)
+    gen = fwd(n_prompt, n_prompt / 2) + fwd(n_new, n_prompt + n_new / 2)
     if fast_path:
         # fast rollout path: policy logprobs + values were captured inside
         # the sampling loop (already counted under gen), so score is ONLY

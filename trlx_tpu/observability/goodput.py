@@ -169,17 +169,13 @@ class GoodputLedger:
 
     def configure_unit_flops(self, model_cfg, n_prompt: int, n_new: int,
                              unfrozen: int, window_ok: bool = True,
-                             fast_path: bool = False,
-                             spec_k: int = 0, spec_accept: float = 0.0,
-                             spec_rank: int = 64) -> None:
+                             fast_path: bool = False) -> None:
         """Price one sample with the bench FLOP model. ppo_epochs=1: the
         train cost is charged per-minibatch-row as epochs actually run,
         so repeated epochs accumulate naturally."""
         unit = flops_per_sample(
             model_cfg, n_prompt, n_new, ppo_epochs=1, unfrozen=unfrozen,
             window_ok=window_ok, fast_path=fast_path,
-            spec_k=spec_k,
-            spec_accept=spec_accept, spec_rank=spec_rank,
         )
         with self._lock:
             self._unit = unit

@@ -390,7 +390,7 @@ def paged_kv_write(
     view: patching its [1, nkv*block] rows makes XLA re-lay the plane.
 
     Who calls it (`models/transformer.py:Attention`): every prefill and
-    insert and speculative verify (t > 1: whole blocks are written and the
+    insert (t > 1: whole blocks are written and the
     scatter is the right tool), every step of the gather read path, and a
     decode step (t == 1) in front of the kernel where the kernel does not
     write: int8 arenas and their planes, and arenas whose blocks are

@@ -20,7 +20,7 @@ mask. Stop-sequence trimming is string-level host-side post-processing
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -179,12 +179,12 @@ class BlockPlan:
 
 
 def block_plan(model_cfg: TransformerConfig, gen_cfg: GenerationConfig, plen: int,
-               block: int = PREFILL_BLOCK, spec_k: int = 0) -> Optional[BlockPlan]:
+               block: int = PREFILL_BLOCK) -> Optional[BlockPlan]:
     """The plan `make_generate_fn`'s program runs a prompt block of `plen`
     columns by, or None where it keeps the one-shot prefill: the sampler is
-    the token-at-a-time causal loop (one beam, no speculative rounds), and
+    the token-at-a-time causal loop (one beam), and
     the model's layers and the width allow it (`prefill_by_blocks`)."""
-    if (block <= 0 or spec_k > 0 or gen_cfg.num_beams > 1 or getattr(model_cfg, "is_seq2seq", False)
+    if (block <= 0 or gen_cfg.num_beams > 1 or getattr(model_cfg, "is_seq2seq", False)
             or not prefill_by_blocks(model_cfg, plen, block)):
         return None
     return BlockPlan.of(plen, gen_cfg.max_new_tokens, block)
@@ -206,9 +206,6 @@ def make_generate_fn(
     two_qs: bool = True,
     capture: bool = False,
     capture_split: int = 0,
-    spec_k: int = 0,  # > 0: self-speculative decode, k drafts per round
-    spec_split: int = 0,  # hydra split = draft trunk depth (required when spec_k > 0)
-    spec_draft_head: Optional[Tuple] = None,  # (A [d, r], B [r, V]) low-rank readout
     prefill_block: int = PREFILL_BLOCK,  # 0: always the one-shot prefill
 ) -> Callable:
     """Build a jittable generate(params, input_ids, attn_mask, rng) ->
@@ -258,45 +255,6 @@ def make_generate_fn(
             "rollout stat capture supports single-beam causal LM "
             "generation only (no ILQL, seq2seq, or beam search)"
         )
-
-    if spec_k > 0:
-        # Self-speculative decode gates. These mirror the trainer-side
-        # `_spec_decode_available` checks but refuse loudly here too, so a
-        # direct make_generate_fn caller can't silently get a sampler whose
-        # distribution differs from the plain one.
-        if mode != "lm" or is_seq2seq or gen_cfg.num_beams > 1:
-            raise NotImplementedError(
-                "speculative decode supports single-beam causal LM "
-                "generation only (no ILQL, seq2seq, or beam search)"
-            )
-        if gen_cfg.repetition_penalty != 1.0:
-            raise NotImplementedError(
-                "speculative decode with repetition_penalty != 1 is not "
-                "supported (the seen-token mask would need per-draft "
-                "rollback)"
-            )
-        if getattr(model_cfg, "has_slot_state", False):
-            from trlx_tpu.models.transformer import slot_state_of
-
-            raise NotImplementedError(
-                f"speculative decode over slot state ({slot_state_of(model_cfg)}) is not supported: "
-                "rejected drafts roll back by clearing mask bits, which does not undo a state a row"
-            )
-        if getattr(model_cfg, "moe_experts", 0) > 0:
-            raise NotImplementedError(
-                "speculative decode with MoE blocks is not supported "
-                "(expert routing differs between draft and verify widths)"
-            )
-        if spec_split <= 0:
-            raise ValueError(
-                "speculative decode requires a hydra split > 0 (the frozen "
-                "trunk IS the draft model)"
-            )
-        if spec_draft_head is None:
-            raise ValueError(
-                "speculative decode requires a draft head (A, B) — see "
-                "spec_draft_head_from_params"
-            )
 
     if gen_cfg.num_beams > 1:
         if mode != "lm" or logit_mask is not None or gen_cfg.suppress_tokens:
@@ -576,314 +534,7 @@ def make_generate_fn(
             "response_mask": samples_mask,
         }
 
-    if spec_k > 0:
-        k = spec_k
-        a_fac = jnp.asarray(spec_draft_head[0], model_cfg.dtype)
-        b_fac = jnp.asarray(spec_draft_head[1], model_cfg.dtype)
-        greedy = (not gen_cfg.do_sample) or (gen_cfg.temperature == 0.0)
-        if capture and capture_split != spec_split:
-            raise ValueError(
-                "capture_split must equal spec_split under speculative "
-                "decode (both are the hydra split)"
-            )
-
-        def spec_draft(params, tokens, cache, token_mask):
-            """One trunk-only step, blocks [0, split): (h_split, its `ln_f`
-            reading for the draft head, cache). No head runs."""
-            _, h_norm, cache, h_split = model.apply(
-                {"params": params}, tokens, cache, token_mask,
-                stop=spec_split, capture_split=spec_split,
-                method=type(model).decode_step,
-            )
-            return h_split, h_norm, cache
-
-        def spec_verify(params, h, cache, row_start, positions):
-            """The suffix blocks over all k+1 drafted positions at once."""
-            out = model.apply(
-                {"params": params}, h, cache, None,
-                start=spec_split, block_start=row_start, positions=positions,
-                method=type(model).decode_step,
-                **({"with_value": True} if capture else {}),
-            )
-            # slot 1 is the policy wrapper's values (None without capture)
-            # or a bare TransformerLM's h_final: unused without capture
-            return out[0], out[1] if capture else None, out[2]
-
-        def warp(raw_logits, prev_token, step):
-            return process_logits(
-                shift_logits(raw_logits, None, prev_token), gen_cfg, step, None
-            )
-
-        def generate_spec(params, input_ids, attn_mask, rng):
-            """Draft/verify round schedule. Each round: feed the pending
-            token plus k sampled drafts through the frozen TRUNK only (k+1
-            per-row t=1 cached steps, low-rank early-exit readout between
-            them), then ONE batched suffix pass over all k+1 positions
-            resuming from the trunk's own h_split (verify pays suffix
-            blocks only), accept the longest matching draft prefix with
-            exact rejection-sampling correction, and roll rejected KV back
-            by clearing mask bits. Greedy output is bitwise the plain
-            sampler's (argmax prefix match); sampled output follows the
-            identical warped distribution (standard speculative-sampling
-            correctness)."""
-            params = dequantize_tree(params)
-            b, plen = input_ids.shape
-            total = plen + max_new
-            token_dtype = input_ids.dtype
-            # k spare cache slots: a round may write k positions past the
-            # budget before the rollback clears them
-            cache = init_kv_cache(model_cfg, b, total + k)
-            last_logits, _, last_value, h_cap, cache = step_model(
-                params, input_ids, cache, attn_mask, True
-            )
-            # token 0: bitwise the plain sampler's preamble (same prefill,
-            # same RNG split, same warp chain)
-            rng, key = jax.random.split(rng)
-            scores0 = warp(last_logits, input_ids[:, -1], 0)
-            token0 = select_token(scores0, key, gen_cfg).astype(token_dtype)
-            finished0 = (token0 == gen_cfg.eos_token_id) | (max_new <= 1)
-            out_tokens0 = jnp.full((b, max_new), gen_cfg.pad_token_id, dtype=token_dtype)
-            out_tokens0 = out_tokens0.at[:, 0].set(token0)
-            out_mask0 = jnp.zeros((b, max_new), jnp.int32).at[:, 0].set(1)
-            if capture:
-                lp0 = jnp.zeros((b, max_new), jnp.float32).at[:, 0].set(
-                    sampled_token_logprob(last_logits, token0)
-                )
-                v0 = jnp.zeros((b, max_new), jnp.float32).at[:, 0].set(last_value)
-                hs0 = jnp.zeros((b, total, h_cap.shape[-1]), h_cap.dtype)
-                hs0 = jax.lax.dynamic_update_slice(hs0, h_cap, (0, 0, 0))
-                cap0 = (lp0, v0, hs0)
-            else:
-                cap0 = ()
-            # scalar-index prefill cache -> per-row offsets (rows diverge
-            # once they accept different draft counts)
-            row_cache = {
-                "row_index": jnp.full((b,), cache["index"], jnp.int32),
-                "mask": cache["mask"],
-                "pos": cache["pos"],
-                "layers": cache["layers"],
-            }
-            state = (
-                jnp.asarray(0, jnp.int32), rng, row_cache, token0, finished0,
-                jnp.ones((b,), jnp.int32),  # out_i: token 0 already written
-                out_tokens0, out_mask0,
-                jnp.zeros((b,), jnp.int32),  # rounds (per active row)
-                jnp.zeros((b,), jnp.int32),  # accepted drafts
-                cap0,
-            )
-            jidx = jnp.arange(k + 1)[None, :]
-
-            def cond(state):
-                return (state[0] <= max_new) & jnp.any(~state[4])
-
-            def body(state):
-                (i, rng, cache, pending, finished, out_i, out_tokens,
-                 out_mask, rounds, acc_tot, cap) = state
-                active = ~finished
-                act_i = active.astype(jnp.int32)
-                row_start = cache["row_index"]
-                pos_start = cache["pos"]
-                f = pending
-                h_rows, q_scores, draft_toks, toks_fed = [], [], [], [pending]
-                for j in range(k + 1):
-                    h_j, hn_j, cache = spec_draft(
-                        params, f[:, None], cache, act_i[:, None]
-                    )
-                    h_rows.append(h_j)
-                    if j < k:
-                        rng, key = jax.random.split(rng)
-                        dl = ((hn_j[:, 0] @ a_fac) @ b_fac).astype(jnp.float32)
-                        sq = warp(dl, f, out_i + j)
-                        f = select_token(sq, key, gen_cfg).astype(token_dtype)
-                        q_scores.append(sq)
-                        draft_toks.append(f)
-                        toks_fed.append(f)
-                h_block = jnp.concatenate(h_rows, axis=1)  # [b, k+1, d]
-                positions = pos_start[:, None] + jnp.arange(k + 1)[None, :]
-                logits_v, values_v, cache = spec_verify(
-                    params, h_block, cache, row_start, positions
-                )
-                logits_v = logits_v.astype(jnp.float32)
-                p_scores = [
-                    warp(logits_v[:, j], toks_fed[j], out_i + j)
-                    for j in range(k + 1)
-                ]
-                # longest accepted draft prefix
-                if greedy:
-                    acc = [
-                        jnp.argmax(p_scores[j], -1).astype(token_dtype)
-                        == draft_toks[j]
-                        for j in range(k)
-                    ]
-                else:
-                    acc = []
-                    for j in range(k):
-                        rng, key = jax.random.split(rng)
-                        u = jax.random.uniform(key, (b,))
-                        tok = draft_toks[j].astype(jnp.int32)[:, None]
-                        lr = (
-                            jnp.take_along_axis(
-                                jax.nn.log_softmax(p_scores[j], -1), tok, 1
-                            )
-                            - jnp.take_along_axis(
-                                jax.nn.log_softmax(q_scores[j], -1), tok, 1
-                            )
-                        )[:, 0]
-                        acc.append(u < jnp.exp(jnp.minimum(lr, 0.0)))
-                run = jnp.ones((b,), bool)
-                m = jnp.zeros((b,), jnp.int32)
-                for j in range(k):
-                    run = run & acc[j]
-                    m = m + run.astype(jnp.int32)
-                # correction candidates per possible acceptance count:
-                # greedy -> the full-model argmax; sampled -> residual
-                # normalize(clip(p - q, 0)) for a rejection at j, the plain
-                # warped draw for the all-accepted bonus position
-                corr = []
-                for j in range(k + 1):
-                    if greedy:
-                        corr.append(jnp.argmax(p_scores[j], -1).astype(token_dtype))
-                    elif j < k:
-                        rng, key = jax.random.split(rng)
-                        p_w = jax.nn.softmax(p_scores[j], -1)
-                        q_w = jax.nn.softmax(q_scores[j], -1)
-                        res = jnp.clip(p_w - q_w, 0.0, None)
-                        tot = res.sum(-1, keepdims=True)
-                        res = jnp.where(tot > 0, res / tot, p_w)
-                        corr.append(
-                            jax.random.categorical(
-                                key,
-                                jnp.where(res > 0, jnp.log(res), -jnp.inf),
-                                axis=-1,
-                            ).astype(token_dtype)
-                        )
-                    else:
-                        rng, key = jax.random.split(rng)
-                        corr.append(
-                            select_token(p_scores[j], key, gen_cfg).astype(token_dtype)
-                        )
-                corr = jnp.stack(corr, axis=1)  # [b, k+1]
-                corr_at_m = jnp.take_along_axis(corr, m[:, None], axis=1)[:, 0]
-                draft_mat = jnp.stack(draft_toks + [corr[:, k]], axis=1)
-                emit_toks = jnp.where(
-                    jidx < m[:, None],
-                    draft_mat,
-                    jnp.where(
-                        jidx == m[:, None], corr_at_m[:, None], gen_cfg.pad_token_id
-                    ),
-                ).astype(token_dtype)
-                # eos / budget truncation of this round's emissions
-                alive = active
-                valids = []
-                for j in range(k + 1):
-                    v_j = alive & (j <= m) & (out_i + j < max_new)
-                    valids.append(v_j)
-                    alive = v_j & (emit_toks[:, j] != gen_cfg.eos_token_id)
-                valid_mat = jnp.stack(valids, axis=1)
-                emit_toks = jnp.where(
-                    valid_mat, emit_toks, gen_cfg.pad_token_id
-                ).astype(token_dtype)
-                e = valid_mat.astype(jnp.int32).sum(1)
-                hit_eos = jnp.any(
-                    valid_mat & (emit_toks == gen_cfg.eos_token_id), axis=1
-                )
-                new_out_i = out_i + e
-                new_finished = finished | (
-                    active & (hit_eos | (new_out_i >= max_new))
-                )
-                new_pending = jnp.where(active & ~new_finished, corr_at_m, pending)
-                # roll back rejected KV: keep mask bits for the e fed-and-
-                # kept tokens f_0..f_{e-1}, clear the rest — next round's
-                # writes land exactly on the first cleared offset
-                rows_b = jnp.arange(b)[:, None]
-                offs = row_start[:, None] + jidx
-                new_mask_c = cache["mask"].at[rows_b, offs].set(
-                    (jidx < e[:, None]).astype(cache["mask"].dtype)
-                )
-                cache = dict(
-                    cache, mask=new_mask_c,
-                    row_index=row_start + e, pos=pos_start + e,
-                )
-                out_idx = jnp.where(valid_mat, out_i[:, None] + jidx, max_new)
-                out_tokens = out_tokens.at[rows_b, out_idx].set(emit_toks)
-                out_mask = out_mask.at[rows_b, out_idx].set(
-                    valid_mat.astype(jnp.int32)
-                )
-                if capture:
-                    lp_buf, v_buf, hs_buf = cap
-                    lsm = jax.nn.log_softmax(logits_v, axis=-1)
-                    lp_emit = jnp.take_along_axis(
-                        lsm, emit_toks.astype(jnp.int32)[..., None], axis=-1
-                    )[..., 0]
-                    lp_buf = lp_buf.at[rows_b, out_idx].set(lp_emit)
-                    v_buf = v_buf.at[rows_b, out_idx].set(
-                        values_v.astype(jnp.float32)
-                    )
-                    # h rows for the fed tokens f_0..f_{e-1} land at their
-                    # sequence positions plen + out_i - 1 + j; the final
-                    # emitted token's row is never written (same invariant
-                    # as the plain capture loop)
-                    hs_off = hs_buf.shape[1] - max_new
-                    h_idx = jnp.where(
-                        jidx < e[:, None],
-                        hs_off + out_i[:, None] - 1 + jidx,
-                        hs_buf.shape[1],
-                    )
-                    hs_buf = hs_buf.at[rows_b, h_idx].set(
-                        h_block.astype(hs_buf.dtype)
-                    )
-                    cap = (lp_buf, v_buf, hs_buf)
-                return (i + 1, rng, cache, new_pending, new_finished, new_out_i,
-                        out_tokens, out_mask, rounds + act_i,
-                        acc_tot + m * act_i, cap)
-
-            final = jax.lax.while_loop(cond, body, state)
-            out_tokens, out_mask = final[6], final[7]
-            samples = jnp.concatenate([input_ids, out_tokens], axis=1)
-            samples_mask = jnp.concatenate(
-                [attn_mask.astype(jnp.int32), out_mask], axis=1
-            )
-            out = {
-                "samples": samples,
-                "samples_mask": samples_mask,
-                "response_tokens": out_tokens,
-                "response_mask": out_mask,
-                "spec_rounds": final[8],
-                "spec_accepted": final[9],
-            }
-            if capture:
-                out["logprobs"], out["values"], out["h_split"] = final[10]
-            return out
-
-        return generate_spec
-
     return generate_seq2seq if is_seq2seq else generate
-
-
-def spec_draft_head_from_params(params, model_cfg: TransformerConfig, rank: int):
-    """Low-rank draft readout (A [d, r], B [r, V]) from the unembedding:
-    truncated SVD W_U ≈ A @ B, computed host-side ONCE. Under a hydra
-    split with tied embeddings the unembedding never trains, so the
-    factors never go stale; with an untied (trainable) lm_head they decay
-    in quality as training moves the head — a PERF effect only, since the
-    rejection-sampling correction keeps the sampled distribution exact
-    regardless of draft quality. Draft logits = ln_f(h_split) @ A @ B,
-    an early-exit readout that streams r*(d+V) draft-head bytes per step
-    instead of the full d*V unembedding."""
-    def dense(leaf):
-        # tolerate the int8 decode view (ops/quant.py node layout)
-        if isinstance(leaf, dict) and set(leaf.keys()) == {"q", "scale"}:
-            return np.asarray(leaf["q"], np.float32) * np.asarray(leaf["scale"], np.float32)
-        return np.asarray(leaf, np.float32)
-
-    lm = params["lm"] if "lm" in params else params
-    if model_cfg.tie_embeddings:
-        w = dense(lm["embed_tokens"]["embedding"]).T  # [d, V]
-    else:
-        w = dense(lm["lm_head"]["kernel"])  # [d, V]
-    r = int(min(rank, min(w.shape)))
-    u, s, vt = np.linalg.svd(w, full_matrices=False)
-    return (u[:, :r] * s[:r][None, :]).astype(np.float32), vt[:r].astype(np.float32)
 
 
 def generate(
@@ -899,14 +550,9 @@ def generate(
     two_qs: bool = True,
     capture: bool = False,
     capture_split: int = 0,
-    spec_k: int = 0,
-    spec_split: int = 0,
-    spec_draft_head: Optional[Tuple] = None,
     prefill_block: int = PREFILL_BLOCK,
 ):
     """One-shot convenience wrapper (not cached across shapes)."""
     fn = make_generate_fn(model, model_cfg, gen_cfg, mode, logit_mask, two_qs,
-                          capture=capture, capture_split=capture_split,
-                          spec_k=spec_k, spec_split=spec_split,
-                          spec_draft_head=spec_draft_head, prefill_block=prefill_block)
+                          capture=capture, capture_split=capture_split, prefill_block=prefill_block)
     return fn(params, jnp.asarray(input_ids), jnp.asarray(attn_mask), rng)

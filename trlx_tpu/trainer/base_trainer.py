@@ -348,19 +348,17 @@ class TPUTrainer(BaseRLTrainer):
                             ledger=self._compile_ledger, **jit_kwargs)
 
     def get_generate_fn(self, batch_size: int, prompt_len: int, gen_kwargs: Dict, mode: str = "lm",
-                        capture: bool = False, spec_k: int = 0):
+                        capture: bool = False):
         """Jit-cached generate fn per (shape, kwargs) bucket. `capture`
         builds the rollout fast-path sampler, which additionally emits
-        per-token logprobs/values and the hydra-split activations; spec_k
-        > 0 builds the self-speculative draft/verify sampler instead of
-        the token-at-a-time loop (see ops/sampling.py)."""
+        per-token logprobs/values and the hydra-split activations (see
+        ops/sampling.py)."""
         from trlx_tpu.ops.sampling import make_generate_fn
 
         # repr-normalize values: gen_kwargs may carry unhashable HF-style
         # knobs (lists/dicts) from configs written against the reference
         block = self._prefill_block()
-        key = (batch_size, prompt_len, repr(sorted(gen_kwargs.items())), mode, bool(capture),
-               int(spec_k), block)
+        key = (batch_size, prompt_len, repr(sorted(gen_kwargs.items())), mode, bool(capture), block)
         if key not in self._generate_cache:
             gen_cfg = self._generation_config(gen_kwargs)
             two_qs = bool(getattr(self.config.method, "two_qs", True))
@@ -368,8 +366,6 @@ class TPUTrainer(BaseRLTrainer):
                 self.model, self.model_cfg, gen_cfg, mode=mode,
                 logit_mask=self.logit_mask, two_qs=two_qs,
                 capture=capture, capture_split=self.split if capture else 0,
-                spec_k=spec_k, spec_split=self.split if spec_k > 0 else 0,
-                spec_draft_head=self._spec_draft_head() if spec_k > 0 else None,
                 prefill_block=block,
             )
             # each (shape, kwargs) bucket is its own compiled program by
@@ -381,19 +377,10 @@ class TPUTrainer(BaseRLTrainer):
             fn_name = (
                 f"generate[b{batch_size},p{prompt_len},{mode}"
                 + (",cap" if capture else "")
-                + (f",spec{spec_k}" if spec_k else "")
                 + f",kw{kw_tag}]"
             )
             self._generate_cache[key] = self._ljit(fn, fn_name)
         return self._generate_cache[key]
-
-    def _spec_draft_head(self):
-        """Low-rank draft readout for speculative decode; trainers that
-        enable method.speculative_decode override this with a cached SVD
-        of the frozen unembedding (ppo_trainer)."""
-        raise NotImplementedError(
-            "speculative decode needs a trainer-provided draft head"
-        )
 
     def _decode_params(self) -> Dict:
         """Param view fed to the sampler. The base view is the merged
@@ -446,7 +433,7 @@ class TPUTrainer(BaseRLTrainer):
         return trimmed
 
     def generate(self, input_ids, attention_mask, gen_kwargs: Optional[Dict] = None, mode: str = "lm",
-                 capture: bool = False, spec_k: int = 0):
+                 capture: bool = False):
         """Sample continuations for a (host) prompt batch; returns the
         sampling dict (device arrays)."""
         gen_kwargs = gen_kwargs if gen_kwargs is not None else self.generate_kwargs
@@ -461,7 +448,7 @@ class TPUTrainer(BaseRLTrainer):
         else:
             orig = (input_ids.shape[0], 0)
         fn = self.get_generate_fn(input_ids.shape[0], input_ids.shape[1], gen_kwargs, mode,
-                                  capture=capture, spec_k=spec_k)
+                                  capture=capture)
         out = fn(self._decode_params(), jnp.asarray(input_ids), jnp.asarray(attention_mask),
                  self.next_rng())
         return self._unbucket_output(out, orig)
@@ -1915,14 +1902,14 @@ class TPUTrainer(BaseRLTrainer):
 
         return sampling.PREFILL_BLOCK if self.config.tokenizer.padding_side == "left" else 0
 
-    def _block_plan(self, prompt_len: int, gen_kwargs: Dict, spec_k: int = 0, **_):
+    def _block_plan(self, prompt_len: int, gen_kwargs: Dict):
         """The plan by which `get_generate_fn`'s program of that prompt width
         follows a batch's longest prompt, or None where it keeps the one-shot
         prefill: the sampler's own rule (`ops.sampling.block_plan`)."""
         from trlx_tpu.ops.sampling import block_plan
 
         return block_plan(self.model_cfg, self._generation_config(gen_kwargs), prompt_len,
-                          self._prefill_block(), spec_k)
+                          self._prefill_block())
 
 
 def _batch_shapes(batch) -> Tuple:

@@ -58,7 +58,6 @@ logger = logging.get_logger(__name__)
 @register_trainer
 class PipelinedPPOTrainer(PipelinedCausalMixin, PPOTrainer):
     _supports_moe_pp = True  # in-pipe aux-loss carry consumed in make_loss_fn
-    _narrows_rollout_chunks = False  # PipelinedCausalMixin.generate runs no `BlockPlan`
     # r4: the 1F1B loss is expressed in full token width (prepare() scatters
     # the response windows to their predicting positions, CE-preshift
     # style), so it composes with sequence parallelism — the deep-model
@@ -132,22 +131,9 @@ class PipelinedPPOTrainer(PipelinedCausalMixin, PPOTrainer):
         per-block layout — the full-forward train loss stays in charge."""
         return False
 
-    def _spec_decode_available(self) -> bool:
-        """Speculative decode is unavailable here for the same reason as
-        the fast rollout path: the draft/verify split applies
-        (`decode_step(stop=split)` / `(start=split)`) need the unstacked per-block
-        layout — the plain sampler stays in charge."""
-        if (
-            getattr(self.config.method, "speculative_decode", False)
-            and not getattr(self, "_warned_no_spec_decode", False)
-        ):
-            self._warned_no_spec_decode = True
-            logger.warning(
-                "method.speculative_decode is ignored under pipeline "
-                "parallelism (stacked params cannot run the draft/verify "
-                "applies); sampling with the plain fused loop"
-            )
-        return False
+    def _rollout_plan(self, width: int, gen_kwargs):
+        """None: `PipelinedCausalMixin.generate` runs no `BlockPlan`."""
+        return None
 
     def _decode_params(self):
         """The int8 decode view is unavailable here: quantize_frozen_flat

@@ -120,18 +120,6 @@ class PPOConfig(MethodConfig):
     # for short responses). Default off to preserve reference-parity
     # curves (the reference whitens unmasked, utils/modeling.py whiten).
     whiten_with_mask: bool = False
-    # Self-speculative decode: the frozen hydra trunk plus a low-rank SVD
-    # readout of the unembedding drafts spec_k tokens per round; one
-    # batched suffix pass verifies all of them from the trunk's own
-    # h_split (the trunk cache's economics applied to sampling) and
-    # accepts the longest matching prefix with exact rejection-sampling
-    # correction — greedy output stays bitwise the plain sampler's,
-    # sampled output follows the identical distribution. Default off:
-    # flag off is bit-identical to the plain fused sampler. Extra fields
-    # vs the reference config set.
-    speculative_decode: bool = False
-    spec_k: int = 4
-    spec_draft_rank: int = 64
     # Int8 weight-only view of the never-trained decode weights (blocks
     # below the hydra split + embeddings) swapped in for GENERATION only;
     # train/score always see the dense tree. Default off: flag off is
@@ -260,20 +248,13 @@ class PPOTrainer(TPUTrainer):
         """Price the goodput ledger's per-sample FLOPs with the knobs
         an offline estimate passes to flops_per_cycle — live MFU and an
         offline MFU share one model by construction. Re-done every
-        chunk (pure arithmetic): the speculative accept rate is measured,
-        so it converges as rounds accumulate."""
-        spec_k = self._spec_k_effective()
-        rounds = int(getattr(self, "spec_decode_rounds", 0))
-        accepted = int(getattr(self, "spec_decode_accepted", 0))
-        accept = accepted / (spec_k * rounds) if rounds and spec_k else 0.0
+        chunk (pure arithmetic)."""
         self._goodput.configure_unit_flops(
             self.model_cfg, n_prompt, n_new,
             unfrozen=self.model_cfg.n_layers - self.split,
             window_ok=(self._window_loss_ok()
                        and not getattr(self.model_cfg, "sows_moe_aux", False)),
             fast_path=False,  # make_experience scores with the full fwd
-            spec_k=spec_k, spec_accept=accept,
-            spec_rank=int(getattr(self.config.method, "spec_draft_rank", 64)),
         )
 
     def make_loss_fn(self) -> Callable:
@@ -844,11 +825,7 @@ class PPOTrainer(TPUTrainer):
                 if use_fleet:
                     out = self._fleet_generate(b, gen_kwargs, trainer_step=iter_count)
                 else:
-                    # spec_k only travels when a speculative round is actually on:
-                    # the parallel mixins' generate() has no spec_k parameter.
-                    spec_k = self._spec_k_effective()
-                    spec_kw = {"spec_k": spec_k} if spec_k else {}
-                    out = self._rollout_generate(b, gen_kwargs, **spec_kw)
+                    out = self._rollout_generate(b, gen_kwargs)
             return b, out, chunk, t_dispatch
 
         pending = _dispatch_next()
@@ -888,7 +865,6 @@ class PPOTrainer(TPUTrainer):
             real_tokens = int(np.asarray(out["response_mask"]).sum())
             stats["throughput/rollout_tokens_per_s"] = real_tokens / gen_s
             stats["throughput/rollout_requests_per_s"] = n_this / gen_s
-            self._accum_spec_stats(out, stats)
 
             with self._span("ppo.rollout_process", phase="rollout_process",
                             step=iter_count, chunk=chunk) as process:
@@ -1477,20 +1453,14 @@ class PPOTrainer(TPUTrainer):
     def add_prompt_pipeline(self, pipeline):
         self.prompt_iterator = self._rollout_stream(pipeline, self.config.method.chunk_size)
 
-    #: whether a rollout chunk goes through the base trainer's `generate`,
-    #: whose program may follow its longest prompt (a `BlockPlan`); the
-    #: pipelined trainers' own `generate` does not
-    _narrows_rollout_chunks = True
     #: the width the rollout loader pads every chunk's prompts to, where its
     #: pipeline says (`_rollout_stream`)
     _rollout_prompt_width: Optional[int] = None
 
-    def _rollout_plan(self, width: int, gen_kwargs, **generate_kwargs):
+    def _rollout_plan(self, width: int, gen_kwargs):
         """The `BlockPlan` a rollout chunk of that prompt width is generated
         by, or None: the sampler keeps the one-shot prefill."""
-        if not self._narrows_rollout_chunks:
-            return None
-        return self._block_plan(self._bucket_shape(1, width)[1], gen_kwargs, **generate_kwargs)
+        return self._block_plan(self._bucket_shape(1, width)[1], gen_kwargs)
 
     def _rollout_stream(self, pipeline, rows: int, **loader_kwargs) -> LoaderStream:
         """The rollout loader's chunks of `rows` prompts, forever. A pipeline
@@ -1520,7 +1490,7 @@ class PPOTrainer(TPUTrainer):
         input_ids = np.asarray(batch["input_ids"])
         attention_mask = np.asarray(batch["attention_mask"])
         rows, width = self._bucket_shape(*attention_mask.shape)
-        plan = self._rollout_plan(attention_mask.shape[1], gen_kwargs, **generate_kwargs)
+        plan = self._rollout_plan(attention_mask.shape[1], gen_kwargs)
         engaged = {}
         if plan is not None:
             # `_bucket_prompts` pads on the left, as the prompts are
@@ -1605,9 +1575,7 @@ class PPOTrainer(TPUTrainer):
         what the importance ratio needs)."""
         gen_kwargs = self.generate_experience_kwargs or self.generate_kwargs
         batch = next(self.prompt_iterator)
-        spec_k = self._spec_k_effective()
-        out = self._rollout_generate(batch, gen_kwargs, capture=self._fast_rollout_available(),
-                                     **({"spec_k": spec_k} if spec_k else {}))
+        out = self._rollout_generate(batch, gen_kwargs, capture=self._fast_rollout_available())
         return batch, out
 
     def _build_score_reward_fn(self, scalar_scores: bool):
@@ -1801,70 +1769,8 @@ class PPOTrainer(TPUTrainer):
         )
 
     # ------------------------------------------------------------------
-    # Self-speculative decode + int8 frozen-trunk decode view
+    # Int8 frozen-trunk decode view
     # ------------------------------------------------------------------
-
-    def _spec_decode_available(self) -> bool:
-        """Whether generation may run the draft/verify speculative
-        sampler (method.speculative_decode). Needs a real hydra split
-        (the frozen trunk IS the draft model), a causal LM, no MoE (the
-        router recomputes per-token state the rollback can't unwind), no
-        prompt/prefix virtual tokens, single-beam sampling, and no
-        repetition penalty (its `seen` set is order-dependent across a
-        rejected draft). A refusal while the flag is on counts in
-        self.spec_decode_fallbacks — distinct from self.spec_fallbacks,
-        which counts the speculative SCORER's retokenization misses.
-        Overridden to False by the pipelined/sequence-parallel trainers,
-        whose param layouts can't run the split draft/verify applies."""
-        if not getattr(self.config.method, "speculative_decode", False):
-            return False
-        gen_kwargs = self.generate_experience_kwargs or self.generate_kwargs
-        ok = (
-            not self.seq2seq
-            and self.split > 0
-            and getattr(self.model_cfg, "moe_experts", 0) == 0
-            and not getattr(self.model_cfg, "has_slot_state", False)
-            and getattr(self.model_cfg, "prompt_tokens", 0) == 0
-            and getattr(self.model_cfg, "prefix_tokens", 0) == 0
-            and int(gen_kwargs.get("num_beams", 1) or 1) == 1
-            and float(gen_kwargs.get("repetition_penalty", 1.0) or 1.0) == 1.0
-        )
-        if not ok:
-            self.spec_decode_fallbacks = getattr(self, "spec_decode_fallbacks", 0) + 1
-        return ok
-
-    def _spec_k_effective(self) -> int:
-        return int(getattr(self.config.method, "spec_k", 4)) if self._spec_decode_available() else 0
-
-    def _accum_spec_stats(self, out, stats: Optional[Dict] = None):
-        """Fold a sampling dict's speculative counters into the trainer's
-        running totals (and, when given, a per-chunk stats dict). Called
-        only after the chunk's samples were already fetched, so these tiny
-        [b] reads never add a device sync."""
-        if "spec_rounds" not in out:
-            return
-        rounds = int(np.asarray(out["spec_rounds"]).sum())
-        accepted = int(np.asarray(out["spec_accepted"]).sum())
-        self.spec_decode_rounds = getattr(self, "spec_decode_rounds", 0) + rounds
-        self.spec_decode_accepted = getattr(self, "spec_decode_accepted", 0) + accepted
-        if stats is not None and rounds > 0:
-            k = int(getattr(self.config.method, "spec_k", 4))
-            stats["rollout/spec_accept_rate"] = accepted / float(k * rounds)
-            stats["rollout/spec_tokens_per_round"] = 1.0 + accepted / float(rounds)
-
-    def _spec_draft_head(self):
-        """Rank-`spec_draft_rank` SVD of the unembedding, computed once on
-        host (the tied embedding is frozen under any hydra split, so the
-        factors never go stale; an untied lm_head drifts — a draft-quality
-        effect only, the rejection correction keeps outputs exact)."""
-        cached = getattr(self, "_spec_draft_head_cache", None)
-        if cached is None:
-            from trlx_tpu.ops.sampling import spec_draft_head_from_params
-
-            rank = int(getattr(self.config.method, "spec_draft_rank", 64))
-            cached = spec_draft_head_from_params(self.params, self.model_cfg, rank)
-            self._spec_draft_head_cache = cached
-        return cached
 
     def _decode_params(self):
         """Sampler param view: the int8 frozen-trunk tree when
@@ -2070,7 +1976,7 @@ class PPOTrainer(TPUTrainer):
         rows, width = self._bucket_shape(int(self.config.method.chunk_size), self._chunk_prompt_width())
         prompts = jax.ShapeDtypeStruct((rows, width), jnp.int32)
         return self._program_held_bytes(
-            self.get_generate_fn(rows, width, gen_kwargs, spec_k=self._spec_k_effective()),
+            self.get_generate_fn(rows, width, gen_kwargs),
             self._decode_params(), prompts, prompts,
             jax.ShapeDtypeStruct(self.rng.shape, self.rng.dtype))
 
@@ -2584,8 +2490,6 @@ class PPOTrainer(TPUTrainer):
             fetched = jax.device_get(tuple(fetch))
         samples_list = fetched[:k]
         trimmed_list = fetched[k:2 * k] if use_spec else [None] * k
-        for _, o in gens:
-            self._accum_spec_stats(o)
 
         processed = None
         if use_fast:

@@ -110,23 +110,6 @@ class SequenceParallelPPOTrainer(PPOTrainer):
         lives outside that layout — the full-forward loss stays in charge."""
         return False
 
-    def _spec_decode_available(self) -> bool:
-        """Speculative decode is unavailable here: rollouts run through
-        the sharded generate layout, and the draft/verify applies
-        (`decode_step(stop=split)` / `(start=split)`) live outside it — the plain
-        sampler stays in charge."""
-        if (
-            getattr(self.config.method, "speculative_decode", False)
-            and not getattr(self, "_warned_no_spec_decode", False)
-        ):
-            self._warned_no_spec_decode = True
-            logger.warning(
-                "method.speculative_decode is ignored under sequence "
-                "parallelism (the draft/verify applies do not run in the "
-                "sharded layout); sampling with the plain fused loop"
-            )
-        return False
-
     def _decode_params(self):
         """The int8 decode view is unavailable here: the sharded decode
         path consumes the dense replicated tree — dense weights stay in
